@@ -1339,7 +1339,7 @@ class Broker:
 
     def _finish_batch_member(self, m, table, results, stats, err):
         """Realtime part + reduce + finish for one batched member, honoring
-        the kill/timeout taxonomy: a detached member degrades to a partial
+        the kill/timeout classification: a detached member degrades to a partial
         result when it opted in, else its error is its outcome."""
         allow_partial = str(m.ctx.options.get("allowPartialResults", "")).lower() in (
             "1", "true", "yes",
@@ -1472,7 +1472,7 @@ class Broker:
         e = out
         if isinstance(e, QueryKilledError) and e.reason == "hedge_lost":
             # cooperative cancel landed: not a failure — no punish, breaker
-            # untouched (mirrors the watchdog-kill taxonomy in _scatter)
+            # untouched (mirrors the watchdog-kill classification in _scatter)
             METRICS.counter("broker.hedgesCancelled").inc()
             METRICS.timer("broker.hedgeCancelMs").update(ms)
             if stats is not None:
@@ -1612,7 +1612,7 @@ class Broker:
                 name, ok, out, ms = name2, True, out2, ms2
             else:
                 # both failed: side-account the backup, raise the primary's
-                # error so the outer taxonomy keys on the routed server
+                # error so the outer classification keys on the routed server
                 prim_err, hedge_err = (out, out2) if name == primary else (out2, out)
                 hedge_ms = ms2 if name == primary else ms
                 self._account_loser(target, False, hedge_err, hedge_ms, table, stats, batch=batch)

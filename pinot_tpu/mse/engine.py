@@ -971,9 +971,7 @@ class MultiStageEngine:
             out_spec = (P(), P())
 
         def run(fact_cols, fact_valid, dim_cols_list, dim_valids, params):
-            from pinot_tpu.parallel.engine import shard_map_compat
-
-            kern = shard_map_compat(
+            kern = jax.shard_map(
                 shard_kernel,
                 mesh=mesh,
                 in_specs=(
@@ -984,6 +982,7 @@ class MultiStageEngine:
                     _param_specs(params),
                 ),
                 out_specs=out_spec,
+                check_vma=False,
             )
             return kern(fact_cols, fact_valid, tuple(dim_cols_list), tuple(dim_valids), params)
 

@@ -3,25 +3,25 @@
 The XLA path (ops/segmented.py) streams each macro-batch several times —
 bitmap words unpack into a row-length bool mask, limbs stack into an [n, L]
 matrix (or re-slice per chunk), and the one-hot matmuls read it all back.
-Measured ceiling ~11 Grows/s with ~27 GB/s of HBM touched per effective
-pass (VERDICT r5: 2.09e9 rows/s end-to-end on config 2, ~3% of a v5e's
-~819 GB/s).  This module fuses the whole row pipeline into ONE Pallas grid
-over row tiles, so each input byte is read exactly once:
+This module fuses the whole row pipeline into ONE Pallas grid over row
+tiles, so each input byte is read exactly once:
 
   tile load:   dict codes in STORAGE dtype (int8 stays int8 in HBM),
                range-index prefix-bitmap WORDS ([T/32] uint32, unpacked
                in-register), optional predicate codes
-  tile math:   dictionary-code range predicate, 8-bit-limb extraction
+  tile math:   all in int32 with rows along LANES ((1, C) row vectors):
+               dictionary-code range predicate, 8-bit-limb extraction
                (two's-complement int32 / signed-magnitude int64 halves),
-               two-level one-hot (A, B) pair shared by every limb, one
-               [Hp, W] MXU matmul per limb column
+               group-major two-level one-hot (A [Hp, C], B [W, C]) pair
+               shared by every limb, one bf16 A.B^T MXU matmul per limb
+               column (operands are integers <= 255: exact)
   tile store:  int32 accumulation into a VMEM-resident [L, Hp, W] block,
                revisited across the tiles of one "super-segment"
 
 Exactness contract (matches segmented.fused_group_tables bit-for-bit on
-integer kinds): every limb is < 256 so each per-tile f32 dot accumulates
-< 255 * _TILE < 2^24 (exact); tiles add into int32 where one super-segment
-covers <= 2^23 rows so |sum| <= 255 * 2^23 < 2^31 (exact); the per-super
+integer kinds): every limb is < 256 so each per-chunk f32 dot accumulates
+< 255 * _TILE_CHUNK < 2^24 (exact); chunks add into int32 where one
+super-segment covers <= 2^23 rows so |sum| <= 255 * 2^23 < 2^31 (exact); the per-super
 int32 tables recombine OUTSIDE the kernel in f64 with the limb scales —
 TPU Pallas has no f64, and the recombine is table-sized anyway.  Float
 kinds (f32_sum/f32_sumsq) are NOT eligible: f32 accumulation over 2^23-row
@@ -50,23 +50,22 @@ from jax import lax
 
 from pinot_tpu.ops import segmented as _seg
 
-try:  # pallas ships with jax on this image; gate defensively anyway
-    from jax.experimental import pallas as pl
+from jax.experimental import pallas as pl
 
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover - environment without pallas
-    pl = None
-    _HAS_PALLAS = False
-
-# Rows per grid step.  Multiple of 32 so bitmap word tiles slice cleanly;
-# 4096 keeps the worst-case VMEM working set (A [T, 128] f32 + B [T, 64]
-# + one limb temp) a few MB under the 16MB budget.
-_TILE = 4096
-# Grid steps per int32 accumulator "super-segment": 2048 * 4096 = 2^23
-# rows, so a per-limb super sum is <= 255 * 2^23 < 2^31 - 1 (int32 exact).
-_SUPER_TILES = 2048
+# Rows per grid step.  XLA tiles a 1-D 32-bit HBM array by 1024 elements and
+# a kernel block must be whole tiles, so the narrowest packed operand — the
+# range-index bitmap, 32 rows per word — sets the floor: 1024 words = 2^15
+# rows.
+_TILE = 1 << 15
+# Rows per in-kernel chunk: the [Hp, chunk] / [W, chunk] one-hot working set
+# (Hp <= 128 sublanes) stays a few MB under the 16MB VMEM budget.
+_TILE_CHUNK = 4096
+# Grid steps per int32 accumulator "super-segment": 256 * 2^15 = 2^23 rows,
+# so a per-limb super sum is <= 255 * 2^23 < 2^31 - 1 (int32 exact).
+_SUPER_TILES = 256
 
 _W = _seg._W  # two-level decomposition lane width (code = hi * 64 + lo)
+_W_SHIFT = _W.bit_length() - 1  # _W is a power of two: hi = code >> shift
 
 # Pallas-eligible fused entry kinds: exact integer accumulation only (see
 # module docstring for why floats stay on the XLA path).
@@ -77,6 +76,9 @@ PALLAS_KINDS = ("count", "int_sum", "int64_sum")
 SPARSE_EMPTY_KEY = np.int64(np.iinfo(np.int64).max)
 
 
+SCAN_BACKENDS = ("pallas", "xla", "interpret")
+
+
 @functools.lru_cache(maxsize=None)
 def scan_backend() -> str:
     """Plan-time scan-backend selector, part of every plan-cache key.
@@ -84,14 +86,25 @@ def scan_backend() -> str:
     "pallas" on a real TPU backend, "xla" everywhere else.  Env override
     PINOT_TPU_SCAN_BACKEND in {pallas, xla, interpret}: "interpret" routes
     plans through this kernel under the Pallas interpreter (CPU tests, the
-    bench smoke gate).  lru_cached like accum_policy — tests that flip the
-    env var must scan_backend.cache_clear()."""
+    bench smoke gate).  A forced value that cannot be honoured — an unknown
+    name, or "pallas" where the backend is not a TPU (Mosaic compiles for
+    nothing else) — raises instead of degrading to another backend.
+    lru_cached like accum_policy — tests that flip the env var must
+    scan_backend.cache_clear()."""
     forced = os.environ.get("PINOT_TPU_SCAN_BACKEND", "").strip().lower()
-    if forced in ("pallas", "xla", "interpret"):
-        if forced in ("pallas", "interpret") and not _HAS_PALLAS:
-            return "xla"
-        return forced
-    return "pallas" if (_HAS_PALLAS and jax.default_backend() == "tpu") else "xla"
+    on_tpu = jax.default_backend() == "tpu"
+    if not forced:
+        return "pallas" if on_tpu else "xla"
+    if forced not in SCAN_BACKENDS:
+        raise ValueError(
+            f"PINOT_TPU_SCAN_BACKEND={forced!r}: expected one of {SCAN_BACKENDS}"
+        )
+    if forced == "pallas" and not on_tpu:
+        raise RuntimeError(
+            "PINOT_TPU_SCAN_BACKEND=pallas needs a TPU backend, found "
+            f"{jax.default_backend()!r}; use 'interpret' to run the kernel off the chip"
+        )
+    return forced
 
 
 def matmul_flops_per_row(num_groups: int, num_entries: int) -> float:
@@ -110,7 +123,7 @@ def pallas_supported(entries, num_groups: int) -> bool:
 
     Integer-exact kinds only, group table narrow enough for the one-hot
     matmul (the same _MATMUL_MAX_GROUPS ceiling as the XLA matmul path)."""
-    if not _HAS_PALLAS or num_groups < 1 or num_groups > _seg._MATMUL_MAX_GROUPS:
+    if num_groups < 1 or num_groups > _seg._MATMUL_MAX_GROUPS:
         return False
     for kind, values, _mask, _lp in entries:
         if kind not in PALLAS_KINDS:
@@ -124,23 +137,29 @@ def pallas_supported(entries, num_groups: int) -> bool:
     return True
 
 
-def _row_iota(shape_len: int):
-    # TPU Mosaic rejects 1D iota; build [n] from a 2D one
-    return lax.broadcasted_iota(jnp.int32, (shape_len, 1), 0).reshape(shape_len)
-
-
-def _lane_unpack(w, bits: int, rows: int):
-    """In-register lane unpack: [rows * bits // 32] uint32 words -> [rows]
-    uint32 lanes, lane l of word i covering row i * (32 // bits) + l.
+def _lane_unpack(w, bits: int):
+    """In-register lane unpack: a (1, rows * bits // 32) row of int32 words
+    -> a (1, rows) row of int32 lanes, lane l of word i covering row
+    i * (32 // bits) + l.
 
     The shared primitive behind BOTH packed operand kinds: range-index
     bitmap words are the bits=1 case (one bool per lane), bit-packed
-    forward indexes (segment/packing.py) the bits=4/8/16 case.  Pure
-    shift/mask on the VPU — the packed word tile is the only HBM read and
-    the widened lanes never leave registers/VMEM."""
+    forward indexes (segment/packing.py) the bits=4/8/16 case.  Each word is
+    repeated along the lane axis and shifted against a lane iota, so the
+    array stays 2-D throughout (Mosaic refuses the rank-changing reshape a
+    [words, lanes] -> [rows] unpack needs) — the packed word tile is the
+    only HBM read and the widened lanes never leave registers/VMEM."""
     f = 32 // bits
-    shifts = lax.broadcasted_iota(jnp.uint32, (rows // f, f), 1) * jnp.uint32(bits)
-    return ((w[:, None] >> shifts) & jnp.uint32((1 << bits) - 1)).reshape(rows)
+    x = jnp.repeat(w, f, axis=1)
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = (lane & np.int32(f - 1)) * np.int32(bits)
+    return lax.shift_right_logical(x, shift) & np.int32((1 << bits) - 1)
+
+
+def _as_i32_words(words):
+    """uint32 packed words -> the same bits as int32: the TPU kernel does
+    all of its integer math in int32 (Mosaic has no uint32 -> f32 cast)."""
+    return lax.bitcast_convert_type(words.astype(jnp.uint32), jnp.int32)
 
 
 def fused_group_tables_pallas(
@@ -178,12 +197,30 @@ def fused_group_tables_pallas(
     if not pallas_supported(entries, num_groups):
         raise ValueError("entries not eligible for the Pallas fused scan")
 
-    T = _TILE
+    T, C = _TILE, _TILE_CHUNK
     n_tiles = max(1, -(-n // T))
     n_super = -(-n_tiles // _SUPER_TILES)
     H = -(-num_groups // _W)
     Hp = -(-H // 8) * 8  # pad the sublane dim for TPU tiling
 
+    # TPU Pallas is a 32-bit world and the package runs with jax_enable_x64
+    # on: every scalar the kernel or an index map touches is an explicit
+    # np.int32 (a Python int would trace as a weak int64 that Mosaic cannot
+    # convert), and the block index maps stay in int32 end to end.
+    def _tile_map(i):
+        return (i,)
+
+    def _word_tile_map(i):
+        return (i, np.int32(0))
+
+    def _super_map(i):
+        z = np.int32(0)
+        return (lax.div(i, np.int32(_SUPER_TILES)), z, z, z)
+
+    # inputs[k] covers rows_per[k] rows per element: 1 for a row-length
+    # operand, 32 // bits for packed words
+    inputs: List[Any] = []
+    rows_per: List[int] = []
     key_bits = None
     if codes_packed is not None:
         kw, key_bits = codes_packed
@@ -191,40 +228,42 @@ def fused_group_tables_pallas(
         key_factor = 32 // key_bits
         if n % key_factor or int(kw.shape[0]) != n // key_factor:
             raise ValueError("codes_packed rows must be lane-aligned with codes")
-        inputs: List[Any] = [kw]
-        in_specs: List[Any] = [pl.BlockSpec((T // key_factor,), lambda i: (i,))]
+        inputs.append(_as_i32_words(kw))
+        rows_per.append(key_factor)
     else:
-        inputs = [codes]
-        in_specs = [pl.BlockSpec((T,), lambda i: (i,))]
+        inputs.append(codes)
+        rows_per.append(1)
     ix_of: Dict[int, int] = {}
 
     def _operand(arr) -> int:
         k = id(arr)
         if k not in ix_of:
-            inputs.append(arr)
-            in_specs.append(pl.BlockSpec((T,), lambda i: (i,)))
+            # bool refs are not a VMEM type: masks ride as int8 (the cast
+            # fuses into the XLA producer of the mask)
+            inputs.append(arr.astype(jnp.int8) if arr.dtype == jnp.bool_ else arr)
+            rows_per.append(1)
             ix_of[k] = len(inputs) - 1
         return ix_of[k]
 
     words_ix = None
     if mask_words is not None:
-        inputs.append(mask_words)
-        in_specs.append(pl.BlockSpec((T // 32,), lambda i: (i,)))
+        inputs.append(_as_i32_words(mask_words))
+        rows_per.append(32)
         words_ix = len(inputs) - 1
     pred_plan = None
     if code_pred is not None:
         pc, plo, phi = code_pred
-        pred_plan = (_operand(pc), int(plo), int(phi))
+        pred_plan = (_operand(pc), np.int32(plo), np.int32(phi))
 
     halves_of: Dict[int, Tuple[Any, Any]] = {}
 
     def _halves(arr):
-        """uint32 (lo, hi) halves of an int64 column, split OUTSIDE the
+        """int32 (lo, hi) halves of an int64 column, split OUTSIDE the
         kernel — TPU Pallas has no 64-bit row ops; the bitcast is a cheap
         elementwise pass and the kernel reads the halves once."""
         k = id(arr)
         if k not in halves_of:
-            h = lax.bitcast_convert_type(arr, jnp.uint32)
+            h = lax.bitcast_convert_type(arr, jnp.int32)
             lo_ix = _seg._i64_low_half_index()
             halves_of[k] = (h[..., lo_ix], h[..., 1 - lo_ix])
         return halves_of[k]
@@ -255,91 +294,126 @@ def fused_group_tables_pallas(
     L = col
 
     if n % T:
+        # padding carries mask=False / zero words, so it contributes nothing
         pad = n_tiles * T - n
-        padded = []
-        for ix, a in enumerate(inputs):
-            # packed operands pad by lanes-per-word: bitmap words carry 32
-            # rows each, key words 32 // key_bits
-            if ix == words_ix:
-                w = pad // 32
-            elif ix == 0 and key_bits is not None:
-                w = pad * key_bits // 32
-            else:
-                w = pad
-            padded.append(jnp.pad(a, (0, w)))
-        inputs = padded
+        inputs = [jnp.pad(a, (0, pad // f)) for a, f in zip(inputs, rows_per)]
+    # packed words ride as [words / 128, 128]: for a 32-bit array that is the
+    # same bytes as the 1-D HBM tiling, and it makes the words of chunk c
+    # whole sublane rows (a 1-D block can only be sliced by 1024s)
+    inputs = [a if f == 1 else a.reshape(-1, 128) for a, f in zip(inputs, rows_per)]
+    in_specs = [
+        pl.BlockSpec((T,), _tile_map)
+        if f == 1
+        else pl.BlockSpec((T // f // 128, 128), _word_tile_map)
+        for f in rows_per
+    ]
+
+    i32 = jnp.int32
+    zero, one, byte = np.int32(0), np.int32(1), np.int32(0xFF)
+    super_tiles = np.int32(_SUPER_TILES)
 
     def scan_kernel(*refs):
         out_ref = refs[-1]
         i = pl.program_id(0)
 
-        @pl.when(i % _SUPER_TILES == 0)
+        @pl.when(lax.rem(i, super_tiles) == zero)
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        if key_bits is not None:
-            # super-tile key read: T rows arrive as T * key_bits / 32 words
-            ki = _lane_unpack(refs[0][...], key_bits, T).astype(jnp.int32)
-        else:
-            ki = refs[0][...].astype(jnp.int32)
+        def body(c, carry):
+            _scan_chunk(refs, out_ref, c)
+            return carry
+
+        # jnp (not np/Python) bounds: a concrete trip count would lower to a
+        # scan whose counter is int64 under x64
+        lax.fori_loop(jnp.int32(0), jnp.int32(T // C), body, jnp.int32(0))
+
+    def _scan_chunk(refs, out_ref, c):
+        """Chunk c of the tile: the tile is as wide as the widest packed
+        operand's HBM tiling demands, the chunk as narrow as the [Hp, C]
+        one-hot working set needs to stay in VMEM."""
+
+        def row(ix):
+            # a [C] slice of the block as a (1, C) int32 row: ROWS RUN ALONG
+            # LANES, so per-row values broadcast over the one-hot sublanes
+            # for free and the contraction is the MXU-native A.B^T form
+            start = pl.multiple_of(c * np.int32(C), C)
+            return refs[ix][pl.ds(start, C)].astype(i32)[None, :]
+
+        def unpacked_row(ix, bits: int):
+            # the chunk's C * bits / 32 packed words are whole sublane rows
+            # of the [.., 128] word block; laid end to end they are the
+            # (1, words) row _lane_unpack widens to (1, C)
+            r = C * bits // 32 // 128
+            w = refs[ix][pl.ds(c * np.int32(r), r), :]
+            return _lane_unpack(
+                jnp.concatenate([w[k:k + 1, :] for k in range(r)], axis=1), bits
+            )
+
+        ki = unpacked_row(0, key_bits) if key_bits is not None else row(0)
         base = None
         if words_ix is not None:
-            base = _lane_unpack(refs[words_ix][...], 1, T) != jnp.uint32(0)
+            base = unpacked_row(words_ix, 1) != zero
         if pred_plan is not None:
             p_ix, plo, phi = pred_plan
-            pc = refs[p_ix][...].astype(jnp.int32)
+            pc = row(p_ix)
             pm = (pc >= plo) & (pc < phi)
             base = pm if base is None else base & pm
 
-        # one (A, B) one-hot pair shared by EVERY limb matmul of the tile —
+        # one (A, B) one-hot pair shared by EVERY limb matmul of the chunk —
         # the same sharing that makes the fused XLA scan 3x faster than
-        # per-table scans, now also sharing the single HBM read
-        A = (lax.broadcasted_iota(jnp.int32, (T, Hp), 1) == (ki // _W)[:, None]).astype(
-            jnp.float32
-        )
-        B = (lax.broadcasted_iota(jnp.int32, (T, _W), 1) == (ki % _W)[:, None]).astype(
-            jnp.float32
-        )
+        # per-table scans, now also sharing the single HBM read.  Both are
+        # group-major ([Hp, C] / [W, C]); codes are >= 0 so shift/mask is
+        # the hi/lo split.
+        a_hot = lax.broadcasted_iota(i32, (Hp, C), 0) == (ki >> np.int32(_W_SHIFT))
+        b_hot = lax.broadcasted_iota(i32, (_W, C), 0) == (ki & np.int32(_W - 1))
+        # the per-row limb weight rides whichever one-hot is narrower; every
+        # operand value is an integer of magnitude <= 255, exact in bf16
+        weigh_a = Hp <= _W
+        hot, other = (a_hot, b_hot) if weigh_a else (b_hot, a_hot)
+        fixed = other.astype(jnp.float32).astype(jnp.bfloat16)
 
         def accum(col_ix, wcol):
+            weighed = jnp.where(hot, wcol.astype(jnp.float32), np.float32(0)).astype(
+                jnp.bfloat16
+            )
+            lhs, rhs = (weighed, fixed) if weigh_a else (fixed, weighed)
             s = lax.dot_general(
-                A * wcol[:, None], B, (((0,), (0,)), ((), ())),
+                lhs, rhs, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            out_ref[0, col_ix] = out_ref[0, col_ix] + s.astype(jnp.int32)
+            out_ref[0, col_ix] = out_ref[0, col_ix] + s.astype(i32)
 
         for kind, m_ix, v_ixs, lp, col0 in plans:
-            m = refs[m_ix][...]
+            m = row(m_ix) != zero
             if base is not None:
                 m = m & base
-            mf = m.astype(jnp.float32)
             if kind == "count":
-                accum(col0, mf)
+                accum(col0, jnp.where(m, one, zero))
             elif kind == "int_sum":
                 n_limbs, signed = lp
-                vm = jnp.where(m, refs[v_ixs[0]][...].astype(jnp.int32), 0)
-                u = vm.astype(jnp.uint32)
+                vm = jnp.where(m, row(v_ixs[0]), zero)
                 for k in range(n_limbs):
-                    accum(col0 + k, ((u >> jnp.uint32(8 * k)) & jnp.uint32(0xFF)).astype(jnp.float32))
+                    # arithmetic shift then mask == the two's-complement byte
+                    accum(col0 + k, (vm >> np.int32(8 * k)) & byte)
                 if signed:
-                    accum(col0 + n_limbs, (vm < 0).astype(jnp.float32))
-            else:  # int64_sum
-                lo_h = refs[v_ixs[0]][...]
-                hi_h = refs[v_ixs[1]][...]
-                neg = hi_h >= jnp.uint32(1 << 31)
-                alo = jnp.where(neg, ~lo_h + jnp.uint32(1), lo_h)
-                ahi = jnp.where(neg, ~hi_h + (lo_h == jnp.uint32(0)).astype(jnp.uint32), hi_h)
-                sgn = jnp.where(neg, -1, 1).astype(jnp.float32) * mf
+                    accum(col0 + n_limbs, jnp.where(vm < zero, one, zero))
+            else:  # int64_sum: signed-magnitude limbs of the (lo, hi) halves
+                lo_h = row(v_ixs[0])
+                hi_h = row(v_ixs[1])
+                neg = hi_h < zero
+                alo = jnp.where(neg, -lo_h, lo_h)  # wrapping: ~lo + 1
+                ahi = jnp.where(neg, ~hi_h + jnp.where(lo_h == zero, one, zero), hi_h)
+                sgn = jnp.where(m, jnp.where(neg, np.int32(-1), one), zero)
                 for k in range(lp):
                     h = alo if k < 4 else ahi
-                    limb = ((h >> jnp.uint32(8 * (k % 4))) & jnp.uint32(0xFF)).astype(jnp.float32)
-                    accum(col0 + k, limb * sgn)
+                    accum(col0 + k, ((h >> np.int32(8 * (k % 4))) & byte) * sgn)
 
     out = pl.pallas_call(
         scan_kernel,
         grid=(n_tiles,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, L, Hp, _W), lambda i: (i // _SUPER_TILES, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, L, Hp, _W), _super_map),
         out_shape=jax.ShapeDtypeStruct((n_super, L, Hp, _W), jnp.int32),
         interpret=bool(interpret),
     )(*inputs)
@@ -366,9 +440,10 @@ def merge_sparse_tables(
     num_slots: int,
     field_ops: Sequence[Dict[str, str]],
     order_spec: Optional[Tuple[int, str, bool]] = None,
+    may_trim: bool = True,
 ):
-    """Merge stacked fixed-slot sparse group tables ON DEVICE (VERDICT
-    weak #5): replaces the host numpy fold of sparse_tables_to_result for
+    """Merge stacked fixed-slot sparse group tables ON DEVICE: replaces the
+    host numpy fold of sparse_tables_to_result for
     the macro-batched path, so cross-launch combining is part of the graph
     and only FINAL [num_slots] tables ever cross PCIe.
 
@@ -381,7 +456,11 @@ def merge_sparse_tables(
     device analog of executor._order_trim_select: rank by the merged order
     value (empty/NaN groups last), tie-break by packed key, keep the top
     num_slots, and emit survivors in ascending key order so downstream
-    decode matches the host merge byte-for-byte.
+    decode matches the host merge byte-for-byte.  may_trim=False is the
+    caller's static promise that the distinct keys always fit num_slots
+    (slots cover the whole key space): the merged groups already sit in
+    ascending key order, so the ranking and compaction sorts — 64-bit
+    multi-key sorts, minutes of TPU compile each — are skipped.
 
     The merge is sort-based over the SAME fixed-slot contract as the
     per-launch kernel (sort keys -> segment starts -> running group id ->
@@ -421,6 +500,8 @@ def merge_sparse_tables(
         .at[gslot]
         .set(jnp.where(is_start, skey, SPARSE_EMPTY_KEY))
     )
+    if not may_trim:
+        return gkey[:num_slots], [{f: t[:num_slots] for f, t in q.items()} for q in merged]
     phantom = gkey == SPARSE_EMPTY_KEY  # slots past the last real group
     if order_spec is None:
         # lowest packed keys win — the deterministic numGroupsLimit trim

@@ -433,16 +433,23 @@ def fused_group_tables(
     per-chunk MXU dot accumulates < 2^24 in f32 (exact); cross-chunk
     accumulation is f64.  f32_sum/f32_sumsq share the scan by promoting the
     one-hot matrices to f32 (int limbs stay exact there too)."""
+    from pinot_tpu.utils.metrics import METRICS
+
     if backend in ("pallas", "interpret"):
         from pinot_tpu.ops import pallas_scan  # lazy: keeps import DAG flat
 
         if pallas_scan.pallas_supported(entries, num_groups):
+            # trace-time record of which kernel THIS plan's dense scan got:
+            # the plan-time backend tag alone cannot say whether the entry
+            # set was eligible
+            METRICS.counter(f"scan.traced.{backend}").inc()
             return pallas_scan.fused_group_tables_pallas(
                 entries, codes, num_groups,
                 mask_words=mask_words,
                 codes_packed=codes_packed,
                 interpret=(backend == "interpret"),
             )
+    METRICS.counter("scan.traced.xla").inc()
     if mask_words is not None:
         # declined the Pallas path (wide table, float kinds, CPU policy):
         # fall back to one explicit unpack shared by every entry

@@ -61,19 +61,6 @@ from pinot_tpu.utils import perf
 from pinot_tpu.utils.metrics import METRICS
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: new jax exposes it top-level with
-    `check_vma`; older releases (<= 0.4.x, this image) only have
-    jax.experimental.shard_map with the `check_rep` spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-
-
 def _psum_field(name: str, x, axes):
     """Combine one partial field across the data axes, innermost axis
     (ICI) first — on the 2-D mesh the REPLICA_AXIS step is the only one
@@ -912,7 +899,8 @@ class DistributedEngine:
                 key = planner_mod.packed_key64(cols, group_dims, view)
                 inputs = _agg_inputs(cols, params, tmask)
                 return planner_mod.sparse_grouped_tables(
-                    aggs, inputs, tmask, key, num_slots, order_spec
+                    aggs, inputs, tmask, key, num_slots, order_spec,
+                    num_groups=num_groups,
                 )
 
             out_specs = P(self.axis)
@@ -949,7 +937,8 @@ class DistributedEngine:
                         for i in range(len(field_ops))
                     ]
                     return ops.merge_sparse_tables(
-                        uniq, parts, num_slots, field_ops, order_spec=morder
+                        uniq, parts, num_slots, field_ops, order_spec=morder,
+                        may_trim=num_slots < num_groups,
                     )
 
                 sparse_merge_fn = (
@@ -1002,7 +991,7 @@ class DistributedEngine:
         row_sharded = frozenset(fc.row_sharded_params)
 
         def run(cols, params):
-            kern = shard_map_compat(
+            kern = jax.shard_map(
                 shard_kernel,
                 mesh=mesh,
                 in_specs=(
@@ -1010,6 +999,7 @@ class DistributedEngine:
                     {k: (P(axis, None) if k in row_sharded else P()) for k in params},
                 ),
                 out_specs=out_specs,
+                check_vma=False,
             )
             return kern(cols, params)
 
@@ -1129,7 +1119,7 @@ class DistributedEngine:
             trace = Trace(False)
         # Launches are PIPELINED up to pipeline_depth in flight (default 2 =
         # double-buffering): batch k+1 dispatches while batch k computes,
-        # hiding the host-side dispatch/relay gap between launches.  Each
+        # hiding the host-side dispatch gap between launches.  Each
         # in-flight execution holds a capture copy of its batch inputs, so
         # resident HBM is bounded by depth * batch bytes (depth=1 restores
         # the old fully-serialized loop).  The fence is a device_get of the

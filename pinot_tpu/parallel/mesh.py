@@ -89,9 +89,16 @@ def make_mesh2d(
             f"mesh shape ({num_replicas} replicas x {num_shards} shards) "
             f"needs {num_replicas * num_shards} devices, have {n}"
         )
-    if num_devices is None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh((num_replicas, num_shards), DATA_AXES)
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType, Mesh
+
+    if num_devices is None:
+        # Auto axes, like Mesh(): jax.make_mesh defaults to Explicit axes,
+        # which put shardings into array types and refuse plain jnp slices
+        # of a sharded table (the in-graph sparse merge)
+        return jax.make_mesh(
+            (num_replicas, num_shards), DATA_AXES,
+            axis_types=(AxisType.Auto, AxisType.Auto),
+        )
 
     arr = np.asarray(devs[:n]).reshape(num_replicas, num_shards)
     return Mesh(arr, DATA_AXES)

@@ -197,7 +197,8 @@ def _sig_value(v):
 
 
 def _segment_signature(
-    segment: ImmutableSegment, needed: List[str], sketch_cols: frozenset = frozenset()
+    segment: ImmutableSegment, needed: List[str], sketch_cols: frozenset = frozenset(),
+    group_cols: frozenset = frozenset(),
 ) -> Tuple:
     sig = [segment.num_docs, segment.valid_docs is not None]
     for name in sorted(needed):
@@ -208,10 +209,13 @@ def _segment_signature(
         if getattr(c, "mv_lengths", None) is not None:
             arr = c.codes if c.codes is not None else c.values
             mv_width = int(arr.shape[1]) if arr is not None and arr.ndim == 2 else None
-        # Raw columns include min/max: the kernel bakes rawint group-dim
-        # base/cardinality in statically, so they are part of the cache key.
+        # Raw GROUP BY columns include min/max: the kernel bakes rawint
+        # group-dim base/cardinality in statically, so they are part of the
+        # cache key.  A raw column that is only aggregated or filtered bakes
+        # nothing but its limb plan (column_limb_sig below) — keying it on
+        # min/max would compile one kernel per segment.
         raw_range = None
-        if not c.has_dictionary and c.data_type.is_numeric:
+        if name in group_cols and not c.has_dictionary and c.data_type.is_numeric:
             raw_range = (
                 (_sig_value(c.stats.min_value), _sig_value(c.stats.max_value)) if c.stats.num_docs else (0, 0)
             )
@@ -770,7 +774,8 @@ def packed_key64(cols, group_dims, segment) -> jnp.ndarray:
     return key
 
 
-def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=None):
+def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=None,
+                          num_groups: Optional[int] = None):
     """Device-side high-cardinality group-by: sort + segment-scatter into
     FIXED-size tables (the IndexedTable analog with numGroupsLimit trim
     built into the kernel).
@@ -790,12 +795,25 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
     accumulates long sums in double), min/max float64.  This path is
     scatter/HBM-bound, not MXU-bound, so f64 costs little on TPU here.
 
+    num_groups, when given, is the static size of the key space (every key
+    is < num_groups).  It buys two things the TPU cares about — a 64-bit or
+    multi-key row-length sort is minutes of compile there and emulated at
+    run time: a key space under 2^31 sorts as int32, and a key space that
+    fits the slots (num_slots >= num_groups) can never trim, so the ORDER
+    BY-aware ranking sort is skipped.
+
     Returns (uniq_keys[num_slots] int64 with SPARSE_EMPTY_KEY padding,
              [{field: table[num_slots]}] per agg)."""
     from jax import lax
 
     n = tmask.shape[0]
-    k64 = jnp.where(tmask, key, SPARSE_EMPTY_KEY)
+    if num_groups is not None and num_slots >= num_groups:
+        order_spec = None
+    i32_max = np.iinfo(np.int32).max  # the int32 twin of SPARSE_EMPTY_KEY
+    if num_groups is not None and num_groups < i32_max:
+        krow = jnp.where(tmask, key.astype(jnp.int32), i32_max)
+    else:
+        krow = jnp.where(tmask, key, SPARSE_EMPTY_KEY)
     iota = jnp.arange(n, dtype=jnp.int32)
     if order_spec is not None and order_spec[1] in ("min", "max"):
         # min/max order value rides the row sort as a secondary key: after
@@ -805,10 +823,10 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
         ovr = ov_raw.astype(jnp.float64)
         ovr = ovr if omode == "min" else -ovr
         ovr = jnp.where(om, ovr, jnp.inf)
-        skey, sov, perm = lax.sort((k64, ovr, iota), num_keys=2)
+        skey, sov, perm = lax.sort((krow, ovr, iota), num_keys=2)
     else:
         sov = None
-        skey, perm = lax.sort((k64, iota), num_keys=1)
+        skey, perm = lax.sort((krow, iota), num_keys=1)
     smask = tmask[perm]
     prev = jnp.concatenate([jnp.full((1,), -1, skey.dtype), skey[:-1]])
     is_start = smask & (skey != prev)
@@ -887,7 +905,7 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
     uniq = (
         jnp.full((num_slots + 1,), SPARSE_EMPTY_KEY, dtype=jnp.int64)
         .at[jnp.where(is_start, slot, num_slots)]
-        .set(skey)
+        .set(skey.astype(jnp.int64))
     )
     partials = []
     for fn, (vals, mask) in zip(aggs, inputs):
@@ -941,7 +959,10 @@ def plan_segment(ctx: QueryContext, segment: ImmutableSegment) -> SegmentPlan:
     needed = _needed_columns(ctx, segment)
     key = (
         ctx.shape_fingerprint(column_info_from(segment)),
-        _segment_signature(segment, needed, sketch_bound_columns(ctx) | const_bound_columns(ctx)),
+        _segment_signature(
+            segment, needed, sketch_bound_columns(ctx) | const_bound_columns(ctx),
+            group_cols=frozenset(c for g in ctx.group_by for c in g.columns()),
+        ),
         ops.scan_backend(),  # pallas/xla plans trace different kernels
     )
     cached = _PLAN_CACHE.get(key)
@@ -1200,7 +1221,8 @@ def _build_plan(
             def kernel(cols, params):
                 tmask, _ = filter_fn(cols, params)
                 key, t_f, inputs = _mv_explode(cols, params, tmask, jnp.int64)
-                return sparse_grouped_tables(aggs, inputs, t_f, key, num_slots, order_spec)
+                return sparse_grouped_tables(aggs, inputs, t_f, key, num_slots, order_spec,
+                                             num_groups=num_groups)
 
         else:
 
@@ -1208,7 +1230,8 @@ def _build_plan(
                 tmask, _ = filter_fn(cols, params)
                 key = packed_key64(cols, group_dims, segment)
                 inputs = _agg_inputs(cols, params, tmask)
-                return sparse_grouped_tables(aggs, inputs, tmask, key, num_slots, order_spec)
+                return sparse_grouped_tables(aggs, inputs, tmask, key, num_slots, order_spec,
+                                             num_groups=num_groups)
 
     elif kind == "selection":
 

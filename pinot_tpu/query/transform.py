@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 import numpy as np
@@ -62,6 +63,10 @@ _UNARY = {
 }
 
 
+# see column_values: the padded lane view a materialized unpack may hold
+_DECODE_BARRIER_MAX_BYTES = 1 << 30
+
+
 def column_values(name: str, segment: ImmutableSegment, cols: Dict) -> EvalResult:
     """Numeric values of a column from the device pytree (dictionary gather
     for dict-encoded numerics — the ProjectionOperator/DataFetcher analog)."""
@@ -75,7 +80,21 @@ def column_values(name: str, segment: ImmutableSegment, cols: Dict) -> EvalResul
     if "values" in entry:
         vals = entry["values"]
     else:
-        vals = entry["dict"][entry["codes"].astype(jnp.int32)]
+        codes = entry["codes"].astype(jnp.int32)
+        bits = getattr(c, "code_bits", None)
+        if "codes_packed" in entry and bits and codes.ndim == 1:
+            # `codes` is the trace-level lane unpack of the packed words.  A
+            # 1-D gather whose indices are that unpack FUSED IN compiles
+            # pathologically on XLA's TPU backend (AOT for a described v5e,
+            # 1.5M rows: 44 s at 4-bit lanes, 104 s at 8, 241 s at 16; ~1 s
+            # behind a barrier).  Materializing the unpack has its own
+            # price: XLA holds the [words, lanes] view tile-padded to 128
+            # lanes, 512 B per word — fine for a segment, 16 GB at 2^27 rows
+            # of 8-bit lanes — so only segment-sized decodes take the barrier.
+            padded_bytes = codes.shape[0] * bits // 32 * 512
+            if padded_bytes <= _DECODE_BARRIER_MAX_BYTES:
+                codes = jax.lax.optimization_barrier(codes)
+        vals = entry["dict"][codes]
     nulls = entry.get("nulls")
     return vals, nulls
 

@@ -222,17 +222,14 @@ def peak_hbm_bytes_per_sec() -> float:
                 return v
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return _CPU_PEAK_HBM_BPS
+    kind = jax.devices()[0].device_kind.lower()
     if "tpu" in kind:
         for marker, bps in _PEAK_HBM_BPS:
             if marker in kind:
                 return bps
-        return _PEAK_HBM_BPS[0][1]
+        raise ValueError(f"no peak HBM bandwidth on record for TPU kind {kind!r}")
     return _CPU_PEAK_HBM_BPS
 
 

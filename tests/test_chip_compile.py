@@ -1,0 +1,230 @@
+"""AOT compiles of the chip's own program, for a DESCRIBED TPU v5e.
+
+Tier-1 runs the Pallas scan in interpret mode on the CPU, which cannot show
+what the chip's compiler refuses (tiling, VMEM, 64-bit scalars in a kernel,
+unsupported casts and shape casts).  The TPU compiler is installed in the
+sandbox and compiles for a chip that is described, not attached — so these
+cases compile, at the widths the served queries produce and with
+jax_enable_x64 ON as the package runs, every Pallas variant the planner can
+select, the device-side sparse merge, and one DistributedEngine dense
+group-by step under the chip's arithmetic policy.  Nothing executes: a pass
+here is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at import,
+in a skipif or in parametrize arguments): only the worker that runs this
+file loads the TPU library.  No child process; the persistent compile cache
+is off around the compiles (an entry written for a described chip cannot be
+read back without one).
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+from pinot_tpu import ops
+from pinot_tpu.ops import pallas_scan, segmented
+
+ROWS = 1 << 23  # one int32 super-segment: 256 tiles of 2^15 rows
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe means skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _case(kinds, groups, words=False, packed=None, pred=False,
+          code_dt=jnp.int32, val_dt=jnp.int32, limbs=(4, True)):
+    return dict(kinds=kinds, groups=groups, words=words, packed=packed, pred=pred,
+                code_dt=code_dt, val_dt=val_dt, limbs=limbs)
+
+
+# G = 2406 (lo_orderdate) and 11 (lo_discount) are the served queries' tables;
+# 8192 is the widest table the planner hands to the kernel
+SCAN_CASES = {
+    "count_g11": _case(["count"], 11),
+    "count_g11_words": _case(["count"], 11, words=True),
+    "int_sum_g11": _case(["int_sum"], 11),
+    "int_sum_g11_words": _case(["int_sum"], 11, words=True),
+    "int64_sum_g2406": _case(["int64_sum"], 2406),
+    "int64_sum_g2406_words": _case(["int64_sum"], 2406, words=True),
+    "packed4": _case(["count"], 11, packed=4),
+    "packed8": _case(["count"], 50, packed=8),
+    "packed16": _case(["count"], 2406, packed=16),
+    "code_pred": _case(["count"], 11, pred=True),
+    "headline_a": _case(["count", "int64_sum"], 2406, words=True, packed=16),
+    "agg_bound_c": _case(["count", "int_sum", "int64_sum"], 2406, packed=16),
+    "widest_table": _case(["count", "int64_sum"], 8192, words=True),
+    "storage_dtypes": _case(["count", "int_sum"], 50, code_dt=jnp.uint8, val_dt=jnp.int16,
+                            limbs=(2, True)),
+    "unsigned_limbs": _case(["int_sum"], 2406, code_dt=jnp.uint16, val_dt=jnp.uint8,
+                            limbs=(1, False)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SCAN_CASES))
+def test_pallas_scan_compiles_for_v5e(one_chip, no_compile_cache, name):
+    case = SCAN_CASES[name]
+    assert jax.config.jax_enable_x64  # as the package runs
+
+    def shape(n, dt):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+
+    packed = case["packed"]
+
+    def scan(codes, mask, v32, v64, mask_words, key_words):
+        entries = []
+        for k in case["kinds"]:
+            if k == "count":
+                entries.append(("count", None, mask, None))
+            elif k == "int_sum":
+                entries.append(("int_sum", v32, mask, case["limbs"]))
+            else:
+                entries.append(("int64_sum", v64, mask, 8))
+        assert pallas_scan.pallas_supported(entries, case["groups"])
+        return pallas_scan.fused_group_tables_pallas(
+            entries, codes, case["groups"],
+            mask_words=mask_words if case["words"] else None,
+            codes_packed=(key_words, packed) if packed else None,
+            code_pred=(codes, 3, 9) if case["pred"] else None,
+        )
+
+    compiled = jax.jit(scan).lower(
+        shape(ROWS, case["code_dt"]), shape(ROWS, jnp.bool_), shape(ROWS, case["val_dt"]),
+        shape(ROWS, jnp.int64), shape(ROWS // 32, jnp.uint32),
+        shape(ROWS * (packed or 32) // 32, jnp.uint32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel is in the program
+
+
+# the smoke's sparse query (d) tracks its whole key space (no trim).  The
+# default numGroupsLimit path ranks and trims through an (f64, int64) two-key
+# sort that XLA's TPU backend compiles for ~265 s at this size — marked slow
+# so tier-1 stays well inside its time limit; run it with `-m slow`.
+@pytest.mark.parametrize(
+    "slots,tables,may_trim",
+    [
+        (8192, 4, False),
+        pytest.param(8192, 4, True, marks=pytest.mark.slow),
+        (1_323_300, 1, False),
+    ],
+    ids=["4x8192", "4x8192_trim", "smoke_d_1chip"],
+)
+def test_merge_sparse_tables_compiles_for_v5e(one_chip, no_compile_cache, slots, tables, may_trim):
+    m = slots * tables
+
+    def shape(dt):
+        return jax.ShapeDtypeStruct((m,), dt, sharding=one_chip)
+
+    def merge(uniq, sums, counts):
+        return pallas_scan.merge_sparse_tables(
+            uniq, [{"sum": sums, "count": counts}], slots,
+            [{"sum": "add", "count": "add"}], order_spec=(0, "sum", False),
+            may_trim=may_trim,
+        )
+
+    jax.jit(merge).lower(shape(jnp.int64), shape(jnp.float64), shape(jnp.int64)).compile()
+
+
+def test_sparse_group_tables_compile_for_v5e(one_chip, no_compile_cache):
+    """The per-segment sparse kernel of the smoke's query (d): one 1.5M-row
+    segment, 1,323,300 possible groups, every group tracked."""
+    from pinot_tpu.query import planner
+    from pinot_tpu.query.functions import get_agg_function
+
+    n, groups = 1_500_000, 1_323_300
+    sum_fn = get_agg_function("sum")
+
+    def shape(dt):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+
+    def kernel(vals, mask, key):
+        return planner.sparse_grouped_tables(
+            [sum_fn], [(vals, mask)], mask, key, groups, (0, "sum", False), num_groups=groups
+        )
+
+    jax.jit(kernel).lower(shape(jnp.int64), shape(jnp.bool_), shape(jnp.int64)).compile()
+
+
+def test_engine_dense_groupby_step_compiles_for_v5e(topo, no_compile_cache, monkeypatch):
+    """One single-device DistributedEngine step of the headline query — word-
+    fused filter, 16-bit packed key, int64 limbs — traced as the chip traces
+    it: scan_backend()="pallas", accum_policy()="chunked32" (both ask
+    jax.default_backend(), which says cpu here, so the test steers them)."""
+    from pinot_tpu.parallel import mesh as mesh_mod
+    from pinot_tpu.parallel.engine import DistributedEngine
+    from pinot_tpu.parallel.stacked import StackedTable
+    from pinot_tpu.spi.config import IndexingConfig, TableConfig
+    from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
+    from pinot_tpu.sql.parser import parse_query
+
+    monkeypatch.setattr(ops, "scan_backend", lambda: "pallas")
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+
+    rng = np.random.default_rng(0)
+    schema = Schema(
+        "lineorder",
+        [
+            FieldSpec("lo_orderdate", DataType.INT),
+            FieldSpec("lo_quantity", DataType.INT),
+            FieldSpec("lo_revenue", DataType.LONG, role=FieldRole.METRIC),
+        ],
+    )
+    data = {
+        "lo_orderdate": (19920101 + rng.integers(0, 2406, ROWS)).astype(np.int32),
+        "lo_quantity": rng.integers(1, 51, ROWS).astype(np.int32),
+        "lo_revenue": rng.integers(100, 1_000_000, ROWS).astype(np.int64),
+    }
+    cfg = TableConfig("lineorder", indexing=IndexingConfig(range_index_columns=["lo_quantity"]))
+    stacked = StackedTable.build(schema, data, 1, table_config=cfg)
+    ctx = parse_query(
+        "SELECT lo_orderdate, SUM(lo_revenue) FROM lineorder "
+        "WHERE lo_quantity < 25 GROUP BY lo_orderdate LIMIT 2500"
+    )
+
+    # concrete inputs staged on a CPU device give the step's pytree, shapes
+    # and partition specs; the described chip cannot hold an array
+    host = DistributedEngine(mesh=mesh_mod.default_mesh(num_devices=1), hbm_cache_bytes=0)
+    host.register_table("lineorder", stacked)
+    [(cols, params)] = host.device_batches(host._plan(ctx, stacked), stacked)
+
+    chip_mesh = Mesh(np.asarray(topo.devices[:1]), (mesh_mod.SEG_AXIS,))
+    chip = DistributedEngine(mesh=chip_mesh, hbm_cache_bytes=0)
+    chip.register_table("lineorder", stacked)
+    plan = chip._plan(ctx, stacked)
+    assert plan.kind == "groupby_dense" and plan.row_sharded_params  # word-fused filter
+
+    def described(x):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(chip_mesh, x.sharding.spec)
+        )
+
+    compiled = plan.fn.lower(*jax.tree_util.tree_map(described, (cols, params))).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -92,3 +92,33 @@ def test_reset_clears_counts():
     assert audit.compile_count("a") == 1
     audit.reset()
     assert audit.compile_count("a") == 0 and audit.counts() == {}
+
+
+def test_same_shaped_segments_share_one_plan_for_a_raw_metric():
+    """Segments of equal shape whose RAW metric column differs only in its
+    min/max share one compiled plan when the column is aggregated or
+    filtered (nothing of min/max is baked beyond the limb plan); as a GROUP
+    BY dimension its range is baked, so each range keeps its own plan."""
+    rng = np.random.default_rng(5)
+    schema = Schema(
+        "m",
+        [
+            FieldSpec("k", DataType.INT),
+            FieldSpec("v", DataType.LONG, role=FieldRole.METRIC),
+        ],
+    )
+    e = QueryEngine()
+    e.register_table(schema)
+    for i in range(4):
+        data = {
+            "k": np.arange(500, dtype=np.int32) % 7,
+            "v": (1000 + i + rng.integers(0, 50_000, 500)).astype(np.int64),
+        }
+        e.add_segment("m", build_segment(schema, data, f"s{i}"))
+    planner.plan_cache_clear()
+    SSE_AUDIT.reset()
+    e.sql("SELECT k, SUM(v) FROM m WHERE v > 5 GROUP BY k")
+    assert SSE_AUDIT.summary()["compiles_total"] == 1
+    SSE_AUDIT.reset()
+    e.sql("SELECT v, COUNT(*) FROM m GROUP BY v LIMIT 5")
+    assert SSE_AUDIT.summary()["compiles_total"] == 4

@@ -25,11 +25,6 @@ from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
 from pinot_tpu.sql.parser import parse_query
 
 
-pytestmark = pytest.mark.skipif(
-    not pallas_scan._HAS_PALLAS, reason="jax.experimental.pallas unavailable"
-)
-
-
 def _reference(entries, codes, num_groups):
     return [
         np.asarray(segmented._entry_fallback(k, v, m, codes, num_groups), np.float64)
@@ -121,9 +116,15 @@ def test_scan_backend_env(monkeypatch):
     monkeypatch.setenv("PINOT_TPU_SCAN_BACKEND", "interpret")
     ops.scan_backend.cache_clear()
     assert ops.scan_backend() == "interpret"
+    # a forced backend that cannot be honoured raises; it never degrades
     monkeypatch.setenv("PINOT_TPU_SCAN_BACKEND", "pallas")
     ops.scan_backend.cache_clear()
-    assert ops.scan_backend() == "pallas"
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        ops.scan_backend()
+    monkeypatch.setenv("PINOT_TPU_SCAN_BACKEND", "mosaic")
+    ops.scan_backend.cache_clear()
+    with pytest.raises(ValueError, match="expected one of"):
+        ops.scan_backend()
     monkeypatch.delenv("PINOT_TPU_SCAN_BACKEND")
     ops.scan_backend.cache_clear()
     assert ops.scan_backend() == "xla"  # CPU default: Pallas only on TPU

@@ -152,6 +152,22 @@ class TestRoofline:
         assert perf.roofline_pct(0.0, 1.0) is None
         assert perf.roofline_pct(100.0, 0.0) is None
 
+    def test_unknown_tpu_kind_is_an_error(self, monkeypatch):
+        # a TPU the peaks table does not know gets no borrowed peak
+        import jax
+
+        class _Dev:
+            device_kind = "TPU v9 imaginary"
+
+        monkeypatch.delenv("PINOT_TPU_PEAK_HBM_BPS", raising=False)
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
+        perf.peak_hbm_bytes_per_sec.cache_clear()
+        try:
+            with pytest.raises(ValueError, match="no peak HBM bandwidth on record"):
+                perf.peak_hbm_bytes_per_sec()
+        finally:
+            perf.peak_hbm_bytes_per_sec.cache_clear()
+
     def test_cpu_fallback_peak_is_positive(self, monkeypatch):
         monkeypatch.delenv("PINOT_TPU_PEAK_HBM_BPS", raising=False)
         perf.peak_hbm_bytes_per_sec.cache_clear()
@@ -190,6 +206,10 @@ class TestEngineCostIntegration:
         # through the analytic fallback
         monkeypatch.setenv("PINOT_TPU_SCAN_BACKEND", "interpret")
         ops.scan_backend.cache_clear()
+        # a peak this low keeps every measured roofline % far above the
+        # two-decimal rounding, however slow the interpreted kernel runs
+        monkeypatch.setenv("PINOT_TPU_PEAK_HBM_BPS", "1e3")
+        perf.peak_hbm_bytes_per_sec.cache_clear()
         try:
             eng = _engine(table="perfinterp", rows=170)
             res = eng.query(
@@ -212,6 +232,7 @@ class TestEngineCostIntegration:
             assert any(r[5] for r in trace_launch)  # span-level kernelBytes
         finally:
             ops.scan_backend.cache_clear()
+            perf.peak_hbm_bytes_per_sec.cache_clear()
 
 
 # ---------------------------------------------------------------------------

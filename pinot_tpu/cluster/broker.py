@@ -36,7 +36,7 @@ from pinot_tpu.query.result import ExecutionStats, ResultTable
 from pinot_tpu.query.safety import Deadline, QueryTimeoutError
 from pinot_tpu.utils import threads
 from pinot_tpu.utils.hashing import partition_of
-from pinot_tpu.utils.metrics import METRICS, Trace
+from pinot_tpu.utils.metrics import METRICS, Trace, annotate_root, stage
 from pinot_tpu.utils.slowlog import SlowQueryLog
 
 
@@ -762,9 +762,13 @@ class Broker:
     def query(self, sql: str) -> ResultTable:
         from pinot_tpu.sql.parser import parse_query
 
-        ctx = parse_query(sql)
+        # the parse runs before the query's Trace exists: a stage of its
+        # own, a timer, and an attr on the answer's root span
+        with stage("sql_parse") as parse:
+            ctx = parse_query(sql)
         if ctx.options.get("__explain__"):
             return self.execute(ctx)  # plan-only: not a served query
+        METRICS.timer("broker.parseMs").update(parse.ms)
         fp = ctx.fingerprint()
         sfp = ctx.shape_fingerprint()
         try:
@@ -774,6 +778,7 @@ class Broker:
                 sql, fp, None, error=f"{type(e).__name__}: {e}", shape_fingerprint=sfp
             )
             raise
+        annotate_root(out.stats.trace, parseMs=round(parse.ms, 3))
         self.slow_queries.record(sql, fp, out, shape_fingerprint=sfp)
         return out
 
@@ -1028,6 +1033,7 @@ class Broker:
         member) runs through."""
         with trace.span("reduce"):
             out = reduce_mod.reduce_results(ctx, results, stats)
+        trace.flush(METRICS, {"reduce": "broker.reduceMs"})
         out.stats.time_ms = (time.perf_counter() - t0) * 1000
         out.stats.query_id = qid
         tr = trace.finish()
@@ -1828,7 +1834,7 @@ class Broker:
                             )
                             return srv.execute(
                                 ctx, _segs, table_schema=meta.schema,
-                                deadline=_per_call, cancel=comp,
+                                deadline=_per_call, cancel=comp, query_id=qid,
                             )
 
                         with trace.span(

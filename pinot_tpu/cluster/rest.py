@@ -22,7 +22,7 @@ import numpy as np
 
 from pinot_tpu.query.cursors import ResponseStore
 from pinot_tpu.query.result import ResultTable
-from pinot_tpu.utils.metrics import METRICS
+from pinot_tpu.utils.metrics import METRICS, annotate_root, stage
 
 
 def _jsonable(v):
@@ -74,7 +74,9 @@ class QueryServer:
                 pass
 
             def _send(self, code: int, payload: Dict[str, Any]) -> None:
-                body = json.dumps(payload).encode("utf-8")
+                self._send_body(code, json.dumps(payload).encode("utf-8"))
+
+            def _send_body(self, code: int, body: bytes) -> None:
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(body)))
@@ -166,23 +168,38 @@ class QueryServer:
                     self._send(500, {"error": f"{type(e).__name__}: {e}"})
 
             def do_POST(self):
+                """The front door's four stages, each a profiler annotation
+                and (for a query that is answered) one timer update: read +
+                decode the body, the engine call, build + serialise the
+                answer, write it.  A traced answer carries the read time on
+                its root span (httpReadMs); serialise and write cannot ride
+                the payload they produce."""
                 try:
-                    n = int(self.headers.get("Content-Length", 0))
-                    req = json.loads(self.rfile.read(n) or b"{}")
+                    with stage("http_read") as read:
+                        n = int(self.headers.get("Content-Length", 0))
+                        req = json.loads(self.rfile.read(n) or b"{}")
                     if self.path not in ("/query/sql", "/query"):
                         self._send(404, {"error": f"unknown path {self.path}"})
                         return
                     sql = req.get("sql", "")
                     run = getattr(outer.engine, "sql", None) or outer.engine.query
-                    result = run(sql)
-                    payload = broker_response(result)
-                    if req.get("useCursor"):
-                        cid = outer.cursors.register(result, int(req.get("pageSize", 1000)))
-                        payload["cursorId"] = cid
-                        payload["resultTable"]["rows"] = payload["resultTable"]["rows"][
-                            : int(req.get("pageSize", 1000))
-                        ]
-                    self._send(200, payload)
+                    with stage("http_engine"):
+                        result = run(sql)
+                    annotate_root(result.stats.trace, httpReadMs=round(read.ms, 3))
+                    with stage("http_serialize") as ser:
+                        payload = broker_response(result)
+                        if req.get("useCursor"):
+                            cid = outer.cursors.register(result, int(req.get("pageSize", 1000)))
+                            payload["cursorId"] = cid
+                            payload["resultTable"]["rows"] = payload["resultTable"]["rows"][
+                                : int(req.get("pageSize", 1000))
+                            ]
+                        body = json.dumps(payload).encode("utf-8")
+                    with stage("http_write") as write:
+                        self._send_body(200, body)
+                    METRICS.timer("rest.readMs").update(read.ms)
+                    METRICS.timer("rest.serializeMs").update(ser.ms)
+                    METRICS.timer("rest.writeMs").update(write.ms)
                 except Exception as e:  # noqa: BLE001 - boundary
                     from pinot_tpu.analysis.plan_check import PlanCheckError
                     from pinot_tpu.cluster.admission import (

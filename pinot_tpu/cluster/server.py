@@ -32,6 +32,15 @@ from pinot_tpu.segment.segment import ImmutableSegment
 from pinot_tpu.utils import perf
 from pinot_tpu.utils.metrics import METRICS, MetricsRegistry
 
+# span name -> this server's timer: one update a query, the sum over its segments
+_STAGE_TIMERS = {
+    "launch_plan": "server.launchPlanMs",
+    "launch_ship": "server.launchShipMs",
+    "launch_enqueue": "server.launchEnqueueMs",
+    "launch_release": "server.launchReleaseMs",  # inside launch_enqueue
+    "collect": "server.collectMs",
+}
+
 
 def _staging_depth() -> int:
     """Scatter staging window: how many consecutive segments must be
@@ -155,6 +164,7 @@ class ServerInstance:
         deadline: Optional[Deadline] = None,
         cancel=None,
         source: str = "broker",
+        query_id: Optional[str] = None,
     ):
         """Run one query over the named LOCAL segments; returns
         (segment results, stats) — the DataTable the reference ships back.
@@ -169,11 +179,16 @@ class ServerInstance:
         broker should fail the segments over to another replica.
 
         Tracing (ctx option `trace`): builds a per-server span subtree —
-        dispatch (host-side plan+ship+async-launch per segment), device_wait
-        (ONE block_until_ready over every pending output: the device-compute
-        share the async dispatch hides), then per-segment collect spans —
+        dispatch (per segment a launch:<segment> span over the executor's
+        launch_plan / launch_ship / launch_enqueue, the last ending in
+        launch_release), device_wait (ONE block_until_ready over every
+        pending output: the device-compute share the async dispatch
+        hides), then per-segment collect spans —
         annotated with segments/docs/backend and any fault-plan events, and
-        ships it back via stats.trace for the broker to graft."""
+        ships it back via stats.trace for the broker to graft.  Traced or
+        not, every span is a profiler annotation carrying `query_id` (the
+        broker's), and the stages' sums go once a query into this server's
+        timers (_STAGE_TIMERS)."""
         from pinot_tpu.query.planner import _needed_columns
         from pinot_tpu.utils.metrics import Trace
 
@@ -183,7 +198,9 @@ class ServerInstance:
             # a dead process looks like a transport error to the broker —
             # exactly the signal that drives its failover/breaker paths
             raise ServerFaultError(f"server {self.name} is down (crashed)")
-        trace = Trace(bool(ctx.options.get("trace", False)), root=f"server:{self.name}")
+        trace = Trace(
+            bool(ctx.options.get("trace", False)), root=f"server:{self.name}", query_id=query_id
+        )
         ticket = None
         if self.budget is not None:
             # working-set estimate for the batch, reserved all-or-nothing
@@ -261,18 +278,23 @@ class ServerInstance:
                             prefetch=True,
                         )
                     # pipelined: dispatch all kernels async, then drain (executor.py)
-                    with trace.span(f"launch:{seg.name}") as lsp:
+                    with trace.span(f"launch:{seg.name}", cpu=True, segment=seg.name) as lsp:
                         st = executor.launch_segment(
-                            ctx, seg, device=self.device, residency=self.residency
+                            ctx, seg, device=self.device, residency=self.residency,
+                            trace=trace,
                         )
                         pending.append(st)
-                    if lsp is not None and st[0] == "pending":
-                        # per-operator cost model for EXPLAIN ANALYZE / traces
-                        lsp.annotate(
-                            kernelBytes=st[5].kernel_bytes,
-                            kernelFlops=st[5].kernel_flops,
-                            costSource=st[5].kernel_cost_source,
-                        )
+                    if lsp is not None:
+                        # as an attr too: beside the span's wall time, the
+                        # rest is waiting (interpreter lock, a lock, the device)
+                        lsp.annotate(cpuMs=round(lsp.cpu_ms, 3))
+                        if st[0] == "pending":
+                            # per-operator cost model for EXPLAIN ANALYZE
+                            lsp.annotate(
+                                kernelBytes=st[5].kernel_bytes,
+                                kernelFlops=st[5].kernel_flops,
+                                costSource=st[5].kernel_cost_source,
+                            )
                 if dsp is not None:
                     dsp.annotate(launches=len(pending))
             if trace.enabled:
@@ -310,7 +332,8 @@ class ServerInstance:
             # server-local series the broker federates into the cluster view
             self.metrics.counter("server.queries").inc()
             self.metrics.counter("server.docsScanned").inc(stats.num_docs_scanned)
-            self.metrics.counter("server.kernelBytes").inc(int(stats.kernel_bytes))
+            self.metrics.counter("server.launches").inc(len(pending))
+            trace.flush(self.metrics, _STAGE_TIMERS)
             if stats.compile_ms > 0:
                 self.metrics.timer("server.compileMs").update(stats.compile_ms)
             if trace.enabled:
@@ -372,7 +395,7 @@ class ServerInstance:
         n = len(ctxs)
         deadlines = list(deadlines) if deadlines else [None] * n
         cancels = list(cancels) if cancels else [None] * n
-        trace = Trace(trace_enabled, root=f"server:{self.name}")
+        trace = Trace(trace_enabled, root=f"server:{self.name}", query_id=batch_id)
         ticket = None
         if self.budget is not None:
             # members share one plan shape, so the working set is the
@@ -440,18 +463,20 @@ class ServerInstance:
                             scan.append(i)
                     if not scan:
                         continue
-                    with trace.span(f"launch:{seg.name}", members=len(scan)):
+                    with trace.span(
+                        f"launch:{seg.name}", cpu=True, segment=seg.name, members=len(scan)
+                    ) as lsp:
                         if len(scan) == 1:
                             st = executor.launch_segment(
                                 ctxs[scan[0]], seg, device=self.device,
-                                residency=self.residency,
+                                residency=self.residency, trace=trace,
                             )
                             pending.append((st, scan))
                         else:
                             try:
                                 st = executor.launch_segment_batch(
                                     [ctxs[i] for i in scan], seg, device=self.device,
-                                    residency=self.residency,
+                                    residency=self.residency, trace=trace,
                                 )
                                 pending.append((st, scan))
                             except executor.BatchShapeError:
@@ -462,11 +487,13 @@ class ServerInstance:
                                         (
                                             executor.launch_segment(
                                                 ctxs[i], seg, device=self.device,
-                                                residency=self.residency,
+                                                residency=self.residency, trace=trace,
                                             ),
                                             [i],
                                         )
                                     )
+                    if lsp is not None:
+                        lsp.annotate(cpuMs=round(lsp.cpu_ms, 3))
                 if dsp is not None:
                     dsp.annotate(launches=len(pending))
             if trace.enabled:
@@ -512,9 +539,8 @@ class ServerInstance:
             METRICS.histogram("server.batchSize").update(n)
             docs_total = sum(s.num_docs_scanned for s in stats)
             self.metrics.counter("server.docsScanned").inc(docs_total)
-            self.metrics.counter("server.kernelBytes").inc(
-                int(sum(s.kernel_bytes for s in stats))
-            )
+            self.metrics.counter("server.launches").inc(len(pending))
+            trace.flush(self.metrics, _STAGE_TIMERS)
             killed = sum(
                 1 for e in errors if isinstance(e, QueryKilledError)
             )

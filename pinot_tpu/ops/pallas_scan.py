@@ -235,6 +235,7 @@ def fused_group_tables_pallas(
         rows_per.append(1)
     ix_of: Dict[int, int] = {}
 
+    @jax.named_scope("scan_operands")
     def _operand(arr) -> int:
         k = id(arr)
         if k not in ix_of:
@@ -257,6 +258,7 @@ def fused_group_tables_pallas(
 
     halves_of: Dict[int, Tuple[Any, Any]] = {}
 
+    @jax.named_scope("scan_operands")
     def _halves(arr):
         """int32 (lo, hi) halves of an int64 column, split OUTSIDE the
         kernel — TPU Pallas has no 64-bit row ops; the bitcast is a cheap
@@ -293,14 +295,15 @@ def fused_group_tables_pallas(
         scales_per_entry.append(scales)
     L = col
 
-    if n % T:
-        # padding carries mask=False / zero words, so it contributes nothing
-        pad = n_tiles * T - n
-        inputs = [jnp.pad(a, (0, pad // f)) for a, f in zip(inputs, rows_per)]
-    # packed words ride as [words / 128, 128]: for a 32-bit array that is the
-    # same bytes as the 1-D HBM tiling, and it makes the words of chunk c
-    # whole sublane rows (a 1-D block can only be sliced by 1024s)
-    inputs = [a if f == 1 else a.reshape(-1, 128) for a, f in zip(inputs, rows_per)]
+    with jax.named_scope("scan_operands"):
+        if n % T:
+            # padding carries mask=False / zero words, so it contributes nothing
+            pad = n_tiles * T - n
+            inputs = [jnp.pad(a, (0, pad // f)) for a, f in zip(inputs, rows_per)]
+        # packed words ride as [words / 128, 128]: for a 32-bit array that is the
+        # same bytes as the 1-D HBM tiling, and it makes the words of chunk c
+        # whole sublane rows (a 1-D block can only be sliced by 1024s)
+        inputs = [a if f == 1 else a.reshape(-1, 128) for a, f in zip(inputs, rows_per)]
     in_specs = [
         pl.BlockSpec((T,), _tile_map)
         if f == 1
@@ -350,15 +353,17 @@ def fused_group_tables_pallas(
                 jnp.concatenate([w[k:k + 1, :] for k in range(r)], axis=1), bits
             )
 
-        ki = unpacked_row(0, key_bits) if key_bits is not None else row(0)
-        base = None
-        if words_ix is not None:
-            base = unpacked_row(words_ix, 1) != zero
+        with jax.named_scope("lane_unpack"):
+            ki = unpacked_row(0, key_bits) if key_bits is not None else row(0)
+            base = None
+            if words_ix is not None:
+                base = unpacked_row(words_ix, 1) != zero
         if pred_plan is not None:
-            p_ix, plo, phi = pred_plan
-            pc = row(p_ix)
-            pm = (pc >= plo) & (pc < phi)
-            base = pm if base is None else base & pm
+            with jax.named_scope("predicate"):
+                p_ix, plo, phi = pred_plan
+                pc = row(p_ix)
+                pm = (pc >= plo) & (pc < phi)
+                base = pm if base is None else base & pm
 
         # one (A, B) one-hot pair shared by EVERY limb matmul of the chunk —
         # the same sharing that makes the fused XLA scan 3x faster than
@@ -373,6 +378,7 @@ def fused_group_tables_pallas(
         hot, other = (a_hot, b_hot) if weigh_a else (b_hot, a_hot)
         fixed = other.astype(jnp.float32).astype(jnp.bfloat16)
 
+        @jax.named_scope("onehot_accumulate")
         def accum(col_ix, wcol):
             weighed = jnp.where(hot, wcol.astype(jnp.float32), np.float32(0)).astype(
                 jnp.bfloat16
@@ -384,31 +390,39 @@ def fused_group_tables_pallas(
             )
             out_ref[0, col_ix] = out_ref[0, col_ix] + s.astype(i32)
 
+        # value transform: each entry's masked values as 8-bit limbs, one
+        # accumulate per limb
         for kind, m_ix, v_ixs, lp, col0 in plans:
-            m = row(m_ix) != zero
-            if base is not None:
-                m = m & base
+            with jax.named_scope("value_transform"):
+                m = row(m_ix) != zero
+                if base is not None:
+                    m = m & base
             if kind == "count":
                 accum(col0, jnp.where(m, one, zero))
             elif kind == "int_sum":
                 n_limbs, signed = lp
-                vm = jnp.where(m, row(v_ixs[0]), zero)
+                with jax.named_scope("value_transform"):
+                    vm = jnp.where(m, row(v_ixs[0]), zero)
                 for k in range(n_limbs):
                     # arithmetic shift then mask == the two's-complement byte
                     accum(col0 + k, (vm >> np.int32(8 * k)) & byte)
                 if signed:
                     accum(col0 + n_limbs, jnp.where(vm < zero, one, zero))
             else:  # int64_sum: signed-magnitude limbs of the (lo, hi) halves
-                lo_h = row(v_ixs[0])
-                hi_h = row(v_ixs[1])
-                neg = hi_h < zero
-                alo = jnp.where(neg, -lo_h, lo_h)  # wrapping: ~lo + 1
-                ahi = jnp.where(neg, ~hi_h + jnp.where(lo_h == zero, one, zero), hi_h)
-                sgn = jnp.where(m, jnp.where(neg, np.int32(-1), one), zero)
+                with jax.named_scope("value_transform"):
+                    lo_h = row(v_ixs[0])
+                    hi_h = row(v_ixs[1])
+                    neg = hi_h < zero
+                    alo = jnp.where(neg, -lo_h, lo_h)  # wrapping: ~lo + 1
+                    ahi = jnp.where(neg, ~hi_h + jnp.where(lo_h == zero, one, zero), hi_h)
+                    sgn = jnp.where(m, jnp.where(neg, np.int32(-1), one), zero)
                 for k in range(lp):
                     h = alo if k < 4 else ahi
                     accum(col0 + k, ((h >> np.int32(8 * (k % 4))) & byte) * sgn)
 
+    # The name is the HLO instruction's and so the device trace's: the dense
+    # one-hot scan, its limb columns and its table height in sublanes.  It
+    # keeps the `kernel` prefix the benchmark's scan_kernel_ms pattern reads.
     out = pl.pallas_call(
         scan_kernel,
         grid=(n_tiles,),
@@ -416,18 +430,20 @@ def fused_group_tables_pallas(
         out_specs=pl.BlockSpec((1, L, Hp, _W), _super_map),
         out_shape=jax.ShapeDtypeStruct((n_super, L, Hp, _W), jnp.int32),
         interpret=bool(interpret),
+        name=f"kernel_dense_onehot_l{L}_h{Hp}",
     )(*inputs)
 
     # cross-super recombine in f64 (table-sized): every per-super value is
     # an exact integer < 2^31, every partial sum stays < 2^53 under the
     # same contract as the XLA path's per-chunk f64 combine
-    flat = out.astype(jnp.float64).sum(axis=0).reshape(L, Hp * _W)[:, :num_groups]
-    tables = []
-    for (kind, _m, _v, _lp, col0), scales in zip(plans, scales_per_entry):
-        t = flat[col0] if scales[0] == 1.0 else flat[col0] * scales[0]
-        for j, s in enumerate(scales[1:], start=1):
-            t = t + flat[col0 + j] * s
-        tables.append(t)
+    with jax.named_scope("recombine_f64"):
+        flat = out.astype(jnp.float64).sum(axis=0).reshape(L, Hp * _W)[:, :num_groups]
+        tables = []
+        for (kind, _m, _v, _lp, col0), scales in zip(plans, scales_per_entry):
+            t = flat[col0] if scales[0] == 1.0 else flat[col0] * scales[0]
+            for j, s in enumerate(scales[1:], start=1):
+                t = t + flat[col0 + j] * s
+            tables.append(t)
     return tables
 
 
@@ -467,6 +483,11 @@ def merge_sparse_tables(
     scatter-combine), not a literal probed hash table: table-sized lax.sort
     is TPU-native and exact, where open-addressing probe loops serialize.
     Everything here is [M]-sized (never row-length)."""
+    with jax.named_scope("merge_sparse_tables"):
+        return _merge_sparse_tables(uniq, partials, num_slots, field_ops, order_spec, may_trim)
+
+
+def _merge_sparse_tables(uniq, partials, num_slots, field_ops, order_spec, may_trim):
     M = int(uniq.shape[0])
     uniq = uniq.astype(jnp.int64).reshape(-1)
     iota = jnp.arange(M, dtype=jnp.int32)

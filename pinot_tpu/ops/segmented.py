@@ -450,6 +450,13 @@ def fused_group_tables(
                 interpret=(backend == "interpret"),
             )
     METRICS.counter("scan.traced.xla").inc()
+    with jax.named_scope("xla_scan"):
+        return _fused_group_tables_xla(entries, codes, num_groups, mask_words, codes_packed)
+
+
+def _fused_group_tables_xla(entries, codes, num_groups: int, mask_words, codes_packed):
+    """The XLA scan of fused_group_tables (everything the Pallas kernel
+    declined or the backend does not have)."""
     if mask_words is not None:
         # declined the Pallas path (wide table, float kinds, CPU policy):
         # fall back to one explicit unpack shared by every entry

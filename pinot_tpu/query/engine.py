@@ -139,8 +139,8 @@ class QueryEngine:
                 if executor.prune_segment(ctx, seg):
                     stats.num_segments_pruned += 1
                     continue
-                with trace.span(f"launch:{seg.name}") as lsp:
-                    st = executor.launch_segment(ctx, seg, device=device)
+                with trace.span(f"launch:{seg.name}", segment=seg.name) as lsp:
+                    st = executor.launch_segment(ctx, seg, device=device, trace=trace)
                     pending.append(st)
                 if lsp is not None and st[0] == "pending":
                     # per-operator cost model on the launch span: EXPLAIN

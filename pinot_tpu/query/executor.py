@@ -21,6 +21,7 @@ import numpy as np
 
 from pinot_tpu.query import planner
 from pinot_tpu.utils import perf
+from pinot_tpu.utils.metrics import Trace
 from pinot_tpu.query.functions import combine_field
 from pinot_tpu.query.ir import Expr, FilterNode, FilterOp, PredicateType, QueryContext
 from pinot_tpu.query.transform import eval_expr_host
@@ -95,7 +96,8 @@ def prune_segment(ctx: QueryContext, segment: ImmutableSegment) -> bool:
 # Execution
 # ---------------------------------------------------------------------------
 def launch_segment(
-    ctx: QueryContext, segment: ImmutableSegment, device=None, residency=None
+    ctx: QueryContext, segment: ImmutableSegment, device=None, residency=None,
+    trace: Optional[Trace] = None,
 ):
     """Phase 1 of pipelined execution: plan, ship inputs, and DISPATCH the
     segment kernel (jax dispatch is asynchronous — the call returns as soon
@@ -105,12 +107,23 @@ def launch_segment(
     This is the pipeline-parallelism axis (SURVEY.md §2.5): while segment
     k's kernel runs on device, the host plans/ships segment k+1 and later
     drains results — the streaming overlap the reference gets from mailbox
-    block streaming."""
+    block streaming.
+
+    The stages are spans of `trace` (the caller's, under its
+    `launch:<segment>` span): launch_plan (star-tree probe + plan cache),
+    launch_ship (columns and parameters to the device), launch_enqueue (the
+    jitted call; it names the plan when it had to compile), which ends with
+    its child launch_release (the call's parameter arrays are dropped)."""
     import jax
 
     from pinot_tpu.query.startree import try_startree
 
-    star = try_startree(ctx, segment)
+    trace = trace if trace is not None else Trace()
+    with trace.span("launch_plan", segment=segment.name) as psp:
+        star = try_startree(ctx, segment)
+        plan = planner.plan_segment(ctx, segment) if star is None else None
+        if psp is not None:
+            psp.annotate(cache="startree" if plan is None else "hit" if plan.cache_hit else "miss")
     if star is not None:
         return ("done", star)
 
@@ -120,41 +133,54 @@ def launch_segment(
         num_docs_scanned=segment.num_docs,
         total_docs=segment.num_docs,
     )
-    plan = planner.plan_segment(ctx, segment)
     stats.filter_index_uses = tuple(plan.index_uses)
-    cols = segment.to_device(
-        device=device, columns=plan.needed_columns, packed_codes=True,
-        residency=residency,
-    )
-    params = {k: jax.device_put(v, device) for k, v in plan.params.items()}
+    with trace.span("launch_ship", segment=segment.name, params=len(plan.params)):
+        cols = segment.to_device(
+            device=device, columns=plan.needed_columns, packed_codes=True,
+            residency=residency,
+        )
+        params = {k: jax.device_put(v, device) for k, v in plan.params.items()}
     first_launch = plan.cost is None
     if first_launch:
         # cost model captured ONCE per cached plan (hits copy it forward in
         # plan_segment); racing first launches both capture — idempotent
-        plan.cost = perf.capture_cost(
-            plan.fn,
-            (cols, params),
-            perf.analytic_cost(
-                segment.num_docs,
-                perf.analytic_bytes_per_row(
-                    segment.column(n) for n in plan.needed_columns
-                ),
-                kind=plan.kind,
-                num_groups=plan.num_groups,
-                num_entries=len(plan.aggs),
-            ),
-        )
-    t0 = time.perf_counter()
-    out = plan.fn(cols, params)  # async dispatch; device_get happens at collect
-    if first_launch:
-        # first jit dispatch pays trace+compile before enqueueing — its wall
-        # time IS the compile cost (AOT compile would pay it a second time)
-        plan.cost.compile_ms = (time.perf_counter() - t0) * 1000.0
-        stats.compile_ms = plan.cost.compile_ms + plan.cost.lower_ms
+        plan.cost = _capture_cost(plan, segment, cols, params)
+    with trace.span(
+        "launch_enqueue", segment=segment.name, kind=plan.kind, backend=plan.cache_key[2]
+    ) as esp:
+        t0 = time.perf_counter()
+        out = plan.fn(cols, params)  # async dispatch; device_get happens at collect
+        if first_launch:
+            # first jit dispatch pays trace+compile before enqueueing — its wall
+            # time IS the compile cost (AOT compile would pay it a second time)
+            plan.cost.compile_ms = (time.perf_counter() - t0) * 1000.0
+            stats.compile_ms = plan.cost.compile_ms + plan.cost.lower_ms
+            if esp is not None:
+                esp.annotate(firstLaunch=True, compileMs=round(stats.compile_ms, 3))
+        with trace.span("launch_release", segment=segment.name):
+            # the parameter arrays lived for this call only: dropping them
+            # here, inside a span, keeps what their release costs (one trip
+            # through the runtime an array) out of the launch's untimed tail
+            del params
     stats.kernel_bytes = plan.cost.bytes_accessed
     stats.kernel_flops = plan.cost.flops
     stats.kernel_cost_source = plan.cost.source
     return ("pending", ctx, segment, plan, out, stats)
+
+
+def _capture_cost(plan, segment: ImmutableSegment, cols, params):
+    """The single-lane cost model of a plan's first launch (utils/perf.py)."""
+    return perf.capture_cost(
+        plan.fn,
+        (cols, params),
+        perf.analytic_cost(
+            segment.num_docs,
+            perf.analytic_bytes_per_row(segment.column(n) for n in plan.needed_columns),
+            kind=plan.kind,
+            num_groups=plan.num_groups,
+            num_entries=len(plan.aggs),
+        ),
+    )
 
 
 def pending_outputs(states) -> list:
@@ -277,7 +303,8 @@ _BATCH_FN_CACHE = None
 
 
 def launch_segment_batch(
-    ctxs: List[QueryContext], segment: ImmutableSegment, device=None, residency=None
+    ctxs: List[QueryContext], segment: ImmutableSegment, device=None, residency=None,
+    trace: Optional[Trace] = None,
 ):
     """Dispatch N same-shape queries over one segment as a SINGLE vmapped
     kernel launch: member literal-parameter pytrees stack along a leading
@@ -294,13 +321,17 @@ def launch_segment_batch(
     Raises BatchShapeError when members don't resolve to one compiled plan
     (callers fall back to per-member launches).  Star-tree shortcuts are
     intentionally not taken here — members were vetted as batchable by the
-    broker before coalescing."""
+    broker before coalescing.  Same spans as launch_segment."""
     import jax
 
     n = len(ctxs)
     if n < 1:
         raise ValueError("launch_segment_batch needs at least one member")
-    plans = [planner.plan_segment(ctx, segment) for ctx in ctxs]
+    trace = trace if trace is not None else Trace()
+    with trace.span("launch_plan", segment=segment.name) as psp:
+        plans = [planner.plan_segment(ctx, segment) for ctx in ctxs]
+        if psp is not None:
+            psp.annotate(cache="hit" if all(p.cache_hit for p in plans) else "miss")
     base = plans[0]
     for p in plans[1:]:
         if p.fn is not base.fn or p.kind != base.kind:
@@ -315,22 +346,23 @@ def launch_segment_batch(
     params_list = [p.params for p in plans]
     if n < width:
         params_list = params_list + [plans[-1].params] * (width - n)
-    cols = segment.to_device(
-        device=device, columns=base.needed_columns, packed_codes=True,
-        residency=residency,
-    )
-    stacked = {}
-    for k, v0 in base.params.items():
-        if k in shared_keys:
-            stacked[k] = jax.device_put(v0, device)
-        else:
-            stacked[k] = jax.device_put(
-                jax.tree_util.tree_map(
-                    lambda *xs: np.stack([np.asarray(x) for x in xs]),
-                    *(pl[k] for pl in params_list),
-                ),
-                device,
-            )
+    with trace.span("launch_ship", segment=segment.name, params=len(base.params)):
+        cols = segment.to_device(
+            device=device, columns=base.needed_columns, packed_codes=True,
+            residency=residency,
+        )
+        stacked = {}
+        for k, v0 in base.params.items():
+            if k in shared_keys:
+                stacked[k] = jax.device_put(v0, device)
+            else:
+                stacked[k] = jax.device_put(
+                    jax.tree_util.tree_map(
+                        lambda *xs: np.stack([np.asarray(x) for x in xs]),
+                        *(pl[k] for pl in params_list),
+                    ),
+                    device,
+                )
 
     key = (base.cache_key or id(base.fn), width, shared_keys)
     cache = _batch_fn_cache()
@@ -348,24 +380,20 @@ def launch_segment_batch(
         # same single-lane cost model as launch_segment, so per-member
         # shares divide the identical numbers an unbatched run reports
         single = {k: jax.device_put(v, device) for k, v in base.params.items()}
-        base.cost = perf.capture_cost(
-            base.fn,
-            (cols, single),
-            perf.analytic_cost(
-                segment.num_docs,
-                perf.analytic_bytes_per_row(
-                    segment.column(nm) for nm in base.needed_columns
-                ),
-                kind=base.kind,
-                num_groups=base.num_groups,
-                num_entries=len(base.aggs),
-            ),
-        )
-    t0 = time.perf_counter()
-    out = fnb(cols, stacked)  # async dispatch; one device_get at collect
-    # deliberately times the dispatch: the first vmapped call pays
-    # trace+compile inline, and THAT is the cost being recorded
-    compile_ms = (time.perf_counter() - t0) * 1000.0 if first_batched else 0.0  # pinot-lint: disable=W017
+        base.cost = _capture_cost(base, segment, cols, single)
+    with trace.span(
+        "launch_enqueue", segment=segment.name, kind=base.kind, backend=base.cache_key[2],
+        members=n,
+    ) as esp:
+        t0 = time.perf_counter()
+        out = fnb(cols, stacked)  # async dispatch; one device_get at collect
+        # deliberately times the dispatch: the first vmapped call pays
+        # trace+compile inline, and THAT is the cost being recorded
+        compile_ms = (time.perf_counter() - t0) * 1000.0 if first_batched else 0.0  # pinot-lint: disable=W017
+        if first_batched and esp is not None:
+            esp.annotate(firstLaunch=True, compileMs=round(compile_ms + base.cost.lower_ms, 3))
+        with trace.span("launch_release", segment=segment.name):
+            del stacked  # as in launch_segment
 
     docs = segment.num_docs
     share, rem = divmod(docs, n)

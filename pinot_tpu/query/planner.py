@@ -149,6 +149,9 @@ class SegmentPlan:
     # identity the cross-query batcher keys its vmapped-fn LRU on, so
     # batching never compiles more than once per (shape, batch width)
     cache_key: Optional[Tuple] = None
+    # whether plan_segment took the compiled fn from the plan cache (the
+    # `cache` attr of the launch_plan span)
+    cache_hit: bool = False
 
 
 # jit cache: (query SHAPE fingerprint, segment signature, backend) -> plan.
@@ -806,144 +809,146 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
              [{field: table[num_slots]}] per agg)."""
     from jax import lax
 
-    n = tmask.shape[0]
-    if num_groups is not None and num_slots >= num_groups:
-        order_spec = None
-    i32_max = np.iinfo(np.int32).max  # the int32 twin of SPARSE_EMPTY_KEY
-    if num_groups is not None and num_groups < i32_max:
-        krow = jnp.where(tmask, key.astype(jnp.int32), i32_max)
-    else:
-        krow = jnp.where(tmask, key, SPARSE_EMPTY_KEY)
-    iota = jnp.arange(n, dtype=jnp.int32)
-    if order_spec is not None and order_spec[1] in ("min", "max"):
-        # min/max order value rides the row sort as a secondary key: after
-        # sorting by (key, ±value) the group's extremum sits at its start row
-        oi, omode, _ = order_spec
-        ov_raw, om = inputs[oi]
-        ovr = ov_raw.astype(jnp.float64)
-        ovr = ovr if omode == "min" else -ovr
-        ovr = jnp.where(om, ovr, jnp.inf)
-        skey, sov, perm = lax.sort((krow, ovr, iota), num_keys=2)
-    else:
-        sov = None
-        skey, perm = lax.sort((krow, iota), num_keys=1)
-    smask = tmask[perm]
-    prev = jnp.concatenate([jnp.full((1,), -1, skey.dtype), skey[:-1]])
-    is_start = smask & (skey != prev)
-    seg = jnp.cumsum(is_start.astype(jnp.int32)) - 1
-    if order_spec is None:
-        # slot num_slots = overflow/invalid bin, sliced off before returning;
-        # first-num_slots-groups-by-packed-key trim (deterministic)
-        slot = jnp.where(smask & (seg < num_slots), seg, num_slots)
-    else:
-        # ORDER BY-aware trim (TableResizer analog): compute each group's
-        # order value in-row-space, rank groups by (order value, packed key)
-        # on device, and give slots to the top num_slots groups only.
-        oi, omode, asc = order_spec
-        if sov is not None:
-            empty = jnp.isinf(sov)  # no agg-mask rows in the group: NULL
-            group_ov = sov  # valid at start rows: the group's min / -max
-            group_ov = group_ov if asc else -group_ov
-            # sov carries -v for max, so one more flip restores the sign
-            if omode == "max":
-                group_ov = -group_ov
-            # NULL (empty) and NaN order values rank LAST in every direction
-            # (matching the host-side _order_trim_select NaN handling); clamp
-            # keeps them FINITE so the finite check below still marks the
-            # group rankable instead of dropping it (review-caught).  An
-            # all-NaN group's start-row sov is NaN (NaN sorts last), which
-            # would otherwise survive clip as NaN and drop the group.
-            group_ov = jnp.clip(
-                jnp.where(empty | jnp.isnan(group_ov), jnp.inf, group_ov), -1e300, 1e300
-            )
+    with jax.named_scope("sparse_sort"):
+        n = tmask.shape[0]
+        if num_groups is not None and num_slots >= num_groups:
+            order_spec = None
+        i32_max = np.iinfo(np.int32).max  # the int32 twin of SPARSE_EMPTY_KEY
+        if num_groups is not None and num_groups < i32_max:
+            krow = jnp.where(tmask, key.astype(jnp.int32), i32_max)
         else:
+            krow = jnp.where(tmask, key, SPARSE_EMPTY_KEY)
+        iota = jnp.arange(n, dtype=jnp.int32)
+        if order_spec is not None and order_spec[1] in ("min", "max"):
+            # min/max order value rides the row sort as a secondary key: after
+            # sorting by (key, ±value) the group's extremum sits at its start row
+            oi, omode, _ = order_spec
             ov_raw, om = inputs[oi]
-            isn = None
-            if omode == "count":
-                c = om.astype(jnp.float64)
-            else:
-                v = ov_raw if getattr(ov_raw, "ndim", 0) else jnp.broadcast_to(ov_raw, (n,))
-                cv = v.astype(jnp.float64)
-                # NaN rows are excluded from the cumsum (one NaN would poison
-                # the prefix sums of every later-keyed group) and tracked per
-                # group instead; NaN-sum groups rank last like the host path
-                isn = jnp.isnan(cv)
-                c = jnp.where(om & ~isn, cv, 0.0)
-            cp = c[perm]
-            s0 = jnp.concatenate([jnp.zeros((1,), jnp.float64), jnp.cumsum(cp)])
-            # smallest start index >= i, from the right; strict next start
-            starts_at = jnp.where(is_start, iota, np.int32(n))
-            nxt_ge = lax.cummin(starts_at[::-1])[::-1]
-            nxt = jnp.concatenate([nxt_ge[1:], jnp.full((1,), n, jnp.int32)])
-            total = s0[nxt] - s0[iota]  # valid at start rows
-            group_ov = total if asc else -total
-            if omode == "sum":
-                # SUM over zero agg-mask rows is SQL NULL, not 0: count the
-                # mask the same way and send empty groups to rank-last
-                mp = om.astype(jnp.float64)[perm]
-                m0 = jnp.concatenate([jnp.zeros((1,), jnp.float64), jnp.cumsum(mp)])
-                np_ = (isn & om).astype(jnp.float64)[perm]
-                n0 = jnp.concatenate([jnp.zeros((1,), jnp.float64), jnp.cumsum(np_)])
-                # rank-last when the group saw a NaN value, when the prefix
-                # sums overflowed to inf (inf - inf = NaN), or when no
-                # agg-mask rows contributed (SQL NULL)
-                bad = ((n0[nxt] - n0[iota]) > 0) | jnp.isnan(group_ov)
+            ovr = ov_raw.astype(jnp.float64)
+            ovr = ovr if omode == "min" else -ovr
+            ovr = jnp.where(om, ovr, jnp.inf)
+            skey, sov, perm = lax.sort((krow, ovr, iota), num_keys=2)
+        else:
+            sov = None
+            skey, perm = lax.sort((krow, iota), num_keys=1)
+        smask = tmask[perm]
+        prev = jnp.concatenate([jnp.full((1,), -1, skey.dtype), skey[:-1]])
+        is_start = smask & (skey != prev)
+        seg = jnp.cumsum(is_start.astype(jnp.int32)) - 1
+        if order_spec is None:
+            # slot num_slots = overflow/invalid bin, sliced off before returning;
+            # first-num_slots-groups-by-packed-key trim (deterministic)
+            slot = jnp.where(smask & (seg < num_slots), seg, num_slots)
+        else:
+            # ORDER BY-aware trim (TableResizer analog): compute each group's
+            # order value in-row-space, rank groups by (order value, packed key)
+            # on device, and give slots to the top num_slots groups only.
+            oi, omode, asc = order_spec
+            if sov is not None:
+                empty = jnp.isinf(sov)  # no agg-mask rows in the group: NULL
+                group_ov = sov  # valid at start rows: the group's min / -max
+                group_ov = group_ov if asc else -group_ov
+                # sov carries -v for max, so one more flip restores the sign
+                if omode == "max":
+                    group_ov = -group_ov
+                # NULL (empty) and NaN order values rank LAST in every direction
+                # (matching the host-side _order_trim_select NaN handling); clamp
+                # keeps them FINITE so the finite check below still marks the
+                # group rankable instead of dropping it (review-caught).  An
+                # all-NaN group's start-row sov is NaN (NaN sorts last), which
+                # would otherwise survive clip as NaN and drop the group.
                 group_ov = jnp.clip(
-                    jnp.where(bad | ((m0[nxt] - m0[iota]) <= 0), jnp.inf, group_ov),
-                    -1e300, 1e300,
+                    jnp.where(empty | jnp.isnan(group_ov), jnp.inf, group_ov), -1e300, 1e300
                 )
-        ovkey = jnp.where(is_start, group_ov, jnp.inf)
-        sovk, sskey, sseg = lax.sort((ovkey, skey, seg), num_keys=2)
-        rank = jnp.minimum(iota, np.int32(num_slots))
-        ranks = (
-            jnp.full((n + 1,), num_slots, dtype=jnp.int32)
-            .at[jnp.where(jnp.isfinite(sovk), sseg, np.int32(n))]
-            .set(rank, mode="drop")
-        )
-        gslot = ranks[jnp.minimum(seg, np.int32(n))]
-        slot = jnp.where(smask & (gslot < num_slots), gslot, num_slots)
-    uniq = (
-        jnp.full((num_slots + 1,), SPARSE_EMPTY_KEY, dtype=jnp.int64)
-        .at[jnp.where(is_start, slot, num_slots)]
-        .set(skey.astype(jnp.int64))
-    )
-    partials = []
-    for fn, (vals, mask) in zip(aggs, inputs):
-        m = mask[perm]
-
-        def _perm(x):
-            x = x if getattr(x, "ndim", 0) else jnp.broadcast_to(x, (n,))
-            return x[perm]
-
-        if fn.field_kinds is None:
-            # sketch / own-scatter family (HLL registers, presence bitmaps,
-            # histograms, KMV, (t, v) pairs, MV wrappers): the slot array IS
-            # a dense group-key space of num_slots+1 ids, so the function's
-            # own partial_grouped scatters per-slot vector fields directly;
-            # the overflow slot is sliced off like the scalar tables.
-            v = tuple(_perm(x) for x in vals) if isinstance(vals, tuple) else _perm(vals)
-            own = fn.partial_grouped(v, m, slot, num_slots + 1)
-            partials.append({f: t[:num_slots] for f, t in own.items()})
-            continue
-        v = _perm(vals)
-        p: Dict[str, Any] = {}
-        for fname in fn.fields:
-            comb = FIELD_COMBINE[fname]
-            if comb == "add":
-                if fname == "count":
-                    acc = jnp.zeros((num_slots + 1,), jnp.int64).at[slot].add(m.astype(jnp.int64))
-                else:
-                    w = v.astype(jnp.float64)
-                    if fname == "sumsq":
-                        w = w * w
-                    acc = jnp.zeros((num_slots + 1,), jnp.float64).at[slot].add(jnp.where(m, w, 0.0))
             else:
-                ident = field_identity(fname)
-                masked = jnp.where(m, v.astype(jnp.float64), ident)
-                base = jnp.full((num_slots + 1,), ident, jnp.float64)
-                acc = base.at[slot].min(masked) if comb == "min" else base.at[slot].max(masked)
-            p[fname] = acc[:num_slots]
-        partials.append(p)
+                ov_raw, om = inputs[oi]
+                isn = None
+                if omode == "count":
+                    c = om.astype(jnp.float64)
+                else:
+                    v = ov_raw if getattr(ov_raw, "ndim", 0) else jnp.broadcast_to(ov_raw, (n,))
+                    cv = v.astype(jnp.float64)
+                    # NaN rows are excluded from the cumsum (one NaN would poison
+                    # the prefix sums of every later-keyed group) and tracked per
+                    # group instead; NaN-sum groups rank last like the host path
+                    isn = jnp.isnan(cv)
+                    c = jnp.where(om & ~isn, cv, 0.0)
+                cp = c[perm]
+                s0 = jnp.concatenate([jnp.zeros((1,), jnp.float64), jnp.cumsum(cp)])
+                # smallest start index >= i, from the right; strict next start
+                starts_at = jnp.where(is_start, iota, np.int32(n))
+                nxt_ge = lax.cummin(starts_at[::-1])[::-1]
+                nxt = jnp.concatenate([nxt_ge[1:], jnp.full((1,), n, jnp.int32)])
+                total = s0[nxt] - s0[iota]  # valid at start rows
+                group_ov = total if asc else -total
+                if omode == "sum":
+                    # SUM over zero agg-mask rows is SQL NULL, not 0: count the
+                    # mask the same way and send empty groups to rank-last
+                    mp = om.astype(jnp.float64)[perm]
+                    m0 = jnp.concatenate([jnp.zeros((1,), jnp.float64), jnp.cumsum(mp)])
+                    np_ = (isn & om).astype(jnp.float64)[perm]
+                    n0 = jnp.concatenate([jnp.zeros((1,), jnp.float64), jnp.cumsum(np_)])
+                    # rank-last when the group saw a NaN value, when the prefix
+                    # sums overflowed to inf (inf - inf = NaN), or when no
+                    # agg-mask rows contributed (SQL NULL)
+                    bad = ((n0[nxt] - n0[iota]) > 0) | jnp.isnan(group_ov)
+                    group_ov = jnp.clip(
+                        jnp.where(bad | ((m0[nxt] - m0[iota]) <= 0), jnp.inf, group_ov),
+                        -1e300, 1e300,
+                    )
+            ovkey = jnp.where(is_start, group_ov, jnp.inf)
+            sovk, sskey, sseg = lax.sort((ovkey, skey, seg), num_keys=2)
+            rank = jnp.minimum(iota, np.int32(num_slots))
+            ranks = (
+                jnp.full((n + 1,), num_slots, dtype=jnp.int32)
+                .at[jnp.where(jnp.isfinite(sovk), sseg, np.int32(n))]
+                .set(rank, mode="drop")
+            )
+            gslot = ranks[jnp.minimum(seg, np.int32(n))]
+            slot = jnp.where(smask & (gslot < num_slots), gslot, num_slots)
+    with jax.named_scope("sparse_scatter"):
+        uniq = (
+            jnp.full((num_slots + 1,), SPARSE_EMPTY_KEY, dtype=jnp.int64)
+            .at[jnp.where(is_start, slot, num_slots)]
+            .set(skey.astype(jnp.int64))
+        )
+        partials = []
+        for fn, (vals, mask) in zip(aggs, inputs):
+            m = mask[perm]
+
+            def _perm(x):
+                x = x if getattr(x, "ndim", 0) else jnp.broadcast_to(x, (n,))
+                return x[perm]
+
+            if fn.field_kinds is None:
+                # sketch / own-scatter family (HLL registers, presence bitmaps,
+                # histograms, KMV, (t, v) pairs, MV wrappers): the slot array IS
+                # a dense group-key space of num_slots+1 ids, so the function's
+                # own partial_grouped scatters per-slot vector fields directly;
+                # the overflow slot is sliced off like the scalar tables.
+                v = tuple(_perm(x) for x in vals) if isinstance(vals, tuple) else _perm(vals)
+                own = fn.partial_grouped(v, m, slot, num_slots + 1)
+                partials.append({f: t[:num_slots] for f, t in own.items()})
+                continue
+            v = _perm(vals)
+            p: Dict[str, Any] = {}
+            for fname in fn.fields:
+                comb = FIELD_COMBINE[fname]
+                if comb == "add":
+                    if fname == "count":
+                        acc = jnp.zeros((num_slots + 1,), jnp.int64).at[slot].add(m.astype(jnp.int64))
+                    else:
+                        w = v.astype(jnp.float64)
+                        if fname == "sumsq":
+                            w = w * w
+                        acc = jnp.zeros((num_slots + 1,), jnp.float64).at[slot].add(jnp.where(m, w, 0.0))
+                else:
+                    ident = field_identity(fname)
+                    masked = jnp.where(m, v.astype(jnp.float64), ident)
+                    base = jnp.full((num_slots + 1,), ident, jnp.float64)
+                    acc = base.at[slot].min(masked) if comb == "min" else base.at[slot].max(masked)
+                p[fname] = acc[:num_slots]
+            partials.append(p)
     return uniq[:num_slots], partials
 
 
@@ -977,6 +982,7 @@ def plan_segment(ctx: QueryContext, segment: ImmutableSegment) -> SegmentPlan:
             # launch of the cached plan, never re-lowered on hits
             plan.cost = cached.cost
             plan.cache_key = key
+            plan.cache_hit = True
             SSE_AUDIT.record_hit(key[0])
             return plan
     SSE_AUDIT.record_compile(key[0])
@@ -1009,6 +1015,15 @@ def _build_plan(
             t, nl = base_filter_fn(cols, params)
             v = params["__valid__"]
             return t & v, (nl & v if nl is not None else None)
+
+    # Device-trace names (HLO op_name metadata only; nothing computes
+    # differently).  The scopes open inside the functions, which run at trace
+    # time only: this builder runs for every segment of every query.
+    unscoped_filter_fn = filter_fn
+
+    def filter_fn(cols, params):
+        with jax.named_scope("predicate"):
+            return unscoped_filter_fn(cols, params)
 
     agg_specs = list(ctx.aggregations)
     aggs = bind_aggs(agg_specs, segment, ctx)
@@ -1050,12 +1065,13 @@ def _build_plan(
         from pinot_tpu.segment import packing
 
         out = dict(cols)
-        for name, bits in packed_meta.items():
-            e = out.get(name)
-            if e is not None and "codes_packed" in e and "codes" not in e:
-                e = dict(e)
-                e["codes"] = packing.unpack_codes_jnp(e["codes_packed"], bits, num_docs)
-                out[name] = e
+        with jax.named_scope("lane_unpack"):
+            for name, bits in packed_meta.items():
+                e = out.get(name)
+                if e is not None and "codes_packed" in e and "codes" not in e:
+                    e = dict(e)
+                    e["codes"] = packing.unpack_codes_jnp(e["codes_packed"], bits, num_docs)
+                    out[name] = e
         return out
 
     if ctx.is_aggregate and not ctx.group_by:
@@ -1077,51 +1093,53 @@ def _build_plan(
 
     def _agg_inputs(cols, params, base_mask):
         """Per-aggregation (values, mask) with null + FILTER handling."""
-        out = []
-        for spec, fn, ffn, sfns in zip(agg_specs, aggs, agg_filter_fns, agg_subfilter_fns):
-            mask = base_mask
-            if ffn is not None:
-                ft, _ = ffn(cols, params)
-                mask = mask & ft
-            if getattr(fn, "mv_input", False):
-                out.append(mv_agg_input(spec, fn, segment, cols, mask))
-                continue
-            if spec.expr is None:
-                vals = mask  # COUNT(*): values unused
-            elif fn.needs_codes:
-                vals, mask = agg_input_codes(spec, fn, segment, cols, mask, null_handling)
-            elif fn.name == "count" and spec.expr.is_column:
-                # COUNT(col) needs only the null mask — works on strings too.
-                vals = mask
-                c = segment.column(spec.expr.op)
-                if c.nulls is not None and null_handling:
-                    mask = mask & ~cols[spec.expr.op]["nulls"]
-            else:
-                vals, nulls = eval_expr(spec.expr, segment, cols)
-                vals = as_row_array(vals, mask.shape)
-                if nulls is not None and null_handling:
-                    mask = mask & ~nulls
-            if fn.needs_extra_exprs:
-                extras = []
-                for ex in spec.extra_exprs:
-                    ev, en = eval_expr(ex, segment, cols)
-                    extras.append(as_row_array(ev, mask.shape))
-                    if en is not None and null_handling:
-                        mask = mask & ~en
-                vals = (vals, *extras)
-            if sfns:
-                vals = (vals, *[mask & sf(cols, params)[0] for sf in sfns])
-            out.append((vals, mask))
-        return out
+        with jax.named_scope("value_transform"):
+            out = []
+            for spec, fn, ffn, sfns in zip(agg_specs, aggs, agg_filter_fns, agg_subfilter_fns):
+                mask = base_mask
+                if ffn is not None:
+                    ft, _ = ffn(cols, params)
+                    mask = mask & ft
+                if getattr(fn, "mv_input", False):
+                    out.append(mv_agg_input(spec, fn, segment, cols, mask))
+                    continue
+                if spec.expr is None:
+                    vals = mask  # COUNT(*): values unused
+                elif fn.needs_codes:
+                    vals, mask = agg_input_codes(spec, fn, segment, cols, mask, null_handling)
+                elif fn.name == "count" and spec.expr.is_column:
+                    # COUNT(col) needs only the null mask — works on strings too.
+                    vals = mask
+                    c = segment.column(spec.expr.op)
+                    if c.nulls is not None and null_handling:
+                        mask = mask & ~cols[spec.expr.op]["nulls"]
+                else:
+                    vals, nulls = eval_expr(spec.expr, segment, cols)
+                    vals = as_row_array(vals, mask.shape)
+                    if nulls is not None and null_handling:
+                        mask = mask & ~nulls
+                if fn.needs_extra_exprs:
+                    extras = []
+                    for ex in spec.extra_exprs:
+                        ev, en = eval_expr(ex, segment, cols)
+                        extras.append(as_row_array(ev, mask.shape))
+                        if en is not None and null_handling:
+                            mask = mask & ~en
+                    vals = (vals, *extras)
+                if sfns:
+                    vals = (vals, *[mask & sf(cols, params)[0] for sf in sfns])
+                out.append((vals, mask))
+            return out
 
     def _group_key(cols, params):
         if len(group_dims) == 1 and group_dims[0].kind == "dict":
             # storage-dtype passthrough: the group kernels cast per chunk
             return cols[group_dims[0].name]["codes"]
         key = None
-        for gd in group_dims:
-            code = gd.device_code(cols, segment, jnp.int32)
-            key = code if key is None else key * np.int32(gd.cardinality) + code
+        with jax.named_scope("group_key"):
+            for gd in group_dims:
+                code = gd.device_code(cols, segment, jnp.int32)
+                key = code if key is None else key * np.int32(gd.cardinality) + code
         return key
 
     def _key_packed(cols):
@@ -1144,7 +1162,9 @@ def _build_plan(
 
         def kernel(cols, params):
             tmask, _ = filter_fn(cols, params)
-            return [fn.partial(vals, mask) for fn, (vals, mask) in zip(aggs, _agg_inputs(cols, params, tmask))]
+            inputs = _agg_inputs(cols, params, tmask)
+            with jax.named_scope("aggregate"):
+                return [fn.partial(vals, mask) for fn, (vals, mask) in zip(aggs, inputs)]
 
     mv_dims = [i for i, gd in enumerate(group_dims) if gd.mv]
     if len(mv_dims) > 1:
@@ -1245,6 +1265,9 @@ def _build_plan(
         def kernel(cols, params):
             return base_kernel(_overlay_unpacked(cols), params)
 
+    # the jitted program is named by what it is (module `jit_<kind>_<backend>`
+    # in a device trace), not `kernel`
+    kernel.__name__ = kernel.__qualname__ = f"{kind}_{scan_be}"
     fn = compiled_fn if compiled_fn is not None else jax.jit(kernel)
 
     select_columns = []

@@ -21,10 +21,15 @@ series the way promhttp would).
 from __future__ import annotations
 
 import bisect
+import collections
 import re
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+_profiling = TraceAnnotation.is_enabled  # True while a jax.profiler session records
 
 
 class Counter:
@@ -400,35 +405,138 @@ class Span:
     scanned, scan backend, retry round, breaker state, fault events) that
     ride the span instead of exploding into metric names.  `children` may
     hold Span objects or already-rendered span dicts — a server-built
-    subtree grafts into the broker trace as a dict."""
+    subtree grafts into the broker trace as a dict.
 
-    __slots__ = ("name", "start", "duration_ms", "children", "attrs")
+    A span keeps where it started (`time.perf_counter_ns`).  Opened with
+    `cpu=True` (every root, and the per-segment launch spans) it also keeps
+    the CPU time its thread used while it was open (`time.thread_time`):
+    wall less CPU is time the thread waited — for the interpreter lock, a
+    lock or the device.  Not every span: that clock is a system call, 6 us a
+    read on the TPU host against 0.1 us for the wall clock (PERF.md, PR 24)."""
 
-    def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None):
+    __slots__ = ("name", "start_ns", "duration_ms", "cpu_ms", "children", "attrs", "_cpu0")
+
+    def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None,
+                 start_ns: Optional[int] = None, cpu: bool = False):
         self.name = name
-        self.start = time.perf_counter()
+        self.start_ns = time.perf_counter_ns() if start_ns is None else start_ns
+        self._cpu0 = time.thread_time() if cpu else None
         self.duration_ms = 0.0
+        self.cpu_ms: Optional[float] = None
         self.children: List[Any] = []  # Span | dict
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
 
     def annotate(self, **kw: Any) -> None:
         self.attrs.update(kw)
 
-    def close(self) -> None:
-        self.duration_ms = (time.perf_counter() - self.start) * 1000
+    def close(self, end_ns: Optional[int] = None) -> None:
+        end_ns = time.perf_counter_ns() if end_ns is None else end_ns
+        self.duration_ms = (end_ns - self.start_ns) / 1e6
+        if self._cpu0 is not None:
+            self.cpu_ms = (time.thread_time() - self._cpu0) * 1000
 
-    def to_dict(self) -> Dict[str, Any]:
-        d: Dict[str, Any] = {"name": self.name, "ms": round(self.duration_ms, 3)}
+    def to_dict(self, root_ns: Optional[int] = None) -> Dict[str, Any]:
+        """`startMs` counts from the tree's root.  The root (root_ns None)
+        also says where it stands on the process's clock (`t0Ns`,
+        perf_counter_ns) and on which thread, so that a subtree grafted from
+        another thread or read beside a profiler trace can be placed."""
+        is_root = root_ns is None
+        if is_root:
+            root_ns = self.start_ns
+        d: Dict[str, Any] = {
+            "name": self.name,
+            "ms": round(self.duration_ms, 3),
+            "startMs": round((self.start_ns - root_ns) / 1e6, 3),
+        }
+        if self.cpu_ms is not None:
+            d["cpuMs"] = round(self.cpu_ms, 3)
+        if is_root:
+            d["t0Ns"] = self.start_ns
+            d["thread"] = threading.current_thread().name
         if self.attrs:
             d["attrs"] = dict(self.attrs)
         if self.children:
-            d["children"] = [c if isinstance(c, dict) else c.to_dict() for c in self.children]
+            d["children"] = [c if isinstance(c, dict) else c.to_dict(root_ns) for c in self.children]
         return d
 
 
+class Stage:
+    """One timed stage of a query: the context manager behind `Trace.span`
+    and `stage`.  It reads the clock once at entry and once at exit and
+    feeds three sinks: a `jax.profiler.TraceAnnotation` of the same name
+    (whenever a profiler session is recording, whether the query is traced
+    or not: the stage then sits in the trace's host plane on the device
+    trace's own clock; with no session it is one check), the trace's
+    per-query totals (always), and the span tree (when the query is
+    traced).  `ms` is readable after exit."""
+
+    __slots__ = ("trace", "name", "attrs", "cpu", "sp", "ms", "_t0", "_ann")
+
+    def __init__(self, trace: Optional["Trace"], name: str, attrs: Optional[Dict[str, Any]] = None,
+                 cpu: bool = False):
+        self.trace = trace
+        self.name = name
+        self.attrs = attrs
+        self.cpu = cpu
+
+    def __enter__(self):
+        tr = self.trace
+        self._t0 = time.perf_counter_ns()
+        self._ann = self._annotate() if _profiling() else None
+        if tr is None:
+            return self
+        if tr.enabled:
+            sp = self.sp = Span(self.name, self.attrs, start_ns=self._t0, cpu=self.cpu)
+            tr._stack[-1].children.append(sp)
+            tr._stack.append(sp)
+            return sp
+        self.sp = None
+        return None
+
+    def _annotate(self):
+        """A profiler session is recording: the stage goes into its host
+        plane under the span's name, with the query's id and entry attrs."""
+        meta = self.attrs or {}
+        if self.trace is not None and self.trace.query_id is not None:
+            meta = {"query_id": self.trace.query_id, **meta}
+        ann = TraceAnnotation(self.name, **meta)
+        ann.__enter__()
+        return ann
+
+    def __exit__(self, *exc):
+        end = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.ms = (end - self._t0) / 1e6
+        tr = self.trace
+        if tr is not None:
+            tr.totals_ns[self.name] += end - self._t0
+            if self.sp is not None:
+                self.sp.close(end)
+                tr._stack.pop()
+        return False
+
+
+def stage(name: str, **meta: Any) -> Stage:
+    """A timed, annotated stage outside any trace (the front door's HTTP
+    read and SQL parse run before the query's Trace exists); yields itself,
+    `ms` holds the time after exit."""
+    return Stage(None, name, meta or None)
+
+
+def annotate_root(tree: Optional[Dict[str, Any]], **kw: Any) -> None:
+    """Attrs onto the root of a finished span tree (what was timed before
+    the tree existed: httpReadMs, parseMs); no-op for an untraced answer."""
+    if tree is not None:
+        tree.setdefault("attrs", {}).update(kw)
+
+
 class Trace:
-    """Span-tree builder: `with trace.span("plan"): ...`; no-ops when
-    disabled so the hot path pays one attribute check.
+    """Span-tree builder: `with trace.span("plan"): ...`.  Every span is a
+    Stage: annotated for the profiler and summed into `totals_ns` whether
+    the query is traced or not; the span tree itself is built only when
+    enabled, so the untraced hot path pays two clock reads, one annotation
+    and one dict update per stage, and takes no lock.
 
     Distributed propagation: the broker mints the query id on the root span
     (`query_id=`), each server builds its own Trace (root="server:<name>")
@@ -438,33 +546,16 @@ class Trace:
 
     def __init__(self, enabled: bool = False, root: str = "query", query_id: Optional[str] = None):
         self.enabled = enabled
-        self.root = Span(root) if enabled else None
+        self.query_id = query_id
+        self.totals_ns: Dict[str, int] = collections.defaultdict(int)  # span name -> summed ns, this query
+        self.root = Span(root, cpu=True) if enabled else None
         if self.root is not None and query_id is not None:
             self.root.attrs["queryId"] = query_id
         self._stack = [self.root] if enabled else []
 
-    class _Ctx:
-        def __init__(self, trace: "Trace", name: str, attrs: Optional[Dict[str, Any]] = None):
-            self.trace = trace
-            self.name = name
-            self.attrs = attrs
-            self.sp = None
-
-        def __enter__(self):
-            if self.trace.enabled:
-                self.sp = Span(self.name, self.attrs)
-                self.trace._stack[-1].children.append(self.sp)
-                self.trace._stack.append(self.sp)
-            return self.sp
-
-        def __exit__(self, *exc):
-            if self.sp is not None:
-                self.sp.close()
-                self.trace._stack.pop()
-            return False
-
-    def span(self, name: str, **attrs: Any) -> "Trace._Ctx":
-        return Trace._Ctx(self, name, attrs or None)
+    def span(self, name: str, cpu: bool = False, **attrs: Any) -> Stage:
+        """`cpu=True`: the span also measures its thread's CPU time (`cpuMs`)."""
+        return Stage(self, name, attrs or None, cpu)
 
     def annotate(self, **kw: Any) -> None:
         """Attach attrs to the innermost open span (no-op when disabled)."""
@@ -476,6 +567,14 @@ class Trace:
         as a child of the innermost open span."""
         if self.enabled and subtree:
             self._stack[-1].children.append(subtree)
+
+    def flush(self, registry: MetricsRegistry, timers: Dict[str, str]) -> None:
+        """Once a query: what the named stages summed to, into the
+        registry's timers ({span name: timer name}); a stage that never ran
+        updates nothing."""
+        for name, timer in timers.items():
+            if name in self.totals_ns:
+                registry.timer(timer).update(self.totals_ns[name] / 1e6)
 
     def finish(self):
         if self.root is not None:

@@ -404,3 +404,200 @@ class TestExplainAnalyze:
         eng = _engine()
         with pytest.raises(SqlParseError):
             eng.query("EXPLAIN NONSENSE SELECT COUNT(*) FROM t")
+
+
+# ---------------------------------------------------------------------------
+# Stages: span starts and CPU time, the launch split, timers, annotations
+# ---------------------------------------------------------------------------
+GROUP_SQL = "SELECT city, COUNT(*), SUM(v) FROM t WHERE v > 5 GROUP BY city ORDER BY city"
+STAGE_TIMERS = {
+    "server": ["server.launchPlanMs", "server.launchShipMs", "server.launchEnqueueMs", "server.launchReleaseMs",
+               "server.collectMs"],
+    "process": ["broker.parseMs", "broker.reduceMs", "rest.readMs", "rest.serializeMs", "rest.writeMs"],
+}
+
+
+def _placed(node, base_ns=None, thread=None, out=None, parent=None):
+    """Every span as (node, start_ns, end_ns, thread, parent) on the
+    process's clock: a root (`t0Ns`) re-bases its subtree."""
+    if out is None:
+        out = []
+    if "t0Ns" in node:
+        base_ns, thread = node["t0Ns"] - node["startMs"] * 1e6, node["thread"]
+    start = base_ns + node["startMs"] * 1e6
+    me = (node, start, start + node["ms"] * 1e6, thread, parent)
+    out.append(me)
+    for c in node.get("children", []):
+        _placed(c, base_ns, thread, out, me)
+    return out
+
+
+class TestStages:
+    def _post(self, srv, sql):
+        body = json.dumps({"sql": sql}).encode()
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{srv.port}/query/sql", data=body,
+            headers={"Content-Type": "application/json"},
+        )
+        with urllib.request.urlopen(req) as r:
+            return json.loads(r.read().decode("utf-8"))
+
+    def test_to_dict_keeps_the_old_keys_and_adds_start_cpu_and_root_clock(self):
+        from pinot_tpu.utils.metrics import Trace
+
+        t = Trace(True, query_id="q_1")
+        with t.span("outer", cpu=True, segments=2):
+            with t.span("inner"):
+                sum(range(20000))
+        d = t.finish()
+        outer = d["children"][0]
+        inner = outer["children"][0]
+        assert {"name", "ms", "attrs", "children"} <= set(outer)  # as before
+        assert (outer["name"], outer["attrs"], inner["name"]) == ("outer", {"segments": 2}, "inner")
+        assert "attrs" not in inner and "children" not in inner  # still left out when empty
+        for node in (d, outer, inner):
+            assert node["startMs"] >= 0.0
+        for node in (d, outer):  # roots, and spans opened with cpu=True
+            assert 0.0 < node["cpuMs"] <= node["ms"] + 1.0  # the loop ran on this thread
+        assert "cpuMs" not in inner  # the CPU clock is a system call: only where asked for
+        assert isinstance(d["t0Ns"], int) and d["thread"] == threading.current_thread().name
+        assert "t0Ns" not in outer and "thread" not in outer  # roots only
+        assert d["startMs"] == 0.0 and outer["startMs"] <= inner["startMs"]
+
+    def test_children_lie_inside_parents_and_siblings_do_not_overlap(self):
+        coord = _cluster(n_servers=2, replication=1, n_segments=4)
+        res = Broker(coord).query("SET trace = true; " + GROUP_SQL)
+        placed = _placed(res.stats.trace)
+        assert len([p for p in placed if "t0Ns" in p[0]]) == 3  # query + two grafted server roots
+        slack = 2_000.0  # ns: startMs and ms are rounded to the microsecond
+        by_parent = {}
+        for node, start, end, thread, parent in placed:
+            if parent is None:
+                continue
+            assert parent[1] - slack <= start and end <= parent[2] + slack, (node["name"], parent[0]["name"])
+            if thread == parent[3]:
+                by_parent.setdefault(id(parent[0]), []).append((start, end, node["name"]))
+        for sibs in by_parent.values():
+            sibs.sort()
+            for (_, end_a, a), (start_b, _, b) in zip(sibs, sibs[1:]):
+                assert end_a <= start_b + slack, (a, b)
+
+    def test_launch_split_under_every_segment_miss_then_hit(self):
+        from pinot_tpu.query.planner import plan_cache_clear
+
+        coord = _cluster(n_servers=1, replication=1, n_segments=2)
+        broker = Broker(coord)
+        plan_cache_clear()  # process-wide: an earlier test may have compiled this shape
+        res = broker.query("SET trace = true; " + GROUP_SQL)
+        launches = [n for name, ns in _spans(res.stats.trace).items() if name.startswith("launch:") for n in ns]
+        assert sorted(n["name"] for n in launches) == ["launch:seg0", "launch:seg1"]
+        caches = {}
+        for n in launches:
+            kids = n["children"]
+            assert [k["name"] for k in kids] == ["launch_plan", "launch_ship", "launch_enqueue"]
+            assert all(k["attrs"]["segment"] == n["attrs"]["segment"] for k in kids)
+            assert n["attrs"]["cpuMs"] == n["cpuMs"]
+            plan, ship, enqueue = kids
+            caches[n["name"]] = plan["attrs"]["cache"]
+            assert ship["attrs"]["params"] >= 0
+            assert enqueue["attrs"]["kind"] == "groupby_dense" and enqueue["attrs"]["backend"]
+            assert [k["name"] for k in enqueue["children"]] == ["launch_release"]  # its arguments dropped
+            assert ("firstLaunch" in enqueue["attrs"]) == (plan["attrs"]["cache"] == "miss")
+            assert sum(k["ms"] for k in kids) <= n["ms"] + 0.01
+        assert caches == {"launch:seg0": "miss", "launch:seg1": "hit"}
+        first = [n for n in launches if n["name"] == "launch:seg0"][0]["children"][2]
+        assert first["attrs"]["compileMs"] > 0
+
+    def test_untraced_query_moves_launches_and_every_stage_timer_once(self):
+        coord = _cluster(n_servers=1, replication=1, n_segments=3)
+        server = coord.servers["server0"]
+        front = QueryServer(Broker(coord)).start()
+        try:
+            self._post(front, GROUP_SQL)  # compiles; the counts below are of the second query
+
+            def counts():
+                s, p = server.metrics.snapshot(), METRICS.snapshot()
+                return (s["counters"].get("server.launches", 0),
+                        {k: s["timers"][k]["count"] for k in STAGE_TIMERS["server"]},
+                        {k: p["timers"][k]["count"] for k in STAGE_TIMERS["process"]})
+
+            before = counts()
+            resp = self._post(front, GROUP_SQL)
+            assert resp["trace"] is None
+            deadline = threading.Event()
+            for _ in range(100):  # the write timer is updated after the client has its answer
+                after = counts()
+                if after[2]["rest.writeMs"] > before[2]["rest.writeMs"]:
+                    break
+                deadline.wait(0.01)
+        finally:
+            front.stop()
+        assert after[0] - before[0] == 3
+        assert {k: after[1][k] - before[1][k] for k in after[1]} == dict.fromkeys(STAGE_TIMERS["server"], 1)
+        assert {k: after[2][k] - before[2][k] for k in after[2]} == dict.fromkeys(STAGE_TIMERS["process"], 1)
+        assert "server.kernelBytes" not in server.metrics.snapshot()["counters"]  # removed: nothing read it
+
+    def test_traced_answer_carries_front_door_attrs_and_equals_the_untraced(self):
+        coord = _cluster(n_servers=2, replication=1, n_segments=4)
+        front = QueryServer(Broker(coord)).start()
+        try:
+            plain = self._post(front, GROUP_SQL)
+            traced = self._post(front, "SET trace = true; " + GROUP_SQL)
+        finally:
+            front.stop()
+        assert plain["trace"] is None and traced["trace"]["name"] == "query"
+        assert traced["resultTable"] == plain["resultTable"] and len(plain["resultTable"]["rows"]) == 3
+        for key in ("numDocsScanned", "numSegmentsQueried", "numSegmentsProcessed", "totalDocs"):
+            assert traced[key] == plain[key]
+        attrs = traced["trace"]["attrs"]
+        assert attrs["parseMs"] > 0 and attrs["httpReadMs"] > 0
+        roots = [n for ns in _spans(traced["trace"]).values() for n in ns if n["name"].startswith("server:")]
+        assert [r["attrs"]["queryId"] for r in roots] == [traced["requestId"]] * 2  # the broker's id travels
+
+    def test_query_inside_a_profiler_session_leaves_annotations_in_the_host_plane(self, tmp_path):
+        """The sandbox's profiler records on the CPU, so this reads a real
+        trace.  Its own time limit: the session runs on a side thread that
+        is given 120 s."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        coord = _cluster(n_servers=1, replication=1, n_segments=2)
+        broker = Broker(coord)
+        broker.query(GROUP_SQL)  # compile outside the session
+        got = {}
+
+        def session():
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+            try:
+                got["res"] = broker.query(GROUP_SQL)  # untraced: annotations need no SET trace
+            finally:
+                jax.profiler.stop_trace()
+
+        worker = threading.Thread(target=session, daemon=True)
+        worker.start()
+        worker.join(120.0)
+        assert not worker.is_alive(), "the profiler session did not end inside its time limit"
+        assert got["res"].stats.trace is None
+        paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        assert paths, "the profiler wrote no trace"
+        events = [
+            (e.name, dict(e.stats))
+            for plane in ProfileData.from_file(paths[0]).planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+        ]
+        names = {n for n, _ in events}
+        for want in ("sql_parse", "plan", "prune", "scatter", "round:0", "server_execute", "dispatch",
+                     "launch:seg0", "launch:seg1", "launch_plan", "launch_ship", "launch_enqueue",
+                     "launch_release", "collect", "reduce"):
+            assert want in names, want
+        assert "device_wait" not in names  # the fence is the traced path's alone
+        qid = got["res"].stats.query_id
+        launches = [st for n, st in events if n.startswith("launch:")]
+        assert [st["query_id"] for st in launches] == [qid, qid]
+        assert sorted(st["segment"] for st in launches) == ["seg0", "seg1"]
+        parts = [st for n, st in events if n in ("launch_plan", "launch_ship", "launch_enqueue")]
+        assert len(parts) == 6 and {st["query_id"] for st in parts} == {qid}

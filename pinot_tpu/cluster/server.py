@@ -181,7 +181,10 @@ class ServerInstance:
         Tracing (ctx option `trace`): builds a per-server span subtree —
         dispatch (per segment a launch:<segment> span over the executor's
         launch_plan / launch_ship / launch_enqueue, the last ending in
-        launch_release), device_wait (ONE block_until_ready over every
+        launch_release: launch_ship is the resident columns' lookup alone,
+        the query's parameters ride the jitted call inside launch_enqueue as
+        host numpy and run on self.device, and launch_release has nothing
+        left to drop), device_wait (ONE block_until_ready over every
         pending output: the device-compute share the async dispatch
         hides), then per-segment collect spans —
         annotated with segments/docs/backend and any fault-plan events, and

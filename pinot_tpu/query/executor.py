@@ -12,6 +12,7 @@ launch, and the post-kernel decode (dense group table -> present keys, the
 sparse-groupby host fallback, selection row gather)."""
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -110,12 +111,15 @@ def launch_segment(
     block streaming.
 
     The stages are spans of `trace` (the caller's, under its
-    `launch:<segment>` span): launch_plan (star-tree probe + plan cache),
-    launch_ship (columns and parameters to the device), launch_enqueue (the
-    jitted call; it names the plan when it had to compile), which ends with
-    its child launch_release (the call's parameter arrays are dropped)."""
-    import jax
-
+    `launch:<segment>` span): launch_plan (star-tree probe + plan cache);
+    launch_ship (the plan's columns looked up in, or staged into, the
+    device's cache — nothing else: no device array is made for a parameter;
+    attr paramArrays counts the host buffers that carry them, one per dtype,
+    planner.pack_params); launch_enqueue (the jitted call, the launch's one
+    trip into the runtime that carries data: those buffers ride it as
+    arguments; it names the plan when it had to compile), which ends with
+    its child launch_release (the launch holds no device array of its own,
+    so there is nothing to drop: it times an empty block)."""
     from pinot_tpu.query.startree import try_startree
 
     trace = trace if trace is not None else Trace()
@@ -134,22 +138,25 @@ def launch_segment(
         total_docs=segment.num_docs,
     )
     stats.filter_index_uses = tuple(plan.index_uses)
-    with trace.span("launch_ship", segment=segment.name, params=len(plan.params)):
+    with trace.span("launch_ship", segment=segment.name, params=len(plan.param_layout)) as ssp:
         cols = segment.to_device(
             device=device, columns=plan.needed_columns, packed_codes=True,
             residency=residency,
         )
-        params = {k: jax.device_put(v, device) for k, v in plan.params.items()}
+        if ssp is not None:
+            ssp.annotate(paramArrays=len(plan.params))
     first_launch = plan.cost is None
     if first_launch:
         # cost model captured ONCE per cached plan (hits copy it forward in
         # plan_segment); racing first launches both capture — idempotent
-        plan.cost = _capture_cost(plan, segment, cols, params)
+        plan.cost = _capture_cost(plan, segment, cols, plan.params, device)
     with trace.span(
         "launch_enqueue", segment=segment.name, kind=plan.kind, backend=plan.cache_key[2]
     ) as esp:
         t0 = time.perf_counter()
-        out = plan.fn(cols, params)  # async dispatch; device_get happens at collect
+        with _placed_on(device):
+            # async dispatch; device_get happens at collect
+            out = plan.fn(cols, plan.params)
         if first_launch:
             # first jit dispatch pays trace+compile before enqueueing — its wall
             # time IS the compile cost (AOT compile would pay it a second time)
@@ -158,29 +165,40 @@ def launch_segment(
             if esp is not None:
                 esp.annotate(firstLaunch=True, compileMs=round(stats.compile_ms, 3))
         with trace.span("launch_release", segment=segment.name):
-            # the parameter arrays lived for this call only: dropping them
-            # here, inside a span, keeps what their release costs (one trip
-            # through the runtime an array) out of the launch's untimed tail
-            del params
+            pass  # nothing of the launch's own to drop (see the docstring)
     stats.kernel_bytes = plan.cost.bytes_accessed
     stats.kernel_flops = plan.cost.flops
     stats.kernel_cost_source = plan.cost.source
     return ("pending", ctx, segment, plan, out, stats)
 
 
-def _capture_cost(plan, segment: ImmutableSegment, cols, params):
+def _placed_on(device):
+    """Where a jitted call runs when no committed argument says.  Parameters
+    are host numpy (uncommitted), and the kernel may read no column at all
+    (COUNT(*) over an upsert segment reads `__valid__` only; unused
+    arguments pin nothing), so the caller's device is made the call's
+    default: thread-local, no transfer.  Every call and lowering of a
+    `plan.fn` goes through here, so one plan sees one argument form and one
+    trace context."""
+    import jax
+
+    return jax.default_device(device) if device is not None else contextlib.nullcontext()
+
+
+def _capture_cost(plan, segment: ImmutableSegment, cols, params, device=None):
     """The single-lane cost model of a plan's first launch (utils/perf.py)."""
-    return perf.capture_cost(
-        plan.fn,
-        (cols, params),
-        perf.analytic_cost(
-            segment.num_docs,
-            perf.analytic_bytes_per_row(segment.column(n) for n in plan.needed_columns),
-            kind=plan.kind,
-            num_groups=plan.num_groups,
-            num_entries=len(plan.aggs),
-        ),
-    )
+    with _placed_on(device):
+        return perf.capture_cost(
+            plan.fn,
+            (cols, params),
+            perf.analytic_cost(
+                segment.num_docs,
+                perf.analytic_bytes_per_row(segment.column(n) for n in plan.needed_columns),
+                kind=plan.kind,
+                num_groups=plan.num_groups,
+                num_entries=len(plan.aggs),
+            ),
+        )
 
 
 def pending_outputs(states) -> list:
@@ -321,7 +339,10 @@ def launch_segment_batch(
     Raises BatchShapeError when members don't resolve to one compiled plan
     (callers fall back to per-member launches).  Star-tree shortcuts are
     intentionally not taken here — members were vetted as batchable by the
-    broker before coalescing.  Same spans as launch_segment."""
+    broker before coalescing.  Same spans as launch_segment: launch_ship is
+    the columns plus one host-side np.stack per packed buffer of the members'
+    parameters (they ride the vmapped call as host numpy), launch_release an
+    empty block."""
     import jax
 
     n = len(ctxs)
@@ -342,27 +363,21 @@ def launch_segment_batch(
     if n > width:
         raise BatchShapeError(f"batch of {n} exceeds lane width {width}")
 
-    shared_keys = frozenset(k for k in base.params if k == "__valid__")
+    shared_keys = frozenset(base.params.keys() & {planner.VALID_KEY})
     params_list = [p.params for p in plans]
     if n < width:
         params_list = params_list + [plans[-1].params] * (width - n)
-    with trace.span("launch_ship", segment=segment.name, params=len(base.params)):
+    with trace.span("launch_ship", segment=segment.name, params=len(base.param_layout)) as ssp:
         cols = segment.to_device(
             device=device, columns=base.needed_columns, packed_codes=True,
             residency=residency,
         )
-        stacked = {}
-        for k, v0 in base.params.items():
-            if k in shared_keys:
-                stacked[k] = jax.device_put(v0, device)
-            else:
-                stacked[k] = jax.device_put(
-                    jax.tree_util.tree_map(
-                        lambda *xs: np.stack([np.asarray(x) for x in xs]),
-                        *(pl[k] for pl in params_list),
-                    ),
-                    device,
-                )
+        stacked = {
+            k: v0 if k in shared_keys else np.stack([pl[k] for pl in params_list])
+            for k, v0 in base.params.items()
+        }
+        if ssp is not None:
+            ssp.annotate(paramArrays=len(stacked))
 
     key = (base.cache_key or id(base.fn), width, shared_keys)
     cache = _batch_fn_cache()
@@ -379,21 +394,21 @@ def launch_segment_batch(
     if base.cost is None:
         # same single-lane cost model as launch_segment, so per-member
         # shares divide the identical numbers an unbatched run reports
-        single = {k: jax.device_put(v, device) for k, v in base.params.items()}
-        base.cost = _capture_cost(base, segment, cols, single)
+        base.cost = _capture_cost(base, segment, cols, base.params, device)
     with trace.span(
         "launch_enqueue", segment=segment.name, kind=base.kind, backend=base.cache_key[2],
         members=n,
     ) as esp:
         t0 = time.perf_counter()
-        out = fnb(cols, stacked)  # async dispatch; one device_get at collect
+        with _placed_on(device):
+            out = fnb(cols, stacked)  # async dispatch; one device_get at collect
         # deliberately times the dispatch: the first vmapped call pays
         # trace+compile inline, and THAT is the cost being recorded
         compile_ms = (time.perf_counter() - t0) * 1000.0 if first_batched else 0.0  # pinot-lint: disable=W017
         if first_batched and esp is not None:
             esp.annotate(firstLaunch=True, compileMs=round(compile_ms + base.cost.lower_ms, 3))
         with trace.span("launch_release", segment=segment.name):
-            del stacked  # as in launch_segment
+            pass  # as in launch_segment
 
     docs = segment.num_docs
     share, rem = divmod(docs, n)

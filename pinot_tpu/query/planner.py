@@ -23,6 +23,7 @@ replaced by a Pallas hash table.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -40,6 +41,7 @@ from pinot_tpu.query.functions import (
     get_agg_function,
 )
 from pinot_tpu.query.ir import AggregationSpec, Expr, QueryContext
+from pinot_tpu.query.shape import column_info_from, params_structure
 from pinot_tpu.query.transform import as_row_array, eval_expr
 from pinot_tpu.segment.segment import ImmutableSegment
 from pinot_tpu.spi.schema import DataType
@@ -131,8 +133,14 @@ def decode_packed_keys(group_dims: List["GroupDim"], packed: np.ndarray) -> List
 class SegmentPlan:
     kind: str  # "aggregation" | "groupby_dense" | "groupby_sparse" | "selection"
     fn: Callable  # jitted kernel(cols, params)
-    params: Dict[str, Any]
+    # what fn takes: the query's parameters packed into one host numpy
+    # buffer per dtype (pack_params), so a launch's call carries 1-3 small
+    # arrays however many predicates the query has
+    params: Dict[str, np.ndarray]
     needed_columns: List[str]
+    # (key, dtype, shape) of every parameter the kernel reads, sorted: the
+    # packed buffers' layout, and what a plan-cache hit must match
+    param_layout: Tuple = ()
     aggs: List[AggFunction] = field(default_factory=list)
     group_dims: List[GroupDim] = field(default_factory=list)
     num_groups: int = 0
@@ -152,6 +160,47 @@ class SegmentPlan:
     # whether plan_segment took the compiled fn from the plan cache (the
     # `cache` attr of the launch_plan span)
     cache_hit: bool = False
+
+
+# Upsert validDocIds ride beside the packed buffers, not in them: the mask
+# is the segment's state (bool[num_docs], shared by every member of a batched
+# launch), not a literal of the query.
+VALID_KEY = "__valid__"
+
+
+def pack_params(params: Dict[str, Any], layout: Tuple) -> Dict[str, np.ndarray]:
+    """The FilterCompiler's parameters (numpy scalars and small tables, one
+    per key) as one flat host buffer per dtype, in `layout`'s order.  Every
+    argument of a jitted call is a transfer of its own, and on a busy host
+    each costs a trip through the runtime: a Q1 query's six int32 bounds
+    travel as one int32[6]."""
+    groups: Dict[str, List[Any]] = {}
+    for key, dtype, _ in layout:
+        if key != VALID_KEY:
+            groups.setdefault(dtype, []).append(params[key])
+    packed = {
+        dtype: np.concatenate([np.ravel(v) for v in vals]) for dtype, vals in groups.items()
+    }
+    if VALID_KEY in params:
+        packed[VALID_KEY] = params[VALID_KEY]
+    return packed
+
+
+def unpack_params(packed: Dict[str, Any], layout: Tuple) -> Dict[str, Any]:
+    """pack_params undone: the dict the kernel reads.  Runs inside the jitted
+    program (static slices of the traced buffers); works on the host
+    buffers too."""
+    out: Dict[str, Any] = {}
+    offsets: Dict[str, int] = {}
+    for key, dtype, shape in layout:
+        if key == VALID_KEY:
+            out[key] = packed[key]
+            continue
+        at = offsets.get(dtype, 0)
+        size = math.prod(shape)
+        offsets[dtype] = at + size
+        out[key] = packed[dtype][at] if shape == () else packed[dtype][at : at + size].reshape(shape)
+    return out
 
 
 # jit cache: (query SHAPE fingerprint, segment signature, backend) -> plan.
@@ -956,8 +1005,6 @@ def plan_segment(ctx: QueryContext, segment: ImmutableSegment) -> SegmentPlan:
     from pinot_tpu.analysis.compile_audit import SSE_AUDIT
     from pinot_tpu.analysis.plan_check import check_plan_cached
 
-    from pinot_tpu.query.shape import column_info_from, params_structure
-
     # static IR validation before anything traces: malformed plans raise
     # structured PlanCheckError here instead of a tracer error inside jit
     check_plan_cached(ctx)
@@ -977,7 +1024,7 @@ def plan_segment(ctx: QueryContext, segment: ImmutableSegment) -> SegmentPlan:
         # safety net under the shape audit — a mismatch would silently
         # retrace, so it counts (and compiles) as a miss instead.
         plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn)
-        if params_structure(plan.params) == params_structure(cached.params):
+        if plan.param_layout == cached.param_layout:
             # cost model rides the cache entry: captured once at the first
             # launch of the cached plan, never re-lowered on hits
             plan.cost = cached.cost
@@ -1008,12 +1055,12 @@ def _build_plan(
     # queries apply without recompiling; presence is part of the plan-cache
     # signature (_segment_signature) since the kernel must consume it.
     if segment.valid_docs is not None:
-        fc.params["__valid__"] = np.asarray(segment.valid_docs, dtype=bool)
+        fc.params[VALID_KEY] = np.asarray(segment.valid_docs, dtype=bool)
         base_filter_fn = filter_fn
 
         def filter_fn(cols, params):
             t, nl = base_filter_fn(cols, params)
-            v = params["__valid__"]
+            v = params[VALID_KEY]
             return t & v, (nl & v if nl is not None else None)
 
     # Device-trace names (HLO op_name metadata only; nothing computes
@@ -1265,6 +1312,14 @@ def _build_plan(
         def kernel(cols, params):
             return base_kernel(_overlay_unpacked(cols), params)
 
+    # the plan.fn boundary: the call carries the packed buffers, the program
+    # slices them back into the dict the kernel reads
+    param_layout = params_structure(fc.params)
+    dict_kernel = kernel
+
+    def kernel(cols, packed):
+        return dict_kernel(cols, unpack_params(packed, param_layout))
+
     # the jitted program is named by what it is (module `jit_<kind>_<backend>`
     # in a device trace), not `kernel`
     kernel.__name__ = kernel.__qualname__ = f"{kind}_{scan_be}"
@@ -1292,8 +1347,9 @@ def _build_plan(
     return SegmentPlan(
         kind=kind,
         fn=fn,
-        params=fc.params,
+        params=pack_params(fc.params, param_layout),
         needed_columns=needed,
+        param_layout=param_layout,
         aggs=aggs,
         group_dims=group_dims,
         num_groups=num_groups,

@@ -94,7 +94,8 @@ def test_indexed_filter_column_not_shipped(env):
     assert "city" not in plan.needed_columns
     assert "v" in plan.needed_columns
     # bitmap words param shipped instead: ceil(N/32) uint32 words
-    bits_params = [v for k, v in plan.params.items() if k.endswith(".bits")]
+    params = planner.unpack_params(plan.params, plan.param_layout)  # what the kernel reads
+    bits_params = [v for k, v in params.items() if k.endswith(".bits")]
     assert len(bits_params) == 1 and bits_params[0].dtype == np.uint32
     assert bits_params[0].shape[0] == -(-N // 32)
 
@@ -107,7 +108,8 @@ def test_sorted_range_zero_reads(env):
     plan = planner.plan_segment(ctx, seg)
     assert ("day", "sorted") in plan.index_uses
     assert "day" not in plan.needed_columns
-    assert all(np.asarray(v).size <= 1 for v in plan.params.values())
+    assert all(shape == () for _, _, shape in plan.param_layout)
+    assert {k: v.shape for k, v in plan.params.items()} == {"int32": (2,)}  # the doc range, packed
 
 
 def test_index_nulls_respected():

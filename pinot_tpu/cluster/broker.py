@@ -654,6 +654,7 @@ class Broker:
         exclude: frozenset = frozenset(),
         partial_ok: bool = False,
         prefer_group: Optional[int] = None,
+        seen: Optional[Dict] = None,
     ):
         """segment list -> {server: [segments]} picking ONE live replica per
         segment (InstanceSelector contract).
@@ -667,7 +668,10 @@ class Broker:
         selector only) starts the group rotation at that replica group —
         the batched scatter path uses it to pin a whole batch to one mesh
         replica row; a dead/partial preferred group still falls through the
-        rotation, so it's a preference, never an availability constraint."""
+        rotation, so it's a preference, never an availability constraint.
+        `seen` (a traced query's `route` span passes one) is given
+        `minReplicas`: the fewest live candidates any segment had (not by
+        the strict replica-group pick, which takes a whole group or none)."""
         view = self.coordinator.external_view(table)
         healthy = {
             s for s in self.coordinator.live if s not in exclude and self.health.available(s)
@@ -709,6 +713,8 @@ class Broker:
                     unroutable.append(seg)
                     continue
                 raise NoReplicaAvailableError(f"segment {table}/{seg} has no live replica")
+            if seen is not None:
+                seen["minReplicas"] = min(len(candidates), seen.get("minReplicas", len(candidates)))
             # gray-failure weighting: prefer non-browned replicas, but a
             # fully-browned candidate set still serves (availability wins,
             # exactly like breaker quarantine above)
@@ -1769,10 +1775,16 @@ class Broker:
         numServersResponded < numServersQueried); otherwise the query fails
         with the collected per-server exceptions.
 
-        Tracing: each failover round gets a `round:N` span; each routed call
-        a `server_execute` span (server, round, probe, error, breaker state)
-        with the server's own finished subtree grafted beneath it — the
-        retry/breaker machinery is visible in ONE tree per query.
+        Tracing: each failover round gets a `round:N` span holding a `route`
+        span (selector, segments, servers routed, the largest segment list,
+        the fewest live replicas a segment had); each routed call a
+        `server_execute` span (server, round, probe, error, breaker state,
+        the answering server's replica group) with the server's own finished
+        subtree grafted beneath it — the retry/breaker machinery is visible
+        in ONE tree per query.  The span this runs under (`scatter`) is given
+        the distinct servers that answered and the rounds run.  Always on:
+        `broker.scatter.serverCalls` counts routed calls and
+        `broker.routedSegments.<server>` the segments routed to each server.
 
         Governance faults are NOT server faults: a ReservationError (server
         at HBM capacity) fails the segments over to another replica without
@@ -1796,15 +1808,26 @@ class Broker:
         responded: Set[str] = set()
         pending = list(seg_names)
         rounds = 0
+        rounds_run = 0
+        warming: List[threading.Thread] = []  # peers compiling this query's program
         killed = False  # watchdog kill absorbed as a partial result
         capacity_rejections = 0  # ReservationError count this scatter
         non_capacity_failure = False  # any genuine server fault seen
         try:
             while pending:
+                rounds_run += 1
                 with trace.span(f"round:{rounds}", segments=len(pending)):
-                    assign, unroutable = self._route(
-                        table, pending, exclude=frozenset(excluded), partial_ok=True
-                    )
+                    with trace.span("route", selector=self.selector, segments=len(pending)) as rsp:
+                        seen = None if rsp is None else {}
+                        assign, unroutable = self._route(
+                            table, pending, exclude=frozenset(excluded), partial_ok=True, seen=seen
+                        )
+                        if rsp is not None:
+                            rsp.annotate(
+                                servers=len(assign),
+                                maxPerServer=max(map(len, assign.values()), default=0),
+                                **seen,
+                            )
                     if unroutable:
                         if capacity_rejections and not non_capacity_failure and not allow_partial:
                             # every replica was excluded for CAPACITY, not
@@ -1818,8 +1841,12 @@ class Broker:
                         self._absorb_unroutable(table, unroutable, excluded, allow_partial, stats)
                     failed: List[str] = []
                     for server_name, segs in assign.items():
+                        if warming:
+                            self._join_warming(warming)
                         deadline.check(f"query on {table}")
                         queried.add(server_name)
+                        METRICS.counter("broker.scatter.serverCalls").inc()
+                        METRICS.counter(f"broker.routedSegments.{server_name}").inc(len(segs))
                         probe = self.health.state(server_name) == "half_open"
                         self.health.begin_probe(server_name)  # no-op unless half-open
                         per_call = deadline.bounded(
@@ -1835,6 +1862,9 @@ class Broker:
                             return srv.execute(
                                 ctx, _segs, table_schema=meta.schema,
                                 deadline=_per_call, cancel=comp, query_id=qid,
+                                on_first_launch=lambda: self._warm_peers(
+                                    ctx, table, name, seg_names, meta, warming
+                                ),
                             )
 
                         with trace.span(
@@ -1917,7 +1947,11 @@ class Broker:
                             # the winner may be the hedged backup, not the
                             # routed primary: success accounting keys on it
                             self.health.record_success(winner)
-                            transition = self.health.note_latency(winner, win_ms)
+                            # less what its first launches of a plan took to
+                            # compile: that is the plan's cost, not the
+                            # server's pace, and a server that had more of
+                            # them must not read as a gray failure
+                            transition = self.health.note_latency(winner, win_ms - sstats.compile_ms)
                             if transition is not None:
                                 stats.brownout_events.append(f"{transition}:{winner}")
                                 if ssp is not None:
@@ -1943,7 +1977,10 @@ class Broker:
                             stats.add_kernel_cost(sstats)
                             trace.graft(sstats.trace)
                             if ssp is not None:
-                                ssp.annotate(docs=sstats.num_docs_scanned)
+                                ssp.annotate(
+                                    docs=sstats.num_docs_scanned,
+                                    replicaGroup=self.coordinator.replica_group.get(winner),
+                                )
                 pending = failed
                 if killed:
                     break  # partial-result kill: no failover for what's left
@@ -1979,9 +2016,54 @@ class Broker:
                             / 1000.0
                         )
         finally:
+            if warming:
+                self._join_warming(warming)
             stats.num_servers_queried = len(queried)
             stats.num_servers_responded = len(responded)
+            if trace.enabled:  # onto the caller's `scatter` span
+                trace.annotate(servers=len(responded), rounds=rounds_run)
         return results
+
+    def _warm_peers(self, ctx: QueryContext, table: str, cold: str, seg_names: List[str], meta, warming: List) -> None:
+        """Server `cold` is about to compile this query's program for its
+        device.  A jitted program compiles once for EVERY device it runs on,
+        and the scatter calls servers in turn, so a query shape new to a
+        table on N chips would compile N times one after another (four chips:
+        a first query of minutes, beyond a client's patience).  So the
+        table's other live servers that sit on another device compile it at
+        the same time, each on one of its own segments of this query
+        (`ServerInstance.warm`, `warm-<server>` threads appended to
+        `warming`): the scatter waits for them before it calls the next
+        server, and before it returns.  Once a query; servers that share the
+        cold server's device (or have none) need nothing."""
+        if warming:
+            return
+        servers = self.coordinator.servers
+        device = servers[cold].device
+        view = self.coordinator.external_view(table)
+        one: Dict[str, str] = {}  # peer -> one of its segments of this query
+        for seg in seg_names:
+            for peer in view.get(seg, ()):
+                if peer != cold and peer not in one and servers[peer].device != device:
+                    one[peer] = seg
+
+        def warm(peer: str, seg: str) -> None:
+            try:
+                servers[peer].warm(ctx, seg, table_schema=meta.schema)
+            except Exception:  # noqa: BLE001 — the scatter's own call meets the fault and accounts for it
+                METRICS.counter("broker.peerWarmupFailures").inc()
+
+        for peer, seg in one.items():
+            METRICS.counter("broker.peerWarmups").inc()
+            t = threading.Thread(target=warm, args=(peer, seg), daemon=True, name=f"warm-{peer}")
+            warming.append(t)
+            t.start()
+
+    @staticmethod
+    def _join_warming(warming: List) -> None:
+        for t in warming:
+            t.join()
+        warming.clear()
 
     def _absorb_unroutable(
         self,

@@ -165,9 +165,13 @@ class ServerInstance:
         cancel=None,
         source: str = "broker",
         query_id: Optional[str] = None,
+        on_first_launch=None,
     ):
         """Run one query over the named LOCAL segments; returns
         (segment results, stats) — the DataTable the reference ships back.
+
+        `on_first_launch`: zero-arg hook, called when a launch is about to
+        compile its program for this server's device (executor.launch_segment).
 
         `cancel`: optional zero-arg probe (the broker watchdog's closure)
         returning a kill reason or None — checked between kernels alongside
@@ -284,7 +288,7 @@ class ServerInstance:
                     with trace.span(f"launch:{seg.name}", cpu=True, segment=seg.name) as lsp:
                         st = executor.launch_segment(
                             ctx, seg, device=self.device, residency=self.residency,
-                            trace=trace,
+                            trace=trace, on_first_launch=on_first_launch,
                         )
                         pending.append(st)
                     if lsp is not None:
@@ -354,6 +358,25 @@ class ServerInstance:
         finally:
             if ticket is not None:
                 self.budget.release(ticket)
+
+    def warm(self, ctx: QueryContext, seg_name: str, table_schema=None) -> None:
+        """Compile, for this server's device, the program `ctx` runs over the
+        named local segment, by running it once and dropping the answer.  Not
+        a served call: no fault plan, no budget, no query counters; only the
+        compile is recorded (`server.compileMs`), as a served first launch's
+        is.  The broker calls it on a table's other servers while one of them
+        compiles the same program (Broker._scatter)."""
+        from pinot_tpu.query.planner import _needed_columns
+
+        seg = self.get_segment(ctx.table, seg_name)
+        if seg is None:
+            return
+        if table_schema is not None:
+            seg.ensure_columns(table_schema, _needed_columns(ctx, seg))
+        state = executor.launch_segment(ctx, seg, device=self.device, residency=self.residency)
+        _, stats = executor.collect_segment(state)
+        if stats.compile_ms > 0:
+            self.metrics.timer("server.compileMs").update(stats.compile_ms)
 
     def execute_batch(
         self,

@@ -98,7 +98,7 @@ def prune_segment(ctx: QueryContext, segment: ImmutableSegment) -> bool:
 # ---------------------------------------------------------------------------
 def launch_segment(
     ctx: QueryContext, segment: ImmutableSegment, device=None, residency=None,
-    trace: Optional[Trace] = None,
+    trace: Optional[Trace] = None, on_first_launch=None,
 ):
     """Phase 1 of pipelined execution: plan, ship inputs, and DISPATCH the
     segment kernel (jax dispatch is asynchronous — the call returns as soon
@@ -117,9 +117,15 @@ def launch_segment(
     attr paramArrays counts the host buffers that carry them, one per dtype,
     planner.pack_params); launch_enqueue (the jitted call, the launch's one
     trip into the runtime that carries data: those buffers ride it as
-    arguments; it names the plan when it had to compile), which ends with
+    arguments; it names the plan when it had to compile: the plan's first
+    launch on this device), which ends with
     its child launch_release (the launch holds no device array of its own,
-    so there is nothing to drop: it times an empty block)."""
+    so there is nothing to drop: it times an empty block).
+
+    `on_first_launch` (zero-arg) is called just before the jitted call when
+    that call will compile, i.e. the plan has not run on this device yet:
+    the broker uses it to start the same compile on the table's other
+    servers' devices while this one runs."""
     from pinot_tpu.query.startree import try_startree
 
     trace = trace if trace is not None else Trace()
@@ -150,6 +156,11 @@ def launch_segment(
         # cost model captured ONCE per cached plan (hits copy it forward in
         # plan_segment); racing first launches both capture — idempotent
         plan.cost = _capture_cost(plan, segment, cols, plan.params, device)
+    # the program compiles once for every device it runs on: each server's
+    # chip pays its own first launch of a plan another server has compiled
+    first_on_device = device not in plan.cost.launched_on
+    if first_on_device and on_first_launch is not None:
+        on_first_launch()
     with trace.span(
         "launch_enqueue", segment=segment.name, kind=plan.kind, backend=plan.cache_key[2]
     ) as esp:
@@ -157,11 +168,14 @@ def launch_segment(
         with _placed_on(device):
             # async dispatch; device_get happens at collect
             out = plan.fn(cols, plan.params)
-        if first_launch:
+        if first_on_device:
             # first jit dispatch pays trace+compile before enqueueing — its wall
             # time IS the compile cost (AOT compile would pay it a second time)
-            plan.cost.compile_ms = (time.perf_counter() - t0) * 1000.0
-            stats.compile_ms = plan.cost.compile_ms + plan.cost.lower_ms
+            plan.cost.launched_on.add(device)
+            stats.compile_ms = (time.perf_counter() - t0) * 1000.0
+            if first_launch:
+                plan.cost.compile_ms = stats.compile_ms
+                stats.compile_ms += plan.cost.lower_ms
             if esp is not None:
                 esp.annotate(firstLaunch=True, compileMs=round(stats.compile_ms, 3))
         with trace.span("launch_release", segment=segment.name):

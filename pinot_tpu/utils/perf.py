@@ -56,7 +56,10 @@ class KernelCost:
     (trace+compile happen inside the first jit call; XLA's AOT compile path
     would pay compilation twice and pin the executable to one device, so we
     never use it here).  `lower_ms` is the StableHLO lowering wall time when
-    the XLA source ran, 0 for the analytic path.
+    the XLA source ran, 0 for the analytic path.  `launched_on` holds the
+    devices the plan has been dispatched on: a jitted program compiles anew
+    for every device it first runs on (and the persistent cache keys an
+    entry by its device too), so "first launch" is a fact of (plan, device).
     """
 
     flops: float = 0.0
@@ -65,6 +68,7 @@ class KernelCost:
     source: str = "analytic"  # "xla" | "analytic"
     lower_ms: float = 0.0
     compile_ms: float = 0.0
+    launched_on: set = field(default_factory=set)
 
     def to_dict(self) -> Dict[str, Any]:
         return {

@@ -129,8 +129,10 @@ packed-word operand (an identifier matching `packed`/`word`) WITHOUT a
 `>>` lane-unpack anywhere in that receiver expression widens the packed
 words to full dtype before the predicate/accumulate — spilling the
 register-resident unpack back into a full-width HBM intermediate, which
-forfeits the bandwidth the packing bought.  Shift first (`_lane_unpack`),
-then cast the unpacked lanes.
+forfeits the bandwidth the packing bought.  Shift first (the kernel's
+`key_row` for a block-planar forward index, `_lane_unpack` for interleaved
+bitmap words), then cast the unpacked lanes.  The rule is about the order
+of shift and cast and holds for either lane layout.
 
 W021 guards the tiered-storage staging contract (segment/residency.py): a
 `jax.device_put(...)` whose shipped argument references a SEGMENT-SIZED

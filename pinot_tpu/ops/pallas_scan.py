@@ -49,14 +49,16 @@ import numpy as np
 from jax import lax
 
 from pinot_tpu.ops import segmented as _seg
+from pinot_tpu.segment import packing
 
 from jax.experimental import pallas as pl
 
 # Rows per grid step.  XLA tiles a 1-D 32-bit HBM array by 1024 elements and
 # a kernel block must be whole tiles, so the narrowest packed operand — the
 # range-index bitmap, 32 rows per word — sets the floor: 1024 words = 2^15
-# rows.
-_TILE = 1 << 15
+# rows.  It is also one block of a bit-packed forward index, so a packed
+# key's tile holds each lane's rows as one run of whole sublane rows.
+_TILE = packing.BLOCK_ROWS
 # Rows per in-kernel chunk: the [Hp, chunk] / [W, chunk] one-hot working set
 # (Hp <= 128 sublanes) stays a few MB under the 16MB VMEM budget.
 _TILE_CHUNK = 4096
@@ -138,13 +140,13 @@ def pallas_supported(entries, num_groups: int) -> bool:
 
 
 def _lane_unpack(w, bits: int):
-    """In-register lane unpack: a (1, rows * bits // 32) row of int32 words
-    -> a (1, rows) row of int32 lanes, lane l of word i covering row
-    i * (32 // bits) + l.
+    """In-register unpack of INTERLEAVED lanes: a (1, rows * bits // 32) row
+    of int32 words -> a (1, rows) row of int32 lanes, lane l of word i
+    covering row i * (32 // bits) + l.
 
-    The shared primitive behind BOTH packed operand kinds: range-index
-    bitmap words are the bits=1 case (one bool per lane), bit-packed
-    forward indexes (segment/packing.py) the bits=4/8/16 case.  Each word is
+    That is the range-index bitmap's layout (bits=1, one bool per lane:
+    query/filter.eval_bitmap); a bit-packed forward index is block-planar
+    and needs no lane shuffle (_scan_chunk's key_row).  Each word is
     repeated along the lane axis and shifted against a lane iota, so the
     array stays 2-D throughout (Mosaic refuses the rank-changing reshape a
     [words, lanes] -> [rows] unpack needs) — the packed word tile is the
@@ -181,10 +183,11 @@ def fused_group_tables_pallas(
     mask IN-REGISTER, so the row-length bool mask never exists in HBM.
     code_pred: optional (codes_array, lo, hi) dictionary-code range
     predicate, likewise fused.  codes_packed: optional (words, code_bits)
-    bit-packed forward index of the key column (segment/packing.py lanes);
-    the kernel streams the uint32 word tiles — a 32/code_bits-factor
-    super-tile of rows per word tile — and lane-unpacks in-register, so
-    the key's HBM traffic is its PACKED byte count.  Returns
+    bit-packed forward index of the key column (segment/packing.py: whole
+    blocks, so the words already cover the padded row count); the kernel
+    streams the uint32 word tiles, one block a tile, and shifts each
+    chunk's codes out of its lane in-register, so the key's HBM traffic is
+    its PACKED byte count.  Returns
     f64[num_groups] tables in entry order, bit-identical to the XLA path
     (both are exact integer sums).
 
@@ -225,11 +228,10 @@ def fused_group_tables_pallas(
     if codes_packed is not None:
         kw, key_bits = codes_packed
         key_bits = int(key_bits)
-        key_factor = 32 // key_bits
-        if n % key_factor or int(kw.shape[0]) != n // key_factor:
-            raise ValueError("codes_packed rows must be lane-aligned with codes")
+        if int(kw.shape[0]) != packing.packed_words(n, key_bits):
+            raise ValueError("codes_packed must be the whole blocks of its rows")
         inputs.append(_as_i32_words(kw))
-        rows_per.append(key_factor)
+        rows_per.append(32 // key_bits)
     else:
         inputs.append(codes)
         rows_per.append(1)
@@ -298,8 +300,10 @@ def fused_group_tables_pallas(
     with jax.named_scope("scan_operands"):
         if n % T:
             # padding carries mask=False / zero words, so it contributes nothing
-            pad = n_tiles * T - n
-            inputs = [jnp.pad(a, (0, pad // f)) for a, f in zip(inputs, rows_per)]
+            # (a packed key's words are whole blocks already: nothing to add)
+            inputs = [
+                jnp.pad(a, (0, n_tiles * T // f - a.shape[0])) for a, f in zip(inputs, rows_per)
+            ]
         # packed words ride as [words / 128, 128]: for a 32-bit array that is the
         # same bytes as the 1-D HBM tiling, and it makes the words of chunk c
         # whole sublane rows (a 1-D block can only be sliced by 1024s)
@@ -343,21 +347,30 @@ def fused_group_tables_pallas(
             start = pl.multiple_of(c * np.int32(C), C)
             return refs[ix][pl.ds(start, C)].astype(i32)[None, :]
 
-        def unpacked_row(ix, bits: int):
-            # the chunk's C * bits / 32 packed words are whole sublane rows
-            # of the [.., 128] word block; laid end to end they are the
-            # (1, words) row _lane_unpack widens to (1, C)
-            r = C * bits // 32 // 128
-            w = refs[ix][pl.ds(c * np.int32(r), r), :]
-            return _lane_unpack(
-                jnp.concatenate([w[k:k + 1, :] for k in range(r)], axis=1), bits
-            )
+        def word_rows(ix, first, r: int):
+            # r whole sublane rows of the [.., 128] word block, laid end to
+            # end as a (1, r * 128) row
+            w = refs[ix][pl.ds(first, r), :]
+            return jnp.concatenate([w[k:k + 1, :] for k in range(r)], axis=1)
+
+        def key_row(bits: int):
+            # block-planar lanes (segment/packing.py): the tile is one
+            # block, lane l of its words holds rows [l, l + 1) * T / f, so
+            # the chunk's C codes sit in ONE lane of C whole words: a shift
+            # of C / 128 sublane rows, no shuffle along lanes
+            per = np.int32(T * bits // 32 // C)  # chunks per lane run
+            lane, part = lax.div(c, per), lax.rem(c, per)
+            w = word_rows(0, part * np.int32(C // 128), C // 128)
+            shifted = lax.shift_right_logical(w, lane * np.int32(bits))
+            return shifted & np.int32((1 << bits) - 1)
 
         with jax.named_scope("lane_unpack"):
-            ki = unpacked_row(0, key_bits) if key_bits is not None else row(0)
+            ki = key_row(key_bits) if key_bits is not None else row(0)
             base = None
             if words_ix is not None:
-                base = unpacked_row(words_ix, 1) != zero
+                # the chunk's C / 32 bitmap words, interleaved lanes
+                r = C // 32 // 128
+                base = _lane_unpack(word_rows(words_ix, c * np.int32(r), r), 1) != zero
         if pred_plan is not None:
             with jax.named_scope("predicate"):
                 p_ix, plo, phi = pred_plan

@@ -57,6 +57,7 @@ from pinot_tpu.query.result import (
     SelectionSegmentResult,
 )
 from pinot_tpu.query.transform import as_row_array, eval_expr
+from pinot_tpu.segment import packing
 from pinot_tpu.utils import perf
 from pinot_tpu.utils.metrics import METRICS
 
@@ -624,7 +625,8 @@ class DistributedEngine:
         that alone exceeds a v5e chip.  Splitting the doc axis into B
         host-level launches caps the copy at one batch's bytes; the combine
         across launches is group-table-sized (never row-length).  Batch
-        width is 32-aligned so index bitmap words slice cleanly; a ragged
+        width is 32-aligned so index bitmap words slice cleanly (and whole
+        lane blocks where the table's shards are); a ragged
         tail re-launches the last full-width window with already-covered
         rows masked via the `fresh` offset (same trick as
         ops/segmented._fused_scan_inchunk)."""
@@ -655,7 +657,10 @@ class DistributedEngine:
         n_batches = max(1, -(-per_dev // self.launch_bytes))
         if n_batches == 1 or D < 64:
             return D, ((0, 0),)
-        batch_docs = min(D, -(-(-(-D // n_batches)) // 32) * 32)
+        # a table of whole lane blocks a shard (StackedTable.build) is cut at
+        # block bounds, so every batch's dictionary columns ship bit-packed
+        align = packing.BLOCK_ROWS if D % packing.BLOCK_ROWS == 0 else 32
+        batch_docs = min(D, -(-(-(-D // n_batches)) // align) * align)
         offsets = []
         off = 0
         while off + batch_docs <= D:
@@ -755,7 +760,9 @@ class DistributedEngine:
         # uint32 lane words under "codes_packed" instead of the unpacked
         # codes; every kernel sees an overlay that adds trace-level unpacked
         # "codes" (XLA dedups/DCEs; the Pallas fused path additionally gets
-        # the raw words via key_packed and unpacks in-register).
+        # the raw words via key_packed and unpacks in-register).  A slice
+        # ships packed only as whole lane blocks a shard, so a device's
+        # shards laid end to end are whole blocks too.
         packed_meta: Dict[str, int] = {
             name: int(c.code_bits)
             for name, c in stacked.columns.items()
@@ -764,8 +771,6 @@ class DistributedEngine:
         }
 
         def _flat(cols, _rows=local_rows):
-            from pinot_tpu.segment import packing
-
             out = flatten_cols(cols)
             for name, bits in packed_meta.items():
                 e = out.get(name)

@@ -20,6 +20,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from pinot_tpu.segment import packing
 from pinot_tpu.segment.dictionary import Dictionary, min_code_dtype
 from pinot_tpu.segment.segment import ColumnData, ImmutableSegment
 from pinot_tpu.segment.stats import ColumnStats
@@ -42,8 +43,10 @@ class StackedColumn:
     mv_lengths: Optional[np.ndarray] = None
     # bit-packed forward index (segment/packing.py layout): codes in
     # `code_bits`-wide lanes inside uint32 words, [S, D * code_bits / 32].
-    # D is 32-aligned so no word straddles a shard boundary.  None when the
-    # cardinality needs >16 bits (stored unpacked) or the column is MV.
+    # Only where D is whole lane blocks, so no block straddles a shard
+    # boundary.  None otherwise (such a table ships its codes unpacked),
+    # when the cardinality needs >16 bits (stored unpacked) or the column
+    # is MV.
     code_bits: Optional[int] = None
     packed: Optional[np.ndarray] = None
 
@@ -189,9 +192,14 @@ class StackedTable:
         names = schema.column_names
         n = len(data[names[0]]) if names else 0
         # 32-align docs_per_shard: per-device row counts stay multiples of 32
-        # so index bitmap words split cleanly across devices
+        # so index bitmap words split cleanly across devices.  Dictionary
+        # columns ship bit-packed only from shards of whole lane blocks, so
+        # a shard is rounded up to them where that pads it by at most 1/16
         D = -(-n // num_shards)  # ceil
         D = -(-D // 32) * 32
+        whole = -(-D // packing.BLOCK_ROWS) * packing.BLOCK_ROWS
+        if whole - D <= D // 16:
+            D = whole
         total = num_shards * D
 
         # sorted column: physically sort rows (the sorted "index" IS the
@@ -223,18 +231,16 @@ class StackedTable:
                 padded_nulls[:n] = nmask
                 padded_nulls = padded_nulls.reshape(num_shards, D)
             if use_dict:
-                from pinot_tpu.segment import packing
-
                 dictionary, codes32 = Dictionary.build(f.data_type, arr)
                 codes = np.zeros(total, dtype=min_code_dtype(dictionary.cardinality))
                 codes[:n] = codes32.astype(codes.dtype)
                 stats = collect_stats(f.name, f.data_type, arr, nmask, dictionary.cardinality, True)
                 bits = packing.lane_bits(dictionary.cardinality)
-                # D is 32-aligned, so packing the flat codes and reshaping
-                # never straddles a shard boundary with one word
+                # a shard of whole lane blocks packs on its own axis, and a
+                # device's shards laid end to end are still whole blocks
                 packed = (
-                    packing.pack_codes(codes, bits).reshape(num_shards, -1)
-                    if bits < 32
+                    packing.pack_codes(codes.reshape(num_shards, D), bits)
+                    if bits < 32 and D % packing.BLOCK_ROWS == 0
                     else None
                 )
                 columns[f.name] = StackedColumn(
@@ -245,7 +251,7 @@ class StackedTable:
                     None,
                     padded_nulls,
                     stats,
-                    code_bits=bits if bits < 32 else None,
+                    code_bits=bits if packed is not None else None,
                     packed=packed,
                 )
                 card = dictionary.cardinality
@@ -350,13 +356,14 @@ class StackedTable:
 
     # -- device residency ----------------------------------------------
     def _use_packed(self, c: StackedColumn, sl, packed_codes: bool) -> bool:
-        # packed shipping needs lane-aligned doc offsets (macro-batch
-        # offsets are 32-aligned by _batching, so this always holds there)
+        # a doc slice ships packed only as whole lane blocks (the engine's
+        # _batching cuts a packed table's batches there); any other slice
+        # ships the unpacked codes
         return bool(
             packed_codes
             and c.packed is not None
-            and sl[0] % (32 // c.code_bits) == 0
-            and sl[1] % (32 // c.code_bits) == 0
+            and sl[0] % packing.BLOCK_ROWS == 0
+            and sl[1] % packing.BLOCK_ROWS == 0
         )
 
     @staticmethod

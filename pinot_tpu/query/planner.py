@@ -1198,7 +1198,7 @@ def _build_plan(
         if gd.kind != "dict" or gd.mv:
             return None
         bits = packed_meta.get(gd.name)
-        if not bits or num_docs % (32 // bits):
+        if not bits:
             return None
         e = cols.get(gd.name)
         if e is None or "codes_packed" not in e:

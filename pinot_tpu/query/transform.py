@@ -63,8 +63,8 @@ _UNARY = {
 }
 
 
-# see column_values: the padded lane view a materialized unpack may hold
-_DECODE_BARRIER_MAX_BYTES = 1 << 30
+# see column_values: the bytes a materialized unpack may hold (2^24 rows)
+_DECODE_BARRIER_MAX_BYTES = 1 << 26
 
 
 def column_values(name: str, segment: ImmutableSegment, cols: Dict) -> EvalResult:
@@ -86,13 +86,13 @@ def column_values(name: str, segment: ImmutableSegment, cols: Dict) -> EvalResul
             # `codes` is the trace-level lane unpack of the packed words.  A
             # 1-D gather whose indices are that unpack FUSED IN compiles
             # pathologically on XLA's TPU backend (AOT for a described v5e,
-            # 1.5M rows: 44 s at 4-bit lanes, 104 s at 8, 241 s at 16; ~1 s
-            # behind a barrier).  Materializing the unpack has its own
-            # price: XLA holds the [words, lanes] view tile-padded to 128
-            # lanes, 512 B per word — fine for a segment, 16 GB at 2^27 rows
-            # of 8-bit lanes — so only segment-sized decodes take the barrier.
-            padded_bytes = codes.shape[0] * bits // 32 * 512
-            if padded_bytes <= _DECODE_BARRIER_MAX_BYTES:
+            # 1.5M rows of interleaved lanes, PR 22: 44 s at 4-bit lanes, 104
+            # s at 8, 241 s at 16; ~1 s behind a barrier).  Materializing
+            # the unpack writes the int32 codes once, 4 B a row (6 MB for a
+            # 1.5M-row segment; the interleaved layout's [words, lanes] view
+            # was held tile-padded at 512 B a word, 384 MB) — fine for a
+            # segment, so only segment-sized decodes take the barrier.
+            if codes.shape[0] * 4 <= _DECODE_BARRIER_MAX_BYTES:
                 codes = jax.lax.optimization_barrier(codes)
         vals = entry["dict"][codes]
     nulls = entry.get("nulls")

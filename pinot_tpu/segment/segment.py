@@ -29,9 +29,11 @@ from pinot_tpu.segment.stats import ColumnStats
 from pinot_tpu.spi.schema import DataType, Schema
 
 # Bumped when build-time encoding changes shape (v2: bit-packed forward
-# indexes).  Segments carry it in meta; absent/v1 segments have no
-# `codeBits` column attribute and load through the raw path unchanged.
-BUILDER_VERSION = 2
+# indexes; v3: their lanes block-planar, stamped per column by
+# packing.LAYOUT_KEY).  Segments carry it in meta; absent/v1 segments have no
+# `codeBits` column attribute and load through the raw path unchanged; a v2
+# segment's interleaved lane words are re-packed once at load.
+BUILDER_VERSION = 3
 
 
 @dataclass
@@ -370,8 +372,9 @@ class ImmutableSegment:
         for c in self.columns.values():
             if c.dictionary is not None:
                 regions.extend(c.dictionary.to_regions(c.name))
-                # packed columns persist the lane words; codes are
-                # rematerialized at load via packing.unpack_codes
+                # packed columns persist the lane words (block-planar, the
+                # layout stamped below); codes are rematerialized at load
+                # via packing.unpack_codes
                 regions.append((f"{c.name}.fwd", c.packed if c.packed is not None else c.codes))
             else:
                 regions.append((f"{c.name}.fwd", c.values))
@@ -386,6 +389,7 @@ class ImmutableSegment:
             }
             if c.packed is not None:
                 cm["codeBits"] = int(c.code_bits)
+                cm[packing.LAYOUT_KEY] = packing.BLOCK_ROWS
             col_meta.append(cm)
         for kind, by_col in self.indexes.items():
             for cname, idx in by_col.items():
@@ -434,10 +438,21 @@ class ImmutableSegment:
                 packed = None
                 codes = fwd
                 if bits and bits < 32:
-                    packed = np.asarray(fwd)
-                    codes = packing.unpack_codes(
-                        packed, bits, num_docs, dtype=min_code_dtype(dictionary.cardinality)
-                    )
+                    code_dtype = min_code_dtype(dictionary.cardinality)
+                    block_rows = cm.get(packing.LAYOUT_KEY)
+                    if block_rows is None:
+                        # written before the layout stamp: interleaved lanes,
+                        # re-packed once here so every reader sees one layout
+                        codes = packing.unpack_interleaved(fwd, bits, num_docs, dtype=code_dtype)
+                        packed = packing.pack_codes(codes, bits)
+                    elif block_rows != packing.BLOCK_ROWS:
+                        raise ValueError(
+                            f"segment {path!r} column {name!r}: lane blocks of {block_rows} "
+                            f"rows, this build reads {packing.BLOCK_ROWS}"
+                        )
+                    else:
+                        packed = np.asarray(fwd)
+                        codes = packing.unpack_codes(packed, bits, num_docs, dtype=code_dtype)
                 columns[name] = ColumnData(
                     name, dt, dictionary, codes, None, nulls, stats,
                     mv_lengths=mv_lengths, code_bits=bits, packed=packed,

@@ -10,7 +10,7 @@ entry points a client uses:
   kernels  ten kernel-level exactness checks against numpy (signed int32 /
            int64 limb sums, bitmap unpack, sparse tables, macro-batched
            range-index group-by, sketches, MV explode).
-  load     the lineorder-shaped table bench.py defines, made from --seed
+  load     a lineorder-shaped table (make_data below), made from --seed
            (--rows, default 2^25: see DEFAULT_ROWS for the cut from 2^27),
            built as 1.5M-row segments -> Coordinator.add_segment -> one
            ServerInstance on the chip, columns resident in HBM (packed).
@@ -43,7 +43,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-HEADLINE_ROWS = 1 << 27  # the repo's headline size (bench.py N_ROWS)
+HEADLINE_ROWS = 1 << 27  # the repo's headline size
 # The default is cut to 2^25, the floor, for two measured reasons (PR 22):
 # time — the sparse query (d), with all 1.32M groups tracked so its answer is
 # exact, runs ~2.1 us/row served and ~0.6 us/row stacked on one v5e, and the
@@ -76,7 +76,7 @@ QUERIES: Dict[str, str] = {
     ),
     # 4-bit lanes
     "b": f"SELECT COUNT(*) FROM {TABLE} WHERE lo_discount = 7",
-    # bench.py's agg_bound: three aggregates share one scan
+    # three aggregates share one scan
     "c": (
         f"SELECT lo_orderdate, COUNT(*), SUM(lo_revenue), AVG(lo_quantity) "
         f"FROM {TABLE} GROUP BY lo_orderdate LIMIT 2500"
@@ -119,9 +119,9 @@ class Reporter:
 # data and the plain reference
 # ---------------------------------------------------------------------------
 def make_data(rows: int, seed: int) -> Dict[str, np.ndarray]:
-    """bench.py's lineorder columns (same draw order, so --seed 42 is its
-    table): orderdate card 2406 -> 16-bit lanes, quantity card 50 with a
-    range index, discount card 11 -> 4-bit lanes, revenue int64."""
+    """Four lineorder columns: orderdate card 2406 -> 16-bit lanes, quantity
+    card 50 with a range index, discount card 11 -> 4-bit lanes, revenue
+    int64."""
     rng = np.random.default_rng(seed)
     return {
         "lo_orderdate": (19920101 + rng.integers(0, OD_CARD, rows)).astype(np.int32),

@@ -21,8 +21,8 @@ only *overrides*, swapped in as one immutable dict, so
 
 ``Autopilot`` — a sim-clock-friendly controller on a fixed tick
 (injectable clock, utils/threads primitives so the deterministic
-scheduler can drive it).  It reads the PerfLedger windows (admitted p99,
-roofline %, plan-cache hit rate, QPS), hedge/brownout counters, and
+scheduler can drive it).  It reads the ShapeStats windows (admitted p99,
+plan-cache hit rate, QPS), hedge/brownout counters, and
 ResourceBudget high-water marks, and moves AT MOST ONE knob per tick
 along a fixed degradation ladder:
 
@@ -307,7 +307,7 @@ class Autopilot:
         history: int = 64,
     ):
         self.registry = registry if registry is not None else knobs()
-        self.ledger = ledger if ledger is not None else perf.PERF_LEDGER
+        self.ledger = ledger if ledger is not None else perf.SHAPE_STATS
         self.governor = governor
         self.clock = clock if clock is not None else threads.monotonic
         self.tick_s = (
@@ -346,14 +346,13 @@ class Autopilot:
 
     # -- signal plane ----------------------------------------------------
     def _signals(self) -> Tuple[Dict[str, Any], Dict[str, Any]]:
-        """Read the feedback signal: PerfLedger windows, hedge/brownout
+        """Read the feedback signal: ShapeStats windows, hedge/brownout
         counters, budget high-water marks.  Telemetry failures degrade to
         an idle signal — the controller holds rather than dies."""
         tables: Dict[str, Any] = {}
         worst_p99: Optional[float] = None
         qps_total = 0.0
         hit_rates: List[float] = []
-        roof = 0.0
         try:
             snap = self.ledger.snapshot()
             for tname, t in snap.get("tables", {}).items():
@@ -366,9 +365,6 @@ class Autopilot:
                     hr = shape.get("planCacheHitRate")
                     if hr is not None:
                         hit_rates.append(float(hr))
-                    rf = (shape.get("rooflinePct", {}) or {}).get("mean")
-                    if rf:
-                        roof = max(roof, float(rf))
                 tqps = float(t.get("qps", 0.0))
                 qps_total += tqps
                 tables[tname] = {
@@ -388,7 +384,6 @@ class Autopilot:
             "planCacheHitRate": (
                 round(sum(hit_rates) / len(hit_rates), 3) if hit_rates else None
             ),
-            "rooflinePct": round(roof, 3),
             "hedgesLaunched": METRICS.counter("broker.hedgesLaunched").value,
             "hedgesDenied": METRICS.counter("broker.hedgesDenied").value,
             "pressureLevel": METRICS.gauge("admission.pressureLevel").value,

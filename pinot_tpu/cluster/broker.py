@@ -621,8 +621,8 @@ class Broker:
 
     def attach_autopilot(self, controller=None, start: bool = False):
         """Wire an SLO autopilot to this broker (replacing any previous
-        one).  Default construction feeds it the process PerfLedger and this
-        broker's governor; `start` launches the fixed-tick thread."""
+        one).  Default construction feeds it the process ShapeStats window and
+        this broker's governor; `start` launches the fixed-tick thread."""
         from pinot_tpu.cluster import autopilot as autopilot_mod
 
         old = self.autopilot
@@ -1035,7 +1035,7 @@ class Broker:
         stats: ExecutionStats,
     ) -> ResultTable:
         """Reduce + response stamping + result-cache populate + latency and
-        PerfLedger accounting — the tail every served query (sync or batch
+        ShapeStats accounting — the tail every served query (sync or batch
         member) runs through."""
         with trace.span("reduce"):
             out = reduce_mod.reduce_results(ctx, results, stats)
@@ -1057,7 +1057,7 @@ class Broker:
         from pinot_tpu.query.shape import shape_digest
         from pinot_tpu.utils import perf
 
-        perf.PERF_LEDGER.record(
+        perf.SHAPE_STATS.record(
             table,
             shape_digest(ctx.shape_fingerprint()),
             rows=out.stats.num_docs_scanned,
@@ -2118,12 +2118,12 @@ class Broker:
         }
 
     def perf_snapshot(self):
-        """Per-table/per-shape perf ledger view (GET /debug/perf), plus the
+        """Per-table/per-shape stats-window view (GET /debug/perf), plus the
         live named-cache occupancy (plan caches, result cache)."""
         from pinot_tpu.utils.cache import named_cache_stats
-        from pinot_tpu.utils.perf import PERF_LEDGER
+        from pinot_tpu.utils.perf import SHAPE_STATS
 
-        snap = PERF_LEDGER.snapshot()
+        snap = SHAPE_STATS.snapshot()
         snap["caches"] = named_cache_stats()
         return snap
 

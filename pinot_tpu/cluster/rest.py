@@ -128,16 +128,16 @@ class QueryServer:
                             return
                         self._send(200, gov.snapshot())
                     elif url.path == "/debug/perf":
-                        # per-table/per-shape perf ledger (utils/perf.py):
-                        # rolling rows/s, bytes/s, roofline %, compile ms,
+                        # per-table/per-shape stats window (utils/perf.py):
+                        # rolling rows/s, bytes/s, compile ms,
                         # plan-cache outcomes, QPS — the `cli perf` source
                         snap_fn = getattr(outer.engine, "perf_snapshot", None)
                         if snap_fn is not None:
                             self._send(200, snap_fn())
                         else:
-                            from pinot_tpu.utils.perf import PERF_LEDGER
+                            from pinot_tpu.utils.perf import SHAPE_STATS
 
-                            self._send(200, PERF_LEDGER.snapshot())
+                            self._send(200, SHAPE_STATS.snapshot())
                     elif url.path == "/debug/autopilot":
                         # SLO autopilot view: knob values vs clamp bounds,
                         # last N controller decisions with triggering signal,

@@ -29,7 +29,6 @@ from pinot_tpu.query.ir import QueryContext
 from pinot_tpu.query.result import ExecutionStats
 from pinot_tpu.query.safety import Deadline, QueryTimeoutError, estimate_segment_bytes
 from pinot_tpu.segment.segment import ImmutableSegment
-from pinot_tpu.utils import perf
 from pinot_tpu.utils.metrics import METRICS, MetricsRegistry
 
 # span name -> this server's timer: one update a query, the sum over its segments
@@ -296,12 +295,8 @@ class ServerInstance:
                         # rest is waiting (interpreter lock, a lock, the device)
                         lsp.annotate(cpuMs=round(lsp.cpu_ms, 3))
                         if st[0] == "pending":
-                            # per-operator cost model for EXPLAIN ANALYZE
-                            lsp.annotate(
-                                kernelBytes=st[5].kernel_bytes,
-                                kernelFlops=st[5].kernel_flops,
-                                costSource=st[5].kernel_cost_source,
-                            )
+                            # EXPLAIN ANALYZE's Bytes
+                            lsp.annotate(kernelBytes=st[5].kernel_bytes)
                 if dsp is not None:
                     dsp.annotate(launches=len(pending))
             if trace.enabled:
@@ -309,21 +304,12 @@ class ServerInstance:
                 # (trace-only — the untraced path lets collect's device_get be
                 # the fence so cancellation stays responsive between collects)
                 import jax
-                import time as _time
 
-                pend_bytes = sum(
-                    s[5].kernel_bytes for s in pending if s[0] == "pending"
-                )
-                tw = _time.perf_counter()
                 with trace.span("device_wait", launches=len(pending)) as wsp:
                     jax.block_until_ready(executor.pending_outputs(pending))
-                wait_s = _time.perf_counter() - tw
-                stats.device_ms = wait_s * 1000.0
                 if wsp is not None:
-                    roof = perf.roofline_pct(pend_bytes, wait_s)
                     wsp.annotate(
-                        kernelBytes=pend_bytes,
-                        **({"rooflinePct": round(roof, 2)} if roof is not None else {}),
+                        kernelBytes=sum(s[5].kernel_bytes for s in pending if s[0] == "pending")
                     )
             for i, st in enumerate(pending):
                 self._check_budget(deadline, cancelled=len(pending) - i, cancel=cancel)
@@ -402,7 +388,7 @@ class ServerInstance:
         per-member execution through the normal failover machinery.
 
         Stats attribution: each segment's scanned docs and kernel
-        bytes/flops divide across the members that actually scanned it
+        bytes divide across the members that actually scanned it
         (pruned members are excluded from the division), so summing member
         stats reproduces one unbatched run — never N duplicated copies.
 
@@ -524,17 +510,11 @@ class ServerInstance:
                     dsp.annotate(launches=len(pending))
             if trace.enabled:
                 import jax
-                import time as _time
 
-                tw = _time.perf_counter()
                 with trace.span("device_wait", launches=len(pending)):
                     jax.block_until_ready(
                         executor.pending_outputs([p[0] for p in pending])
                     )
-                wait_ms = (_time.perf_counter() - tw) * 1000.0
-                live = [i for i in range(n) if errors[i] is None]
-                for i in live:
-                    stats[i].device_ms = wait_ms / max(1, len(live))
             for st, members in pending:
                 self._probe_members(deadlines, cancels, errors, only=members)
                 alive = [i for i in members if errors[i] is None]

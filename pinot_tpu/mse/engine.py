@@ -30,7 +30,7 @@ both sides outside the ON clause) raise JoinPlanError/NotImplementedError.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -64,6 +64,7 @@ from pinot_tpu.query.result import (
     SelectionSegmentResult,
 )
 from pinot_tpu.spi.schema import DataType
+from pinot_tpu.utils.perf import scan_bytes_per_row
 from types import SimpleNamespace
 
 _INT_KEY_TYPES = (DataType.INT, DataType.LONG, DataType.TIMESTAMP, DataType.BOOLEAN)
@@ -158,9 +159,14 @@ class _MsePlan:
     select_columns: List[str] = None
     joins_info: List[Tuple[str, str]] = None
     dup_idx: Optional[int] = None
-    # kernel cost model (utils/perf.KernelCost), captured at first dispatch
-    # and shared through the plan cache (hits copy it forward)
-    cost: Optional[Any] = None
+    # bytes the fact-side scan must read (utils/perf.scan_bytes_per_row x
+    # the fact table's rows; dim tables are broadcast-small by strategy),
+    # counted when the plan-cache entry is built
+    scan_bytes: float = 0.0
+    # wall ms of the program's first call (trace + compile), empty until it
+    # ran: the list is the plan-cache entry's, shared with the plan every
+    # hit builds
+    first_call_ms: List[float] = field(default_factory=list)
     # shuffle bucket slack this plan's kernel was TRACED with (cap_f bakes
     # into the program, so slack is part of the plan-cache key); the
     # overflow back-pressure loop doubles it and re-plans
@@ -275,7 +281,7 @@ class MultiStageEngine:
         from pinot_tpu.query.shape import shape_digest
         from pinot_tpu.utils import perf
 
-        perf.PERF_LEDGER.record(
+        perf.SHAPE_STATS.record(
             rq.fact,
             shape_digest(getattr(self, "_last_shape_fp", "")),
             rows=out.stats.num_docs_scanned,
@@ -346,9 +352,8 @@ class MultiStageEngine:
                 params_structure(plan.params) == params_structure(cached.params)
                 and plan.sharded_by_ns == cached.sharded_by_ns
             ):
-                # cost model rides the cache entry (captured once at the
-                # cached plan's first dispatch, never re-lowered on hits)
-                plan.cost = cached.cost
+                plan.scan_bytes = cached.scan_bytes
+                plan.first_call_ms = cached.first_call_ms
                 MSE_AUDIT.record_hit(key[0])
                 self._last_plan_cache_hit = True
                 self._last_shape_fp = key[0]
@@ -357,6 +362,10 @@ class MultiStageEngine:
         self._last_plan_cache_hit = False
         self._last_shape_fp = key[0]
         plan = self._build_plan(rq, strategy, slack)
+        fact_st = self.tables[rq.fact]
+        plan.scan_bytes = fact_st.num_docs * scan_bytes_per_row(
+            fact_st.column(n) for n in plan.fact_needed
+        )
         self._plan_cache.put(key, plan)
         return plan
 
@@ -1008,35 +1017,16 @@ class MultiStageEngine:
 
     # ------------------------------------------------------------------
     def _run(self, ctx, plan: _MsePlan, fact_cols, fact_valid, dim_cols, dim_valids, params, stats):
-        from pinot_tpu.utils import perf
-
-        first_dispatch = plan.cost is None
-        if first_dispatch:
-            # fact-side scan dominates the byte traffic; dim tables are
-            # broadcast-small by strategy, so the analytic model reads the
-            # fact columns only (the XLA source covers everything)
-            fact_st = self.tables[plan.rq.fact]
-            plan.cost = perf.capture_cost(
-                plan.fn,
-                (fact_cols, fact_valid, dim_cols, dim_valids, params),
-                perf.analytic_cost(
-                    fact_st.num_docs,
-                    perf.analytic_bytes_per_row(
-                        fact_st.column(n) for n in plan.fact_needed
-                    ),
-                    kind=plan.kind,
-                    num_groups=plan.num_groups,
-                    num_entries=len(plan.aggs) if plan.aggs else 1,
-                ),
-            )
+        first_call = not plan.first_call_ms
         td0 = time.perf_counter()
         out, overflow = plan.fn(fact_cols, fact_valid, dim_cols, dim_valids, params)
-        if first_dispatch:
-            plan.cost.compile_ms = (time.perf_counter() - td0) * 1000.0
-            stats.compile_ms += plan.cost.compile_ms + plan.cost.lower_ms
-        stats.kernel_bytes += plan.cost.bytes_accessed
-        stats.kernel_flops += plan.cost.flops
-        stats.kernel_cost_source = plan.cost.source
+        if first_call:
+            # the first jit dispatch pays trace+compile; its wall time is the
+            # compile cost this query actually paid
+            compile_ms = (time.perf_counter() - td0) * 1000.0
+            plan.first_call_ms.append(compile_ms)
+            stats.compile_ms += compile_ms
+        stats.kernel_bytes += plan.scan_bytes
         overflow = int(jax.device_get(overflow))
         if overflow:
             # execute()'s back-pressure loop catches this, doubles the slack

@@ -109,17 +109,6 @@ def scan_backend() -> str:
     return forced
 
 
-def matmul_flops_per_row(num_groups: int, num_entries: int) -> float:
-    """Analytic flop estimate for the group-accumulate path, per scanned
-    row: the fused kernel (Pallas and the dense XLA fallback alike)
-    accumulates each row into the group table via a one-hot matmul, i.e. a
-    multiply+add against every group slot for every agg entry — 2·G·E
-    flops/row.  Predicate masks and bitmap ANDs are O(1)/row noise next to
-    the G-wide accumulate, so they are deliberately not modeled.  Used by
-    utils.perf.analytic_cost when XLA cost_analysis is unavailable."""
-    return 2.0 * float(max(1, num_groups)) * float(max(1, num_entries))
-
-
 def pallas_supported(entries, num_groups: int) -> bool:
     """Can fused_group_tables_pallas compute these entries exactly?
 

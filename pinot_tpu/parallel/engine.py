@@ -29,7 +29,7 @@ DataTable/Netty have no analog here by design: the wire format between
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from types import SimpleNamespace
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -194,9 +194,14 @@ class _DistPlan:
     # jitted device-side cross-launch merge for the sparse group-by path
     # (ops.merge_sparse_tables); None falls back to the host numpy merge
     sparse_merge_fn: Optional[Callable] = None
-    # per-LAUNCH kernel cost model (utils/perf.KernelCost), captured at the
-    # first dispatch and shared through the plan cache (hits copy it)
-    cost: Optional[Any] = None
+    # bytes ONE launch must read (utils/perf.scan_bytes_per_row x the rows a
+    # launch covers; every macro-batch shares the shape), counted when the
+    # plan-cache entry is built
+    scan_bytes: float = 0.0
+    # wall ms of the program's first call (trace + compile), empty until it
+    # ran: the list is the plan-cache entry's, shared with the plan every
+    # hit builds
+    first_call_ms: List[float] = field(default_factory=list)
 
 
 class DistributedEngine:
@@ -374,7 +379,7 @@ class DistributedEngine:
         METRICS.histogram("dist.queryLatency").update(out.stats.time_ms)
         from pinot_tpu.query.shape import shape_digest
 
-        perf.PERF_LEDGER.record(
+        perf.SHAPE_STATS.record(
             ctx.table,
             shape_digest(self._last_shape_fp),
             rows=out.stats.num_docs_scanned,
@@ -471,20 +476,6 @@ class DistributedEngine:
                 sse_executor.BATCH_AUDIT.record_compile()
             else:
                 sse_executor.BATCH_AUDIT.record_hit()
-            if base.cost is None:
-                base.cost = perf.capture_cost(
-                    base.fn,
-                    (cols, dev_params),
-                    perf.analytic_cost(
-                        stacked.num_shards * base.batch_docs,
-                        perf.analytic_bytes_per_row(
-                            stacked.column(nm) for nm in base.needed_columns
-                        ),
-                        kind=base.kind,
-                        num_groups=base.num_groups,
-                        num_entries=len(base.aggs),
-                    ),
-                )
             td0 = _time.perf_counter()
             host = jax.device_get(fnb(cols, stacked_params))
             compile_ms = (_time.perf_counter() - td0) * 1000.0 if first_batched else 0.0
@@ -502,10 +493,7 @@ class DistributedEngine:
                 total_docs=stacked.num_docs,
             )
             stats.add_index_uses(plan.index_uses)
-            if base.cost is not None:
-                stats.kernel_bytes = base.cost.bytes_accessed / n
-                stats.kernel_flops = base.cost.flops / n
-                stats.kernel_cost_source = base.cost.source
+            stats.kernel_bytes = base.scan_bytes / n
             if i == 0 and compile_ms:
                 stats.compile_ms = compile_ms
             if base.kind == "aggregation":
@@ -537,7 +525,7 @@ class DistributedEngine:
             out.stats.time_ms = (_time.perf_counter() - t0) * 1000
             METRICS.counter("dist.queries").inc()
             METRICS.histogram("dist.queryLatency").update(out.stats.time_ms)
-            perf.PERF_LEDGER.record(
+            perf.SHAPE_STATS.record(
                 ctx.table,
                 shape_digest(self._last_shape_fp),
                 rows=out.stats.num_docs_scanned,
@@ -605,15 +593,18 @@ class DistributedEngine:
                 params_structure(plan.params) == params_structure(cached.params)
                 and plan.row_sharded_params == cached.row_sharded_params
             ):
-                # cost model rides the cache entry — captured at the cached
-                # plan's first dispatch, never re-lowered on hits
-                plan.cost = cached.cost
+                plan.scan_bytes = cached.scan_bytes
+                plan.first_call_ms = cached.first_call_ms
                 DIST_AUDIT.record_hit(key[0])
                 self._last_plan_cache_hit = True
                 return plan
         DIST_AUDIT.record_compile(key[0])
         self._last_plan_cache_hit = False
         plan = self._build_plan(ctx, stacked, batch_docs, batch_offsets)
+        plan.scan_bytes = stacked.num_shards * batch_docs * perf.scan_bytes_per_row(
+            (stacked.column(n) for n in plan.needed_columns),
+            bitmap_params=len(plan.row_sharded_params),
+        )
         self._plan_cache.put(key, plan)
         return plan
 
@@ -1082,10 +1073,9 @@ class DistributedEngine:
         return cols, params
 
     def device_batches(self, plan: _DistPlan, stacked) -> List[Tuple[Dict, Dict]]:
-        """Device-placed (cols, params) per macro-batch launch (bench.py's
-        marginal-timing hook shares this with _run; _run itself stages
-        lazily through the prefetch stream instead of materializing the
-        whole list)."""
+        """Device-placed (cols, params) per macro-batch launch, the whole
+        list at once (the batched path and the AOT compile tests; _run itself
+        stages lazily through the prefetch stream)."""
         shared, repl, shard = self._shared_params(plan)
         return [
             self._stage_batch(plan, stacked, j, shared, repl, shard)
@@ -1144,7 +1134,6 @@ class DistributedEngine:
         keep_device = plan.kind == "groupby_sparse" and plan.sparse_merge_fn is not None
         batch_outs = []
         pending: List[Any] = []
-        launch_rows = stacked.num_shards * plan.batch_docs  # rows per launch
         n_batches = len(plan.batch_offsets)
         # Staging pipeline: with a residency manager attached, batch j+1's
         # host->device copies run on the residency staging thread while
@@ -1183,7 +1172,6 @@ class DistributedEngine:
             )
             return out
 
-        tl0 = time.perf_counter()
         with trace.span("launches") as lsp:
             _ensure(0, False)
             for i in range(n_batches):
@@ -1191,60 +1179,30 @@ class DistributedEngine:
                     _ensure(j, True)
                 with trace.span(f"stage:{i}"):
                     cols, params = _consume(i)
-                first_dispatch = i == 0 and plan.cost is None
-                if first_dispatch:
-                    # cost model captured ONCE per cached plan (per LAUNCH —
-                    # every batch shares the shape, so one model covers all)
-                    plan.cost = perf.capture_cost(
-                        plan.fn,
-                        (cols, params),
-                        perf.analytic_cost(
-                            launch_rows,
-                            perf.analytic_bytes_per_row(
-                                (stacked.column(n) for n in plan.needed_columns),
-                                bitmap_params=len(plan.row_sharded_params),
-                            ),
-                            kind=plan.kind,
-                            num_groups=plan.num_groups,
-                            num_entries=len(plan.aggs),
-                        ),
-                    )
+                first_call = i == 0 and not plan.first_call_ms
                 td0 = time.perf_counter()
                 with trace.span(f"dispatch:{i}"):
                     pending.append(plan.fn(cols, params))
-                if first_dispatch:
+                if first_call:
                     # the first jit dispatch pays trace+compile; its wall
                     # time is the compile cost this query actually paid
-                    plan.cost.compile_ms = (time.perf_counter() - td0) * 1000.0
-                    stats.compile_ms += plan.cost.compile_ms + plan.cost.lower_ms
+                    compile_ms = (time.perf_counter() - td0) * 1000.0
+                    plan.first_call_ms.append(compile_ms)
+                    stats.compile_ms += compile_ms
                 if len(pending) >= depth:
                     with trace.span("drain"):
                         batch_outs.append(self._drain(pending.pop(0), keep_device))
             while pending:
                 with trace.span("drain"):
                     batch_outs.append(self._drain(pending.pop(0), keep_device))
-            # every drain is a device_get fence, so the launches-section wall
-            # time bounds device compute — the roofline denominator here
-            launch_s = time.perf_counter() - tl0
-            total_bytes = total_flops = 0.0
-            if plan.cost is not None:
-                n_launches = len(plan.batch_offsets)
-                total_bytes = plan.cost.bytes_accessed * n_launches
-                total_flops = plan.cost.flops * n_launches
-                stats.kernel_bytes += total_bytes
-                stats.kernel_flops += total_flops
-                stats.kernel_cost_source = plan.cost.source
-                stats.device_ms += launch_s * 1000.0
+            total_bytes = plan.scan_bytes * len(plan.batch_offsets)
+            stats.kernel_bytes += total_bytes
             if lsp is not None:
-                roof = perf.roofline_pct(total_bytes, launch_s)
                 lsp.annotate(
                     batches=len(plan.batch_offsets),
                     pipelineDepth=depth,
                     backend=ops.scan_backend(),
                     kernelBytes=total_bytes,
-                    kernelFlops=total_flops,
-                    costSource=plan.cost.source if plan.cost is not None else None,
-                    **({"rooflinePct": round(roof, 2)} if roof is not None else {}),
                 )
 
         if plan.kind == "aggregation":

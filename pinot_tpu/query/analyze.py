@@ -24,18 +24,13 @@ ANALYZE_COLUMNS = [
     "Parent_Id",
     "Actual_Ms",
     "Rows",
-    # kernel cost accounting (utils/perf.py): cost-model bytes/flops the
-    # stage's compiled kernels streamed, and achieved-vs-peak HBM roofline %
+    # bytes the stage's launches had to read (SegmentPlan.scan_bytes)
     "Bytes",
-    "Flops",
-    "Roofline_Pct",
 ]
 
-# span names carrying per-kernel cost attrs (SSE/server `launch:*` spans,
-# the dist engine's `launches` section) and the fence spans carrying the
-# measured roofline — the two sets never double-count inside one trace
+# span names carrying the per-launch kernelBytes attr (SSE/server `launch:*`
+# spans, the dist engine's `launches` section)
 _SCAN_COST_SPANS = ("launch", "launches")
-_ROOFLINE_SPANS = ("device_wait", "launches")
 
 # operator-name prefix -> trace span names whose ms sum to that stage
 # (a span matches a candidate by exact name or "<candidate>:" prefix)
@@ -96,76 +91,50 @@ def _attr_summary(attrs: Dict[str, Any]) -> str:
     return ", ".join(parts)
 
 
-def _span_cost_index(
-    trace: Optional[Dict[str, Any]],
-) -> Tuple[Dict[str, float], Dict[str, float], Dict[str, float]]:
-    """Per-span-base-name sums of the kernelBytes/kernelFlops attrs and the
-    max rooflinePct seen — the cost twin of _span_ms_index."""
+def _span_bytes_index(trace: Optional[Dict[str, Any]]) -> Dict[str, float]:
+    """Per-span-base-name sums of the kernelBytes attr — the cost twin of
+    _span_ms_index."""
     bytes_by: Dict[str, float] = {}
-    flops_by: Dict[str, float] = {}
-    roof_by: Dict[str, float] = {}
 
     def walk(node: Optional[Dict[str, Any]]) -> None:
         if not node:
             return
-        attrs = node.get("attrs", {})
-        base = node.get("name", "").split(":", 1)[0]
-        for key, acc in (("kernelBytes", bytes_by), ("kernelFlops", flops_by)):
-            v = attrs.get(key)
-            if isinstance(v, (int, float)):
-                acc[base] = acc.get(base, 0.0) + float(v)
-        roof = attrs.get("rooflinePct")
-        if isinstance(roof, (int, float)):
-            roof_by[base] = max(roof_by.get(base, 0.0), float(roof))
+        v = node.get("attrs", {}).get("kernelBytes")
+        if isinstance(v, (int, float)):
+            base = node.get("name", "").split(":", 1)[0]
+            bytes_by[base] = bytes_by.get(base, 0.0) + float(v)
         for c in node.get("children", ()):
             walk(c)
 
     walk(trace)
-    return bytes_by, flops_by, roof_by
+    return bytes_by
 
 
-def _stage_cost(
-    op_name: str,
-    executed: ResultTable,
-    bytes_by: Dict[str, float],
-    flops_by: Dict[str, float],
-    roof_by: Dict[str, float],
-) -> Tuple[Optional[float], Optional[float], Optional[float]]:
-    """(Bytes, Flops, Roofline_Pct) for one operator row: the scan stage
-    carries its launch-span cost sums + the fence-measured roofline; the
-    root BROKER_REDUCE row carries the query totals from ExecutionStats."""
-    s = executed.stats
+def _stage_bytes(
+    op_name: str, executed: ResultTable, bytes_by: Dict[str, float]
+) -> Optional[float]:
+    """Bytes for one operator row: the scan stage carries its launch spans'
+    sum; the root BROKER_REDUCE row the query total from ExecutionStats."""
     if op_name.startswith("BROKER_REDUCE"):
-        roof = None
-        if s.kernel_bytes and s.device_ms:
-            from pinot_tpu.utils.perf import roofline_pct
-
-            r = roofline_pct(s.kernel_bytes, s.device_ms / 1000.0)
-            roof = round(r, 2) if r is not None else None
-        return (s.kernel_bytes or None, s.kernel_flops or None, roof)
-    if op_name.startswith(("AGGREGATE", "GROUP_BY", "SELECT", "COMBINE")):
-        b = sum(bytes_by.get(c, 0.0) for c in _SCAN_COST_SPANS)
-        f = sum(flops_by.get(c, 0.0) for c in _SCAN_COST_SPANS)
-        roofs = [roof_by[c] for c in _ROOFLINE_SPANS if c in roof_by]
-        if op_name.startswith("COMBINE"):
-            # the combine row owns the fence: show where the device time
-            # went (roofline) without re-counting the scan's bytes
-            return (None, None, round(max(roofs), 2) if roofs else None)
-        return (b or None, f or None, round(max(roofs), 2) if roofs else None)
-    return (None, None, None)
+        return executed.stats.kernel_bytes or None
+    if op_name.startswith(("AGGREGATE", "GROUP_BY", "SELECT")):
+        return sum(bytes_by.get(c, 0.0) for c in _SCAN_COST_SPANS) or None
+    return None
 
 
 def analyze_result(static: ResultTable, executed: ResultTable) -> ResultTable:
-    """Static EXPLAIN rows + Actual_Ms/Rows + per-operator kernel cost
-    (Bytes/Flops/Roofline_Pct), followed by the measured span tree as
-    TRACE(...) rows parented under the operator root."""
+    """Static EXPLAIN rows + Actual_Ms/Rows + per-operator Bytes, followed
+    by the measured span tree as TRACE(...) rows parented under the operator
+    root."""
     index = _span_ms_index(executed.stats.trace)
-    cost_idx = _span_cost_index(executed.stats.trace)
+    bytes_by = _span_bytes_index(executed.stats.trace)
     rows: List[tuple] = []
     for op_name, oid, parent in static.rows:
-        b, f, r = _stage_cost(op_name, executed, *cost_idx)
         rows.append(
-            (op_name, oid, parent, _stage_ms(op_name, index), _stage_rows(op_name, executed), b, f, r)
+            (
+                op_name, oid, parent, _stage_ms(op_name, index),
+                _stage_rows(op_name, executed), _stage_bytes(op_name, executed, bytes_by),
+            )
         )
     next_id = max((r[1] for r in static.rows), default=0) + 1
 
@@ -187,8 +156,6 @@ def analyze_result(static: ResultTable, executed: ResultTable) -> ResultTable:
                 round(float(node.get("ms", 0.0)), 3),
                 docs,
                 attrs.get("kernelBytes"),
-                attrs.get("kernelFlops"),
-                attrs.get("rooflinePct"),
             )
         )
         for c in node.get("children", ()):

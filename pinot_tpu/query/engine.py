@@ -143,33 +143,19 @@ class QueryEngine:
                     st = executor.launch_segment(ctx, seg, device=device, trace=trace)
                     pending.append(st)
                 if lsp is not None and st[0] == "pending":
-                    # per-operator cost model on the launch span: EXPLAIN
-                    # ANALYZE and the trace view read these attributes
-                    lst = st[5]
-                    lsp.annotate(
-                        kernelBytes=lst.kernel_bytes,
-                        kernelFlops=lst.kernel_flops,
-                        costSource=lst.kernel_cost_source,
-                    )
+                    # EXPLAIN ANALYZE's Bytes
+                    lsp.annotate(kernelBytes=st[5].kernel_bytes)
             if trace.enabled:
                 # device/host time split: ONE fence over every pending output
                 # (trace-only — the untraced path lets collect's device_get
                 # fence so deadline checks stay responsive between collects)
                 import jax
 
-                pend_bytes = sum(
-                    st[5].kernel_bytes for st in pending if st[0] == "pending"
-                )
-                tw = time.perf_counter()
                 with trace.span("device_wait", launches=len(pending)) as wsp:
                     jax.block_until_ready(executor.pending_outputs(pending))
-                wait_s = time.perf_counter() - tw
-                stats.device_ms = wait_s * 1000.0
                 if wsp is not None:
-                    roof = perf.roofline_pct(pend_bytes, wait_s)
                     wsp.annotate(
-                        kernelBytes=pend_bytes,
-                        **({"rooflinePct": round(roof, 2)} if roof is not None else {}),
+                        kernelBytes=sum(st[5].kernel_bytes for st in pending if st[0] == "pending")
                     )
             for st in pending:
                 deadline.check(f"query on {ctx.table}")
@@ -196,7 +182,7 @@ class QueryEngine:
         METRICS.counter("docsScanned").inc(stats.num_docs_scanned)
         from pinot_tpu.query.shape import shape_digest
 
-        perf.PERF_LEDGER.record(
+        perf.SHAPE_STATS.record(
             ctx.table,
             shape_digest(ctx.shape_fingerprint()),
             rows=out.stats.num_docs_scanned,

@@ -21,7 +21,6 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from pinot_tpu.query import planner
-from pinot_tpu.utils import perf
 from pinot_tpu.utils.metrics import Trace
 from pinot_tpu.query.functions import combine_field
 from pinot_tpu.query.ir import Expr, FilterNode, FilterOp, PredicateType, QueryContext
@@ -151,39 +150,41 @@ def launch_segment(
         )
         if ssp is not None:
             ssp.annotate(paramArrays=len(plan.params))
-    first_launch = plan.cost is None
-    if first_launch:
-        # cost model captured ONCE per cached plan (hits copy it forward in
-        # plan_segment); racing first launches both capture — idempotent
-        plan.cost = _capture_cost(plan, segment, cols, plan.params, device)
-    # the program compiles once for every device it runs on: each server's
-    # chip pays its own first launch of a plan another server has compiled
-    first_on_device = device not in plan.cost.launched_on
-    if first_on_device and on_first_launch is not None:
+    out, stats.compile_ms = _enqueue(
+        trace, plan, (cols, plan.params), device, on_first_launch,
+        segment=segment.name, kind=plan.kind, backend=plan.cache_key[2],
+    )
+    stats.kernel_bytes = plan.scan_bytes
+    return ("pending", ctx, segment, plan, out, stats)
+
+
+def _enqueue(trace, plan, args, device, on_first_launch=None, **attrs):
+    """A launch's jitted call, `plan.fn(*args)`, inside its `launch_enqueue`
+    span (`attrs` are the span's), and the one place that knows a first
+    launch.  Where `device` is not in `plan.launched_on` the call will trace
+    and compile before it enqueues, so `on_first_launch` is called first,
+    the call's wall time is recorded there as the compile time (an AOT
+    compile would pay it a second time) and the span says `firstLaunch` /
+    `compileMs`.  The span ends with its child launch_release (an empty
+    block, see launch_segment).  Returns the asynchronously dispatched output
+    (device_get happens at collect) and the compile ms this call paid, 0.0
+    on a warm launch."""
+    launched_on = plan.launched_on
+    first = device not in launched_on
+    if first and on_first_launch is not None:
         on_first_launch()
-    with trace.span(
-        "launch_enqueue", segment=segment.name, kind=plan.kind, backend=plan.cache_key[2]
-    ) as esp:
+    compile_ms = 0.0
+    with trace.span("launch_enqueue", **attrs) as esp:
         t0 = time.perf_counter()
         with _placed_on(device):
-            # async dispatch; device_get happens at collect
-            out = plan.fn(cols, plan.params)
-        if first_on_device:
-            # first jit dispatch pays trace+compile before enqueueing — its wall
-            # time IS the compile cost (AOT compile would pay it a second time)
-            plan.cost.launched_on.add(device)
-            stats.compile_ms = (time.perf_counter() - t0) * 1000.0
-            if first_launch:
-                plan.cost.compile_ms = stats.compile_ms
-                stats.compile_ms += plan.cost.lower_ms
+            out = plan.fn(*args)
+        if first:
+            compile_ms = launched_on[device] = (time.perf_counter() - t0) * 1000.0
             if esp is not None:
-                esp.annotate(firstLaunch=True, compileMs=round(stats.compile_ms, 3))
-        with trace.span("launch_release", segment=segment.name):
-            pass  # nothing of the launch's own to drop (see the docstring)
-    stats.kernel_bytes = plan.cost.bytes_accessed
-    stats.kernel_flops = plan.cost.flops
-    stats.kernel_cost_source = plan.cost.source
-    return ("pending", ctx, segment, plan, out, stats)
+                esp.annotate(firstLaunch=True, compileMs=round(compile_ms, 3))
+        with trace.span("launch_release", segment=attrs["segment"]):
+            pass  # nothing of the launch's own to drop
+    return out, compile_ms
 
 
 def _placed_on(device):
@@ -191,28 +192,12 @@ def _placed_on(device):
     are host numpy (uncommitted), and the kernel may read no column at all
     (COUNT(*) over an upsert segment reads `__valid__` only; unused
     arguments pin nothing), so the caller's device is made the call's
-    default: thread-local, no transfer.  Every call and lowering of a
-    `plan.fn` goes through here, so one plan sees one argument form and one
+    default: thread-local, no transfer.  Every call of a `plan.fn` goes
+    through here (_enqueue), so one plan sees one argument form and one
     trace context."""
     import jax
 
     return jax.default_device(device) if device is not None else contextlib.nullcontext()
-
-
-def _capture_cost(plan, segment: ImmutableSegment, cols, params, device=None):
-    """The single-lane cost model of a plan's first launch (utils/perf.py)."""
-    with _placed_on(device):
-        return perf.capture_cost(
-            plan.fn,
-            (cols, params),
-            perf.analytic_cost(
-                segment.num_docs,
-                perf.analytic_bytes_per_row(segment.column(n) for n in plan.needed_columns),
-                kind=plan.kind,
-                num_groups=plan.num_groups,
-                num_entries=len(plan.aggs),
-            ),
-        )
 
 
 def pending_outputs(states) -> list:
@@ -346,7 +331,7 @@ def launch_segment_batch(
     width so batching never causes recompile churn.
 
     Per-member ExecutionStats divide the physical launch's cost — docs
-    scanned, kernel bytes/flops — across the N live members (padding lanes
+    scanned, kernel bytes — across the N live members (padding lanes
     attributed to nobody), so summing member stats reproduces ONE unbatched
     run of the same query, not N copies.  compile_ms lands on member 0.
 
@@ -357,8 +342,6 @@ def launch_segment_batch(
     the columns plus one host-side np.stack per packed buffer of the members'
     parameters (they ride the vmapped call as host numpy), launch_release an
     empty block."""
-    import jax
-
     n = len(ctxs)
     if n < 1:
         raise ValueError("launch_segment_batch needs at least one member")
@@ -395,34 +378,17 @@ def launch_segment_batch(
 
     key = (base.cache_key or id(base.fn), width, shared_keys)
     cache = _batch_fn_cache()
-    fnb = cache.get(key)
-    first_batched = fnb is None
-    if first_batched:
-        axes = {k: (None if k in shared_keys else 0) for k in base.params}
-        fnb = jax.jit(jax.vmap(base.fn, in_axes=(None, axes)))
-        cache.put(key, fnb)
+    batched = cache.get(key)
+    if batched is None:
+        batched = planner.vmapped_plan(base, shared_keys)
+        cache.put(key, batched)
         BATCH_AUDIT.record_compile()
     else:
         BATCH_AUDIT.record_hit()
-
-    if base.cost is None:
-        # same single-lane cost model as launch_segment, so per-member
-        # shares divide the identical numbers an unbatched run reports
-        base.cost = _capture_cost(base, segment, cols, base.params, device)
-    with trace.span(
-        "launch_enqueue", segment=segment.name, kind=base.kind, backend=base.cache_key[2],
-        members=n,
-    ) as esp:
-        t0 = time.perf_counter()
-        with _placed_on(device):
-            out = fnb(cols, stacked)  # async dispatch; one device_get at collect
-        # deliberately times the dispatch: the first vmapped call pays
-        # trace+compile inline, and THAT is the cost being recorded
-        compile_ms = (time.perf_counter() - t0) * 1000.0 if first_batched else 0.0  # pinot-lint: disable=W017
-        if first_batched and esp is not None:
-            esp.annotate(firstLaunch=True, compileMs=round(compile_ms + base.cost.lower_ms, 3))
-        with trace.span("launch_release", segment=segment.name):
-            pass  # as in launch_segment
+    out, compile_ms = _enqueue(
+        trace, batched, (cols, stacked), device,
+        segment=segment.name, kind=base.kind, backend=base.cache_key[2], members=n,
+    )
 
     docs = segment.num_docs
     share, rem = divmod(docs, n)
@@ -435,12 +401,9 @@ def launch_segment_batch(
             total_docs=docs,
         )
         st.filter_index_uses = tuple(plans[i].index_uses)
-        st.kernel_bytes = base.cost.bytes_accessed / n
-        st.kernel_flops = base.cost.flops / n
-        st.kernel_cost_source = base.cost.source
+        st.kernel_bytes = base.scan_bytes / n
         stats_list.append(st)
-    if first_batched:
-        stats_list[0].compile_ms = compile_ms + base.cost.lower_ms
+    stats_list[0].compile_ms = compile_ms
     return ("pending_batch", ctxs, segment, plans, out, stats_list)
 
 

@@ -24,7 +24,7 @@ replaced by a Pallas hash table.
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -45,6 +45,7 @@ from pinot_tpu.query.shape import column_info_from, params_structure
 from pinot_tpu.query.transform import as_row_array, eval_expr
 from pinot_tpu.segment.segment import ImmutableSegment
 from pinot_tpu.spi.schema import DataType
+from pinot_tpu.utils.perf import scan_bytes_per_row
 
 MAX_DENSE_RAW_INT_RANGE = 1 << 20  # raw ints join the dense keyspace when (max-min+1) is small
 
@@ -149,10 +150,17 @@ class SegmentPlan:
     select_exprs: List[Expr] = field(default_factory=list)
     # (column, index kind) per index-accelerated filter predicate
     index_uses: List[Tuple[str, str]] = field(default_factory=list)
-    # kernel cost model (utils/perf.KernelCost), captured lazily at the
-    # FIRST launch of this plan and shared through the plan cache: hits
-    # copy the cached cost instead of re-lowering (None until captured)
-    cost: Optional[Any] = None
+    # bytes the scan must read: the segment's rows x the stored bytes per
+    # row of needed_columns (utils/perf.scan_bytes_per_row), counted once
+    # when the plan-cache entry is built; every launch reports it
+    scan_bytes: float = 0.0
+    # device -> wall ms of this program's first call there (trace + compile:
+    # a jitted program compiles anew for every device it first runs on, and
+    # the persistent cache keys an entry by its device too).  The dict is the
+    # plan-cache entry's, created with it and shared by reference with the
+    # plan every hit builds, so "has not run on this device yet" is one fact
+    # however many queries race the first launch
+    launched_on: Dict[Any, float] = field(default_factory=dict)
     # plan-cache key (shape fp, segment signature, backend) — the stable
     # identity the cross-query batcher keys its vmapped-fn LRU on, so
     # batching never compiles more than once per (shape, batch width)
@@ -160,6 +168,17 @@ class SegmentPlan:
     # whether plan_segment took the compiled fn from the plan cache (the
     # `cache` attr of the launch_plan span)
     cache_hit: bool = False
+
+
+def vmapped_plan(base: SegmentPlan, shared_keys: frozenset) -> SegmentPlan:
+    """`base` with its kernel vmapped over a leading `query` axis of every
+    parameter buffer but `shared_keys` (the columns are shared): what a
+    cross-query batched launch calls.  A program of its own, compiled apart
+    from base.fn, so with a first-launch record of its own."""
+    axes = {k: (None if k in shared_keys else 0) for k in base.params}
+    return replace(
+        base, fn=jax.jit(jax.vmap(base.fn, in_axes=(None, axes))), launched_on={}
+    )
 
 
 # Upsert validDocIds ride beside the packed buffers, not in them: the mask
@@ -1025,15 +1044,17 @@ def plan_segment(ctx: QueryContext, segment: ImmutableSegment) -> SegmentPlan:
         # retrace, so it counts (and compiles) as a miss instead.
         plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn)
         if plan.param_layout == cached.param_layout:
-            # cost model rides the cache entry: captured once at the first
-            # launch of the cached plan, never re-lowered on hits
-            plan.cost = cached.cost
+            plan.scan_bytes = cached.scan_bytes
+            plan.launched_on = cached.launched_on
             plan.cache_key = key
             plan.cache_hit = True
             SSE_AUDIT.record_hit(key[0])
             return plan
     SSE_AUDIT.record_compile(key[0])
     plan = _build_plan(ctx, segment, needed, compiled_fn=None)
+    plan.scan_bytes = segment.num_docs * scan_bytes_per_row(
+        segment.column(n) for n in plan.needed_columns
+    )
     plan.cache_key = key
     _PLAN_CACHE.put(key, plan)
     return plan

@@ -42,18 +42,11 @@ class ExecutionStats:
     trace: Optional[dict] = None
     # broker/engine-minted request id (RequestContext requestId analog)
     query_id: Optional[str] = None
-    # kernel cost accounting (utils/perf.KernelCost, summed over every
-    # kernel launch this query dispatched): cost-model bytes/flops the
-    # compiled scans streamed, the lower+compile wall time paid by THIS
-    # query (0 on plan-cache hits), and where the model came from
-    # ("xla" | "analytic" | "mixed" across kernels)
+    # bytes the launches of this query had to read (SegmentPlan.scan_bytes,
+    # summed over every launch), and the trace + compile wall time THIS
+    # query paid (0 where every program had run on its device before)
     kernel_bytes: float = 0.0
-    kernel_flops: float = 0.0
-    kernel_cost_source: Optional[str] = None
     compile_ms: float = 0.0
-    # fence-bounded device-compute wall time (the device_wait span), when
-    # the execution path measured one — the roofline denominator
-    device_ms: float = 0.0
     # tail-tolerance surface (hedged scatter + brownout router, r15): how
     # many scatter calls hedged a backup, which server won the last hedged
     # call, how long the cancelled loser ran (best-effort: the loser thread
@@ -84,17 +77,10 @@ class ExecutionStats:
         self.add_kernel_cost(other)
 
     def add_kernel_cost(self, other: "ExecutionStats") -> None:
-        """Accumulate just the kernel-cost slice of `other` (used by the
+        """Accumulate just the launch-cost slice of `other` (used by the
         broker's scatter path, which merges the rest field-by-field)."""
-        from pinot_tpu.utils.perf import combine_sources
-
         self.kernel_bytes += other.kernel_bytes
-        self.kernel_flops += other.kernel_flops
         self.compile_ms += other.compile_ms
-        self.device_ms += other.device_ms
-        self.kernel_cost_source = combine_sources(
-            self.kernel_cost_source, other.kernel_cost_source
-        )
 
     def add_index_uses(self, uses: Tuple) -> None:
         """Order-preserving dedup-union into filter_index_uses."""

@@ -11,8 +11,9 @@ bytes that survive host-side pruning ride the slow link.  The TPU mapping:
 `ResidencyManager` owns the device-cache byte budget (an r11
 `ResourceBudget` ledger, shared with query working-set reservations so
 cache bytes and in-flight reservations can never jointly overcommit), a
-cost-aware eviction policy fed by the r13 `PERF_LEDGER` (hot tables — high
-bytes/s — survive; within a table, least-recently-used first), and the
+cost-aware eviction policy fed by the `SHAPE_STATS` window of utils/perf.py
+(hot tables — high bytes/s — survive; within a table, least-recently-used
+first), and the
 single-worker *staging stream*: the one thread allowed to issue
 segment-sized host->device copies (repo_lint W021 flags segment-shaped
 `jax.device_put` anywhere else on the serving path).
@@ -88,7 +89,7 @@ class ResidencyManager:
     ):
         self.budget = budget  # cluster.admission.ResourceBudget
         self.name = name
-        # r13 perf ledger supplying the eviction cost signal (bytes/s per
+        # stats window supplying the eviction cost signal (bytes/s per
         # table); None falls back to pure LRU
         self._ledger = ledger
         self.stall_timeout_s = float(stall_timeout_s)
@@ -296,7 +297,7 @@ class ResidencyManager:
         """Cost-ranked victim: most over-share table first when the
         autopilot has published per-table residency splits (a table resident
         beyond its traffic-weighted fraction of the budget donates first),
-        then coldest table (r13 ledger bytes/s — a hot table's groups are
+        then coldest table (stats-window bytes/s — a hot table's groups are
         the expensive ones to refetch), then least recently used within a
         heat class.  With no splits set (autopilot off) this is exactly the
         pre-autopilot heat/LRU policy."""
@@ -401,7 +402,7 @@ def default_residency(budget=None, name: str = "residency"):
     """Process-default residency manager factory: budget from
     PINOT_TPU_HBM_CACHE_BYTES (0 disables tiering — every to_device call
     behaves as the legacy pin-everything path), else the server HBM default;
-    eviction heat from the process PERF_LEDGER."""
+    eviction heat from the process SHAPE_STATS."""
     import os
 
     from pinot_tpu.utils import perf
@@ -415,7 +416,7 @@ def default_residency(budget=None, name: str = "residency"):
         if nbytes <= 0:
             return None
         budget = ResourceBudget(nbytes, gauge=f"{name}.reservedBytes")
-    return ResidencyManager(budget, name=name, ledger=perf.PERF_LEDGER)
+    return ResidencyManager(budget, name=name, ledger=perf.SHAPE_STATS)
 
 
 def row_residency(num_rows: int, row: int, total_bytes=None, name: str = "residency"):
@@ -445,4 +446,4 @@ def row_residency(num_rows: int, row: int, total_bytes=None, name: str = "reside
 
     row_name = f"{name}.row{row}"
     budget = ResourceBudget(share, gauge=f"{row_name}.reservedBytes")
-    return ResidencyManager(budget, name=row_name, ledger=perf.PERF_LEDGER)
+    return ResidencyManager(budget, name=row_name, ledger=perf.SHAPE_STATS)

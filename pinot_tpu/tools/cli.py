@@ -286,49 +286,8 @@ def cmd_autopilot(args) -> int:
 
 
 def cmd_perf(args) -> int:
-    """Perf observatory view + bench-regression gate.
-
-    Without --check: print a serving endpoint's per-table/per-shape perf
-    ledger (GET /debug/perf) — rows/s, bytes/s, roofline %, compile ms,
-    plan-cache hit rate, QPS.
-
-    With --check: compare the newest bench_history.jsonl record against the
-    pinned baseline (utils/perf.check_regression) with a noise-aware
-    threshold, exiting nonzero on a regression — the CI gate that turns
-    BENCH files from write-only artifacts into enforcement."""
-    from pinot_tpu.utils import perf as perf_mod
-
-    if args.check:
-        history = perf_mod.load_bench_history(args.history)
-        if not history:
-            print(f"perf gate: no usable records in {args.history}", file=sys.stderr)
-            return 1
-        latest = history[-1]
-        try:
-            with open(args.baseline, "r", encoding="utf-8") as f:
-                baseline = json.load(f)
-        except (OSError, json.JSONDecodeError) as e:
-            print(f"perf gate: cannot read baseline {args.baseline}: {e}", file=sys.stderr)
-            return 1
-        verdict = perf_mod.check_regression(latest, baseline, threshold=args.threshold)
-        if args.json:
-            print(json.dumps(verdict, indent=2))
-        else:
-            for c in verdict["checks"]:
-                mark = "ok  " if c["ok"] else "FAIL"
-                print(
-                    f"{mark} {c['metric']:<28} baseline={c['baseline']:<14g} "
-                    f"latest={c['latest']:<14g} drop={c['drop_pct']:+.2f}%"
-                )
-            for r in verdict["reasons"]:
-                print(f"FAIL {r}")
-            status = "PASS" if verdict["ok"] else "REGRESSION"
-            print(
-                f"perf gate: {status} (allowed drop {verdict['allowed_drop'] * 100:.1f}%)",
-                file=sys.stderr,
-            )
-        return 0 if verdict["ok"] else 1
-
+    """Print a serving endpoint's per-table/per-shape stats window (GET
+    /debug/perf): rows/s, bytes/s, compile ms, plan-cache hit rate, QPS."""
     import urllib.request
 
     url = args.url.rstrip("/") + "/debug/perf"
@@ -342,12 +301,12 @@ def cmd_perf(args) -> int:
         print(f"table {table}: {t.get('queries', 0)} quer(y/ies), qps={t.get('qps', 0):g}")
         for fp, sh in sorted(t.get("shapes", {}).items()):
             rps = sh.get("rowsPerSec", {})
-            roof = sh.get("rooflinePct", {})
+            bps = sh.get("bytesPerSec", {})
             hit = sh.get("planCacheHitRate")
             print(
                 f"  shape {fp}: n={sh.get('queries', 0)} "
                 f"rows/s last={rps.get('last', 0):g} mean={rps.get('mean', 0):g} "
-                f"roofline last={roof.get('last', 0):g}% "
+                f"bytes/s last={bps.get('last', 0):g} "
                 f"compileMs={sh.get('compileMsTotal', 0):g} "
                 f"cacheHit={'n/a' if hit is None else f'{hit:.0%}'} "
                 f"qps={sh.get('qps', 0):g}"
@@ -525,13 +484,9 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true", help="dump the raw snapshot as JSON")
     ap.set_defaults(fn=cmd_autopilot)
 
-    pf = sub.add_parser("perf", help="perf ledger view + bench-regression gate")
+    pf = sub.add_parser("perf", help="print a serving endpoint's per-table/per-shape stats window")
     pf.add_argument("--url", default="http://127.0.0.1:8099", help="query server base URL")
-    pf.add_argument("--json", action="store_true", help="dump the raw snapshot/verdict as JSON")
-    pf.add_argument("--check", action="store_true", help="gate mode: compare bench history vs baseline")
-    pf.add_argument("--history", default="bench_history.jsonl", help="bench history file (--check)")
-    pf.add_argument("--baseline", default="BENCH_BASELINE.json", help="pinned baseline record (--check)")
-    pf.add_argument("--threshold", type=float, default=None, help="override allowed fractional drop (--check)")
+    pf.add_argument("--json", action="store_true", help="dump the raw snapshot as JSON")
     pf.set_defaults(fn=cmd_perf)
 
     lt = sub.add_parser("lint", help="JAX-aware static lint over the pinot_tpu tree")

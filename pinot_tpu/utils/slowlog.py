@@ -76,21 +76,12 @@ class SlowQueryLog:
             "partialResult": bool(stats.partial_result) if stats else False,
             "numExceptions": len(stats.exceptions) if stats else 0,
         }
-        # kernel cost accounting (utils/perf.py): bytes/flops the compiled
-        # scans streamed, the compile cost THIS query paid, and the achieved
-        # roofline % — slow queries annotated with whether the device or the
-        # compile/dispatch path made them slow
+        # the bytes the query's scans had to read, the compile time THIS
+        # query paid and its row rate: whether the device or the
+        # compile/dispatch path made a slow query slow
         if stats is not None and getattr(stats, "kernel_bytes", 0):
-            from pinot_tpu.utils.perf import roofline_pct
-
             entry["kernelBytes"] = round(stats.kernel_bytes, 1)
-            entry["kernelFlops"] = round(stats.kernel_flops, 1)
-            entry["costSource"] = stats.kernel_cost_source
             entry["compileMs"] = round(stats.compile_ms, 3)
-            denom_s = (stats.device_ms or time_ms) / 1000.0
-            roof = roofline_pct(stats.kernel_bytes, denom_s)
-            if roof is not None:
-                entry["rooflinePct"] = round(roof, 2)
             if time_ms > 0:
                 entry["rowsPerSec"] = round(stats.num_docs_scanned / (time_ms / 1000.0), 1)
         if error is not None:

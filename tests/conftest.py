@@ -30,14 +30,14 @@ def rng():
 
 @pytest.fixture(autouse=True)
 def _reset_metrics():
-    """The METRICS registry and the perf ledger are process-global; without
+    """The METRICS registry and the stats window are process-global; without
     a reset, counter/histogram assertions and federated per-server series
     see spill-over from whichever tests ran before."""
     from pinot_tpu.utils.metrics import METRICS
-    from pinot_tpu.utils.perf import PERF_LEDGER
+    from pinot_tpu.utils.perf import SHAPE_STATS
 
     METRICS.reset()
-    PERF_LEDGER.reset()
+    SHAPE_STATS.reset()
     yield
 
 

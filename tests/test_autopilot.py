@@ -20,7 +20,7 @@ from pinot_tpu.cluster.autopilot import (
 
 
 class FakeLedger:
-    """Minimal PerfLedger stand-in: per-table (p99_ms, qps)."""
+    """Minimal ShapeStats stand-in: per-table (p99_ms, qps)."""
 
     def __init__(self):
         self.tables = {}

@@ -206,26 +206,19 @@ def test_warm_batched_launch_ships_no_parameter_array(name, device_puts):
 
 
 @pytest.mark.parametrize("name", list(CASES))
-def test_one_compiled_plan_sees_one_argument_form(name, monkeypatch):
+def test_one_compiled_plan_sees_one_argument_form(name):
     """Committed device arrays and host numpy are different signatures to
     jit: a caller that still device-put its parameters would compile the
-    plan a second time.  The batch path's cost capture, launch_segment and
-    execute_segment all run one plan here; it is compiled once, and other
-    literals of the same shape add nothing."""
-    monkeypatch.setenv("PINOT_TPU_COST_SOURCE", "xla")  # the cost model lowers plan.fn with the arguments
+    plan a second time.  The batch path, launch_segment and execute_segment
+    all run one plan here; it is compiled once, and other literals of the
+    same shape add nothing."""
     kind, seg, ctxs, wants = _case(name)
     planner.plan_cache_clear()
     METRICS.reset()
 
-    lowered = []
-    real = executor.perf.capture_cost
-    monkeypatch.setattr(
-        executor.perf, "capture_cost", lambda fn, args, *a, **kw: lowered.append(args) or real(fn, args, *a, **kw)
-    )
-    batch = executor.launch_segment_batch(ctxs, seg)  # cold: _capture_cost runs here
+    batch = executor.launch_segment_batch(ctxs, seg)  # cold
     fn = batch[3][0].fn
-    assert len(lowered) == 1 and lowered[0][1] is batch[3][0].params  # the plan's own host parameters
-    assert fn._cache_size() == 0  # lowered for its cost, called only under vmap so far
+    assert fn._cache_size() == 0  # called only under vmap so far, and lowered by nobody
     assert [_answer(r) for r, _ in executor.collect_segment_batch(batch)] == wants
 
     def compiles():
@@ -239,7 +232,67 @@ def test_one_compiled_plan_sees_one_argument_form(name, monkeypatch):
     assert fn._cache_size() == 1
     assert _answer(executor.execute_segment(ctxs[1], seg)[0]) == wants[1]  # same shape, other literals
     assert fn._cache_size() == 1 and compiles() == n_compiles
-    assert len(lowered) == 1  # the cost rode the cache entry
+
+
+# ---------------------------------------------------------------------------
+# first launch: a fact of (program, device), kept on the plan-cache entry
+# ---------------------------------------------------------------------------
+def _launch_one(ctxs, seg, device, trace, on_first_launch=None):
+    st = executor.launch_segment(ctxs[0], seg, device=device, trace=trace, on_first_launch=on_first_launch)
+    _, stats = executor.collect_segment(st)
+    return st[3], stats.compile_ms, stats.kernel_bytes
+
+
+def _launch_batch(ctxs, seg, device, trace, on_first_launch=None):
+    st = executor.launch_segment_batch(ctxs, seg, device=device, trace=trace)
+    stats = [s for _, s in executor.collect_segment_batch(st)]
+    assert all(s.compile_ms == 0.0 for s in stats[1:])  # the compile lands on member 0
+    return st[3][0], stats[0].compile_ms, sum(s.kernel_bytes for s in stats)
+
+
+@pytest.mark.parametrize("launch", [_launch_one, _launch_batch], ids=["launch_segment", "launch_segment_batch"])
+def test_first_launch_is_per_plan_and_device(launch):
+    """Two of tier-1's eight devices: the program compiles once on each, the
+    span says so there and only there, and the record is the plan-cache
+    entry's, so the plan a cache hit builds knows what the first one did."""
+    _, seg, ctxs, _ = _case("groupby_dense")
+    planner.plan_cache_clear()
+    executor._batch_fn_cache().clear()
+    d0, d1 = jax.devices()[1], jax.devices()[2]
+    seen = []
+    for device, want_first in [(d0, True), (d0, False), (d1, True), (d1, False), (d0, False)]:
+        trace = Trace(True)
+        hooked = []
+        plan, compile_ms, kernel_bytes = launch(ctxs, seg, device, trace, lambda: hooked.append(1))
+        (enqueue,) = _spans(trace.finish())["launch_enqueue"]
+        assert enqueue["attrs"].get("firstLaunch", False) == want_first, (device, enqueue)
+        assert (compile_ms > 0) == want_first and ("compileMs" in enqueue["attrs"]) == want_first
+        if launch is _launch_one:
+            assert hooked == ([1] if want_first else [])  # called before the compile, and only then
+        assert kernel_bytes == pytest.approx(plan.scan_bytes) and plan.scan_bytes > 0
+        seen.append(plan)
+    record = seen[0].launched_on
+    assert all(p.launched_on is record for p in seen)  # one record, shared by reference
+    assert seen[0] is not seen[1] and seen[1].cache_hit
+    if launch is _launch_one:
+        assert set(record) == {d0, d1} and all(ms > 0 for ms in record.values())
+    else:
+        assert record == {}  # the vmapped program keeps its own; the plan's own fn never ran
+        ((batched, _, _),) = executor._batch_fn_cache()._entries.values()
+        assert batched.fn is not seen[0].fn and set(batched.launched_on) == {d0, d1}
+
+
+def test_a_hit_that_races_the_first_launch_shares_its_record():
+    """Two plans of one cache entry built BEFORE either launched (two queries
+    of a cold shape in flight at once): the second launch is not a first."""
+    _, seg, ctxs, _ = _case("aggregation")
+    planner.plan_cache_clear()
+    first, second = planner.plan_segment(ctxs[0], seg), planner.plan_segment(ctxs[1], seg)
+    assert not first.cache_hit and second.cache_hit and second.launched_on is first.launched_on == {}
+    assert second.scan_bytes == first.scan_bytes > 0
+    (_, s1), (_, s2) = executor.execute_segment(ctxs[0], seg), executor.execute_segment(ctxs[1], seg)
+    assert s1.compile_ms > 0 and s2.compile_ms == 0.0
+    assert list(first.launched_on) == [None]  # the default device, as the callers name it
 
 
 # ---------------------------------------------------------------------------

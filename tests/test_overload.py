@@ -4,8 +4,8 @@ degradation, and their REST / breaker / cache interactions.
 
 Determinism: admission tests inject the bucket clock (the simulated arrival
 schedule IS the clock, host speed is irrelevant), watchdog tests inject a
-counting clock, and the overload acceptance sweep reuses the bench.py
-methodology — offered load is simulated, outcomes are exact counts.
+counting clock, and in the overload acceptance sweep offered load is
+simulated and outcomes are exact counts.
 """
 import json
 import threading

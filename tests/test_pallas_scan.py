@@ -188,7 +188,7 @@ N = 1245 * 8
 
 
 def _bench_shaped_table(eng, *, seed=3):
-    """Mirror bench.py's lineorder: dict-encoded filter column with a range
+    """A lineorder-shaped table: dict-encoded filter column with a range
     index, so the whole WHERE compiles to one plain bitmap and the dense
     kernel takes the word-fused path."""
     rng = np.random.default_rng(seed)
@@ -238,7 +238,7 @@ def _reset_backend_cache():
 
 
 def test_engine_word_fused_dense_routing(monkeypatch):
-    """The bench query rides the range-index bitmap on both backends and
+    """The headline group-by rides the range-index bitmap on both backends and
     returns identical rows, exact vs a pure-numpy reference."""
     rows = {}
     for be in ("xla", "interpret"):

@@ -1,6 +1,6 @@
-"""Performance observatory (round 6): kernel cost capture + fallbacks,
-roofline accounting, the per-table/per-shape perf ledger, cluster metric
-federation, /debug/perf, and the bench-history regression gate."""
+"""What the program keeps of its own speed (utils/perf.py): the bytes a scan
+must read, counted once a plan; the per-table/per-shape stats window; cluster
+metric federation, /debug/perf and `cli perf`; the slow-query log's fields."""
 import json
 import urllib.request
 
@@ -10,6 +10,7 @@ import pytest
 from pinot_tpu import ops
 from pinot_tpu.cluster import Broker, Coordinator, ServerInstance
 from pinot_tpu.cluster.rest import QueryServer
+from pinot_tpu.query import planner
 from pinot_tpu.query.engine import QueryEngine
 from pinot_tpu.query.result import ExecutionStats
 from pinot_tpu.segment.builder import build_segment
@@ -38,6 +39,15 @@ def _data(n, seed):
     }
 
 
+def _spans(node, out=None):
+    """A span tree as {base name: [node, ...]} (launch:seg0 under launch)."""
+    out = {} if out is None else out
+    out.setdefault(node["name"].split(":", 1)[0], []).append(node)
+    for c in node.get("children", []):
+        _spans(c, out)
+    return out
+
+
 def _engine(table="t", n_segments=2, rows=150):
     eng = QueryEngine()
     eng.register_table(_schema(table))
@@ -53,194 +63,98 @@ class _FakeCol:
         self.nulls = nulls
 
 
-# ---------------------------------------------------------------------------
-# capture_cost fallbacks
-# ---------------------------------------------------------------------------
-class TestCaptureCost:
-    def test_auto_on_cpu_is_analytic_without_lowering(self):
-        # auto mode on a CPU backend must not even touch fn (no extra
-        # trace+lower on the tier-1 serving path)
-        analytic = perf.analytic_cost(100, 8.0)
-        got = perf.capture_cost(None, (), analytic)
-        assert got is analytic and got.source == "analytic"
-
-    def test_forced_xla_reads_cost_analysis_on_cpu(self):
-        import jax
-        import jax.numpy as jnp
-
-        fn = jax.jit(lambda x: (x * x).sum())
-        x = jnp.arange(1024, dtype=jnp.float32)
-        analytic = perf.analytic_cost(1024, 4.0)
-        got = perf.capture_cost(fn, (x,), analytic, force="xla")
-        # CPU XLA reports cost_analysis (probed); if a backend ever stops,
-        # the guarded fallback hands back the analytic estimate instead
-        assert got.source in ("xla", "analytic")
-        assert got.bytes_accessed > 0
-        if got.source == "xla":
-            assert got.flops > 0 and got.lower_ms > 0
-
-    def test_lowering_failure_falls_back_to_analytic(self):
-        class Exploding:
-            def lower(self, *a):
-                raise RuntimeError("backend without cost analysis")
-
-        analytic = perf.analytic_cost(10, 4.0)
-        got = perf.capture_cost(Exploding(), (1,), analytic, force="xla")
-        assert got is analytic and got.source == "analytic"
-
-    def test_missing_bytes_key_falls_back_but_keeps_lower_ms(self):
-        class NoBytes:
-            def lower(self, *a):
-                return self
-
-            def cost_analysis(self):
-                return {"flops": 42.0}  # no 'bytes accessed' -> unusable
-
-        analytic = perf.analytic_cost(10, 4.0)
-        got = perf.capture_cost(NoBytes(), (1,), analytic, force="xla")
-        assert got.source == "analytic" and got.lower_ms > 0
-
-    def test_env_override_forces_analytic(self, monkeypatch):
-        monkeypatch.setenv("PINOT_TPU_COST_SOURCE", "analytic")
-        analytic = perf.analytic_cost(10, 4.0)
-        got = perf.capture_cost(None, (), analytic)
-        assert got is analytic
-
-    def test_combine_sources(self):
-        assert perf.combine_sources(None, "xla") == "xla"
-        assert perf.combine_sources("xla", "xla") == "xla"
-        assert perf.combine_sources("xla", "analytic") == "mixed"
-        assert perf.combine_sources("analytic", None) == "analytic"
-
-
-class TestAnalyticModel:
+class TestScanBytes:
     def test_bytes_per_row_uses_stored_widths(self):
         cols = [
             _FakeCol(codes=np.zeros(4, np.int8)),  # dict codes at code width
             _FakeCol(values=np.zeros(4, np.int64), nulls=np.zeros(4, bool)),
         ]
-        bpr = perf.analytic_bytes_per_row(cols, bitmap_params=1)
+        bpr = perf.scan_bytes_per_row(cols, bitmap_params=1)
         assert bpr == pytest.approx(1 + 8 + 1 + 4 / 32)
-
-    def test_groupby_flops_follow_one_hot_matmul(self):
-        from pinot_tpu.ops.pallas_scan import matmul_flops_per_row
-
-        c = perf.analytic_cost(1000, 8.0, kind="groupby", num_groups=50, num_entries=2)
-        assert c.flops == pytest.approx(1000 * matmul_flops_per_row(50, 2))
-        assert c.bytes_accessed == pytest.approx(8000.0)
-        assert c.output_bytes > 0
-
-    def test_aggregation_and_selection_kinds(self):
-        agg = perf.analytic_cost(100, 4.0, kind="aggregation", num_entries=3)
-        sel = perf.analytic_cost(100, 4.0, kind="selection")
-        assert agg.flops == pytest.approx(600.0)
-        assert sel.flops == pytest.approx(100.0)
-
-
-class TestRoofline:
-    def test_env_override_wins(self, monkeypatch):
-        monkeypatch.setenv("PINOT_TPU_PEAK_HBM_BPS", "1e9")
-        perf.peak_hbm_bytes_per_sec.cache_clear()
-        try:
-            assert perf.peak_hbm_bytes_per_sec() == 1e9
-            # 5e8 bytes in 1s = 50% of a 1e9 peak
-            assert perf.roofline_pct(5e8, 1.0) == pytest.approx(50.0)
-        finally:
-            perf.peak_hbm_bytes_per_sec.cache_clear()
-
-    def test_unmeasurable_is_none(self):
-        assert perf.roofline_pct(0.0, 1.0) is None
-        assert perf.roofline_pct(100.0, 0.0) is None
-
-    def test_unknown_tpu_kind_is_an_error(self, monkeypatch):
-        # a TPU the peaks table does not know gets no borrowed peak
-        import jax
-
-        class _Dev:
-            device_kind = "TPU v9 imaginary"
-
-        monkeypatch.delenv("PINOT_TPU_PEAK_HBM_BPS", raising=False)
-        monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
-        perf.peak_hbm_bytes_per_sec.cache_clear()
-        try:
-            with pytest.raises(ValueError, match="no peak HBM bandwidth on record"):
-                perf.peak_hbm_bytes_per_sec()
-        finally:
-            perf.peak_hbm_bytes_per_sec.cache_clear()
-
-    def test_cpu_fallback_peak_is_positive(self, monkeypatch):
-        monkeypatch.delenv("PINOT_TPU_PEAK_HBM_BPS", raising=False)
-        perf.peak_hbm_bytes_per_sec.cache_clear()
-        try:
-            assert perf.peak_hbm_bytes_per_sec() > 0
-        finally:
-            perf.peak_hbm_bytes_per_sec.cache_clear()
 
 
 # ---------------------------------------------------------------------------
 # engine integration: cost on stats, EXPLAIN ANALYZE, cached reuse
 # ---------------------------------------------------------------------------
 class TestEngineCostIntegration:
-    def test_stats_carry_kernel_cost(self):
+    def test_stats_carry_the_scan_bytes_of_every_launch(self):
         eng = _engine(table="perfcost")
         out = eng.query("SELECT city, SUM(v) FROM perfcost GROUP BY city")
-        s = out.stats
-        assert s.kernel_bytes > 0 and s.kernel_flops > 0
-        assert s.kernel_cost_source in ("analytic", "xla", "mixed")
+        # two segments of 150 rows; `city` packs three values into 4-bit
+        # lanes (0.5 B/row), `v` < 100 is stored at one byte
+        want = 0
+        for seg in eng.tables["perfcost"].query_segments():
+            want += seg.num_docs * perf.scan_bytes_per_row(seg.column(c) for c in ("city", "v"))
+        assert out.stats.kernel_bytes == pytest.approx(want) and want > 0
 
-    def test_cost_captured_once_not_relowered_on_hits(self):
+    def test_bytes_counted_once_and_no_plan_lowered_on_a_cold_launch(self, monkeypatch):
+        """The number rides the plan-cache entry, and a cold launch calls
+        the plan's jitted function and nothing else of it: `.lower()`, which
+        the retired cost model ran before every first launch, would trace
+        the program a second time."""
+        import jax
+
+        lowered = []
+        real_jit = jax.jit
+
+        def counting_jit(fn, *a, **kw):
+            jitted = real_jit(fn, *a, **kw)
+
+            class Jitted:
+                def __call__(self, *args, **kwargs):
+                    return jitted(*args, **kwargs)
+
+                def lower(self, *args, **kwargs):
+                    lowered.append(fn)
+                    return jitted.lower(*args, **kwargs)
+
+            return Jitted()
+
+        monkeypatch.setattr(jax, "jit", counting_jit)
+        planner.plan_cache_clear()
         eng = _engine(table="perfreuse")
         sql = "SELECT city, SUM(v) FROM perfreuse GROUP BY city"
         first = eng.query(sql).stats
         second = eng.query(sql).stats
-        # cold: compile wall time recorded; warm: plan-cache hit copies the
-        # captured cost without re-lowering, and pays no compile
-        assert first.compile_ms > 0
+        assert first.compile_ms > 0  # cold: the first call's wall time
         assert second.compile_ms == 0.0
-        assert second.kernel_bytes == pytest.approx(first.kernel_bytes)
-        assert second.kernel_cost_source == first.kernel_cost_source
+        assert second.kernel_bytes == pytest.approx(first.kernel_bytes) and first.kernel_bytes > 0
+        assert lowered == []
 
-    def test_explain_analyze_interpret_pallas_shows_cost_columns(self, monkeypatch):
-        # the acceptance shape: a Pallas-backed group-by scan on CPU tier-1
-        # (interpret mode) surfaces per-operator Bytes/Flops/Roofline_Pct
-        # through the analytic fallback
+    def test_explain_analyze_interpret_pallas_shows_bytes(self, monkeypatch):
+        # a Pallas-backed group-by scan on CPU tier-1 (interpret mode)
+        # surfaces per-operator Bytes, and none of the retired columns
         monkeypatch.setenv("PINOT_TPU_SCAN_BACKEND", "interpret")
         ops.scan_backend.cache_clear()
-        # a peak this low keeps every measured roofline % far above the
-        # two-decimal rounding, however slow the interpreted kernel runs
-        monkeypatch.setenv("PINOT_TPU_PEAK_HBM_BPS", "1e3")
-        perf.peak_hbm_bytes_per_sec.cache_clear()
         try:
             eng = _engine(table="perfinterp", rows=170)
             res = eng.query(
                 "EXPLAIN ANALYZE SELECT city, SUM(v) FROM perfinterp GROUP BY city"
             )
             assert res.columns == [
-                "Operator", "Operator_Id", "Parent_Id", "Actual_Ms", "Rows",
-                "Bytes", "Flops", "Roofline_Pct",
+                "Operator", "Operator_Id", "Parent_Id", "Actual_Ms", "Rows", "Bytes",
             ]
+            assert all(len(r) == len(res.columns) for r in res.rows)
             gb = [r for r in res.rows if str(r[0]).startswith(("GROUP_BY", "AGGREGATE"))]
             assert gb, res.rows
-            op = gb[0]
-            assert op[5] > 0 and op[6] > 0  # Bytes, Flops
-            assert op[7] is None or op[7] > 0  # Roofline_Pct when fence measured
-            # roofline must be measured somewhere in the plan: the fence-
-            # owning COMBINE row or a TRACE(device_wait) span carries it
-            roofs = [r[7] for r in res.rows if r[7] is not None]
-            assert roofs and all(v > 0 for v in roofs)
-            trace_launch = [r for r in res.rows if str(r[0]).startswith("TRACE(launch")]
-            assert any(r[5] for r in trace_launch)  # span-level kernelBytes
+            assert gb[0][5] > 0  # Bytes
+            trace_launch = [r for r in res.rows if str(r[0]).startswith("TRACE(launch:")]
+            assert trace_launch and all(r[5] > 0 for r in trace_launch)  # span-level kernelBytes
+            assert sum(r[5] for r in trace_launch) == pytest.approx(gb[0][5])
+            # and the spans say the bytes and nothing else of a cost
+            spans = _spans(res.stats.trace)
+            assert all(set(n["attrs"]) == {"segment", "kernelBytes"} for n in spans["launch"])
+            assert [set(n["attrs"]) for n in spans["device_wait"]] == [{"launches", "kernelBytes"}]
         finally:
             ops.scan_backend.cache_clear()
-            perf.peak_hbm_bytes_per_sec.cache_clear()
 
 
 # ---------------------------------------------------------------------------
-# perf ledger
+# stats window
 # ---------------------------------------------------------------------------
-class TestPerfLedger:
+class TestShapeStats:
     def test_record_snapshot_and_gauges(self):
-        led = perf.PerfLedger(window=4)
+        led = perf.ShapeStats(window=4)
         for i in range(6):  # overflow the window: deques stay bounded
             led.record(
                 "t", "abc123", rows=1000, time_ms=10.0, kernel_bytes=8000.0,
@@ -252,19 +166,26 @@ class TestPerfLedger:
         assert sh["rowsPerSec"]["last"] == pytest.approx(100000.0)
         assert sh["planCacheHitRate"] == pytest.approx(5 / 6, abs=1e-3)
         assert sh["compileMsTotal"] == pytest.approx(5.0)
-        assert sh["rooflinePct"]["last"] > 0
+        assert sh["bytesPerSec"]["last"] == pytest.approx(800000.0)
+        assert set(sh) == {
+            "queries", "qps", "rowsPerSec", "bytesPerSec", "latencyMs", "compileMsTotal",
+            "planCacheHitRate",
+        }
         assert sh["qps"] >= 0
 
-    def test_global_ledger_exports_table_gauges(self):
-        perf.PERF_LEDGER.record("gt", "fp", rows=100, time_ms=5.0, kernel_bytes=400.0)
+    def test_global_window_exports_table_gauges(self):
+        perf.SHAPE_STATS.record("gt", "fp", rows=100, time_ms=5.0, kernel_bytes=400.0)
         snap = METRICS.snapshot()
         assert snap["gauges"]["perf.gt.rowsPerSec"] == pytest.approx(20000.0)
-        assert "perf.gt.bytesPerSec" in snap["gauges"]
+        assert snap["gauges"]["perf.gt.bytesPerSec"] == pytest.approx(80000.0)
+        assert {k for k in snap["gauges"] if k.startswith("perf.gt.")} == {
+            "perf.gt.rowsPerSec", "perf.gt.bytesPerSec", "perf.gt.qps",
+        }
 
-    def test_sse_query_lands_in_global_ledger(self):
+    def test_sse_query_lands_in_global_window(self):
         eng = _engine(table="perfledger")
         eng.query("SELECT COUNT(*) FROM perfledger")
-        snap = perf.PERF_LEDGER.snapshot()
+        snap = perf.SHAPE_STATS.snapshot()
         assert "perfledger" in snap["tables"]
         t = snap["tables"]["perfledger"]
         assert t["queries"] >= 1
@@ -365,7 +286,7 @@ class TestFederation:
 # slow log perf fields
 # ---------------------------------------------------------------------------
 class TestSlowLogPerfFields:
-    def test_entry_carries_kernel_cost_and_roofline(self):
+    def test_entry_carries_bytes_compile_time_and_row_rate(self):
         class R:
             stats = ExecutionStats()
             rows = [(1,)]
@@ -373,16 +294,14 @@ class TestSlowLogPerfFields:
         R.stats.time_ms = 10.0
         R.stats.num_docs_scanned = 1000
         R.stats.kernel_bytes = 8.0e6
-        R.stats.kernel_flops = 2.0e6
-        R.stats.kernel_cost_source = "analytic"
         R.stats.compile_ms = 3.0
-        R.stats.device_ms = 8.0
         log = SlowQueryLog(capacity=4, slow_ms=1e9)
         entry = log.record("SELECT 1", "fp", result=R())
         assert entry["kernelBytes"] == 8.0e6
-        assert entry["costSource"] == "analytic"
-        assert entry["rooflinePct"] > 0
+        assert entry["compileMs"] == 3.0
         assert entry["rowsPerSec"] == pytest.approx(100000.0)
+        lean = set(log.record("SELECT 1", "fp", result=type("R0", (), {"stats": ExecutionStats(), "rows": []})()))
+        assert set(entry) - lean == {"kernelBytes", "compileMs", "rowsPerSec"}
 
     def test_entry_without_cost_stays_lean(self):
         class R:
@@ -395,128 +314,28 @@ class TestSlowLogPerfFields:
 
 
 # ---------------------------------------------------------------------------
-# bench-history regression gate
+# cli perf: the stats-window view, and no gate
 # ---------------------------------------------------------------------------
-def _rec(scale=1.0, backend="xla", rows=1000, rv=0.02):
-    return {
-        "schema": 1,
-        "bench": "ssb_groupby",
-        "backend": backend,
-        "rows": rows,
-        "metrics": {
-            "kernel_rows_per_sec": 1e6 * scale,
-            "e2e_rows_per_sec": 5e5 * scale,
-            "warm_p50_rows_per_sec": 8e5 * scale,
-            "effective_bytes_per_sec": 9e6 * scale,
-        },
-        "noise": {"run_variance": rv},
-    }
-
-
-class TestRegressionGate:
-    def test_identical_records_pass(self):
-        v = perf.check_regression(_rec(), _rec())
-        assert v["ok"] and len(v["checks"]) == 4
-
-    def test_twenty_percent_drop_always_fails(self):
-        # the acceptance bar: a true >=20% throughput regression trips the
-        # gate regardless of measured noise
-        v = perf.check_regression(_rec(scale=0.80), _rec(), threshold=None)
-        assert not v["ok"] and v["reasons"]
-        v_noisy = perf.check_regression(_rec(scale=0.80, rv=10.0), _rec(rv=10.0))
-        assert not v_noisy["ok"]  # allowance clamps below 20%
-
-    def test_small_drop_within_noise_passes(self):
-        assert perf.check_regression(_rec(scale=0.90), _rec())["ok"]
-
-    def test_incomparable_records_fail(self):
-        v = perf.check_regression(_rec(backend="interpret"), _rec())
-        assert not v["ok"] and any("incomparable" in r for r in v["reasons"])
-
-    def test_empty_comparison_fails(self):
-        v = perf.check_regression({"metrics": {}}, {"metrics": {}})
-        assert not v["ok"] and "no gated metrics" in v["reasons"][0]
-
-    def test_allowance_clamps(self):
-        assert perf.regression_allowance(_rec(rv=0.0)) == pytest.approx(0.15)
-        assert perf.regression_allowance(_rec(rv=1.0)) == pytest.approx(0.19)
-
-    def test_history_roundtrip_skips_corrupt_lines(self, tmp_path):
-        p = tmp_path / "hist.jsonl"
-        perf.append_bench_history(str(p), _rec())
-        p.write_text(p.read_text() + "{torn line\n")
-        perf.append_bench_history(str(p), _rec(scale=1.1))
-        hist = perf.load_bench_history(str(p))
-        assert len(hist) == 2
-        assert hist[-1]["metrics"]["kernel_rows_per_sec"] == pytest.approx(1.1e6)
-
-    def test_bench_record_distills_report(self):
-        report = {
-            "value": 123.0,
-            "value_e2e": 45.0,
-            "run_variance": 0.07,
-            "rows": 10,
-            "backend": "xla",
-            "effective_bytes_per_sec": 999.0,
-            "distinct_literal_sweep": {"warm_p50_rows_per_sec": 77.0},
-            "plan_cache": {"hit_rate": 0.9},
-            "roofline": {"device_kind": "cpu", "kernel_roofline_pct": 1.5,
-                         "cost_bytes_per_sec": 1000.0},
-        }
-        rec = perf.bench_record(report)
-        assert rec["metrics"]["kernel_rows_per_sec"] == 123.0
-        assert rec["metrics"]["warm_p50_rows_per_sec"] == 77.0
-        assert rec["metrics"]["roofline_pct"] == 1.5
-        assert rec["noise"]["run_variance"] == 0.07
-
-    def test_cli_perf_check_exits_nonzero_on_synthetic_regression(self, tmp_path, capsys):
+class TestCliPerf:
+    def test_prints_the_served_shapes(self, capsys):
         from pinot_tpu.tools.cli import main
 
-        hist = tmp_path / "bench_history.jsonl"
-        base = tmp_path / "BENCH_BASELINE.json"
-        base.write_text(json.dumps(_rec()))
-        perf.append_bench_history(str(hist), _rec(scale=0.75))  # injected -25%
-        rc = main(["perf", "--check", "--history", str(hist), "--baseline", str(base)])
-        assert rc == 1
+        eng = _engine(table="perfcli")
+        srv = QueryServer(eng).start()
+        try:
+            eng.query("SELECT city, SUM(v) FROM perfcli GROUP BY city")
+            assert main(["perf", "--url", f"http://127.0.0.1:{srv.port}"]) == 0
+        finally:
+            srv.stop()
         out = capsys.readouterr().out
-        assert "FAIL" in out
+        assert "table perfcli: 1 quer" in out
+        assert "bytes/s last=" in out and "roofline" not in out
 
-    def test_cli_perf_check_passes_on_healthy_run(self, tmp_path, capsys):
+    @pytest.mark.parametrize("flag", ["--check", "--history", "--baseline", "--threshold"])
+    def test_the_gate_options_are_argparse_errors(self, flag, capsys):
         from pinot_tpu.tools.cli import main
 
-        hist = tmp_path / "bench_history.jsonl"
-        base = tmp_path / "BENCH_BASELINE.json"
-        base.write_text(json.dumps(_rec()))
-        perf.append_bench_history(str(hist), _rec(scale=1.02))
-        rc = main(["perf", "--check", "--history", str(hist), "--baseline", str(base)])
-        assert rc == 0
-
-    def test_cli_perf_check_fails_on_missing_history(self, tmp_path):
-        from pinot_tpu.tools.cli import main
-
-        base = tmp_path / "BENCH_BASELINE.json"
-        base.write_text(json.dumps(_rec()))
-        rc = main([
-            "perf", "--check",
-            "--history", str(tmp_path / "nope.jsonl"),
-            "--baseline", str(base),
-        ])
-        assert rc == 1
-
-
-@pytest.mark.slow
-def test_repo_bench_baseline_gate_passes():
-    """The committed bench history vs the pinned baseline must pass the
-    gate — this is the regression check CI runs after a real bench run."""
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    hist = os.path.join(root, "bench_history.jsonl")
-    base = os.path.join(root, "BENCH_BASELINE.json")
-    if not (os.path.exists(hist) and os.path.exists(base)):
-        pytest.skip("no committed bench artifacts")
-    latest = perf.load_bench_history(hist)[-1]
-    with open(base, "r", encoding="utf-8") as f:
-        baseline = json.load(f)
-    verdict = perf.check_regression(latest, baseline)
-    assert verdict["ok"], verdict["reasons"]
+        with pytest.raises(SystemExit) as e:
+            main(["perf", flag] + ([] if flag == "--check" else ["x"]))
+        assert e.value.code == 2
+        assert "unrecognized arguments" in capsys.readouterr().err

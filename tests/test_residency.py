@@ -34,7 +34,7 @@ from pinot_tpu.segment.residency import (
 from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
 from pinot_tpu.utils import crashpoints
 from pinot_tpu.utils.metrics import METRICS
-from pinot_tpu.utils.perf import PerfLedger
+from pinot_tpu.utils.perf import ShapeStats
 
 _seq = itertools.count()
 
@@ -144,7 +144,7 @@ class TestStateMachine:
 
 class TestCostRankedEviction:
     def test_cold_table_evicted_before_hot_despite_recency(self):
-        ledger = PerfLedger()
+        ledger = ShapeStats()
         # hot table: high bytes/s in the r13 ledger -> expensive to refetch
         ledger.record("hotT", "fp", rows=1e6, time_ms=10.0, kernel_bytes=1e9)
         res = _mgr(1_000, ledger=ledger)

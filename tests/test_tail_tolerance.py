@@ -26,7 +26,6 @@ from pinot_tpu.cluster.admission import AdmissionController, QueryKilledError
 from pinot_tpu.segment.builder import build_segment
 from pinot_tpu.spi.config import SegmentsConfig, TableConfig
 from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
-from pinot_tpu.utils import perf
 from pinot_tpu.utils.metrics import METRICS
 
 
@@ -509,42 +508,3 @@ class TestTailAcceptance:
         assert settled == launched  # one loser per engaged pair, all reclaimed
         assert METRICS.counter("broker.scatterServerFailures").value == 0
         assert broker.health.state("server0") == "closed"
-
-
-# ---------------------------------------------------------------------------
-# perf gate: hedged_p99_ms is lower-is-better
-# ---------------------------------------------------------------------------
-class TestPerfGateLowerIsBetter:
-    @staticmethod
-    def _rec(hedged_p99):
-        return {
-            "schema": 1,
-            "bench": "ssb_groupby",
-            "backend": "cpu",
-            "rows": 1000,
-            "metrics": {"kernel_rows_per_sec": 1e9, "hedged_p99_ms": hedged_p99},
-        }
-
-    def test_latency_rise_fails_the_gate(self):
-        v = perf.check_regression(self._rec(14.0), self._rec(10.0), threshold=0.10)
-        assert not v["ok"]
-        assert any("hedged_p99_ms" in r for r in v["reasons"])
-
-    def test_latency_drop_passes_the_gate(self):
-        v = perf.check_regression(self._rec(8.0), self._rec(10.0), threshold=0.10)
-        assert v["ok"]
-
-    def test_bench_record_extracts_tail_section(self):
-        rec = perf.bench_record(
-            {
-                "backend": "cpu",
-                "tail_latency": {
-                    "hedged": {"p99_ms": 12.5},
-                    "unhedged": {"p99_ms": 80.0},
-                    "hedge_rate": 0.44,
-                },
-            }
-        )
-        assert rec["metrics"]["hedged_p99_ms"] == 12.5
-        assert rec["metrics"]["unhedged_p99_ms"] == 80.0
-        assert rec["metrics"]["hedge_rate"] == 0.44

@@ -41,6 +41,14 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
     )
 
+# Compiles of a quarter second or more are kept, not only those of a second
+# or more (JAX's default): on the chip a dense template's per-segment and
+# narrow group programs compile in 0.3-1 s each, a plan has up to six
+# programs, and a process that starts with a warm cache should compile none
+# of them again (PERF.md, PR 29).  Not everything: the CPU backend's many
+# tiny programs are cheaper to compile than to look up.
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.25)
+
 __version__ = "0.1.0"
 
 from pinot_tpu.spi.schema import DataType, FieldSpec, FieldRole, Schema  # noqa: E402,F401

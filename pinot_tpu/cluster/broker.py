@@ -2025,13 +2025,14 @@ class Broker:
         return results
 
     def _warm_peers(self, ctx: QueryContext, table: str, cold: str, seg_names: List[str], meta, warming: List) -> None:
-        """Server `cold` is about to compile this query's program for its
-        device.  A jitted program compiles once for EVERY device it runs on,
+        """Server `cold` is about to compile one of this query's programs for
+        its device.  A jitted program compiles once for EVERY device it runs on,
         and the scatter calls servers in turn, so a query shape new to a
         table on N chips would compile N times one after another (four chips:
         a first query of minutes, beyond a client's patience).  So the
         table's other live servers that sit on another device compile it at
-        the same time, each on one of its own segments of this query
+        the same time, each over its own segments of this query, so that it
+        compiles the group widths its served call will launch
         (`ServerInstance.warm`, `warm-<server>` threads appended to
         `warming`): the scatter waits for them before it calls the next
         server, and before it returns.  Once a query; servers that share the
@@ -2041,21 +2042,21 @@ class Broker:
         servers = self.coordinator.servers
         device = servers[cold].device
         view = self.coordinator.external_view(table)
-        one: Dict[str, str] = {}  # peer -> one of its segments of this query
+        mine: Dict[str, List[str]] = {}  # peer -> its segments of this query
         for seg in seg_names:
             for peer in view.get(seg, ()):
-                if peer != cold and peer not in one and servers[peer].device != device:
-                    one[peer] = seg
+                if peer != cold and servers[peer].device != device:
+                    mine.setdefault(peer, []).append(seg)
 
-        def warm(peer: str, seg: str) -> None:
+        def warm(peer: str, segs: List[str]) -> None:
             try:
-                servers[peer].warm(ctx, seg, table_schema=meta.schema)
+                servers[peer].warm(ctx, segs, table_schema=meta.schema)
             except Exception:  # noqa: BLE001 — the scatter's own call meets the fault and accounts for it
                 METRICS.counter("broker.peerWarmupFailures").inc()
 
-        for peer, seg in one.items():
+        for peer, segs in mine.items():
             METRICS.counter("broker.peerWarmups").inc()
-            t = threading.Thread(target=warm, args=(peer, seg), daemon=True, name=f"warm-{peer}")
+            t = threading.Thread(target=warm, args=(peer, segs), daemon=True, name=f"warm-{peer}")
             warming.append(t)
             t.start()
 

@@ -170,7 +170,7 @@ class ServerInstance:
         (segment results, stats) — the DataTable the reference ships back.
 
         `on_first_launch`: zero-arg hook, called when a launch is about to
-        compile its program for this server's device (executor.launch_segment).
+        compile its program for this server's device (executor._enqueue).
 
         `cancel`: optional zero-arg probe (the broker watchdog's closure)
         returning a kill reason or None — checked between kernels alongside
@@ -183,18 +183,18 @@ class ServerInstance:
 
         Tracing (ctx option `trace`): builds a per-server span subtree —
         dispatch (per segment a launch:<segment> span over the executor's
-        launch_plan / launch_ship / launch_enqueue, the last ending in
-        launch_release: launch_ship is the resident columns' lookup alone,
-        the query's parameters ride the jitted call inside launch_enqueue as
-        host numpy and run on self.device, and launch_release has nothing
-        left to drop), device_wait (ONE block_until_ready over every
-        pending output: the device-compute share the async dispatch
-        hides), then per-segment collect spans —
-        annotated with segments/docs/backend and any fault-plan events, and
-        ships it back via stats.trace for the broker to graft.  Traced or
-        not, every span is a profiler annotation carrying `query_id` (the
-        broker's), and the stages' sums go once a query into this server's
-        timers (_STAGE_TIMERS)."""
+        launch_plan / launch_ship, and per GROUP of segments that share a
+        compiled kernel one launch_enqueue, ending in launch_release:
+        executor.QueryLaunches; launch_ship is the resident columns' lookup
+        alone, the members' parameters ride the jitted call inside
+        launch_enqueue as host numpy and run on self.device), device_wait
+        (ONE block_until_ready over every pending output: the device-compute
+        share the async dispatch hides; `launches` = jitted calls), then one
+        collect span a group — annotated with segments/docs/backend and any
+        fault-plan events, and ships it back via stats.trace for the broker
+        to graft.  Traced or not, every span is a profiler annotation
+        carrying `query_id` (the broker's), and the stages' sums go once a
+        query into this server's timers (_STAGE_TIMERS)."""
         from pinot_tpu.query.planner import _needed_columns
         from pinot_tpu.utils.metrics import Trace
 
@@ -226,8 +226,10 @@ class ServerInstance:
                 # as a staged fetch instead of 503ing; a window that
                 # exceeds the whole budget cannot fit even transiently
                 # and still raises ReservationError.  The window width is
-                # the autopilot staging_depth knob (read per decision).
-                win = _staging_depth()
+                # the autopilot staging_depth knob (read per decision), or
+                # the widest group the segments launch in, whose members
+                # are resident all at once (executor.group_cap).
+                win = max(_staging_depth(), executor.group_cap(max(est, default=0), self.residency))
                 need = max(
                     (sum(est[i : i + win]) for i in range(len(est))), default=0
                 )
@@ -248,8 +250,11 @@ class ServerInstance:
                 if trace.enabled and len(plan.log) > fault_n0:
                     trace.annotate(faults=[k for (_, _, k, _) in plan.log[fault_n0:]])
             stats = ExecutionStats()
-            results = []
-            pending = []
+            launches = executor.QueryLaunches(
+                ctx, device=self.device, residency=self.residency, trace=trace,
+                on_first_launch=on_first_launch,
+                check=lambda: self._check_budget(deadline, cancelled=launches.uncollected, cancel=cancel),
+            )
             with trace.span("dispatch") as dsp:
                 # host-side pre-filter FIRST: range/bloom metadata prunes
                 # cold segments before any staging, so a pruned segment
@@ -270,10 +275,10 @@ class ServerInstance:
                         continue
                     scan.append(seg)
                 for k, seg in enumerate(scan):
-                    self._check_budget(deadline, cancelled=len(pending), cancel=cancel)
                     if self.residency is not None and k + 1 < len(scan):
                         # double-buffer: stage segment k+1's columns on the
                         # residency staging thread while k dispatches/runs
+                        # (a server with tiered residency launches at width 1)
                         nxt = scan[k + 1]
                         self.residency.submit(
                             nxt.to_device,
@@ -283,40 +288,24 @@ class ServerInstance:
                             residency=self.residency,
                             prefetch=True,
                         )
-                    # pipelined: dispatch all kernels async, then drain (executor.py)
-                    with trace.span(f"launch:{seg.name}", cpu=True, segment=seg.name) as lsp:
-                        st = executor.launch_segment(
-                            ctx, seg, device=self.device, residency=self.residency,
-                            trace=trace, on_first_launch=on_first_launch,
-                        )
-                        pending.append(st)
-                    if lsp is not None:
-                        # as an attr too: beside the span's wall time, the
-                        # rest is waiting (interpreter lock, a lock, the device)
-                        lsp.annotate(cpuMs=round(lsp.cpu_ms, 3))
-                        if st[0] == "pending":
-                            # EXPLAIN ANALYZE's Bytes
-                            lsp.annotate(kernelBytes=st[5].kernel_bytes)
+                    # pipelined: a full group dispatches async while the
+                    # host plans the next, then drain (executor.QueryLaunches)
+                    launches.add(seg)
+                launches.flush()
                 if dsp is not None:
-                    dsp.annotate(launches=len(pending))
+                    dsp.annotate(launches=launches.calls)
             if trace.enabled:
                 # device/host time split: ONE fence over every pending output
                 # (trace-only — the untraced path lets collect's device_get be
                 # the fence so cancellation stays responsive between collects)
                 import jax
 
-                with trace.span("device_wait", launches=len(pending)) as wsp:
-                    jax.block_until_ready(executor.pending_outputs(pending))
+                with trace.span("device_wait", launches=launches.calls) as wsp:
+                    jax.block_until_ready(launches.outputs())
                 if wsp is not None:
-                    wsp.annotate(
-                        kernelBytes=sum(s[5].kernel_bytes for s in pending if s[0] == "pending")
-                    )
-            for i, st in enumerate(pending):
-                self._check_budget(deadline, cancelled=len(pending) - i, cancel=cancel)
-                with trace.span("collect") as csp:
-                    res, seg_stats = executor.collect_segment(st)
-                if csp is not None:
-                    csp.annotate(docs=seg_stats.num_docs_scanned)
+                    wsp.annotate(kernelBytes=launches.kernel_bytes)
+            results = []
+            for res, seg_stats in launches.collect():
                 stats.num_segments_processed += 1
                 stats.num_docs_scanned += seg_stats.num_docs_scanned
                 stats.add_index_uses(seg_stats.filter_index_uses)
@@ -325,7 +314,8 @@ class ServerInstance:
             # server-local series the broker federates into the cluster view
             self.metrics.counter("server.queries").inc()
             self.metrics.counter("server.docsScanned").inc(stats.num_docs_scanned)
-            self.metrics.counter("server.launches").inc(len(pending))
+            self.metrics.counter("server.launches").inc(launches.calls)
+            self.metrics.counter("server.groupedSegments").inc(launches.grouped_segments)
             trace.flush(self.metrics, _STAGE_TIMERS)
             if stats.compile_ms > 0:
                 self.metrics.timer("server.compileMs").update(stats.compile_ms)
@@ -345,24 +335,29 @@ class ServerInstance:
             if ticket is not None:
                 self.budget.release(ticket)
 
-    def warm(self, ctx: QueryContext, seg_name: str, table_schema=None) -> None:
-        """Compile, for this server's device, the program `ctx` runs over the
-        named local segment, by running it once and dropping the answer.  Not
-        a served call: no fault plan, no budget, no query counters; only the
-        compile is recorded (`server.compileMs`), as a served first launch's
-        is.  The broker calls it on a table's other servers while one of them
-        compiles the same program (Broker._scatter)."""
+    def warm(self, ctx: QueryContext, seg_names: List[str], table_schema=None) -> None:
+        """Compile, for this server's device, the programs `ctx` runs over the
+        named local segments, by running it once as `execute` would (the same
+        groups, so the same widths) and dropping the answer.  Not a served
+        call: no fault plan, no budget, no query counters; only the compile
+        is recorded (`server.compileMs`), as a served first launch's is.  The
+        broker calls it on a table's other servers while one of them compiles
+        the same programs (Broker._scatter)."""
         from pinot_tpu.query.planner import _needed_columns
 
-        seg = self.get_segment(ctx.table, seg_name)
-        if seg is None:
-            return
-        if table_schema is not None:
-            seg.ensure_columns(table_schema, _needed_columns(ctx, seg))
-        state = executor.launch_segment(ctx, seg, device=self.device, residency=self.residency)
-        _, stats = executor.collect_segment(state)
-        if stats.compile_ms > 0:
-            self.metrics.timer("server.compileMs").update(stats.compile_ms)
+        launches = executor.QueryLaunches(ctx, device=self.device, residency=self.residency)
+        for name in seg_names:
+            seg = self.get_segment(ctx.table, name)
+            if seg is None:
+                continue
+            if table_schema is not None:
+                seg.ensure_columns(table_schema, _needed_columns(ctx, seg))
+            if not executor.prune_segment(ctx, seg):
+                launches.add(seg)
+        launches.flush()
+        compile_ms = sum(seg_stats.compile_ms for _, seg_stats in launches.collect())
+        if compile_ms > 0:
+            self.metrics.timer("server.compileMs").update(compile_ms)
 
     def execute_batch(
         self,

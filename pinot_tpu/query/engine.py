@@ -118,14 +118,16 @@ class QueryEngine:
         stats = ExecutionStats()
         results = []
         try:
-            # pipelined execution: dispatch every segment kernel (async),
-            # then drain — device compute for segment k overlaps planning/
-            # shipping of k+1 and the collect of earlier segments
+            # pipelined execution: dispatch the segments' kernels (async, one
+            # call a group of segments that share a program:
+            # executor.QueryLaunches), then drain
             from pinot_tpu.query.planner import _needed_columns
 
-            pending = []
+            launches = executor.QueryLaunches(
+                ctx, device=device, trace=trace,
+                check=lambda: deadline.check(f"query on {ctx.table}"),
+            )
             for seg in segments:
-                deadline.check(f"query on {ctx.table}")
                 stats.num_segments_queried += 1
                 stats.total_docs += seg.num_docs
                 # schema evolution: older segments synthesize virtual
@@ -139,28 +141,19 @@ class QueryEngine:
                 if executor.prune_segment(ctx, seg):
                     stats.num_segments_pruned += 1
                     continue
-                with trace.span(f"launch:{seg.name}", segment=seg.name) as lsp:
-                    st = executor.launch_segment(ctx, seg, device=device, trace=trace)
-                    pending.append(st)
-                if lsp is not None and st[0] == "pending":
-                    # EXPLAIN ANALYZE's Bytes
-                    lsp.annotate(kernelBytes=st[5].kernel_bytes)
+                launches.add(seg)
+            launches.flush()
             if trace.enabled:
                 # device/host time split: ONE fence over every pending output
                 # (trace-only — the untraced path lets collect's device_get
                 # fence so deadline checks stay responsive between collects)
                 import jax
 
-                with trace.span("device_wait", launches=len(pending)) as wsp:
-                    jax.block_until_ready(executor.pending_outputs(pending))
+                with trace.span("device_wait", launches=launches.calls) as wsp:
+                    jax.block_until_ready(launches.outputs())
                 if wsp is not None:
-                    wsp.annotate(
-                        kernelBytes=sum(st[5].kernel_bytes for st in pending if st[0] == "pending")
-                    )
-            for st in pending:
-                deadline.check(f"query on {ctx.table}")
-                with trace.span("collect"):
-                    res, seg_stats = executor.collect_segment(st)
+                    wsp.annotate(kernelBytes=launches.kernel_bytes)
+            for res, seg_stats in launches.collect():
                 stats.num_segments_processed += 1
                 stats.num_docs_scanned += seg_stats.num_docs_scanned
                 stats.add_index_uses(seg_stats.filter_index_uses)

@@ -6,16 +6,19 @@ acquire segments, server-side pruning (SegmentPrunerService, value/bloom
 pruners), per-segment plan execution — and the per-segment hot loop of
 SURVEY.md 3.1.
 
-Re-design: "execution" is one jitted kernel call per segment (planner.py);
+Re-design: "execution" is one jitted kernel call per GROUP of a query's
+segments that share a compiled kernel (planner.py; QueryLaunches here);
 this module owns the host-side halves: pruning from metadata before any
-launch, and the post-kernel decode (dense group table -> present keys, the
-sparse-groupby host fallback, selection row gather)."""
+launch, the grouping, and the post-kernel decode per segment (dense group
+table -> present keys, the sparse-groupby host fallback, selection row
+gather)."""
 from __future__ import annotations
 
 import contextlib
 import os
 import threading
 import time
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -95,39 +98,61 @@ def prune_segment(ctx: QueryContext, segment: ImmutableSegment) -> bool:
 # ---------------------------------------------------------------------------
 # Execution
 # ---------------------------------------------------------------------------
-def launch_segment(
-    ctx: QueryContext, segment: ImmutableSegment, device=None, residency=None,
-    trace: Optional[Trace] = None, on_first_launch=None,
-):
-    """Phase 1 of pipelined execution: plan, ship inputs, and DISPATCH the
-    segment kernel (jax dispatch is asynchronous — the call returns as soon
-    as the work is enqueued).  Returns an opaque pending state for
-    collect_segment.
+# A group program is compiled for a power-of-two number of members, so a plan
+# has at most log2(MAX_GROUP_WIDTH) programs beside its own however a query's
+# pruning moves its member count: 40 segments launch as 5 x 8, 37 as 4 x 8 +
+# 4 + 1.  Eight, not more, by measurement on the chip (PERF.md, PR 29): a
+# server's 40 segments then need ONE group program a plan where 32 + 8 need
+# two, and a program's cost is its set-up (compile: ~1 s at 8 and 2.6-4.2 s
+# at 32 for a dense group-by, 6-7 s either way for Q1; trace, lower and load
+# from a warm cache: ~0.7 s), which every process pays for every query shape;
+# 32 + 8 answered 5-6 % more queries a second and took 2.4-2.6 x the parent's
+# cold warm-up and 12-17 % more of a warm start's set-up.
+MAX_GROUP_WIDTH = 8
+# The group program joins its members' columns into one array a column on the
+# device before it scans the kernel over them (planner._join): a temporary of
+# `SegmentPlan.scan_bytes` a member, held to 1/16 of a v5e's 16 GB.  A plan
+# whose segment is larger takes narrower groups.
+GROUP_STACK_BYTES = 1 << 30
 
-    This is the pipeline-parallelism axis (SURVEY.md §2.5): while segment
-    k's kernel runs on device, the host plans/ships segment k+1 and later
-    drains results — the streaming overlap the reference gets from mailbox
-    block streaming.
 
-    The stages are spans of `trace` (the caller's, under its
-    `launch:<segment>` span): launch_plan (star-tree probe + plan cache);
+def group_cap(member_bytes: float, residency=None) -> int:
+    """The widest group (a power of two) of members that each read
+    `member_bytes` of resident columns: MAX_GROUP_WIDTH, held to
+    GROUP_STACK_BYTES of joined columns, and, where HBM is a cache
+    (segment/residency.py), to a quarter of that cache's budget, because a
+    group's members must be resident all at once, beside the segment
+    prefetching behind them and what other queries hold: a server paging a
+    table larger than its cache launches at the width its window holds,
+    down to 1."""
+    room = GROUP_STACK_BYTES
+    if residency is not None:
+        room = min(room, residency.budget.budget_bytes // 4)
+    fits = max(1, int(room // max(member_bytes, 1.0)))
+    return min(MAX_GROUP_WIDTH, 1 << (fits.bit_length() - 1))
+
+
+@dataclass
+class _Member:
+    """One planned segment of a query, its columns resident: what a launch
+    takes a group of."""
+
+    segment: ImmutableSegment
+    plan: planner.SegmentPlan
+    cols: Dict
+    stats: ExecutionStats
+
+
+def _plan_member(ctx, segment, device, residency, trace):
+    """A launch's per-segment stages, as spans of `trace`: launch_plan
+    (star-tree probe + plan cache: dictionary look-ups are per segment) and
     launch_ship (the plan's columns looked up in, or staged into, the
-    device's cache — nothing else: no device array is made for a parameter;
+    device's cache, nothing else: no device array is made for a parameter;
     attr paramArrays counts the host buffers that carry them, one per dtype,
-    planner.pack_params); launch_enqueue (the jitted call, the launch's one
-    trip into the runtime that carries data: those buffers ride it as
-    arguments; it names the plan when it had to compile: the plan's first
-    launch on this device), which ends with
-    its child launch_release (the launch holds no device array of its own,
-    so there is nothing to drop: it times an empty block).
-
-    `on_first_launch` (zero-arg) is called just before the jitted call when
-    that call will compile, i.e. the plan has not run on this device yet:
-    the broker uses it to start the same compile on the table's other
-    servers' devices while this one runs."""
+    planner.pack_params).  Returns the `("done", answer)` state where the
+    star-tree answered, else the _Member to launch."""
     from pinot_tpu.query.startree import try_startree
 
-    trace = trace if trace is not None else Trace()
     with trace.span("launch_plan", segment=segment.name) as psp:
         star = try_startree(ctx, segment)
         plan = planner.plan_segment(ctx, segment) if star is None else None
@@ -143,19 +168,181 @@ def launch_segment(
         total_docs=segment.num_docs,
     )
     stats.filter_index_uses = tuple(plan.index_uses)
+    stats.kernel_bytes = plan.scan_bytes
     with trace.span("launch_ship", segment=segment.name, params=len(plan.param_layout)) as ssp:
+        # a kernel that reads no column gets none (to_device reads an empty
+        # list as every column)
         cols = segment.to_device(
             device=device, columns=plan.needed_columns, packed_codes=True,
             residency=residency,
-        )
+        ) if plan.needed_columns else {}
         if ssp is not None:
             ssp.annotate(paramArrays=len(plan.params))
-    out, stats.compile_ms = _enqueue(
-        trace, plan, (cols, plan.params), device, on_first_launch,
-        segment=segment.name, kind=plan.kind, backend=plan.cache_key[2],
+    return _Member(segment, plan, cols, stats)
+
+
+def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=None):
+    """ONE jitted call for `members`, whose plans share one compiled kernel
+    (`plan.fn` is one object) and differ in their parameters' values: the
+    launch's one trip into the runtime (span launch_enqueue, attrs
+    `segments` = members, `width` = what the program was compiled for).  A
+    lone member calls `plan.fn(cols, params)` itself; N call the plan's
+    program of that width (planner.grouped_plan) with the members' resident
+    column pytrees as a tuple, nothing re-staged, and their packed parameter
+    buffers stacked on the host to [N, n], riding the call as a lone
+    member's ride theirs.  Returns the pending state collect_group takes:
+    the outputs carry a leading member axis when N > 1."""
+    base = members[0].plan
+    width = len(members)
+    if width == 1:
+        program, args = base, (members[0].cols, base.params)
+    else:
+        program = planner.grouped_plan(base, width)
+        args = (
+            tuple(m.cols for m in members),
+            {k: np.stack([m.plan.params[k] for m in members]) for k in base.params},
+        )
+    out, members[0].stats.compile_ms = _enqueue(
+        trace, program, args, device, on_first_launch,
+        segments=width, width=width, kind=base.kind, backend=base.cache_key[2],
     )
-    stats.kernel_bytes = plan.scan_bytes
-    return ("pending", ctx, segment, plan, out, stats)
+    return (
+        "pending", ctx, [m.segment for m in members], [m.plan for m in members], out,
+        [m.stats for m in members],
+    )
+
+
+def launch_segment(
+    ctx: QueryContext, segment: ImmutableSegment, device=None, residency=None,
+    trace: Optional[Trace] = None, on_first_launch=None,
+):
+    """Phase 1 of pipelined execution: plan, ship inputs, and DISPATCH the
+    segment kernel (jax dispatch is asynchronous — the call returns as soon
+    as the work is enqueued).  Returns an opaque pending state for
+    collect_segment.  The group launch's width-1 case: the stages are
+    _plan_member's and _launch_group's.
+
+    `on_first_launch` (zero-arg) is called just before the jitted call when
+    that call will compile, i.e. the program has not run on this device yet:
+    the broker uses it to start the same compile on the table's other
+    servers' devices while this one runs."""
+    trace = trace if trace is not None else Trace()
+    member = _plan_member(ctx, segment, device, residency, trace)
+    if not isinstance(member, _Member):
+        return member
+    return _launch_group(ctx, [member], device, trace, on_first_launch)
+
+
+def _ladder(n: int, cap: int) -> List[int]:
+    """`n` members as widths a group program is compiled for: the largest
+    power of two that fits under `cap`, then the rest the same way."""
+    widths = []
+    while n:
+        w = 1 << (min(n, cap).bit_length() - 1)
+        widths.append(w)
+        n -= w
+    return widths
+
+
+class QueryLaunches:
+    """One query's launches on one device: one jitted call a GROUP of its
+    segments, not one a segment, and one fetch a group.
+
+    `add` plans a segment (span `launch:<segment>` over its launch_plan and
+    launch_ship) and files it with the segments whose plans resolved to the
+    same compiled kernel.  A group that has reached its cap launches at once,
+    so the device works while the host plans the rest (the pipeline
+    SURVEY.md 2.5 asks for: a per-segment launch had it between segments,
+    a group launch has it between groups); `flush` launches what is left,
+    in widths from the ladder.  The cap is what the code can see, and no
+    option (group_cap): the bytes the plan's columns take against what the
+    program's joined columns may take and, under tiered residency, against what the
+    cache can hold at once (the caller still prefetches segment k+1 while k
+    is planned).  A star-tree answer, a plan of another kernel and a lone
+    segment are groups of their own.
+
+    `check` (zero-arg, raises to abandon the query: the deadline, a kill) is
+    called before each segment is planned, before each jitted call, and
+    before each member's decode: abandoning means never collecting (jax
+    dispatch is async; nothing syncs back).  `collect` gives the answers in
+    the order the segments were added."""
+
+    def __init__(self, ctx: QueryContext, device=None, residency=None,
+                 trace: Optional[Trace] = None, on_first_launch=None, check=None):
+        self.ctx = ctx
+        self.device = device
+        self.residency = residency
+        self.trace = trace if trace is not None else Trace()
+        self.on_first_launch = on_first_launch
+        self.check = check if check is not None else (lambda: None)
+        self.calls = 0  # jitted calls made
+        self.grouped_segments = 0  # segments that shared a call
+        self.kernel_bytes = 0.0
+        self.uncollected = 0  # launched groups not yet fetched
+        self._added = 0
+        self._open: Dict[int, List[Tuple[int, _Member]]] = {}  # id(plan.fn) -> (slot, member)
+        self._states: List[Tuple[Tuple, List[int]]] = []  # (state, its members' slots), in launch order
+
+    def add(self, segment: ImmutableSegment) -> None:
+        self.check()
+        slot, self._added = self._added, self._added + 1
+        with self.trace.span(f"launch:{segment.name}", cpu=True, segment=segment.name) as lsp:
+            member = _plan_member(self.ctx, segment, self.device, self.residency, self.trace)
+        if not isinstance(member, _Member):
+            self._states.append((member, [slot]))
+            return
+        self.kernel_bytes += member.plan.scan_bytes
+        if lsp is not None:
+            # cpuMs as an attr too: beside the span's wall time, the rest is
+            # waiting (interpreter lock, a lock); kernelBytes is EXPLAIN
+            # ANALYZE's Bytes
+            lsp.annotate(cpuMs=round(lsp.cpu_ms, 3), kernelBytes=member.plan.scan_bytes)
+        group = self._open.setdefault(id(member.plan.fn), [])
+        group.append((slot, member))
+        if len(group) >= group_cap(member.plan.scan_bytes, self.residency):
+            del self._open[id(member.plan.fn)]
+            self._launch(group)
+
+    def flush(self) -> None:
+        for group in self._open.values():
+            at = 0
+            for width in _ladder(len(group), group_cap(group[0][1].plan.scan_bytes, self.residency)):
+                self._launch(group[at : at + width])
+                at += width
+        self._open = {}
+
+    def _launch(self, group: List[Tuple[int, _Member]]) -> None:
+        self.check()
+        state = _launch_group(
+            self.ctx, [m for _, m in group], self.device, self.trace, self.on_first_launch
+        )
+        self._states.append((state, [slot for slot, _ in group]))
+        self.calls += 1
+        self.uncollected += 1
+        if len(group) > 1:
+            self.grouped_segments += len(group)
+
+    def outputs(self) -> list:
+        """Device outputs of every launched group: what a tracing caller
+        fences on with ONE jax.block_until_ready (pending_outputs)."""
+        return pending_outputs([state for state, _ in self._states])
+
+    def collect(self) -> List[Tuple]:
+        """(segment result, ExecutionStats) of every added segment, in the
+        order added: one `collect` span and one fetch a group."""
+        answers: List = [None] * self._added
+        for state, slots in self._states:
+            if state[0] == "done":
+                answers[slots[0]] = state[1]
+                continue
+            self.check()
+            with self.trace.span("collect", segments=len(slots)) as csp:
+                for slot, answer in zip(slots, collect_group(state, self.check)):
+                    answers[slot] = answer
+            self.uncollected -= 1
+            if csp is not None:
+                csp.annotate(docs=sum(answers[slot][1].num_docs_scanned for slot in slots))
+        return answers
 
 
 def _enqueue(trace, plan, args, device, on_first_launch=None, **attrs):
@@ -166,7 +353,8 @@ def _enqueue(trace, plan, args, device, on_first_launch=None, **attrs):
     the call's wall time is recorded there as the compile time (an AOT
     compile would pay it a second time) and the span says `firstLaunch` /
     `compileMs`.  The span ends with its child launch_release (an empty
-    block, see launch_segment).  Returns the asynchronously dispatched output
+    block: the launch holds no device array of its own, so there is nothing
+    to drop).  Returns the asynchronously dispatched output
     (device_get happens at collect) and the compile ms this call paid, 0.0
     on a warm launch."""
     launched_on = plan.launched_on
@@ -182,7 +370,7 @@ def _enqueue(trace, plan, args, device, on_first_launch=None, **attrs):
             compile_ms = launched_on[device] = (time.perf_counter() - t0) * 1000.0
             if esp is not None:
                 esp.annotate(firstLaunch=True, compileMs=round(compile_ms, 3))
-        with trace.span("launch_release", segment=attrs["segment"]):
+        with trace.span("launch_release"):
             pass  # nothing of the launch's own to drop
     return out, compile_ms
 
@@ -208,15 +396,29 @@ def pending_outputs(states) -> list:
     return [st[4] for st in states if st[0] in ("pending", "pending_batch")]
 
 
-def collect_segment(state):
-    """Phase 2: block on the kernel's outputs and finish host-side."""
+def collect_group(state, check=None):
+    """Phase 2: ONE jax.device_get for the group's outputs (the fence, and
+    the launch's one trip back), then the host-side decode a member on its
+    slice of the leading member axis.  Yields (result, stats) a member, and
+    calls `check` (QueryLaunches) before each decode after the first."""
     import jax
 
+    _, ctx, segments, plans, out, stats_list = state
+    host = jax.device_get(out)
+    for i, (segment, plan, stats) in enumerate(zip(segments, plans, stats_list)):
+        if i and check is not None:
+            check()
+        member = host if len(segments) == 1 else jax.tree_util.tree_map(lambda a: a[i], host)
+        yield _decode_host(ctx, segment, plan, member, stats)
+
+
+def collect_segment(state):
+    """Phase 2 of launch_segment: block on the kernel's outputs and finish
+    host-side."""
     if state[0] == "done":
         return state[1]
-    _, ctx, segment, plan, out, stats = state
-    host = jax.device_get(out)
-    return _decode_host(ctx, segment, plan, host, stats)
+    (answer,) = collect_group(state)
+    return answer
 
 
 def _decode_host(ctx, segment, plan, host, stats):

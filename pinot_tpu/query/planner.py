@@ -45,6 +45,7 @@ from pinot_tpu.query.shape import column_info_from, params_structure
 from pinot_tpu.query.transform import as_row_array, eval_expr
 from pinot_tpu.segment.segment import ImmutableSegment
 from pinot_tpu.spi.schema import DataType
+from pinot_tpu.utils.metrics import METRICS
 from pinot_tpu.utils.perf import scan_bytes_per_row
 
 MAX_DENSE_RAW_INT_RANGE = 1 << 20  # raw ints join the dense keyspace when (max-min+1) is small
@@ -161,6 +162,10 @@ class SegmentPlan:
     # plan every hit builds, so "has not run on this device yet" is one fact
     # however many queries race the first launch
     launched_on: Dict[Any, float] = field(default_factory=dict)
+    # width -> this plan's group program (grouped_plan): the plan-cache
+    # entry's too, shared by reference like launched_on, so a program is
+    # built once a width and leaves the process with its entry
+    widened: Dict[int, "SegmentPlan"] = field(default_factory=dict)
     # plan-cache key (shape fp, segment signature, backend) — the stable
     # identity the cross-query batcher keys its vmapped-fn LRU on, so
     # batching never compiles more than once per (shape, batch width)
@@ -179,6 +184,66 @@ def vmapped_plan(base: SegmentPlan, shared_keys: frozenset) -> SegmentPlan:
     return replace(
         base, fn=jax.jit(jax.vmap(base.fn, in_axes=(None, axes))), launched_on={}
     )
+
+
+# A member's run in a group program's joined column starts on a multiple of
+# this many elements: whole tiles of the chip's 1-D layout for every dtype
+_MEMBER_ALIGN = 1 << 12
+
+
+def _join(xs):
+    """The members' arrays `xs` of one column leaf (one shape) as ONE device
+    array, and the function `i -> member i's array` a traced loop reads it
+    with.  A leaf of at least _MEMBER_ALIGN elements is flattened, padded to
+    whole runs and concatenated end to end, so member i is a contiguous,
+    tile-aligned dynamic slice.  NOT jnp.stack: XLA lays a stacked [S, n]
+    out with the member axis in its (8, 128) tiles, the members interleaved
+    sublane by sublane, and reading one back costs a strided pass (on the
+    chip +23-29 % device time a query, +4-6 % this way: PERF.md, PR 29).
+    Smaller leaves are stacked: nothing to lay out."""
+    x0 = xs[0]
+    if x0.size < _MEMBER_ALIGN:
+        stacked = jnp.stack(xs)
+        return lambda i: stacked[i]
+    run = -(-x0.size // _MEMBER_ALIGN) * _MEMBER_ALIGN
+    joined = jnp.concatenate([jnp.pad(x.reshape(-1), (0, run - x0.size)) for x in xs])
+    return lambda i: jax.lax.dynamic_slice(joined, (i * run,), (x0.size,)).reshape(x0.shape)
+
+
+def grouped_plan(base: SegmentPlan, width: int) -> SegmentPlan:
+    """`base`'s group program for `width` members: `fn(cols, packed)` takes
+    the members' column pytrees as a tuple and their parameter buffers
+    stacked to [width, n], joins each column's members into one array on the
+    device (_join; the copy: the members' stored bytes read and written once
+    more) and scans `base.fn` over the members, so the kernel's body is
+    compiled ONCE whatever the width and every output comes back with a
+    leading member axis.  The arithmetic is the per-segment kernel's.  A
+    program of its own, with a first-launch record of its own, kept on the
+    plan-cache entry (`widened`)."""
+    grouped = base.widened.get(width)
+    if grouped is None:
+        kernel = base.fn
+
+        def group(cols, packed):
+            leaves, treedefs = zip(*(jax.tree_util.tree_flatten(c) for c in cols))
+            with jax.named_scope("group_stack"):
+                takes = [_join(xs) for xs in zip(*leaves)]
+
+            def member(_, at):
+                i, params = at
+                mine = jax.tree_util.tree_unflatten(treedefs[0], [take(i) for take in takes])
+                return (), kernel(mine, params)
+
+            members = (jnp.arange(width, dtype=jnp.int32), packed)
+            return jax.lax.scan(member, (), members, length=width)[1]
+
+        group.__name__ = group.__qualname__ = f"{base.kind}_{base.cache_key[2]}_x{width}"
+        # a program, not a query's plan: it keeps none of `base`'s parameter buffers
+        mine = replace(base, fn=jax.jit(group), params={}, launched_on={}, widened={})
+        grouped = base.widened.setdefault(width, mine)  # a racing query's wins
+        if grouped is mine:
+            METRICS.counter("compile.group.programs").inc()
+    return grouped
 
 
 # Upsert validDocIds ride beside the packed buffers, not in them: the mask
@@ -1046,6 +1111,7 @@ def plan_segment(ctx: QueryContext, segment: ImmutableSegment) -> SegmentPlan:
         if plan.param_layout == cached.param_layout:
             plan.scan_bytes = cached.scan_bytes
             plan.launched_on = cached.launched_on
+            plan.widened = cached.widened
             plan.cache_key = key
             plan.cache_hit = True
             SSE_AUDIT.record_hit(key[0])

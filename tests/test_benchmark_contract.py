@@ -154,3 +154,23 @@ def test_program_still_says_what_the_metric_reads(name, served):
         assert len(family) == N_SERVERS and all(v > 0 for v in family.values()), (name, family)
     if "timer" in spec:
         assert timers.get(spec["timer"], 0) > 0, f"{name}: timer {spec['timer']!r}"
+
+
+def test_launches_per_query_counts_the_jitted_calls(served):
+    """`launches_per_query` reads `device_wait.launches`: the number of
+    jitted calls a server made for the query, one a GROUP of segments, which
+    is the number of `launch_enqueue` spans under that server's root (and
+    `dispatch.launches`); the segments are the `launch:<segment>` spans."""
+    spec = SPECS["launches_per_query"]
+    assert (spec["span"], spec["attr"]) == ("device_wait", "launches")
+    trees, _, _ = served
+    for sql, tree in zip(QUERIES, trees):
+        roots = [n for n in _named(tree, "server") if "server" in n.get("attrs", {})]
+        assert len(roots) == N_SERVERS, sql
+        for root in roots:
+            (wait,), (dispatch,) = _named(root, "device_wait"), _named(root, "dispatch")
+            enqueues = _named(root, "launch_enqueue")
+            assert wait["attrs"]["launches"] == dispatch["attrs"]["launches"] == len(enqueues) == 1, sql
+            # two of the four segments a server, in one call and one fetch
+            assert len(_named(root, "launch")) == enqueues[0]["attrs"]["segments"] == 2
+            assert [n["attrs"]["segments"] for n in _named(root, "collect")] == [2]

@@ -17,6 +17,7 @@ is off around the compiles (an entry written for a described chip cannot be
 read back without one).
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -228,3 +229,61 @@ def test_engine_dense_groupby_step_compiles_for_v5e(topo, no_compile_cache, monk
 
     compiled = plan.fn.lower(*jax.tree_util.tree_map(described, (cols, params))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", [4, 8])
+def test_group_program_compiles_its_kernel_once_for_v5e(one_chip, no_compile_cache, monkeypatch, width):
+    """The served path's group launch (planner.grouped_plan): `width`
+    members' packed columns joined end to end and the per-segment kernel
+    scanned over them.  Whatever the width the program holds ONE Mosaic
+    kernel, in the body of one while loop, so a wide group compiles about as
+    long as a lone segment; and no column is a [width, rows] array, whose
+    member axis the chip would tile (planner._join)."""
+    from pinot_tpu.query import planner
+    from pinot_tpu.segment.builder import build_segment
+    from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
+    from pinot_tpu.sql.parser import parse_query
+
+    monkeypatch.setattr(ops, "scan_backend", lambda: "pallas")
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    planner.plan_cache_clear()
+
+    rows = (1 << 17) + 4321  # whole kernel tiles and a tail
+    rng = np.random.default_rng(0)
+    schema = Schema(
+        "lineorder",
+        [
+            FieldSpec("d_year", DataType.INT),
+            FieldSpec("p_brand", DataType.INT),
+            FieldSpec("lo_quantity", DataType.INT),
+            FieldSpec("lo_revenue", DataType.LONG, role=FieldRole.METRIC),
+        ],
+    )
+    seg = build_segment(schema, {
+        "d_year": rng.integers(1992, 1999, rows).astype(np.int32),
+        "p_brand": rng.integers(0, 1000, rows).astype(np.int32),
+        "lo_quantity": rng.integers(1, 51, rows).astype(np.int32),
+        "lo_revenue": rng.integers(100, 1_000_000, rows),
+    }, "seg0")
+    ctx = parse_query(
+        "SELECT d_year, p_brand, SUM(lo_revenue) FROM lineorder WHERE lo_quantity < 25 "
+        "GROUP BY d_year, p_brand LIMIT 10000"
+    )
+    try:
+        plan = planner.plan_segment(ctx, seg)
+        assert plan.kind == "groupby_dense" and plan.cache_key[2] == "pallas"
+        cols = seg.to_device(columns=plan.needed_columns, packed_codes=True)  # on the CPU: shapes only
+        assert any("codes_packed" in entry for entry in cols.values())
+
+        def described(x, lead=()):
+            return jax.ShapeDtypeStruct(lead + x.shape, x.dtype, sharding=one_chip)
+
+        members = tuple(jax.tree_util.tree_map(described, cols) for _ in range(width))
+        stacked = {k: described(v, (width,)) for k, v in plan.params.items()}
+        text = planner.grouped_plan(plan, width).fn.lower(members, stacked).compile().as_text()
+    finally:
+        planner.plan_cache_clear()
+    assert text.count("tpu_custom_call") == 1 and text.count(" while(") == 1
+    assert f"groupby_dense_pallas_x{width}" in text
+    assert not re.search(rf"\[{width},\d{{5,}}\]", text)  # the outputs are [width, 7000 slots]

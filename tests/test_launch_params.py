@@ -180,7 +180,7 @@ def test_warm_launch_ships_no_parameter_array(name, device_puts):
     trace = Trace(True)
     st = executor.launch_segment(ctx, seg, trace=trace)
     assert len(device_puts) == staged  # resident columns, host parameters: no transfer but the call's
-    plan = st[3]
+    (plan,) = st[3]  # a launch's state holds its members' plans: here the one
     assert plan.kind == kind and plan.cache_hit
     _check_carried(name, plan)
     res, _ = executor.collect_segment(st)
@@ -227,7 +227,7 @@ def test_one_compiled_plan_sees_one_argument_form(name):
     n_compiles = compiles()
     assert n_compiles == 1
     st = executor.launch_segment(ctxs[0], seg)
-    assert st[3].fn is fn and _answer(executor.collect_segment(st)[0]) == wants[0]
+    assert st[3][0].fn is fn and _answer(executor.collect_segment(st)[0]) == wants[0]
     assert _answer(executor.execute_segment(ctxs[0], seg)[0]) == wants[0]
     assert fn._cache_size() == 1
     assert _answer(executor.execute_segment(ctxs[1], seg)[0]) == wants[1]  # same shape, other literals
@@ -240,7 +240,7 @@ def test_one_compiled_plan_sees_one_argument_form(name):
 def _launch_one(ctxs, seg, device, trace, on_first_launch=None):
     st = executor.launch_segment(ctxs[0], seg, device=device, trace=trace, on_first_launch=on_first_launch)
     _, stats = executor.collect_segment(st)
-    return st[3], stats.compile_ms, stats.kernel_bytes
+    return st[3][0], stats.compile_ms, stats.kernel_bytes
 
 
 def _launch_batch(ctxs, seg, device, trace, on_first_launch=None):
@@ -317,16 +317,16 @@ def test_server_on_device_3_answers_from_device_3(name, monkeypatch):
     server.add_segment("t", _segment(upsert, "s3"))
 
     states = []
-    real = executor.launch_segment
+    real = executor._launch_group
     monkeypatch.setattr(
-        executor, "launch_segment", lambda *a, **kw: states.append(real(*a, **kw)) or states[-1]
+        executor, "_launch_group", lambda *a, **kw: states.append(real(*a, **kw)) or states[-1]
     )
     for _ in range(2):  # cold, then warm
         results, stats = server.execute(parse_query(sql), ["s3"])
         assert stats.num_segments_processed == 1
     assert len(states) == 2
     for st in states:
-        plan = st[3]
+        (plan,) = st[3]
         assert bool(plan.needed_columns) == has_columns and len(plan.param_layout) == n_params
         leaves = jax.tree_util.tree_leaves(st[4])
         assert leaves and all(leaf.devices() == {dev} for leaf in leaves)

@@ -191,10 +191,13 @@ class TestEngineTrace:
         spans = _spans(res.stats.trace)
         assert res.stats.query_id and res.stats.query_id.startswith("engine_")
         assert spans["query"][0]["attrs"]["queryId"] == res.stats.query_id
+        # three segments of one plan ride two jitted calls (widths 2 + 1 of
+        # the ladder) and come back in two fetches
         dw = spans["device_wait"][0]
-        assert dw["attrs"]["launches"] == 3
+        assert dw["attrs"]["launches"] == 2
         assert len([n for n in spans if n.startswith("launch:")]) == 3
-        assert len(spans["collect"]) == 3
+        assert [n["attrs"]["width"] for n in spans["launch_enqueue"]] == [2, 1]
+        assert [n["attrs"]["segments"] for n in spans["collect"]] == [2, 1]
 
     def test_untraced_query_has_no_id_overhead_fields(self):
         eng = _engine()
@@ -493,19 +496,29 @@ class TestStages:
         caches = {}
         for n in launches:
             kids = n["children"]
-            assert [k["name"] for k in kids] == ["launch_plan", "launch_ship", "launch_enqueue"]
+            assert [k["name"] for k in kids] == ["launch_plan", "launch_ship"]
             assert all(k["attrs"]["segment"] == n["attrs"]["segment"] for k in kids)
-            assert n["attrs"]["cpuMs"] == n["cpuMs"]
-            plan, ship, enqueue = kids
+            assert n["attrs"]["cpuMs"] == n["cpuMs"] and n["attrs"]["kernelBytes"] > 0
+            plan, ship = kids
             caches[n["name"]] = plan["attrs"]["cache"]
             assert ship["attrs"]["params"] >= 0
-            assert enqueue["attrs"]["kind"] == "groupby_dense" and enqueue["attrs"]["backend"]
-            assert [k["name"] for k in enqueue["children"]] == ["launch_release"]  # its arguments dropped
-            assert ("firstLaunch" in enqueue["attrs"]) == (plan["attrs"]["cache"] == "miss")
             assert sum(k["ms"] for k in kids) <= n["ms"] + 0.01
         assert caches == {"launch:seg0": "miss", "launch:seg1": "hit"}
-        first = [n for n in launches if n["name"] == "launch:seg0"][0]["children"][2]
-        assert first["attrs"]["compileMs"] > 0
+        # the two segments share one compiled kernel: ONE jitted call, a
+        # sibling of their launch:<segment> spans under dispatch, and ONE fetch
+        (dispatch,) = _spans(res.stats.trace)["dispatch"]
+        assert [k["name"] for k in dispatch["children"]] == ["launch:seg0", "launch:seg1", "launch_enqueue"]
+        enqueue = dispatch["children"][2]
+        assert enqueue["attrs"]["kind"] == "groupby_dense" and enqueue["attrs"]["backend"]
+        assert enqueue["attrs"]["segments"] == enqueue["attrs"]["width"] == dispatch["attrs"]["launches"] * 2 == 2
+        assert [k["name"] for k in enqueue["children"]] == ["launch_release"]  # its arguments dropped
+        assert enqueue["attrs"]["firstLaunch"] and enqueue["attrs"]["compileMs"] > 0  # the group program's
+        (collect,) = _spans(res.stats.trace)["collect"]
+        assert collect["attrs"]["segments"] == 2 and collect["attrs"]["docs"] > 0
+        # warm: the same program, no first launch
+        res = broker.query("SET trace = true; " + GROUP_SQL)
+        (enqueue,) = _spans(res.stats.trace)["launch_enqueue"]
+        assert "firstLaunch" not in enqueue["attrs"] and enqueue["attrs"]["width"] == 2
 
     def test_untraced_query_moves_launches_and_every_stage_timer_once(self):
         coord = _cluster(n_servers=1, replication=1, n_segments=3)
@@ -537,7 +550,7 @@ class TestStages:
             assert resp["trace"] is None
         finally:
             front.stop()
-        assert after[0] - before[0] == 3
+        assert after[0] - before[0] == 2  # server.launches counts jitted calls: three segments ride 2 + 1
         assert {k: after[1][k] - before[1][k] for k in after[1]} == dict.fromkeys(STAGE_TIMERS["server"], 1)
         assert {k: after[2][k] - before[2][k] for k in after[2]} == dict.fromkeys(STAGE_TIMERS["process"], 1)
         assert "server.kernelBytes" not in server.metrics.snapshot()["counters"]  # removed: nothing read it
@@ -605,4 +618,6 @@ class TestStages:
         assert [st["query_id"] for st in launches] == [qid, qid]
         assert sorted(st["segment"] for st in launches) == ["seg0", "seg1"]
         parts = [st for n, st in events if n in ("launch_plan", "launch_ship", "launch_enqueue")]
-        assert len(parts) == 6 and {st["query_id"] for st in parts} == {qid}
+        # plan and ship a segment, ONE jitted call for the two
+        assert len(parts) == 5 and {st["query_id"] for st in parts} == {qid}
+        assert [st["segments"] for n, st in events if n == "launch_enqueue"] == [2]

@@ -143,7 +143,7 @@ class TestEngineCostIntegration:
             assert sum(r[5] for r in trace_launch) == pytest.approx(gb[0][5])
             # and the spans say the bytes and nothing else of a cost
             spans = _spans(res.stats.trace)
-            assert all(set(n["attrs"]) == {"segment", "kernelBytes"} for n in spans["launch"])
+            assert all(set(n["attrs"]) == {"segment", "cpuMs", "kernelBytes"} for n in spans["launch"])
             assert [set(n["attrs"]) for n in spans["device_wait"]] == [{"launches", "kernelBytes"}]
         finally:
             ops.scan_backend.cache_clear()

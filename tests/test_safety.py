@@ -95,7 +95,9 @@ class TestMetricsAndTrace:
         names = [c["name"] for c in tr["children"]]
         assert "reduce" in names
         assert sum(1 for n in names if n.startswith("launch:")) == 3
-        assert sum(1 for n in names if n == "collect") == 3
+        # three segments of one plan: two jitted calls (2 + 1) and two fetches
+        assert sum(1 for n in names if n == "launch_enqueue") == 2
+        assert sum(1 for n in names if n == "collect") == 2
         assert all(c["ms"] >= 0 for c in tr["children"])
 
     def test_trace_off_by_default(self):

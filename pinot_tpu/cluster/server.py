@@ -316,6 +316,7 @@ class ServerInstance:
             self.metrics.counter("server.docsScanned").inc(stats.num_docs_scanned)
             self.metrics.counter("server.launches").inc(launches.calls)
             self.metrics.counter("server.groupedSegments").inc(launches.grouped_segments)
+            self.metrics.counter("server.sparseGroups").inc(launches.sparse_groups)
             trace.flush(self.metrics, _STAGE_TIMERS)
             if stats.compile_ms > 0:
                 self.metrics.timer("server.compileMs").update(stats.compile_ms)

@@ -28,8 +28,9 @@ TPU-native design (measured on v5e; numbers for 16M rows x 2406 groups):
     2^53; this path doesn't round at all for int32 inputs.)
   * Float sums use the single-f32 matmul (~1e-5 worst-case relative error;
     float-float "double-single" limbs are a planned upgrade).
-  * Group tables wider than _MATMUL_MAX_GROUPS fall back to the f32 scatter
-    (correct, slower); min/max always use scatter (no matmul semiring).
+  * Group tables wider than _MATMUL_MAX_GROUPS scatter (_wide_group_tables):
+    integers as 12-bit limbs into int32 tables over 2^19-row chunks, exact;
+    min/max always use scatter (no matmul semiring).
   * On CPU (tests, golden comparisons) the "wide" policy scatters directly
     in f64/i64 — bit-exact vs sqlite — still with int32 indices.
 
@@ -223,6 +224,22 @@ def sum_limb_plan64(vmin, vmax) -> int:
     return 8
 
 
+def _int64_magnitude_halves(values, mask):
+    """int64 values (masked rows 0) -> (low, high) uint32 halves of |v| and
+    the row's sign as int32 +-1, every op 32-bit: the column is bitcast to
+    uint32 halves and |v| computed with a one-bit carry (~v + 1 carries iff
+    lo == 0)."""
+    vm = jnp.where(mask, values, jnp.int64(0))
+    halves = lax.bitcast_convert_type(vm, jnp.uint32)  # [n, 2]
+    lo_ix = _i64_low_half_index()
+    lo = halves[..., lo_ix]
+    hi = halves[..., 1 - lo_ix]
+    neg = hi >= np.uint32(1 << 31)
+    alo = jnp.where(neg, ~lo + np.uint32(1), lo)
+    ahi = jnp.where(neg, ~hi + (lo == np.uint32(0)).astype(jnp.uint32), hi)
+    return alo, ahi, jnp.where(neg, np.int32(-1), np.int32(1))
+
+
 def _int64_signed_limbs(values, mask, n_limbs: int, dt):
     """Signed-magnitude 8-bit limb columns + scales for int64 values.
 
@@ -232,19 +249,10 @@ def _int64_signed_limbs(values, mask, n_limbs: int, dt):
     sign-magnitude limbs keep every recombine partial sum bounded by
     sum(|v| mod 2^(8k)) <= sum(|v|), so the ascending-scale f64 recombine
     is BIT-exact while sum(|v|) < 2^53 — the reference's double-accumulate
-    contract (SumAggregationFunction.java).  Every row-axis op is 32-bit:
-    the i64 column is bitcast to uint32 halves, |v| is computed with a
-    one-bit carry (~v + 1 carries iff lo == 0), and each limb (<= 255,
-    exact in bf16) is signed by the row's sign."""
-    vm = jnp.where(mask, values, jnp.int64(0))
-    halves = lax.bitcast_convert_type(vm, jnp.uint32)  # [n, 2]
-    lo_ix = _i64_low_half_index()
-    lo = halves[..., lo_ix]
-    hi = halves[..., 1 - lo_ix]
-    neg = hi >= np.uint32(1 << 31)
-    alo = jnp.where(neg, ~lo + np.uint32(1), lo)
-    ahi = jnp.where(neg, ~hi + (lo == np.uint32(0)).astype(jnp.uint32), hi)
-    sgn = jnp.where(neg, np.int32(-1), np.int32(1))
+    contract (SumAggregationFunction.java).  Every row-axis op is 32-bit
+    (_int64_magnitude_halves), and each limb (<= 255, exact in bf16) is
+    signed by the row's sign."""
+    alo, ahi, sgn = _int64_magnitude_halves(values, mask)
     cols, scales = [], []
     for k in range(n_limbs):
         h = alo if k < 4 else ahi
@@ -256,14 +264,6 @@ def _int64_signed_limbs(values, mask, n_limbs: int, dt):
 
 # entry kinds understood by fused_group_tables
 FUSED_KINDS = ("count", "int_sum", "int64_sum", "f32_sum", "f32_sumsq")
-
-
-def _entry_fallback(kind, values, mask, codes, num_groups):
-    if kind == "count":
-        return group_count(mask, codes, num_groups)
-    if kind in ("int_sum", "int64_sum", "f32_sum"):
-        return group_sum(values, mask, codes, num_groups)
-    return group_sum_sq(values, mask, codes, num_groups)
 
 
 def _fused_wide_tables(entries, codes, num_groups: int):
@@ -465,7 +465,7 @@ def _fused_group_tables_xla(entries, codes, num_groups: int, mask_words, codes_p
     if accum_policy() == "wide":
         return _fused_wide_tables(entries, codes, num_groups)
     if num_groups > _MATMUL_MAX_GROUPS:
-        return [_entry_fallback(k, v, m, codes, num_groups) for k, v, m, _ in entries]
+        return _wide_group_tables(entries, codes, num_groups)
 
     use_f32 = any(k in ("f32_sum", "f32_sumsq") for k, _, _, _ in entries)
     dt = jnp.float32 if use_f32 else jnp.bfloat16
@@ -533,6 +533,97 @@ def _fused_group_tables_xla(entries, codes, num_groups: int, mask_words, codes_p
 
 
 # ---------------------------------------------------------------------------
+# Wide group tables (chunked32, num_groups > _MATMUL_MAX_GROUPS)
+# ---------------------------------------------------------------------------
+# Past _MATMUL_MAX_GROUPS the one-hot matrices stop paying and rows scatter.
+# The chip scatters 32-bit words at ~7 ns a row and 64-bit ones (emulated)
+# twenty times slower, and an f32 table loses a unit as soon as a slot
+# passes 2^24 (SSB Q3.2 on the chip, PERF.md PR 31).  So an integer rides as
+# 12-bit limbs, each scattered into an int32 table of its own over chunks of
+# 2^19 rows: a chunk's limb sum is at most 2^19 * (2^12 - 1) < 2^31, exact;
+# chunks and limbs recombine in int64 at table size, exact while the group's
+# sum is an int64; the table leaves as f64 like every fused table, exact
+# below 2^53.  The limb count comes from the column's min/max at plan time
+# (sum_limb_plan / sum_limb_plan64); without stats it is the storage type's
+# full width, never fewer.  Floats have no exact sum: they keep an f32
+# table over 2^16-row chunks and an f64 combine (relative error of a chunk's
+# accumulation <= 2^16 * 2^-24, as the matmul path's).
+_WIDE_LIMB_BITS = 12
+_WIDE_CHUNK = 1 << 19
+
+
+def _chunked_scatter(col, codes, num_groups: int, chunk_rows: int):
+    """[n] 32-bit updates -> [k, num_groups] per-chunk tables of the same
+    dtype, k = ceil(n / chunk_rows): ONE scatter into a flat k * num_groups
+    table, the chunk folded into the index."""
+    n = codes.shape[0]
+    k = max(1, -(-n // chunk_rows))
+    idx = codes if k == 1 else (lax.iota(jnp.int32, n) // np.int32(chunk_rows)) * np.int32(num_groups) + codes
+    return _scatter_add(jnp.zeros((k * num_groups,), col.dtype), idx, col).reshape(k, num_groups)
+
+
+def _wide_int_limbs(kind, values, mask, limb_plan):
+    """-> [(int32 limb column, bit shift)] of an integer entry: its table is
+    the sum over limbs of limb_table << shift.  int32 and narrower: the
+    two's complement in 8 * n_limbs bits cut into 12-bit limbs, plus, where
+    the plan is signed, minus one a negative row at shift 8 * n_limbs.
+    int64: signed-magnitude limbs (_int64_signed_limbs' reasoning), the
+    sign riding each limb."""
+    m12 = np.uint32((1 << _WIDE_LIMB_BITS) - 1)
+    if kind == "int_sum":
+        n_limbs, signed = limb_plan if limb_plan is not None else (4, True)
+        bits = 8 * n_limbs
+        vm = jnp.where(mask, values, np.int32(0)).astype(jnp.int32)
+        u = vm.astype(jnp.uint32)
+        if bits < 32:
+            u = u & np.uint32((1 << bits) - 1)
+        out = [(((u >> np.uint32(s)) & m12).astype(jnp.int32), s) for s in range(0, bits, _WIDE_LIMB_BITS)]
+        if signed:
+            out.append((-(vm < 0).astype(jnp.int32), bits))
+        return out
+    alo, ahi, sgn = _int64_magnitude_halves(values, mask)
+    out = []
+    for s in range(0, 8 * (limb_plan if limb_plan is not None else 8), _WIDE_LIMB_BITS):
+        if s >= 32:
+            w = ahi >> np.uint32(s - 32)
+        elif s + _WIDE_LIMB_BITS <= 32:
+            w = alo >> np.uint32(s)
+        else:  # the limb that straddles the halves
+            w = (alo >> np.uint32(s)) | (ahi << np.uint32(32 - s))
+        out.append(((w & m12).astype(jnp.int32) * sgn, s))
+    return out
+
+
+def _wide_group_tables(entries, codes, num_groups: int):
+    """fused_group_tables for a table past _MATMUL_MAX_GROUPS under
+    chunked32: one int32 (or, for floats, f32) scatter a limb column, the
+    form described above.  Returns f64[num_groups] tables in entry order."""
+    from pinot_tpu.utils.metrics import METRICS
+
+    METRICS.counter("scan.traced.wide_scatter").inc()  # trace time: which form this plan's table got
+    codes = _i32(codes)
+    out = []
+    with jax.named_scope("wide_scatter"):
+        for kind, values, mask, limb_plan in entries:
+            if kind == "count":
+                # a count is a sum of ones: no chunk can overflow int32
+                t = _chunked_scatter(mask.astype(jnp.int32), codes, num_groups, codes.shape[0])
+                out.append(t[0].astype(jnp.float64))
+            elif kind in ("int_sum", "int64_sum"):
+                total = sum(
+                    _chunked_scatter(limb, codes, num_groups, _WIDE_CHUNK).astype(jnp.int64).sum(axis=0) << np.int64(shift)
+                    for limb, shift in _wide_int_limbs(kind, values, mask, limb_plan)
+                )
+                out.append(total.astype(jnp.float64))
+            else:
+                v = values.astype(jnp.float32)
+                v = jnp.where(mask, v * v if kind == "f32_sumsq" else v, np.float32(0.0))
+                t = _chunked_scatter(v, codes, num_groups, _CHUNK)
+                out.append(t.astype(jnp.float64).sum(axis=0))
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Grouped reductions
 # ---------------------------------------------------------------------------
 def group_sum(values, mask, codes, num_groups: int):
@@ -543,13 +634,8 @@ def group_sum(values, mask, codes, num_groups: int):
         v = jnp.where(mask, values.astype(jnp.float64), 0.0)
         return _scatter_add(jnp.zeros((num_groups,), jnp.float64), codes, v)
     if num_groups > _MATMUL_MAX_GROUPS:
-        if is_int and values.dtype.itemsize > 4:
-            # exact-below-2^53 f64 scatter (matches the sparse path and the
-            # reference's double accumulate); this path is scatter-bound
-            # already, so the emulated-f64 adds cost little extra
-            v = jnp.where(mask, values.astype(jnp.float64), 0.0)
-            return _scatter_add(jnp.zeros((num_groups,), jnp.float64), codes, v)
-        return _scatter_group_sum_f32(values, mask, codes, num_groups)
+        kind = "f32_sum" if not is_int else "int_sum" if values.dtype.itemsize <= 4 else "int64_sum"
+        return _wide_group_tables([(kind, values, mask, None)], codes, num_groups)[0]
     if is_int and values.dtype.itemsize <= 4:
         # exact limb path (int32 and narrower)
         vm = jnp.where(mask, values, np.int32(0)).astype(jnp.int32)
@@ -567,17 +653,6 @@ def group_sum(values, mask, codes, num_groups: int):
     return _matmul_group_sum_f32(v, codes, num_groups)
 
 
-def _scatter_group_sum_f32(values, mask, codes, num_groups: int):
-    """Fallback for wide group tables: chunked f32 scatter + f64 combine."""
-    n = values.shape[0]
-    k = -(-n // _CHUNK)
-    v = jnp.where(mask, values.astype(jnp.float32), np.float32(0.0))
-    chunk_ids = lax.iota(jnp.int32, n) // np.int32(_CHUNK)
-    idx = chunk_ids * np.int32(num_groups) + codes
-    table = _scatter_add(jnp.zeros((k * num_groups,), jnp.float32), idx, v)
-    return table.reshape(k, num_groups).astype(jnp.float64).sum(axis=0)
-
-
 def group_sum_sq(values, mask, codes, num_groups: int):
     if accum_policy() == "wide":
         v = values.astype(jnp.float64)
@@ -592,12 +667,7 @@ def group_count(mask, codes, num_groups: int):
     if accum_policy() == "wide":
         return _scatter_add(jnp.zeros((num_groups,), jnp.int64), codes, mask.astype(jnp.int64))
     if num_groups > _MATMUL_MAX_GROUPS:
-        n = mask.shape[0]
-        k = -(-n // _CHUNK)
-        chunk_ids = lax.iota(jnp.int32, n) // np.int32(_CHUNK)
-        idx = chunk_ids * np.int32(num_groups) + codes
-        table = _scatter_add(jnp.zeros((k * num_groups,), jnp.int32), idx, mask.astype(jnp.int32))
-        return table.reshape(k, num_groups).astype(jnp.int64).sum(axis=0)
+        return _wide_group_tables([("count", None, mask, None)], codes, num_groups)[0].astype(jnp.int64)
     # single-limb matmul: per-chunk counts <= _CHUNK, exact in f32
     stacked = mask.astype(jnp.bfloat16)[:, None]
     return _matmul_group_table(stacked, [1.0], codes, num_groups).astype(jnp.int64)

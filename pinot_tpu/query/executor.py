@@ -279,6 +279,7 @@ class QueryLaunches:
         self.grouped_segments = 0  # segments that shared a call
         self.kernel_bytes = 0.0
         self.uncollected = 0  # launched groups not yet fetched
+        self.sparse_groups = 0  # groups the collected sparse tables held, summed over segments
         self._added = 0
         self._open: Dict[int, List[Tuple[int, _Member]]] = {}  # id(plan.fn) -> (slot, member)
         self._states: List[Tuple[Tuple, List[int]]] = []  # (state, its members' slots), in launch order
@@ -337,8 +338,10 @@ class QueryLaunches:
                 continue
             self.check()
             with self.trace.span("collect", segments=len(slots)) as csp:
-                for slot, answer in zip(slots, collect_group(state, self.check)):
+                for slot, answer in zip(slots, collect_group(state, self.check, self.trace)):
                     answers[slot] = answer
+                if state[3][0].kind == "groupby_sparse":
+                    self.sparse_groups += sum(answers[slot][1].num_groups for slot in slots)
             self.uncollected -= 1
             if csp is not None:
                 csp.annotate(docs=sum(answers[slot][1].num_docs_scanned for slot in slots))
@@ -396,20 +399,35 @@ def pending_outputs(states) -> list:
     return [st[4] for st in states if st[0] in ("pending", "pending_batch")]
 
 
-def collect_group(state, check=None):
+def collect_group(state, check=None, trace: Optional[Trace] = None):
     """Phase 2: ONE jax.device_get for the group's outputs (the fence, and
     the launch's one trip back), then the host-side decode a member on its
-    slice of the leading member axis.  Yields (result, stats) a member, and
-    calls `check` (QueryLaunches) before each decode after the first."""
+    slice of the leading member axis.  Returns [(result, stats)] a member,
+    and calls `check` (QueryLaunches) before each decode after the first.
+    A group-by's decodes sit in a span `table_decode` of `trace`: the
+    fetched tables' bytes (`tableBytes`), the slots of a member's table
+    (`keySpace`: the dense key space, or the sparse table's fixed size) and
+    the groups the members' tables held (`groups`)."""
     import jax
 
     _, ctx, segments, plans, out, stats_list = state
     host = jax.device_get(out)
-    for i, (segment, plan, stats) in enumerate(zip(segments, plans, stats_list)):
-        if i and check is not None:
-            check()
-        member = host if len(segments) == 1 else jax.tree_util.tree_map(lambda a: a[i], host)
-        yield _decode_host(ctx, segment, plan, member, stats)
+    answers = []
+    plan = plans[0]
+    table = trace is not None and plan.kind.startswith("groupby")
+    with trace.span("table_decode", kind=plan.kind) if table else contextlib.nullcontext() as tsp:
+        for i, (segment, member_plan, stats) in enumerate(zip(segments, plans, stats_list)):
+            if i and check is not None:
+                check()
+            member = host if len(segments) == 1 else jax.tree_util.tree_map(lambda a: a[i], host)
+            answers.append(_decode_host(ctx, segment, member_plan, member, stats))
+        if tsp is not None:
+            tsp.annotate(
+                tableBytes=sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(host)),
+                keySpace=plan.num_groups if plan.kind == "groupby_dense" else min(plan.num_groups, ctx.num_groups_limit),
+                groups=sum(stats.num_groups for stats in stats_list),
+            )
+    return answers
 
 
 def collect_segment(state):

@@ -942,6 +942,9 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
              [{field: table[num_slots]}] per agg)."""
     from jax import lax
 
+    from pinot_tpu.utils.metrics import METRICS
+
+    METRICS.counter("scan.traced.sparse_sort").inc()  # trace time: this plan's table is sorted, not scattered by key
     with jax.named_scope("sparse_sort"):
         n = tmask.shape[0]
         if num_groups is not None and num_slots >= num_groups:

@@ -10,6 +10,13 @@ the front door, a traced group-by and a traced scalar sum must produce every
 span and attr the file names, and the registries must hold every counter,
 counter family and timer it names.
 
+The metrics of the layer `wide and sparse group-by` (PR 31) read what only a
+group table past the one-hot kernel produces, and the device-trace ones among
+them pick their templates by a trace-time counter (`served_by_counter(s)`):
+they are cases of their own, on a table whose key space is wide, served once
+through the wide scatter under the chip's accumulation policy and once
+through the sparse sort.
+
 The files are read as data: nothing of `benchmarks/lib` is imported, and no
 number is checked, only presence.
 """
@@ -23,8 +30,10 @@ import jax
 import numpy as np
 import pytest
 
+from pinot_tpu import ops
 from pinot_tpu.cluster import Broker, Coordinator, ServerInstance
 from pinot_tpu.cluster.rest import QueryServer
+from pinot_tpu.ops import segmented
 from pinot_tpu.query import planner
 from pinot_tpu.segment.builder import build_segment
 from pinot_tpu.spi.config import TableConfig
@@ -37,17 +46,23 @@ SPAN_KEYS = ("spans", "span", "numerator", "denominator")
 REGISTRY_KEYS = ("counter", "prefix", "timer")
 
 
+DRILL_LAYER = "wide and sparse group-by"
+
+
 def _program_metrics():
-    specs = {}
+    """(the files that read spans, counters or timers; every file of DRILL_LAYER)"""
+    specs, drill = {}, {}
     for path in sorted(glob.glob(os.path.join(ROOT, "benchmarks", "layer_metrics", "*.json"))):
         with open(path, encoding="utf-8") as f:
             spec = json.load(f)
-        if spec.get("source") in ("program_span", "program_counter"):
+        if spec.get("layer") == DRILL_LAYER:
+            drill[spec["name"]] = spec
+        elif spec.get("source") in ("program_span", "program_counter"):
             specs[spec["name"]] = spec
-    return specs
+    return specs, drill
 
 
-SPECS = _program_metrics()
+SPECS, DRILL_SPECS = _program_metrics()
 N_SERVERS = 2
 QUERIES = (
     "SELECT region, SUM(rev) FROM contract WHERE qty < 40 GROUP BY region ORDER BY region",
@@ -62,6 +77,18 @@ def _named(tree, name):
     for c in tree.get("children", ()):
         out.extend(_named(c, name))
     return out
+
+
+def _ask_traced(front, sql):
+    body = json.dumps({"sql": "SET trace = true; " + sql}).encode()
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{front.port}/query/sql", data=body,
+        headers={"Content-Type": "application/json"},
+    )
+    with urllib.request.urlopen(req, timeout=120) as r:
+        answer = json.loads(r.read().decode("utf-8"))
+    assert answer["trace"] and not answer.get("exceptions"), answer
+    return answer["trace"]
 
 
 @pytest.fixture(scope="module")
@@ -95,15 +122,7 @@ def served():
     trees = []
     try:
         for sql in QUERIES:
-            body = json.dumps({"sql": "SET trace = true; " + sql}).encode()
-            req = urllib.request.Request(
-                f"http://127.0.0.1:{front.port}/query/sql", data=body,
-                headers={"Content-Type": "application/json"},
-            )
-            with urllib.request.urlopen(req, timeout=120) as r:
-                answer = json.loads(r.read().decode("utf-8"))
-            assert answer["trace"] and not answer.get("exceptions"), answer
-            trees.append(answer["trace"])
+            trees.append(_ask_traced(front, sql))
         # the front door updates rest.*Ms after the client has its answer
         settled = threading.Event()
         for _ in range(500):
@@ -174,3 +193,121 @@ def test_launches_per_query_counts_the_jitted_calls(served):
             # two of the four segments a server, in one call and one fetch
             assert len(_named(root, "launch")) == enqueues[0]["attrs"]["segments"] == 2
             assert [n["attrs"]["segments"] for n in _named(root, "collect")] == [2]
+
+
+# ---------------------------------------------------------------------------
+# the layer `wide and sparse group-by` (PR 31)
+# ---------------------------------------------------------------------------
+DRILL_QUERIES = {  # 120 x 120 = 14,400 slots: past the one-hot kernel's 8,192
+    "wide": "SELECT a, b, SUM(rev) FROM drill WHERE qty < 40 GROUP BY a, b LIMIT 100000",
+    "sparse": "SET maxDenseGroups = 8192; SELECT a, b, SUM(rev) FROM drill WHERE qty < 40 GROUP BY a, b LIMIT 100000",
+}
+
+
+@pytest.fixture(scope="module")
+def drill_served():
+    """({wide, sparse}: span tree of the traced answer, the counters) of one
+    server with two segments, under the chip's accumulation policy."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(ops, "accum_policy", lambda: "chunked32")
+    mp.setattr(segmented, "accum_policy", lambda: "chunked32")
+    schema = Schema(
+        "drill",
+        [
+            FieldSpec("a", DataType.INT),
+            FieldSpec("b", DataType.INT),
+            FieldSpec("qty", DataType.INT),
+            FieldSpec("rev", DataType.INT, role=FieldRole.METRIC),
+        ],
+    )
+    coord = Coordinator(replication=1)
+    server = ServerInstance("server0")
+    coord.register_server(server)
+    coord.add_table(schema, TableConfig(name="drill"))
+    rng = np.random.default_rng(31)
+    for i in range(2):
+        block = {
+            "a": rng.permutation(np.arange(600) % 120).astype(np.int32),
+            "b": rng.permutation(np.arange(600) % 120).astype(np.int32),
+            "qty": rng.integers(1, 51, 600).astype(np.int32),
+            "rev": rng.integers(1, 10**7, 600).astype(np.int32),
+        }
+        coord.add_segment("drill", build_segment(schema, block, f"seg{i}"))
+    METRICS.reset()
+    planner.plan_cache_clear()
+    front = QueryServer(Broker(coord)).start()
+    try:
+        trees = {name: _ask_traced(front, sql) for name, sql in DRILL_QUERIES.items()}
+    finally:
+        front.stop()
+        mp.undo()
+        planner.plan_cache_clear()
+    counters = dict(METRICS.snapshot()["counters"])
+    for k, v in server.metrics.snapshot()["counters"].items():
+        counters[k] = counters.get(k, 0) + v
+    return trees, counters
+
+
+def test_the_drill_layer_has_its_metrics():
+    assert {"wide_table_ms", "sparse_sort_ms", "sparse_roofline", "sparse_table_bytes_per_query",
+            "sparse_decode_ms"} <= set(DRILL_SPECS)
+
+
+@pytest.mark.parametrize("name", sorted(DRILL_SPECS))
+def test_program_still_says_what_a_drill_metric_reads(name, drill_served):
+    spec = DRILL_SPECS[name]
+    trees, counters = drill_served
+    named = [k for k in SPAN_KEYS + ("served_by_counter", "served_by_counters") if k in spec]
+    assert named, f"{name}: names nothing this test knows how to look for: {sorted(spec)}"
+    span_names = []
+    for key in SPAN_KEYS:
+        value = spec.get(key, [])
+        span_names.extend([value] if isinstance(value, str) else value)
+    for span in span_names:
+        for how, tree in trees.items():
+            assert _named(tree, span), f"{name}: no span {span!r} in the traced {how} group-by"
+    if "attr" in spec:
+        for how, tree in trees.items():
+            values = [n.get("attrs", {}).get(spec["attr"]) for n in _named(tree, spec["span"])]
+            assert values and all(isinstance(v, (int, float)) for v in values), (name, how, values)
+    for counter in [spec.get("served_by_counter")] + spec.get("served_by_counters", []):
+        if counter is not None:
+            assert counters.get(counter, 0) > 0, f"{name}: trace-time counter {counter!r}"
+
+
+def test_table_decode_says_what_came_back(drill_served):
+    """`table_decode` (inside `collect`): `tableBytes` of the fetched tables,
+    `keySpace` slots a member's table has, `groups` they held; and the
+    always-on `server.sparseGroups` counts the sparse plan's alone."""
+    trees, counters = drill_served
+    groups = {}
+    for how, tree in trees.items():
+        (collect,) = _named(tree, "collect")
+        (decode,) = _named(collect, "table_decode")
+        attrs = decode["attrs"]
+        assert attrs["kind"] == {"wide": "groupby_dense", "sparse": "groupby_sparse"}[how]
+        assert attrs["keySpace"] == 120 * 120 and attrs["tableBytes"] >= 2 * 8 * attrs["keySpace"]
+        groups[how] = attrs["groups"]
+    assert groups["wide"] == groups["sparse"] > 0
+    assert counters["server.sparseGroups"] == groups["sparse"]
+    assert counters["scan.traced.wide_scatter"] >= 1 and counters["scan.traced.sparse_sort"] >= 1
+
+
+def test_the_drill_configuration_shares_sf1s_table():
+    """`ssb_flat_sf1_drill` is `ssb_flat_sf1`'s table on purpose: the same
+    generator, schema, query set and rows, so the same seed gives the same
+    rows and every line of staging and of the reference is shared."""
+    def load(name):
+        with open(os.path.join(ROOT, "benchmarks", "configs", name + ".json"), encoding="utf-8") as f:
+            return json.load(f)
+
+    drill, sf1 = load("ssb_flat_sf1_drill"), load("ssb_flat_sf1")
+    for key in ("columns", "datagen", "query_set", "rows", "segment_rows", "packed_codes", "table", "table_config", "hierarchy"):
+        assert drill[key] == sf1[key], key
+    assert (drill["servers"], drill["chips"], drill["replication"]) == (1, 1, 1)
+    assert set(drill["reduced"]) == set(sf1["reduced"]) | {"scale_factor", "templates"}
+    assert set(drill["reduced"]) == set(drill["reduced_why"])
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        bench = json.load(f)
+    (entry,) = [c for c in bench["configs"] if c["name"] == "ssb_flat_sf1_drill"]
+    assert entry["source"] == drill["source"] and entry["reduced"] == drill["reduced"]

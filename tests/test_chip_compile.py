@@ -124,6 +124,38 @@ def test_pallas_scan_compiles_for_v5e(one_chip, no_compile_cache, name):
     assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel is in the program
 
 
+# SSB Q3.2-Q3.4's table (437,500 slots) over one served segment: past the
+# one-hot kernel, so under chunked32 the fused tables are _wide_group_tables'
+WIDE_CASES = {
+    "q3_revenue": [("count", None), ("int_sum", (3, False))],  # lo_revenue < 2^24: two 12-bit limbs
+    "int32_any": [("int_sum", None)],  # no stats: three limbs and the negatives' count
+    "int64_and_float": [("int64_sum", None), ("f32_sum", None), ("f32_sumsq", None)],
+}
+
+
+@pytest.mark.parametrize("name", sorted(WIDE_CASES))
+def test_wide_group_tables_compile_for_v5e(one_chip, no_compile_cache, monkeypatch, name):
+    """Every integer table is int32 scatters (one a 12-bit limb, the chip's
+    fast scatter), never a 64-bit or an f32 one; floats keep an f32 table."""
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    n, groups = 1_500_000, 437_500
+
+    def shape(dt):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+
+    def tables(codes, mask, v32, v64, vf):
+        values = {"count": None, "int_sum": v32, "int64_sum": v64, "f32_sum": vf, "f32_sumsq": vf}
+        return segmented.fused_group_tables([(k, values[k], mask, lp) for k, lp in WIDE_CASES[name]], codes, groups)
+
+    text = jax.jit(tables).lower(
+        shape(jnp.int32), shape(jnp.bool_), shape(jnp.int32), shape(jnp.int64), shape(jnp.float32)
+    ).compile().as_text()
+    # the entry computation's instructions (a fused computation's root repeats each as `ROOT %fusion...`)
+    scatters = re.findall(r"^\s*%[\w.\-]+ = (\(?\w+)\[\d+\]\S* fusion\([^\n]*kind=kCustom[^\n]*scatter-add", text, re.M)
+    want = {"q3_revenue": ["s32"] * 3, "int32_any": ["s32"] * 4, "int64_and_float": ["s32"] * 6 + ["f32"] * 2}[name]
+    assert sorted(scatters) == sorted(want), scatters
+
+
 # the smoke's sparse query (d) tracks its whole key space (no trim).  The
 # default numGroupsLimit path ranks and trims through an (f64, int64) two-key
 # sort that XLA's TPU backend compiles for ~265 s at this size — marked slow

@@ -6,7 +6,8 @@ kernel scanned over them) with their parameter buffers stacked on the host,
 and come back in ONE fetch; the per-segment decode and everything above it
 are unchanged.  These tests hold the answers bit-equal to the per-segment
 launch's (with the kernel interpreted and 32-bit accumulation, the chip's
-path, as tests/test_ssb_templates_chip_path.py steers it), the grouping of a
+path, as tests/test_ssb_templates_chip_path.py steers it; a dense and a
+SPARSE group-by among them), the grouping of a
 mixed scan list, the widths' ladder, the first launch of a group program on
 each device, `ServerInstance.warm`, cancellation between groups, and an
 upsert segment in a group.
@@ -67,6 +68,13 @@ QUERIES = {
     ),
     "groupby_dense": (
         "SELECT city, year, COUNT(*), SUM(rev) FROM t WHERE city <> 'edi' AND qty BETWEEN 5 AND 40 GROUP BY city, year",
+        lambda b: (b["city"] != "edi") & (b["qty"] >= 5) & (b["qty"] <= 40), ("city", "year"),
+    ),
+    # the same table past maxDenseGroups: sort + slot scatter (planner.sparse_grouped_tables), which PR 29
+    # left untested in a group on the chip's arithmetic
+    "groupby_sparse": (
+        "SET maxDenseGroups = 16; SELECT city, year, COUNT(*), SUM(rev) FROM t WHERE city <> 'edi' AND qty BETWEEN 5 AND 40 "
+        "GROUP BY city, year",
         lambda b: (b["city"] != "edi") & (b["qty"] >= 5) & (b["qty"] <= 40), ("city", "year"),
     ),
 }
@@ -171,8 +179,10 @@ def test_grouped_launch_equals_the_per_segment_launch_bit_for_bit(name, chip_pat
 
     assert all(_same(a, b) for a, b in zip(grouped, one_by_one))
     assert [_answer(r) for r in grouped] == [_reference(b, mask_of(b), group_cols) for b in BLOCKS]
-    if group_cols:  # the dense group-by is the kernel's, interpreted (traced once: the group program maps the same jitted kernel)
+    if name == "groupby_dense":  # the kernel's, interpreted (traced once: the group program maps the same jitted kernel)
         assert METRICS.counter("scan.traced.interpret").value == kernel + 1
+    if name == "groupby_sparse":  # sorted, not scattered by key; traced once as well
+        assert METRICS.counter("scan.traced.sparse_sort").value == 1
     enqueues = _spans(trace.finish())["launch_enqueue"]
     assert [(e["attrs"]["segments"], e["attrs"]["width"]) for e in enqueues] == [(4, 4), (1, 1)]
 

@@ -25,11 +25,17 @@ from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
 from pinot_tpu.sql.parser import parse_query
 
 
+def _one_table(kind, values, mask, codes, num_groups):
+    """The entry through the single-table API, one scan of its own."""
+    if kind == "count":
+        return ops.group_count(mask, codes, num_groups)
+    if kind == "f32_sumsq":
+        return ops.group_sum_sq(values, mask, codes, num_groups)
+    return ops.group_sum(values, mask, codes, num_groups)
+
+
 def _reference(entries, codes, num_groups):
-    return [
-        np.asarray(segmented._entry_fallback(k, v, m, codes, num_groups), np.float64)
-        for k, v, m, _ in entries
-    ]
+    return [np.asarray(_one_table(k, v, m, codes, num_groups), np.float64) for k, v, m, _ in entries]
 
 
 def _entries(rng, n):

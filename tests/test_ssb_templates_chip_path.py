@@ -8,8 +8,8 @@ SSB's published literals run over a two-segment table from the benchmark's
 generator with the kernel interpreted and 32-bit accumulation (steered as
 tests/test_chip_compile.py steers them: both ask `jax.default_backend()`,
 which says cpu here), and are compared at difference 0 with the benchmark's
-plain numpy reference.  The four sparse templates are left out: Q3.2 is not
-exact on the chip (PERF.md section 7) and has a queue item of its own.
+plain numpy reference.  The four drill-down templates (Q3.2-Q3.4, Q4.3: the
+wide group table and the sparse sort) have tests/test_sparse_drill_exact.py.
 """
 import os
 import sys

@@ -195,7 +195,7 @@ class ServerInstance:
         to graft.  Traced or not, every span is a profiler annotation
         carrying `query_id` (the broker's), and the stages' sums go once a
         query into this server's timers (_STAGE_TIMERS)."""
-        from pinot_tpu.query.planner import _needed_columns
+        from pinot_tpu.query.planner import QueryPlanning
         from pinot_tpu.utils.metrics import Trace
 
         if self.crashed:
@@ -207,6 +207,9 @@ class ServerInstance:
         trace = Trace(
             bool(ctx.options.get("trace", False)), root=f"server:{self.name}", query_id=query_id
         )
+        # the query's half of planning, derived once for every segment below:
+        # the columns it reads here, its plans in QueryLaunches
+        planning = QueryPlanning(ctx)
         ticket = None
         if self.budget is not None:
             # working-set estimate for the batch, reserved all-or-nothing
@@ -216,7 +219,7 @@ class ServerInstance:
             for name in seg_names:
                 seg = self.get_segment(ctx.table, name)
                 if seg is not None:
-                    est.append(estimate_segment_bytes(ctx, seg, _needed_columns(ctx, seg)))
+                    est.append(estimate_segment_bytes(ctx, seg, planning.needed_columns(seg)))
             if self.residency is not None:
                 # tiered storage: HBM is a cache, so a scatter only needs
                 # its PIPELINE WINDOW resident at once (current segment +
@@ -252,7 +255,7 @@ class ServerInstance:
             stats = ExecutionStats()
             launches = executor.QueryLaunches(
                 ctx, device=self.device, residency=self.residency, trace=trace,
-                on_first_launch=on_first_launch,
+                on_first_launch=on_first_launch, planning=planning,
                 check=lambda: self._check_budget(deadline, cancelled=launches.uncollected, cancel=cancel),
             )
             with trace.span("dispatch") as dsp:
@@ -269,7 +272,7 @@ class ServerInstance:
                     stats.num_segments_queried += 1
                     stats.total_docs += seg.num_docs
                     if table_schema is not None:
-                        seg.ensure_columns(table_schema, _needed_columns(ctx, seg))
+                        seg.ensure_columns(table_schema, planning.needed_columns(seg))
                     if executor.prune_segment(ctx, seg):
                         stats.num_segments_pruned += 1
                         continue
@@ -283,7 +286,7 @@ class ServerInstance:
                         self.residency.submit(
                             nxt.to_device,
                             device=self.device,
-                            columns=_needed_columns(ctx, nxt),
+                            columns=planning.needed_columns(nxt),
                             packed_codes=True,
                             residency=self.residency,
                             prefetch=True,
@@ -344,15 +347,13 @@ class ServerInstance:
         is recorded (`server.compileMs`), as a served first launch's is.  The
         broker calls it on a table's other servers while one of them compiles
         the same programs (Broker._scatter)."""
-        from pinot_tpu.query.planner import _needed_columns
-
         launches = executor.QueryLaunches(ctx, device=self.device, residency=self.residency)
         for name in seg_names:
             seg = self.get_segment(ctx.table, name)
             if seg is None:
                 continue
             if table_schema is not None:
-                seg.ensure_columns(table_schema, _needed_columns(ctx, seg))
+                seg.ensure_columns(table_schema, launches.planning.needed_columns(seg))
             if not executor.prune_segment(ctx, seg):
                 launches.add(seg)
         launches.flush()

@@ -143,9 +143,12 @@ class _Member:
     stats: ExecutionStats
 
 
-def _plan_member(ctx, segment, device, residency, trace):
+def _plan_member(ctx, segment, device, residency, trace, planning=None):
     """A launch's per-segment stages, as spans of `trace`: launch_plan
-    (star-tree probe + plan cache: dictionary look-ups are per segment) and
+    (star-tree probe + plan cache: dictionary look-ups are per segment;
+    `planning` is the query's planner.QueryPlanning where the caller plans
+    more than one segment, so the query's half is derived once; attr `cache`
+    = hit / miss / startree, and on a hit `bind` = recipe / rebuild) and
     launch_ship (the plan's columns looked up in, or staged into, the
     device's cache, nothing else: no device array is made for a parameter;
     attr paramArrays counts the host buffers that carry them, one per dtype,
@@ -155,9 +158,15 @@ def _plan_member(ctx, segment, device, residency, trace):
 
     with trace.span("launch_plan", segment=segment.name) as psp:
         star = try_startree(ctx, segment)
-        plan = planner.plan_segment(ctx, segment) if star is None else None
+        if star is None:
+            plan = planning.plan(segment) if planning is not None else planner.plan_segment(ctx, segment)
         if psp is not None:
-            psp.annotate(cache="startree" if plan is None else "hit" if plan.cache_hit else "miss")
+            if star is not None:
+                psp.annotate(cache="startree")
+            elif plan.cache_hit:
+                psp.annotate(cache="hit", bind=plan.bind)
+            else:
+                psp.annotate(cache="miss")
     if star is not None:
         return ("done", star)
 
@@ -268,7 +277,8 @@ class QueryLaunches:
     the order the segments were added."""
 
     def __init__(self, ctx: QueryContext, device=None, residency=None,
-                 trace: Optional[Trace] = None, on_first_launch=None, check=None):
+                 trace: Optional[Trace] = None, on_first_launch=None, check=None,
+                 planning: Optional[planner.QueryPlanning] = None):
         self.ctx = ctx
         self.device = device
         self.residency = residency
@@ -281,6 +291,9 @@ class QueryLaunches:
         self.uncollected = 0  # launched groups not yet fetched
         self.sparse_groups = 0  # groups the collected sparse tables held, summed over segments
         self._added = 0
+        # the query's half of its plans, derived once (the caller's, where it
+        # already asked it for the columns the query reads)
+        self.planning = planning if planning is not None else planner.QueryPlanning(ctx)
         self._open: Dict[int, List[Tuple[int, _Member]]] = {}  # id(plan.fn) -> (slot, member)
         self._states: List[Tuple[Tuple, List[int]]] = []  # (state, its members' slots), in launch order
 
@@ -288,7 +301,9 @@ class QueryLaunches:
         self.check()
         slot, self._added = self._added, self._added + 1
         with self.trace.span(f"launch:{segment.name}", cpu=True, segment=segment.name) as lsp:
-            member = _plan_member(self.ctx, segment, self.device, self.residency, self.trace)
+            member = _plan_member(
+                self.ctx, segment, self.device, self.residency, self.trace, self.planning
+            )
         if not isinstance(member, _Member):
             self._states.append((member, [slot]))
             return

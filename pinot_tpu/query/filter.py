@@ -82,6 +82,59 @@ def like_to_regex(pattern: str) -> str:
     return "^" + "".join(out) + "$"
 
 
+def dict_predicate_codes(p: Predicate, d) -> Tuple[Optional[int], Optional[int], Optional[np.ndarray]]:
+    """A predicate's literals resolved against ONE sorted dictionary `d`:
+    `(lo_code, hi_code, None)` for EQ / RANGE (the codes `lo <= code < hi`
+    match), `(None, None, table)` for NEQ / IN / NOT_IN / REGEXP_LIKE / LIKE
+    (a bool per dictionary entry).  A pure function of (dictionary,
+    literals): the compiler calls it when a plan is built, and a plan-cache
+    hit calls it again to bind the same predicate's parameters for another
+    segment or other literals (planner.ParamRecipe), so the two cannot
+    differ.  Raises ValueError for a predicate kind it does not resolve
+    (TEXT_MATCH / JSON_MATCH read an index, not the dictionary alone)."""
+    pt = p.ptype
+    if pt is PredicateType.EQ:
+        i = d.index_of(p.values[0])
+        return (i, i + 1, None) if i >= 0 else (0, 0, None)
+    if pt is PredicateType.RANGE:
+        values = d.values
+        lo_code, hi_code = 0, d.cardinality
+        # raw literals into searchsorted: numpy's cross-dtype compare keeps
+        # 2.5 between 2 and 3 on an INT dictionary (no truncation).
+        if p.lower is not None:
+            lo_code = int(np.searchsorted(values, p.lower, side="left" if p.lower_inclusive else "right"))
+        if p.upper is not None:
+            hi_code = int(np.searchsorted(values, p.upper, side="right" if p.upper_inclusive else "left"))
+        return lo_code, hi_code, None
+    card = d.cardinality
+    if pt is PredicateType.NEQ:
+        i = d.index_of(p.values[0])
+        table = np.ones(card, dtype=bool)
+        if i >= 0:
+            table[i] = False
+        return None, None, table
+    if pt in (PredicateType.IN, PredicateType.NOT_IN):
+        table = np.zeros(card, dtype=bool)
+        for v in p.values:
+            i = d.index_of(v)
+            if i >= 0:
+                table[i] = True
+        return None, None, (~table if pt is PredicateType.NOT_IN else table)
+    if pt in (PredicateType.REGEXP_LIKE, PredicateType.LIKE):
+        pat = p.values[0]
+        rx = re.compile(pat if pt is PredicateType.REGEXP_LIKE else like_to_regex(pat))
+        # regex over the dictionary, not the rows — card evaluations total.
+        table = np.fromiter((rx.search(str(v)) is not None for v in d.values), dtype=bool, count=card)
+        return None, None, table
+    raise ValueError(f"predicate {pt} is not resolved by a dictionary alone")
+
+
+# the predicate kinds dict_predicate_codes resolves
+_DICT_RESOLVED = frozenset({
+    PredicateType.EQ, PredicateType.NEQ, PredicateType.RANGE, PredicateType.IN,
+    PredicateType.NOT_IN, PredicateType.REGEXP_LIKE, PredicateType.LIKE,
+})
+
 # IN/NOT_IN/regex tables resolve through the inverted index only up to this
 # many bitmap-row ORs (past it a code scan reads less)
 _INV_MAX_ROWS = 256
@@ -139,6 +192,17 @@ class FilterCompiler:
         # words to the fused scan (pallas_scan word-slicing)
         self.sole_bitmap_param: Optional[str] = None
         self._root_compiled = False
+        # How each compiled predicate's parameters are made, in compile
+        # order: (kind, ptype, column, multi-value, the param keys it
+        # wrote), kind "none" (no parameter), "range" (lo, hi codes) or
+        # "table" (a bool per dictionary entry), both dict_predicate_codes
+        # of (the segment's dictionary, the literals).  None once a
+        # predicate was compiled whose parameters are not such a pure
+        # function, or whose path an index may choose differently for
+        # another segment or literal: a plan-cache hit then rebuilds
+        # (planner.ParamRecipe).
+        self.binders: Optional[List[Tuple]] = []
+        self._bindable: Optional[Tuple] = None
 
     def _key(self, suffix: str) -> str:
         k = f"f{self._counter}.{suffix}"
@@ -224,6 +288,17 @@ class FilterCompiler:
 
     # ------------------------------------------------------------------
     def _compile_predicate(self, p: Predicate) -> Callable[[Dict, Dict], MaskPair]:
+        first_key = len(self.params)
+        self._bindable = None  # the branch that can be bound says how
+        fn = self._compile_one(p)
+        if self.binders is not None:
+            if self._bindable is None:
+                self.binders = None
+            else:
+                self.binders.append((*self._bindable, tuple(list(self.params)[first_key:])))
+        return fn
+
+    def _compile_one(self, p: Predicate) -> Callable[[Dict, Dict], MaskPair]:
         seg = self.segment
         # IS_NULL / IS_NOT_NULL act on the column's null vector directly.
         if p.ptype in (PredicateType.IS_NULL, PredicateType.IS_NOT_NULL):
@@ -235,6 +310,7 @@ class FilterCompiler:
             if has_nulls:
                 self.used_columns.add(p.lhs.op)
             n = seg.num_docs
+            self._bindable = ("none", p.ptype, p.lhs.op, False)
 
             def eval_null(cols, params, _want=want_null, _has=has_nulls, _name=p.lhs.op):
                 if not _has:
@@ -327,7 +403,6 @@ class FilterCompiler:
         name = p.lhs.op
         col = self.segment.column(name)
         d = col.dictionary
-        card = d.cardinality
         values = d.values
         pt = p.ptype
         # Multi-value columns: predicates match a row when ANY element
@@ -341,36 +416,8 @@ class FilterCompiler:
         lo_code = hi_code = None
         table: Optional[np.ndarray] = None
 
-        if pt is PredicateType.EQ:
-            i = d.index_of(p.values[0])
-            lo_code, hi_code = (i, i + 1) if i >= 0 else (0, 0)
-        elif pt is PredicateType.NEQ:
-            i = d.index_of(p.values[0])
-            table = np.ones(card, dtype=bool)
-            if i >= 0:
-                table[i] = False
-        elif pt is PredicateType.RANGE:
-            lo_code = 0
-            hi_code = card
-            # raw literals into searchsorted: numpy's cross-dtype compare keeps
-            # 2.5 between 2 and 3 on an INT dictionary (no truncation).
-            if p.lower is not None:
-                lo_code = int(np.searchsorted(values, p.lower, side="left" if p.lower_inclusive else "right"))
-            if p.upper is not None:
-                hi_code = int(np.searchsorted(values, p.upper, side="right" if p.upper_inclusive else "left"))
-        elif pt in (PredicateType.IN, PredicateType.NOT_IN):
-            table = np.zeros(card, dtype=bool)
-            for v in p.values:
-                i = d.index_of(v)
-                if i >= 0:
-                    table[i] = True
-            if pt is PredicateType.NOT_IN:
-                table = ~table
-        elif pt in (PredicateType.REGEXP_LIKE, PredicateType.LIKE):
-            pat = p.values[0]
-            rx = re.compile(pat if pt is PredicateType.REGEXP_LIKE else like_to_regex(pat))
-            # regex over the dictionary, not the rows — card evaluations total.
-            table = np.fromiter((rx.search(str(v)) is not None for v in values), dtype=bool, count=card)
+        if pt in _DICT_RESOLVED:
+            lo_code, hi_code, table = dict_predicate_codes(p, d)
         elif pt is PredicateType.TEXT_MATCH:
             from pinot_tpu.indexes.text import TextIndex
 
@@ -401,6 +448,14 @@ class FilterCompiler:
             accel = self._try_index_paths(name, col, lo_code, hi_code, table, has_nulls)
             if accel is not None:
                 return accel
+
+        # What follows scans the codes with parameters that are
+        # dict_predicate_codes of this segment's dictionary.  With an
+        # inverted index on the column the choice between bitmap and scan
+        # hangs on how many codes the literals select in THIS dictionary, so
+        # another segment of the same signature may take the other path.
+        if pt in _DICT_RESOLVED and (is_mv or self._col_index("inverted", name) is None):
+            self._bindable = ("range" if table is None else "table", pt, name, is_mv)
 
         if table is not None:
             if is_mv:

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pinot_tpu import ops
-from pinot_tpu.query.filter import FilterCompiler
+from pinot_tpu.query.filter import FilterCompiler, dict_predicate_codes
 from pinot_tpu.query.functions import (
     FIELD_COMBINE,
     AggFunction,
@@ -173,6 +173,13 @@ class SegmentPlan:
     # whether plan_segment took the compiled fn from the plan cache (the
     # `cache` attr of the launch_plan span)
     cache_hit: bool = False
+    # how a hit came by its parameters (the `bind` attr of the launch_plan
+    # span): "recipe" = the entry's ParamRecipe evaluated against this
+    # segment's dictionaries, "rebuild" = _build_plan run again
+    bind: Optional[str] = None
+    # the plan-cache entry's: how its parameters are made from (segment,
+    # literals), None where some predicate has no such recipe
+    recipe: Optional["ParamRecipe"] = None
 
 
 def vmapped_plan(base: SegmentPlan, shared_keys: frozenset) -> SegmentPlan:
@@ -475,7 +482,11 @@ def _all_column_names(segment) -> List[str]:
     return segment.schema.column_names
 
 
-def _needed_columns(ctx: QueryContext, segment: ImmutableSegment) -> List[str]:
+def _referenced_columns(ctx: QueryContext) -> Tuple[List[str], List[str], set]:
+    """What `ctx` alone says about the columns a query reads: (the column
+    references of WHERE, GROUP BY and the select list, in that order and
+    with repeats; those of ORDER BY and HAVING; the aliases of selected
+    aggregations).  _needed_columns makes a segment's list of them."""
     cols: List[str] = []
     if ctx.filter:
         cols.extend(ctx.filter.columns())
@@ -504,24 +515,36 @@ def _needed_columns(ctx: QueryContext, segment: ImmutableSegment) -> List[str]:
                 cols.extend(o.expr.columns())
         else:
             cols.extend(s.columns())
-    # ORDER BY/HAVING references to AGGREGATION aliases are resolved by
-    # reduce against final arrays, not segment columns — skip them unless a
-    # physical column shadows the alias.
+    # "*" here can only come from count(*) inside an ORDER BY/HAVING call —
+    # it needs no column loads (unlike SELECT *).
+    late: List[str] = []
+    for o in ctx.order_by:
+        late.extend(c for c in o.expr.columns() if c != "*")
+    if ctx.having:
+        late.extend(c for c in ctx.having.columns() if c != "*")
     agg_aliases = {
         a
         for s, a in zip(ctx.select_list, ctx.select_aliases)
         if a and isinstance(s, AggregationSpec)
     }
-    physical = set(segment.schema.column_names)
-    alias_only = agg_aliases - physical
-    # "*" here can only come from count(*) inside an ORDER BY/HAVING call —
-    # it needs no column loads (unlike SELECT *).
-    for o in ctx.order_by:
-        cols.extend(c for c in o.expr.columns() if c not in alias_only and c != "*")
-    if ctx.having:
-        cols.extend(c for c in ctx.having.columns() if c not in alias_only and c != "*")
+    return cols, late, agg_aliases
+
+
+def _needed_columns(
+    ctx: QueryContext, segment: ImmutableSegment, referenced: Optional[Tuple] = None
+) -> List[str]:
+    """The columns of `segment` the query reads, each once, in the order
+    first referenced.  `referenced` is _referenced_columns(ctx) where the
+    caller already has it."""
+    cols, late, agg_aliases = referenced if referenced is not None else _referenced_columns(ctx)
+    # ORDER BY/HAVING references to AGGREGATION aliases are resolved by
+    # reduce against final arrays, not segment columns — skip them unless a
+    # physical column shadows the alias.
+    if agg_aliases:
+        alias_only = agg_aliases - set(segment.schema.column_names)
+        late = [c for c in late if c not in alias_only]
     seen, out = set(), []
-    for c in cols:
+    for c in [*cols, *late]:
         if c == "*":
             for name in _all_column_names(segment):
                 if name not in seen:
@@ -1088,45 +1111,316 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
     return uniq[:num_slots], partials
 
 
-def plan_segment(ctx: QueryContext, segment: ImmutableSegment) -> SegmentPlan:
-    from pinot_tpu.analysis.compile_audit import SSE_AUDIT
-    from pinot_tpu.analysis.plan_check import check_plan_cached
+@dataclass(frozen=True)
+class ParamRecipe:
+    """How a plan-cache entry's packed parameters are made from (segment,
+    literals) without planning again.  Recorded when _build_plan compiles
+    the entry; keyed, like the entry, by the query's SHAPE, so it holds no
+    literal and no dictionary: a hit walks the CURRENT query's predicates in
+    the order the FilterCompiler compiled them and resolves each against
+    THIS segment's dictionary (filter.dict_predicate_codes, the function the
+    compiler itself calls), writing straight into buffers of
+    `param_layout`'s packing."""
 
-    # static IR validation before anything traces: malformed plans raise
-    # structured PlanCheckError here instead of a tracer error inside jit
-    check_plan_cached(ctx)
-    needed = _needed_columns(ctx, segment)
-    key = (
-        ctx.shape_fingerprint(column_info_from(segment)),
-        _segment_signature(
-            segment, needed, sketch_bound_columns(ctx) | const_bound_columns(ctx),
-            group_cols=frozenset(c for g in ctx.group_by for c in g.columns()),
-        ),
-        ops.scan_backend(),  # pallas/xla plans trace different kernels
+    # one a compiled predicate, in compile order:
+    # (kind, ptype, column, multi-value, ((dtype, offset, shape), ...))
+    binders: Tuple[Tuple, ...]
+    # (dtype, length) of each packed buffer, in pack_params' order
+    buffers: Tuple[Tuple[str, int], ...]
+    valid_docs: bool  # VALID_KEY rides beside the buffers
+
+
+def _param_recipe(binders: Optional[List[Tuple]], layout: Tuple) -> Optional[ParamRecipe]:
+    """The recipe for a plan whose FilterCompiler recorded `binders` and
+    whose parameters pack as `layout`, or None where a predicate cannot be
+    bound or a parameter is nobody's."""
+    if binders is None:
+        return None
+    where: Dict[str, Tuple] = {}
+    lengths: Dict[str, int] = {}
+    for key, dtype, shape in layout:
+        if key != VALID_KEY:
+            at = lengths.get(dtype, 0)
+            where[key] = (dtype, at, shape)
+            lengths[dtype] = at + math.prod(shape)
+    out = []
+    for kind, ptype, column, mv, keys in binders:
+        want = {"none": [], "range": [("int32", ()), ("int32", ())], "table": [("bool", None)]}[kind]
+        slots = [where.pop(k, None) for k in keys]
+        if len(slots) != len(want) or any(
+            s is None or s[0] != dtype or (shape is not None and s[2] != shape)
+            for s, (dtype, shape) in zip(slots, want)
+        ):
+            return None
+        out.append((kind, ptype, column, mv, tuple(slots)))
+    if where:
+        return None
+    return ParamRecipe(
+        tuple(out), tuple(lengths.items()), any(key == VALID_KEY for key, _, _ in layout)
     )
-    cached = _PLAN_CACHE.get(key)
-    if cached is not None:
-        # params are per-query/per-segment (literals, dictionary lookups):
-        # rebuild them, reuse the compiled fn.  The structure check is the
-        # safety net under the shape audit — a mismatch would silently
-        # retrace, so it counts (and compiles) as a miss instead.
-        plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn)
-        if plan.param_layout == cached.param_layout:
-            plan.scan_bytes = cached.scan_bytes
-            plan.launched_on = cached.launched_on
-            plan.widened = cached.widened
-            plan.cache_key = key
-            plan.cache_hit = True
-            SSE_AUDIT.record_hit(key[0])
-            return plan
-    SSE_AUDIT.record_compile(key[0])
-    plan = _build_plan(ctx, segment, needed, compiled_fn=None)
-    plan.scan_bytes = segment.num_docs * scan_bytes_per_row(
-        segment.column(n) for n in plan.needed_columns
+
+
+def _bind_params(
+    recipe: ParamRecipe, predicates: List, segment, same_dict: Tuple, lookups: Dict
+) -> Optional[Dict[str, np.ndarray]]:
+    """`recipe` evaluated for `predicates` (the current query's, in compile
+    order) against `segment`: the packed buffers _build_plan would make,
+    byte for byte, or None where the recipe does not fit (the caller
+    rebuilds).  `lookups` lives for one query and `same_dict[i]` says which
+    dictionary predicate i meets in this segment (_dictionary_identity):
+    segments whose dictionaries have the same content share one resolution
+    of a predicate."""
+    if len(predicates) != len(recipe.binders):
+        return None
+    packed = {dtype: np.empty(n, dtype) for dtype, n in recipe.buffers}
+    columns = segment.columns  # the signature was made of these: each is there
+    for at, ((kind, ptype, column, mv, slots), p) in enumerate(zip(recipe.binders, predicates)):
+        if p.ptype is not ptype or p.lhs.op != column:
+            return None
+        if kind == "none":
+            continue
+        memo = (at, same_dict[at])
+        codes = lookups.get(memo)
+        if codes is None:
+            codes = lookups[memo] = dict_predicate_codes(p, columns[column].dictionary)
+        if kind == "range":
+            (_, lo_at, _), (_, hi_at, _) = slots
+            ints = packed["int32"]
+            ints[lo_at], ints[hi_at] = codes[0], codes[1]
+        else:
+            ((_, table_at, shape),) = slots
+            table = codes[2]
+            if table.shape[0] + mv != shape[0]:
+                return None
+            packed["bool"][table_at : table_at + table.shape[0]] = table
+            if mv:
+                packed["bool"][table_at + table.shape[0]] = False  # padding code slot
+    if recipe.valid_docs:
+        if segment.valid_docs is None:
+            return None
+        packed[VALID_KEY] = np.asarray(segment.valid_docs, dtype=bool)
+    return packed
+
+
+# A dictionary's content hash is read once and kept (Dictionary.fingerprint),
+# but reading it walks every value: past this many, two segments' look-ups
+# are shared only where they hold the very same Dictionary
+_SHARED_LOOKUP_MAX_CARDINALITY = 1 << 16
+
+
+def _dictionary_identity(segment, column: Optional[str]):
+    """What tells the dictionaries two segments hold for `column` apart, for
+    sharing a predicate's resolution between them within one query."""
+    if column is None or column not in segment.columns:
+        return None
+    d = segment.columns[column].dictionary
+    if d is None:
+        return None
+    return d.fingerprint() if d.cardinality <= _SHARED_LOOKUP_MAX_CARDINALITY else id(d)
+
+
+class _SegmentMemo:
+    """The segment's half of planning, kept on the segment: its columns'
+    shapes (shape.ColumnShape), its signatures, its dictionaries' identities
+    and its group dimensions do not change between queries.  What CAN change
+    is in `state`, and a memo whose state is not the segment's is thrown
+    away: `valid_docs` appearing (upserts) and `indexes` gaining a column
+    (FilterCompiler._cache_index builds text / json indexes lazily), both of
+    which _segment_signature reads."""
+
+    __slots__ = ("state", "halves", "group_dims")
+    MAX_ENTRIES = 128  # query shapes a segment remembers; past it, start over
+
+    def __init__(self, state):
+        self.state = state
+        # (predicate columns, needed columns, bound columns, group columns) ->
+        # (the predicate columns' ColumnShapes, _segment_signature, the
+        # predicate columns' _dictionary_identity)
+        self.halves: Dict[Tuple, Tuple] = {}
+        # (GROUP BY fingerprint, null handling) -> [GroupDim]: the decode's
+        # view of this segment's dictionaries
+        self.group_dims: Dict[Tuple, List[GroupDim]] = {}
+
+
+def _segment_memo(segment) -> _SegmentMemo:
+    indexes = getattr(segment, "indexes", None)
+    state = (
+        segment.valid_docs is not None,
+        tuple([(kind, tuple(by_col)) for kind, by_col in indexes.items()]) if indexes else (),
     )
-    plan.cache_key = key
-    _PLAN_CACHE.put(key, plan)
-    return plan
+    memo = getattr(segment, "_plan_memo", None)
+    if memo is None or memo.state != state:
+        memo = _SegmentMemo(state)
+        try:
+            segment._plan_memo = memo
+        except AttributeError:  # a segment-like view without room for it: no memo
+            pass
+    return memo
+
+
+class QueryPlanning:
+    """The query's half of planning, made once a query a server
+    (executor.QueryLaunches) and asked for a plan a segment.
+
+    Everything plan_segment used to derive again for every segment from
+    `ctx` alone is derived here once: the static plan check, the columns the
+    query reads, the columns whose dictionaries are baked into kernels, the
+    group-by columns, the predicates in compile order, and the shape
+    fingerprint per distinct set of filter-column shapes.  The segment's
+    half is memoised on the segment (_SegmentMemo).  A plan-cache hit then
+    BINDS its parameters through the entry's ParamRecipe; an entry without
+    one, or a recipe that does not fit, rebuilds through _build_plan as
+    before."""
+
+    def __init__(self, ctx: QueryContext):
+        self.ctx = ctx
+        self._checked = False  # check_plan_cached(ctx) has passed: at the first plan
+        self.bound_cols = sketch_bound_columns(ctx) | const_bound_columns(ctx)
+        self.group_cols = frozenset(c for g in ctx.group_by for c in g.columns())
+        # the query's predicates in the order _build_plan compiles them:
+        # WHERE's, then each aggregation's FILTER clause's
+        self.predicates: List = list(ctx.filter.predicates()) if ctx.filter else []
+        for spec in ctx.aggregations:
+            if spec.filter is not None:
+                self.predicates.extend(spec.filter.predicates())
+        # the columns a segment is asked about: their shapes
+        # (shape.audit_predicate, WHERE's alone; the rest ride along) and
+        # their dictionaries
+        self.predicate_cols = tuple(p.lhs.op if p.lhs.is_column else None for p in self.predicates)
+        self._group_by = ("|".join(g.fingerprint() for g in ctx.group_by), ctx.null_handling)
+        self._referenced = _referenced_columns(ctx)
+        self._needed: Optional[List[str]] = None  # where no segment changes it
+        self._half_key: Optional[Tuple] = None
+        self._shape_fps: Dict[Tuple, str] = {}
+        self._lookups: Dict[Tuple, Tuple] = {}
+
+    def needed_columns(self, segment) -> List[str]:
+        """_needed_columns(ctx, segment).  The segment enters it in two
+        places only, a `*` (expanded to the segment's own columns) and an
+        aggregation alias in ORDER BY / HAVING (a column only where the
+        segment's schema has one of that name): a query with neither reads
+        the same columns of every segment."""
+        if self._needed is not None:
+            return self._needed
+        needed = _needed_columns(self.ctx, segment, self._referenced)
+        cols, _, agg_aliases = self._referenced
+        if "*" not in cols and not agg_aliases:
+            self._needed = needed
+        return needed
+
+    def key(self, segment) -> Tuple:
+        """The plan-cache key of (ctx, segment): what plan_segment has always
+        keyed on, `(ctx.shape_fingerprint(column_info_from(segment)),
+        _segment_signature(...), ops.scan_backend())`, each half derived
+        once."""
+        return self._key(segment, self.needed_columns(segment), _segment_memo(segment))[0]
+
+    def _key(self, segment, needed: List[str], memo: _SegmentMemo) -> Tuple[Tuple, Tuple]:
+        """(the key, the predicate columns' _dictionary_identity)."""
+        half_key = self._half_key if needed is self._needed else None
+        if half_key is None:
+            half_key = (self.predicate_cols, tuple(needed), self.bound_cols, self.group_cols)
+            if needed is self._needed:
+                self._half_key = half_key
+        half = memo.halves.get(half_key)
+        if half is None:
+            info = column_info_from(segment)
+            half = (
+                tuple([info(c) for c in self.predicate_cols if c is not None]),
+                _segment_signature(segment, needed, self.bound_cols, group_cols=self.group_cols),
+                tuple([_dictionary_identity(segment, c) for c in self.predicate_cols]),
+            )
+            if len(memo.halves) >= memo.MAX_ENTRIES:
+                memo.halves.clear()
+            memo.halves[half_key] = half
+        shapes, signature, same_dict = half
+        fp = self._shape_fps.get(shapes)
+        if fp is None:
+            fp = self._shape_fps[shapes] = self.ctx.shape_fingerprint(column_info_from(segment))
+        return (fp, signature, ops.scan_backend()), same_dict  # pallas/xla plans trace different kernels
+
+    def _bound(self, cached: SegmentPlan, segment, same_dict: Tuple, memo: _SegmentMemo) -> Optional[SegmentPlan]:
+        """The hit's plan by the entry's recipe: the entry itself with THIS
+        query's parameters and THIS segment's group dimensions (the decode
+        reads their dictionaries).  Everything else of a plan is the same for
+        every (query, segment) of the key: the kernel, the layout, the
+        columns, the aggregation functions, the key space."""
+        params = _bind_params(cached.recipe, self.predicates, segment, same_dict, self._lookups)
+        if params is None:
+            return None
+        # dataclasses.replace reads every field through getattr and runs
+        # __init__: ~30 calls where this runs for every segment of every query
+        plan = object.__new__(SegmentPlan)
+        plan.__dict__.update(cached.__dict__)
+        plan.params = params
+        ctx = self.ctx
+        if ctx.group_by:
+            dims = memo.group_dims.get(self._group_by)
+            if dims is None:
+                dims = [_group_dim(g, segment, ctx.null_handling) for g in ctx.group_by]
+                if len(memo.group_dims) >= memo.MAX_ENTRIES:
+                    memo.group_dims.clear()
+                memo.group_dims[self._group_by] = dims
+            plan.group_dims = dims
+        return plan
+
+    def plan(self, segment: ImmutableSegment) -> SegmentPlan:
+        from pinot_tpu.analysis.compile_audit import SSE_AUDIT
+
+        ctx = self.ctx
+        if not self._checked:
+            from pinot_tpu.analysis.plan_check import check_plan_cached
+
+            # static IR validation before anything traces: malformed plans
+            # raise structured PlanCheckError here instead of a tracer error
+            # inside jit
+            check_plan_cached(ctx)
+            self._checked = True
+        needed = self._needed if self._needed is not None else self.needed_columns(segment)
+        memo = _segment_memo(segment)
+        key, same_dict = self._key(segment, needed, memo)
+        cached = _PLAN_CACHE.get(key)
+        if cached is not None:
+            plan = self._bound(cached, segment, same_dict, memo) if cached.recipe is not None else None
+            bind = "recipe"
+            if plan is None:
+                # no recipe, or one that does not fit: the parameters are
+                # rebuilt and the compiled fn reused.  The structure check is
+                # the safety net under the shape audit — a mismatch would
+                # silently retrace, so it counts (and compiles) as a miss
+                # instead.
+                bind = "rebuild"
+                plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn)
+                if plan.param_layout == cached.param_layout:
+                    plan.scan_bytes = cached.scan_bytes
+                    plan.launched_on = cached.launched_on
+                    plan.widened = cached.widened
+                else:
+                    plan = None
+            if plan is not None:
+                plan.cache_key = key
+                plan.cache_hit = True
+                plan.bind = bind
+                METRICS.counter("compile.sse.binds" if bind == "recipe" else "compile.sse.rebuilds").inc()
+                SSE_AUDIT.record_hit(key[0])
+                return plan
+        SSE_AUDIT.record_compile(key[0])
+        # a process that has an entry has both counters, moved or not
+        METRICS.counter("compile.sse.binds"), METRICS.counter("compile.sse.rebuilds")
+        plan = _build_plan(ctx, segment, needed, compiled_fn=None)
+        plan.scan_bytes = segment.num_docs * scan_bytes_per_row(
+            segment.column(n) for n in plan.needed_columns
+        )
+        plan.cache_key = key
+        _PLAN_CACHE.put(key, plan)
+        return plan
+
+
+def plan_segment(ctx: QueryContext, segment: ImmutableSegment) -> SegmentPlan:
+    """The plan of one query over one segment.  A caller with many segments
+    makes ONE QueryPlanning and asks it a plan a segment
+    (executor.QueryLaunches): the query's half is then derived once."""
+    return QueryPlanning(ctx).plan(segment)
 
 
 def _build_plan(
@@ -1446,4 +1740,12 @@ def _build_plan(
         select_columns=select_columns,
         select_exprs=select_exprs,
         index_uses=list(fc.index_uses),
+        # an entry's alone: a rebuilt hit has the entry's.  A theta
+        # sub-filter's predicates come out of a function's arguments, not
+        # the query's filter trees: no recipe walks them
+        recipe=(
+            _param_recipe(fc.binders, param_layout)
+            if compiled_fn is None and not any(agg_subfilter_fns)
+            else None
+        ),
     )

@@ -133,6 +133,11 @@ class ImmutableSegment:
         self.valid_docs: Optional[np.ndarray] = None
         self.sort_order: Optional[np.ndarray] = None
         self._device_cache: Dict[str, Any] = {}
+        # the segment's half of its plan-cache keys (query/planner.py
+        # _SegmentMemo): column shapes, signatures and group dimensions do
+        # not change between queries; thrown away when valid_docs or an
+        # index appears
+        self._plan_memo = None
         # guards _device_cache reads/publishes under tiered residency
         # (segment/residency.py); NEVER held across a device copy — owners
         # stage with no lock held, then publish in one critical section so a

@@ -47,6 +47,10 @@ REGISTRY_KEYS = ("counter", "prefix", "timer")
 
 
 DRILL_LAYER = "wide and sparse group-by"
+# counters that count what should not happen: the registries must HOLD them
+# (a reducer that finds no such counter reports nothing), at 0 after QUERIES,
+# whose every plan-cache hit binds its parameters by the entry's recipe
+SOUND_AT_ZERO = {"plan_rebuilds_in_window"}
 
 
 def _program_metrics():
@@ -166,13 +170,40 @@ def test_program_still_says_what_the_metric_reads(name, served):
             )
 
     if "counter" in spec:
-        assert counters.get(spec["counter"], 0) > 0, f"{name}: counter {spec['counter']!r}"
+        if name in SOUND_AT_ZERO:
+            assert counters.get(spec["counter"]) == 0, f"{name}: counter {spec['counter']!r} missing, or moved"
+        else:
+            assert counters.get(spec["counter"], 0) > 0, f"{name}: counter {spec['counter']!r}"
     if "prefix" in spec:
         family = {k: v for k, v in counters.items() if k.startswith(spec["prefix"])}
         # replication 2 over two servers: the balanced selector routes to both
         assert len(family) == N_SERVERS and all(v > 0 for v in family.values()), (name, family)
     if "timer" in spec:
         assert timers.get(spec["timer"], 0) > 0, f"{name}: timer {spec['timer']!r}"
+
+
+def test_plan_rebuilds_counts_the_hits_that_planned_again(served):
+    """`plan_rebuilds_in_window` reads `compile.sse.rebuilds`: it stands still
+    while hits bind by their entry's recipe (`compile.sse.binds` moves instead:
+    QUERIES' segments of one signature share an entry) and moves by one a hit
+    whose entry has none, here a raw column's predicate."""
+    spec = SPECS["plan_rebuilds_in_window"]
+    assert spec["reducer"] == "counter_delta" and spec["counter"] == "compile.sse.rebuilds"
+    _, counters, _ = served
+    assert counters["compile.sse.binds"] > 0 and counters["compile.sse.rebuilds"] == 0
+    schema = Schema("raw_t", [FieldSpec("k", DataType.INT), FieldSpec("v", DataType.LONG, role=FieldRole.METRIC)])
+    block = {"k": np.arange(50, dtype=np.int32) % 5, "v": np.arange(50)}
+    seg = build_segment(schema, block, "raw0")
+    from pinot_tpu.sql.parser import parse_query
+
+    before = METRICS.counter("compile.sse.rebuilds").value
+    try:
+        for bound in (10, 20, 30):
+            plan = planner.plan_segment(parse_query(f"SELECT COUNT(*) FROM raw_t WHERE v < {bound}"), seg)
+        assert plan.cache_hit and plan.bind == "rebuild"
+        assert METRICS.counter("compile.sse.rebuilds").value == before + 2
+    finally:
+        planner.plan_cache_clear()
 
 
 def test_launches_per_query_counts_the_jitted_calls(served):

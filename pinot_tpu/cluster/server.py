@@ -139,12 +139,13 @@ class ServerInstance:
     def drop_segment(self, table: str, seg_name: str) -> None:
         seg = self.segments.get(table, {}).pop(seg_name, None)
         if seg is not None:
-            if self.residency is not None:
-                # uncharge the cache budget AND drop the device entry;
-                # the evict callback clears raw + #packed flavors together
-                self.residency.evict(seg.device_group(self.device))
-            # idempotent with the residency evict; also clears legacy pins
-            seg.evict_device(self.device)
+            for held in [seg, *seg.star_tables(made_only=True)]:  # its star-tree levels are groups of their own
+                if self.residency is not None:
+                    # uncharge the cache budget AND drop the device entry;
+                    # the evict callback clears raw + #packed flavors together
+                    self.residency.evict(held.device_group(self.device))
+                # idempotent with the residency evict; also clears legacy pins
+                held.evict_device(self.device)
             METRICS.gauge(f"server.segmentBytes.{table}").add(-_segment_bytes(seg))
             self.metrics.gauge(f"server.segmentBytes.{table}").add(-_segment_bytes(seg))
 
@@ -182,7 +183,8 @@ class ServerInstance:
         broker should fail the segments over to another replica.
 
         Tracing (ctx option `trace`): builds a per-server span subtree —
-        dispatch (per segment a launch:<segment> span over the executor's
+        dispatch (attr starSegments: the segments a star-tree level answered
+        for; per segment a launch:<segment> span over the executor's
         launch_plan / launch_ship, and per GROUP of segments that share a
         compiled kernel one launch_enqueue, ending in launch_release:
         executor.QueryLaunches; launch_ship is the resident columns' lookup
@@ -219,7 +221,9 @@ class ServerInstance:
             for name in seg_names:
                 seg = self.get_segment(ctx.table, name)
                 if seg is not None:
-                    est.append(estimate_segment_bytes(ctx, seg, planning.needed_columns(seg)))
+                    # what the query reads for it: its columns, or a star-tree level's
+                    table, asked = planning.source(seg)
+                    est.append(estimate_segment_bytes(asked.ctx, table, asked.needed_columns(table)))
             if self.residency is not None:
                 # tiered storage: HBM is a cache, so a scatter only needs
                 # its PIPELINE WINDOW resident at once (current segment +
@@ -282,11 +286,11 @@ class ServerInstance:
                         # double-buffer: stage segment k+1's columns on the
                         # residency staging thread while k dispatches/runs
                         # (a server with tiered residency launches at width 1)
-                        nxt = scan[k + 1]
+                        nxt, asked = planning.source(scan[k + 1])
                         self.residency.submit(
                             nxt.to_device,
                             device=self.device,
-                            columns=planning.needed_columns(nxt),
+                            columns=asked.needed_columns(nxt),
                             packed_codes=True,
                             residency=self.residency,
                             prefetch=True,
@@ -296,7 +300,7 @@ class ServerInstance:
                     launches.add(seg)
                 launches.flush()
                 if dsp is not None:
-                    dsp.annotate(launches=launches.calls)
+                    dsp.annotate(launches=launches.calls, starSegments=launches.star_segments)
             if trace.enabled:
                 # device/host time split: ONE fence over every pending output
                 # (trace-only — the untraced path lets collect's device_get be
@@ -320,6 +324,9 @@ class ServerInstance:
             self.metrics.counter("server.launches").inc(launches.calls)
             self.metrics.counter("server.groupedSegments").inc(launches.grouped_segments)
             self.metrics.counter("server.sparseGroups").inc(launches.sparse_groups)
+            if launches.star_segments:
+                self.metrics.counter("server.starTreeSegments").inc(launches.star_segments)
+                self.metrics.counter("server.starTreeLevelRows").inc(launches.star_level_rows)
             trace.flush(self.metrics, _STAGE_TIMERS)
             if stats.compile_ms > 0:
                 self.metrics.timer("server.compileMs").update(stats.compile_ms)

@@ -1,4 +1,4 @@
-"""Star-tree index: pre-aggregated prefix-level tensors.
+"""Star-tree index: pre-aggregated prefix-level tables, resident on the device.
 
 Reference parity: Pinot's StarTreeV2 — a materialized tree over a dimension
 split order where star (*) nodes pre-aggregate over the remaining dimensions,
@@ -16,13 +16,19 @@ all other columns.  So we materialize exactly that — for each prefix length
 k, a small columnar table ("level") of the distinct (d1..dk) combos with
 pre-aggregated partial FIELDS (count/sum/sumsq/min/max per metric).  A query
 whose filter+group-by columns all fall in the first k dims answers from
-level k: same filter compiler, same group-key packing, same partial-field
-contracts as the raw-scan path — just over collapsed rows.  Star-node
-traversal becomes *level selection*, a host-side O(1) decision.
+level k.  Star-node traversal becomes *level selection*, a host-side O(1)
+decision (query/startree.py pick_level).
 
-Level dimension columns share the PARENT segment's dictionaries (codes are
-parent codes), so star results and raw-scan results from other segments merge
-in the same key space at reduce time.
+A level is answered by the ORDINARY plan: `StarLevel.table(parent)` is the
+level as a segment of its own (LevelSegment): its dimension columns carry the
+PARENT segment's dictionaries over the level's codes (so star results and
+raw-scan results from other segments merge in one key space at reduce time),
+its fields are metric columns named `<column>:<kind>` (`*:count`,
+`lo_revenue:sum`), and its rows are padded to a bucket (level_bucket) so that
+the same level of every segment of a table has ONE shape and shares ONE
+compiled program; the true row count is the plan's bound parameter
+(planner.ROWS_KEY).  The parent's `to_device()` stages its levels with it,
+each a residency group of its own.
 
 Pinot's functionColumnPairs config maps 1:1; maxLeafRecords is accepted but
 moot here (every "leaf" is one aggregated row); instead `min_collapse`
@@ -30,28 +36,52 @@ skips building when the finest level barely collapses the data.
 """
 from __future__ import annotations
 
+import threading
+from dataclasses import replace
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from pinot_tpu.query.functions import get_agg_function
+from pinot_tpu.segment.segment import ColumnData, ImmutableSegment
 from pinot_tpu.segment.stats import ColumnStats
+from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
 
-# field kinds stored per metric column (count is global: "*:count")
-_ADDITIVE = ("sum", "sumsq")
-_MINMAX = ("min", "max")
+# A level's rows on the device are padded to a bucket: a power of two from
+# _MIN_BUCKET up to the dense scan kernel's row tile, whole tiles past it
+# (35,000 combinations -> 65,536 rows, 4,375 -> 8,192, one grand total ->
+# 1,024).  The padding is identity rows, masked out by the bound row count.
+_MIN_BUCKET = 1 << 10
+_ROW_TILE = 1 << 15
+# a dense key space up to this many slots is counted (bincount), not sorted
+_DENSE_KEY_SPACE = 1 << 24
+
+
+def level_bucket(num_rows: int) -> int:
+    if num_rows > _ROW_TILE:
+        return -(-num_rows // _ROW_TILE) * _ROW_TILE
+    return max(_MIN_BUCKET, 1 << max(0, num_rows - 1).bit_length())
+
+
+def field_column(col: str, kind: str) -> str:
+    """The level table's column that holds field `kind` of metric `col`."""
+    return f"{col}:{kind}"
 
 
 def scatter_combine(kind: str, inverse: np.ndarray, vals: np.ndarray, n_groups: int) -> np.ndarray:
     """One (count|sum|sumsq|min|max) scatter-aggregate into n_groups slots —
-    the single combine rule shared by the finest-level build, the coarser-level
-    rollup, and the star-served group-by path.  Additive integer kinds
-    accumulate exactly in int64; float kinds use bincount; min/max use ufunc
-    scatter.  `vals` is taken as-is (callers square before passing sumsq of
-    raw rows; partials re-combine without squaring)."""
+    the single combine rule shared by the finest-level build and the
+    coarser-level rollup.  Additive integer kinds accumulate exactly in
+    int64 (through bincount's f64 where every partial sum stays under 2^53,
+    else np.add.at); float kinds use bincount; min/max use ufunc scatter.
+    `vals` is taken as-is (callers square before passing sumsq of raw rows;
+    partials re-combine without squaring)."""
     vals = np.asarray(vals)
     if kind in ("count", "sum", "sumsq"):
         if np.issubdtype(vals.dtype, np.integer) and kind != "sumsq":
+            bound = int(np.abs(vals).max(initial=0)) * max(1, len(vals))
+            if bound < (1 << 53):
+                return np.bincount(inverse, weights=vals, minlength=n_groups).astype(np.int64)
             acc = np.zeros(n_groups, dtype=np.int64)
             np.add.at(acc, inverse, vals.astype(np.int64, copy=False))
             return acc
@@ -77,6 +107,16 @@ def _parse_pairs(pairs: List[Any]) -> List[Tuple[str, str]]:
             func, col = p
         out.append((func.lower(), col))
     return out
+
+
+def _distinct(key: np.ndarray, space: int) -> Tuple[np.ndarray, np.ndarray]:
+    """np.unique(key, return_inverse=True) for keys in [0, space): counted
+    where the key space is small enough to hold, sorted otherwise."""
+    if space <= _DENSE_KEY_SPACE:
+        present = np.bincount(key, minlength=space) > 0
+        rank = np.cumsum(present) - 1
+        return np.nonzero(present)[0], rank[key]
+    return np.unique(key, return_inverse=True)
 
 
 class StarTreeIndex:
@@ -110,19 +150,27 @@ class StarTreeIndex:
 
         Returns None (tree not worth it / not buildable) when: a dim or
         metric column has nulls, a metric is non-numeric, or the finest
-        level collapses rows by less than `min_collapse`x."""
-        pairs = _parse_pairs(function_column_pairs)
+        level collapses rows by less than `min_collapse`x.
 
-        # dim code matrix [n, k]: parent dict codes, or raw ints as-is
-        dim_mat = []
+        The rows' dimension codes are packed into ONE mixed-radix int64 key
+        (first dimension most significant), so a level's distinct
+        combinations are a one-dimensional unique of `key // stride` and a
+        coarser level is the next-finer one's keys divided down: the levels
+        np.unique(matrix, axis=0) gives, without sorting rows of a matrix."""
+        pairs = _parse_pairs(function_column_pairs)
+        if not num_docs:
+            return None
+
+        # per dimension: parent dict codes, or raw ints as-is
+        dim_cols = []
         for d in split_order:
             c = columns.get(d)
-            if c is None or c.nulls is not None:
+            if c is None or c.nulls is not None or c.mv_lengths is not None:
                 return None
             if c.codes is not None:
-                dim_mat.append(np.asarray(c.codes, dtype=np.int64))
+                dim_cols.append(np.asarray(c.codes))
             elif c.values is not None and np.issubdtype(np.asarray(c.values).dtype, np.integer):
-                dim_mat.append(np.asarray(c.values, dtype=np.int64))
+                dim_cols.append(np.asarray(c.values))
             else:
                 return None
 
@@ -145,12 +193,30 @@ class StarTreeIndex:
                     continue
                 need[(col, kind)] = vals
 
-        mat = np.stack(dim_mat, axis=1) if dim_mat else np.zeros((num_docs, 0), np.int64)
-        finest, inverse = np.unique(mat, axis=0, return_inverse=True)
+        # the packed key: digit i = dim i's value less its minimum, radix its range
+        lows = [int(a.min()) for a in dim_cols]
+        radix = [int(a.max()) - lo + 1 for a, lo in zip(dim_cols, lows)]
+        K = len(split_order)
+        strides = [1] * (K + 1)  # strides[k]: what a key is divided by to keep its first k digits
+        for k in range(K - 1, -1, -1):
+            strides[k] = strides[k + 1] * radix[k]
+        if strides[0] >= (1 << 62):
+            return None  # the combinations do not fit one int64 key: the scan serves the table
+        key = np.zeros(num_docs, dtype=np.int64)
+        for a, lo, stride in zip(dim_cols, lows, strides[1:]):
+            key += (a.astype(np.int64) - lo) * stride
+        finest, inverse = _distinct(key, strides[0])
         if len(finest) * min_collapse > num_docs:
             return None  # barely collapses: scanning raw rows is as cheap
 
-    # finest level: aggregate raw rows into the distinct-combo table
+        def dims_of(keys: np.ndarray, k: int) -> Dict[str, np.ndarray]:
+            """The first k dimensions' values of level k's keys (`keys` hold k digits)."""
+            return {
+                d: (keys // (strides[i + 1] // strides[k])) % radix[i] + lows[i]
+                for i, d in enumerate(split_order[:k])
+            }
+
+        # finest level: aggregate raw rows into the distinct-combo table
         n_g = len(finest)
         fields: Dict[Tuple[str, str], np.ndarray] = {}
         fields[("*", "count")] = np.bincount(inverse, minlength=n_g).astype(np.int64)
@@ -158,29 +224,16 @@ class StarTreeIndex:
             src = vals.astype(np.float64) ** 2 if kind == "sumsq" else vals
             fields[(col, kind)] = scatter_combine(kind, inverse, src, n_g)
 
-        K = len(split_order)
-        levels: Dict[int, StarLevel] = {
-            K: StarLevel(
-                num_rows=n_g,
-                dims={d: finest[:, i].copy() for i, d in enumerate(split_order)},
-                fields=fields,
-            )
-        }
+        levels: Dict[int, StarLevel] = {K: StarLevel(n_g, dims_of(finest, K), fields)}
         # coarser levels: aggregate the next-finer level (adds add, mins min)
-        cur = finest  # combo matrix aligned with levels[k + 1]'s rows
+        cur = finest  # level k + 1's keys, ascending
         for k in range(K - 1, -1, -1):
-            finer = levels[k + 1]
-            sub = cur[:, :k] if k else np.zeros((len(cur), 0), np.int64)
-            combos, inv2 = np.unique(sub, axis=0, return_inverse=True)
-            m = len(combos)
-            f2: Dict[Tuple[str, str], np.ndarray] = {}
-            for (col, kind), arr in finer.fields.items():
-                f2[(col, kind)] = scatter_combine(kind, inv2, arr, m)
-            levels[k] = StarLevel(
-                num_rows=m,
-                dims={d: combos[:, i].copy() for i, d in enumerate(split_order[:k])},
-                fields=f2,
-            )
+            combos, inv2 = np.unique(cur // radix[k], return_inverse=True)
+            f2 = {
+                field: scatter_combine(field[1], inv2, arr, len(combos))
+                for field, arr in levels[k + 1].fields.items()
+            }
+            levels[k] = StarLevel(len(combos), dims_of(combos, k), f2)
             cur = combos
         return StarTreeIndex(split_order, pairs, levels, num_docs)
 
@@ -222,15 +275,14 @@ class StarTreeIndex:
         )
 
     # -- query-time API --------------------------------------------------
-    def level_for(self, dims_used: set) -> Optional[int]:
+    def level_for(self, dims_used) -> Optional[int]:
         """Smallest prefix length covering dims_used, or None."""
-        if not dims_used <= set(self.split_order):
-            return None
-        k = 0
+        k = found = 0
         for i, d in enumerate(self.split_order):
             if d in dims_used:
                 k = i + 1
-        return k
+                found += 1
+        return k if found == len(dims_used) else None
 
     def has_fields(self, func: str, col: str) -> bool:
         fn = get_agg_function(func)
@@ -244,7 +296,9 @@ class StarTreeIndex:
 
 
 class StarLevel:
-    """One collapsed table: distinct prefix combos + aggregated fields."""
+    """One collapsed table: distinct prefix combos + aggregated fields, as
+    host arrays of its true rows (what the segment file holds), and as the
+    table a plan reads (`table`)."""
 
     def __init__(
         self,
@@ -255,54 +309,94 @@ class StarLevel:
         self.num_rows = num_rows
         self.dims = dims
         self.fields = fields
+        self.k = len(dims)  # the prefix length
+        self.made: Optional[LevelSegment] = None  # `table`'s, once asked for
+        self._lock = threading.Lock()
 
-    def facade(self, parent) -> "_StarSegmentView":
-        """Segment-shaped view over this level for FilterCompiler/_group_dim:
-        dim columns carry the PARENT's dictionaries over the level's codes."""
-        return _StarSegmentView(self, parent)
+    def table(self, parent: ImmutableSegment, tree: str) -> "LevelSegment":
+        """This level as a segment under `parent`'s dictionaries, made once."""
+        with self._lock:
+            if self.made is None:
+                self.made = LevelSegment(parent, tree, self)
+            return self.made
 
 
-class _StarSegmentView:
-    """Duck-typed ImmutableSegment over one star level (dims only)."""
+def _envelope(arr: np.ndarray) -> Tuple[int, int]:
+    """(min, max) bounds of an integer field column, widened to what its
+    limb plan can tell apart (ops.sum_limb_plan: whole bytes, int32's sign
+    bit), so that the same level of a table's segments, whose sums differ,
+    states ONE range and shares one compiled program."""
+    lo, hi = (int(arr.min()), int(arr.max())) if len(arr) else (0, 0)
+    m = max(abs(lo), abs(hi))
+    if m < (1 << 31):
+        top = (1 << 31) - 1  # narrows to int32
+        for bits in (8, 16, 24):
+            if m < (1 << bits):
+                top = (1 << bits) - 1
+                break
+    else:
+        top = (1 << (8 * (-(-m.bit_length() // 8)))) - 1
+    return (0 if lo >= 0 else -top - 1), top
 
-    def __init__(self, level: StarLevel, parent):
-        from pinot_tpu.segment.segment import ColumnData
 
-        self.num_docs = level.num_rows
-        self.schema = parent.schema
-        self.indexes: Dict[str, Dict[str, Any]] = {}
-        self.columns: Dict[str, ColumnData] = {}
+class LevelSegment(ImmutableSegment):
+    """One star-tree level as a table of its own, padded to level_bucket
+    rows: dimension columns under the parent's dictionaries (or its raw
+    ints), one metric column a field (LONG for integer counts and sums,
+    DOUBLE for the rest).  The padding rows hold code 0 and the fields'
+    identities (count 0, sum 0, min +inf, max -inf); `level_rows` is the
+    true row count, which every plan over a level binds as a parameter and
+    masks by (planner.ROWS_KEY)."""
+
+    def __init__(self, parent: ImmutableSegment, tree: str, level: StarLevel):
+        n = level.num_rows
+        bucket = level_bucket(n)
+        specs: List[FieldSpec] = []
+        columns: Dict[str, ColumnData] = {}
         for name, arr in level.dims.items():
             pc = parent.column(name)
+            # the parent's statistics: a raw-int dimension's key space (its
+            # base and range) is then the scan's; a level holds every value
+            # of a dimension its parent holds
+            stats = replace(pc.stats, num_docs=bucket, is_sorted=False, partition_id=None, num_partitions=None)
             if pc.has_dictionary:
-                codes = arr.astype(np.min_scalar_type(max(1, pc.dictionary.cardinality - 1)))
-                mn = pc.dictionary.get_values(np.array([arr.min()]))[0] if len(arr) else None
-                mx = pc.dictionary.get_values(np.array([arr.max()]))[0] if len(arr) else None
-                stats = ColumnStats(
-                    name=name, data_type=pc.data_type, num_docs=level.num_rows,
-                    cardinality=pc.dictionary.cardinality, min_value=mn, max_value=mx,
-                    is_sorted=bool(len(arr) < 2 or np.all(np.diff(arr) >= 0)),
-                    has_nulls=False, has_dictionary=True,
-                )
-                self.columns[name] = ColumnData(
-                    name, pc.data_type, pc.dictionary, codes, None, None, stats
-                )
+                codes = np.zeros(bucket, dtype=pc.codes.dtype)
+                codes[:n] = arr
+                columns[name] = ColumnData(name, pc.data_type, pc.dictionary, codes, None, None, stats)
             else:
-                vals = arr.astype(pc.values.dtype)
-                stats = ColumnStats(
-                    name=name, data_type=pc.data_type, num_docs=level.num_rows,
-                    cardinality=len(np.unique(arr)),
-                    min_value=arr.min() if len(arr) else None,
-                    max_value=arr.max() if len(arr) else None,
-                    is_sorted=bool(len(arr) < 2 or np.all(np.diff(arr) >= 0)),
-                    has_nulls=False, has_dictionary=False,
-                )
-                self.columns[name] = ColumnData(
-                    name, pc.data_type, None, None, vals, None, stats
-                )
+                vals = np.full(bucket, pc.stats.min_value, dtype=pc.values.dtype)
+                vals[:n] = arr
+                columns[name] = ColumnData(name, pc.data_type, None, None, vals, None, stats)
+            specs.append(parent.schema.field(name))
+        for (col, kind), arr in level.fields.items():
+            name = field_column(col, kind)
+            if np.issubdtype(arr.dtype, np.integer):
+                data_type, lo_hi = DataType.LONG, _envelope(arr)
+                vals = np.zeros(bucket, dtype=np.int64)
+            else:
+                data_type = DataType.DOUBLE
+                lo_hi = (float(arr.min()), float(arr.max())) if n else (None, None)
+                vals = np.full(bucket, {"min": np.inf, "max": -np.inf}.get(kind, 0.0))
+            vals[:n] = arr
+            stats = ColumnStats(
+                name=name, data_type=data_type, num_docs=bucket, cardinality=n,
+                min_value=lo_hi[0], max_value=lo_hi[1], has_dictionary=False,
+            )
+            columns[name] = ColumnData(name, data_type, None, None, vals, None, stats)
+            specs.append(FieldSpec(name, data_type, role=FieldRole.METRIC))
+        super().__init__(
+            name=f"{parent.name}/{tree}.L{level.k}",
+            table_name=parent.table_name,
+            schema=Schema(parent.schema.name, specs),
+            columns=columns,
+            num_docs=bucket,
+        )
+        self.level_rows = n
+        self.tree = tree
+        self.level = level.k
+        self.prefix = "/".join(level.dims) or "*"  # what EXPLAIN's index uses name the level by
 
-    def column(self, name: str):
-        try:
-            return self.columns[name]
-        except KeyError:
-            raise KeyError(f"star level has no dimension column {name!r}") from None
+    def device_group(self, device=None):
+        """A residency group of its own kind: the manager tells a level's
+        bytes from a segment's by it (gauge starTreeBytes)."""
+        return ("star", id(self), device)

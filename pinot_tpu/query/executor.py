@@ -135,59 +135,60 @@ def group_cap(member_bytes: float, residency=None) -> int:
 @dataclass
 class _Member:
     """One planned segment of a query, its columns resident: what a launch
-    takes a group of."""
+    takes a group of.  `table` is what the plan reads: the segment, or the
+    star-tree level that serves the query for it (indexes/startree.py
+    LevelSegment), then with `rewrite` set (query/startree.py StarRewrite:
+    the plan is the rewritten query's, `rewrite.ctx`)."""
 
-    segment: ImmutableSegment
+    table: ImmutableSegment
     plan: planner.SegmentPlan
     cols: Dict
     stats: ExecutionStats
+    rewrite: Optional[object] = None
 
 
-def _plan_member(ctx, segment, device, residency, trace, planning=None):
-    """A launch's per-segment stages, as spans of `trace`: launch_plan
-    (star-tree probe + plan cache: dictionary look-ups are per segment;
-    `planning` is the query's planner.QueryPlanning where the caller plans
-    more than one segment, so the query's half is derived once; attr `cache`
-    = hit / miss / startree, and on a hit `bind` = recipe / rebuild) and
-    launch_ship (the plan's columns looked up in, or staged into, the
-    device's cache, nothing else: no device array is made for a parameter;
-    attr paramArrays counts the host buffers that carry them, one per dtype,
-    planner.pack_params).  Returns the `("done", answer)` state where the
-    star-tree answered, else the _Member to launch."""
-    from pinot_tpu.query.startree import try_startree
-
+def _plan_member(ctx, segment, device, residency, trace, planning=None) -> _Member:
+    """A launch's per-segment stages, as spans of `trace`: launch_plan (the
+    star-tree's level selection + the plan cache: dictionary look-ups are
+    per segment; `planning` is the query's planner.QueryPlanning where the
+    caller plans more than one segment, so the query's half is derived once;
+    attr `cache` = hit / miss, on a hit `bind` = recipe / rebuild, and where
+    a star-tree level is what was planned `star` = the tree's name and
+    `level`) and launch_ship (the plan's columns looked up in, or staged
+    into, the device's cache, nothing else: no device array is made for a
+    parameter; attr paramArrays counts the host buffers that carry them, one
+    per dtype, planner.pack_params).  Returns the _Member to launch."""
+    if planning is None:
+        planning = planner.QueryPlanning(ctx)
     with trace.span("launch_plan", segment=segment.name) as psp:
-        star = try_startree(ctx, segment)
-        if star is None:
-            plan = planning.plan(segment) if planning is not None else planner.plan_segment(ctx, segment)
+        table, asked = planning.source(segment)
+        plan = asked.plan(table)
         if psp is not None:
-            if star is not None:
-                psp.annotate(cache="startree")
-            elif plan.cache_hit:
+            if plan.cache_hit:
                 psp.annotate(cache="hit", bind=plan.bind)
             else:
                 psp.annotate(cache="miss")
-    if star is not None:
-        return ("done", star)
+            if table is not segment:
+                psp.annotate(star=table.tree, level=table.level)
 
     stats = ExecutionStats(
         num_segments_queried=1,
         num_segments_processed=1,
-        num_docs_scanned=segment.num_docs,
+        num_docs_scanned=segment.num_docs if table is segment else table.level_rows,
         total_docs=segment.num_docs,
     )
     stats.filter_index_uses = tuple(plan.index_uses)
+    if table is not segment:
+        stats.add_index_uses([(table.prefix, "startree")])
     stats.kernel_bytes = plan.scan_bytes
     with trace.span("launch_ship", segment=segment.name, params=len(plan.param_layout)) as ssp:
-        # a kernel that reads no column gets none (to_device reads an empty
-        # list as every column)
-        cols = segment.to_device(
+        cols = table.to_device(
             device=device, columns=plan.needed_columns, packed_codes=True,
             residency=residency,
-        ) if plan.needed_columns else {}
+        )
         if ssp is not None:
             ssp.annotate(paramArrays=len(plan.params))
-    return _Member(segment, plan, cols, stats)
+    return _Member(table, plan, cols, stats, asked.rewrite)
 
 
 def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=None):
@@ -200,7 +201,13 @@ def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=No
     column pytrees as a tuple, nothing re-staged, and their packed parameter
     buffers stacked on the host to [N, n], riding the call as a lone
     member's ride theirs.  Returns the pending state collect_group takes:
-    the outputs carry a leading member axis when N > 1."""
+    the outputs carry a leading member axis when N > 1; the query is the one
+    the plans were made from, so the rewritten one where the members are
+    star-tree levels (all of a group are: they share a kernel), and the
+    state's last item is then the rewrite that restores their answers."""
+    rewrite = members[0].rewrite
+    if rewrite is not None:
+        ctx = rewrite.ctx
     base = members[0].plan
     width = len(members)
     if width == 1:
@@ -216,8 +223,8 @@ def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=No
         segments=width, width=width, kind=base.kind, backend=base.cache_key[2],
     )
     return (
-        "pending", ctx, [m.segment for m in members], [m.plan for m in members], out,
-        [m.stats for m in members],
+        "pending", ctx, [m.table for m in members], [m.plan for m in members], out,
+        [m.stats for m in members], rewrite,
     )
 
 
@@ -237,8 +244,6 @@ def launch_segment(
     servers' devices while this one runs."""
     trace = trace if trace is not None else Trace()
     member = _plan_member(ctx, segment, device, residency, trace)
-    if not isinstance(member, _Member):
-        return member
     return _launch_group(ctx, [member], device, trace, on_first_launch)
 
 
@@ -267,8 +272,12 @@ class QueryLaunches:
     option (group_cap): the bytes the plan's columns take against what the
     program's joined columns may take and, under tiered residency, against what the
     cache can hold at once (the caller still prefetches segment k+1 while k
-    is planned).  A star-tree answer, a plan of another kernel and a lone
-    segment are groups of their own.
+    is planned).  A plan of another kernel and a lone segment are groups of
+    their own.  A segment whose star-tree serves the query is a member like
+    any other: what it plans, ships and launches is the tree's level
+    (planner.QueryPlanning.source), the levels of a table's segments share
+    a kernel and ride one call a group, and the span says so (`levelRows`:
+    the level's true rows, beside `cpuMs` and `kernelBytes`).
 
     `check` (zero-arg, raises to abandon the query: the deadline, a kill) is
     called before each segment is planned, before each jitted call, and
@@ -290,6 +299,8 @@ class QueryLaunches:
         self.kernel_bytes = 0.0
         self.uncollected = 0  # launched groups not yet fetched
         self.sparse_groups = 0  # groups the collected sparse tables held, summed over segments
+        self.star_segments = 0  # segments a star-tree level answered for
+        self.star_level_rows = 0  # the true rows of those levels
         self._added = 0
         # the query's half of its plans, derived once (the caller's, where it
         # already asked it for the columns the query reads)
@@ -304,15 +315,17 @@ class QueryLaunches:
             member = _plan_member(
                 self.ctx, segment, self.device, self.residency, self.trace, self.planning
             )
-        if not isinstance(member, _Member):
-            self._states.append((member, [slot]))
-            return
         self.kernel_bytes += member.plan.scan_bytes
         if lsp is not None:
             # cpuMs as an attr too: beside the span's wall time, the rest is
             # waiting (interpreter lock, a lock); kernelBytes is EXPLAIN
             # ANALYZE's Bytes
             lsp.annotate(cpuMs=round(lsp.cpu_ms, 3), kernelBytes=member.plan.scan_bytes)
+        if member.rewrite is not None:
+            self.star_segments += 1
+            self.star_level_rows += member.table.level_rows
+            if lsp is not None:
+                lsp.annotate(levelRows=member.table.level_rows)
         group = self._open.setdefault(id(member.plan.fn), [])
         group.append((slot, member))
         if len(group) >= group_cap(member.plan.scan_bytes, self.residency):
@@ -348,9 +361,6 @@ class QueryLaunches:
         order added: one `collect` span and one fetch a group."""
         answers: List = [None] * self._added
         for state, slots in self._states:
-            if state[0] == "done":
-                answers[slots[0]] = state[1]
-                continue
             self.check()
             with self.trace.span("collect", segments=len(slots)) as csp:
                 for slot, answer in zip(slots, collect_group(state, self.check, self.trace)):
@@ -425,7 +435,7 @@ def collect_group(state, check=None, trace: Optional[Trace] = None):
     the groups the members' tables held (`groups`)."""
     import jax
 
-    _, ctx, segments, plans, out, stats_list = state
+    _, ctx, segments, plans, out, stats_list, rewrite = state
     host = jax.device_get(out)
     answers = []
     plan = plans[0]
@@ -435,7 +445,10 @@ def collect_group(state, check=None, trace: Optional[Trace] = None):
             if i and check is not None:
                 check()
             member = host if len(segments) == 1 else jax.tree_util.tree_map(lambda a: a[i], host)
-            answers.append(_decode_host(ctx, segment, member_plan, member, stats))
+            answer = _decode_host(ctx, segment, member_plan, member, stats)
+            if rewrite is not None:  # a star-tree level's answer, under the query's own aggregations
+                answer = (rewrite.restore(answer[0]), stats)
+            answers.append(answer)
         if tsp is not None:
             tsp.annotate(
                 tableBytes=sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(host)),
@@ -448,8 +461,6 @@ def collect_group(state, check=None, trace: Optional[Trace] = None):
 def collect_segment(state):
     """Phase 2 of launch_segment: block on the kernel's outputs and finish
     host-side."""
-    if state[0] == "done":
-        return state[1]
     (answer,) = collect_group(state)
     return answer
 
@@ -571,9 +582,8 @@ def launch_segment_batch(
     run of the same query, not N copies.  compile_ms lands on member 0.
 
     Raises BatchShapeError when members don't resolve to one compiled plan
-    (callers fall back to per-member launches).  Star-tree shortcuts are
-    intentionally not taken here — members were vetted as batchable by the
-    broker before coalescing.  Same spans as launch_segment: launch_ship is
+    (callers fall back to per-member launches).  Star-tree levels are
+    not read here: the members' plans are the segment's own.  Same spans as launch_segment: launch_ship is
     the columns plus one host-side np.stack per packed buffer of the members'
     parameters (they ride the vmapped call as host numpy), launch_release an
     empty block."""

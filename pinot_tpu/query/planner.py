@@ -42,6 +42,7 @@ from pinot_tpu.query.functions import (
 )
 from pinot_tpu.query.ir import AggregationSpec, Expr, QueryContext
 from pinot_tpu.query.shape import column_info_from, params_structure
+from pinot_tpu.query.startree import StarRewrite, pick_level, star_enabled, star_need
 from pinot_tpu.query.transform import as_row_array, eval_expr
 from pinot_tpu.segment.segment import ImmutableSegment
 from pinot_tpu.spi.schema import DataType
@@ -257,6 +258,11 @@ def grouped_plan(base: SegmentPlan, width: int) -> SegmentPlan:
 # is the segment's state (bool[num_docs], shared by every member of a batched
 # launch), not a literal of the query.
 VALID_KEY = "__valid__"
+# A star-tree level's table is padded to a bucket of rows (indexes/startree.py
+# LevelSegment) so that the same level of every segment has one shape: the
+# true row count is an int32 parameter of every plan over a level, packed
+# with the query's own, and rows from it on are masked out of every filter.
+ROWS_KEY = "__rows__"
 
 
 def pack_params(params: Dict[str, Any], layout: Tuple) -> Dict[str, np.ndarray]:
@@ -344,6 +350,8 @@ def _segment_signature(
     group_cols: frozenset = frozenset(),
 ) -> Tuple:
     sig = [segment.num_docs, segment.valid_docs is not None]
+    if getattr(segment, "level_rows", None) is not None:
+        sig.append(ROWS_KEY)  # a star-tree level: its kernel masks by the bound row count
     for name in sorted(needed):
         c = segment.column(name)
         # MV columns: the padded width is a static kernel shape, and the
@@ -731,6 +739,9 @@ def column_limb_sig(c) -> Optional[Tuple[int, bool]]:
     if c.data_type in (DataType.INT, DataType.LONG, DataType.TIMESTAMP, DataType.BOOLEAN):
         s = c.stats
         if s.num_docs and s.min_value is not None:
+            if int(s.min_value) < -(1 << 31) or int(s.max_value) >= (1 << 31):
+                # past int32 the fused scan takes signed-magnitude int64 limbs
+                return ("int64", ops.sum_limb_plan64(s.min_value, s.max_value))
             return ops.sum_limb_plan(s.min_value, s.max_value)
     return None
 
@@ -1128,6 +1139,7 @@ class ParamRecipe:
     # (dtype, length) of each packed buffer, in pack_params' order
     buffers: Tuple[Tuple[str, int], ...]
     valid_docs: bool  # VALID_KEY rides beside the buffers
+    level_rows: Optional[int] = None  # where ROWS_KEY sits in the int32 buffer (a star-tree level's plan)
 
 
 def _param_recipe(binders: Optional[List[Tuple]], layout: Tuple) -> Optional[ParamRecipe]:
@@ -1143,6 +1155,7 @@ def _param_recipe(binders: Optional[List[Tuple]], layout: Tuple) -> Optional[Par
             at = lengths.get(dtype, 0)
             where[key] = (dtype, at, shape)
             lengths[dtype] = at + math.prod(shape)
+    rows_at = where.pop(ROWS_KEY, None)
     out = []
     for kind, ptype, column, mv, keys in binders:
         want = {"none": [], "range": [("int32", ()), ("int32", ())], "table": [("bool", None)]}[kind]
@@ -1156,7 +1169,8 @@ def _param_recipe(binders: Optional[List[Tuple]], layout: Tuple) -> Optional[Par
     if where:
         return None
     return ParamRecipe(
-        tuple(out), tuple(lengths.items()), any(key == VALID_KEY for key, _, _ in layout)
+        tuple(out), tuple(lengths.items()), any(key == VALID_KEY for key, _, _ in layout),
+        None if rows_at is None else rows_at[1],
     )
 
 
@@ -1199,6 +1213,10 @@ def _bind_params(
         if segment.valid_docs is None:
             return None
         packed[VALID_KEY] = np.asarray(segment.valid_docs, dtype=bool)
+    if recipe.level_rows is not None:
+        if segment.level_rows is None:
+            return None
+        packed["int32"][recipe.level_rows] = segment.level_rows
     return packed
 
 
@@ -1270,10 +1288,23 @@ class QueryPlanning:
     half is memoised on the segment (_SegmentMemo).  A plan-cache hit then
     BINDS its parameters through the entry's ParamRecipe; an entry without
     one, or a recipe that does not fit, rebuilds through _build_plan as
-    before."""
+    before.
+
+    Where a segment has a star-tree that serves the query (`source`), what
+    is planned is the tree's level, a table of its own, under the query
+    rewritten onto the level's fields (query/startree.py StarRewrite): that
+    query has a QueryPlanning of its own, made at the first such segment,
+    and everything above holds for it and the levels."""
 
     def __init__(self, ctx: QueryContext):
         self.ctx = ctx
+        # what the query asks of a star-tree (startree.star_need), None where
+        # none may serve it; the rewritten query's planning, once a segment
+        # has a tree that does; on THAT planning, the rewrite it plans
+        self._star_need = star_need(ctx) if star_enabled(ctx) else None
+        self._star: Optional[QueryPlanning] = None
+        self.rewrite: Optional[StarRewrite] = None
+        self._sources: Dict[int, Tuple] = {}  # id(segment) -> source(segment)
         self._checked = False  # check_plan_cached(ctx) has passed: at the first plan
         self.bound_cols = sketch_bound_columns(ctx) | const_bound_columns(ctx)
         self.group_cols = frozenset(c for g in ctx.group_by for c in g.columns())
@@ -1293,6 +1324,28 @@ class QueryPlanning:
         self._half_key: Optional[Tuple] = None
         self._shape_fps: Dict[Tuple, str] = {}
         self._lookups: Dict[Tuple, Tuple] = {}
+
+    def source(self, segment) -> Tuple[Any, "QueryPlanning"]:
+        """(the table the query reads for `segment`, the planning to ask for
+        its columns and its plan): the star-tree level that serves the query
+        and the rewritten query's planning (its `rewrite` set), else
+        (segment, self).  A table without a tree returns at once."""
+        if self._star_need is None or not getattr(segment, "indexes", {}).get("startree"):
+            return segment, self
+        got = self._sources.get(id(segment))
+        if got is None:
+            pick = pick_level(self._star_need, segment)
+            if pick is None:
+                got = (segment, self)
+            else:
+                if self._star is None:
+                    rewrite = StarRewrite(self.ctx)
+                    self._star = QueryPlanning(rewrite.ctx)
+                    self._star.rewrite = rewrite
+                name, tree, k = pick
+                got = (tree.levels[k].table(segment, name), self._star)
+            self._sources[id(segment)] = got
+        return got
 
     def needed_columns(self, segment) -> List[str]:
         """_needed_columns(ctx, segment).  The segment enters it in two
@@ -1445,6 +1498,21 @@ def _build_plan(
         def filter_fn(cols, params):
             t, nl = base_filter_fn(cols, params)
             v = params[VALID_KEY]
+            return t & v, (nl & v if nl is not None else None)
+
+    # A star-tree level: its table's rows past the true count are padding
+    # (identity rows), masked out of every filter like replaced rows above.
+    level_rows = getattr(segment, "level_rows", None)
+    if level_rows is not None:
+        fc.params[ROWS_KEY] = np.int32(level_rows)
+        whole_table_filter_fn = filter_fn
+
+        def filter_fn(cols, params):
+            # trace time: this plan's program reads a star-tree level (beside
+            # the backend's own scan.traced.* counter)
+            METRICS.counter("scan.traced.startree").inc()
+            t, nl = whole_table_filter_fn(cols, params)
+            v = jnp.arange(segment.num_docs, dtype=jnp.int32) < params[ROWS_KEY]
             return t & v, (nl & v if nl is not None else None)
 
     # Device-trace names (HLO op_name metadata only; nothing computes

@@ -1,4 +1,5 @@
-"""Star-tree query routing + execution over collapsed level tables.
+"""Star-tree query routing: level selection and the query's rewrite onto a
+level's fields.
 
 Reference parity: Pinot injects the star-tree when a group-by's filter and
 group columns fall inside the tree's dimension split order and every
@@ -10,188 +11,147 @@ StarTreeFilterOperator.java:90,218; StarTreeAggregationExecutor /
 StarTreeGroupByExecutor, .../core/startree/executor/).
 
 Re-design (see indexes/startree.py): tree traversal becomes level selection —
-pick the smallest prefix level covering the query's dimension set, compile the
-ordinary FilterCompiler against the level facade (parent dictionaries, so the
-result merges with raw-scan segments in one key space), and combine the
-pre-aggregated partial FIELDS per group.  Rows scanned = collapsed level rows,
-the docs-scanned win the reference gets from skipping to aggregated docs.
+pick the smallest prefix level covering the query's dimension set
+(`pick_level`, O(1) on the host) — and the level is a table like any other
+(LevelSegment: the parent's dictionaries, the fields as metric columns,
+resident on the device).  What is left to do here is to say the query in the
+level's columns (`StarRewrite`: COUNT(*) becomes the sum of `*:count`, SUM(x)
+the sum of `x:sum`, MIN / MAX those of `x:min` / `x:max`, AVG both), so that
+the ORDINARY plan answers it — plan cache, group launch, one fetch a group,
+the decode (planner.QueryPlanning.source, executor.QueryLaunches) — and to put
+the answer's partials back under the names the query's own aggregations
+merge by (`StarRewrite.restore`).  Rows scanned = collapsed level rows, the
+docs-scanned win the reference gets from skipping to aggregated docs.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from pinot_tpu.indexes.startree import scatter_combine
-from pinot_tpu.query import planner
-from pinot_tpu.query.filter import FilterCompiler
+from pinot_tpu.indexes.startree import field_column
 from pinot_tpu.query.functions import for_spec
-from pinot_tpu.query.ir import QueryContext
-from pinot_tpu.query.result import (
-    AggSegmentResult,
-    ExecutionStats,
-    GroupBySegmentResult,
-)
+from pinot_tpu.query.ir import AggregationSpec, Expr, QueryContext
+from pinot_tpu.query.result import AggSegmentResult, DenseGroupData, GroupBySegmentResult
 
-_IDENT = {"count": 0, "sum": 0, "sumsq": 0.0, "min": np.inf, "max": -np.inf}
+# what answers a field kind over a level's rows, and the field of that answer
+_FIELD_AGG = {"count": "sum", "sum": "sum", "sumsq": "sum", "min": "min", "max": "max"}
 
 
-def pick_tree(ctx: QueryContext, segment) -> Optional[Tuple[object, int]]:
-    """(StarTreeIndex, level k) when a tree of this segment can answer ctx."""
-    trees = getattr(segment, "indexes", {}).get("startree", {})
-    if not trees or ctx.joins or not ctx.is_aggregate:
+def star_enabled(ctx: QueryContext) -> bool:
+    """The query option `useStarTree` (upstream's; default true)."""
+    opt = ctx.options.get("useStarTree", True)
+    return bool(opt) and not (isinstance(opt, str) and opt.lower() in ("false", "0"))
+
+
+def star_need(ctx: QueryContext) -> Optional[Tuple[frozenset, Tuple[Tuple[str, str], ...]]]:
+    """What `ctx` asks of a tree, by upstream's rules: (the dimensions its
+    filters and GROUP BY name, (function, column) of each aggregation), or
+    None where no tree can serve it: a join, no aggregation, a GROUP BY
+    expression, an aggregation over an expression.  The query's half of the
+    decision, made once a query (planner.QueryPlanning)."""
+    if ctx.joins or not ctx.is_aggregate:
         return None
+    dims = set()
     for g in ctx.group_by:
-        if not g.is_column or g.op == "*":
+        if not g.is_column:
             return None
-    group_cols = {g.op for g in ctx.group_by}
-    filter_cols = set(ctx.filter.columns()) if ctx.filter else set()
-    agg_filter_cols = set()
+        dims.add(g.op)
+    if ctx.filter:
+        dims.update(ctx.filter.columns())
+    pairs = []
     for spec in ctx.aggregations:
         if spec.expr is not None and not spec.expr.is_column:
             return None
         if spec.filter is not None:
-            agg_filter_cols |= set(spec.filter.columns())
-    dims_used = group_cols | filter_cols | agg_filter_cols
-    if "*" in dims_used:
+            dims.update(spec.filter.columns())
+        pairs.append((spec.function, spec.expr.op if spec.expr is not None else "*"))
+    if "*" in dims:
         return None
+    return frozenset(dims), tuple(pairs)
 
-    best: Optional[Tuple[object, int]] = None
-    for st in trees.values():
-        k = st.level_for(dims_used)
+
+def pick_level(need, segment) -> Optional[Tuple[str, object, int]]:
+    """(tree name, StarTreeIndex, level k) of the tree of `segment` that
+    answers `need` (star_need) from the fewest rows, or None.  Upsert
+    segments never use one: pre-aggregated levels cannot honor per-row
+    validDocIds (the reference likewise excludes star-trees from upsert
+    tables)."""
+    trees = segment.indexes.get("startree")
+    if not trees or need is None or segment.valid_docs is not None:
+        return None
+    dims, pairs = need
+    best = None
+    for name, st in trees.items():
+        k = st.level_for(dims)
         if k is None:
             continue
-        ok = True
-        for spec in ctx.aggregations:
-            col = spec.expr.op if spec.expr is not None else "*"
-            if col != "*" and segment.column(col).nulls is not None:
-                ok = False  # star count fields assume null-free metrics
-                break
-            if not st.has_fields(spec.function, col):
-                ok = False
-                break
-        if not ok:
+        if best is not None and st.levels[k].num_rows >= best[1].levels[best[2]].num_rows:
             continue
-        if best is None or st.levels[k].num_rows < best[0].levels[best[1]].num_rows:
-            best = (st, k)
+        # star count fields assume null-free metrics
+        if all(
+            st.has_fields(func, col) and (col == "*" or segment.column(col).nulls is None)
+            for func, col in pairs
+        ):
+            best = (name, st, k)
     return best
 
 
-def execute_star(ctx: QueryContext, segment, st, k):
-    """Run ctx against star level k; returns (SegmentResult, ExecutionStats).
+class StarRewrite:
+    """`ctx` said in a level's columns.  Every field of every aggregation
+    becomes one aggregation over the level's field column (shared where two
+    aggregations read the same field): `self.ctx` is what a level's plan is
+    made from, `restore` puts its answer under the query's own aggregations.
+    It depends on the query alone, not on the tree or the level: one a
+    query.
 
-    Returns None when a runtime limit (composite key overflow) forces the
-    regular scan path after all."""
-    lvl = st.levels[k]
-    view = lvl.facade(segment)
-    stats = ExecutionStats(
-        num_segments_queried=1,
-        num_segments_processed=1,
-        num_docs_scanned=lvl.num_rows,
-        total_docs=segment.num_docs,
-    )
+    The rewritten query keeps the filters, the GROUP BY and the options; it
+    drops HAVING and ORDER BY, which the reduce applies to the merged
+    groups.  (A level that holds more groups than numGroupsLimit is trimmed
+    by lowest key, as every path trims without an ORDER BY it can rank by.)"""
 
-    fc = FilterCompiler(view, null_handling=False)
-    filter_fn = fc.compile(ctx.filter)
-    agg_specs = list(ctx.aggregations)
-    agg_filter_fns = [
-        fc.compile(s.filter) if s.filter is not None else None for s in agg_specs
-    ]
-
-    # level tables are collapsed-small: evaluate the compiled mask closures
-    # eagerly (jnp ops accept numpy inputs) and finish host-side
-    cols: Dict[str, Dict[str, np.ndarray]] = {}
-    for name, c in view.columns.items():
-        entry: Dict[str, np.ndarray] = {}
-        if c.codes is not None:
-            entry["codes"] = c.codes
-            dv = c.dictionary.device_values() if c.dictionary else None
-            if dv is not None:
-                entry["dict"] = dv
-        if c.values is not None:
-            entry["values"] = c.values
-        cols[name] = entry
-    tmask = np.asarray(filter_fn(cols, fc.params)[0])
-    agg_masks = [
-        tmask if fn is None else (tmask & np.asarray(fn(cols, fc.params)[0]))
-        for fn in agg_filter_fns
-    ]
-
-    counts = lvl.fields[("*", "count")]
-    aggs = [for_spec(s) for s in agg_specs]
-    stats.add_index_uses(fc.index_uses)
-    stats.add_index_uses([("/".join(st.split_order[:k]) or "*", "startree")])
-
-    def field_source(spec, kind) -> np.ndarray:
-        if kind == "count":
-            return counts
-        return lvl.fields[(spec.expr.op, kind)]
-
-    if not ctx.group_by:
-        partials: List[Dict[str, np.ndarray]] = []
-        for spec, fn, m in zip(agg_specs, aggs, agg_masks):
-            p: Dict[str, np.ndarray] = {}
-            for fname, kind in fn.field_kinds.items():
-                src = field_source(spec, kind)
-                sel = src[m]
-                if kind in ("count", "sum", "sumsq"):
-                    p[fname] = sel.sum() if len(sel) else np.asarray(_IDENT[kind], src.dtype)
-                elif kind == "min":
-                    p[fname] = sel.min() if len(sel) else np.asarray(np.inf)
-                else:
-                    p[fname] = sel.max() if len(sel) else np.asarray(-np.inf)
-            partials.append(p)
-        return AggSegmentResult(partials=partials), stats
-
-    # group-by: pack level dim codes into composite keys (same packing as the
-    # raw-scan paths so decoded keys land in the same space)
-    group_dims = [planner._group_dim(g, view, False) for g in ctx.group_by]
-    packed = np.zeros(lvl.num_rows, dtype=np.int64)
-    scale = 1
-    for gd in reversed(group_dims):
-        if scale > (1 << 62) // max(1, gd.cardinality):
-            return None  # >63-bit composite key: let the scan path handle it
-        c = view.column(gd.name)
-        code = (
-            c.codes.astype(np.int64)
-            if gd.kind == "dict"
-            else c.values.astype(np.int64) - gd.base
+    def __init__(self, ctx: QueryContext):
+        specs: List[AggregationSpec] = []
+        at: Dict[Tuple, int] = {}
+        # per aggregation of ctx: ((its field, index into specs, that answer's field, counts?), ...)
+        self.fields: List[Tuple[Tuple[str, int, str, bool], ...]] = []
+        for spec in ctx.aggregations:
+            col = spec.expr.op if spec.expr is not None else "*"
+            mine = []
+            for fname, kind in for_spec(spec).field_kinds.items():
+                source = field_column("*" if kind == "count" else col, kind)
+                key = (_FIELD_AGG[kind], source, spec.filter.fingerprint() if spec.filter else None)
+                if key not in at:
+                    at[key] = len(specs)
+                    specs.append(AggregationSpec(_FIELD_AGG[kind], Expr.col(source), filter=spec.filter))
+                mine.append((fname, at[key], _FIELD_AGG[kind], kind == "count"))
+            self.fields.append(tuple(mine))
+        self.ctx = dataclasses.replace(
+            ctx, select_list=list(specs), select_aliases=[None] * len(specs),
+            extra_aggregations=[], having=None, order_by=[],
         )
-        packed += code * scale
-        scale *= gd.cardinality
 
-    sel = np.nonzero(tmask)[0]
-    uniq, inverse_sel = np.unique(packed[sel], return_inverse=True)
-    if len(uniq) > ctx.num_groups_limit:
-        keep = inverse_sel < ctx.num_groups_limit
-        sel = sel[keep]
-        inverse_sel = inverse_sel[keep]
-        uniq = uniq[: ctx.num_groups_limit]
-    n_groups = len(uniq)
-    keys = planner.decode_packed_keys(group_dims, uniq)
+    def _partials(self, partials: List[Dict]) -> List[Dict]:
+        out = []
+        for mine in self.fields:
+            p = {}
+            for fname, i, field, counts in mine:
+                v = partials[i][field]
+                # a count is a sum of the level's counts: whole, as COUNT's is
+                p[fname] = np.rint(np.asarray(v)).astype(np.int64) if counts else v
+            out.append(p)
+        return out
 
-    partials = []
-    for spec, fn, m in zip(agg_specs, aggs, agg_masks):
-        msel = m[sel]
-        p: Dict[str, np.ndarray] = {}
-        for fname, kind in fn.field_kinds.items():
-            src = field_source(spec, kind)[sel]
-            p[fname] = scatter_combine(kind, inverse_sel[msel], src[msel], n_groups)
-        partials.append(p)
-    stats.num_groups = n_groups
-    return GroupBySegmentResult(keys=keys, partials=partials, dense=None), stats
-
-
-def try_startree(ctx: QueryContext, segment):
-    """Entry point for executor: result when a star-tree served the query."""
-    opt = ctx.options.get("useStarTree", True)
-    if (not opt) or (isinstance(opt, str) and opt.lower() in ("false", "0")):
-        return None
-    # upsert segments: pre-aggregated levels can't honor per-row validDocIds
-    # (the reference likewise excludes star-trees from upsert tables)
-    if getattr(segment, "valid_docs", None) is not None:
-        return None
-    pick = pick_tree(ctx, segment)
-    if pick is None:
-        return None
-    return execute_star(ctx, segment, pick[0], pick[1])
+    def restore(self, result):
+        """The level's answer as the scan's: same keys, the partials under
+        the query's own aggregations and field names."""
+        if isinstance(result, AggSegmentResult):
+            return AggSegmentResult(partials=self._partials(result.partials))
+        dense = result.dense
+        if dense is not None:
+            dense = DenseGroupData(
+                presence=dense.presence, partials=self._partials(dense.partials),
+                key_space=dense.key_space, group_dims=dense.group_dims,
+            )
+        return GroupBySegmentResult(keys=result.keys, partials=self._partials(result.partials), dense=dense)

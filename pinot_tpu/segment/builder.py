@@ -199,7 +199,9 @@ def build_segment(
     # star-tree indexes: pre-aggregated prefix-level tensors (indexes/startree.py)
     for i, st_cfg in enumerate(idx_cfg.star_tree_index_configs):
         from pinot_tpu.indexes.startree import StarTreeIndex
+        from pinot_tpu.utils.metrics import METRICS
 
+        t0 = time.perf_counter()
         st = StarTreeIndex.build(
             columns,
             num_docs,
@@ -207,6 +209,7 @@ def build_segment(
             st_cfg.get("functionColumnPairs", []),
             min_collapse=float(st_cfg.get("minCollapse", 1.1)),
         )
+        METRICS.timer("segment.starTreeBuildMs").update((time.perf_counter() - t0) * 1000.0)
         if st is not None:
             indexes.setdefault("startree", {})[f"st{i}"] = st
 

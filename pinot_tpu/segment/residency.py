@@ -97,6 +97,8 @@ class ResidencyManager:
         self._entries: Dict[Tuple, _Entry] = {}
         self._clock = 0  # logical access clock (recency, not wall time)
         self._resident_bytes = 0
+        # of which star-tree levels (groups keyed ("star", ...): gauge starTreeBytes)
+        self._star_bytes = 0
         self._stream: Optional[ThreadPoolExecutor] = None
 
     # -- staging stream -------------------------------------------------
@@ -204,6 +206,8 @@ class ResidencyManager:
             e = self._entries[group]
             e.nbytes += e.pending
             self._resident_bytes += e.pending
+            if group[0] == "star":
+                self._star_bytes += e.pending
             e.pending = 0
             e.state = RESIDENT
             self._clock += 1
@@ -287,6 +291,8 @@ class ResidencyManager:
             self.budget.uncharge(e.nbytes)
             with self._lock:
                 self._resident_bytes -= e.nbytes
+                if e.group[0] == "star":
+                    self._star_bytes -= e.nbytes
                 e.nbytes = 0
                 self._entries.pop(e.group, None)
                 METRICS.counter(f"{self.name}.evictions").inc()
@@ -368,6 +374,7 @@ class ResidencyManager:
 
     def _publish_locked(self) -> None:
         METRICS.gauge(f"{self.name}.residentBytes").set(float(self._resident_bytes))
+        METRICS.gauge(f"{self.name}.starTreeBytes").set(float(self._star_bytes))
 
     # -- observability ---------------------------------------------------
     @property

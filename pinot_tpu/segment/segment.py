@@ -106,6 +106,10 @@ class ColumnData:
 class ImmutableSegment:
     """Loaded immutable segment with optional device residency."""
 
+    # the true row count of a star-tree level's table (indexes/startree.py
+    # LevelSegment), whose rows are padded to a bucket; None: every row counts
+    level_rows: Optional[int] = None
+
     def __init__(
         self,
         name: str,
@@ -189,6 +193,17 @@ class ImmutableSegment:
     @property
     def column_names(self) -> List[str]:
         return list(self.columns)
+
+    def star_tables(self, made_only: bool = False) -> List["ImmutableSegment"]:
+        """Every level of every star-tree of this segment, as the tables the
+        plans read (indexes/startree.py LevelSegment); with `made_only`,
+        those a plan or a staging has already asked for."""
+        tables = [
+            level.made if made_only else level.table(self, name)
+            for name, tree in self.indexes.get("startree", {}).items()
+            for level in tree.levels.values()
+        ]
+        return [t for t in tables if t is not None]
 
     # -- device residency ----------------------------------------------
     def device_group(self, device=None):
@@ -300,8 +315,19 @@ class ImmutableSegment:
         only plan kernels that unpack at trace time (or route the words to
         the Pallas lane-unpack) can consume it; direct `cols[n]["codes"]`
         readers keep the default.  Packed entries cache under a distinct
-        key so the two shapes never alias."""
-        cols = columns or list(self.columns)
+        key so the two shapes never alias.
+
+        `columns=None` is the whole segment: every column, and every level
+        of its star-trees (each a table and a residency group of its own,
+        under key "*startree" -> its name); an empty list is no column."""
+        cols = list(self.columns) if columns is None else columns
+        if columns is None and self.indexes.get("startree"):
+            out = self.to_device(device, cols, packed_codes, residency, prefetch, query_id)
+            out["*startree"] = {
+                t.name: t.to_device(device, None, packed_codes, residency, prefetch, query_id)
+                for t in self.star_tables()
+            }
+            return out
         if residency is None:
             # legacy pin-everything path: no budget, no eviction — but the
             # copy still happens with no lock held, and the publish races

@@ -17,8 +17,18 @@ they are cases of their own, on a table whose key space is wide, served once
 through the wide scatter under the chip's accumulation policy and once
 through the sparse sort.
 
+The metrics of the layer `star-tree` (PR 37) read what only a table with a
+star-tree produces (`starSegments`, `levelRows`, the trace-time counter
+`scan.traced.startree`, the build timer, the residency gauge): cases of their
+own again, on a table with a tree, served one query the tree answers and one
+it may not.
+
 The files are read as data: nothing of `benchmarks/lib` is imported, and no
-number is checked, only presence.
+number is checked, only presence.  The last test alone runs the benchmark's
+own readers: PR 36 was refused because one of them found nothing in a cell it
+is given (`launch_cpu_ms`), so every per-layer metric `load_cell` gives the
+star-tree cell must return a value over a traced window of that cell's own
+traffic at toy size.
 """
 import glob
 import json
@@ -47,6 +57,7 @@ REGISTRY_KEYS = ("counter", "prefix", "timer")
 
 
 DRILL_LAYER = "wide and sparse group-by"
+STAR_LAYER = "star-tree"
 # counters that count what should not happen: the registries must HOLD them
 # (a reducer that finds no such counter reports nothing), at 0 after QUERIES,
 # whose every plan-cache hit binds its parameters by the entry's recipe
@@ -54,19 +65,21 @@ SOUND_AT_ZERO = {"plan_rebuilds_in_window"}
 
 
 def _program_metrics():
-    """(the files that read spans, counters or timers; every file of DRILL_LAYER)"""
-    specs, drill = {}, {}
+    """(the files that read spans, counters or timers; every file of DRILL_LAYER; every file of STAR_LAYER)"""
+    specs, drill, star = {}, {}, {}
     for path in sorted(glob.glob(os.path.join(ROOT, "benchmarks", "layer_metrics", "*.json"))):
         with open(path, encoding="utf-8") as f:
             spec = json.load(f)
         if spec.get("layer") == DRILL_LAYER:
             drill[spec["name"]] = spec
+        elif spec.get("layer") == STAR_LAYER:
+            star[spec["name"]] = spec
         elif spec.get("source") in ("program_span", "program_counter"):
             specs[spec["name"]] = spec
-    return specs, drill
+    return specs, drill, star
 
 
-SPECS, DRILL_SPECS = _program_metrics()
+SPECS, DRILL_SPECS, STAR_SPECS = _program_metrics()
 N_SERVERS = 2
 QUERIES = (
     "SELECT region, SUM(rev) FROM contract WHERE qty < 40 GROUP BY region ORDER BY region",
@@ -342,3 +355,188 @@ def test_the_drill_configuration_shares_sf1s_table():
         bench = json.load(f)
     (entry,) = [c for c in bench["configs"] if c["name"] == "ssb_flat_sf1_drill"]
     assert entry["source"] == drill["source"] and entry["reduced"] == drill["reduced"]
+
+
+# ---------------------------------------------------------------------------
+# the layer `star-tree` (PR 37)
+# ---------------------------------------------------------------------------
+STAR_QUERIES = {  # the tree serves the first; the second sums an expression, which Pinot's rules send to the scan
+    "tree": "SELECT region, SUM(rev), COUNT(*) FROM star WHERE qty < 40 GROUP BY region ORDER BY region",
+    "scan": "SELECT region, SUM(rev - qty) FROM star WHERE qty < 40 GROUP BY region ORDER BY region",
+}
+
+
+@pytest.fixture(scope="module")
+def star_served():
+    """({tree, scan}: span tree of the traced answer, counters, timers, gauges)
+    of one server with two segments that each have a star-tree."""
+    from pinot_tpu.spi.config import IndexingConfig
+
+    schema = Schema(
+        "star",
+        [
+            FieldSpec("region", DataType.INT),
+            FieldSpec("qty", DataType.INT),
+            FieldSpec("rev", DataType.INT, role=FieldRole.METRIC),
+        ],
+    )
+    tcfg = TableConfig(name="star", indexing=IndexingConfig(star_tree_index_configs=[
+        {"dimensionsSplitOrder": ["region", "qty"], "functionColumnPairs": ["SUM__rev", "COUNT__*"]}]))
+    coord = Coordinator(replication=1)
+    server = ServerInstance("server0")
+    coord.register_server(server)
+    coord.add_table(schema, tcfg)
+    METRICS.reset()
+    planner.plan_cache_clear()
+    rng = np.random.default_rng(37)
+    for i in range(2):
+        block = {
+            "region": rng.integers(0, 5, 3000).astype(np.int32),
+            "qty": rng.integers(1, 51, 3000).astype(np.int32),
+            "rev": rng.integers(1, 10**7, 3000).astype(np.int32),
+        }
+        seg = build_segment(schema, block, f"seg{i}", table_config=tcfg)
+        coord.add_segment("star", seg)
+        seg.to_device(device=server.device, residency=server.residency)  # as the benchmark's set-up stages it
+    front = QueryServer(Broker(coord)).start()
+    try:
+        trees = {name: _ask_traced(front, sql) for name, sql in STAR_QUERIES.items()}
+    finally:
+        front.stop()
+        planner.plan_cache_clear()
+    snaps = [METRICS.snapshot(), server.metrics.snapshot()]
+    counters, timers, gauges = {}, {}, {}
+    for snap in snaps:
+        for k, v in snap["counters"].items():
+            counters[k] = counters.get(k, 0) + v
+        for k, t in snap["timers"].items():
+            timers[k] = timers.get(k, 0) + t["count"]
+        gauges.update(snap["gauges"])
+    return trees, counters, timers, gauges
+
+
+def test_the_star_tree_layer_has_its_metrics():
+    assert set(STAR_SPECS) == {"startree_segments_per_query", "startree_level_rows_per_query", "startree_roofline",
+                               "startree_build_s", "startree_resident_bytes"}
+
+
+@pytest.mark.parametrize("name", sorted(STAR_SPECS))
+def test_program_still_says_what_a_star_tree_metric_reads(name, star_served):
+    import re
+
+    spec = STAR_SPECS[name]
+    trees, counters, timers, gauges = star_served
+    named = [k for k in ("span", "served_by_counter", "timer", "pattern") if k in spec]
+    assert named, f"{name}: names nothing this test knows how to look for: {sorted(spec)}"
+    if "span" in spec:
+        values = {how: [n.get("attrs", {}).get(spec["attr"]) for n in _named(tree, spec["span"])] for how, tree in trees.items()}
+        assert values["tree"] and all(isinstance(v, (int, float)) for v in values["tree"]), (name, values)
+        if spec["attr"] == "levelRows":  # of the launches that read a level, and of no other
+            assert all(v > 0 for v in values["tree"]) and values["scan"] == [None, None], values
+        else:  # of every query: the scan-served one says 0
+            assert values == {"tree": [2], "scan": [0]}, values
+    if "served_by_counter" in spec:
+        assert counters.get(spec["served_by_counter"], 0) > 0, f"{name}: trace-time counter {spec['served_by_counter']!r}"
+    if "timer" in spec:
+        assert timers.get(spec["timer"], 0) == 2, f"{name}: timer {spec['timer']!r}: one update a tree a segment"
+    if "pattern" in spec:
+        found = {k: v for k, v in gauges.items() if re.fullmatch(spec["pattern"], k)}
+        assert list(found) == ["residency.server0.starTreeBytes"] and found["residency.server0.starTreeBytes"] > 0, (name, gauges)
+
+
+def test_a_star_tree_launch_is_a_launch_like_any_other(star_served):
+    """What the star-tree layer adds to the spans the older metrics read:
+    `launch_plan` says `star` and `level` beside `cache`, `launch:<segment>`
+    keeps `cpuMs` and `kernelBytes` (PR 36 was refused for a cell without a
+    single `cpuMs`), the root's `docsScanned` is the levels' rows, and the
+    always-on counters count segments and rows."""
+    trees, counters, _, _ = star_served
+    tree = trees["tree"]
+    plans = _named(tree, "launch_plan")
+    assert [(n["attrs"]["star"], n["attrs"]["level"]) for n in plans] == [("st0", 2), ("st0", 2)]
+    assert all(n["attrs"]["cache"] in ("hit", "miss") for n in plans)
+    launches = [n for n in _named(tree, "launch") if n["name"].startswith("launch:")]
+    assert len(launches) == 2 and all(n["attrs"]["cpuMs"] >= 0 and n["attrs"]["kernelBytes"] > 0 for n in launches)
+    (root,) = [n for n in _named(tree, "server") if "server" in n.get("attrs", {})]
+    rows = sum(n["attrs"]["levelRows"] for n in launches)
+    assert root["attrs"]["docsScanned"] == rows < 2 * 3000
+    assert _named(tree, "device_wait")[0]["attrs"]["launches"] == 1  # the two levels rode one call
+    assert counters["server.starTreeSegments"] == 2 and counters["server.starTreeLevelRows"] == rows
+    assert all("star" not in n["attrs"] for n in _named(trees["scan"], "launch_plan"))
+
+
+def test_the_star_tree_configuration_shares_sf10s_table():
+    """`ssb_flat_sf10_startree` is `ssb_flat_sf10`'s table, row for row, plus
+    the two trees: the same generator, schema, query set and rows, so cell 1
+    beside its cell IS "the same queries without the tree"."""
+    def load(name):
+        with open(os.path.join(ROOT, "benchmarks", "configs", name + ".json"), encoding="utf-8") as f:
+            return json.load(f)
+
+    star, sf10 = load("ssb_flat_sf10_startree"), load("ssb_flat_sf10")
+    for key in ("columns", "datagen", "query_set", "rows", "segment_rows", "packed_codes", "table", "hierarchy",
+                "servers", "chips", "replication", "scale_factor"):
+        assert star[key] == sf10[key], key
+    trees = star["table_config"].pop("starTreeIndexConfigs")
+    assert sf10["table_config"].pop("starTreeIndexConfigs") == [] and star["table_config"] == sf10["table_config"]
+    assert [t["dimensionsSplitOrder"] for t in trees] == [
+        ["s_region", "d_year", "p_category", "p_brand1"], ["c_region", "s_region", "d_year", "c_nation", "s_nation"]]
+    assert all(t["functionColumnPairs"] == ["SUM__lo_revenue", "COUNT__*"] and t["maxLeafRecords"] == 10000 for t in trees)
+    assert set(star["reduced"]) == set(sf10["reduced"]) | {"templates"} == set(star["reduced_why"])
+    assert set(sf10["guarantees"]) < set(star["guarantees"])
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        bench = json.load(f)
+    (entry,) = [c for c in bench["configs"] if c["name"] == "ssb_flat_sf10_startree"]
+    assert entry["source"] == star["source"] and entry["reduced"] == star["reduced"]
+    with open(os.path.join(ROOT, "benchmarks", "traffic", "rollup_closed.json"), encoding="utf-8") as f:
+        mix = json.load(f)
+    assert (mix["loop"], mix["clients"], mix["templates"], mix["sample_checked"], mix["rolling_start_s"]) == (
+        "closed", 4, ["q2_1", "q2_2", "q2_3", "q3_1", "q4_1"], 40, 3.0)
+
+
+def test_every_metric_of_the_star_tree_cell_has_a_reader_that_returns_a_value():
+    """The benchmark's own readers over a traced window of the cell's own
+    traffic, through its own set-up, warm-up and load generator, at toy size
+    on the CPU: every per-layer metric `load_cell` gives the cell, the
+    list-less ones of older PRs too, returns a value.  Only the device's trace
+    is made by hand (this process has no chip): busy seconds and the share of
+    each template in the traced span."""
+    import sys
+
+    bench_dir = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench_dir)
+    try:
+        from lib import cluster as cluster_mod
+        from lib import harness, loadgen
+
+        cell = harness.load_cell("ssb_sf10_startree.rollup_closed")
+        config = dict(cell["config"], rows=48_000, segment_rows=12_000)
+        planner.plan_cache_clear()
+        cl = cluster_mod.Cluster(config, 37, jax.devices()[:1], build_threads=2)
+        try:
+            moved = {}
+            harness.warm_up(cl.url, cell, True, counters=cl.counters, moved=moved)
+            before = cl.counters()
+            window = loadgen.run(cl.url, cell["mix"], cell["query_set"], 37, 1.5, traced=True)
+            reqs = window["requests"]
+            assert {r.template for r in reqs} == set(cell["mix"]["templates"]) and all(r.spans for r in reqs)
+            weights = {t: float(sum(r.template == t for r in reqs)) for t in cell["mix"]["templates"]}
+            ctx = {
+                "window_requests": reqs, "faults": {}, "window_s": window["window_s"], "failed_latency_s": 120.0,
+                "timers": dict(cl.timers, setup_s=1.0), "requests": reqs, "counters_before": before,
+                "counters_after": cl.counters(), "warm_moved": moved, "config": config, "query_set": cell["query_set"],
+                "peak": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9, "name": "TPU v5e"},
+                "device_trace": {"busy_s": 1.0, "window_s": 1.5, "events": {}, "chips": 1,
+                                 "queries_in_trace": float(len(reqs)), "template_weights": weights},
+            }
+            values = {m["name"]: harness.metric_value("layer_metrics", m["name"], ctx) for m in cell["per_layer"]}
+        finally:
+            cl.close()
+            planner.plan_cache_clear()
+    finally:
+        sys.path.remove(bench_dir)
+    assert set(STAR_SPECS) | {"launch_cpu_ms", "launches_per_query", "compiles_in_window", "plan_rebuilds_in_window"} <= set(values)
+    assert not [name for name, v in values.items() if v is None], values
+    assert values["startree_segments_per_query"] == pytest.approx(4 * sum(weights[t] for t in weights if t != "q4_1") / len(reqs))
+    assert 0.0 < values["startree_roofline"] < 100.0 and values["startree_resident_bytes"] > 0
+    assert values["compiles_in_window"] == values["plan_rebuilds_in_window"] == 0.0

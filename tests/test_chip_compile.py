@@ -319,3 +319,63 @@ def test_group_program_compiles_its_kernel_once_for_v5e(one_chip, no_compile_cac
     assert text.count("tpu_custom_call") == 1 and text.count(" while(") == 1
     assert f"groupby_dense_pallas_x{width}" in text
     assert not re.search(rf"\[{width},\d{{5,}}\]", text)  # the outputs are [width, 7000 slots]
+
+
+@pytest.mark.parametrize("query", ["q2_1", "q3_1"])
+def test_star_tree_level_group_program_compiles_for_v5e(one_chip, no_compile_cache, monkeypatch, query):
+    """The star-tree cell's tree-served program (PR 37): the plan of SSB Q2.1
+    over level 4 of the brand tree (a 65,536-row bucket, 7,000 slots) and of
+    Q3.1 over level 5 of the nation tree (8,192 rows, 4,375 slots), as the
+    width-8 group program 40 segments launch: int64 field columns narrowed by
+    their stated range, the bound row count, ONE Mosaic kernel in one loop."""
+    from pinot_tpu.query import planner
+    from pinot_tpu.segment.builder import build_segment
+    from pinot_tpu.spi.config import IndexingConfig, TableConfig
+    from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
+    from pinot_tpu.sql.parser import parse_query
+
+    monkeypatch.setattr(ops, "scan_backend", lambda: "pallas")
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    planner.plan_cache_clear()
+
+    rows = 400_000  # enough uniform rows to fill both levels' combinations
+    rng = np.random.default_rng(37)
+    region = np.arange(25) % 5
+    dims = ["s_region", "d_year", "p_category", "p_brand1", "c_region", "c_nation", "s_nation"]
+    schema = Schema("lineorder_flat", [FieldSpec(d, DataType.INT) for d in dims]
+                    + [FieldSpec("lo_revenue", DataType.INT, role=FieldRole.METRIC)])
+    brand, c_nation, s_nation = rng.integers(0, 1000, rows), rng.integers(0, 25, rows), rng.integers(0, 25, rows)
+    block = {
+        "s_region": region[s_nation], "d_year": rng.integers(1992, 1999, rows), "p_category": brand // 40,
+        "p_brand1": brand, "c_region": region[c_nation], "c_nation": c_nation, "s_nation": s_nation,
+        "lo_revenue": rng.integers(90_000, 10_000_000, rows),
+    }
+    tcfg = TableConfig("lineorder_flat", indexing=IndexingConfig(star_tree_index_configs=[
+        {"dimensionsSplitOrder": ["s_region", "d_year", "p_category", "p_brand1"], "functionColumnPairs": ["SUM__lo_revenue", "COUNT__*"]},
+        {"dimensionsSplitOrder": ["c_region", "s_region", "d_year", "c_nation", "s_nation"], "functionColumnPairs": ["SUM__lo_revenue", "COUNT__*"]},
+    ]))
+    seg = build_segment(schema, {k: v.astype(np.int32) for k, v in block.items()}, "seg0", table_config=tcfg)
+    sql, bucket, slots = {
+        "q2_1": ("SELECT SUM(lo_revenue), d_year, p_brand1 FROM lineorder_flat WHERE p_category = 1 AND s_region = 1 "
+                 "GROUP BY d_year, p_brand1 LIMIT 10000", 65_536, 7_000),
+        "q3_1": ("SELECT c_nation, s_nation, d_year, SUM(lo_revenue) FROM lineorder_flat WHERE c_region = 2 AND s_region = 2 "
+                 "AND d_year >= 1992 AND d_year <= 1997 GROUP BY c_nation, s_nation, d_year LIMIT 100000", 8_192, 4_375),
+    }[query]
+    try:
+        table, asked = planner.QueryPlanning(parse_query(sql)).source(seg)
+        assert table.num_docs == bucket and table.level_rows < bucket and asked.rewrite is not None
+        plan = asked.plan(table)
+        assert plan.kind == "groupby_dense" and plan.cache_key[2] == "pallas" and plan.num_groups == slots
+        cols = table.to_device(columns=plan.needed_columns, packed_codes=True)  # on the CPU: shapes only
+
+        def described(x, lead=()):
+            return jax.ShapeDtypeStruct(lead + x.shape, x.dtype, sharding=one_chip)
+
+        members = tuple(jax.tree_util.tree_map(described, cols) for _ in range(8))
+        stacked = {k: described(v, (8,)) for k, v in plan.params.items()}
+        text = planner.grouped_plan(plan, 8).fn.lower(members, stacked).compile().as_text()
+    finally:
+        planner.plan_cache_clear()
+    assert text.count("tpu_custom_call") == 1 and text.count(" while(") == 1
+    assert "groupby_dense_pallas_x8" in text

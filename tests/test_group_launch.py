@@ -192,7 +192,7 @@ def test_grouped_launch_equals_the_per_segment_launch_bit_for_bit(name, chip_pat
 # ---------------------------------------------------------------------------
 def test_mixed_scan_list_forms_the_right_groups_and_the_same_rows(segments):
     """Seven named segments: four of one plan around one of another row
-    count, one the star-tree answers and one an upsert segment (a plan of
+    count, one whose star-tree's level answers and one an upsert segment (a plan of
     its own: it reads `__valid__`); a second query's literal prunes three."""
     star_cfg = TableConfig(name="t", indexing=IndexingConfig(star_tree_index_configs=[{
         "dimensionsSplitOrder": ["city", "year"], "functionColumnPairs": ["COUNT__*", "SUM__rev"],
@@ -215,12 +215,19 @@ def test_mixed_scan_list_forms_the_right_groups_and_the_same_rows(segments):
     results, stats = server.execute(ctx, names)
     assert (stats.num_segments_queried, stats.num_segments_pruned, stats.num_segments_processed) == (7, 0, 7)
     spans = _spans(stats.trace)
-    plans = [n["attrs"]["cache"] for n in spans["launch_plan"]]
-    assert plans.count("startree") == 1 and len(plans) == 7
-    # seg0, seg1, no_ber, seg4 share a kernel; other_rows and upsert are alone; the star-tree launches nothing
-    assert sorted(n["attrs"]["segments"] for n in spans["launch_enqueue"]) == [1, 1, 4]
-    assert spans["device_wait"][0]["attrs"]["launches"] == spans["dispatch"][0]["attrs"]["launches"] == 3
-    assert sum(n["attrs"]["segments"] for n in spans["collect"]) == 6 and sum(n["attrs"]["docs"] for n in spans["collect"]) == 6 * N + 500
+    plans = spans["launch_plan"]
+    assert len(plans) == 7 and all(n["attrs"]["cache"] in ("hit", "miss") for n in plans)
+    # the star-tree's level is planned like a segment (PR 37: it was `cache` = "startree", answered on the host)
+    level = star.indexes["startree"]["st0"].levels[2]
+    assert [(n["attrs"]["star"], n["attrs"]["level"]) for n in plans if "star" in n["attrs"]] == [("st0", 2)]
+    assert [n["attrs"].get("levelRows") for n in spans["launch:star"]] == [level.num_rows]
+    assert all("cpuMs" in n["attrs"] for name, nodes in spans.items() if name.startswith("launch:") for n in nodes)
+    assert spans["dispatch"][0]["attrs"]["starSegments"] == 1
+    # seg0, seg1, no_ber, seg4 share a kernel; other_rows, upsert and the star-tree's level are alone
+    assert sorted(n["attrs"]["segments"] for n in spans["launch_enqueue"]) == [1, 1, 1, 4]
+    assert spans["device_wait"][0]["attrs"]["launches"] == spans["dispatch"][0]["attrs"]["launches"] == 4
+    assert sum(n["attrs"]["segments"] for n in spans["collect"]) == 7
+    assert sum(n["attrs"]["docs"] for n in spans["collect"]) == 6 * N + 500 + level.num_rows
     # the same rows, in the order the segments were named
     untraced = parse_query(sql)
     assert all(_same(got, executor.execute_segment(untraced, seg)[0]) for got, seg in zip(results, scan))

@@ -285,16 +285,22 @@ class ServerInstance:
                     if self.residency is not None and k + 1 < len(scan):
                         # double-buffer: stage segment k+1's columns on the
                         # residency staging thread while k dispatches/runs
-                        # (a server with tiered residency launches at width 1)
+                        # (a server with tiered residency launches at width 1).
+                        # Columns the device already holds have nothing to
+                        # stage: no task, no wake-up of the staging thread
+                        # (a table that fits its cache: every segment of
+                        # every query after the first)
                         nxt, asked = planning.source(scan[k + 1])
-                        self.residency.submit(
-                            nxt.to_device,
-                            device=self.device,
-                            columns=asked.needed_columns(nxt),
-                            packed_codes=True,
-                            residency=self.residency,
-                            prefetch=True,
-                        )
+                        ahead = asked.needed_columns(nxt)
+                        if not nxt.resident(self.device, ahead, packed_codes=True):
+                            self.residency.submit(
+                                nxt.to_device,
+                                device=self.device,
+                                columns=ahead,
+                                packed_codes=True,
+                                residency=self.residency,
+                                prefetch=True,
+                            )
                     # pipelined: a full group dispatches async while the
                     # host plans the next, then drain (executor.QueryLaunches)
                     launches.add(seg)

@@ -708,31 +708,62 @@ def masked_count(mask):
     return jnp.sum(mask, dtype=jnp.int32).astype(jnp.int64)
 
 
+# Rows one whole-column int32 reduction of an 8-bit limb may cover:
+# 255 * 2^23 < 2^31.  A segment is one piece; a longer column is cut at
+# static bounds and the pieces' sums meet in int64.
+_SCALAR_PIECE = 1 << 23
+
+
+def _scalar_limb_sums(limbs):
+    """One exact i64 scalar a limb column (int32[n], |x| <= 255).  Each limb
+    is reduced WHOLE, in int32, where it is produced: no limb is stacked,
+    padded or reshaped, so the limbs of a value column are the outputs of
+    one reduction fusion over the column's operands, and only L scalars a
+    piece reach the recombine."""
+    n = limbs[0].shape[0]
+    sums = []
+    for limb in limbs:
+        total = jnp.int64(0)
+        for start in range(0, max(n, 1), _SCALAR_PIECE):
+            total = total + jnp.sum(limb[start : start + _SCALAR_PIECE], dtype=jnp.int32).astype(jnp.int64)
+        sums.append(total)
+    return sums
+
+
 def masked_sum(values, mask):
     """f64 scalar masked sum.
 
-    chunked32: integer inputs (int32 and narrower) ride the exact limb path
-    as a 1-group group_sum — bit-exact like the grouped path, matching
-    Pinot's double accumulator below 2^53.  Floats use XLA's f32 tree
-    reduction with an f64 chunk combine (~2^-24 relative error per chunk)."""
+    chunked32: integer inputs ride exact 8-bit limbs, each summed in int32
+    (_scalar_limb_sums): Pinot's double accumulator below 2^53.  int32 and
+    narrower: the two's complement's low bytes unsigned and its top byte
+    SIGNED (an arithmetic shift), so no sign-correction column; |sum| <
+    rows * 2^31 cannot reach 2^63, so the limb sums meet in int64 and are
+    converted once.  int64: signed-magnitude limbs, whose sum CAN pass 2^63
+    (four rows of 2^62), so their sums meet in f64, scales ascending
+    (_int64_signed_limbs' reasoning: exact below 2^53, a double's rounding
+    past it, never a wrap).  Floats use XLA's f32 tree reduction with an
+    f64 chunk combine (~2^-24 relative error per chunk)."""
     if accum_policy() == "wide":
         return jnp.sum(jnp.where(mask, values.astype(jnp.float64), 0.0))
     if jnp.issubdtype(values.dtype, jnp.integer):
-        # direct chunked limb reduction (no one-hot needed without groups):
-        # per-chunk per-limb f32 sums, |sum| <= 255 * _CHUNK < 2^24, exact.
-        if values.dtype.itemsize <= 4:
-            vm = jnp.where(mask, values, np.int32(0)).astype(jnp.int32)
-            u = vm.astype(jnp.uint32)
-            limbs = [((u >> np.uint32(8 * i)) & np.uint32(0xFF)).astype(jnp.float32) for i in range(4)]
-            limbs.append((vm < 0).astype(jnp.float32))  # two's-complement correction
-            scales = [float(1 << (8 * i)) for i in range(4)] + [-float(1 << 32)]
-        else:
-            # int64: signed-magnitude limbs (exact while sum(|v|) < 2^53)
-            limbs, scales = _int64_signed_limbs(values, mask, 8, jnp.float32)
-        stacked = jnp.stack(limbs, axis=1)
-        (stacked,) = _pad_to_chunks(stacked)
-        chunk_sums = stacked.reshape(-1, _CHUNK, len(limbs)).sum(axis=1)
-        return (chunk_sums.astype(jnp.float64) * jnp.asarray(scales, jnp.float64)).sum()
+        from pinot_tpu.utils.metrics import METRICS
+
+        METRICS.counter("scan.traced.scalar_limbs").inc()  # trace time: one a value column summed in limbs
+        with jax.named_scope("scalar_sum"):
+            if values.dtype.itemsize <= 4:
+                vm = jnp.where(mask, values, np.int32(0)).astype(jnp.int32)
+                top = 8 * (values.dtype.itemsize - 1)
+                limbs = [(vm >> np.int32(s)) & np.int32(0xFF) for s in range(0, top, 8)]
+                limbs.append(vm >> np.int32(top))
+                total = jnp.int64(0)
+                for k, s in enumerate(_scalar_limb_sums(limbs)):
+                    total = total + (s << np.int64(8 * k))
+                return total.astype(jnp.float64)
+            cols, scales = _int64_signed_limbs(values, mask, 8, jnp.int32)
+            total = jnp.float64(0.0)
+            for s, scale in zip(_scalar_limb_sums(cols), scales):
+                total = total + s.astype(jnp.float64) * np.float64(scale)
+            return total
     v = jnp.where(mask, values.astype(jnp.float32), np.float32(0.0))
     # two-stage: f32 chunk sums (vectorized reduce), f64 combine of the
     # small vector — bounds error without the scatter.

@@ -244,6 +244,11 @@ class ImmutableSegment:
                 nbytes += self._entry_bytes(c, use_packed)
         return need, nbytes
 
+    def resident(self, device, columns: List[str], packed_codes: bool = False) -> bool:
+        """Whether every entry to_device would hand out for `columns` is in
+        the device cache now: there is nothing to stage ahead of need."""
+        return not self._plan_missing(device, columns, packed_codes)[0]
+
     def _stage_entry(self, c: ColumnData, use_packed: bool, device) -> Dict[str, Any]:
         """One column's host->device copy (NO locks held — this runs on the
         staging stream or a staging owner, never under _device_lock)."""

@@ -321,6 +321,71 @@ def test_group_program_compiles_its_kernel_once_for_v5e(one_chip, no_compile_cac
     assert not re.search(rf"\[{width},\d{{5,}}\]", text)  # the outputs are [width, 7000 slots]
 
 
+def test_q1_group_program_reduces_its_limbs_in_one_fusion_for_v5e(one_chip, no_compile_cache, monkeypatch):
+    """SSB Q1.1's width-8 group program over served segments of 1.5M rows, under
+    the chip's arithmetic: the exact scalar SUM (ops.masked_sum, PR 38) is ONE
+    multi-output reduction fusion, an int32 scalar a limb, in the body of the
+    one while loop; no f32[rows, 5] limb stack, no [rows, 1] column, no pad or
+    reshape of a row-length operand on the way to it."""
+    from pinot_tpu.query import planner
+    from pinot_tpu.segment.builder import build_segment
+    from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
+    from pinot_tpu.sql.parser import parse_query
+
+    monkeypatch.setattr(ops, "scan_backend", lambda: "pallas")
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    planner.plan_cache_clear()
+
+    rows = 1_500_000
+    rng = np.random.default_rng(38)
+    schema = Schema("lineorder_flat", [
+        FieldSpec("d_year", DataType.INT), FieldSpec("lo_discount", DataType.INT), FieldSpec("lo_quantity", DataType.INT),
+        FieldSpec("lo_extendedprice", DataType.INT, role=FieldRole.METRIC),
+    ])
+    seg = build_segment(schema, {
+        "d_year": rng.integers(1992, 1999, rows).astype(np.int32),
+        "lo_discount": rng.integers(0, 11, rows).astype(np.int32),
+        "lo_quantity": rng.integers(1, 51, rows).astype(np.int32),
+        "lo_extendedprice": rng.integers(90_000, 10_000_000, rows).astype(np.int32),
+    }, "seg0")
+    ctx = parse_query("SELECT SUM(lo_extendedprice * lo_discount) FROM lineorder_flat "
+                      "WHERE d_year = 1993 AND lo_discount BETWEEN 1 AND 3 AND lo_quantity < 25")
+    try:
+        plan = planner.plan_segment(ctx, seg)
+        assert plan.kind == "aggregation"
+        cols = seg.to_device(columns=plan.needed_columns, packed_codes=True)  # on the CPU: shapes only
+        assert any("codes_packed" in entry for entry in cols.values())
+
+        def described(x, lead=()):
+            return jax.ShapeDtypeStruct(lead + x.shape, x.dtype, sharding=one_chip)
+
+        members = tuple(jax.tree_util.tree_map(described, cols) for _ in range(8))
+        stacked = {k: described(v, (8,)) for k, v in plan.params.items()}
+        text = planner.grouped_plan(plan, 8).fn.lower(members, stacked).compile().as_text()
+    finally:
+        planner.plan_cache_clear()
+    assert "aggregation_pallas_x8" in text and text.count(" while(") == 1
+    assert not re.search(rf"\[(?:{rows}|\d{{6,}}),[1-9]\d?\]", text)  # no [rows, L] with a small L, padded or not
+    assert not re.search(r"f32\[\d+,65536,\d\]", text)
+    sums = [line.split(" fusion(")[0] for line in text.splitlines() if " fusion(" in line and "scalar_sum/reduce_sum" in line]
+    assert len(sums) == 1 and sums[0].count("s32[]") == 4, sums  # the four limbs of the product's int32, reduced together
+
+
+def test_long_scalar_sum_compiles_for_v5e_with_its_limbs_met_in_f64(one_chip, no_compile_cache, monkeypatch):
+    """SUM over a LONG column of a served segment's length under `chunked32`:
+    eight signed-magnitude limbs reduced together as int32 scalars, met in f64
+    (a sum past 2^63 rounds, it does not wrap: PR 38's review), and no
+    row-length array of eight columns."""
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    rows = 1_500_000
+    args = (jax.ShapeDtypeStruct((rows,), jnp.int64, sharding=one_chip), jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
+    text = jax.jit(ops.masked_sum).lower(*args).compile().as_text()
+    assert not re.search(rf"\[{rows},[3-9]\]", text)  # [rows, 2] is the column's own uint32 halves
+    sums = [line.split(" fusion(")[0] for line in text.splitlines() if " fusion(" in line and "scalar_sum/reduce_sum" in line]
+    assert sum(head.count("s32[]") for head in sums) == 8, sums
+
+
 @pytest.mark.parametrize("query", ["q2_1", "q3_1"])
 def test_star_tree_level_group_program_compiles_for_v5e(one_chip, no_compile_cache, monkeypatch, query):
     """The star-tree cell's tree-served program (PR 37): the plan of SSB Q2.1

@@ -331,6 +331,37 @@ def test_a_server_paging_a_small_cache_launches_at_the_width_its_window_holds():
     assert [_answer(r) for r in results] == [_reference(b, b["qty"] < 30, None) for b in blocks]
 
 
+def test_nothing_is_handed_to_the_staging_thread_for_columns_the_device_holds():
+    """A cache that holds the table: the first query stages segment k+1
+    behind k, as above; from then on every column it reads is resident and
+    the server hands the staging thread NOTHING (PR 38: forty tasks and
+    forty wake-ups a query were the largest waste on a Q1 query's host path
+    once its device time fell).  A query that reads another column stages
+    again."""
+    blocks = [_block(i) for i in range(5)]
+    residency = ResidencyManager(ResourceBudget(1 << 30), name="residency.roomy")
+    server = ServerInstance("s", residency=residency)
+    for i, b in enumerate(blocks):
+        server.add_segment("t", build_segment(SCHEMA, b, f"roomy{i}"))
+    names = [f"roomy{i}" for i in range(5)]
+    handed, real = [], residency.submit
+    residency.submit = lambda fn, *a, **kw: handed.append(tuple(kw["columns"])) or real(fn, *a, **kw)
+    ctx = parse_query("SELECT COUNT(*), SUM(rev) FROM t WHERE qty < 30")
+    try:
+        first, _ = server.execute(ctx, names)
+        assert len(handed) == 4  # behind each segment but the last
+        del handed[:]
+        again, _ = server.execute(ctx, names)
+        assert handed == []
+        other, _ = server.execute(parse_query("SELECT COUNT(*), SUM(rev) FROM t WHERE year = 1994"), names)
+        assert len(handed) == 4 and all("year" in cols for cols in handed)
+    finally:
+        residency.shutdown()
+    want = [_reference(b, b["qty"] < 30, None) for b in blocks]
+    assert [_answer(r) for r in first] == want and [_answer(r) for r in again] == want
+    assert [_answer(r) for r in other] == [_reference(b, b["year"] == 1994, None) for b in blocks]
+
+
 # ---------------------------------------------------------------------------
 # (4) first launch: per (group program, device); `warm` compiles what the served call runs
 # ---------------------------------------------------------------------------

@@ -502,3 +502,56 @@ class TestLoweredPlans:
         traced = unpacks.value
         jax.block_until_ready(plan.fn(cols, params))  # warm: the same program, nothing retraced
         assert unpacks.value == traced
+
+    @pytest.mark.parametrize("name", ["q1_1", "q1_2", "q1_3"])
+    def test_scalar_sum_holds_no_narrow_row_length_array_under_chunked32(self, ssb, name, monkeypatch):
+        """The chip's arithmetic (`chunked32`; the case above lowers the CPU's
+        `wide`): Q1's exact sum reduces its limbs whole, so no tensor of ANY
+        element type pairs a row-length dimension with a minor one below a
+        lane row (PR 38 removed an f32[rows, 5] limb stack, its pad and its
+        [chunks, 65536, 5] view), and `scan.traced.scalar_limbs` says so at
+        trace time, once a summed column."""
+        import re
+
+        import jax
+
+        from pinot_tpu import ops
+        from pinot_tpu.ops import segmented
+        from pinot_tpu.query import planner
+        from pinot_tpu.sql.parser import parse_query
+        from pinot_tpu.utils.metrics import METRICS
+
+        monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+        monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+        planner.plan_cache_clear()  # the cache does not key on the policy
+        seg, sql = ssb
+        try:
+            plan = planner.plan_segment(parse_query(sql[name]), seg)
+            assert plan.kind == "aggregation"
+            cols = seg.to_device(columns=plan.needed_columns, packed_codes=True)
+            params = {k: jax.device_put(v) for k, v in plan.params.items()}
+            limbs = METRICS.counter("scan.traced.scalar_limbs")
+            before = limbs.value
+            text = plan.fn.lower(cols, params).as_text()
+            assert limbs.value - before == 1  # SUM(lo_extendedprice * lo_discount): one column
+            # but for a dictionary look-up's codes as [rows, 1]: StableHLO's form of a gather's
+            # index vector, a bitcast to the compiler (lo_discount's values by code)
+            index = set(re.findall(r'"stablehlo\.gather"\(%\w+, (%\w+)\)', text))
+            body = "\n".join(line for line in text.splitlines() if '"stablehlo.gather"' not in line
+                             and line.split(" = ")[0].strip() not in index)
+            shapes = re.findall(r"tensor<((?:\d+x)+)(\w+)>", body)
+            assert any(dims.endswith("x128x") for dims, _ in shapes)  # the lane unpack, in whole lanes
+            narrow = set()
+            for dims, ty in shapes:
+                sizes = [int(d) for d in dims.split("x") if d]
+                if len(sizes) >= 2 and sizes[-1] < 128 and max(sizes[:-1]) >= seg.num_docs:
+                    narrow.add(f"{dims}{ty}")
+            assert not narrow, narrow
+
+            got = jax.block_until_ready(plan.fn(cols, params))
+            traced = limbs.value
+            again = jax.block_until_ready(plan.fn(cols, params))  # warm: the same program, nothing retraced
+            assert limbs.value == traced
+            assert jax.tree_util.tree_all(jax.tree_util.tree_map(lambda a, b: bool((a == b).all()), got, again))
+        finally:
+            planner.plan_cache_clear()

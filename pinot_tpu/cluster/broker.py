@@ -785,6 +785,8 @@ class Broker:
             )
             raise
         annotate_root(out.stats.trace, parseMs=round(parse.ms, 3))
+        if out.stats.stage_ns is not None:  # the parse ran before the query's Trace existed
+            out.stats.stage_ns.append(("broker", {"sql_parse": parse.ms * 1e6}))
         self.slow_queries.record(sql, fp, out, shape_fingerprint=sfp)
         return out
 
@@ -1037,7 +1039,7 @@ class Broker:
         """Reduce + response stamping + result-cache populate + latency and
         ShapeStats accounting — the tail every served query (sync or batch
         member) runs through."""
-        with trace.span("reduce"):
+        with trace.span("reduce", cpu=True):
             out = reduce_mod.reduce_results(ctx, results, stats)
         trace.flush(METRICS, {"reduce": "broker.reduceMs"})
         out.stats.time_ms = (time.perf_counter() - t0) * 1000
@@ -1053,6 +1055,8 @@ class Broker:
                 import copy
 
                 self.result_cache.put(ckey, copy.deepcopy(out))
+        # the servers' stage totals (stats, from the scatter) and this Trace's
+        out.stats.stage_ns = (stats.stage_ns or []) + [("broker", trace.totals_ns)]
         METRICS.histogram("broker.queryLatency").update(out.stats.time_ms)
         from pinot_tpu.query.shape import shape_digest
         from pinot_tpu.utils import perf
@@ -1975,6 +1979,8 @@ class Broker:
                             stats.total_docs += sstats.total_docs
                             stats.add_index_uses(sstats.filter_index_uses)
                             stats.add_kernel_cost(sstats)
+                            if sstats.stage_ns:
+                                stats.stage_ns = (stats.stage_ns or []) + sstats.stage_ns
                             trace.graft(sstats.trace)
                             if ssp is not None:
                                 ssp.annotate(

@@ -22,7 +22,7 @@ import numpy as np
 
 from pinot_tpu.query.cursors import ResponseStore
 from pinot_tpu.query.result import ResultTable
-from pinot_tpu.utils.metrics import METRICS, annotate_root, stage
+from pinot_tpu.utils.metrics import METRICS, annotate_root, mark, now_ns, stage
 
 
 def _jsonable(v):
@@ -61,6 +61,23 @@ def broker_response(result: ResultTable) -> Dict[str, Any]:
     }
 
 
+class _StampedServer(ThreadingHTTPServer):
+    """The accept loop, with its two stamps.  The loop is the front door's one
+    serial resource: every request waits for its turn of it, so what a turn
+    takes (accept() back to the handler's thread made and started) is a
+    timer of its own, updated on the loop's thread."""
+
+    def get_request(self):
+        request, address = super().get_request()
+        # the handler finds the stamp where it finds the socket's peer: the
+        # last item of its client_address
+        return request, (*address, now_ns())
+
+    def process_request(self, request, client_address):
+        super().process_request(request, client_address)
+        METRICS.timer("rest.acceptLoopMs").update((now_ns() - client_address[-1]) / 1e6)
+
+
 class QueryServer:
     """Serves one engine-like object (anything with .sql or .query)."""
 
@@ -73,18 +90,24 @@ class QueryServer:
             def log_message(self, fmt, *args):  # quiet
                 pass
 
+            def setup(self):
+                # the handler thread's first line: `http_head` runs from here
+                # until the request line and the headers are parsed, and what
+                # came before, on another thread, is the accept wait
+                self.head = stage("http_head").__enter__()
+                self.accept_ns = self.client_address[-1]
+                mark("http_accepted", accept_wait_us=(self.head.t0_ns - self.accept_ns) // 1000)
+                super().setup()
+
+            def parse_request(self):
+                ok = super().parse_request()
+                self.head.__exit__(None, None, None)
+                return ok
+
             def _send(self, code: int, payload: Dict[str, Any]) -> None:
                 self._send_body(code, json.dumps(payload).encode("utf-8"))
 
-            def _send_body(self, code: int, body: bytes) -> None:
-                self.send_response(code)
-                self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-            def _send_text(self, code: int, text: str, content_type: str) -> None:
-                body = text.encode("utf-8")
+            def _send_body(self, code: int, body: bytes, content_type: str = "application/json") -> None:
                 self.send_response(code)
                 self.send_header("Content-Type", content_type)
                 self.send_header("Content-Length", str(len(body)))
@@ -103,9 +126,9 @@ class QueryServer:
                         # fall back to this process's registry
                         fed = getattr(outer.engine, "federated_prometheus", None)
                         if qs.get("format", [""])[0] == "prometheus":
-                            self._send_text(
+                            self._send_body(
                                 200,
-                                fed() if fed is not None else METRICS.to_prometheus(),
+                                (fed() if fed is not None else METRICS.to_prometheus()).encode("utf-8"),
                                 "text/plain; version=0.0.4; charset=utf-8",
                             )
                         else:
@@ -168,12 +191,22 @@ class QueryServer:
                     self._send(500, {"error": f"{type(e).__name__}: {e}"})
 
             def do_POST(self):
-                """The front door's four stages, each a profiler annotation
-                and (for a query that is answered) one timer update: read +
-                decode the body, the engine call, build + serialise the
-                answer, write it.  A traced answer carries the read time on
-                its root span (httpReadMs); serialise and write cannot ride
-                the payload they produce."""
+                """The front door's stages, each a profiler annotation and
+                (for a query that is answered) one timer update: `http_head`
+                (setup to the parsed headers), then read + decode the body,
+                the engine call, build + serialise the answer, write it.
+                Before them the accept wait (accept() on the loop's thread to
+                setup on this one), and over all of them `doorMs`, accept()
+                to the last byte written; what a request waited before
+                accept() returned it (the kernel's listen queue, while the
+                loop's thread waited for the interpreter) no clock of the
+                program sees.  A traced answer carries what came
+                before its root span on it (acceptT0Ns on t0Ns' clock,
+                acceptWaitMs, headMs, httpReadMs); serialise and write cannot
+                ride the payload they produce, and reach, like the rest, the
+                slow-query log's entry of a request that was slow here."""
+                head, accept_ns = self.head, self.accept_ns
+                wait_ms = (head.t0_ns - accept_ns) / 1e6
                 try:
                     with stage("http_read") as read:
                         n = int(self.headers.get("Content-Length", 0))
@@ -183,9 +216,13 @@ class QueryServer:
                         return
                     sql = req.get("sql", "")
                     run = getattr(outer.engine, "sql", None) or outer.engine.query
-                    with stage("http_engine"):
+                    with stage("http_engine") as eng:
                         result = run(sql)
-                    annotate_root(result.stats.trace, httpReadMs=round(read.ms, 3))
+                    if result.stats.trace is not None:
+                        annotate_root(
+                            result.stats.trace, acceptT0Ns=accept_ns, acceptWaitMs=round(wait_ms, 3),
+                            headMs=round(head.ms, 3), httpReadMs=round(read.ms, 3),
+                        )
                     with stage("http_serialize") as ser:
                         payload = broker_response(result)
                         if req.get("useCursor"):
@@ -197,9 +234,15 @@ class QueryServer:
                         body = json.dumps(payload).encode("utf-8")
                     with stage("http_write") as write:
                         self._send_body(200, body)
-                    METRICS.timer("rest.readMs").update(read.ms)
-                    METRICS.timer("rest.serializeMs").update(ser.ms)
-                    METRICS.timer("rest.writeMs").update(write.ms)
+                    door = {
+                        "acceptWaitMs": wait_ms, "headMs": head.ms, "readMs": read.ms, "engineMs": eng.ms,
+                        "serializeMs": ser.ms, "writeMs": write.ms, "doorMs": (now_ns() - accept_ns) / 1e6,
+                    }
+                    for name, ms in door.items():  # rest.acceptWaitMs ... rest.doorMs
+                        METRICS.timer("rest." + name).update(ms)
+                    slow = getattr(outer.engine, "slow_queries", None)
+                    if slow is not None:
+                        slow.door(result.stats, door)
                 except Exception as e:  # noqa: BLE001 - boundary
                     from pinot_tpu.analysis.plan_check import PlanCheckError
                     from pinot_tpu.cluster.admission import (
@@ -292,7 +335,7 @@ class QueryServer:
                     else:
                         self._send(500, {"error": f"{type(e).__name__}: {e}"})
 
-        self._httpd = ThreadingHTTPServer((host, port), Handler)
+        self._httpd = _StampedServer((host, port), Handler)
         self.port = self._httpd.server_address[1]
         self._thread: Optional[threading.Thread] = None
 

@@ -183,8 +183,9 @@ class ServerInstance:
         broker should fail the segments over to another replica.
 
         Tracing (ctx option `trace`): builds a per-server span subtree —
-        dispatch (attr starSegments: the segments a star-tree level answered
-        for; per segment a launch:<segment> span over the executor's
+        dispatch (attrs starSegments: the segments a star-tree level answered
+        for, loopMs: its time outside its child spans; per segment a
+        launch:<segment> span over the executor's
         launch_plan / launch_ship, and per GROUP of segments that share a
         compiled kernel one launch_enqueue, ending in launch_release:
         executor.QueryLaunches; launch_ship is the resident columns' lookup
@@ -305,8 +306,13 @@ class ServerInstance:
                     # host plans the next, then drain (executor.QueryLaunches)
                     launches.add(seg)
                 launches.flush()
-                if dsp is not None:
-                    dsp.annotate(launches=launches.calls, starSegments=launches.star_segments)
+            if dsp is not None:
+                # loopMs: the span's time outside its children, which is this
+                # loop itself a segment (prune, residency check, budget check)
+                dsp.annotate(
+                    launches=launches.calls, starSegments=launches.star_segments,
+                    loopMs=round(dsp.duration_ms - sum(c.duration_ms for c in dsp.children), 3),
+                )
             if trace.enabled:
                 # device/host time split: ONE fence over every pending output
                 # (trace-only — the untraced path lets collect's device_get be
@@ -334,6 +340,7 @@ class ServerInstance:
                 self.metrics.counter("server.starTreeSegments").inc(launches.star_segments)
                 self.metrics.counter("server.starTreeLevelRows").inc(launches.star_level_rows)
             trace.flush(self.metrics, _STAGE_TIMERS)
+            stats.stage_ns = [(self.name, trace.totals_ns)]  # by reference: only a slow request's log entry reads it
             if stats.compile_ms > 0:
                 self.metrics.timer("server.compileMs").update(stats.compile_ms)
             if trace.enabled:
@@ -514,8 +521,6 @@ class ServerInstance:
                                             [i],
                                         )
                                     )
-                    if lsp is not None:
-                        lsp.annotate(cpuMs=round(lsp.cpu_ms, 3))
                 if dsp is not None:
                     dsp.annotate(launches=len(pending))
             if trace.enabled:
@@ -530,7 +535,7 @@ class ServerInstance:
                 alive = [i for i in members if errors[i] is None]
                 if not alive:
                     continue  # every rider died — abandon uncollected
-                with trace.span("collect", members=len(alive)) as csp:
+                with trace.span("collect", cpu=True, members=len(alive)) as csp:
                     if st[0] == "pending_batch":
                         collected = executor.collect_segment_batch(st)
                     else:

@@ -160,7 +160,7 @@ class QueryEngine:
                 stats.add_kernel_cost(seg_stats)
                 results.append(res)
             deadline.check(f"query on {ctx.table}")
-            with trace.span("reduce"):
+            with trace.span("reduce", cpu=True):
                 out = reduce_mod.reduce_results(ctx, results, stats)
         except Exception:
             METRICS.counter("queryExceptions").inc()

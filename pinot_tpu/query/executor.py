@@ -317,10 +317,9 @@ class QueryLaunches:
             )
         self.kernel_bytes += member.plan.scan_bytes
         if lsp is not None:
-            # cpuMs as an attr too: beside the span's wall time, the rest is
-            # waiting (interpreter lock, a lock); kernelBytes is EXPLAIN
-            # ANALYZE's Bytes
-            lsp.annotate(cpuMs=round(lsp.cpu_ms, 3), kernelBytes=member.plan.scan_bytes)
+            # beside the span's cpuMs (the rest of its wall time is waiting:
+            # interpreter lock, a lock); kernelBytes is EXPLAIN ANALYZE's Bytes
+            lsp.annotate(kernelBytes=member.plan.scan_bytes)
         if member.rewrite is not None:
             self.star_segments += 1
             self.star_level_rows += member.table.level_rows
@@ -362,7 +361,7 @@ class QueryLaunches:
         answers: List = [None] * self._added
         for state, slots in self._states:
             self.check()
-            with self.trace.span("collect", segments=len(slots)) as csp:
+            with self.trace.span("collect", cpu=True, segments=len(slots)) as csp:
                 for slot, answer in zip(slots, collect_group(state, self.check, self.trace)):
                     answers[slot] = answer
                 if state[3][0].kind == "groupby_sparse":
@@ -390,7 +389,7 @@ def _enqueue(trace, plan, args, device, on_first_launch=None, **attrs):
     if first and on_first_launch is not None:
         on_first_launch()
     compile_ms = 0.0
-    with trace.span("launch_enqueue", **attrs) as esp:
+    with trace.span("launch_enqueue", cpu=True, **attrs) as esp:
         t0 = time.perf_counter()
         with _placed_on(device):
             out = plan.fn(*args)
@@ -440,7 +439,7 @@ def collect_group(state, check=None, trace: Optional[Trace] = None):
     answers = []
     plan = plans[0]
     table = trace is not None and plan.kind.startswith("groupby")
-    with trace.span("table_decode", kind=plan.kind) if table else contextlib.nullcontext() as tsp:
+    with trace.span("table_decode", cpu=True, kind=plan.kind) if table else contextlib.nullcontext() as tsp:
         for i, (segment, member_plan, stats) in enumerate(zip(segments, plans, stats_list)):
             if i and check is not None:
                 check()

@@ -56,6 +56,12 @@ class ExecutionStats:
     hedge_winner: Optional[str] = None
     hedge_cancelled_ms: float = 0.0
     brownout_events: List[str] = field(default_factory=list)
+    # [(source, {stage name: summed ns})]: the per-stage totals of every Trace
+    # that served the query (each server's, the broker's), by reference; and
+    # the query's slow-log entry, which copies them if the request turns out
+    # slow at the front door (utils/slowlog.SlowQueryLog.door)
+    stage_ns: Optional[List[Tuple[str, Dict[str, int]]]] = None
+    slow_entry: Optional[dict] = None
 
     def merge(self, other: "ExecutionStats") -> None:
         self.num_segments_queried += other.num_segments_queried

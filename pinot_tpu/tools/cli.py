@@ -131,7 +131,9 @@ def cmd_quickstart(args) -> int:
 
 def cmd_slow_queries(args) -> int:
     """Print a serving broker/engine's recent-query ring (GET /debug/queries):
-    newest first, one line per query, trace presence flagged."""
+    newest first, one line per query, trace presence flagged; under a request
+    that was slow at the front door, where it waited: the door's own times
+    and what each stage summed to on the broker and on each server."""
     import urllib.request
 
     url = args.url.rstrip("/") + f"/debug/queries?limit={args.limit}"
@@ -154,6 +156,9 @@ def cmd_slow_queries(args) -> int:
             f"docs={e.get('numDocsScanned', 0):<10} qid={e.get('queryId')} "
             f"fp={e.get('planFingerprint')} {' '.join(flags)}  {e.get('sql', '')}"
         )
+        for source, ms in [("door", e.get("door"))] + sorted((e.get("stagesMs") or {}).items()):
+            if ms:
+                print(f"{'':>14}{source}: " + " ".join(f"{k}={v:g}" for k, v in ms.items()))
     print(f"-- {len(entries)} entr(y/ies)", file=sys.stderr)
     return 0
 

@@ -408,11 +408,14 @@ class Span:
     subtree grafts into the broker trace as a dict.
 
     A span keeps where it started (`time.perf_counter_ns`).  Opened with
-    `cpu=True` (every root, and the per-segment launch spans) it also keeps
-    the CPU time its thread used while it was open (`time.thread_time`):
-    wall less CPU is time the thread waited — for the interpreter lock, a
-    lock or the device.  Not every span: that clock is a system call, 6 us a
-    read on the TPU host against 0.1 us for the wall clock (PERF.md, PR 24)."""
+    `cpu=True` (every root, the per-segment launch spans and the group-level
+    stages launch_enqueue, collect, table_decode, reduce) it also keeps the
+    CPU time its thread used while it was open (`time.thread_time`), as
+    `cpuMs` beside `ms` and among its attrs, where the benchmark's readers
+    look: wall less CPU is time the thread waited — for the interpreter
+    lock, a lock or the device.  Not every span: that clock is a system
+    call, 6 us a read on the TPU host against 0.1 us for the wall clock
+    (PERF.md, PR 24), and no untraced query reads it (only a Span does)."""
 
     __slots__ = ("name", "start_ns", "duration_ms", "cpu_ms", "children", "attrs", "_cpu0")
 
@@ -434,6 +437,7 @@ class Span:
         self.duration_ms = (end_ns - self.start_ns) / 1e6
         if self._cpu0 is not None:
             self.cpu_ms = (time.thread_time() - self._cpu0) * 1000
+            self.attrs["cpuMs"] = round(self.cpu_ms, 3)
 
     def to_dict(self, root_ns: Optional[int] = None) -> Dict[str, Any]:
         """`startMs` counts from the tree's root.  The root (root_ns None)
@@ -468,9 +472,10 @@ class Stage:
     or not: the stage then sits in the trace's host plane on the device
     trace's own clock; with no session it is one check), the trace's
     per-query totals (always), and the span tree (when the query is
-    traced).  `ms` is readable after exit."""
+    traced).  `t0_ns` (perf_counter_ns at entry) is readable inside, `ms`
+    after exit."""
 
-    __slots__ = ("trace", "name", "attrs", "cpu", "sp", "ms", "_t0", "_ann")
+    __slots__ = ("trace", "name", "attrs", "cpu", "sp", "ms", "t0_ns", "_ann")
 
     def __init__(self, trace: Optional["Trace"], name: str, attrs: Optional[Dict[str, Any]] = None,
                  cpu: bool = False):
@@ -481,12 +486,12 @@ class Stage:
 
     def __enter__(self):
         tr = self.trace
-        self._t0 = time.perf_counter_ns()
+        self.t0_ns = time.perf_counter_ns()
         self._ann = self._annotate() if _profiling() else None
         if tr is None:
             return self
         if tr.enabled:
-            sp = self.sp = Span(self.name, self.attrs, start_ns=self._t0, cpu=self.cpu)
+            sp = self.sp = Span(self.name, self.attrs, start_ns=self.t0_ns, cpu=self.cpu)
             tr._stack[-1].children.append(sp)
             tr._stack.append(sp)
             return sp
@@ -507,10 +512,10 @@ class Stage:
         end = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        self.ms = (end - self._t0) / 1e6
+        self.ms = (end - self.t0_ns) / 1e6
         tr = self.trace
         if tr is not None:
-            tr.totals_ns[self.name] += end - self._t0
+            tr.totals_ns[self.name] += end - self.t0_ns
             if self.sp is not None:
                 self.sp.close(end)
                 tr._stack.pop()
@@ -522,6 +527,19 @@ def stage(name: str, **meta: Any) -> Stage:
     read and SQL parse run before the query's Trace exists); yields itself,
     `ms` holds the time after exit."""
     return Stage(None, name, meta or None)
+
+
+now_ns = time.perf_counter_ns  # the tracer's wall clock, for a stamp that crosses threads (the front door's accept())
+
+
+def mark(name: str, **meta: Any) -> None:
+    """An instant in the profiler's host plane: a Stage that opens and closes
+    at once, carrying `meta` (what was timed across threads and so cannot be
+    an annotation of its own: the front door's accept wait).  With no
+    session recording it is one check."""
+    if _profiling():
+        with Stage(None, name, meta):
+            pass
 
 
 def annotate_root(tree: Optional[Dict[str, Any]], **kw: Any) -> None:

@@ -5,7 +5,12 @@ logs requestId/SQL/timing per request, rate-limited) + the druid-style
 /debug surface.  Re-design: an in-memory deque the REST layer serves at
 `GET /debug/queries` (newest first) and the CLI prints via `slow-queries`;
 queries over `slow_ms` additionally keep their full span tree, so the tail
-that matters arrives with its own flame graph attached.
+that matters arrives with its own flame graph attached.  A request that came
+through the HTTP front door is judged there a second time (`door`): by its
+time from `accept()` to its last byte, which holds the waits the engine's
+`timeMs` starts after; a slow one, traced or not, keeps the front door's
+times (`door`) and what each stage summed to on the broker and on every
+server that answered (`stagesMs`).
 
 Entries are plain dicts (JSON-ready); SQL text is stored verbatim but
 NEVER used as a metric/span name (repo_lint W007 guards that class), and
@@ -105,17 +110,48 @@ class SlowQueryLog:
             }
         if stats is not None and getattr(stats, "brownout_events", None):
             entry["brownout"] = list(stats.brownout_events)
-        if time_ms >= self.slow_ms or error is not None or "kill" in entry:
-            METRICS.counter("broker.slowQueries").inc()
-            if stats is not None and stats.trace is not None:
-                entry["trace"] = stats.trace
+        if self._kept_as_slow(entry):
+            self._slow(entry, stats)
+        if stats is not None:
+            stats.slow_entry = entry  # for the front door: door()
         with self._lock:
             self._entries.append(entry)
         return entry
 
+    def _kept_as_slow(self, entry: Dict[str, Any]) -> bool:
+        return entry["timeMs"] >= self.slow_ms or "error" in entry or "kill" in entry
+
+    @staticmethod
+    def _slow(entry: Dict[str, Any], stats) -> None:
+        METRICS.counter("broker.slowQueries").inc()
+        if stats is not None and stats.trace is not None:
+            entry["trace"] = stats.trace
+
+    def door(self, stats, door: Dict[str, float]) -> None:
+        """The front door's verdict on a request it has just answered:
+        `door` holds its times there in ms (`doorMs`: accept() to the last
+        byte written).  Slow by `doorMs`, the request's entry keeps them and
+        `stagesMs`: per source (`broker`, each server) what every stage
+        summed to over the query, `launch:<segment>` folded into `launch`.
+        A fast request's entry is left as record() made it."""
+        entry = stats.slow_entry
+        if entry is None or door["doorMs"] < self.slow_ms:
+            return
+        stages: Dict[str, Dict[str, float]] = {}  # source -> stage -> summed ns
+        for source, totals in stats.stage_ns or ():
+            into = stages.setdefault(source, {})
+            for name, ns in totals.items():
+                stem = name.split(":", 1)[0]
+                into[stem] = into.get(stem, 0.0) + ns
+        with self._lock:  # snapshot() may be reading the entry
+            if not self._kept_as_slow(entry):
+                self._slow(entry, stats)  # record() had let it pass
+            entry["door"] = {k: round(v, 3) for k, v in door.items()}
+            entry["stagesMs"] = {s: {k: round(ns / 1e6, 3) for k, ns in d.items()} for s, d in stages.items()}
+
     def snapshot(self, limit: Optional[int] = None) -> List[Dict[str, Any]]:
         with self._lock:
-            out = list(self._entries)
+            out = [dict(e) for e in self._entries]
         out.reverse()  # newest first
         return out[:limit] if limit else out
 

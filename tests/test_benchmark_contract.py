@@ -23,12 +23,17 @@ star-tree produces (`starSegments`, `levelRows`, the trace-time counter
 own again, on a table with a tree, served one query the tree answers and one
 it may not.
 
+The metrics of PR 39 (the front door's waits from accept() on, CPU beside
+wall on the group-level stages, the server's loop) are cases of the first
+kind: each names an attr or a timer, and the traced answers came through the
+door, so the attr is looked for and not the span alone.
+
 The files are read as data: nothing of `benchmarks/lib` is imported, and no
-number is checked, only presence.  The last test alone runs the benchmark's
-own readers: PR 36 was refused because one of them found nothing in a cell it
-is given (`launch_cpu_ms`), so every per-layer metric `load_cell` gives the
-star-tree cell must return a value over a traced window of that cell's own
-traffic at toy size.
+number is checked, only presence.  The last two tests alone run the
+benchmark's own readers: PR 36 was refused because one of them found nothing
+in a cell it is given (`launch_cpu_ms`), so every per-layer metric `load_cell`
+gives the star-tree cell, and a scan cell, must return a value over a traced
+window of that cell's own traffic at toy size.
 """
 import glob
 import json
@@ -62,6 +67,15 @@ STAR_LAYER = "star-tree"
 # (a reducer that finds no such counter reports nothing), at 0 after QUERIES,
 # whose every plan-cache hit binds its parameters by the entry's recipe
 SOUND_AT_ZERO = {"plan_rebuilds_in_window"}
+# read what only a group-by produces (BENCHMARK.json lists their cells): looked for in QUERIES' group-by alone
+GROUP_BY_ONLY = {"table_decode_cpu_ms"}
+# PR 39's, all among SPECS
+DOOR_SPECS = {
+    "frontdoor_accept_wait_ms", "frontdoor_accept_wait_p99_ms", "frontdoor_head_ms", "frontdoor_read_ms",
+    "frontdoor_write_ms", "frontdoor_accept_loop_ms", "frontdoor_door_ms", "frontdoor_before_accept_ms",
+    "frontdoor_engine_ms", "launch_enqueue_cpu_ms", "collect_cpu_ms", "reduce_cpu_ms", "table_decode_cpu_ms",
+    "dispatch_loop_ms",
+}
 
 
 def _program_metrics():
@@ -167,16 +181,17 @@ def test_program_still_says_what_the_metric_reads(name, served):
     trees, counters, timers = served
     named_keys = [k for k in SPAN_KEYS + REGISTRY_KEYS if k in spec]
     assert named_keys, f"{name}: names nothing this test knows how to look for: {sorted(spec)}"
+    answers = [(sql, tree) for sql, tree in zip(QUERIES, trees) if name not in GROUP_BY_ONLY or "GROUP BY" in sql]
 
     span_names = []
     for key in SPAN_KEYS:
         value = spec.get(key, [])
         span_names.extend([value] if isinstance(value, str) else value)
     for span in span_names:
-        for sql, tree in zip(QUERIES, trees):
+        for sql, tree in answers:
             assert _named(tree, span), f"{name}: no span {span!r} in the traced answer of: {sql}"
     if "attr" in spec:
-        for sql, tree in zip(QUERIES, trees):
+        for sql, tree in answers:
             values = [n.get("attrs", {}).get(spec["attr"]) for n in _named(tree, spec["span"])]
             assert values and all(isinstance(v, (int, float)) for v in values), (
                 f"{name}: attr {spec['attr']!r} of span {spec['span']!r} in the answer of: {sql}: {values}"
@@ -494,13 +509,13 @@ def test_the_star_tree_configuration_shares_sf10s_table():
         "closed", 4, ["q2_1", "q2_2", "q2_3", "q3_1", "q4_1"], 40, 3.0)
 
 
-def test_every_metric_of_the_star_tree_cell_has_a_reader_that_returns_a_value():
-    """The benchmark's own readers over a traced window of the cell's own
+def _toy_window(workload, rows, seed, seconds=1.5):
+    """The benchmark's own readers over a traced window of a cell's own
     traffic, through its own set-up, warm-up and load generator, at toy size
-    on the CPU: every per-layer metric `load_cell` gives the cell, the
-    list-less ones of older PRs too, returns a value.  Only the device's trace
-    is made by hand (this process has no chip): busy seconds and the share of
-    each template in the traced span."""
+    on the CPU: {metric: value} of every per-layer metric `load_cell` gives
+    the cell, the cell, its requests.  Only the device's trace is made by
+    hand (this process has no chip): busy seconds and the share of each
+    template in the traced span."""
     import sys
 
     bench_dir = os.path.join(ROOT, "benchmarks")
@@ -509,15 +524,15 @@ def test_every_metric_of_the_star_tree_cell_has_a_reader_that_returns_a_value():
         from lib import cluster as cluster_mod
         from lib import harness, loadgen
 
-        cell = harness.load_cell("ssb_sf10_startree.rollup_closed")
-        config = dict(cell["config"], rows=48_000, segment_rows=12_000)
+        cell = harness.load_cell(workload)
+        config = dict(cell["config"], rows=rows, segment_rows=rows // 4)
         planner.plan_cache_clear()
-        cl = cluster_mod.Cluster(config, 37, jax.devices()[:1], build_threads=2)
+        cl = cluster_mod.Cluster(config, seed, jax.devices()[:1], build_threads=2)
         try:
             moved = {}
             harness.warm_up(cl.url, cell, True, counters=cl.counters, moved=moved)
             before = cl.counters()
-            window = loadgen.run(cl.url, cell["mix"], cell["query_set"], 37, 1.5, traced=True)
+            window = loadgen.run(cl.url, cell["mix"], cell["query_set"], seed, seconds, traced=True)
             reqs = window["requests"]
             assert {r.template for r in reqs} == set(cell["mix"]["templates"]) and all(r.spans for r in reqs)
             weights = {t: float(sum(r.template == t for r in reqs)) for t in cell["mix"]["templates"]}
@@ -526,7 +541,7 @@ def test_every_metric_of_the_star_tree_cell_has_a_reader_that_returns_a_value():
                 "timers": dict(cl.timers, setup_s=1.0), "requests": reqs, "counters_before": before,
                 "counters_after": cl.counters(), "warm_moved": moved, "config": config, "query_set": cell["query_set"],
                 "peak": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9, "name": "TPU v5e"},
-                "device_trace": {"busy_s": 1.0, "window_s": 1.5, "events": {}, "chips": 1,
+                "device_trace": {"busy_s": 1.0, "window_s": seconds, "events": {}, "chips": 1,
                                  "queries_in_trace": float(len(reqs)), "template_weights": weights},
             }
             values = {m["name"]: harness.metric_value("layer_metrics", m["name"], ctx) for m in cell["per_layer"]}
@@ -535,8 +550,36 @@ def test_every_metric_of_the_star_tree_cell_has_a_reader_that_returns_a_value():
             planner.plan_cache_clear()
     finally:
         sys.path.remove(bench_dir)
+    return values, cell, reqs, weights
+
+
+def test_every_metric_of_the_star_tree_cell_has_a_reader_that_returns_a_value():
+    values, _, reqs, weights = _toy_window("ssb_sf10_startree.rollup_closed", 48_000, 37)
     assert set(STAR_SPECS) | {"launch_cpu_ms", "launches_per_query", "compiles_in_window", "plan_rebuilds_in_window"} <= set(values)
+    assert DOOR_SPECS <= set(values)  # its group-bys decode tables: table_decode_cpu_ms lists the cell
     assert not [name for name, v in values.items() if v is None], values
     assert values["startree_segments_per_query"] == pytest.approx(4 * sum(weights[t] for t in weights if t != "q4_1") / len(reqs))
     assert 0.0 < values["startree_roofline"] < 100.0 and values["startree_resident_bytes"] > 0
     assert values["compiles_in_window"] == values["plan_rebuilds_in_window"] == 0.0
+
+
+def test_the_door_and_cpu_metrics_read_a_value_on_a_scan_cells_traffic():
+    """PR 39's metrics carry no `workloads` list (but `table_decode_cpu_ms`), so
+    every cell is given them: on Q1's traffic, which decodes no table, each
+    returns a value and `table_decode_cpu_ms` is not asked for; and what they
+    return hangs together: the door's time holds its parts, a stage's CPU is at
+    most its wall, the server's loop is part of `dispatch`."""
+    values, cell, reqs, _ = _toy_window("ssb_sf10.q1_closed", 40_000, 39)
+    assert DOOR_SPECS - {"table_decode_cpu_ms"} <= set(values) and "table_decode_cpu_ms" not in values
+    assert not [name for name, v in values.items() if v is None], values
+    assert all(values[name] >= 0.0 for name in DOOR_SPECS & set(values)), values
+    assert values["frontdoor_door_ms"] >= (values["frontdoor_accept_wait_ms"] + values["frontdoor_head_ms"]
+                                           + values["frontdoor_read_ms"] + values["frontdoor_engine_ms"]
+                                           + values["frontdoor_serialize_ms"] + values["frontdoor_write_ms"]) - 0.01
+    assert values["frontdoor_accept_wait_p99_ms"] >= values["frontdoor_accept_wait_ms"] / 2
+    for stage in ("launch_enqueue", "collect", "reduce"):
+        assert values[stage + "_cpu_ms"] <= values[stage + "_ms"] + 1.0, stage
+    assert values["dispatch_loop_ms"] <= values["dispatch_ms"]
+    # one update of rest.doorMs an answered request: the two means of frontdoor_before_accept_ms are over the same requests
+    sent = np.mean([r.done - r.sent for r in reqs]) * 1000.0
+    assert values["frontdoor_before_accept_ms"] == pytest.approx(sent - values["frontdoor_door_ms"])

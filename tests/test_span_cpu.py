@@ -1,0 +1,139 @@
+"""CPU beside wall on the host's group-level stages, on the traced path only.
+
+`launch_enqueue`, `collect`, `table_decode` (query/executor.py) and the
+broker's `reduce` are opened with `cpu=True`: a traced answer's span says
+`cpuMs` (time.thread_time over the span) beside `ms`, top level and among its
+attrs, where the benchmark's `span_attr_mean` looks; wall less CPU is the
+stage's wait for the interpreter lock, a lock or the device.  `dispatch`
+says `loopMs`: its time outside its child spans, the server's per-segment
+loop.  An UNTRACED query builds no Span and so never reads the CPU clock,
+which is a system call (6 us on the TPU host).
+"""
+import time
+
+import numpy as np
+import pytest
+
+from pinot_tpu.cluster import Broker, Coordinator, ServerInstance
+from pinot_tpu.segment.builder import build_segment
+from pinot_tpu.spi.config import TableConfig
+from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
+from pinot_tpu.utils import metrics
+
+GROUP_SQL = "SELECT region, SUM(rev) FROM cpu_t WHERE qty < 40 GROUP BY region ORDER BY region"
+SCALAR_SQL = "SELECT SUM(rev) FROM cpu_t WHERE qty < 40"
+SEGMENTS = 4
+CPU_STAGES = ("launch_enqueue", "collect", "table_decode", "reduce")
+CLOCK_SLACK_MS = 1.0  # two clocks, read one after the other
+
+
+@pytest.fixture(scope="module")
+def broker():
+    schema = Schema(
+        "cpu_t",
+        [
+            FieldSpec("region", DataType.INT),
+            FieldSpec("qty", DataType.INT),
+            FieldSpec("rev", DataType.LONG, role=FieldRole.METRIC),
+        ],
+    )
+    coord = Coordinator(replication=1)
+    coord.register_server(ServerInstance("server0"))
+    coord.add_table(schema, TableConfig(name="cpu_t"))
+    rng = np.random.default_rng(39)
+    for i in range(SEGMENTS):
+        block = {
+            # every value in every segment: one dictionary shape, so one kernel and one group launch
+            "region": rng.permutation(np.arange(400) % 5).astype(np.int32),
+            "qty": rng.permutation(np.arange(400) % 50 + 1).astype(np.int32),
+            "rev": rng.integers(1, 10**6, 400),
+        }
+        coord.add_segment("cpu_t", build_segment(schema, block, f"seg{i}"))
+    b = Broker(coord)
+    for sql in (GROUP_SQL, SCALAR_SQL):  # compile outside every case
+        b.query(sql)
+    return b
+
+
+@pytest.fixture(scope="module")
+def tree(broker):
+    return broker.query("SET trace = true; " + GROUP_SQL).stats.trace
+
+
+def _named(node, name):
+    out = [node] if node["name"].split(":", 1)[0] == name else []
+    for c in node.get("children", ()):
+        out.extend(_named(c, name))
+    return out
+
+
+@pytest.mark.parametrize("stage", CPU_STAGES)
+def test_group_level_stage_says_its_cpu_beside_its_wall(tree, stage):
+    spans = _named(tree, stage)
+    assert spans, f"no {stage} span in a traced group-by"
+    for sp in spans:
+        assert sp["attrs"]["cpuMs"] == sp["cpuMs"]  # top level as every cpu=True span, and where the readers look
+        assert 0.0 <= sp["cpuMs"] <= sp["ms"] + CLOCK_SLACK_MS, sp
+
+
+def test_a_scalar_sum_decodes_no_table(broker):
+    scalar = broker.query("SET trace = true; " + SCALAR_SQL).stats.trace
+    assert not _named(scalar, "table_decode")
+    assert all("cpuMs" in sp["attrs"] for name in ("launch_enqueue", "collect", "reduce") for sp in _named(scalar, name))
+
+
+def test_table_decode_cpu_is_part_of_collects(tree):
+    (collect,) = _named(tree, "collect")
+    (decode,) = _named(collect, "table_decode")
+    assert decode["cpuMs"] <= collect["cpuMs"] + 0.01
+
+
+def test_the_stages_no_one_asked_stay_off_the_cpu_clock(tree):
+    for name in ("launch_plan", "launch_ship", "launch_release", "dispatch", "plan", "prune", "scatter", "route"):
+        spans = _named(tree, name)
+        assert spans and not [sp for sp in spans if "cpuMs" in sp or "cpuMs" in sp.get("attrs", {})], name
+
+
+def test_dispatch_loop_ms_is_its_time_less_its_childrens(tree):
+    (dispatch,) = _named(tree, "dispatch")
+    kids = dispatch["children"]
+    assert sorted({k["name"].split(":", 1)[0] for k in kids}) == ["launch", "launch_enqueue"]
+    assert len(kids) == SEGMENTS + 1  # four segments planned and shipped, one jitted call
+    loop = dispatch["ms"] - sum(k["ms"] for k in kids)
+    assert dispatch["attrs"]["loopMs"] == pytest.approx(loop, abs=0.001 * (len(kids) + 2))  # each rounded to the us
+    assert 0.0 <= dispatch["attrs"]["loopMs"] < dispatch["ms"]
+
+
+def test_a_slow_loop_is_loop_time_and_no_childs(broker, monkeypatch):
+    """What the server's loop does between two launches has no span: it is
+    `loopMs`, and no child's `ms`."""
+    from pinot_tpu.query import executor
+
+    real = executor.prune_segment
+
+    def slow_prune(ctx, seg):
+        time.sleep(0.02)
+        return real(ctx, seg)
+
+    monkeypatch.setattr(executor, "prune_segment", slow_prune)
+    slow = broker.query("SET trace = true; " + GROUP_SQL).stats.trace
+    (dispatch,) = _named(slow, "dispatch")
+    assert dispatch["attrs"]["loopMs"] >= SEGMENTS * 20.0
+    assert sum(k["ms"] for k in dispatch["children"]) < dispatch["ms"] - SEGMENTS * 20.0 + 1.0
+
+
+@pytest.mark.parametrize("sql", [GROUP_SQL, SCALAR_SQL], ids=["group_by", "scalar"])
+def test_an_untraced_query_never_reads_the_cpu_clock(broker, monkeypatch, sql):
+    reads = []
+    real = time.thread_time
+
+    def counting():
+        reads.append(1)
+        return real()
+
+    monkeypatch.setattr(metrics.time, "thread_time", counting)
+    out = broker.query(sql)
+    assert out.stats.trace is None and out.rows and not reads
+    traced = broker.query("SET trace = true; " + sql)
+    spans = [n for name in CPU_STAGES + ("launch", "query", "server") for n in _named(traced.stats.trace, name)]
+    assert len(reads) == 2 * len(spans)  # once at entry, once at exit, of every span that asked and no other

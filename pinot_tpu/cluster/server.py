@@ -184,7 +184,11 @@ class ServerInstance:
 
         Tracing (ctx option `trace`): builds a per-server span subtree —
         dispatch (attrs starSegments: the segments a star-tree level answered
-        for, loopMs: its time outside its child spans; per segment a
+        for, combinedSegments: the segments whose dense group tables the chip
+        folded into their group's ONE before the fetch (executor._launch_group:
+        such a group has one result, at its first segment's place in the
+        results, and None at the others'), loopMs: its time outside its child
+        spans; per segment a
         launch:<segment> span over the executor's
         launch_plan / launch_ship, and per GROUP of segments that share a
         compiled kernel one launch_enqueue, ending in launch_release:
@@ -311,6 +315,7 @@ class ServerInstance:
                 # loop itself a segment (prune, residency check, budget check)
                 dsp.annotate(
                     launches=launches.calls, starSegments=launches.star_segments,
+                    combinedSegments=launches.combined_segments,
                     loopMs=round(dsp.duration_ms - sum(c.duration_ms for c in dsp.children), 3),
                 )
             if trace.enabled:
@@ -336,6 +341,7 @@ class ServerInstance:
             self.metrics.counter("server.launches").inc(launches.calls)
             self.metrics.counter("server.groupedSegments").inc(launches.grouped_segments)
             self.metrics.counter("server.sparseGroups").inc(launches.sparse_groups)
+            self.metrics.counter("server.combinedSegments").inc(launches.combined_segments)
             if launches.star_segments:
                 self.metrics.counter("server.starTreeSegments").inc(launches.star_segments)
                 self.metrics.counter("server.starTreeLevelRows").inc(launches.star_level_rows)

@@ -191,7 +191,20 @@ def _plan_member(ctx, segment, device, residency, trace, planning=None) -> _Memb
     return _Member(table, plan, cols, stats, asked.rewrite)
 
 
-def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=None):
+def _combines_as(members: List[_Member]) -> Optional[Tuple]:
+    """What a group of `members` must share with another for the chip to
+    fold the dense group tables of both into one, or None where it folds
+    nothing: the compiled kernel and ONE key space (the group columns'
+    dictionaries, by fingerprint), for a plan whose fields combine by name
+    (planner.combines) and more than one member."""
+    base = members[0].plan
+    if len(members) == 1 or not planner.combines(base):
+        return None
+    spaces = {_key_space_id(m.plan) for m in members}
+    return (id(base.fn), spaces.pop()) if len(spaces) == 1 else None
+
+
+def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=None, combined=None, before=None):
     """ONE jitted call for `members`, whose plans share one compiled kernel
     (`plan.fn` is one object) and differ in their parameters' values: the
     launch's one trip into the runtime (span launch_enqueue, attrs
@@ -200,11 +213,20 @@ def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=No
     program of that width (planner.grouped_plan) with the members' resident
     column pytrees as a tuple, nothing re-staged, and their packed parameter
     buffers stacked on the host to [N, n], riding the call as a lone
-    member's ride theirs.  Returns the pending state collect_group takes:
-    the outputs carry a leading member axis when N > 1; the query is the one
+    member's ride theirs.  With `combined` (the caller's _combines_as of the
+    members: dense group-bys over ONE key space whose fields combine by
+    name) the program folds their tables into ONE before the fetch: the
+    server's combine, on the chip.  `before` is then the state of the
+    query's earlier groups that combine as the same, if any: this call
+    folds into THEIR table (a device array; nothing is fetched) and the
+    state returned holds both calls' members and the one table.
+    Returns the pending state collect_group takes:
+    the outputs carry a leading member axis when N > 1 and not combined; the
+    query is the one
     the plans were made from, so the rewritten one where the members are
     star-tree levels (all of a group are: they share a kernel), and the
-    state's last item is then the rewrite that restores their answers."""
+    state's last items are then the rewrite that restores their answers, and
+    what the members combine as (None: a result a member)."""
     rewrite = members[0].rewrite
     if rewrite is not None:
         ctx = rewrite.ctx
@@ -213,19 +235,24 @@ def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=No
     if width == 1:
         program, args = base, (members[0].cols, base.params)
     else:
-        program = planner.grouped_plan(base, width)
+        program = planner.grouped_plan(base, width, combined is not None)
         args = (
             tuple(m.cols for m in members),
             {k: np.stack([m.plan.params[k] for m in members]) for k in base.params},
         )
+        if combined is not None:
+            args += (
+                before[4] if before is not None
+                else planner.identity_tables(program, base.fn, (members[0].cols, base.params), device),
+            )
     out, members[0].stats.compile_ms = _enqueue(
         trace, program, args, device, on_first_launch,
         segments=width, width=width, kind=base.kind, backend=base.cache_key[2],
     )
-    return (
-        "pending", ctx, [m.table for m in members], [m.plan for m in members], out,
-        [m.stats for m in members], rewrite,
-    )
+    tables, plans, stats = [m.table for m in members], [m.plan for m in members], [m.stats for m in members]
+    if combined is not None and before is not None:
+        tables, plans, stats = before[2] + tables, before[3] + plans, before[5] + stats
+    return ("pending", ctx, tables, plans, out, stats, rewrite, combined)
 
 
 def launch_segment(
@@ -301,12 +328,14 @@ class QueryLaunches:
         self.sparse_groups = 0  # groups the collected sparse tables held, summed over segments
         self.star_segments = 0  # segments a star-tree level answered for
         self.star_level_rows = 0  # the true rows of those levels
+        self.combined_segments = 0  # segments whose dense tables the chip folded into their group's one
         self._added = 0
         # the query's half of its plans, derived once (the caller's, where it
         # already asked it for the columns the query reads)
         self.planning = planning if planning is not None else planner.QueryPlanning(ctx)
         self._open: Dict[int, List[Tuple[int, _Member]]] = {}  # id(plan.fn) -> (slot, member)
-        self._states: List[Tuple[Tuple, List[int]]] = []  # (state, its members' slots), in launch order
+        self._states: List[Tuple[Tuple, List[int], int]] = []  # (state, its members' slots, its calls), in launch order
+        self._combining: Dict[Tuple, int] = {}  # what a state's members combine as -> its place in _states
 
     def add(self, segment: ImmutableSegment) -> None:
         self.check()
@@ -341,32 +370,47 @@ class QueryLaunches:
 
     def _launch(self, group: List[Tuple[int, _Member]]) -> None:
         self.check()
+        members, slots = [m for _, m in group], [slot for slot, _ in group]
+        combined = _combines_as(members)
+        # the query's earlier groups of this kernel and key space: this one folds into their table
+        at = self._combining.get(combined)
         state = _launch_group(
-            self.ctx, [m for _, m in group], self.device, self.trace, self.on_first_launch
+            self.ctx, members, self.device, self.trace, self.on_first_launch,
+            combined, None if at is None else self._states[at][0],
         )
-        self._states.append((state, [slot for slot, _ in group]))
+        if at is not None:
+            self._states[at] = (state, self._states[at][1] + slots, self._states[at][2] + 1)
+        else:
+            self._states.append((state, slots, 1))
+            if combined is not None:
+                self._combining[combined] = len(self._states) - 1
         self.calls += 1
         self.uncollected += 1
         if len(group) > 1:
             self.grouped_segments += len(group)
+        if combined is not None:
+            self.combined_segments += len(group)
 
     def outputs(self) -> list:
         """Device outputs of every launched group: what a tracing caller
         fences on with ONE jax.block_until_ready (pending_outputs)."""
-        return pending_outputs([state for state, _ in self._states])
+        return pending_outputs([state for state, _, _ in self._states])
 
     def collect(self) -> List[Tuple]:
         """(segment result, ExecutionStats) of every added segment, in the
-        order added: one `collect` span and one fetch a group."""
+        order added: one `collect` span and one fetch a group.  The groups
+        whose tables the chip combined have ONE fetch and ONE result between
+        them, at their first member's place, and None at the others'; every
+        member keeps its stats."""
         answers: List = [None] * self._added
-        for state, slots in self._states:
+        for state, slots, calls in self._states:
             self.check()
             with self.trace.span("collect", cpu=True, segments=len(slots)) as csp:
                 for slot, answer in zip(slots, collect_group(state, self.check, self.trace)):
                     answers[slot] = answer
                 if state[3][0].kind == "groupby_sparse":
                     self.sparse_groups += sum(answers[slot][1].num_groups for slot in slots)
-            self.uncollected -= 1
+            self.uncollected -= calls
             if csp is not None:
                 csp.annotate(docs=sum(answers[slot][1].num_docs_scanned for slot in slots))
         return answers
@@ -426,31 +470,38 @@ def pending_outputs(states) -> list:
 def collect_group(state, check=None, trace: Optional[Trace] = None):
     """Phase 2: ONE jax.device_get for the group's outputs (the fence, and
     the launch's one trip back), then the host-side decode a member on its
-    slice of the leading member axis.  Returns [(result, stats)] a member,
+    slice of the leading member axis, or ONE decode where the chip combined
+    the members' tables (_launch_group): that result is the first member's
+    and the others' is None.  Returns [(result, stats)] a member,
     and calls `check` (QueryLaunches) before each decode after the first.
     A group-by's decodes sit in a span `table_decode` of `trace`: the
-    fetched tables' bytes (`tableBytes`), the slots of a member's table
-    (`keySpace`: the dense key space, or the sparse table's fixed size) and
-    the groups the members' tables held (`groups`)."""
+    fetched tables' bytes (`tableBytes`), the tables decoded (`tables`), the
+    slots of a table (`keySpace`: the dense key space, or the sparse table's
+    fixed size) and the groups the decoded tables held (`groups`)."""
     import jax
 
-    _, ctx, segments, plans, out, stats_list, rewrite = state
+    _, ctx, segments, plans, out, stats_list, rewrite, combined = state
     host = jax.device_get(out)
     answers = []
     plan = plans[0]
+    one = combined is not None  # ONE table came back for all the members
     table = trace is not None and plan.kind.startswith("groupby")
     with trace.span("table_decode", cpu=True, kind=plan.kind) if table else contextlib.nullcontext() as tsp:
         for i, (segment, member_plan, stats) in enumerate(zip(segments, plans, stats_list)):
+            if one and i:
+                answers.append((None, stats))
+                continue
             if i and check is not None:
                 check()
-            member = host if len(segments) == 1 else jax.tree_util.tree_map(lambda a: a[i], host)
-            answer = _decode_host(ctx, segment, member_plan, member, stats)
+            member = host if one or len(segments) == 1 else jax.tree_util.tree_map(lambda a: a[i], host)
+            answer = _decode_host(ctx, segment, member_plan, member, stats, not one)
             if rewrite is not None:  # a star-tree level's answer, under the query's own aggregations
                 answer = (rewrite.restore(answer[0]), stats)
             answers.append(answer)
         if tsp is not None:
             tsp.annotate(
                 tableBytes=sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(host)),
+                tables=1 if one else len(segments),
                 keySpace=plan.num_groups if plan.kind == "groupby_dense" else min(plan.num_groups, ctx.num_groups_limit),
                 groups=sum(stats.num_groups for stats in stats_list),
             )
@@ -464,10 +515,13 @@ def collect_segment(state):
     return answer
 
 
-def _decode_host(ctx, segment, plan, host, stats):
+def _decode_host(ctx, segment, plan, host, stats, trim=True):
     """Host-side decode of one query's (already fetched) kernel outputs —
     shared by the unbatched collect and the per-member unstack of a
-    cross-query batched launch."""
+    cross-query batched launch.  `trim` False: a dense table that is already
+    several segments' combine keeps every group, as the reduce's aligned
+    merge of those segments' tables does (numGroupsLimit bounds what ONE
+    segment tracks)."""
     if plan.kind == "aggregation":
         partials = [fn.host_partial(p) for fn, p in zip(plan.aggs, host)]
         return AggSegmentResult(partials=partials), stats
@@ -481,7 +535,7 @@ def _decode_host(ctx, segment, plan, host, stats):
             group_dims=plan.group_dims,
         )
         keys, sliced = _dense_to_present(
-            plan, presence, partials, ctx.num_groups_limit,
+            plan, presence, partials, ctx.num_groups_limit if trim else None,
             order_trim=planner.order_by_agg_index(ctx),
         )
         stats.num_groups = len(keys[0]) if keys else 0

@@ -163,10 +163,14 @@ class SegmentPlan:
     # plan every hit builds, so "has not run on this device yet" is one fact
     # however many queries race the first launch
     launched_on: Dict[Any, float] = field(default_factory=dict)
-    # width -> this plan's group program (grouped_plan): the plan-cache
-    # entry's too, shared by reference like launched_on, so a program is
-    # built once a width and leaves the process with its entry
-    widened: Dict[int, "SegmentPlan"] = field(default_factory=dict)
+    # (width, combine) -> this plan's group program (grouped_plan): the
+    # plan-cache entry's too, shared by reference like launched_on, so a
+    # program is built once a width and form and leaves the process with its
+    # entry
+    widened: Dict[Tuple[int, bool], "SegmentPlan"] = field(default_factory=dict)
+    # a combining group program's: device -> the tables its first call of a
+    # query folds into (identity_tables), made once a device
+    identity: Dict[Any, Any] = field(default_factory=dict)
     # plan-cache key (shape fp, segment signature, backend) — the stable
     # identity the cross-query batcher keys its vmapped-fn LRU on, so
     # batching never compiles more than once per (shape, batch width)
@@ -218,37 +222,103 @@ def _join(xs):
     return lambda i: jax.lax.dynamic_slice(joined, (i * run,), (x0.size,)).reshape(x0.shape)
 
 
-def grouped_plan(base: SegmentPlan, width: int) -> SegmentPlan:
+def combines(plan: SegmentPlan) -> bool:
+    """Whether the tables of `plan`'s kernel fold into one elementwise: a
+    dense group-by whose every aggregation says its fields' kinds, so each
+    field combines by its NAME (FIELD_COMBINE: add / min / max).  A sketch or
+    own-scatter function (`field_kinds` None) and a pairwise merge (coupled
+    fields: LASTWITHTIME's (t, v)) do not."""
+    return plan.kind == "groupby_dense" and all(
+        fn.field_kinds is not None
+        and not fn.pairwise_merge
+        and all(f in FIELD_COMBINE for f in fn.field_kinds)
+        for fn in plan.aggs
+    )
+
+
+def _identity_tables(shapes):
+    """The dense group-by's (presence, partials) that changes nothing when a
+    member's tables fold into it: zeros, and a min / max field's identity
+    (those fields are f64: ops.group_min / group_max).  Host arrays: made
+    once a program and device, and an eager device op a field would be a
+    small compile of its own in every process."""
+    presence, partials = shapes
+    return (
+        np.zeros(presence.shape, presence.dtype),
+        [{f: np.full(like.shape, field_identity(f), like.dtype) for f, like in p.items()} for p in partials],
+    )
+
+
+_COMBINE = {"add": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def _fold_tables(acc, tables):
+    """A member's dense (presence, partials) combined into `acc`, field by
+    field: reduce._reduce_groupby's aligned merge, on the device."""
+    presence, partials = acc
+    more, theirs = tables
+    return (
+        presence + more,
+        [{f: _COMBINE[FIELD_COMBINE[f]](a[f], b[f]) for f in a} for a, b in zip(partials, theirs)],
+    )
+
+
+def identity_tables(program: SegmentPlan, kernel: Callable, member_args: Tuple, device):
+    """What the first call of a query's combining group `program` folds
+    into: the dense (presence, partials) of `kernel(*member_args)` at every
+    field's identity, on `device`.  Made once a (program, device): the
+    kernel's output types come from the jitted kernel's own cached trace."""
+    start = program.identity.get(device)
+    if start is None:
+        start = jax.device_put(_identity_tables(jax.eval_shape(kernel, *member_args)), device)
+        start = program.identity.setdefault(device, start)
+    return start
+
+
+def grouped_plan(base: SegmentPlan, width: int, combine: bool = False) -> SegmentPlan:
     """`base`'s group program for `width` members: `fn(cols, packed)` takes
     the members' column pytrees as a tuple and their parameter buffers
     stacked to [width, n], joins each column's members into one array on the
     device (_join; the copy: the members' stored bytes read and written once
     more) and scans `base.fn` over the members, so the kernel's body is
     compiled ONCE whatever the width and every output comes back with a
-    leading member axis.  The arithmetic is the per-segment kernel's.  A
+    leading member axis.  With `combine` (the caller's to say: `combines(base)`
+    and the members share ONE key space) it is `fn(cols, packed, tables)`:
+    the scan CARRIES dense group tables, the members' folded into `tables`
+    in member order, and ONE table comes back: the server's combine, on the
+    chip.  `tables` is identity_tables for a query's first such call and the
+    call before's output after it, so a query's groups of one key space
+    leave ONE table however many calls they ride.  The
+    arithmetic is the per-segment kernel's.  A
     program of its own, with a first-launch record of its own, kept on the
     plan-cache entry (`widened`)."""
-    grouped = base.widened.get(width)
+    grouped = base.widened.get((width, combine))
     if grouped is None:
         kernel = base.fn
 
-        def group(cols, packed):
+        def group(cols, packed, tables=()):
             leaves, treedefs = zip(*(jax.tree_util.tree_flatten(c) for c in cols))
             with jax.named_scope("group_stack"):
                 takes = [_join(xs) for xs in zip(*leaves)]
 
-            def member(_, at):
+            def member(acc, at):
                 i, params = at
                 mine = jax.tree_util.tree_unflatten(treedefs[0], [take(i) for take in takes])
-                return (), kernel(mine, params)
+                out = kernel(mine, params)
+                if not combine:
+                    return acc, out
+                with jax.named_scope("group_combine"):
+                    return _fold_tables(acc, out), ()
 
             members = (jnp.arange(width, dtype=jnp.int32), packed)
-            return jax.lax.scan(member, (), members, length=width)[1]
+            return jax.lax.scan(member, tables, members, length=width)[0 if combine else 1]
 
-        group.__name__ = group.__qualname__ = f"{base.kind}_{base.cache_key[2]}_x{width}"
+        group.__name__ = group.__qualname__ = (
+            f"{base.kind}_{base.cache_key[2]}_x{width}" + ("_combined" if combine else "")
+        )
         # a program, not a query's plan: it keeps none of `base`'s parameter buffers
-        mine = replace(base, fn=jax.jit(group), params={}, launched_on={}, widened={})
-        grouped = base.widened.setdefault(width, mine)  # a racing query's wins
+        mine = replace(base, fn=jax.jit(group), params={}, launched_on={}, widened={}, identity={})
+        grouped = base.widened.setdefault((width, combine), mine)  # a racing query's wins
         if grouped is mine:
             METRICS.counter("compile.group.programs").inc()
     return grouped

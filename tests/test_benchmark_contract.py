@@ -336,8 +336,11 @@ def test_program_still_says_what_a_drill_metric_reads(name, drill_served):
 
 def test_table_decode_says_what_came_back(drill_served):
     """`table_decode` (inside `collect`): `tableBytes` of the fetched tables,
-    `keySpace` slots a member's table has, `groups` they held; and the
-    always-on `server.sparseGroups` counts the sparse plan's alone."""
+    `tables` decoded (PR 40: the two segments' dense tables come back as the
+    ONE the chip folded them into, the sparse ones one a segment), `keySpace`
+    slots a table has, `groups` the decoded tables held (the merged table's,
+    or summed over the segments'); and the always-on `server.sparseGroups`
+    counts the sparse plan's alone."""
     trees, counters = drill_served
     groups = {}
     for how, tree in trees.items():
@@ -346,8 +349,11 @@ def test_table_decode_says_what_came_back(drill_served):
         attrs = decode["attrs"]
         assert attrs["kind"] == {"wide": "groupby_dense", "sparse": "groupby_sparse"}[how]
         assert attrs["keySpace"] == 120 * 120 and attrs["tableBytes"] >= 2 * 8 * attrs["keySpace"]
+        assert attrs["tables"] == {"wide": 1, "sparse": 2}[how]
+        # one dense table of `keySpace` slots came back, not one a segment (presence + SUM's sum and count, 8 B each)
+        assert how == "sparse" or attrs["tableBytes"] == 3 * 8 * attrs["keySpace"]
         groups[how] = attrs["groups"]
-    assert groups["wide"] == groups["sparse"] > 0
+    assert 0 < groups["wide"] <= groups["sparse"] <= 2 * groups["wide"]  # a group that two segments hold counts once merged
     assert counters["server.sparseGroups"] == groups["sparse"]
     assert counters["scan.traced.wide_scatter"] >= 1 and counters["scan.traced.sparse_sort"] >= 1
 

@@ -263,14 +263,17 @@ def test_engine_dense_groupby_step_compiles_for_v5e(topo, no_compile_cache, monk
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("combine", [False, True])
 @pytest.mark.parametrize("width", [4, 8])
-def test_group_program_compiles_its_kernel_once_for_v5e(one_chip, no_compile_cache, monkeypatch, width):
+def test_group_program_compiles_its_kernel_once_for_v5e(one_chip, no_compile_cache, monkeypatch, width, combine):
     """The served path's group launch (planner.grouped_plan): `width`
     members' packed columns joined end to end and the per-segment kernel
     scanned over them.  Whatever the width the program holds ONE Mosaic
     kernel, in the body of one while loop, so a wide group compiles about as
     long as a lone segment; and no column is a [width, rows] array, whose
-    member axis the chip would tile (planner._join)."""
+    member axis the chip would tile (planner._join).  With `combine` (what
+    the cells' dense group-bys launch since PR 40: segments of one key
+    space) the loop carries the tables and no [width, slots] table exists."""
     from pinot_tpu.query import planner
     from pinot_tpu.segment.builder import build_segment
     from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
@@ -313,12 +316,17 @@ def test_group_program_compiles_its_kernel_once_for_v5e(one_chip, no_compile_cac
 
         members = tuple(jax.tree_util.tree_map(described, cols) for _ in range(width))
         stacked = {k: described(v, (width,)) for k, v in plan.params.items()}
-        text = planner.grouped_plan(plan, width).fn.lower(members, stacked).compile().as_text()
+        assert planner.combines(plan)
+        args = (members, stacked)
+        if combine:  # the table the members fold into: the kernel's own output types
+            args += (jax.tree_util.tree_map(described, jax.eval_shape(plan.fn, members[0], plan.params)),)
+        text = planner.grouped_plan(plan, width, combine).fn.lower(*args).compile().as_text()
     finally:
         planner.plan_cache_clear()
     assert text.count("tpu_custom_call") == 1 and text.count(" while(") == 1
-    assert f"groupby_dense_pallas_x{width}" in text
-    assert not re.search(rf"\[{width},\d{{5,}}\]", text)  # the outputs are [width, 7000 slots]
+    assert f"groupby_dense_pallas_x{width}" + ("_combined" if combine else "") in text
+    assert not re.search(rf"\[{width},\d{{5,}}\]", text)  # the stacked outputs are [width, 7000 slots]
+    assert (f"[{width},7000]" in text) == (not combine)
 
 
 def test_q1_group_program_reduces_its_limbs_in_one_fusion_for_v5e(one_chip, no_compile_cache, monkeypatch):
@@ -391,8 +399,10 @@ def test_star_tree_level_group_program_compiles_for_v5e(one_chip, no_compile_cac
     """The star-tree cell's tree-served program (PR 37): the plan of SSB Q2.1
     over level 4 of the brand tree (a 65,536-row bucket, 7,000 slots) and of
     Q3.1 over level 5 of the nation tree (8,192 rows, 4,375 slots), as the
-    width-8 group program 40 segments launch: int64 field columns narrowed by
-    their stated range, the bound row count, ONE Mosaic kernel in one loop."""
+    width-8 group program 40 segments launch (the combining one since PR 40:
+    the levels of a table's segments share their dictionaries): int64 field
+    columns narrowed by their stated range, the bound row count, ONE Mosaic
+    kernel in one loop, one table of `slots` out."""
     from pinot_tpu.query import planner
     from pinot_tpu.segment.builder import build_segment
     from pinot_tpu.spi.config import IndexingConfig, TableConfig
@@ -439,8 +449,10 @@ def test_star_tree_level_group_program_compiles_for_v5e(one_chip, no_compile_cac
 
         members = tuple(jax.tree_util.tree_map(described, cols) for _ in range(8))
         stacked = {k: described(v, (8,)) for k, v in plan.params.items()}
-        text = planner.grouped_plan(plan, 8).fn.lower(members, stacked).compile().as_text()
+        assert planner.combines(plan)
+        tables = jax.tree_util.tree_map(described, jax.eval_shape(plan.fn, members[0], plan.params))
+        text = planner.grouped_plan(plan, 8, True).fn.lower(members, stacked, tables).compile().as_text()
     finally:
         planner.plan_cache_clear()
     assert text.count("tpu_custom_call") == 1 and text.count(" while(") == 1
-    assert "groupby_dense_pallas_x8" in text
+    assert "groupby_dense_pallas_x8_combined" in text and f"[8,{slots}]" not in text

@@ -285,7 +285,9 @@ def test_levels_of_unequal_rows_ride_one_program_and_one_launch(bench, ssb):
     assert {level_bucket(r) for r in rows} == {16384}  # one shape: the bucket
     assert [(n["attrs"]["segments"], n["attrs"]["width"]) for n in spans["launch_enqueue"]] == [(SEGMENTS, SEGMENTS)]
     dispatch = spans["dispatch"][0]["attrs"]
-    assert {k: v for k, v in dispatch.items() if k != "loopMs"} == {"launches": 1, "starSegments": SEGMENTS}
+    assert {k: v for k, v in dispatch.items() if k != "loopMs"} == {
+        "launches": 1, "starSegments": SEGMENTS, "combinedSegments": SEGMENTS,  # the levels' tables fold into one on the chip
+    }
     assert stats.trace["attrs"]["docsScanned"] == stats.num_docs_scanned == sum(rows)
     assert all("cpuMs" in n["attrs"] and n["attrs"]["kernelBytes"] > 0 for n in spans["launch"])
     assert [(n["attrs"]["star"], n["attrs"]["level"]) for n in spans["launch_plan"]] == [("st0", 4)] * SEGMENTS

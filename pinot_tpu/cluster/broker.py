@@ -1039,8 +1039,12 @@ class Broker:
         """Reduce + response stamping + result-cache populate + latency and
         ShapeStats accounting — the tail every served query (sync or batch
         member) runs through."""
-        with trace.span("reduce", cpu=True):
+        with trace.span("reduce", cpu=True) as rsp:
             out = reduce_mod.reduce_results(ctx, results, stats)
+            if rsp is not None and ctx.group_by:
+                # the group tables merged by value, 0 where they aligned
+                rsp.annotate(tablesByValue=stats.tables_merged_by_value)
+        METRICS.counter("broker.tablesMergedByValue").inc(stats.tables_merged_by_value)
         trace.flush(METRICS, {"reduce": "broker.reduceMs"})
         out.stats.time_ms = (time.perf_counter() - t0) * 1000
         out.stats.query_id = qid

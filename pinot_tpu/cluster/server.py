@@ -29,6 +29,7 @@ from pinot_tpu.query.ir import QueryContext
 from pinot_tpu.query.result import ExecutionStats
 from pinot_tpu.query.safety import Deadline, QueryTimeoutError, estimate_segment_bytes
 from pinot_tpu.segment.segment import ImmutableSegment
+from pinot_tpu.segment.table_shape import TableShape
 from pinot_tpu.utils.metrics import METRICS, MetricsRegistry
 
 # span name -> this server's timer: one update a query, the sum over its segments
@@ -71,6 +72,10 @@ class ServerInstance:
         self.device = device
         # table -> {segment name -> segment}
         self.segments: Dict[str, Dict[str, ImmutableSegment]] = {}
+        # table -> what its segments HERE share: the dictionary sizes their
+        # kernels are compiled for (segment/table_shape.py), kept by
+        # add_segment / drop_segment
+        self.shapes: Dict[str, TableShape] = {}
         # cluster.faults.FaultPlan hook (None in production)
         self.fault_plan = fault_plan
         # HBM reservation ledger (cluster.admission.ResourceBudget): every
@@ -131,6 +136,8 @@ class ServerInstance:
     # -- data manager ----------------------------------------------------
     def add_segment(self, table: str, segment: ImmutableSegment) -> None:
         self.segments.setdefault(table, {})[segment.name] = segment
+        # one of this name held before (replaced in place) leaves the shape as this one joins
+        self.shapes.setdefault(table, TableShape()).add(segment)
         # device-residency gauge: segment host arrays mirror what the
         # executor's pytree cache pins in HBM for this table
         METRICS.gauge(f"server.segmentBytes.{table}").add(_segment_bytes(segment))
@@ -139,6 +146,7 @@ class ServerInstance:
     def drop_segment(self, table: str, seg_name: str) -> None:
         seg = self.segments.get(table, {}).pop(seg_name, None)
         if seg is not None:
+            self.shapes[table].remove(seg_name)  # add_segment made it
             for held in [seg, *seg.star_tables(made_only=True)]:  # its star-tree levels are groups of their own
                 if self.residency is not None:
                     # uncharge the cache budget AND drop the device entry;
@@ -187,7 +195,10 @@ class ServerInstance:
         for, combinedSegments: the segments whose dense group tables the chip
         folded into their group's ONE before the fetch (executor._launch_group:
         such a group has one result, at its first segment's place in the
-        results, and None at the others'), loopMs: its time outside its child
+        results, and None at the others'), tableShapedSegments: the segments
+        whose plan's kernel was compiled for the dictionary sizes the table's
+        segments here share and not their own (`self.shapes`;
+        planner.compiled_dict_sizes), loopMs: its time outside its child
         spans; per segment a
         launch:<segment> span over the executor's
         launch_plan / launch_ship, and per GROUP of segments that share a
@@ -216,7 +227,7 @@ class ServerInstance:
         )
         # the query's half of planning, derived once for every segment below:
         # the columns it reads here, its plans in QueryLaunches
-        planning = QueryPlanning(ctx)
+        planning = QueryPlanning(ctx, self.shapes.get(ctx.table))
         ticket = None
         if self.budget is not None:
             # working-set estimate for the batch, reserved all-or-nothing
@@ -316,6 +327,7 @@ class ServerInstance:
                 dsp.annotate(
                     launches=launches.calls, starSegments=launches.star_segments,
                     combinedSegments=launches.combined_segments,
+                    tableShapedSegments=launches.table_shaped_segments,
                     loopMs=round(dsp.duration_ms - sum(c.duration_ms for c in dsp.children), 3),
                 )
             if trace.enabled:
@@ -342,6 +354,7 @@ class ServerInstance:
             self.metrics.counter("server.groupedSegments").inc(launches.grouped_segments)
             self.metrics.counter("server.sparseGroups").inc(launches.sparse_groups)
             self.metrics.counter("server.combinedSegments").inc(launches.combined_segments)
+            self.metrics.counter("server.tableShapedSegments").inc(launches.table_shaped_segments)
             if launches.star_segments:
                 self.metrics.counter("server.starTreeSegments").inc(launches.star_segments)
                 self.metrics.counter("server.starTreeLevelRows").inc(launches.star_level_rows)
@@ -373,7 +386,12 @@ class ServerInstance:
         is recorded (`server.compileMs`), as a served first launch's is.  The
         broker calls it on a table's other servers while one of them compiles
         the same programs (Broker._scatter)."""
-        launches = executor.QueryLaunches(ctx, device=self.device, residency=self.residency)
+        from pinot_tpu.query.planner import QueryPlanning
+
+        launches = executor.QueryLaunches(
+            ctx, device=self.device, residency=self.residency,
+            planning=QueryPlanning(ctx, self.shapes.get(ctx.table)),
+        )
         for name in seg_names:
             seg = self.get_segment(ctx.table, name)
             if seg is None:

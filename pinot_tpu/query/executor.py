@@ -152,7 +152,10 @@ def _plan_member(ctx, segment, device, residency, trace, planning=None) -> _Memb
     star-tree's level selection + the plan cache: dictionary look-ups are
     per segment; `planning` is the query's planner.QueryPlanning where the
     caller plans more than one segment, so the query's half is derived once;
-    attr `cache` = hit / miss, on a hit `bind` = recipe / rebuild, and where
+    attr `cache` = hit / miss, on a hit `bind` = recipe / rebuild, `shape` =
+    table where the plan's kernel was compiled for the dictionary sizes the
+    table's segments share and this segment's own is smaller, else segment
+    (planner.compiled_dict_sizes), and where
     a star-tree level is what was planned `star` = the tree's name and
     `level`) and launch_ship (the plan's columns looked up in, or staged
     into, the device's cache, nothing else: no device array is made for a
@@ -168,6 +171,7 @@ def _plan_member(ctx, segment, device, residency, trace, planning=None) -> _Memb
                 psp.annotate(cache="hit", bind=plan.bind)
             else:
                 psp.annotate(cache="miss")
+            psp.annotate(shape="table" if plan.table_shaped else "segment")
             if table is not segment:
                 psp.annotate(star=table.tree, level=table.level)
 
@@ -184,7 +188,7 @@ def _plan_member(ctx, segment, device, residency, trace, planning=None) -> _Memb
     with trace.span("launch_ship", segment=segment.name, params=len(plan.param_layout)) as ssp:
         cols = table.to_device(
             device=device, columns=plan.needed_columns, packed_codes=True,
-            residency=residency,
+            residency=residency, dict_rows=plan.dict_sizes,
         )
         if ssp is not None:
             ssp.annotate(paramArrays=len(plan.params))
@@ -329,6 +333,7 @@ class QueryLaunches:
         self.star_segments = 0  # segments a star-tree level answered for
         self.star_level_rows = 0  # the true rows of those levels
         self.combined_segments = 0  # segments whose dense tables the chip folded into their group's one
+        self.table_shaped_segments = 0  # segments whose kernel was compiled for the table's shape, not their own
         self._added = 0
         # the query's half of its plans, derived once (the caller's, where it
         # already asked it for the columns the query reads)
@@ -345,6 +350,7 @@ class QueryLaunches:
                 self.ctx, segment, self.device, self.residency, self.trace, self.planning
             )
         self.kernel_bytes += member.plan.scan_bytes
+        self.table_shaped_segments += member.plan.table_shaped
         if lsp is not None:
             # beside the span's cpuMs (the rest of its wall time is waiting:
             # interpreter lock, a lock); kernelBytes is EXPLAIN ANALYZE's Bytes
@@ -665,7 +671,7 @@ def launch_segment_batch(
     with trace.span("launch_ship", segment=segment.name, params=len(base.param_layout)) as ssp:
         cols = segment.to_device(
             device=device, columns=base.needed_columns, packed_codes=True,
-            residency=residency,
+            residency=residency, dict_rows=base.dict_sizes,
         )
         stacked = {
             k: v0 if k in shared_keys else np.stack([pl[k] for pl in params_list])
@@ -730,7 +736,8 @@ def _key_space_id(plan) -> Tuple:
     parts = []
     for gd in plan.group_dims:
         if gd.kind == "dict":
-            parts.append(("dict", gd.name, gd.dictionary.fingerprint(), gd.null_code))
+            # the stride too: a table's bound may pass the dictionary (planner.compiled_dict_sizes)
+            parts.append(("dict", gd.name, gd.dictionary.fingerprint(), gd.null_code, gd.cardinality))
         else:
             parts.append(("rawint", gd.name, gd.base, gd.cardinality))
     return tuple(parts)

@@ -163,9 +163,16 @@ class FilterCompiler:
     `index_uses` records (column, kind) per accelerated predicate for
     ExecutionStats."""
 
-    def __init__(self, segment: ImmutableSegment, null_handling: bool = True):
+    def __init__(
+        self, segment: ImmutableSegment, null_handling: bool = True,
+        dict_sizes: Optional[Dict[str, int]] = None,
+    ):
         self.segment = segment
         self.null_handling = null_handling
+        # {dictionary column: the dictionary size the kernel is compiled for}
+        # (planner.compiled_dict_sizes): a code table has that many slots,
+        # this segment's dictionary filling its head; None: the segment's own
+        self.dict_sizes = dict_sizes or {}
         self.params: Params = {}
         self._counter = 0
         # columns whose device entries the compiled closures will read
@@ -458,8 +465,11 @@ class FilterCompiler:
             self._bindable = ("range" if table is None else "table", pt, name, is_mv)
 
         if table is not None:
+            tail = self.dict_sizes.get(name, 0) - len(table)
             if is_mv:
                 table = np.append(table, False)  # padding code slot
+            elif tail > 0:  # the table's bound: no code of this segment reaches the tail
+                table = np.append(table, np.zeros(tail, bool))
             key = self._key("table")
             self.params[key] = table
             self.used_columns.add(name)

@@ -228,9 +228,11 @@ class AggregationSpec:
     # extra EXPRESSION args beyond the first (LASTWITHTIME's time column)
     extra_exprs: Tuple[Expr, ...] = ()
 
-    def fingerprint(self) -> str:
+    def fingerprint(self, filter_fp: Optional[str] = None) -> str:
+        """`filter_fp`: the FILTER clause said another way (query/shape.py
+        says it with its literals canonicalized, as it says WHERE's)."""
         e = self.expr.fingerprint() if self.expr else "*"
-        f = self.filter.fingerprint() if self.filter else ""
+        f = filter_fp if filter_fp is not None else (self.filter.fingerprint() if self.filter else "")
         x = "|".join(a.fingerprint() for a in self.extra_exprs)
         return f"{self.function}({e};{x})[{f}]{self.literal_args!r}"
 

@@ -185,6 +185,14 @@ class SegmentPlan:
     # the plan-cache entry's: how its parameters are made from (segment,
     # literals), None where some predicate has no such recipe
     recipe: Optional["ParamRecipe"] = None
+    # {dictionary column: the dictionary size the kernel was compiled for}
+    # (compiled_dict_sizes): the rows of the column's device dictionary the
+    # launch hands it (ImmutableSegment.to_device `dict_rows`)
+    dict_sizes: Dict[str, int] = field(default_factory=dict)
+    # this segment's own dictionary is smaller than one of `dict_sizes`: the
+    # kernel was compiled for the table's shape, not the segment's (the
+    # `shape` attr of the launch_plan span)
+    table_shaped: bool = False
 
 
 def vmapped_plan(base: SegmentPlan, shared_keys: frozenset) -> SegmentPlan:
@@ -415,10 +423,28 @@ def _sig_value(v):
     return v.item() if isinstance(v, np.generic) else v
 
 
+def compiled_dict_sizes(segment, needed: List[str], exact_cols: frozenset, shape) -> Dict[str, int]:
+    """{dictionary column: the dictionary size a kernel over `segment`'s
+    `needed` columns is compiled for}: the bound the table's segments share
+    (segment/table_shape.py, `shape`: the server's, None where the caller has
+    none), the segment's own cardinality for a column of `exact_cols` (its
+    dictionary's VALUES are baked into the kernel), for a star-tree level
+    (padded and bucketed by its own rule) and without a shape."""
+    shared = shape is not None and getattr(segment, "level_rows", None) is None
+    sizes = {}
+    for name in needed:
+        c = segment.column(name)
+        if c.has_dictionary:
+            sizes[name] = shape.bound(name, c) if shared and name not in exact_cols else c.cardinality
+    return sizes
+
+
 def _segment_signature(
     segment: ImmutableSegment, needed: List[str], sketch_cols: frozenset = frozenset(),
-    group_cols: frozenset = frozenset(),
+    group_cols: frozenset = frozenset(), dict_sizes: Optional[Dict[str, int]] = None,
 ) -> Tuple:
+    """`dict_sizes` (compiled_dict_sizes) is what the kernel bakes of each
+    dictionary column's size; None: every column's own cardinality."""
     sig = [segment.num_docs, segment.valid_docs is not None]
     if getattr(segment, "level_rows", None) is not None:
         sig.append(ROWS_KEY)  # a star-tree level: its kernel masks by the bound row count
@@ -454,7 +480,7 @@ def _segment_signature(
         sig.append(
             (
                 name,
-                c.cardinality if c.has_dictionary else -1,
+                (c.cardinality if dict_sizes is None else dict_sizes[name]) if c.has_dictionary else -1,
                 str(c.codes.dtype if c.codes is not None else c.values.dtype),
                 # packed lane width: packed and unpacked segments trace
                 # different kernels (word inputs vs code inputs)
@@ -653,7 +679,12 @@ def _non_filter_columns(ctx: QueryContext, segment) -> set:
     return set(_needed_columns(ctx2, segment))
 
 
-def _group_dim(expr: Expr, segment: ImmutableSegment, null_handling: bool) -> GroupDim:
+def _group_dim(
+    expr: Expr, segment: ImmutableSegment, null_handling: bool, dict_sizes: Optional[Dict[str, int]] = None
+) -> GroupDim:
+    """`dict_sizes` (compiled_dict_sizes): a dictionary dimension's stride in
+    the key space is the size its kernel was compiled for, which may pass
+    the segment's own dictionary; the decode reads the segment's own."""
     from pinot_tpu.query import scalar
 
     if expr.is_column:
@@ -670,7 +701,8 @@ def _group_dim(expr: Expr, segment: ImmutableSegment, null_handling: bool) -> Gr
                 nc = c.dictionary.index_of(c.data_type.null_placeholder)
                 if nc >= 0:
                     null_code = nc
-            return GroupDim(expr, c.name, "dict", c.dictionary.cardinality, dictionary=c.dictionary, null_code=null_code)
+            size = c.dictionary.cardinality if dict_sizes is None else dict_sizes[c.name]
+            return GroupDim(expr, c.name, "dict", size, dictionary=c.dictionary, null_code=null_code)
         if c.data_type in (DataType.INT, DataType.LONG, DataType.TIMESTAMP, DataType.BOOLEAN):
             lo, hi = int(c.stats.min_value), int(c.stats.max_value)
             rng = hi - lo + 1
@@ -1274,11 +1306,14 @@ def _bind_params(
         else:
             ((_, table_at, shape),) = slots
             table = codes[2]
-            if table.shape[0] + mv != shape[0]:
+            # the slot has the size the kernel was compiled for (the table's
+            # bound, compiled_dict_sizes): this segment's dictionary fills
+            # its head, and no code reaches the rest (a multi-value column's
+            # is its own size and the padding code's slot)
+            if table.shape[0] + mv > shape[0] or (mv and table.shape[0] + mv != shape[0]):
                 return None
             packed["bool"][table_at : table_at + table.shape[0]] = table
-            if mv:
-                packed["bool"][table_at + table.shape[0]] = False  # padding code slot
+            packed["bool"][table_at + table.shape[0] : table_at + shape[0]] = False
     if recipe.valid_docs:
         if segment.valid_docs is None:
             return None
@@ -1321,12 +1356,15 @@ class _SegmentMemo:
 
     def __init__(self, state):
         self.state = state
-        # (predicate columns, needed columns, bound columns, group columns) ->
+        # (predicate columns, needed columns, bound columns, group columns,
+        # the table shape asked and its version) ->
         # (the predicate columns' ColumnShapes, _segment_signature, the
-        # predicate columns' _dictionary_identity)
+        # predicate columns' _dictionary_identity, compiled_dict_sizes,
+        # whether one of those passes the segment's own dictionary, the
+        # group columns' of those sizes)
         self.halves: Dict[Tuple, Tuple] = {}
-        # (GROUP BY fingerprint, null handling) -> [GroupDim]: the decode's
-        # view of this segment's dictionaries
+        # (GROUP BY fingerprint, null handling, the group columns' compiled
+        # sizes) -> [GroupDim]: the decode's view of this segment's dictionaries
         self.group_dims: Dict[Tuple, List[GroupDim]] = {}
 
 
@@ -1366,8 +1404,12 @@ class QueryPlanning:
     query has a QueryPlanning of its own, made at the first such segment,
     and everything above holds for it and the levels."""
 
-    def __init__(self, ctx: QueryContext):
+    def __init__(self, ctx: QueryContext, shape=None):
         self.ctx = ctx
+        # what the table's segments on this server share (segment/table_shape.py
+        # TableShape: the dictionary sizes kernels are compiled for), None
+        # where the caller plans a segment on its own
+        self.shape = shape
         # what the query asks of a star-tree (startree.star_need), None where
         # none may serve it; the rewritten query's planning, once a segment
         # has a tree that does; on THAT planning, the rewrite it plans
@@ -1392,6 +1434,7 @@ class QueryPlanning:
         self._referenced = _referenced_columns(ctx)
         self._needed: Optional[List[str]] = None  # where no segment changes it
         self._half_key: Optional[Tuple] = None
+        self._half_under: Optional[Tuple] = None  # the table shape and version _half_key was made under
         self._shape_fps: Dict[Tuple, str] = {}
         self._lookups: Dict[Tuple, Tuple] = {}
 
@@ -1410,7 +1453,7 @@ class QueryPlanning:
             else:
                 if self._star is None:
                     rewrite = StarRewrite(self.ctx)
-                    self._star = QueryPlanning(rewrite.ctx)
+                    self._star = QueryPlanning(rewrite.ctx, self.shape)
                     self._star.rewrite = rewrite
                 name, tree, k = pick
                 got = (tree.levels[k].table(segment, name), self._star)
@@ -1438,31 +1481,44 @@ class QueryPlanning:
         once."""
         return self._key(segment, self.needed_columns(segment), _segment_memo(segment))[0]
 
-    def _key(self, segment, needed: List[str], memo: _SegmentMemo) -> Tuple[Tuple, Tuple]:
-        """(the key, the predicate columns' _dictionary_identity)."""
-        half_key = self._half_key if needed is self._needed else None
+    def _key(self, segment, needed: List[str], memo: _SegmentMemo) -> Tuple[Tuple, Tuple, Dict[str, int], bool, Tuple]:
+        """(the key, the predicate columns' _dictionary_identity, the
+        dictionary sizes the key's kernel is compiled for, whether one of
+        them passes this segment's own dictionary, the group columns' of
+        those sizes)."""
+        shape = self.shape
+        under = None if shape is None else (id(shape), shape.version)
+        half_key = self._half_key if needed is self._needed and under == self._half_under else None
         if half_key is None:
-            half_key = (self.predicate_cols, tuple(needed), self.bound_cols, self.group_cols)
+            half_key = (self.predicate_cols, tuple(needed), self.bound_cols, self.group_cols, under)
             if needed is self._needed:
-                self._half_key = half_key
+                self._half_key, self._half_under = half_key, under
         half = memo.halves.get(half_key)
         if half is None:
             info = column_info_from(segment)
+            sizes = compiled_dict_sizes(segment, needed, self.bound_cols, shape)
             half = (
                 tuple([info(c) for c in self.predicate_cols if c is not None]),
-                _segment_signature(segment, needed, self.bound_cols, group_cols=self.group_cols),
+                _segment_signature(segment, needed, self.bound_cols, self.group_cols, sizes),
                 tuple([_dictionary_identity(segment, c) for c in self.predicate_cols]),
+                sizes,
+                any(size > segment.column(name).cardinality for name, size in sizes.items()),
+                tuple([sizes.get(c) for c in self.group_cols]),
             )
             if len(memo.halves) >= memo.MAX_ENTRIES:
                 memo.halves.clear()
             memo.halves[half_key] = half
-        shapes, signature, same_dict = half
+        shapes, signature, same_dict, sizes, table_shaped, group_sizes = half
         fp = self._shape_fps.get(shapes)
         if fp is None:
             fp = self._shape_fps[shapes] = self.ctx.shape_fingerprint(column_info_from(segment))
-        return (fp, signature, ops.scan_backend()), same_dict  # pallas/xla plans trace different kernels
+        # pallas/xla plans trace different kernels
+        return (fp, signature, ops.scan_backend()), same_dict, sizes, table_shaped, group_sizes
 
-    def _bound(self, cached: SegmentPlan, segment, same_dict: Tuple, memo: _SegmentMemo) -> Optional[SegmentPlan]:
+    def _bound(
+        self, cached: SegmentPlan, segment, same_dict: Tuple, memo: _SegmentMemo, sizes: Dict[str, int],
+        group_sizes: Tuple,
+    ) -> Optional[SegmentPlan]:
         """The hit's plan by the entry's recipe: the entry itself with THIS
         query's parameters and THIS segment's group dimensions (the decode
         reads their dictionaries).  Everything else of a plan is the same for
@@ -1478,12 +1534,14 @@ class QueryPlanning:
         plan.params = params
         ctx = self.ctx
         if ctx.group_by:
-            dims = memo.group_dims.get(self._group_by)
+            # the strides are the compiled sizes', the values this segment's dictionaries'
+            dims_key = (*self._group_by, group_sizes)
+            dims = memo.group_dims.get(dims_key)
             if dims is None:
-                dims = [_group_dim(g, segment, ctx.null_handling) for g in ctx.group_by]
+                dims = [_group_dim(g, segment, ctx.null_handling, sizes) for g in ctx.group_by]
                 if len(memo.group_dims) >= memo.MAX_ENTRIES:
                     memo.group_dims.clear()
-                memo.group_dims[self._group_by] = dims
+                memo.group_dims[dims_key] = dims
             plan.group_dims = dims
         return plan
 
@@ -1501,10 +1559,13 @@ class QueryPlanning:
             self._checked = True
         needed = self._needed if self._needed is not None else self.needed_columns(segment)
         memo = _segment_memo(segment)
-        key, same_dict = self._key(segment, needed, memo)
+        key, same_dict, sizes, table_shaped, group_sizes = self._key(segment, needed, memo)
         cached = _PLAN_CACHE.get(key)
         if cached is not None:
-            plan = self._bound(cached, segment, same_dict, memo) if cached.recipe is not None else None
+            plan = (
+                self._bound(cached, segment, same_dict, memo, sizes, group_sizes)
+                if cached.recipe is not None else None
+            )
             bind = "recipe"
             if plan is None:
                 # no recipe, or one that does not fit: the parameters are
@@ -1513,7 +1574,7 @@ class QueryPlanning:
                 # silently retrace, so it counts (and compiles) as a miss
                 # instead.
                 bind = "rebuild"
-                plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn)
+                plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn, dict_sizes=sizes)
                 if plan.param_layout == cached.param_layout:
                     plan.scan_bytes = cached.scan_bytes
                     plan.launched_on = cached.launched_on
@@ -1524,16 +1585,18 @@ class QueryPlanning:
                 plan.cache_key = key
                 plan.cache_hit = True
                 plan.bind = bind
+                plan.table_shaped = table_shaped
                 METRICS.counter("compile.sse.binds" if bind == "recipe" else "compile.sse.rebuilds").inc()
                 SSE_AUDIT.record_hit(key[0])
                 return plan
         SSE_AUDIT.record_compile(key[0])
         # a process that has an entry has both counters, moved or not
         METRICS.counter("compile.sse.binds"), METRICS.counter("compile.sse.rebuilds")
-        plan = _build_plan(ctx, segment, needed, compiled_fn=None)
+        plan = _build_plan(ctx, segment, needed, compiled_fn=None, dict_sizes=sizes)
         plan.scan_bytes = segment.num_docs * scan_bytes_per_row(
             segment.column(n) for n in plan.needed_columns
         )
+        plan.table_shaped = table_shaped
         plan.cache_key = key
         _PLAN_CACHE.put(key, plan)
         return plan
@@ -1551,9 +1614,15 @@ def _build_plan(
     segment: ImmutableSegment,
     needed: List[str],
     compiled_fn: Optional[Callable],
+    dict_sizes: Optional[Dict[str, int]] = None,
 ) -> SegmentPlan:
+    """`dict_sizes` (compiled_dict_sizes): what the kernel bakes of each
+    dictionary column's size, the key of the plan cache says the same; None:
+    the segment's own cardinalities."""
     null_handling = ctx.null_handling
-    fc = FilterCompiler(segment, null_handling)
+    if dict_sizes is None:
+        dict_sizes = compiled_dict_sizes(segment, needed, frozenset(), None)
+    fc = FilterCompiler(segment, null_handling, dict_sizes)
     filter_fn = fc.compile(ctx.filter)
 
     # Upsert validDocIds: rows replaced by a newer row elsewhere are ANDed
@@ -1648,7 +1717,7 @@ def _build_plan(
         group_dims: List[GroupDim] = []
         num_groups = 0
     elif ctx.group_by:
-        group_dims = [_group_dim(g, segment, null_handling) for g in ctx.group_by]
+        group_dims = [_group_dim(g, segment, null_handling, dict_sizes) for g in ctx.group_by]
         num_groups = 1
         for gd in group_dims:
             num_groups *= max(1, gd.cardinality)
@@ -1886,4 +1955,5 @@ def _build_plan(
             if compiled_fn is None and not any(agg_subfilter_fns)
             else None
         ),
+        dict_sizes={name: dict_sizes[name] for name in needed if name in dict_sizes},
     )

@@ -144,6 +144,7 @@ def _reduce_groupby(ctx: QueryContext, results: List[GroupBySegmentResult], stat
         keys, partials = results[0].keys, results[0].partials
     else:
         keys, partials = _hash_merge(results, aggs)
+        stats.tables_merged_by_value = len(results)
 
     stats.num_groups = len(keys[0]) if keys else 0
     finals = [np.atleast_1d(np.asarray(fn.final(p))) for fn, p in zip(aggs, partials)]
@@ -177,14 +178,16 @@ def _reduce_groupby(ctx: QueryContext, results: List[GroupBySegmentResult], stat
     for s in ctx.select_list:
         out_cols.append(_eval_env_expr(s, env, n) if isinstance(s, Expr) else env[s.fingerprint()])
 
-    rows = _rows_from_columns(out_cols, n)
     if ctx.gapfill is not None:
-        rows = _apply_gapfill(ctx, rows)
+        rows = _apply_gapfill(ctx, _rows_from_columns(out_cols, n))
         if ctx.order_by:
             rows = _order_rows_by_select(ctx, rows)
         rows = rows[ctx.offset: ctx.offset + ctx.limit]
     else:
-        rows = _order_and_trim(ctx, rows, [s.fingerprint() for s in ctx.select_list], env, n)
+        # order the groups as arrays and make rows of the ones kept only: a
+        # merged table of 100,000 groups under LIMIT 10 is 10 tuples, not 100,000
+        keep = _ordered_slice(ctx, env, n)
+        rows = _rows_from_columns([np.asarray(c)[keep] for c in out_cols], len(keep))
     return ResultTable(columns=ctx.column_names_out(), rows=rows, stats=stats)
 
 
@@ -358,6 +361,12 @@ def _scatter_init(shape, dtype, op: str):
     return np.full(shape, fill, dtype=dtype)
 
 
+# the by-value merge codes an integer dimension by its values, and keeps the
+# merged table dense, while the range / the key space has at most this many
+# slots (8 B a slot of scratch); past it, np.unique's sort as before
+_DENSE_MERGE_SPACE = 1 << 24
+
+
 def _hash_merge_vectorized(results: List[GroupBySegmentResult], aggs):
     """Returns (keys, partials) in first-seen key order, or None when the
     keys defy np.unique coding (caller falls back to the upsert loop)."""
@@ -365,14 +374,30 @@ def _hash_merge_vectorized(results: List[GroupBySegmentResult], aggs):
     total = sum(len(r.keys[0]) if r.keys else 0 for r in results)
     if total == 0 or ndims == 0:
         return None
-    cat_keys = [
-        np.concatenate([np.asarray(r.keys[d], dtype=object) for r in results])
-        for d in range(ndims)
-    ]
+    cat_keys = []
+    for d in range(ndims):
+        arrs = [np.asarray(r.keys[d]) for r in results]
+        # a dimension whose every table decoded to integers (an INT
+        # dictionary's values) keeps its dtype; anything else (strings, a
+        # None among the values, mixed kinds) rides as objects, as before
+        kinds = {a.dtype.kind for a in arrs}
+        if not (len(kinds) == 1 and kinds <= {"i", "u"}):
+            arrs = [a.astype(object) for a in arrs]
+        cat_keys.append(np.concatenate(arrs))
     cards, invs = [], []
     for d in range(ndims):
+        keys = cat_keys[d]
+        lo, hi = (int(keys.min()), int(keys.max())) if keys.dtype != object else (0, _DENSE_MERGE_SPACE)
+        if hi - lo < _DENSE_MERGE_SPACE:
+            # integers of a small range code themselves: no sort.  np.unique
+            # over the 4M keys of 50 tables of ~79,000 groups is a second a
+            # dimension, and the tables of segments built apart meet here
+            cards.append(hi - lo + 1)
+            # in int64: a narrow dtype's range can pass the dtype (int16 keys of -30000..30000)
+            invs.append(np.subtract(keys, lo, dtype=np.int64) if lo else keys)
+            continue
         try:
-            uniq, inv = np.unique(cat_keys[d], return_inverse=True)
+            uniq, inv = np.unique(keys, return_inverse=True)
         except TypeError:
             return None
         cards.append(max(1, len(uniq)))
@@ -382,14 +407,26 @@ def _hash_merge_vectorized(results: List[GroupBySegmentResult], aggs):
         space *= c
     if space >= (1 << 62):  # packed composite code must fit int64
         return None
-    codes = np.zeros(total, dtype=np.int64)
-    for card, inv in zip(cards, invs):
-        codes = codes * np.int64(card) + inv.astype(np.int64)
-    uniq_codes, first_pos, inv = np.unique(codes, return_index=True, return_inverse=True)
-    order = np.argsort(first_pos, kind="stable")  # sorted-unique -> first-seen
-    rank = np.empty(len(uniq_codes), dtype=np.int64)
-    rank[order] = np.arange(len(uniq_codes))
-    g = rank[inv.reshape(-1)]  # row -> output slot
+    codes = invs[0].astype(np.int64)  # a copy: the passes below are in place, over millions of keys
+    for card, inv in zip(cards[1:], invs[1:]):
+        codes *= np.int64(card)
+        codes += inv
+    if space <= _DENSE_MERGE_SPACE:
+        # a key space that fits a table: first positions by one scatter-min
+        first_at = np.full(space, total, dtype=np.int64)
+        np.minimum.at(first_at, codes, np.arange(total, dtype=np.int64))
+        uniq_codes = np.flatnonzero(first_at < total)
+        first_pos = first_at[uniq_codes]
+        order = np.argsort(first_pos, kind="stable")  # sorted-unique -> first-seen
+        slot = np.empty(space, dtype=np.int64)
+        slot[uniq_codes[order]] = np.arange(len(uniq_codes))
+        g = slot[codes]  # row -> output slot
+    else:
+        uniq_codes, first_pos, inv = np.unique(codes, return_index=True, return_inverse=True)
+        order = np.argsort(first_pos, kind="stable")  # sorted-unique -> first-seen
+        rank = np.empty(len(uniq_codes), dtype=np.int64)
+        rank[order] = np.arange(len(uniq_codes))
+        g = rank[inv.reshape(-1)]  # row -> output slot
     k = len(uniq_codes)
     keys_out = [cat_keys[d][first_pos[order]] for d in range(ndims)]
     partials_out: List[Dict[str, np.ndarray]] = []
@@ -691,6 +728,12 @@ def _order_codes(order_by: List[OrderByExpr], ord_vals: List[np.ndarray], n: int
     back to the Python comparator."""
     keys = []
     for ob, vals in zip(reversed(order_by), reversed(ord_vals)):
+        if isinstance(vals, np.ndarray) and vals.dtype.kind in "iu" and (
+            not vals.size or max(abs(int(vals.min())), abs(int(vals.max()))) < (1 << 53)
+        ):
+            num = vals.astype(np.float64)  # exact, so the floats order as the integers do
+            keys.append(num if ob.ascending else -num)
+            continue
         a = np.asarray(vals, dtype=object)
         isnull = np.fromiter((v is None for v in a), dtype=bool, count=len(a))
         body = a[~isnull]
@@ -740,13 +783,10 @@ def _sorted_order(order_by: List[OrderByExpr], ord_vals: List[np.ndarray], n: in
     return sorted(range(n), key=functools.cmp_to_key(cmp))
 
 
-def _order_and_trim(
-    ctx: QueryContext,
-    rows: List[tuple],
-    select_fps: List[str],
-    env: Dict[str, np.ndarray],
-    n: int,
-) -> List[tuple]:
+def _ordered_slice(ctx: QueryContext, env: Dict[str, np.ndarray], n: int) -> np.ndarray:
+    """The indices of the groups a query keeps, in its ORDER BY's order, cut
+    to OFFSET / LIMIT."""
+    order = np.arange(n)
     if ctx.order_by:
         ord_vals = []
         for ob in ctx.order_by:
@@ -756,10 +796,12 @@ def _order_and_trim(
                 raise ValueError(
                     f"ORDER BY {ob.expr} must be a select/group/aggregation expression"
                 ) from None
-            ord_vals.append(np.asarray([_scalar(v) if not isinstance(v, (str, bytes, type(None))) else v for v in vals], dtype=object))
-        order = _sorted_order(ctx.order_by, ord_vals, n)
-        rows = [rows[i] for i in order]
-    return rows[ctx.offset: ctx.offset + ctx.limit]
+            vals = np.asarray(vals)
+            if vals.dtype.kind not in "iu":  # integers have no null and no NaN: they order as they are
+                vals = np.asarray([_scalar(v) if not isinstance(v, (str, bytes, type(None))) else v for v in vals], dtype=object)
+            ord_vals.append(vals)
+        order = np.asarray(_sorted_order(ctx.order_by, ord_vals, n), dtype=np.int64)
+    return order[ctx.offset: ctx.offset + ctx.limit]
 
 
 _ENV_BINOPS = {

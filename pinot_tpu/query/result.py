@@ -25,6 +25,9 @@ class ExecutionStats:
     num_docs_scanned: int = 0
     total_docs: int = 0
     num_groups: int = 0
+    # group tables the reduce merged by VALUE (a hash merge over decoded
+    # keys) because their key spaces differ; 0 where they aligned or one came
+    tables_merged_by_value: int = 0
     time_ms: float = 0.0
     # scatter-gather fault surface (BrokerResponse partialResult /
     # processingExceptions / numServersQueried|Responded analog): a query

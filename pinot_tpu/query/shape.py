@@ -48,6 +48,7 @@ import hashlib
 from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 from pinot_tpu.query.ir import (
+    AggregationSpec,
     FilterNode,
     FilterOp,
     Predicate,
@@ -253,6 +254,16 @@ def _filter_shape_fp(node: Optional[FilterNode], info: Optional[ColumnInfo]) -> 
     return f"{node.op.value}({';'.join(_filter_shape_fp(c, info) for c in node.children)})"
 
 
+def _select_shape_fp(s: Any, info: Optional[ColumnInfo]) -> str:
+    """A select item's fingerprint with the literals of an aggregation's
+    FILTER clause canonicalized like WHERE's: the FilterCompiler compiles
+    both into one params pytree, so `SUM(x) FILTER(WHERE d > 5)` and
+    `... d > 7` are one traced program."""
+    if isinstance(s, AggregationSpec) and s.filter is not None:
+        return s.fingerprint(_filter_shape_fp(s.filter, info))
+    return s.fingerprint()
+
+
 def _host_info(_name: str) -> ColumnShape:
     """Permissive provider for host-evaluated trees (HAVING runs in reduce
     from the live ctx; nothing it holds is ever traced)."""
@@ -302,12 +313,12 @@ def shape_fingerprint(ctx: QueryContext, column_info: Optional[ColumnInfo] = Non
         "shape1",  # versioned prefix: never collides with full fingerprints
         ctx.table,
         "|".join(j.fingerprint() for j in ctx.joins),
-        "|".join(s.fingerprint() for s in ctx.select_list),
+        "|".join(_select_shape_fp(s, column_info) for s in ctx.select_list),
         _filter_shape_fp(ctx.filter, column_info),
         "|".join(g.fingerprint() for g in ctx.group_by),
         _filter_shape_fp(ctx.having, _host_info),
         "|".join(f"{o.expr.fingerprint()}:{o.ascending}" for o in ctx.order_by),
-        "|".join(a.fingerprint() for a in ctx.extra_aggregations),
+        "|".join(_select_shape_fp(a, column_info) for a in ctx.extra_aggregations),
         "?limit" if ctx.limit is not None else "",
         "?offset",
         str(opts),

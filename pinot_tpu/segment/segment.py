@@ -302,8 +302,13 @@ class ImmutableSegment:
         residency=None,
         prefetch: bool = False,
         query_id: Optional[str] = None,
+        dict_rows: Optional[Dict[str, int]] = None,
     ) -> Dict[str, Any]:
         """Pin column arrays into device memory; returns the segment pytree.
+
+        `dict_rows` ({column: rows}, a plan's `dict_sizes`): the device
+        dictionary ("dict") of each named column is handed out with that
+        many rows, the segment's own values at its head (_with_dict_rows).
 
         The pytree is cached — segments are immutable so repeated queries hit
         HBM-resident arrays.  With `residency` (segment/residency.py) HBM is
@@ -325,6 +330,9 @@ class ImmutableSegment:
         `columns=None` is the whole segment: every column, and every level
         of its star-trees (each a table and a residency group of its own,
         under key "*startree" -> its name); an empty list is no column."""
+        if dict_rows:
+            out = self.to_device(device, columns, packed_codes, residency, prefetch, query_id)
+            return self._with_dict_rows(device, out, dict_rows, packed_codes)
         cols = list(self.columns) if columns is None else columns
         if columns is None and self.indexes.get("startree"):
             out = self.to_device(device, cols, packed_codes, residency, prefetch, query_id)
@@ -396,6 +404,37 @@ class ImmutableSegment:
             out = self._assemble(device, cols, packed_codes)
             if out is not None:
                 return out
+
+    def _with_dict_rows(self, device, out: Dict[str, Any], dict_rows: Dict[str, int], packed_codes: bool):
+        """`out` (to_device's pytree) with every column of `dict_rows` whose
+        device dictionary has another number of rows handed a dictionary of
+        that many: the kernel a plan compiled for the TABLE's shape
+        (segment/table_shape.py) takes a dictionary array of the table's
+        bound, whatever this segment's holds.  The tail repeats the last
+        value; no code reaches it.  The new array takes the old one's place
+        in the device cache, so it is made once a change of the bound (a
+        segment added to or dropped from the table); its few KB are not
+        charged to a residency budget."""
+        import jax
+
+        for cname, rows in dict_rows.items():
+            entry = out.get(cname)
+            have = None if entry is None else entry.get("dict")
+            if have is None or have.shape[0] == rows:
+                continue
+            c = self.columns[cname]
+            dvals = c.dictionary.device_values()
+            if rows > len(dvals):
+                last = dvals[-1] if len(dvals) else 0
+                dvals = np.concatenate([dvals, np.full(rows - len(dvals), last, dvals.dtype)])
+            entry = dict(entry, dict=jax.device_put(dvals[:rows], device))
+            key = f"{cname}#packed" if packed_codes and c.packed is not None else cname
+            with self._device_lock:
+                cache = self._device_cache.get(device)
+                if cache is not None and key in cache:
+                    cache[key] = entry
+            out[cname] = entry
+        return out
 
     def release_device(self) -> None:
         with self._device_lock:

@@ -58,7 +58,7 @@ from pinot_tpu.utils.metrics import METRICS
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # the keys by which a metric's file names something of the program
 SPAN_KEYS = ("spans", "span", "numerator", "denominator")
-REGISTRY_KEYS = ("counter", "prefix", "timer")
+REGISTRY_KEYS = ("counter", "counters", "prefix", "timer")
 
 
 DRILL_LAYER = "wide and sparse group-by"
@@ -68,7 +68,7 @@ STAR_LAYER = "star-tree"
 # whose every plan-cache hit binds its parameters by the entry's recipe
 SOUND_AT_ZERO = {"plan_rebuilds_in_window"}
 # read what only a group-by produces (BENCHMARK.json lists their cells): looked for in QUERIES' group-by alone
-GROUP_BY_ONLY = {"table_decode_cpu_ms"}
+GROUP_BY_ONLY = {"table_decode_cpu_ms", "tables_decoded_per_query", "tables_merged_by_value_per_query"}
 # PR 39's, all among SPECS
 DOOR_SPECS = {
     "frontdoor_accept_wait_ms", "frontdoor_accept_wait_p99_ms", "frontdoor_head_ms", "frontdoor_read_ms",
@@ -202,6 +202,8 @@ def test_program_still_says_what_the_metric_reads(name, served):
             assert counters.get(spec["counter"]) == 0, f"{name}: counter {spec['counter']!r} missing, or moved"
         else:
             assert counters.get(spec["counter"], 0) > 0, f"{name}: counter {spec['counter']!r}"
+    for counter in spec.get("counters", ()):  # a sum of several: each is held, whether it moved or not
+        assert counter in counters, f"{name}: counter {counter!r}"
     if "prefix" in spec:
         family = {k: v for k, v in counters.items() if k.startswith(spec["prefix"])}
         # replication 2 over two servers: the balanced selector routes to both
@@ -515,13 +517,14 @@ def test_the_star_tree_configuration_shares_sf10s_table():
         "closed", 4, ["q2_1", "q2_2", "q2_3", "q3_1", "q4_1"], 40, 3.0)
 
 
-def _toy_window(workload, rows, seed, seconds=1.5):
+def _toy_window(workload, rows, seed, seconds=1.5, events=None):
     """The benchmark's own readers over a traced window of a cell's own
     traffic, through its own set-up, warm-up and load generator, at toy size
     on the CPU: {metric: value} of every per-layer metric `load_cell` gives
     the cell, the cell, its requests.  Only the device's trace is made by
-    hand (this process has no chip): busy seconds and the share of each
-    template in the traced span."""
+    hand (this process has no chip): busy seconds, the share of each
+    template in the traced span and, where a cell's metrics read device
+    events by name, `events` ({name: (count, seconds)}) in the chip's form."""
     import sys
 
     bench_dir = os.path.join(ROOT, "benchmarks")
@@ -544,13 +547,15 @@ def _toy_window(workload, rows, seed, seconds=1.5):
             weights = {t: float(sum(r.template == t for r in reqs)) for t in cell["mix"]["templates"]}
             ctx = {
                 "window_requests": reqs, "faults": {}, "window_s": window["window_s"], "failed_latency_s": 120.0,
-                "timers": dict(cl.timers, setup_s=1.0), "requests": reqs, "counters_before": before,
+                "timers": dict(cl.timers, setup_s=1.0, warm_up_s=1.0), "requests": reqs, "counters_before": before,
                 "counters_after": cl.counters(), "warm_moved": moved, "config": config, "query_set": cell["query_set"],
                 "peak": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9, "name": "TPU v5e"},
-                "device_trace": {"busy_s": 1.0, "window_s": seconds, "events": {}, "chips": 1,
+                "device_trace": {"busy_s": 1.0, "window_s": seconds, "events": events or {}, "chips": 1,
                                  "queries_in_trace": float(len(reqs)), "template_weights": weights},
             }
             values = {m["name"]: harness.metric_value("layer_metrics", m["name"], ctx) for m in cell["per_layer"]}
+            # the untraced line's: they read the requests' clocks and the set-up timer alone
+            assert all(harness.metric_value("end_to_end", m["name"], ctx) is not None for m in cell["end_to_end"])
         finally:
             cl.close()
             planner.plan_cache_clear()
@@ -589,3 +594,42 @@ def test_the_door_and_cpu_metrics_read_a_value_on_a_scan_cells_traffic():
     # one update of rest.doorMs an answered request: the two means of frontdoor_before_accept_ms are over the same requests
     sent = np.mean([r.done - r.sent for r in reqs]) * 1000.0
     assert values["frontdoor_before_accept_ms"] == pytest.approx(sent - values["frontdoor_door_ms"])
+
+
+def test_every_metric_of_the_built_apart_cell_has_a_reader_that_returns_a_value(monkeypatch):
+    """`ssqe_exp001_50seg.aggs_closed` (PR 41): every per-layer metric `load_cell` gives the cell returns a value
+    over a traced window of its own traffic at toy size (4 segments of 10,000 rows, dictionaries of 4 sizes), and
+    every end-to-end metric over the same window untraced-wise (they read the requests' clocks alone); and what
+    the new names say hangs together: one kernel a template, three templates in four table-shaped."""
+    # the three ops that pace the cell, as the v5e compiler names them (an AOT compile of the cell's programs:
+    # `predicate/gather`, `value_transform/gather`, `wide_scatter/scatter-add`), at the toy window's 10,000 rows a
+    # segment; a group key's vector (kLoop) and a table of another size are there to be left out
+    events = {
+        "%fusion.2 = pred[10000]{0:T(1024)(128)(4,1)S(1)} fusion(%copy-done, %pad_clamp_fusion), kind=kCustom, calls=%f": (8, 0.30),
+        "%fusion.6 = s32[10000]{0:T(1024)S(1)} fusion(%copy-done, %pad_clamp_fusion), kind=kCustom, calls=%fused_c": (8, 0.20),
+        "%fusion.19 = s32[81920]{0:T(1024)S(1)} fusion(%fusion.1, %broadcast.55, %constant.7), kind=kCustom, calls=%f": (8, 0.10),
+        "%fusion.1 = s32[10000]{0:T(1024)S(1)} fusion(%bitcast.5, %bitcast.4), kind=kLoop, calls=%fused_computation.4": (8, 5.0),
+        "%fusion.7 = s32[70001]{0:T(1024)S(1)} fusion(%fusion.1, %broadcast.55, %constant.7), kind=kCustom, calls=%f": (8, 5.0),
+    }
+    # the chip's accumulation: a group table past the one-hot kernel's slots is scattered (`scan.traced.wide_scatter`)
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    values, cell, reqs, weights = _toy_window("ssqe_exp001_50seg.aggs_closed", 40_000, 41, events=events)
+    new = {"table_shaped_segments_per_query", "tables_decoded_per_query", "tables_merged_by_value_per_query",
+           "warm_up_compiles_per_template", "warm_up_s", "ssqe_roofline",
+           "in_table_gather_ms", "dict_decode_gather_ms", "table_shaped_scatter_ms"}
+    assert new | {"launches_per_query", "compiles_in_window", "table_decode_cpu_ms", "combined_segments_per_query"} <= set(values)
+    assert DOOR_SPECS <= set(values)
+    assert not [name for name, v in values.items() if v is None], values
+    assert values["compiles_in_window"] == values["plan_rebuilds_in_window"] == 0.0
+    # a kernel and its group program (4 segments: one call of width 4) a template, whatever each dictionary holds
+    assert values["warm_up_compiles_per_template"] == 2.0 and values["launches_per_query"] == 1.0
+    shaped = sum(weights[t] for t in weights if t != "sum_query") / len(reqs)
+    assert 3 * shaped <= values["table_shaped_segments_per_query"] <= 4 * shaped  # the segment that holds a bound may be its own shape
+    assert values["tables_decoded_per_query"] == values["tables_merged_by_value_per_query"] == 4.0  # group_low_high's
+    assert values["combined_segments_per_query"] == 0.0 and 0.0 < values["ssqe_roofline"] < 100.0
+    # each op's time over ITS template's traced queries, nothing of the kLoop vector or the other table
+    assert values["in_table_gather_ms"] == pytest.approx(300.0 / weights["count_in"])
+    assert values["dict_decode_gather_ms"] == pytest.approx(200.0 / weights["filtered_query"])
+    assert values["table_shaped_scatter_ms"] == pytest.approx(100.0 / weights["group_low_high"])
+    assert {m["name"] for m in cell["end_to_end"]} == {"throughput_qps", "latency_p50_ms", "latency_p95_ms", "setup_s"}

@@ -230,8 +230,13 @@ class TestCostEstimation:
 # ---------------------------------------------------------------------------
 class TestOverloadAcceptance:
     def test_3x_offered_load_sheds_structured_and_keeps_admitted_latency(self):
-        import time
-
+        """The mechanism, on the injected clock: which arrivals the bucket
+        admits is arithmetic (no host speed in it), a shed is structured and
+        costs the servers nothing (no launch, no reservation), and every
+        admitted query runs whole under its budgets.  What it no longer
+        compares is two wall-clock p99s of ~30 requests each: that ratio
+        measured the host's scheduling, and was the one red test of the
+        driver's runs of PR 31, 36, 37 and 40 (ROADMAP C9)."""
         host_budget_bytes = 1 << 30
         server_budget_bytes = 64 << 20
         coord = _cluster(server_budget=server_budget_bytes)
@@ -239,15 +244,7 @@ class TestOverloadAcceptance:
         for i in range(3):
             broker.query(_sql(i))  # warm: parse/plan/compile
 
-        # uncontended baseline (env-default governor: admission off)
-        base_ms = []
-        for i in range(30):
-            t0 = time.perf_counter()
-            broker.query(_sql(i))
-            base_ms.append((time.perf_counter() - t0) * 1000)
-        uncontended_p99 = float(np.percentile(base_ms, 99))
-        capacity_qps = 1000.0 / float(np.median(base_ms))
-
+        capacity_qps = 100.0  # what the bucket is told the cluster sustains: any rate does, the clock is simulated
         unit_cost = estimate_query_cost(
             parse_query(_sql()), coord.tables["t"].segment_meta.values()
         ).units
@@ -261,25 +258,39 @@ class TestOverloadAcceptance:
         gov.admission.clock = lambda: sim[0]
         broker.governor = gov
 
+        def served():
+            return sum(s.metrics.counter("server.queries").value for s in coord.servers.values())
+
         offered_qps = 3.0 * capacity_qps
-        admitted, admitted_ms, shed_ids = 0, [], []
+        shed0, admitted0 = METRICS.counter("admission.shed").value, METRICS.counter("admission.admitted").value
+        admitted, shed_ids, outcomes, served_by_sheds = 0, [], [], 0
         for i in range(90):
             sim[0] += 1.0 / offered_qps
-            t0 = time.perf_counter()
+            before = served()
             try:
-                broker.query(_sql(i))
+                out = broker.query(_sql(i))
             except TooManyRequestsError as e:
                 shed_ids.append(e.query_id)
+                outcomes.append(False)
+                served_by_sheds += served() - before
             else:
                 admitted += 1
-                admitted_ms.append((time.perf_counter() - t0) * 1000)
+                outcomes.append(True)
+                # an admitted query is answered whole: every segment, no degradation
+                assert not out.stats.partial_result and out.stats.num_segments_processed == 4
 
         # sheds happened, were structured, and carried the minted query id
-        assert shed_ids and all(qid for qid in shed_ids)
-        # bucket math: ~1/3 admitted at 3x offered load (plus the burst)
-        assert 90 // 3 <= admitted <= 90 // 3 + int(2 * unit_cost / unit_cost) + 2
-        # admitted queries are NOT degraded by the shed traffic
-        assert float(np.percentile(admitted_ms, 99)) <= 2.0 * uncontended_p99
+        assert shed_ids and all(qid for qid in shed_ids) and len(set(shed_ids)) == len(shed_ids)
+        # the bucket's own arithmetic on the simulated arrivals: the burst's 2 units first, then a third of a unit
+        # back an arrival, so one arrival in three is admitted (a rounding of the refill may make a gap of three)
+        assert outcomes[:2] == [True, True]
+        at = [i for i, ok in enumerate(outcomes) if ok]
+        assert all(3 <= b - a <= 4 for a, b in zip(at[2:], at[3:])) and at[-1] >= 90 - 4
+        assert 90 // 3 <= admitted <= 90 // 3 + 2 + 2  # ~1/3 admitted at 3x offered load (plus the burst)
+        assert METRICS.counter("admission.shed").value - shed0 == len(shed_ids)
+        assert METRICS.counter("admission.admitted").value - admitted0 == admitted
+        # a shed query reaches no server: the admitted ones are not charged for the traffic that was turned away
+        assert served_by_sheds == 0
         # reservations never exceeded any budget (gauge-backed high-water)
         assert 0 < gov.host_budget.peak <= host_budget_bytes
         for name in ("server0", "server1"):

@@ -7,6 +7,8 @@ updates — shared by the SSE planner, the distributed engine and the MSE.
 from pinot_tpu.ops.segmented import (  # noqa: F401
     accum_policy,
     fused_group_tables,
+    int_sum_entry,
+    limb_scatter_table,
     sum_limb_plan,
     sum_limb_plan64,
     group_count,

@@ -224,6 +224,25 @@ def sum_limb_plan64(vmin, vmax) -> int:
     return 8
 
 
+def int_sum_entry(values, vrange):
+    """(kind, values, limb_plan) for the exact limb sum of an integer row
+    array whose column stats, where it is a bare column, say it lies in
+    `vrange` = (min, max): "int_sum" with sum_limb_plan for int32 and
+    narrower (an int64 column that stats prove inside int32 is narrowed),
+    "int64_sum" with sum_limb_plan64 past it; without stats the storage
+    type's full width.  None where the values are not integers."""
+    if not jnp.issubdtype(values.dtype, jnp.integer):
+        return None
+    if values.dtype.itemsize > 4 and vrange is not None and -(1 << 31) <= vrange[0] and vrange[1] < (1 << 31):
+        values = values.astype(jnp.int32)  # stats prove int32 narrowing safe
+    if values.dtype.itemsize <= 4:
+        return "int_sum", values, sum_limb_plan(*vrange) if vrange is not None else (4, True)
+    # wide-range int64: signed-magnitude limb decomposition, bit-exact
+    # while sum(|v|) < 2^53 — the reference's double-accumulate contract
+    # (SumAggregationFunction)
+    return "int64_sum", values, sum_limb_plan64(*vrange) if vrange is not None else 8
+
+
 def _int64_magnitude_halves(values, mask):
     """int64 values (masked rows 0) -> (low, high) uint32 halves of |v| and
     the row's sign as int32 +-1, every op 32-bit: the column is bitcast to
@@ -536,9 +555,10 @@ def _fused_group_tables_xla(entries, codes, num_groups: int, mask_words, codes_p
 # Wide group tables (chunked32, num_groups > _MATMUL_MAX_GROUPS)
 # ---------------------------------------------------------------------------
 # Past _MATMUL_MAX_GROUPS the one-hot matrices stop paying and rows scatter.
-# The chip scatters 32-bit words at ~7 ns a row and 64-bit ones (emulated)
-# twenty times slower, and an f32 table loses a unit as soon as a slot
-# passes 2^24 (SSB Q3.2 on the chip, PERF.md PR 31).  So an integer rides as
+# The chip scatters 32-bit words at 7-9 ns a row and 64-bit ones (emulated:
+# a pair of 32-bit halves) at 85-94, and an f32 table loses a unit as soon as
+# a slot passes 2^24 (SSB Q3.2 on the chip, PERF.md PR 31; PR 42 for the
+# sparse plan's slot tables, which take this form too).  So an integer rides as
 # 12-bit limbs, each scattered into an int32 table of its own over chunks of
 # 2^19 rows: a chunk's limb sum is at most 2^19 * (2^12 - 1) < 2^31, exact;
 # chunks and limbs recombine in int64 at table size, exact while the group's
@@ -594,6 +614,22 @@ def _wide_int_limbs(kind, values, mask, limb_plan):
     return out
 
 
+def limb_scatter_table(kind, values, mask, limb_plan, codes, num_groups: int):
+    """The exact table of ONE "count" / "int_sum" / "int64_sum" entry by
+    int32 scatters alone, the form described above: int32[num_groups] for a
+    count (a sum of ones: no chunk can overflow), int64 for a sum (one
+    table a 12-bit limb over 2^19-row chunks, chunks and limbs met at table
+    size).  `codes` are int32 in [0, num_groups).  indices_are_sorted buys
+    nothing on the chip where they are (a sparse plan's slots after its
+    sort: 14.02 against 14.05 ms a 1.5M-row scatter, PERF.md PR 42)."""
+    if kind == "count":
+        return _chunked_scatter(mask.astype(jnp.int32), codes, num_groups, codes.shape[0])[0]
+    return sum(
+        _chunked_scatter(limb, codes, num_groups, _WIDE_CHUNK).astype(jnp.int64).sum(axis=0) << np.int64(shift)
+        for limb, shift in _wide_int_limbs(kind, values, mask, limb_plan)
+    )
+
+
 def _wide_group_tables(entries, codes, num_groups: int):
     """fused_group_tables for a table past _MATMUL_MAX_GROUPS under
     chunked32: one int32 (or, for floats, f32) scatter a limb column, the
@@ -605,16 +641,8 @@ def _wide_group_tables(entries, codes, num_groups: int):
     out = []
     with jax.named_scope("wide_scatter"):
         for kind, values, mask, limb_plan in entries:
-            if kind == "count":
-                # a count is a sum of ones: no chunk can overflow int32
-                t = _chunked_scatter(mask.astype(jnp.int32), codes, num_groups, codes.shape[0])
-                out.append(t[0].astype(jnp.float64))
-            elif kind in ("int_sum", "int64_sum"):
-                total = sum(
-                    _chunked_scatter(limb, codes, num_groups, _WIDE_CHUNK).astype(jnp.int64).sum(axis=0) << np.int64(shift)
-                    for limb, shift in _wide_int_limbs(kind, values, mask, limb_plan)
-                )
-                out.append(total.astype(jnp.float64))
+            if kind in ("count", "int_sum", "int64_sum"):
+                out.append(limb_scatter_table(kind, values, mask, limb_plan, codes, num_groups).astype(jnp.float64))
             else:
                 v = values.astype(jnp.float32)
                 v = jnp.where(mask, v * v if kind == "f32_sumsq" else v, np.float32(0.0))

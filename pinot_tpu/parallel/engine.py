@@ -885,6 +885,7 @@ class DistributedEngine:
             # devices rank by local partials — the same accuracy valve as
             # the reference's server-side numGroupsLimit trim)
             order_spec = planner_mod.kernel_order_spec(ctx, aggs)
+            vranges = planner_mod.agg_vranges(agg_specs, stacked)
 
             def shard_kernel(cols, params):
                 cols = _flat(cols)
@@ -896,7 +897,7 @@ class DistributedEngine:
                 inputs = _agg_inputs(cols, params, tmask)
                 return planner_mod.sparse_grouped_tables(
                     aggs, inputs, tmask, key, num_slots, order_spec,
-                    num_groups=num_groups,
+                    num_groups=num_groups, vranges=vranges,
                 )
 
             out_specs = P(self.axis)

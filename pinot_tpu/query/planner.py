@@ -925,23 +925,9 @@ def grouped_partials(aggs, inputs, tmask, key, num_groups: int, vranges,
             if kind == "count":
                 fmap[field] = ("fused", entry_slot("count", None, mask))
             elif kind == "sum":
-                v = vals
-                is_int = jnp.issubdtype(v.dtype, jnp.integer)
-                rng = vranges[i] if i < len(vranges) else None
-                if is_int and v.dtype.itemsize > 4 and rng is not None and (
-                    -(1 << 31) <= rng[0] and rng[1] < (1 << 31)
-                ):
-                    v = v.astype(jnp.int32)  # stats prove int32 narrowing safe
-                    is_int = True
-                if is_int and v.dtype.itemsize <= 4:
-                    lp = ops.sum_limb_plan(*rng) if rng is not None else (4, True)
-                    fmap[field] = ("fused", entry_slot("int_sum", v, mask, lp))
-                elif is_int:
-                    # wide-range int64: signed-magnitude limb decomposition,
-                    # bit-exact while sum(|v|) < 2^53 — the reference's
-                    # double-accumulate contract (SumAggregationFunction)
-                    nl = ops.sum_limb_plan64(*rng) if rng is not None else 8
-                    fmap[field] = ("fused", entry_slot("int64_sum", v, mask, nl))
+                ent = ops.int_sum_entry(vals, vranges[i] if i < len(vranges) else None)
+                if ent is not None:
+                    fmap[field] = ("fused", entry_slot(ent[0], ent[1], mask, ent[2]))
                 else:
                     fmap[field] = ("fused", entry_slot("f32_sum", vals, mask))
             elif kind == "sumsq":
@@ -1047,7 +1033,7 @@ def packed_key64(cols, group_dims, segment) -> jnp.ndarray:
 
 
 def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=None,
-                          num_groups: Optional[int] = None):
+                          num_groups: Optional[int] = None, vranges=()):
     """Device-side high-cardinality group-by: sort + segment-scatter into
     FIXED-size tables (the IndexedTable analog with numGroupsLimit trim
     built into the kernel).
@@ -1064,8 +1050,21 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
 
     Accumulation dtypes mirror the host reduce contracts: counts int64,
     sums/sumsq float64 (exact for int sums < 2^53 — the reference likewise
-    accumulates long sums in double), min/max float64.  This path is
-    scatter/HBM-bound, not MXU-bound, so f64 costs little on TPU here.
+    accumulates long sums in double), min/max float64.  What the tables
+    cost is their scatters, and the chip holds a 64-bit array as a pair of
+    32-bit halves: over a 1.5M-row segment a 64-bit scatter is ~128 ms
+    there (85 ns a row) and an int32 one 14.0 (9.3 ns a row after the
+    sort, 7.3 on random indices), so SSB Q4.3's key, count and sum cost
+    404-416 ms a segment as three 64-bit scatters and 89 as six int32 ones
+    (PERF.md, PR 42).  So the keys of a key space under 2^31 scatter as
+    the int32 they were sorted as, and under accum_policy() "chunked32" the
+    counts and the sums of integer inputs scatter as int32 tables
+    (ops.limb_scatter_table: a sum as 12-bit limbs over 2^19-row chunks,
+    met in int64 at table size, exact; `vranges`, from agg_vranges,
+    shrinks a bare column's limb count by its stats); all are widened at
+    table size.  Float sums, sumsq, min / max and the sketch family keep
+    the 64-bit scatters: an f32 table would be a lower precision than
+    this path states.
 
     num_groups, when given, is the static size of the key space (every key
     is < num_groups).  It buys two things the TPU cares about — a 64-bit or
@@ -1103,7 +1102,10 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
         else:
             sov = None
             skey, perm = lax.sort((krow, iota), num_keys=1)
-        smask = tmask[perm]
+        # a filtered row sorted as the sentinel and every real key is below it:
+        # tmask[perm] without the row-length gather (~13 ms a 1.5M-row segment on the chip)
+        no_key = i32_max if skey.dtype == jnp.int32 else SPARSE_EMPTY_KEY
+        smask = skey != no_key
         prev = jnp.concatenate([jnp.full((1,), -1, skey.dtype), skey[:-1]])
         is_start = smask & (skey != prev)
         seg = jnp.cumsum(is_start.astype(jnp.int32)) - 1
@@ -1178,15 +1180,17 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
             )
             gslot = ranks[jnp.minimum(seg, np.int32(n))]
             slot = jnp.where(smask & (gslot < num_slots), gslot, num_slots)
+    # the chip's form: counts and integer sums as int32 tables, widened at table size (docstring)
+    limbs = ops.accum_policy() == "chunked32"
     with jax.named_scope("sparse_scatter"):
-        uniq = (
-            jnp.full((num_slots + 1,), SPARSE_EMPTY_KEY, dtype=jnp.int64)
-            .at[jnp.where(is_start, slot, num_slots)]
-            .set(skey.astype(jnp.int64))
-        )
+        # the keys scatter as they were sorted: ONE int32 table for a key space under 2^31
+        uniq = jnp.full((num_slots + 1,), no_key, dtype=skey.dtype).at[jnp.where(is_start, slot, num_slots)].set(skey)
+        if uniq.dtype != jnp.int64:
+            uniq = jnp.where(uniq == no_key, SPARSE_EMPTY_KEY, uniq.astype(jnp.int64))
         partials = []
-        for fn, (vals, mask) in zip(aggs, inputs):
-            m = mask[perm]
+        limb_sums = False
+        for i, (fn, (vals, mask)) in enumerate(zip(aggs, inputs)):
+            m = smask if mask is tmask else mask[perm]
 
             def _perm(x):
                 x = x if getattr(x, "ndim", 0) else jnp.broadcast_to(x, (n,))
@@ -1203,12 +1207,19 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
                 partials.append({f: t[:num_slots] for f, t in own.items()})
                 continue
             v = _perm(vals)
+            ent = ops.int_sum_entry(v, vranges[i] if i < len(vranges) else None) if limbs else None
             p: Dict[str, Any] = {}
             for fname in fn.fields:
                 comb = FIELD_COMBINE[fname]
                 if comb == "add":
-                    if fname == "count":
+                    if fname == "count" and limbs:
+                        acc = ops.limb_scatter_table("count", None, m, None, slot, num_slots + 1).astype(jnp.int64)
+                    elif fname == "count":
                         acc = jnp.zeros((num_slots + 1,), jnp.int64).at[slot].add(m.astype(jnp.int64))
+                    elif fname == "sum" and ent is not None:
+                        kind, lv, lp = ent
+                        acc = ops.limb_scatter_table(kind, lv, m, lp, slot, num_slots + 1).astype(jnp.float64)
+                        limb_sums = True
                     else:
                         w = v.astype(jnp.float64)
                         if fname == "sumsq":
@@ -1221,6 +1232,10 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
                     acc = base.at[slot].min(masked) if comb == "min" else base.at[slot].max(masked)
                 p[fname] = acc[:num_slots]
             partials.append(p)
+    if limb_sums:
+        # trace time: this plan's integer sums rode int32 limb tables (its keys
+        # and counts are int32 tables under chunked32 whatever it sums)
+        METRICS.counter("scan.traced.sparse_limb_scatter").inc()
     return uniq[:num_slots], partials
 
 
@@ -1873,6 +1888,7 @@ def _build_plan(
             raise NotImplementedError("composite group key exceeds 62 bits")
         num_slots = min(ctx.num_groups_limit, num_groups)
         order_spec = kernel_order_spec(ctx, aggs)
+        vranges = agg_vranges(agg_specs, segment)
 
         if mv_i is not None:
 
@@ -1880,7 +1896,7 @@ def _build_plan(
                 tmask, _ = filter_fn(cols, params)
                 key, t_f, inputs = _mv_explode(cols, params, tmask, jnp.int64)
                 return sparse_grouped_tables(aggs, inputs, t_f, key, num_slots, order_spec,
-                                             num_groups=num_groups)
+                                             num_groups=num_groups, vranges=vranges)
 
         else:
 
@@ -1889,7 +1905,7 @@ def _build_plan(
                 key = packed_key64(cols, group_dims, segment)
                 inputs = _agg_inputs(cols, params, tmask)
                 return sparse_grouped_tables(aggs, inputs, tmask, key, num_slots, order_spec,
-                                             num_groups=num_groups)
+                                             num_groups=num_groups, vranges=vranges)
 
     elif kind == "selection":
 

@@ -205,6 +205,29 @@ def test_sparse_group_tables_compile_for_v5e(one_chip, no_compile_cache):
     jax.jit(kernel).lower(shape(jnp.int64), shape(jnp.bool_), shape(jnp.int64)).compile()
 
 
+def test_sparse_slot_tables_are_int32_scatters_for_v5e(one_chip, no_compile_cache, monkeypatch):
+    """SSB Q4.3's per-segment sparse kernel under the chip's policy (PR 42): 1.5M rows, 100,000 slots of a
+    1,750,000-key space, an int32 expression summed.  After the sort every slot table is ONE int32 scatter
+    (the keys, the count, three 12-bit limbs and the negatives' count), none a tuple of 32-bit halves (the
+    emulated 64-bit scatter, ~14 times the time a row)."""
+    from pinot_tpu.query import planner
+    from pinot_tpu.query.functions import get_agg_function
+
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    n, slots, groups = 1_500_000, 100_000, 1_750_000
+    sum_fn = get_agg_function("sum")
+
+    def shape(dt):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+
+    def kernel(vals, mask, key):
+        return planner.sparse_grouped_tables([sum_fn], [(vals, mask)], mask, key, slots, None, num_groups=groups)
+
+    text = jax.jit(kernel).lower(shape(jnp.int32), shape(jnp.bool_), shape(jnp.int64)).compile().as_text()
+    scatters = re.findall(r"^\s*%[\w.\-]+ = (\(?\w+\[\d+\])\S* fusion\([^\n]*kind=kCustom[^\n]*sparse_scatter/scatter", text, re.M)
+    assert sorted(scatters) == ["s32[100001]"] * 2 + ["s32[300003]"] * 4, scatters
+
+
 def test_engine_dense_groupby_step_compiles_for_v5e(topo, no_compile_cache, monkeypatch):
     """One single-device DistributedEngine step of the headline query — word-
     fused filter, 16-bit packed key, int64 limbs — traced as the chip traces

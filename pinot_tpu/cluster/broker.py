@@ -1040,7 +1040,7 @@ class Broker:
         ShapeStats accounting — the tail every served query (sync or batch
         member) runs through."""
         with trace.span("reduce", cpu=True) as rsp:
-            out = reduce_mod.reduce_results(ctx, results, stats)
+            out = reduce_mod.reduce_results(ctx, results, stats, trace)
             if rsp is not None and ctx.group_by:
                 # the group tables merged by value, 0 where they aligned
                 rsp.annotate(tablesByValue=stats.tables_merged_by_value)

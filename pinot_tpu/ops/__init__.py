@@ -21,6 +21,8 @@ from pinot_tpu.ops.segmented import (  # noqa: F401
     masked_min,
     masked_sum,
     masked_sum_sq,
+    sketch_count_table,
+    sketch_max_table,
     unpack_bitmap_words,
 )
 from pinot_tpu.ops.pallas_scan import (  # noqa: F401

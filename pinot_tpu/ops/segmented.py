@@ -717,6 +717,9 @@ def group_min(values, mask, codes, num_groups: int):
 
 
 def group_max(values, mask, codes, num_groups: int):
+    """f64[num_groups]; -inf where a group matched no rows.  chunked32: an
+    f32 scatter, as group_min: exact for integers below 2^24, rounded past
+    it.  A sketch's registers do not ride it (sketch_max_table)."""
     codes = _i32(codes)
     if accum_policy() == "wide":
         v = jnp.where(mask, values.astype(jnp.float64), jnp.float64(-np.inf))
@@ -724,6 +727,42 @@ def group_max(values, mask, codes, num_groups: int):
     v = jnp.where(mask, values.astype(jnp.float32), _NEG_INF32)
     out = _scatter_extreme(jnp.full((num_groups,), _NEG_INF32), codes, v, is_min=False)
     return out.astype(jnp.float64)
+
+
+# ---------------------------------------------------------------------------
+# Sketch tables: [groups x width] cells of small integers (query/sketches.py)
+# ---------------------------------------------------------------------------
+def _sketch_scatter_traced() -> None:
+    from pinot_tpu.utils.metrics import METRICS
+
+    METRICS.counter("scan.traced.sketch_scatter").inc()  # trace time: this plan's sketch table took the scatter form
+
+
+def sketch_max_table(values, mask, cells, num_cells: int):
+    """int32[num_cells]: the largest of `values` (int32, >= 0: HyperLogLog's
+    rho) over the mask-true rows of each cell, 0 where a cell has none.  ONE
+    int32 scatter-max under either policy: a register is a small integer and
+    never rides a float."""
+    _sketch_scatter_traced()
+    with jax.named_scope("sketch_scatter"):
+        v = jnp.where(mask, values.astype(jnp.int32), np.int32(0))
+        return _scatter_extreme(jnp.zeros((num_cells,), jnp.int32), _i32(cells), v, is_min=False)
+
+
+def sketch_count_table(mask, cells, num_cells: int):
+    """int64[num_cells]: the mask-true rows of each cell (a histogram's
+    bins).  Exact integers: a table the one-hot matmul holds
+    (_MATMUL_MAX_GROUPS) is group_count's; past it ONE scatter-add, int32
+    under chunked32 (a cell of one call holds at most its rows, far under
+    2^31) and widened at table size, so that tables can meet by addition
+    across any number of segments."""
+    if num_cells <= _MATMUL_MAX_GROUPS:
+        return group_count(mask, cells, num_cells)
+    _sketch_scatter_traced()
+    with jax.named_scope("sketch_scatter"):
+        dt = jnp.int64 if accum_policy() == "wide" else jnp.int32
+        table = _scatter_add(jnp.zeros((num_cells,), dt), _i32(cells), mask.astype(dt))
+        return table.astype(jnp.int64)
 
 
 # ---------------------------------------------------------------------------

@@ -481,9 +481,11 @@ def collect_group(state, check=None, trace: Optional[Trace] = None):
     and the others' is None.  Returns [(result, stats)] a member,
     and calls `check` (QueryLaunches) before each decode after the first.
     A group-by's decodes sit in a span `table_decode` of `trace`: the
-    fetched tables' bytes (`tableBytes`), the tables decoded (`tables`), the
-    slots of a table (`keySpace`: the dense key space, or the sparse table's
-    fixed size) and the groups the decoded tables held (`groups`)."""
+    fetched tables' bytes (`tableBytes`), of which the vector fields' (a
+    sketch's [groups, m] registers or bins: `sketchBytes`, 0 for scalar
+    fields), the tables decoded (`tables`), the slots of a table (`keySpace`:
+    the dense key space, or the sparse table's fixed size) and the groups
+    the decoded tables held (`groups`)."""
     import jax
 
     _, ctx, segments, plans, out, stats_list, rewrite, combined = state
@@ -505,8 +507,10 @@ def collect_group(state, check=None, trace: Optional[Trace] = None):
                 answer = (rewrite.restore(answer[0]), stats)
             answers.append(answer)
         if tsp is not None:
+            slots = np.ndim(host[0])  # the presence / key table's axes: a field with more holds a vector a slot
             tsp.annotate(
                 tableBytes=sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(host)),
+                sketchBytes=sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(host[1]) if np.ndim(a) > slots),
                 tables=1 if one else len(segments),
                 keySpace=plan.num_groups if plan.kind == "groupby_dense" else min(plan.num_groups, ctx.num_groups_limit),
                 groups=sum(stats.num_groups for stats in stats_list),

@@ -92,6 +92,17 @@ class AggFunction:
     # dense group-by scan (ops.fused_group_tables); None = the function's own
     # partial_grouped runs instead (sketch family)
     field_kinds = None
+    # an own-scatter function (field_kinds None) whose fields still meet by
+    # NAME (FIELD_COMBINE) whatever segment made them: a group program folds
+    # its members' tables into one on the chip (planner.combines)
+    fold_by_field: bool = False
+    # what bind_column takes from the SEGMENT, which the plan cache must key
+    # on (planner.sketch_bound_columns): "column" (its dictionary and its
+    # stats), "value_hash" (nothing of a numeric column: its values are
+    # hashed on the device; a string dictionary's per-code hash tables),
+    # "range" (the column's [min, max] alone: the table's where the engine
+    # injected one, `__range__<col>`, which the shape fingerprint holds)
+    binds: str = "column"
 
     # -- binding (sketch functions override; see query/sketches.py) ------
     def with_args(self, literal_args) -> "AggFunction":

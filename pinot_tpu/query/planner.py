@@ -232,14 +232,17 @@ def _join(xs):
 
 def combines(plan: SegmentPlan) -> bool:
     """Whether the tables of `plan`'s kernel fold into one elementwise: a
-    dense group-by whose every aggregation says its fields' kinds, so each
-    field combines by its NAME (FIELD_COMBINE: add / min / max).  A sketch or
-    own-scatter function (`field_kinds` None) and a pairwise merge (coupled
+    dense group-by whose every aggregation says its fields' kinds, or is an
+    own-scatter function that says its fields meet by name whatever segment
+    made them (`fold_by_field`: HLL's registers, a histogram's bins, [groups,
+    m] tables), so each field combines by its NAME (FIELD_COMBINE: add / min /
+    max).  Any other own-scatter function (`field_kinds` None: its cells may
+    be indexed by a segment's own codes) and a pairwise merge (coupled
     fields: LASTWITHTIME's (t, v)) do not."""
     return plan.kind == "groupby_dense" and all(
-        fn.field_kinds is not None
+        (fn.field_kinds is not None or fn.fold_by_field)
         and not fn.pairwise_merge
-        and all(f in FIELD_COMBINE for f in fn.field_kinds)
+        and all(f in FIELD_COMBINE for f in fn.fields)
         for fn in plan.aggs
     )
 
@@ -247,13 +250,22 @@ def combines(plan: SegmentPlan) -> bool:
 def _identity_tables(shapes):
     """The dense group-by's (presence, partials) that changes nothing when a
     member's tables fold into it: zeros, and a min / max field's identity
-    (those fields are f64: ops.group_min / group_max).  Host arrays: made
-    once a program and device, and an eager device op a field would be a
-    small compile of its own in every process."""
+    (+-inf for the float fields of ops.group_min / group_max and a
+    histogram's range, the type's own bound for an integer field: HLL's
+    registers are int32).  Host arrays: made once a program and device, and
+    an eager device op a field would be a small compile of its own in every
+    process."""
     presence, partials = shapes
+
+    def identity(f, like):
+        if FIELD_COMBINE[f] != "add" and np.issubdtype(like.dtype, np.integer):
+            bound = np.iinfo(like.dtype)
+            return bound.max if FIELD_COMBINE[f] == "min" else bound.min
+        return field_identity(f)
+
     return (
         np.zeros(presence.shape, presence.dtype),
-        [{f: np.full(like.shape, field_identity(f), like.dtype) for f, like in p.items()} for p in partials],
+        [{f: np.full(like.shape, identity(f, like), like.dtype) for f, like in p.items()} for p in partials],
     )
 
 
@@ -466,10 +478,14 @@ def _segment_signature(
             raw_range = (
                 (_sig_value(c.stats.min_value), _sig_value(c.stats.max_value)) if c.stats.num_docs else (0, 0)
             )
-        # Sketch-bound columns bake DICTIONARY-DERIVED constants (HLL hash
-        # tables, histogram edges) into the compiled kernel as closure
+        # Sketch-bound columns bake DICTIONARY-DERIVED constants (a string
+        # column's HLL hash tables, a presence domain, histogram edges taken
+        # from the segment's own stats) into the compiled kernel as closure
         # constants — the exact dictionary must be part of the cache key or
         # a same-shaped segment silently reuses another segment's tables.
+        # `sketch_cols` holds only the columns that do (baked_columns): a
+        # sketch that hashes a numeric column's VALUES on the device, or
+        # bins by the table's injected range, bakes nothing of a segment.
         sketch_extra = None
         if name in sketch_cols:
             sketch_extra = (
@@ -503,13 +519,47 @@ def _segment_signature(
     return tuple(sig)
 
 
-def sketch_bound_columns(ctx: QueryContext) -> frozenset:
-    """Columns whose sketch bindings bake per-segment constants into kernels."""
-    out = set()
+def _sketch_binds(ctx: QueryContext):
+    """(column, what the function's bind_column takes from a segment:
+    AggFunction.binds) of every column-bound sketch aggregation."""
     for spec in ctx.aggregations:
-        if spec.expr is not None and spec.expr.is_column and for_spec(spec).needs_binding:
-            out.add(spec.expr.op)
-    return frozenset(out)
+        if spec.expr is not None and spec.expr.is_column:
+            fn = for_spec(spec)
+            if fn.needs_binding:
+                yield spec.expr.op, fn.binds
+
+
+def sketch_bound_columns(ctx: QueryContext) -> frozenset:
+    """Columns whose sketch bindings bake per-segment constants into kernels
+    whatever the segment holds.  Not among them: a column under a VALUE-hash
+    sketch alone (value_hashed_columns), and one whose function takes the
+    column's range alone where the engine injected the TABLE's
+    (`__range__<col>`, which the shape fingerprint holds among the options):
+    every segment then binds alike."""
+    return frozenset(
+        col for col, binds in _sketch_binds(ctx)
+        if binds != "value_hash" and (binds != "range" or f"__range__{col}" not in ctx.options)
+    )
+
+
+def value_hashed_columns(ctx: QueryContext) -> frozenset:
+    """Columns under a VALUE-hash sketch: they bake a segment's dictionary
+    only where it is a string's (baked_columns)."""
+    return frozenset(col for col, binds in _sketch_binds(ctx) if binds == "value_hash")
+
+
+def baked_columns(segment, bound: frozenset, hashed: frozenset) -> frozenset:
+    """The columns whose dictionary VALUES `segment`'s kernel bakes: `bound`,
+    and of `hashed` (value_hashed_columns) the string columns: their values
+    never reach the device, so sketches.DistinctCountHLLFunction hashes the
+    dictionary on the host into tables the kernel closes over.  A numeric
+    column is hashed on the device by value and bakes nothing."""
+    strings = [
+        name for name in hashed
+        for c in [segment.columns.get(name)]
+        if c is not None and c.has_dictionary and c.data_type.is_string_like
+    ]
+    return bound | frozenset(strings) if strings else bound
 
 
 def const_bound_columns(ctx: QueryContext) -> frozenset:
@@ -759,18 +809,20 @@ def column_binding(spec, segment, ctx: Optional[QueryContext] = None):
             mn, mx = rng
         aligned = ctx.options.get(f"__dictfp__{e.op}", "") != "MIXED"
     dict_values = c.dictionary.values if c.has_dictionary else None
+    numeric = not c.data_type.is_string_like
+    wide = numeric and c.data_type.np_dtype.itemsize == 8
     if c.has_dictionary and aligned:
         return ColumnBinding(
             "dict", domain=c.dictionary.cardinality, dict_values=dict_values,
-            min_value=mn, max_value=mx,
+            numeric=numeric, wide=wide, min_value=mn, max_value=mx,
         )
     if c.data_type in (DataType.INT, DataType.LONG, DataType.TIMESTAMP, DataType.BOOLEAN) and mn is not None:
         rng_width = int(mx) - int(mn) + 1
         if rng_width <= MAX_DENSE_RAW_INT_RANGE:
-            return ColumnBinding("rawint", domain=rng_width, base=int(mn), min_value=mn, max_value=mx)
+            return ColumnBinding("rawint", domain=rng_width, base=int(mn), wide=wide, min_value=mn, max_value=mx)
     # dict_values still flow through: value-based host hashing (HLL) stays
     # correct across misaligned dictionaries
-    return ColumnBinding("raw", dict_values=dict_values, min_value=mn, max_value=mx)
+    return ColumnBinding("raw", dict_values=dict_values, numeric=numeric, wide=wide, min_value=mn, max_value=mx)
 
 
 def bind_aggs(agg_specs, segment, ctx: QueryContext):
@@ -1371,7 +1423,8 @@ class _SegmentMemo:
 
     def __init__(self, state):
         self.state = state
-        # (predicate columns, needed columns, bound columns, group columns,
+        # (predicate columns, needed columns, bound columns, value-hashed
+        # columns, group columns,
         # the table shape asked and its version) ->
         # (the predicate columns' ColumnShapes, _segment_signature, the
         # predicate columns' _dictionary_identity, compiled_dict_sizes,
@@ -1434,6 +1487,7 @@ class QueryPlanning:
         self._sources: Dict[int, Tuple] = {}  # id(segment) -> source(segment)
         self._checked = False  # check_plan_cached(ctx) has passed: at the first plan
         self.bound_cols = sketch_bound_columns(ctx) | const_bound_columns(ctx)
+        self.hashed_cols = value_hashed_columns(ctx) - self.bound_cols
         self.group_cols = frozenset(c for g in ctx.group_by for c in g.columns())
         # the query's predicates in the order _build_plan compiles them:
         # WHERE's, then each aggregation's FILTER clause's
@@ -1505,16 +1559,17 @@ class QueryPlanning:
         under = None if shape is None else (id(shape), shape.version)
         half_key = self._half_key if needed is self._needed and under == self._half_under else None
         if half_key is None:
-            half_key = (self.predicate_cols, tuple(needed), self.bound_cols, self.group_cols, under)
+            half_key = (self.predicate_cols, tuple(needed), self.bound_cols, self.hashed_cols, self.group_cols, under)
             if needed is self._needed:
                 self._half_key, self._half_under = half_key, under
         half = memo.halves.get(half_key)
         if half is None:
             info = column_info_from(segment)
-            sizes = compiled_dict_sizes(segment, needed, self.bound_cols, shape)
+            baked = baked_columns(segment, self.bound_cols, self.hashed_cols)
+            sizes = compiled_dict_sizes(segment, needed, baked, shape)
             half = (
                 tuple([info(c) for c in self.predicate_cols if c is not None]),
-                _segment_signature(segment, needed, self.bound_cols, self.group_cols, sizes),
+                _segment_signature(segment, needed, baked, self.group_cols, sizes),
                 tuple([_dictionary_identity(segment, c) for c in self.predicate_cols]),
                 sizes,
                 any(size > segment.column(name).cardinality for name, size in sizes.items()),

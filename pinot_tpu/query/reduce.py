@@ -16,6 +16,7 @@ group-by merge has two paths:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -41,11 +42,13 @@ from pinot_tpu.query.result import (
 )
 
 
-def reduce_results(ctx: QueryContext, results: List[Any], stats: ExecutionStats) -> ResultTable:
+def reduce_results(ctx: QueryContext, results: List[Any], stats: ExecutionStats, trace=None) -> ResultTable:
+    """`trace` (the broker's): a group-by whose aggregations hold vector
+    fields says what their estimator step cost (span `sketch_final`)."""
     if ctx.is_aggregate and not ctx.group_by:
         return _reduce_aggregation(ctx, results, stats)
     if ctx.group_by:
-        return _reduce_groupby(ctx, results, stats)
+        return _reduce_groupby(ctx, results, stats, trace)
     return _reduce_selection(ctx, results, stats)
 
 
@@ -108,7 +111,29 @@ def _register_agg_env(env: Dict[str, Any], spec: AggregationSpec, finals) -> Non
 # ---------------------------------------------------------------------------
 # Group-by
 # ---------------------------------------------------------------------------
-def _reduce_groupby(ctx: QueryContext, results: List[GroupBySegmentResult], stats: ExecutionStats) -> ResultTable:
+def _finals(aggs, partials, trace) -> List[np.ndarray]:
+    """Every aggregation's final column from its merged partials.  The
+    vector-field functions' step (HLL's estimator over [groups, m]
+    registers, a percentile's walk of each group's bins) sits in a span
+    `sketch_final` of `trace`: the groups, the cells read, its CPU time."""
+
+    def final(i: int) -> np.ndarray:
+        return np.atleast_1d(np.asarray(aggs[i].final(partials[i])))
+
+    out = {i: final(i) for i, fn in enumerate(aggs) if not fn.vector_fields}
+    if len(out) < len(aggs):
+        vectors = [a for i, p in enumerate(partials) if i not in out for a in p.values() if np.ndim(a) > 1]
+        span = trace.span(
+            "sketch_final", cpu=True, groups=len(vectors[0]) if vectors else 0, cells=sum(int(np.size(a)) for a in vectors),
+        ) if trace is not None else contextlib.nullcontext()
+        with span:
+            out.update((i, final(i)) for i in range(len(aggs)) if i not in out)
+    return [out[i] for i in range(len(aggs))]
+
+
+def _reduce_groupby(
+    ctx: QueryContext, results: List[GroupBySegmentResult], stats: ExecutionStats, trace=None
+) -> ResultTable:
     aggs = [for_spec(a).bind_reduce(ctx, a) for a in ctx.aggregations]
     results = [r for r in results if r is not None]
     if not results:
@@ -147,7 +172,7 @@ def _reduce_groupby(ctx: QueryContext, results: List[GroupBySegmentResult], stat
         stats.tables_merged_by_value = len(results)
 
     stats.num_groups = len(keys[0]) if keys else 0
-    finals = [np.atleast_1d(np.asarray(fn.final(p))) for fn, p in zip(aggs, partials)]
+    finals = _finals(aggs, partials, trace)
 
     # fingerprint -> column array, for select/having/order resolution
     env: Dict[str, np.ndarray] = {}

@@ -16,19 +16,37 @@ elementwise, so they ride the same dense-group-table + psum machinery as SUM:
     Pinot keeps hash sets per group; a bounded-domain bitmap is the exact
     tensor equivalent (same idea as its RoaringBitmap-based
     DistinctCountBitmapAggregationFunction).
-  * DISTINCTCOUNTHLL: classic HLL registers [.., m] uint8? kept int32 for
-    psum/pmax friendliness; combine = max (HLL union is register-wise max —
-    exactly FIELD_COMBINE's "max").  Hashes are precomputed host-side over
-    the DICTIONARY (card hashes total, not n) — the same dictionary trick the
-    filter layer uses — or computed on device with a murmur-style finalizer
-    for raw int columns.
+  * DISTINCTCOUNTHLL: classic HLL registers [.., m], int32 from the scatter
+    on (ops.sketch_max_table: an int32 scatter-max on the chip and on the
+    CPU, never a float); combine = max (HLL union is register-wise max —
+    exactly FIELD_COMBINE's "max").  A NUMERIC column is hashed ON THE
+    DEVICE, by VALUE: a raw column's values as they are, a dictionary
+    column's decoded through its device dictionary (one gather a row), then
+    murmur3's 32-bit finalizer over the value's 32-bit words
+    (_device_hash_values: one word an INT, two a LONG or a DOUBLE, whatever
+    width a segment stores them in), bucket = the hash's low log2m bits, rho
+    = the leading zeros of the rest + 1 (lax.clz: no float).  It is the hash
+    a raw column has always had, and a dictionary column whose segments'
+    dictionaries differ.  The kernel bakes nothing of a segment's dictionary, so a table
+    whose segments were built apart is ONE kernel a query shape
+    (segment/table_shape.py) and every segment's registers meet by max.  A
+    STRING column's values never reach the device: its dictionary is hashed
+    on the host (blake2b a value, _hll_host_tables) into per-code bucket /
+    rho tables the kernel bakes, so its kernel is its segment's own
+    (planner._segment_signature).
   * PERCENTILE (and the Est/TDigest/KLL names): an equi-width histogram
-    sketch over [lo, hi] taken from column stats; partial "hist" [.., B]
-    additive + "lo"/"hi" scalar fields (min/max combine) to keep merges
+    sketch over [lo, hi], the TABLE's range where the engine or the broker
+    injected one (`__range__<col>`), else the segment's stats; partial
+    "hist" [.., B] additive (ops.sketch_count_table: exact integer counts) +
+    "lo"/"hi" scalar fields (min/max combine) to keep merges
     self-describing.  final interpolates within the hit bin.  Accuracy is
     (hi-lo)/B — with B=2048 that is tighter than Pinot's default TDigest
     compression for most distributions, and the partial is mergeable across
     segments by plain addition (a psum over ICI).
+
+HLL's and PERCENTILE's fields meet by NAME (FIELD_COMBINE), so a server's
+group program folds its members' [groups, m] tables into one on the chip as
+it folds scalar fields (`fold_by_field`, planner.combines).
 
 Binding: these functions need per-column constants (domain width, hash
 tables, bin ranges).  `get_agg_function` returns unbound singletons whose
@@ -55,8 +73,10 @@ MAX_PRESENCE_CELLS = 1 << 26
 
 # Pinot's DistinctCountHLL default is log2m=8 for the plain HLL type
 # (CommonConstants.Helix.DEFAULT_HYPERLOGLOG_LOG2M); we default to 12 —
-# ~0.8% standard error vs ~6.5% — because the register table is a cheap
-# device tensor here.  Documented accuracy delta; pass an explicit log2m
+# 1.04 / sqrt(4096) = 1.6 % standard error vs 6.5 % — because the register
+# table is a device tensor here: int32[groups, 4096], filled by ONE int32
+# scatter-max a segment from a 32-bit hash of the value (20 bits left for
+# rho: registers <= 21).  Documented accuracy delta; pass an explicit log2m
 # literal arg for parity.
 _DEFAULT_LOG2M = 12
 _DEFAULT_PERCENTILE_BINS = 2048
@@ -91,6 +111,12 @@ class ColumnBinding:
     # host-side dictionary values (numeric np array or object array) for
     # hash precomputation; None for raw columns
     dict_values: Optional[np.ndarray] = None
+    # the column's values reach the device (a string's never do: only its
+    # codes), so a value-hash sketch can hash them there
+    numeric: bool = True
+    # a LONG / TIMESTAMP / DOUBLE column: its values are 64-bit ones, though
+    # a segment whose range fits may store them in 32
+    wide: bool = False
     # column stats for histogram ranges
     min_value: Any = None
     max_value: Any = None
@@ -220,42 +246,15 @@ class DistinctCountValueSetFunction(AggFunction):
 # ---------------------------------------------------------------------------
 # DISTINCTCOUNTHLL
 # ---------------------------------------------------------------------------
-def _splitmix64_np(x: np.ndarray) -> np.ndarray:
-    """Vectorized splitmix64 over uint64 (host numpy — no per-value Python)."""
-    with np.errstate(over="ignore"):
-        z = (x + np.uint64(0x9E3779B97F4A7C15)).astype(np.uint64)
-        z = (z ^ (z >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
-        z = (z ^ (z >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
-        return z ^ (z >> np.uint64(31))
-
-
 def _hll_host_tables(values: np.ndarray, log2m: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Per-dictionary-id (bucket, rho) from a 64-bit host hash.
-
-    card hashes total — the dictionary trick: device rows only gather.
-    Numeric dictionaries hash fully vectorized; strings/bytes loop (their
-    bytes must be digested individually)."""
-    m = 1 << log2m
-    nbits = 64 - log2m
-    if values.dtype != object:
-        # bitcast numerics to uint64 (pad narrower types) + splitmix64
-        arr = np.asarray(values)
-        if arr.dtype.itemsize == 8:
-            u = arr.view(np.uint64)
-        else:
-            u = arr.astype(np.int64).view(np.uint64) if np.issubdtype(arr.dtype, np.integer) else arr.astype(np.float64).view(np.uint64)
-        h = _splitmix64_np(u.astype(np.uint64))
-        buckets = (h & np.uint64(m - 1)).astype(np.int32)
-        w = (h >> np.uint64(log2m)).astype(np.uint64)
-        # rho = nbits - floor(log2(w)) for w>0 else nbits+1, vectorized via
-        # float64 exponent (w < 2^52 after the shift, exact)
-        lg = np.zeros(len(w), dtype=np.int32)
-        nz = w > 0
-        lg[nz] = np.floor(np.log2(w[nz].astype(np.float64))).astype(np.int32)
-        rhos = np.where(nz, nbits - lg, nbits + 1).astype(np.int32)
-        return buckets, rhos
+    """Per-dictionary-id (bucket, rho) of a STRING / BYTES dictionary from a
+    64-bit host hash of each value's bytes (blake2b): card hashes total — the
+    dictionary trick: device rows only gather.  A numeric dictionary has no
+    host tables: its values are hashed on the device (bind_column)."""
     import hashlib
 
+    m = 1 << log2m
+    nbits = 64 - log2m
     buckets = np.empty(len(values), dtype=np.int32)
     rhos = np.empty(len(values), dtype=np.int32)
     for i, v in enumerate(values):
@@ -340,11 +339,14 @@ class DistinctCountHLLFunction(AggFunction):
     needs_binding = True
     vector_fields = True
     fields = ("hll",)
+    fold_by_field = True  # registers hash VALUES: every segment's meet by max
+    binds = "value_hash"
 
     input_kind = "codes"
 
-    def __init__(self, log2m: int = _DEFAULT_LOG2M, bucket_table=None, rho_table=None, device_hash=False):
+    def __init__(self, log2m: int = _DEFAULT_LOG2M, bucket_table=None, rho_table=None, device_hash=False, wide=False):
         self.log2m = int(log2m)
+        self.wide = wide  # device_hash: the column's values are 64-bit ones, whatever width a segment stores
         self.m = 1 << self.log2m
         self.bucket_table = bucket_table  # np.int32[card] for dict columns
         self.rho_table = rho_table
@@ -357,46 +359,50 @@ class DistinctCountHLLFunction(AggFunction):
         return self
 
     def bind_column(self, info: ColumnBinding) -> "DistinctCountHLLFunction":
-        if info.dict_values is not None:
-            # value-based host hash: registers align across segments even
-            # when dictionaries differ (HLL union is value-level), so this
-            # applies to "raw"-kind bindings of misaligned dict columns too
+        if info.dict_values is not None and not info.numeric:
+            # a string's values never reach the device: the dictionary is
+            # hashed on the host, by value (registers align across segments
+            # whose dictionaries differ), and the kernel bakes the tables
             b, r = _hll_host_tables(info.dict_values, self.log2m)
             return DistinctCountHLLFunction(self.log2m, bucket_table=b, rho_table=r)
-        return DistinctCountHLLFunction(self.log2m, device_hash=True)
+        # a numeric column, raw or dictionary-encoded: the planner feeds the
+        # decoded values and the kernel bakes nothing of the segment
+        return DistinctCountHLLFunction(self.log2m, device_hash=True, wide=info.wide)
 
     def _bucket_rho(self, values_or_codes):
+        import jax
         import jax.numpy as jnp
 
-        if self.device_hash:
-            h = _device_hash_values(values_or_codes)
-            bucket = (h & np.uint32(self.m - 1)).astype(jnp.int32)
-            w = (h >> np.uint32(self.log2m)).astype(jnp.int32)
-            nbits = 32 - self.log2m
-            # floor(log2(w)) via f32 exponent — w < 2^21 is exact in f32
-            lg = jnp.floor(jnp.log2(jnp.maximum(w, 1).astype(jnp.float32))).astype(jnp.int32)
-            rho = jnp.where(w > 0, nbits - lg, nbits + 1)
+        with jax.named_scope("sketch_hash"):
+            if self.device_hash:
+                # bucket = the hash's low log2m bits; rho = the place of the
+                # first 1 bit of the rest, counted from their top, and one
+                # past them where there is none: the leading zeros of
+                # (hash >> log2m) less log2m - 1, integers all the way
+                v = values_or_codes
+                if self.wide and v.dtype.itemsize < 8 and jnp.issubdtype(v.dtype, jnp.integer):
+                    # a LONG column stored narrowed (its range fits 32 bits in
+                    # THIS segment): hashed as the 64-bit value it is, so that
+                    # a segment that stores it whole holds the same registers
+                    v = v.astype(jnp.int64)
+                h = _device_hash_values(v)
+                bucket = (h & np.uint32(self.m - 1)).astype(jnp.int32)
+                rho = jax.lax.clz(h >> np.uint32(self.log2m)).astype(jnp.int32) - np.int32(self.log2m - 1)
+                return bucket, rho
+            bucket = jnp.asarray(self.bucket_table)[values_or_codes]
+            rho = jnp.asarray(self.rho_table)[values_or_codes]
             return bucket, rho
-        bucket = jnp.asarray(self.bucket_table)[values_or_codes]
-        rho = jnp.asarray(self.rho_table)[values_or_codes]
-        return bucket, rho
 
     def partial(self, codes, mask):
-        import jax.numpy as jnp
-
         bucket, rho = self._bucket_rho(codes)
-        regs = ops.group_max(rho, mask, bucket, self.m)
-        # group_max yields -inf for empty buckets; registers are >= 0
-        return {"hll": jnp.maximum(regs, 0.0).astype(jnp.int32)}
+        return {"hll": ops.sketch_max_table(rho, mask, bucket, self.m)}
 
     def partial_grouped(self, codes, mask, keys, num_groups):
-        import jax.numpy as jnp
-
         _check_cell_budget(self.name, num_groups, self.m)
         bucket, rho = self._bucket_rho(codes)
         flat = keys * np.int32(self.m) + bucket
-        regs = ops.group_max(rho, mask, flat, num_groups * self.m)
-        return {"hll": jnp.maximum(regs, 0.0).astype(jnp.int32).reshape(num_groups, self.m)}
+        regs = ops.sketch_max_table(rho, mask, flat, num_groups * self.m)
+        return {"hll": regs.reshape(num_groups, self.m)}
 
     def merge(self, a, b):
         return {"hll": np.maximum(a["hll"], b["hll"])}
@@ -429,6 +435,8 @@ class PercentileFunction(AggFunction):
     needs_binding = True
     vector_fields = True
     fields = ("hist", "lo", "hi")
+    fold_by_field = True  # one kernel = one [lo, hi]: its members' bins add
+    binds = "range"
 
     def __init__(self, rank: float = 50.0, lo: float = 0.0, hi: float = 1.0, bins: int = _DEFAULT_PERCENTILE_BINS):
         self.rank = float(rank)
@@ -465,7 +473,7 @@ class PercentileFunction(AggFunction):
 
     def partial(self, values, mask):
         b = self._bin(values)
-        hist = ops.group_count(mask, b, self.bins)
+        hist = ops.sketch_count_table(mask, b, self.bins)
         lo, hi = self._range_fields(())
         return {"hist": hist, "lo": lo, "hi": hi}
 
@@ -473,7 +481,7 @@ class PercentileFunction(AggFunction):
         _check_cell_budget(self.name, num_groups, self.bins)
         b = self._bin(values)
         flat = keys * np.int32(self.bins) + b
-        hist = ops.group_count(mask, flat, num_groups * self.bins).reshape(num_groups, self.bins)
+        hist = ops.sketch_count_table(mask, flat, num_groups * self.bins).reshape(num_groups, self.bins)
         lo, hi = self._range_fields((num_groups,))
         return {"hist": hist, "lo": lo, "hi": hi}
 

@@ -68,7 +68,10 @@ STAR_LAYER = "star-tree"
 # whose every plan-cache hit binds its parameters by the entry's recipe
 SOUND_AT_ZERO = {"plan_rebuilds_in_window"}
 # read what only a group-by produces (BENCHMARK.json lists their cells): looked for in QUERIES' group-by alone
-GROUP_BY_ONLY = {"table_decode_cpu_ms", "tables_decoded_per_query", "tables_merged_by_value_per_query"}
+GROUP_BY_ONLY = {"table_decode_cpu_ms", "tables_decoded_per_query", "tables_merged_by_value_per_query",
+                 "sketch_table_bytes_per_query"}
+# read what only a sketch aggregation produces (PR 43: the estimator step's span): looked for in QUERIES' sketch alone
+SKETCH_ONLY = {"sketch_final_cpu_ms"}
 # PR 39's, all among SPECS
 DOOR_SPECS = {
     "frontdoor_accept_wait_ms", "frontdoor_accept_wait_p99_ms", "frontdoor_head_ms", "frontdoor_read_ms",
@@ -98,6 +101,7 @@ N_SERVERS = 2
 QUERIES = (
     "SELECT region, SUM(rev) FROM contract WHERE qty < 40 GROUP BY region ORDER BY region",
     "SELECT SUM(rev) FROM contract WHERE qty BETWEEN 5 AND 30",
+    "SELECT region, DISTINCTCOUNTHLL(qty), PERCENTILETDIGEST(rev, 95) FROM contract WHERE qty < 40 GROUP BY region ORDER BY region",
 )
 
 
@@ -181,7 +185,8 @@ def test_program_still_says_what_the_metric_reads(name, served):
     trees, counters, timers = served
     named_keys = [k for k in SPAN_KEYS + REGISTRY_KEYS if k in spec]
     assert named_keys, f"{name}: names nothing this test knows how to look for: {sorted(spec)}"
-    answers = [(sql, tree) for sql, tree in zip(QUERIES, trees) if name not in GROUP_BY_ONLY or "GROUP BY" in sql]
+    answers = [(sql, tree) for sql, tree in zip(QUERIES, trees)
+               if (name not in GROUP_BY_ONLY or "GROUP BY" in sql) and (name not in SKETCH_ONLY or "HLL" in sql)]
 
     span_names = []
     for key in SPAN_KEYS:
@@ -262,6 +267,8 @@ def test_launches_per_query_counts_the_jitted_calls(served):
 DRILL_QUERIES = {  # 120 x 120 = 14,400 slots: past the one-hot kernel's 8,192
     "wide": "SELECT a, b, SUM(rev) FROM drill WHERE qty < 40 GROUP BY a, b LIMIT 100000",
     "sparse": "SET maxDenseGroups = 8192; SELECT a, b, SUM(rev) FROM drill WHERE qty < 40 GROUP BY a, b LIMIT 100000",
+    # PR 43: 120 slots x 4,096 registers and x 2,048 bins, scattered (`scan.traced.sketch_scatter`)
+    "sketch": "SELECT a, DISTINCTCOUNTHLL(b), PERCENTILETDIGEST(rev, 95) FROM drill WHERE qty < 40 GROUP BY a LIMIT 100000",
 }
 
 
@@ -345,7 +352,8 @@ def test_table_decode_says_what_came_back(drill_served):
     counts the sparse plan's alone."""
     trees, counters = drill_served
     groups = {}
-    for how, tree in trees.items():
+    for how in ("wide", "sparse"):
+        tree = trees[how]
         (collect,) = _named(tree, "collect")
         (decode,) = _named(collect, "table_decode")
         attrs = decode["attrs"]
@@ -632,4 +640,39 @@ def test_every_metric_of_the_built_apart_cell_has_a_reader_that_returns_a_value(
     assert values["in_table_gather_ms"] == pytest.approx(300.0 / weights["count_in"])
     assert values["dict_decode_gather_ms"] == pytest.approx(200.0 / weights["filtered_query"])
     assert values["table_shaped_scatter_ms"] == pytest.approx(100.0 / weights["group_low_high"])
+    assert {m["name"] for m in cell["end_to_end"]} == {"throughput_qps", "latency_p50_ms", "latency_p95_ms", "setup_s"}
+
+
+def test_every_metric_of_the_sketch_cell_has_a_reader_that_returns_a_value(monkeypatch):
+    """`ssb_sf10_sketch.sketch_closed` (PR 43): every per-layer metric `load_cell` gives the cell returns a value
+    over a traced window of its own traffic at toy size (4 segments of 10,000 rows, four `lo_custkey` dictionaries),
+    and what the new names say hangs together: one kernel and one group program a template whatever each dictionary
+    holds, ONE table of 175 x 4,096 registers (or 175 x 2,048 bins) back for the four segments."""
+    # the ops that pace the cell, as the v5e compiler names them (an AOT compile of the cell's programs:
+    # `value_transform/gather`, `sketch_scatter/scatter-max`, `sketch_scatter/scatter-add`); a group key's vector
+    # (kLoop) and a table that is no multiple of 175 slots are there to be left out
+    events = {
+        "%fusion = s32[10000]{0:T(1024)S(1)} fusion(%copy-done, %pad_clamp_fusion), kind=kCustom, calls=%fused_comput": (8, 0.20),
+        "%fusion.1 = s32[716800]{0:T(1024)S(1)} fusion(%get-tuple-element.20, %get-tuple-element.16, %constant.25), kind=kCustom, calls=%f": (8, 0.30),
+        "%fusion.5 = s32[358400]{0:T(1024)S(1)} fusion(%fusion.7, %param_0.64, %param_1.63), kind=kCustom, calls=%f": (4, 0.10),
+        "%fusion.3 = s32[10000]{0:T(1024)S(1)} fusion(%bitcast.5, %bitcast.4), kind=kLoop, calls=%fused_computation.4": (8, 5.0),
+        "%fusion.9 = s32[70001]{0:T(1024)S(1)} fusion(%fusion.1, %broadcast.55, %constant.7), kind=kCustom, calls=%f": (8, 5.0),
+    }
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    values, cell, reqs, weights = _toy_window("ssb_sf10_sketch.sketch_closed", 40_000, 43, events=events)
+    new = {"sketch_scatter_ms", "sketch_hash_ms", "sketch_roofline", "sketch_table_bytes_per_query", "sketch_final_cpu_ms"}
+    assert new | {"launches_per_query", "compiles_in_window", "table_decode_cpu_ms", "combined_segments_per_query",
+                  "tables_decoded_per_query", "warm_up_compiles_per_template", "warm_up_s"} <= set(values)
+    assert DOOR_SPECS <= set(values)
+    assert not [name for name, v in values.items() if v is None], values
+    assert values["compiles_in_window"] == values["plan_rebuilds_in_window"] == 0.0
+    # a kernel and its combining group program (4 segments: one call of width 4) a template
+    assert values["warm_up_compiles_per_template"] == 2.0 and values["launches_per_query"] == 1.0
+    assert values["combined_segments_per_query"] == 4.0 and values["tables_decoded_per_query"] == 1.0
+    assert values["sketch_table_bytes_per_query"] == 175 * 4096 * 4 == 175 * 2048 * 8  # int32 registers, int64 bins
+    assert values["sketch_final_cpu_ms"] > 0.0 and 0.0 < values["sketch_roofline"] < 100.0
+    # the scatters over every traced query (all three templates scatter), the gather over the HLL templates' alone
+    assert values["sketch_scatter_ms"] == pytest.approx(400.0 / len(reqs))
+    assert values["sketch_hash_ms"] == pytest.approx(200.0 / (len(reqs) - weights["p95_rev_year_nation"]))
     assert {m["name"] for m in cell["end_to_end"]} == {"throughput_qps", "latency_p50_ms", "latency_p95_ms", "setup_s"}

@@ -774,7 +774,9 @@ def _group_args(plans, segs):
 FALL_BACKS = {
     "different_dictionaries": (QUERIES["groupby_dense"][0], "groupby_dense"),
     "pairwise_merge": ("SELECT year, LASTWITHTIME(rev, qty, 'LONG') FROM t GROUP BY year", "groupby_dense"),
-    "sketch": ("SELECT year, DISTINCTCOUNTHLL(qty) FROM t GROUP BY year", "groupby_dense"),
+    # an own-scatter function whose cells are a segment's own codes (HLL's registers and a
+    # percentile's bins hash or bin VALUES and do combine: tests/test_sketch_served.py)
+    "sketch": ("SELECT year, DISTINCTCOUNT(qty) FROM t GROUP BY year", "groupby_dense"),
     "sparse": (QUERIES["groupby_sparse"][0], "groupby_sparse"),
     "scalar_aggregation": (QUERIES["aggregation"][0], "aggregation"),
     "selection": ("SELECT city, rev FROM t WHERE qty = 7 LIMIT 100000", "selection"),
@@ -783,7 +785,7 @@ FALL_BACKS = {
 
 @pytest.mark.parametrize("name", list(FALL_BACKS))
 def test_what_does_not_combine_keeps_the_parents_program(name, segments):
-    """Members with different dictionaries, a pairwise merge, a sketch, a
+    """Members with different dictionaries, a pairwise merge, a code-indexed sketch, a
     sparse plan, a scalar aggregation, a selection: the stacked program, its
     text the parent's (so the persistent cache's entries still load: the
     Q1.x cell's), a result a member, `combinedSegments` 0."""

@@ -8,7 +8,8 @@ from pinot_tpu.ops.segmented import (  # noqa: F401
     accum_policy,
     fused_group_tables,
     int_sum_entry,
-    limb_scatter_table,
+    limb_prefix_table,
+    prefix_group_sums,
     sum_limb_plan,
     sum_limb_plan64,
     group_count,

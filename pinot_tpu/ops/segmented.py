@@ -557,8 +557,7 @@ def _fused_group_tables_xla(entries, codes, num_groups: int, mask_words, codes_p
 # Past _MATMUL_MAX_GROUPS the one-hot matrices stop paying and rows scatter.
 # The chip scatters 32-bit words at 7-9 ns a row and 64-bit ones (emulated:
 # a pair of 32-bit halves) at 85-94, and an f32 table loses a unit as soon as
-# a slot passes 2^24 (SSB Q3.2 on the chip, PERF.md PR 31; PR 42 for the
-# sparse plan's slot tables, which take this form too).  So an integer rides as
+# a slot passes 2^24 (SSB Q3.2 on the chip, PERF.md PR 31).  So an integer rides as
 # 12-bit limbs, each scattered into an int32 table of its own over chunks of
 # 2^19 rows: a chunk's limb sum is at most 2^19 * (2^12 - 1) < 2^31, exact;
 # chunks and limbs recombine in int64 at table size, exact while the group's
@@ -568,6 +567,21 @@ def _fused_group_tables_xla(entries, codes, num_groups: int, mask_words, codes_p
 # full width, never fewer.  Floats have no exact sum: they keep an f32
 # table over 2^16-row chunks and an f64 combine (relative error of a chunk's
 # accumulation <= 2^16 * 2^-24, as the matmul path's).
+#
+# A sparse plan (planner.sparse_grouped_tables) has SORTED its rows, so a
+# group is a row range and its limb sum the difference of the limb's prefix
+# sum at the range's two ends (prefix_group_sums, limb_prefix_table): no
+# scatter a limb, one prefix sum (~1 ms a 1.5M-row segment against a
+# scatter's 13) and one table-size gather.  Exactness, for every input:
+# with limbs of prefix_limb_bits(rows) bits (8 up to 2^23 rows) the sum of
+# ANY set of a segment's rows of one limb column is at most (2^bits - 1) *
+# rows < 2^31 in magnitude (a signed-magnitude limb and the negatives'
+# column are -1 at least), so no prefix and no group sum leaves int32,
+# were one group the whole segment at the column's extreme values; the
+# prefix is kept in uint32, where a negative one is its residue mod 2^32,
+# and the difference of two residues read as int32 IS the group's sum.
+# The bound comes from the static row count alone.  Limbs meet in int64 at
+# table size like the scatters' tables, to the same value mod 2^64.
 _WIDE_LIMB_BITS = 12
 _WIDE_CHUNK = 1 << 19
 
@@ -582,14 +596,14 @@ def _chunked_scatter(col, codes, num_groups: int, chunk_rows: int):
     return _scatter_add(jnp.zeros((k * num_groups,), col.dtype), idx, col).reshape(k, num_groups)
 
 
-def _wide_int_limbs(kind, values, mask, limb_plan):
+def _wide_int_limbs(kind, values, mask, limb_plan, limb_bits: int = _WIDE_LIMB_BITS):
     """-> [(int32 limb column, bit shift)] of an integer entry: its table is
     the sum over limbs of limb_table << shift.  int32 and narrower: the
-    two's complement in 8 * n_limbs bits cut into 12-bit limbs, plus, where
-    the plan is signed, minus one a negative row at shift 8 * n_limbs.
-    int64: signed-magnitude limbs (_int64_signed_limbs' reasoning), the
-    sign riding each limb."""
-    m12 = np.uint32((1 << _WIDE_LIMB_BITS) - 1)
+    two's complement in 8 * n_limbs bits cut into limbs of `limb_bits` bits
+    (12 for a wide table), plus, where the plan is signed, minus one a
+    negative row at shift 8 * n_limbs.  int64: signed-magnitude limbs
+    (_int64_signed_limbs' reasoning), the sign riding each limb."""
+    low = np.uint32((1 << limb_bits) - 1)
     if kind == "int_sum":
         n_limbs, signed = limb_plan if limb_plan is not None else (4, True)
         bits = 8 * n_limbs
@@ -597,20 +611,20 @@ def _wide_int_limbs(kind, values, mask, limb_plan):
         u = vm.astype(jnp.uint32)
         if bits < 32:
             u = u & np.uint32((1 << bits) - 1)
-        out = [(((u >> np.uint32(s)) & m12).astype(jnp.int32), s) for s in range(0, bits, _WIDE_LIMB_BITS)]
+        out = [(((u >> np.uint32(s)) & low).astype(jnp.int32), s) for s in range(0, bits, limb_bits)]
         if signed:
             out.append((-(vm < 0).astype(jnp.int32), bits))
         return out
     alo, ahi, sgn = _int64_magnitude_halves(values, mask)
     out = []
-    for s in range(0, 8 * (limb_plan if limb_plan is not None else 8), _WIDE_LIMB_BITS):
+    for s in range(0, 8 * (limb_plan if limb_plan is not None else 8), limb_bits):
         if s >= 32:
             w = ahi >> np.uint32(s - 32)
-        elif s + _WIDE_LIMB_BITS <= 32:
+        elif s + limb_bits <= 32:
             w = alo >> np.uint32(s)
         else:  # the limb that straddles the halves
             w = (alo >> np.uint32(s)) | (ahi << np.uint32(32 - s))
-        out.append(((w & m12).astype(jnp.int32) * sgn, s))
+        out.append(((w & low).astype(jnp.int32) * sgn, s))
     return out
 
 
@@ -627,6 +641,46 @@ def limb_scatter_table(kind, values, mask, limb_plan, codes, num_groups: int):
     return sum(
         _chunked_scatter(limb, codes, num_groups, _WIDE_CHUNK).astype(jnp.int64).sum(axis=0) << np.int64(shift)
         for limb, shift in _wide_int_limbs(kind, values, mask, limb_plan)
+    )
+
+
+def prefix_limb_bits(rows: int) -> int:
+    """The limb width of a sorted-row prefix table (the block above) over
+    `rows` rows: the widest, at most 8 bits, with (2^bits - 1) * rows < 2^31,
+    so that no group, were it every row at the limb's largest value, sums
+    past an int32.  8 up to 2^23 rows (_SCALAR_PIECE's bound), 7 up to
+    2^24, ...; the static shape decides, no data does."""
+    bits = min(8, (((1 << 31) - 1) // max(rows, 1) + 1).bit_length() - 1)
+    if bits < 1:
+        raise ValueError(f"{rows} rows cannot be indexed in int32")
+    return bits
+
+
+def prefix_group_sums(col, lo, hi=None):
+    """int32[groups] sums of the int32 row column `col` over each group's
+    CONTIGUOUS rows [lo[g], hi[g]), read from ONE prefix sum: no row-length
+    scatter.  `hi` None: the groups adjoin, `lo` has groups + 1 bounds and
+    one table-size gather serves both ends.  The prefix is kept in uint32,
+    where a negative one (a signed limb's) is its residue mod 2^32; the
+    difference of two residues, read as int32, is the group's sum while
+    that lies in int32 (the caller's bound: prefix_limb_bits)."""
+    c = jnp.concatenate([jnp.zeros((1,), jnp.uint32), jnp.cumsum(col.astype(jnp.uint32), dtype=jnp.uint32)])
+    at = lo if hi is None else jnp.concatenate([lo, hi])
+    g = c[at]  # the sum of the rows before each bound
+    k = lo.shape[0] - 1 if hi is None else lo.shape[0]
+    return lax.bitcast_convert_type(g[at.shape[0] - k :] - g[:k], jnp.int32)
+
+
+def limb_prefix_table(kind, values, mask, limb_plan, lo, hi=None):
+    """limb_scatter_table's int64 table of an "int_sum" / "int64_sum" entry
+    over SORTED rows whose groups are the row ranges [lo, hi)
+    (prefix_group_sums): one int32 prefix sum a limb of prefix_limb_bits
+    bits, limbs met in int64 at table size.  Bit for bit the scatter's
+    table: both are the group's exact sum mod 2^64."""
+    bits = prefix_limb_bits(mask.shape[0])
+    return sum(
+        prefix_group_sums(limb, lo, hi).astype(jnp.int64) << np.int64(shift)
+        for limb, shift in _wide_int_limbs(kind, values, mask, limb_plan, bits)
     )
 
 

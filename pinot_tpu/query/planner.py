@@ -1086,9 +1086,9 @@ def packed_key64(cols, group_dims, segment) -> jnp.ndarray:
 
 def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=None,
                           num_groups: Optional[int] = None, vranges=()):
-    """Device-side high-cardinality group-by: sort + segment-scatter into
-    FIXED-size tables (the IndexedTable analog with numGroupsLimit trim
-    built into the kernel).
+    """Device-side high-cardinality group-by: sort, then FIXED-size tables
+    read off the sorted rows (the IndexedTable analog with numGroupsLimit
+    trim built into the kernel).
 
     Replaces the round-1/2 host fallback that device_get the mask, codes and
     every agg input for ALL rows (tens of GB over PCIe at 1B rows).  Now the
@@ -1096,27 +1096,38 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
 
       sort rows by packed key (filtered rows get SPARSE_EMPTY_KEY, sorting
       last) -> group starts where the sorted key changes -> running group
-      index = cumsum(starts) -> rows beyond num_slots groups scatter into a
+      index = cumsum(starts) -> rows beyond num_slots groups fall in a
       dropped overflow slot.  Sorted keys make the trim deterministic (lowest
       keys win — the documented analog of Pinot's first-arrival trim).
+
+    After the sort a group's rows are contiguous, so a slot is a row range
+    [lo, hi).  ONE row-length scatter records each slot's first row (a
+    scatter-min of the row index by slot); its end is the next slot's first
+    row where slots rise with the rows, and under the ORDER BY-aware trim
+    (slots are ranks) the next group start after it (`nxt`), either cut at
+    the first filtered row.  The keys are the sorted key gathered at the
+    slots' first rows: a table-size gather, no scatter.
 
     Accumulation dtypes mirror the host reduce contracts: counts int64,
     sums/sumsq float64 (exact for int sums < 2^53 — the reference likewise
     accumulates long sums in double), min/max float64.  What the tables
-    cost is their scatters, and the chip holds a 64-bit array as a pair of
-    32-bit halves: over a 1.5M-row segment a 64-bit scatter is ~128 ms
-    there (85 ns a row) and an int32 one 14.0 (9.3 ns a row after the
-    sort, 7.3 on random indices), so SSB Q4.3's key, count and sum cost
-    404-416 ms a segment as three 64-bit scatters and 89 as six int32 ones
-    (PERF.md, PR 42).  So the keys of a key space under 2^31 scatter as
-    the int32 they were sorted as, and under accum_policy() "chunked32" the
-    counts and the sums of integer inputs scatter as int32 tables
-    (ops.limb_scatter_table: a sum as 12-bit limbs over 2^19-row chunks,
-    met in int64 at table size, exact; `vranges`, from agg_vranges,
-    shrinks a bare column's limb count by its stats); all are widened at
-    table size.  Float sums, sumsq, min / max and the sketch family keep
-    the 64-bit scatters: an f32 table would be a lower precision than
-    this path states.
+    cost is their row-length scatters: the chip takes ~9 ns a row for an
+    int32 one whatever it writes and wherever (13-14 ms a 1.5M-row
+    segment) and ~85 for a 64-bit one (a pair of 32-bit halves), while a
+    prefix sum streams (~1 ms) and a table-size gather is ~1.5 ms.  So
+    under accum_policy() "chunked32" a count is hi - lo (an aggregate with
+    a mask of its own: the difference of the permuted mask's prefix sum)
+    and the sum of an integer input is, an 8-bit limb, the difference of
+    that limb's int32 prefix sum at hi and lo, limbs met in int64 at table
+    size (ops.limb_prefix_table; exact, ops/segmented.py says why;
+    `vranges`, from agg_vranges, shrinks a bare column's limb count by its
+    stats): SSB Q4.3's key, count and sum cost 404-416 ms a segment as
+    three 64-bit scatters, 89 as six int32 ones (PERF.md, PR 42) and 33 as
+    one scatter and five prefix sums (PR 44), the same tables bit for bit.
+    Float sums, sumsq, min / max and the sketch family keep their 64-bit
+    scatters on the slot: a float prefix difference rounds differently, and
+    an f32 table would be a lower precision than this path states.  The
+    "wide" policy (CPU) keeps the 64-bit scatters for counts and sums too.
 
     num_groups, when given, is the static size of the key space (every key
     is < num_groups).  It buys two things the TPU cares about — a 64-bit or
@@ -1170,6 +1181,10 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
             # order value in-row-space, rank groups by (order value, packed key)
             # on device, and give slots to the top num_slots groups only.
             oi, omode, asc = order_spec
+            # each row's next group start (smallest start index > i; n past the last): the end, in
+            # row space, of the group that starts at i
+            starts_at = jnp.where(is_start, iota, np.int32(n))
+            nxt = jnp.concatenate([lax.cummin(starts_at[::-1])[::-1][1:], jnp.full((1,), n, jnp.int32)])
             if sov is not None:
                 empty = jnp.isinf(sov)  # no agg-mask rows in the group: NULL
                 group_ov = sov  # valid at start rows: the group's min / -max
@@ -1201,10 +1216,6 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
                     c = jnp.where(om & ~isn, cv, 0.0)
                 cp = c[perm]
                 s0 = jnp.concatenate([jnp.zeros((1,), jnp.float64), jnp.cumsum(cp)])
-                # smallest start index >= i, from the right; strict next start
-                starts_at = jnp.where(is_start, iota, np.int32(n))
-                nxt_ge = lax.cummin(starts_at[::-1])[::-1]
-                nxt = jnp.concatenate([nxt_ge[1:], jnp.full((1,), n, jnp.int32)])
                 total = s0[nxt] - s0[iota]  # valid at start rows
                 group_ov = total if asc else -total
                 if omode == "sum":
@@ -1232,15 +1243,27 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
             )
             gslot = ranks[jnp.minimum(seg, np.int32(n))]
             slot = jnp.where(smask & (gslot < num_slots), gslot, num_slots)
-    # the chip's form: counts and integer sums as int32 tables, widened at table size (docstring)
+    # the chip's form: counts and integer sums read from int32 prefix sums over the sorted rows (docstring)
     limbs = ops.accum_policy() == "chunked32"
     with jax.named_scope("sparse_scatter"):
-        # the keys scatter as they were sorted: ONE int32 table for a key space under 2^31
-        uniq = jnp.full((num_slots + 1,), no_key, dtype=skey.dtype).at[jnp.where(is_start, slot, num_slots)].set(skey)
-        if uniq.dtype != jnp.int64:
-            uniq = jnp.where(uniq == no_key, SPARSE_EMPTY_KEY, uniq.astype(jnp.int64))
+        # the ONE row-length scatter every plan keeps: each slot's first row.  A group's rows are
+        # contiguous after the sort, so a slot's table entry is a function of its row range
+        # [lo, hi) alone; the overflow slot's first row (the first group past num_slots, else the
+        # first filtered row) ends the last slot's range where slots rise with the rows
+        n_valid = jnp.sum(smask, dtype=jnp.int32)  # the filtered rows sorted last: rows [n_valid, n)
+        first = jnp.minimum(jnp.full((num_slots + 1,), n, jnp.int32).at[slot].min(iota), n_valid)
+        lo = first[:num_slots]  # n_valid where the slot has no group
+        if order_spec is None:
+            hi, bounds = first[1:], (first,)  # slot s + 1 holds the next group: the ranges adjoin
+        else:
+            # slots are ranks: the end is the next group start after lo (row n has none after it)
+            hi = jnp.minimum(jnp.concatenate([nxt, jnp.full((1,), n, jnp.int32)])[lo], n_valid)
+            bounds = (lo, hi)
+        # the keys: the sorted key at each slot's first row; row n_valid is a filtered row's sentinel, or the one appended
+        uniq = jnp.concatenate([skey, jnp.full((1,), no_key, skey.dtype)])[lo]
+        uniq = jnp.where(uniq == no_key, SPARSE_EMPTY_KEY, uniq.astype(jnp.int64))
         partials = []
-        limb_sums = False
+        limb_sums = prefix_sums = False
         for i, (fn, (vals, mask)) in enumerate(zip(aggs, inputs)):
             m = smask if mask is tmask else mask[perm]
 
@@ -1265,13 +1288,15 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
                 comb = FIELD_COMBINE[fname]
                 if comb == "add":
                     if fname == "count" and limbs:
-                        acc = ops.limb_scatter_table("count", None, m, None, slot, num_slots + 1).astype(jnp.int64)
+                        # the filter's own mask passes every row of a group: its count is the range's length
+                        acc = (hi - lo if mask is tmask else ops.prefix_group_sums(m.astype(jnp.int32), *bounds)).astype(jnp.int64)
+                        prefix_sums = True
                     elif fname == "count":
                         acc = jnp.zeros((num_slots + 1,), jnp.int64).at[slot].add(m.astype(jnp.int64))
                     elif fname == "sum" and ent is not None:
                         kind, lv, lp = ent
-                        acc = ops.limb_scatter_table(kind, lv, m, lp, slot, num_slots + 1).astype(jnp.float64)
-                        limb_sums = True
+                        acc = ops.limb_prefix_table(kind, lv, m, lp, *bounds).astype(jnp.float64)
+                        limb_sums = prefix_sums = True
                     else:
                         w = v.astype(jnp.float64)
                         if fname == "sumsq":
@@ -1285,10 +1310,14 @@ def sparse_grouped_tables(aggs, inputs, tmask, key, num_slots: int, order_spec=N
                 p[fname] = acc[:num_slots]
             partials.append(p)
     if limb_sums:
-        # trace time: this plan's integer sums rode int32 limb tables (its keys
-        # and counts are int32 tables under chunked32 whatever it sums)
+        # trace time: this plan's integer sums rode int32 limbs (its counts are
+        # int32 under chunked32 whatever it sums)
         METRICS.counter("scan.traced.sparse_limb_scatter").inc()
-    return uniq[:num_slots], partials
+    if prefix_sums:
+        # trace time: this plan's counts / integer sums were read from prefix sums
+        # at the slots' row ranges, not scattered
+        METRICS.counter("scan.traced.sparse_prefix_sums").inc()
+    return uniq, partials
 
 
 @dataclass(frozen=True)

@@ -205,11 +205,13 @@ def test_sparse_group_tables_compile_for_v5e(one_chip, no_compile_cache):
     jax.jit(kernel).lower(shape(jnp.int64), shape(jnp.bool_), shape(jnp.int64)).compile()
 
 
-def test_sparse_slot_tables_are_int32_scatters_for_v5e(one_chip, no_compile_cache, monkeypatch):
-    """SSB Q4.3's per-segment sparse kernel under the chip's policy (PR 42): 1.5M rows, 100,000 slots of a
-    1,750,000-key space, an int32 expression summed.  After the sort every slot table is ONE int32 scatter
-    (the keys, the count, three 12-bit limbs and the negatives' count), none a tuple of 32-bit halves (the
-    emulated 64-bit scatter, ~14 times the time a row)."""
+def test_sparse_slot_tables_keep_one_row_length_scatter_for_v5e(one_chip, no_compile_cache, monkeypatch):
+    """SSB Q4.3's per-segment sparse kernel under the chip's policy (PR 44): 1.5M rows, 100,000 slots of a
+    1,750,000-key space, an int32 expression summed.  After the sort ONE row-length scatter is left, the
+    slots' first rows (`s32[100001]`, the shape `sparse_limb_scatter_ms` reads); the keys, the count and the
+    sum are table-size gathers, of the sorted key and of five uint32 prefix sums (four 8-bit limbs and the
+    negatives' count), which that metric's `s32` pattern does not take for scatters.  None is a tuple of
+    32-bit halves (the emulated 64-bit scatter, ~14 times the time a row)."""
     from pinot_tpu.query import planner
     from pinot_tpu.query.functions import get_agg_function
 
@@ -224,8 +226,14 @@ def test_sparse_slot_tables_are_int32_scatters_for_v5e(one_chip, no_compile_cach
         return planner.sparse_grouped_tables([sum_fn], [(vals, mask)], mask, key, slots, None, num_groups=groups)
 
     text = jax.jit(kernel).lower(shape(jnp.int32), shape(jnp.bool_), shape(jnp.int64)).compile().as_text()
-    scatters = re.findall(r"^\s*%[\w.\-]+ = (\(?\w+\[\d+\])\S* fusion\([^\n]*kind=kCustom[^\n]*sparse_scatter/scatter", text, re.M)
-    assert sorted(scatters) == ["s32[100001]"] * 2 + ["s32[300003]"] * 4, scatters
+    # the entry computation's custom fusions: (what it writes, the op it was traced as)
+    custom = re.findall(r"^\s*%[\w.\-]+ = (\(?\w+\[\d+\])\S* fusion\([^\n]*kind=kCustom[^\n]*op_name=\"[^\"]*/([\w\-]+)\"", text, re.M)
+    assert [out for out, op in custom if op.startswith("scatter")] == ["s32[100001]"], custom
+    gathers = sorted(out for out, op in custom if op == "gather")
+    assert gathers == ["s32[100000]", "s32[1500000]"] + ["u32[100001]"] * 5, custom
+    assert len(custom) == 1 + len(gathers) and not any(out.startswith("(") for out, _ in custom), custom
+    # the benchmark's reader of the slot tables' scatters (benchmarks/layer_metrics/sparse_limb_scatter_ms.json) finds that one
+    assert len(re.findall(r"^\s*%fusion[\w.\-]* = s32\[([1-9])0000\1\]\S* fusion\(.*kind=kCustom", text, re.M)) == 1
 
 
 def test_engine_dense_groupby_step_compiles_for_v5e(topo, no_compile_cache, monkeypatch):

@@ -1,18 +1,18 @@
-"""A sparse plan's slot tables in 32 bits (PR 42).
+"""A sparse plan's slot tables in 32 bits (PR 42), read from prefix sums (PR 44).
 
 Under `accum_policy()` = "chunked32" (the chip) `planner.sparse_grouped_tables`
-scatters, after its sort, the keys of a key space under 2^31 as ONE int32
-table, every count as an int32 table and every sum of an integer input as
-12-bit limbs into int32 tables over 2^19-row chunks
-(`ops.limb_scatter_table`, the wide group table's form), and widens them at
-table size: what it returns is what it returned (int64 keys with
-`SPARSE_EMPTY_KEY`, int64 counts, f64 sums).  Float sums, min / max and the
-sketch family keep their 64-bit scatters.  On the CPU the engine takes
-"wide", so here the policy is steered as tests/test_sparse_drill_exact.py
-steers it, and every case is held to a numpy group-by at difference 0 and to
-the "wide" policy's tables (the 64-bit scatters the parent ran on the chip
-too; the keys of a key space under 2^31 are one int32 table under either
-policy) field by field.
+keeps, after its sort, ONE row-length scatter (each slot's first row) and reads
+the keys off the sorted key there, every count as the slot's row range (or a
+prefix sum of the aggregate's own mask) and every sum of an integer input as
+8-bit limbs' int32 prefix sums at the range's two ends
+(`ops.limb_prefix_table`), met in int64 at table size: what it returns is what
+it returned (int64 keys with `SPARSE_EMPTY_KEY`, int64 counts, f64 sums); PR 42
+had scattered them as int32 limb tables (`scan.traced.sparse_limb_scatter`
+still says a sum rode limbs).  Float sums, min / max and the sketch family
+keep their 64-bit scatters.  On the CPU the engine takes "wide", so here the
+policy is steered as tests/test_sparse_drill_exact.py steers it, and every
+case is held to a numpy group-by at difference 0 and to the "wide" policy's
+tables (the 64-bit scatters the chip ran before PR 42) field by field.
 """
 import jax
 import numpy as np
@@ -29,6 +29,7 @@ from pinot_tpu.utils.metrics import METRICS
 from tests.test_ssb_templates_chip_path import chip_path  # noqa: F401  (chunked32 for this module's traces)
 
 LIMB = "scan.traced.sparse_limb_scatter"
+PREFIX = "scan.traced.sparse_prefix_sums"
 GROUPS = 3_000  # the key space (num_groups): every key is below it
 
 
@@ -72,17 +73,26 @@ CASES = {
 }
 
 
+def _plan_tables(key, tmask, aggs, num_slots, order_spec=None, groups=GROUPS, vranges=()):
+    """`aggs` = [(function name, values, mask or None for the filter's own)] as ONE plan through
+    sparse_grouped_tables under jit -> (uniq, [tables an aggregate], how far LIMB moved, how far PREFIX moved)."""
+    fns = [get_agg_function(name) for name, _, _ in aggs]
+
+    def kernel(key, tmask, vals, masks):
+        inputs = [(v, tmask if m is None else m) for v, m in zip(vals, masks)]
+        return planner.sparse_grouped_tables(fns, inputs, tmask, key, num_slots, order_spec, num_groups=groups, vranges=vranges)
+
+    before = METRICS.counter(LIMB).value, METRICS.counter(PREFIX).value
+    uniq, parts = jax.jit(kernel)(key, tmask, [v for _, v, _ in aggs], [m for _, _, m in aggs])
+    moved = METRICS.counter(LIMB).value - before[0], METRICS.counter(PREFIX).value - before[1]
+    return np.asarray(uniq), [{f: np.asarray(t) for f, t in p.items()} for p in parts], *moved
+
+
 def _tables(rows, num_slots, order_spec=None, fn_name="sum", groups=GROUPS):
-    """sparse_grouped_tables under jit -> (uniq, partials) as numpy, and how far LIMB moved at trace time."""
-    fn = get_agg_function(fn_name)
-
-    def kernel(vals, mask, tmask, key):
-        return planner.sparse_grouped_tables(
-            [fn], [(vals, mask)], tmask, key, num_slots, order_spec, num_groups=groups, vranges=[rows["vrange"]])
-
-    before = METRICS.counter(LIMB).value
-    uniq, (part,) = jax.jit(kernel)(rows["vals"], rows["mask"], rows["tmask"], rows["key"])
-    return np.asarray(uniq), {f: np.asarray(t) for f, t in part.items()}, METRICS.counter(LIMB).value - before
+    """One aggregate with a mask of its own over `rows` -> (uniq, its tables, how far LIMB moved at trace time)."""
+    uniq, (part,), moved, _ = _plan_tables(rows["key"], rows["tmask"], [(fn_name, rows["vals"], rows["mask"])], num_slots,
+                                           order_spec, groups, [rows["vrange"]])
+    return uniq, part, moved
 
 
 def _numpy_groups(rows):
@@ -163,9 +173,9 @@ def test_min_max_and_sumsq_keep_the_parents_scatter(fn_name, fields, chip_path, 
     assert all(part[f].dtype == w_part[f].dtype and np.array_equal(part[f], w_part[f]) for f in fields)
 
 
-def test_a_key_space_past_2_31_keeps_its_int64_key_scatter(chip_path):
-    """The int32 key table is for the branch that sorts int32 keys; past it the keys are the parent's int64
-    `.at[].set`, the counts and sums still limb tables."""
+def test_a_key_space_past_2_31_keeps_its_int64_keys(chip_path):
+    """Past 2^31 the rows sort by their int64 keys and the slots' keys are gathered as int64, the counts and
+    sums still read from int32 prefix sums."""
     rows = _rows(45, 20_000, CASES["int32_column_with_stats"]["values"], (0, 59_999), groups=5_000)
     rows["key"] = rows["key"] + ((1 << 31) - 10) * (rows["key"] % 2)
     uniq, part, moved = _tables(rows, 20_000, groups=(1 << 31) + 5_000)
@@ -197,3 +207,209 @@ def test_the_mv_explode_rides_the_limb_tables(chip_path):
             want[(t, c)] = (cnt + 1, s + v) if v != 17 else (cnt, s)
     assert [(r[0], r[1], int(r[2]), int(r[3])) for r in res.rows] == [(t, c, *want[(t, c)]) for t, c in sorted(want)]
     assert max(s for _, s in want.values()) > 1 << 31
+
+
+# ---------------------------------------------------------------------------
+# PR 44: counts and integer sums read from prefix sums over the sorted rows
+# ---------------------------------------------------------------------------
+# After the sort a group's rows are contiguous, so under "chunked32" ONE
+# row-length scatter records each slot's first row and a count / an integer
+# sum is the difference of an int32 prefix sum (one an 8-bit limb:
+# segmented.prefix_limb_bits) at the slot's two bounds.  Every case below is
+# a whole plan, held to a numpy group-by at difference 0 and to the "wide"
+# policy's 64-bit scatters field by field and bit by bit.
+I32 = np.iinfo(np.int32)
+_INT_EXPR = CASES["int32_expression_negative"]["values"]
+
+
+def _numpy_plan(key, tmask, aggs):
+    """{key: [{field: value} an aggregate]} over the filtered rows: counts, exact integer sums (Python
+    integers), float sums in row order, minima."""
+    out = {}
+    for k in np.unique(key[tmask]).tolist():
+        row = []
+        for name, vals, mask in aggs:
+            at = (key == k) & (tmask if mask is None else mask)
+            fields = {"count": int(at.sum())}
+            if name == "sum":
+                fields["sum"] = sum(vals[at].tolist()) if np.issubdtype(vals.dtype, np.integer) else float(vals[at].sum())
+            elif name == "min":
+                fields["min"] = float(vals[at].min()) if at.any() else np.inf
+            row.append(fields)
+        out[k] = row
+    return out
+
+
+def _assert_plan(key, tmask, aggs, num_slots, keep, monkeypatch, order_spec=None, groups=GROUPS, limb=1):
+    """The plan's tables under "chunked32" hold exactly the groups `keep` of the numpy group-by, integers at
+    difference 0; PREFIX moved; the "wide" policy's tables are the same bit for bit and PREFIX stays."""
+    uniq, parts, limb_moved, prefix_moved = _plan_tables(key, tmask, aggs, num_slots, order_spec, groups)
+    assert (limb_moved, prefix_moved) == (limb, 1)
+    assert uniq.shape == (num_slots,) and all(t.shape == (num_slots,) for p in parts for t in p.values())
+    want = _numpy_plan(key, tmask, aggs)
+    live = uniq != planner.SPARSE_EMPTY_KEY
+    assert sorted(uniq[live].tolist()) == sorted(keep(want)) and len(set(uniq[live].tolist())) == int(live.sum())
+    for slot in np.flatnonzero(live).tolist():
+        for (name, vals, _), part, ref in zip(aggs, parts, want[int(uniq[slot])]):
+            assert part["count"].dtype == np.int64 and int(part["count"][slot]) == ref["count"]
+            if name == "sum" and np.issubdtype(vals.dtype, np.integer):
+                assert abs(ref["sum"]) < 1 << 53 and part["sum"].dtype == np.float64
+                assert part["sum"][slot] == ref["sum"] and int(part["sum"][slot]) == ref["sum"]
+            elif name == "sum":
+                assert part["sum"][slot] == pytest.approx(ref["sum"], rel=1e-12, abs=1e-9)
+            elif name == "min":
+                assert part["min"][slot] == ref["min"]
+    # a slot without a group is empty in every table
+    for (name, _, _), part in zip(aggs, parts):
+        assert not part["count"][~live].any() and (name != "sum" or not part["sum"][~live].any())
+    monkeypatch.setattr(ops, "accum_policy", lambda: "wide")
+    w_uniq, w_parts, w_limb, w_prefix = _plan_tables(key, tmask, aggs, num_slots, order_spec, groups)
+    assert (w_limb, w_prefix) == (0, 0)
+    assert np.array_equal(uniq, w_uniq)
+    for part, w_part in zip(parts, w_parts):
+        assert sorted(part) == sorted(w_part)
+        assert all(part[f].dtype == w_part[f].dtype and np.array_equal(part[f], w_part[f]) for f in part)
+    return uniq, parts, want
+
+
+def _every(want):
+    return list(want)
+
+
+def _drawn(seed, n, selectivity, groups=GROUPS):
+    rng = np.random.default_rng(seed)
+    return rng, rng.integers(0, groups, n).astype(np.int64), rng.random(n) < selectivity
+
+
+@pytest.mark.parametrize("selectivity", [1e-4, 0.5, 0.0, 1.0], ids=["1e-4", "half", "no_row_passes", "every_row_passes"])
+def test_prefix_form_at_the_filters_selectivities(selectivity, chip_path, monkeypatch):
+    """SUM of an int32 expression under the filter's own mask (`mask is tmask`: the count is the row range's
+    length).  No row passing: every slot empty.  Every row passing: no filtered row sorts last, the last group
+    ends on the last row and the key space's top key is among the groups."""
+    n = 200_000
+    rng, key, tmask = _drawn(50, n, selectivity)
+    key[-1] = key[7] = GROUPS - 1
+    vals = _INT_EXPR(rng, n, key)
+    uniq, parts, want = _assert_plan(key, tmask, [("sum", vals, None)], GROUPS, _every, monkeypatch)
+    assert len(want) == {1e-4: len(np.unique(key[tmask])), 0.5: GROUPS, 0.0: 0, 1.0: GROUPS}[selectivity]
+    if selectivity == 1.0:
+        assert uniq[GROUPS - 1] == GROUPS - 1 and parts[0]["count"].sum() == n
+    if selectivity == 0.0:
+        assert (uniq == planner.SPARSE_EMPTY_KEY).all()
+
+
+def test_prefix_form_drops_the_overflow_slot_and_the_lowest_keys_win(chip_path, monkeypatch):
+    """More groups than slots, no ORDER BY on an aggregate: the first num_slots groups by packed key keep a slot,
+    the last of them ends where the first trimmed group starts, and the trimmed groups' rows are nobody's."""
+    rng, key, tmask = _drawn(51, 40_000, 0.9)
+    slots = 500
+    uniq, parts, want = _assert_plan(key, tmask, [("sum", _INT_EXPR(rng, 40_000, key), None)], slots,
+                                     lambda want: sorted(want)[:slots], monkeypatch)
+    assert len(want) > slots and (uniq != planner.SPARSE_EMPTY_KEY).all() and (np.diff(uniq) > 0).all()
+
+
+@pytest.mark.parametrize("value", [I32.max, I32.min, -1], ids=["int32_max", "int32_min", "minus_one"])
+def test_one_group_of_2_20_plus_1_rows_at_the_limbs_extremes(value, chip_path, monkeypatch):
+    """The wrap probe: ONE group holds every row, each limb at its largest value (int32 max: 255, 255, 255, 127;
+    int32 min and -1: the negatives' column at -1 a row, whose prefix is negative all the way).  A 12-bit limb
+    would sum to 4,095 x (2^20 + 1) > 2^31 here; prefix_limb_bits keeps (2^bits - 1) x rows under 2^31."""
+    n = (1 << 20) + 1
+    assert ((1 << segmented._WIDE_LIMB_BITS) - 1) * n > 1 << 31 > ((1 << segmented.prefix_limb_bits(n)) - 1) * n
+    key, tmask = np.full(n, 1_234, np.int64), np.ones(n, bool)
+    uniq, parts, want = _assert_plan(key, tmask, [("sum", np.full(n, value, np.int32), None)], 16, _every, monkeypatch)
+    assert uniq[0] == 1_234 and parts[0]["count"][0] == n and int(parts[0]["sum"][0]) == value * n
+
+
+@pytest.mark.parametrize("magnitude,groups", [(44, GROUPS), (50, 39_000)], ids=["past_2_44", "past_2_48_few_rows_a_group"])
+def test_prefix_form_of_an_int64_sum_input(magnitude, groups, chip_path, monkeypatch):
+    """An int64 input past int32 ("int64_sum": signed-magnitude limbs, the sign riding each limb, so a
+    prefix runs below zero and back).  With |v| in [2^48, 2^50) a few rows a group keep the sums below 2^53."""
+    n = 40_000
+    rng, key, tmask = _drawn(52, n, 0.9, groups=groups)
+    lowest = 1 << 48 if magnitude == 50 else 0
+    vals = rng.integers(lowest, 1 << magnitude, n).astype(np.int64) * (rng.integers(0, 2, n) * 2 - 1)
+    _assert_plan(key, tmask, [("sum", vals, None)], groups, _every, monkeypatch, groups=groups)
+
+
+def test_prefix_form_under_an_aggregates_own_filter(chip_path, monkeypatch):
+    """`SUM(v) FILTER (WHERE ..)` beside a plain SUM: the aggregate's mask is not the filter's, so its count
+    is a prefix sum of the permuted mask, not the range's length, and a group none of whose rows pass it
+    keeps its slot with count 0."""
+    n = 40_000
+    rng, key, tmask = _drawn(53, n, 0.9)
+    vals = _INT_EXPR(rng, n, key)
+    own = tmask & (rng.random(n) < 0.3) & (key % 5 != 0)
+    uniq, parts, want = _assert_plan(key, tmask, [("sum", vals, own), ("sum", vals, None)], GROUPS, _every, monkeypatch)
+    live = uniq != planner.SPARSE_EMPTY_KEY
+    assert (parts[0]["count"][live & (uniq % 5 == 0)] == 0).all() and (parts[1]["count"][live] > 0).all()
+    assert (parts[0]["count"] < parts[1]["count"])[live].any()
+
+
+def _rank(field, agg, descending):
+    return lambda want: sorted(want, key=lambda k: ((-1 if descending else 1) * want[k][agg][field], k))
+
+
+@pytest.mark.parametrize("kind", ["sum", "count", "min"])
+def test_prefix_form_under_the_order_by_aware_trim(kind, chip_path, monkeypatch):
+    """Fewer slots than groups and ORDER BY an aggregate: slots are ranks, not row order, so a slot's end is
+    its group's next start (`nxt`) and two neighbouring slots' ranges do not adjoin."""
+    n, slots = 40_000, 400
+    rng, key, tmask = _drawn(54, n, 0.9)
+    vals = _INT_EXPR(rng, n, key)
+    aggs, order_spec, keep = {
+        "sum": ([("sum", vals, None)], (0, "sum", False), _rank("sum", 0, True)),
+        "count": ([("count", vals, None), ("sum", vals, None)], (0, "count", False), _rank("count", 0, True)),
+        "min": ([("sum", vals, None), ("min", vals, None)], (1, "min", True), _rank("min", 1, False)),
+    }[kind]
+    uniq, parts, want = _assert_plan(key, tmask, aggs, slots, lambda want: keep(want)[:slots], monkeypatch, order_spec)
+    assert len(want) > slots and uniq.tolist() == keep(want)[:slots]  # the slots ARE the ranks
+
+
+def test_a_float_sum_and_a_min_keep_their_scatters_beside_an_integer_sum(chip_path, monkeypatch):
+    """One plan: SUM(int) (prefix sums), SUM(float) and MIN(int) (their f64 scatters on `slot`, as they were),
+    every count the prefix form's.  The kept scatters still write the right slots."""
+    n = 40_000
+    rng, key, tmask = _drawn(55, n, 0.9)
+    vals = _INT_EXPR(rng, n, key)
+    floats = np.round(rng.random(n) * 1000.0, 3)
+    _assert_plan(key, tmask, [("sum", vals, None), ("sum", floats, None), ("min", vals, None)], 500,
+                 lambda want: sorted(want)[:500], monkeypatch)
+
+
+def test_a_plan_of_floats_alone_still_counts_by_prefix(chip_path, monkeypatch):
+    """No integer sum: LIMB stays, PREFIX moves (the counts)."""
+    rng, key, tmask = _drawn(56, 20_000, 0.9)
+    _assert_plan(key, tmask, [("sum", np.round(rng.random(20_000) * 10.0, 2), None)], GROUPS, _every, monkeypatch, limb=0)
+
+
+@pytest.mark.parametrize("rows,bits", [(1, 8), (1_500_000, 8), (8_421_504, 8), (8_421_505, 7), (1 << 24, 7),
+                                       (1 << 27, 4), ((1 << 31) - 1, 1)])
+def test_prefix_limb_bits_keeps_a_whole_segment_of_one_group_inside_int32(rows, bits):
+    assert segmented.prefix_limb_bits(rows) == bits
+    assert ((1 << bits) - 1) * rows < 1 << 31 and (bits == 8 or ((1 << (bits + 1)) - 1) * rows >= 1 << 31)
+
+
+def test_prefix_limb_bits_refuses_rows_past_int32():
+    with pytest.raises(ValueError):
+        segmented.prefix_limb_bits(1 << 31)
+
+
+@pytest.mark.parametrize("bits", [3, 7])
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+def test_narrower_limbs_of_a_longer_segment_give_the_same_tables(dtype, bits, chip_path, monkeypatch):
+    """A segment past 2^23 rows takes limbs under 8 bits; the width is steered here (a 2^24-row sort is not a
+    tier-1 case) and the tables are the 8-bit ones: 7 and 3 bits do not divide 32, so a limb straddles an
+    int64's halves."""
+    monkeypatch.setattr(segmented, "prefix_limb_bits", lambda rows: bits)
+    n = 20_000
+    rng, key, tmask = _drawn(57, n, 0.9)
+    vals = rng.integers(I32.min, I32.max, n).astype(dtype) if dtype is np.int32 else rng.integers(-(1 << 50), 1 << 50, n)
+    _assert_plan(key, tmask, [("sum", vals, None)], GROUPS, _every, monkeypatch)
+
+
+@pytest.mark.parametrize("rows", [0, 1])
+def test_prefix_form_of_an_empty_and_a_one_row_segment(rows, chip_path, monkeypatch):
+    """No row at all (an empty segment: every gather reads the sentinel appended past the last row) and one."""
+    key, tmask = np.full(rows, 9, np.int64), np.ones(rows, bool)
+    uniq, parts, _ = _assert_plan(key, tmask, [("sum", np.full(rows, -7, np.int32), None)], 4, _every, monkeypatch)
+    assert uniq.tolist() == [9] * rows + [planner.SPARSE_EMPTY_KEY] * (4 - rows) and parts[0]["sum"].tolist() == [-7.0] * rows + [0.0] * (4 - rows)

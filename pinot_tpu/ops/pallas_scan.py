@@ -13,10 +13,14 @@ tiles, so each input byte is read exactly once:
                dictionary-code range predicate, 8-bit-limb extraction
                (two's-complement int32 / signed-magnitude int64 halves),
                group-major two-level one-hot (A [Hp, C], B [W, C]) pair
-               shared by every limb, one bf16 A.B^T MXU matmul per limb
-               column (operands are integers <= 255: exact)
-  tile store:  int32 accumulation into a VMEM-resident [L, Hp, W] block,
-               revisited across the tiles of one "super-segment"
+               shared by every limb; the narrower of the two carries the
+               limbs, its L weighted copies stacked into one [L * S, C]
+               operand, and ONE bf16 X.Y^T MXU matmul a chunk meets the
+               stack with the other one-hot for all L limb columns at once
+               (operands are integers <= 255: exact)
+  tile store:  int32 accumulation into a VMEM-resident block as wide as
+               that matmul's result ([L * S, F] or [F, L * S]), revisited
+               across the tiles of one "super-segment"
 
 Exactness contract (matches segmented.fused_group_tables bit-for-bit on
 integer kinds): every limb is < 256 so each per-chunk f32 dot accumulates
@@ -60,7 +64,9 @@ from jax.experimental import pallas as pl
 # key's tile holds each lane's rows as one run of whole sublane rows.
 _TILE = packing.BLOCK_ROWS
 # Rows per in-kernel chunk: the [Hp, chunk] / [W, chunk] one-hot working set
-# (Hp <= 128 sublanes) stays a few MB under the 16MB VMEM budget.
+# (Hp <= 128 sublanes) stays a few MB under the 16MB VMEM budget, and the
+# stack of weighted one-hots is never built there (Mosaic folds the select
+# into the MXU's masked operands).  2048 measured 8-10 % slower (PR 45).
 _TILE_CHUNK = 4096
 # Grid steps per int32 accumulator "super-segment": 256 * 2^15 = 2^23 rows,
 # so a per-limb super sum is <= 255 * 2^23 < 2^31 - 1 (int32 exact).
@@ -128,6 +134,22 @@ def pallas_supported(entries, num_groups: int) -> bool:
     return True
 
 
+def _stack_streams(rows: int, fixed_rows: int) -> bool:
+    """Is the stack of `rows` weighted one-hot rows the chunk matmul's LEFT
+    operand, against the `fixed_rows` (<= 128) of the shared one-hot?
+
+    The MXU holds 128 rows of a matmul's right operand a pass and streams
+    the whole left operand through each; a streamed row costs about three
+    times a held one on a v5e (its f32 results are popped and added on the
+    VPU: the MXU keeps no sum across the chunk's 128-row slices).  So the
+    side that makes fewer row-passes streams: the stack where it is small
+    against the shared one-hot (a lone COUNT over a wide table), the shared
+    one-hot against ceil(rows / 128) held tiles of the stack otherwise.
+    Measured on the chip over the benchmark's plans, PERF.md section 6
+    (PR 45): each side wins or ties where this picks it."""
+    return rows <= -(-rows // 128) * fixed_rows
+
+
 def _lane_unpack(w, bits: int):
     """In-register unpack of INTERLEAVED lanes: a (1, rows * bits // 32) row
     of int32 words -> a (1, rows) row of int32 lanes, lane l of word i
@@ -189,7 +211,7 @@ def fused_group_tables_pallas(
     if not pallas_supported(entries, num_groups):
         raise ValueError("entries not eligible for the Pallas fused scan")
 
-    T, C = _TILE, _TILE_CHUNK
+    T = _TILE
     n_tiles = max(1, -(-n // T))
     n_super = -(-n_tiles // _SUPER_TILES)
     H = -(-num_groups // _W)
@@ -207,7 +229,7 @@ def fused_group_tables_pallas(
 
     def _super_map(i):
         z = np.int32(0)
-        return (lax.div(i, np.int32(_SUPER_TILES)), z, z, z)
+        return (lax.div(i, np.int32(_SUPER_TILES)), z, z)
 
     # inputs[k] covers rows_per[k] rows per element: 1 for a row-length
     # operand, 32 // bits for packed words
@@ -285,6 +307,13 @@ def fused_group_tables_pallas(
             col += nl
         scales_per_entry.append(scales)
     L = col
+    # the limb weights ride the narrower one-hot (S sublanes a column), the
+    # other one (F sublanes) meets all L columns as it is; (L, Hp) alone say
+    # which and how the two are laid into the chunk's one matmul
+    weigh_a = Hp <= _W
+    S, F = (Hp, _W) if weigh_a else (_W, Hp)
+    C = _TILE_CHUNK
+    stack_lhs = _stack_streams(L * S, F)
 
     with jax.named_scope("scan_operands"):
         if n % T:
@@ -367,70 +396,72 @@ def fused_group_tables_pallas(
                 pm = (pc >= plo) & (pc < phi)
                 base = pm if base is None else base & pm
 
-        # one (A, B) one-hot pair shared by EVERY limb matmul of the chunk —
+        # one (A, B) one-hot pair shared by EVERY limb column of the chunk —
         # the same sharing that makes the fused XLA scan 3x faster than
         # per-table scans, now also sharing the single HBM read.  Both are
         # group-major ([Hp, C] / [W, C]); codes are >= 0 so shift/mask is
         # the hi/lo split.
         a_hot = lax.broadcasted_iota(i32, (Hp, C), 0) == (ki >> np.int32(_W_SHIFT))
         b_hot = lax.broadcasted_iota(i32, (_W, C), 0) == (ki & np.int32(_W - 1))
-        # the per-row limb weight rides whichever one-hot is narrower; every
+        # the per-row limb weights ride whichever one-hot is narrower; every
         # operand value is an integer of magnitude <= 255, exact in bf16
-        weigh_a = Hp <= _W
         hot, other = (a_hot, b_hot) if weigh_a else (b_hot, a_hot)
         fixed = other.astype(jnp.float32).astype(jnp.bfloat16)
 
-        @jax.named_scope("onehot_accumulate")
-        def accum(col_ix, wcol):
-            weighed = jnp.where(hot, wcol.astype(jnp.float32), np.float32(0)).astype(
-                jnp.bfloat16
-            )
-            lhs, rhs = (weighed, fixed) if weigh_a else (fixed, weighed)
-            s = lax.dot_general(
-                lhs, rhs, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            out_ref[0, col_ix] = out_ref[0, col_ix] + s.astype(i32)
-
-        # value transform: each entry's masked values as 8-bit limbs, one
-        # accumulate per limb
-        for kind, m_ix, v_ixs, lp, col0 in plans:
-            with jax.named_scope("value_transform"):
+        # value transform: each entry's masked values as 8-bit limb rows,
+        # in column order
+        limb_rows = []
+        with jax.named_scope("value_transform"):
+            for kind, m_ix, v_ixs, lp, _col0 in plans:
                 m = row(m_ix) != zero
                 if base is not None:
                     m = m & base
-            if kind == "count":
-                accum(col0, jnp.where(m, one, zero))
-            elif kind == "int_sum":
-                n_limbs, signed = lp
-                with jax.named_scope("value_transform"):
+                if kind == "count":
+                    limb_rows.append(jnp.where(m, one, zero))
+                elif kind == "int_sum":
+                    n_limbs, signed = lp
                     vm = jnp.where(m, row(v_ixs[0]), zero)
-                for k in range(n_limbs):
                     # arithmetic shift then mask == the two's-complement byte
-                    accum(col0 + k, (vm >> np.int32(8 * k)) & byte)
-                if signed:
-                    accum(col0 + n_limbs, jnp.where(vm < zero, one, zero))
-            else:  # int64_sum: signed-magnitude limbs of the (lo, hi) halves
-                with jax.named_scope("value_transform"):
+                    limb_rows += [(vm >> np.int32(8 * k)) & byte for k in range(n_limbs)]
+                    if signed:
+                        limb_rows.append(jnp.where(vm < zero, one, zero))
+                else:  # int64_sum: signed-magnitude limbs of the (lo, hi) halves
                     lo_h = row(v_ixs[0])
                     hi_h = row(v_ixs[1])
                     neg = hi_h < zero
                     alo = jnp.where(neg, -lo_h, lo_h)  # wrapping: ~lo + 1
                     ahi = jnp.where(neg, ~hi_h + jnp.where(lo_h == zero, one, zero), hi_h)
                     sgn = jnp.where(m, jnp.where(neg, np.int32(-1), one), zero)
-                for k in range(lp):
-                    h = alo if k < 4 else ahi
-                    accum(col0 + k, ((h >> np.int32(8 * (k % 4))) & byte) * sgn)
+                    limb_rows += [
+                        (((alo if k < 4 else ahi) >> np.int32(8 * (k % 4))) & byte) * sgn
+                        for k in range(lp)
+                    ]
+
+        # ONE contraction for all of the plan's limb columns: their weighted
+        # one-hots laid one under the other ([L * S, C]) meet the shared
+        # one-hot in a single MXU matmul over the chunk's rows
+        with jax.named_scope("onehot_accumulate"):
+            stacked = jnp.concatenate(
+                [jnp.where(hot, w.astype(jnp.float32), np.float32(0)) for w in limb_rows],
+                axis=0,
+            ).astype(jnp.bfloat16)
+            lhs, rhs = (stacked, fixed) if stack_lhs else (fixed, stacked)
+            s = lax.dot_general(
+                lhs, rhs, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            out_ref[0] = out_ref[0] + s.astype(i32)
 
     # The name is the HLO instruction's and so the device trace's: the dense
     # one-hot scan, its limb columns and its table height in sublanes.  It
     # keeps the `kernel` prefix the benchmark's scan_kernel_ms pattern reads.
+    out_block = (L * S, F) if stack_lhs else (F, L * S)
     out = pl.pallas_call(
         scan_kernel,
         grid=(n_tiles,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, L, Hp, _W), _super_map),
-        out_shape=jax.ShapeDtypeStruct((n_super, L, Hp, _W), jnp.int32),
+        out_specs=pl.BlockSpec((1,) + out_block, _super_map),
+        out_shape=jax.ShapeDtypeStruct((n_super,) + out_block, jnp.int32),
         interpret=bool(interpret),
         name=f"kernel_dense_onehot_l{L}_h{Hp}",
     )(*inputs)
@@ -439,7 +470,13 @@ def fused_group_tables_pallas(
     # an exact integer < 2^31, every partial sum stays < 2^53 under the
     # same contract as the XLA path's per-chunk f64 combine
     with jax.named_scope("recombine_f64"):
-        flat = out.astype(jnp.float64).sum(axis=0).reshape(L, Hp * _W)[:, :num_groups]
+        acc = out.astype(jnp.float64).sum(axis=0)
+        # -> [L, S, F], column-major as the kernel stacked it; a group is
+        # hi * _W + lo, hi along the A one-hot's sublanes
+        acc = acc.reshape(L, S, F) if stack_lhs else acc.reshape(F, L, S).transpose(1, 2, 0)
+        if not weigh_a:
+            acc = acc.transpose(0, 2, 1)
+        flat = acc.reshape(L, Hp * _W)[:, :num_groups]
         tables = []
         for (kind, _m, _v, _lp, col0), scales in zip(plans, scales_per_entry):
             t = flat[col0] if scales[0] == 1.0 else flat[col0] * scales[0]

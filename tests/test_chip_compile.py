@@ -86,6 +86,17 @@ SCAN_CASES = {
                             limbs=(2, True)),
     "unsigned_limbs": _case(["int_sum"], 2406, code_dt=jnp.uint16, val_dt=jnp.uint8,
                             limbs=(1, False)),
+    # the stacked operand (PR 45) at the (limb columns, table sublanes) the
+    # benchmark's cells run: cell 1's four, the star-tree levels' two, and a
+    # 16-column plan (one matmul of a [1024, C] stack against [112, C])
+    "l4_h112": _case(["count", ("int_sum", (3, False))], 7000, words=True),
+    "l6_h72": _case(["count", ("int_sum", (4, True))], 4375, words=True),
+    "l4_h72": _case(["count", ("int_sum", (3, False))], 4375, words=True),
+    "l6_h8": _case(["count", ("int_sum", (4, True))], 175, words=True),
+    "l6_h112": _case(["count", ("int_sum", (4, True))], 7000),
+    "l7_h72": _case(["count", ("int64_sum", 6)], 4375),
+    "l16_h112": _case(["count", ("int_sum", (1, True)), ("int_sum", (4, True)), "int64_sum"], 7000),
+    "l16_h8": _case(["count", ("int_sum", (1, True)), ("int_sum", (4, True)), "int64_sum"], 300),
 }
 
 
@@ -102,12 +113,9 @@ def test_pallas_scan_compiles_for_v5e(one_chip, no_compile_cache, name):
     def scan(codes, mask, v32, v64, mask_words, key_words):
         entries = []
         for k in case["kinds"]:
-            if k == "count":
-                entries.append(("count", None, mask, None))
-            elif k == "int_sum":
-                entries.append(("int_sum", v32, mask, case["limbs"]))
-            else:
-                entries.append(("int64_sum", v64, mask, 8))
+            # a kind alone takes the case's limb plan, a pair brings its own
+            k, lp = k if isinstance(k, tuple) else (k, {"int_sum": case["limbs"], "int64_sum": 8}.get(k))
+            entries.append((k, {"count": None, "int_sum": v32, "int64_sum": v64}[k], mask, lp))
         assert pallas_scan.pallas_supported(entries, case["groups"])
         return pallas_scan.fused_group_tables_pallas(
             entries, codes, case["groups"],
@@ -121,7 +129,10 @@ def test_pallas_scan_compiles_for_v5e(one_chip, no_compile_cache, name):
         shape(ROWS, jnp.int64), shape(ROWS // 32, jnp.uint32),
         shape(ROWS * (packed or 32) // 32, jnp.uint32),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel is in the program
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the Mosaic kernel is in the program
+    if re.fullmatch(r"l\d+_h\d+", name):  # under the name the benchmark's device trace reads
+        assert f"%kernel_dense_onehot_{name}" in text
 
 
 # SSB Q3.2-Q3.4's table (437,500 slots) over one served segment: past the

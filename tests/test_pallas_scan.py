@@ -20,6 +20,7 @@ from pinot_tpu import ops
 from pinot_tpu.ops import pallas_scan, segmented
 from pinot_tpu.parallel.engine import DistributedEngine
 from pinot_tpu.parallel.stacked import StackedTable
+from pinot_tpu.segment import packing
 from pinot_tpu.spi.config import IndexingConfig, TableConfig
 from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
 from pinot_tpu.sql.parser import parse_query
@@ -53,16 +54,167 @@ def _entries(rng, n):
     ]
 
 
-@pytest.mark.parametrize("n", [32, 4096, 4096 * 2 + 32, 1000])  # 1000: pad tail
-@pytest.mark.parametrize("num_groups", [1, 7, 300])
-def test_exactness_vs_xla(rng, n, num_groups):
-    entries = _entries(rng, n)
-    codes = jnp.asarray(rng.integers(0, num_groups, n).astype(np.int32))
-    got = pallas_scan.fused_group_tables_pallas(
-        entries, codes, num_groups, interpret=True
+def _mask(rng, n, p=0.8):
+    return jnp.asarray(rng.random(n) < p)
+
+
+def _words(bits):
+    """A row mask as range-index bitmap words (bit r of word w = row 32 w + r)."""
+    return jnp.asarray(
+        np.packbits(bits.reshape(-1, 32), axis=1, bitorder="little").view(np.uint32).reshape(-1)
     )
-    ref = _reference(entries, codes, num_groups)
-    for g, r in zip(got, ref):
+
+
+def _every_kind(num_groups, n):
+    def build(rng):
+        return _entries(rng, n), rng.integers(0, num_groups, n), num_groups, {}
+
+    return build
+
+
+def _q2_count_and_revenue(rng):
+    """SSB Q2.x in cell 1: 7,000 slots, COUNT and an unsigned three-limb SUM
+    (kernel l4_h112: the stack is the wider one-hot's partner)."""
+    n = 40_000
+    v = rng.integers(0, 2**24, n).astype(np.int32)
+    m = _mask(rng, n)
+    entries = [("count", None, m, None), ("int_sum", jnp.asarray(v), m, (3, False))]
+    return entries, rng.integers(0, 7000, n), 7000, {}
+
+
+def _q4_signed_difference(slots):
+    """SSB Q4.x: SUM(lo_revenue - lo_supplycost), four limbs and the
+    negatives' count beside COUNT (l6_h72 at 4,375 slots; at 175 slots
+    l6_h8, where the table's own one-hot is the narrower and carries the
+    limbs)."""
+
+    def build(rng):
+        n = 40_000
+        v = (rng.integers(0, 2**24, n) - rng.integers(0, 2**25, n)).astype(np.int32)
+        m = _mask(rng, n, 0.6)
+        entries = [("count", None, m, None), ("int_sum", jnp.asarray(v), m, (4, True))]
+        return entries, rng.integers(0, slots, n), slots, {}
+
+    return build
+
+
+def _lone_count(slots):
+    def build(rng):
+        n = 33_000
+        return [("count", None, _mask(rng, n), None)], rng.integers(0, slots, n), slots, {}
+
+    return build
+
+
+def _int64_negative(rng):
+    """Signed-magnitude limbs of an int64 column that is mostly negative,
+    six limbs wide as a star-tree level's sums are (l7_h72)."""
+    n = 40_000
+    v = -rng.integers(0, 2**47, n).astype(np.int64) + rng.integers(0, 2**20, n)
+    m = _mask(rng, n)
+    entries = [("count", None, m, None), ("int64_sum", jnp.asarray(v), m, 6)]
+    return entries, rng.integers(0, 4375, n), 4375, {}
+
+
+def _masks_of_their_own(rng):
+    """COUNT(*) FILTER (...) beside a SUM under another filter: each limb
+    column of the stack carries its entry's mask, not a shared one."""
+    n = 4096 * 3
+    v = rng.integers(-(2**31), 2**31 - 1, n).astype(np.int32)
+    entries = [
+        ("count", None, _mask(rng, n, 0.2), None),
+        ("int_sum", jnp.asarray(v), _mask(rng, n, 0.9), (4, True)),
+        ("count", None, _mask(rng, n, 0.5), None),
+    ]
+    return entries, rng.integers(0, 300, n), 300, {}
+
+
+def _packed_key_words_and_pred(rng):
+    """The key read from its bit-packed forward index, the filter from
+    bitmap words and a dictionary-code range, all unpacked in-register."""
+    n, groups, bits = 2 * packing.BLOCK_ROWS + 4096, 200, 8
+    codes = rng.integers(0, groups, n)
+    v = rng.integers(0, 2**16, n).astype(np.int32)
+    m = _mask(rng, n)
+    pc = rng.integers(0, 60, n).astype(np.int32)
+    wbits = rng.random(n) < 0.5
+    entries = [("count", None, m, None), ("int_sum", jnp.asarray(v), m, (2, False))]
+    kwargs = dict(
+        mask_words=_words(wbits),
+        code_pred=(jnp.asarray(pc), 10, 40),
+        codes_packed=(jnp.asarray(packing.pack_codes(codes.astype(np.uint32), bits)), bits),
+    )
+    return entries, codes, groups, kwargs
+
+
+def _ragged_rows(rng):
+    """Neither a tile, a chunk nor 32 rows divide the row count."""
+    n = packing.BLOCK_ROWS + 4096 + 77
+    v = rng.integers(-(2**15), 2**15, n).astype(np.int16)
+    m = _mask(rng, n)
+    entries = [("count", None, m, None), ("int_sum", jnp.asarray(v), m, (2, True))]
+    return entries, rng.integers(0, 4375, n), 4375, {}
+
+
+def _limb_bounds(n):
+    """Every byte 255, every row in ONE group: a chunk's f32 dot reaches
+    255 x 4,096 (< 2^24) and, past 2^23 rows, a super-segment's int32 sum
+    255 x 2^23 (< 2^31) before the next one starts."""
+
+    def build(rng):
+        ones = jnp.ones((n,), bool)
+        entries = [
+            ("count", None, ones, None),
+            ("int_sum", jnp.full((n,), 0xFFFFFF, jnp.int32), ones, (3, False)),
+            ("int_sum", jnp.full((n,), -1, jnp.int32), ones, (4, True)),
+        ]
+        return entries, np.full(n, 5), 70, {}
+
+    return build
+
+
+# the seed's grid (every kind, four row counts, three table widths), then the
+# plans the benchmark's cells run and the edges of the stacked operand
+EXACT_CASES = {
+    f"every_kind_g{g}_n{n}": _every_kind(g, n)
+    for g in (1, 7, 300)
+    for n in (32, 4096, 4096 * 2 + 32, 1000)  # 1000: pad tail
+}
+EXACT_CASES.update(
+    l4_h112_count_and_three_limbs=_q2_count_and_revenue,
+    l6_h72_signed_difference=_q4_signed_difference(4375),
+    l6_h8_signed_difference=_q4_signed_difference(175),
+    l1_h8_lone_count=_lone_count(175),
+    l1_h112_lone_count=_lone_count(7000),
+    l7_h72_int64_negative=_int64_negative,
+    masks_of_their_own=_masks_of_their_own,
+    packed_key_words_and_pred=_packed_key_words_and_pred,
+    ragged_rows=_ragged_rows,
+    limb_bounds_one_chunk=_limb_bounds(4096),
+    limb_bounds_past_a_super_segment=_limb_bounds((1 << 23) + 4096),
+)
+
+
+@pytest.mark.parametrize("case", sorted(EXACT_CASES))
+def test_exactness_vs_xla(rng, case):
+    """Bit for bit against the XLA scan (the same filter handed over as
+    plain row masks) and, entry by entry, the single-table API."""
+    entries, codes, num_groups, kwargs = EXACT_CASES[case](rng)
+    codes = jnp.asarray(np.asarray(codes).astype(np.int32))
+    got = pallas_scan.fused_group_tables_pallas(
+        entries, codes, num_groups, interpret=True, **kwargs
+    )
+    n = int(codes.shape[0])
+    rows = np.ones(n, bool)
+    if "mask_words" in kwargs:
+        rows &= np.asarray(segmented.unpack_bitmap_words(kwargs["mask_words"], n))
+    if "code_pred" in kwargs:
+        pc, lo, hi = kwargs["code_pred"]
+        rows &= (np.asarray(pc) >= lo) & (np.asarray(pc) < hi)
+    plain = [(k, v, jnp.asarray(np.asarray(m) & rows), lp) for k, v, m, lp in entries]
+    xla = segmented.fused_group_tables(plain, codes, num_groups)
+    for g, x, r in zip(got, xla, _reference(plain, codes, num_groups)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
         np.testing.assert_array_equal(np.asarray(g), r)
 
 
@@ -72,12 +224,7 @@ def test_word_mask_and_code_pred_fusion(rng):
     n = 4096 * 3 + 32
     entries = _entries(rng, n)
     codes = jnp.asarray(rng.integers(0, 50, n).astype(np.int32))
-    bits = rng.random(n) < 0.5
-    words = jnp.asarray(
-        np.packbits(bits.reshape(-1, 32), axis=1, bitorder="little")
-        .view(np.uint32)
-        .reshape(-1)
-    )
+    words = _words(rng.random(n) < 0.5)
     lo, hi = 10, 40
     got = pallas_scan.fused_group_tables_pallas(
         entries, codes, 50, mask_words=words, code_pred=(codes, lo, hi), interpret=True

@@ -40,13 +40,11 @@ WARM_PATH_SUFFIXES = (
 )
 WARM_PATH_DIRS = ("/ops/",)
 
-# the sanctioned warm-path fences (r8 device_wait): one block_until_ready
+# the sanctioned warm-path fence (r8 device_wait): one block_until_ready
 # over all pending outputs, splitting device time from host dispatch in the
-# trace tree — execute_batch carries the identical fence for the vmapped
-# cross-query launches (trace-enabled only)
+# trace tree (trace-enabled only)
 ALLOWED_SYNCS: Set[Tuple[str, str]] = {
     ("cluster/server.py", "ServerInstance.execute"),
-    ("cluster/server.py", "ServerInstance.execute_batch"),
 }
 
 _DEVICE_PREFIXES = ("jax.numpy.", "jax.lax.")

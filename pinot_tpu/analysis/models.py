@@ -13,9 +13,6 @@ declares the protocol's correctness argument as executable invariants:
   admission — `ResourceBudget.reserve_or_wait` never overcommits the
       byte budget, and parked staged-fetch waiters are always woken or
       timed out (no lost wakeups).
-  batcher — every submitted future settles exactly once (no lost and no
-      double-settled futures across the flush / full-group / runner-crash
-      races).
   lease — at most one epoch appends to the journal at a time: epochs in
       the journal never decrease, and a deposed writer is always fenced
       before its stale append lands.
@@ -353,134 +350,6 @@ def _make_notify_one() -> type:
 
 
 # ---------------------------------------------------------------------------
-# batcher: no lost and no double-settled futures
-# ---------------------------------------------------------------------------
-class BatcherModel(BaseModel):
-    name = "batcher"
-    MUTATIONS = ("double_run", "lost_on_crash")
-
-    def setup(self) -> None:
-        from pinot_tpu.cluster.batcher import MicroBatcher
-
-        cls = MicroBatcher
-        if self.mutation == "double_run":
-            cls = _make_double_run()
-        elif self.mutation == "lost_on_crash":
-            cls = _make_no_safety_net()
-        # runner crashes mid-group only in the crash scenario; the intact
-        # batcher's safety net turns that into failed futures (handled
-        # below), the mutated twin silently loses the rest of the group
-        self.crashy = self.mutation == "lost_on_crash"
-        self.b = cls(self._runner, wait_ms=50.0, max_batch=2, clock=threads.monotonic)
-        self.futures: List[Any] = []
-        self.results: Dict[int, Any] = {}
-        self.submitted = 0
-        self.all_submitted = threads.Event()
-
-    def _runner(self, entries: List[Any]) -> None:
-        for i, e in enumerate(entries):
-            if self.crashy and len(entries) >= 2 and i == 1:
-                raise RuntimeError("runner crash mid-group")
-            e.future.set_result(e.payload * 2)
-
-    def _submit(self, idx: int, payload: int) -> None:
-        f = self.b.submit("k", payload)
-        self.futures.append(f)
-        self.submitted += 1
-        if self.submitted >= 2:
-            self.all_submitted.set()
-        try:
-            self.results[idx] = f.result(timeout=10_000)
-        except RuntimeError as e:
-            # the safety net failing a crashed group's futures is correct
-            # protocol behavior — record and move on
-            self.results[idx] = e
-
-    def _pump(self) -> None:
-        if not self.all_submitted.wait(timeout=10_000):
-            raise RuntimeError("submitters never arrived")
-        for _ in range(3):
-            threads.checkpoint()
-            self.b.pump(now=threads.monotonic() + 1.0)
-        self.b.flush()
-
-    def threads(self) -> List[Tuple[str, Callable[[], None]]]:
-        return [
-            ("submit-1", lambda: self._submit(1, 10)),
-            ("submit-2", lambda: self._submit(2, 20)),
-            ("pumper", self._pump),
-        ]
-
-    def invariants(self) -> List[Tuple[str, Callable[[], Optional[str]]]]:
-        def settle_once() -> Optional[str]:
-            for f in self.futures:
-                attempts = getattr(f, "resolve_attempts", 0)
-                if attempts > 1:
-                    return f"future settled {attempts} times (double-run group)"
-            return None
-
-        return [("futures-settle-at-most-once", settle_once)]
-
-    def at_quiescence(self) -> List[Tuple[str, Callable[[], Optional[str]]]]:
-        def all_settled() -> Optional[str]:
-            pending = sum(len(g.entries) for g in self.b._groups.values())
-            if pending:
-                return f"{pending} submissions never flushed"
-            if set(self.results) != {1, 2}:
-                return f"results missing for {sorted({1, 2} - set(self.results))}"
-            for idx, payload in ((1, 10), (2, 20)):
-                got = self.results[idx]
-                if not isinstance(got, RuntimeError) and got != payload * 2:
-                    return f"submit-{idx} got {got!r}, wanted {payload * 2}"
-            return None
-
-        return [("no-lost-futures", all_settled)]
-
-
-def _make_double_run() -> type:
-    from pinot_tpu.cluster.batcher import MicroBatcher, _Group
-
-    class DoubleRunBatcher(MicroBatcher):
-        def submit(self, key, payload):
-            from pinot_tpu.cluster.batcher import BatchEntry
-
-            entry = BatchEntry(payload)
-            if self.wait_ms <= 0 or self.max_batch <= 1:
-                self._run([entry])
-                return entry.future
-            full = None
-            with self._cv:
-                group = self._groups.get(key)
-                if group is None:
-                    group = _Group(self.clock() + self.wait_ms / 1000.0)
-                    self._groups[key] = group
-                group.entries.append(entry)
-                if len(group.entries) >= self.max_batch:
-                    # MUTATION: the full group is run inline but NOT removed
-                    # from the pending map — the next pump runs it again
-                    full = group.entries
-                else:
-                    self._cv.notify_all()
-            if full is not None:
-                self._run(full)
-            return entry.future
-
-    return DoubleRunBatcher
-
-
-def _make_no_safety_net() -> type:
-    from pinot_tpu.cluster.batcher import MicroBatcher
-
-    class NoSafetyNetBatcher(MicroBatcher):
-        def _run(self, entries) -> None:
-            # MUTATION: no safety net — a runner crash mid-group leaves the
-            # unreached entries' futures unresolved forever
-            self.runner(entries)
-
-    return NoSafetyNetBatcher
-
-
-# ---------------------------------------------------------------------------
 # lease fencing: at most one epoch appends; deposed writer always fenced
 # ---------------------------------------------------------------------------
 class LeaseModel(BaseModel):
@@ -565,8 +434,8 @@ class KnobModel(BaseModel):
     # one controller "tick" always writes these two knobs to the SAME value
     # (both clamp ranges admit it), so any reader observing them unequal —
     # other than the env-default initial pair — caught a mid-tick mix
-    PAIR = ("batch_wait_ms", "hedge_budget_pct")
-    TICKS = (3.0, 5.0, 7.0)
+    PAIR = ("hedge_delay_mult", "hedge_budget_pct")
+    TICKS = (1.5, 2.5, 3.5)
 
     def setup(self) -> None:
         from pinot_tpu.cluster.autopilot import KnobRegistry
@@ -647,7 +516,6 @@ def _make_torn_registry() -> type:
 PROTOCOLS: Dict[str, type] = {
     ResidencyModel.name: ResidencyModel,
     AdmissionModel.name: AdmissionModel,
-    BatcherModel.name: BatcherModel,
     LeaseModel.name: LeaseModel,
     KnobModel.name: KnobModel,
 }

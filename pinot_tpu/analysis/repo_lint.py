@@ -82,28 +82,13 @@ been bitten by (ADVICE r5) or that silently degrades TPU throughput:
                               calls (`plan.fn(...)`) are out of scope:
                               engine code deliberately times dispatch cost
                               there (compile_ms capture).
-  W018 blocking-in-dispatch   a blocking call (time.sleep, block_until_ready,
-                              synchronous device_get/.item()/.tolist(),
-                              socket recv/sendall/accept/connect) inside the
-                              async batch-dispatch path: a method of a
-                              *Batcher class, or a pump/_pump/
-                              *dispatch_loop* function.  The batcher's
-                              worker/pump drains EVERY key's pending groups —
-                              one blocking call there head-of-line blocks
-                              every coalesced query, exactly the stall the
-                              async broker tier exists to avoid.
-                              `Condition.wait` is the sanctioned deadline
-                              wakeup and stays clean; device fences belong
-                              in the submitting caller's thread
-                              (Future.result) or the runner's collect.
   W019 unbounded-retry-loop   a `while` loop in cluster/ that re-issues a
-                              server call (`.execute(...)` /
-                              `.execute_batch(...)`) either without a
+                              server call (`.execute(...)`) either without a
                               bounded backoff (no sleep/_sleep anywhere in
                               the loop body) or without routing the
                               abandoned attempt through the cancel-probe
-                              path (an execute call missing the cancel=/
-                              cancels= keyword).  A retry/hedge loop with
+                              path (an execute call missing the cancel=
+                              keyword).  A retry/hedge loop with
                               neither is a tight retry storm whose
                               abandoned attempts keep burning device time —
                               the r11 cooperative-cancel contract exists
@@ -200,7 +185,6 @@ RULES: Dict[str, str] = {
     "W015": "unbounded container growth on a cluster serving path (no bound/eviction)",
     "W016": "non-durable write to a durability path (no tmp-fsync-replace discipline)",
     "W017": "wall-clock timing around an async jitted dispatch without a device fence before the stop timestamp",
-    "W018": "blocking call (sleep/device fence/socket I/O) inside an async batch-dispatch path",
     "W019": "retry/hedge loop re-issues a server call without bounded backoff or without the cancel-probe path",
     "W020": "packed words widened via .astype() in a Pallas kernel body before the lane unpack (shift first, then cast)",
     "W021": "synchronous jax.device_put of a segment-sized array outside the staging stream (route through the residency manager's budgeted charge)",
@@ -747,7 +731,7 @@ def _check_w022(path: str, tree: ast.AST, findings: List[Finding]) -> None:
 # runtime mutation must go through a clamped KnobRegistry setter, never a
 # bare attribute write that skips the clamp bounds and the atomic swap
 _W026_KNOB_ATTRS = frozenset(
-    {"wait_ms", "pipeline_depth", "staging_depth", "budget_pct", "quantile_mult"}
+    {"pipeline_depth", "staging_depth", "budget_pct", "quantile_mult"}
 )
 # wall clocks forbidden inside the autopilot: the controller's whole test
 # story rides the injected clock (threads.monotonic or a ctor fake)
@@ -1389,62 +1373,12 @@ def is_suppressed(f: Finding, suppressions: Dict[int, Optional[Set[str]]]) -> bo
     return rules is None or f.rule in rules
 
 
-_W018_BLOCKING_ATTRS = frozenset({
-    "block_until_ready", "device_get", "recv", "recv_into", "sendall",
-    "accept", "connect", "create_connection", "item", "tolist",
-})
-
-
-def _check_w018(path: str, tree: ast.AST, findings: List[Finding]) -> None:
-    """Blocking call inside the async batch-dispatch path.  Scope: methods
-    of classes named *Batcher*, plus functions named pump/_pump or
-    containing "dispatch_loop".  These run under (or are the tick of) the
-    coalescing scheduler — a sleep, device fence, host-sync (.item/.tolist)
-    or socket wait there stalls every key's pending groups at once.
-    Condition.wait (the timed wakeup) is deliberately out of the blocking
-    set: it is how the worker sleeps WITHOUT holding up a flush."""
-    scopes: List[ast.AST] = []
-    for node in ast.walk(tree):
-        if isinstance(node, ast.ClassDef) and "Batcher" in node.name:
-            scopes.extend(
-                n for n in node.body
-                if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))
-            )
-        elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            if node.name in ("pump", "_pump") or "dispatch_loop" in node.name:
-                scopes.append(node)
-    seen: Set[int] = set()
-    for fn in scopes:
-        if id(fn) in seen:
-            continue
-        seen.add(id(fn))
-        for n in ast.walk(fn):
-            if not isinstance(n, ast.Call):
-                continue
-            f = n.func
-            blocked = None
-            if isinstance(f, ast.Name) and f.id == "sleep":
-                blocked = "sleep"
-            elif isinstance(f, ast.Attribute):
-                if f.attr == "sleep" or f.attr in _W018_BLOCKING_ATTRS:
-                    blocked = f.attr
-            if blocked:
-                findings.append(Finding(
-                    path, n.lineno, "W018",
-                    f"blocking call `{blocked}` inside async batch-dispatch "
-                    f"path `{fn.name}` — head-of-line blocks every coalesced query",
-                ))
-
-
-_W019_SERVER_CALLS = frozenset({"execute", "execute_batch"})
-
-
 def _check_w019(path: str, tree: ast.AST, findings: List[Finding]) -> None:
     """W019: retry/hedge loop discipline.  A `while` loop that (re-)issues
-    server calls — `.execute(...)` / `.execute_batch(...)` — is the failover
+    server calls — `.execute(...)` — is the failover
     or hedging shape; it must (a) bound its re-issue rate with a backoff
     (some sleep/_sleep call inside the loop body) and (b) route every server
-    call through the cooperative-cancel contract (cancel=/cancels= keyword),
+    call through the cooperative-cancel contract (cancel= keyword),
     so an abandoned attempt can be killed between kernels instead of burning
     device time to completion.  `for` loops are exempt: a fan-out over an
     assignment is not a retry."""
@@ -1457,7 +1391,7 @@ def _check_w019(path: str, tree: ast.AST, findings: List[Finding]) -> None:
             if not isinstance(n, ast.Call):
                 continue
             f = n.func
-            if isinstance(f, ast.Attribute) and f.attr in _W019_SERVER_CALLS:
+            if isinstance(f, ast.Attribute) and f.attr == "execute":
                 server_calls.append(n)
             if (isinstance(f, ast.Name) and f.id in ("sleep", "_sleep")) or (
                 isinstance(f, ast.Attribute) and f.attr in ("sleep", "_sleep")
@@ -1473,11 +1407,11 @@ def _check_w019(path: str, tree: ast.AST, findings: List[Finding]) -> None:
                 "under failure",
             ))
         for call in server_calls:
-            if not any(kw.arg in ("cancel", "cancels") for kw in call.keywords):
+            if not any(kw.arg == "cancel" for kw in call.keywords):
                 findings.append(Finding(
                     path, call.lineno, "W019",
-                    "server call re-issued in a retry loop without cancel=/"
-                    "cancels= — the abandoned attempt can never be "
+                    "server call re-issued in a retry loop without cancel= "
+                    "— the abandoned attempt can never be "
                     "cooperatively cancelled and burns device time to "
                     "completion",
                 ))
@@ -1603,8 +1537,7 @@ def _check_w025(path: str, tree: ast.AST, findings: List[Finding]) -> None:
 def lint_source(src: str, path: str = "<string>", threaded: bool = False) -> List[Finding]:
     """Lint one module's source.  `threaded` enables the cluster/-scoped
     rules (W004 shared-state races, W006 swallowed exceptions, W015
-    unbounded serving-path growth, W018 blocking calls in async
-    batch-dispatch paths)."""
+    unbounded serving-path growth)."""
     findings: List[Finding] = []
     try:
         tree = ast.parse(src)
@@ -1637,7 +1570,6 @@ def lint_source(src: str, path: str = "<string>", threaded: bool = False) -> Lis
         _check_w004(path, tree, findings)
         _check_w006(path, tree, findings)
         _check_w015(path, tree, findings)
-        _check_w018(path, tree, findings)
         _check_w019(path, tree, findings)
     suppressions = parse_suppressions(src)
     if suppressions:

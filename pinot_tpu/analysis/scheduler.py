@@ -5,7 +5,7 @@ that owns every interleaving decision.  Model threads are real OS threads,
 but they run ONE AT A TIME: each parks on its own semaphore and only the
 scheduler's main loop hands out the single run token.  Every primitive
 operation (lock acquire/release, condition wait/notify, event wait/set,
-future resolve, an explicit `threads.checkpoint()`) is a YIELD POINT where
+an explicit `threads.checkpoint()`) is a YIELD POINT where
 the token returns to the scheduler, which picks the next runnable thread
 
   * by a seeded RNG (random-schedule exploration),
@@ -33,14 +33,11 @@ Failure modes the scheduler itself detects:
 
 Primitive semantics mirror the stdlib: non-reentrant Lock, reentrant
 RLock, Condition with FIFO waiters (notify wakes in wait order; woken
-waiters re-contend for the lock), Event, Thread with join, and a Future
-matching `concurrent.futures.Future` closely enough for the batcher
-(InvalidStateError on double-resolve, TimeoutError from `result`).
+waiters re-contend for the lock), Event, and Thread with join.
 """
 from __future__ import annotations
 
 import random
-from concurrent.futures import InvalidStateError, TimeoutError as FutureTimeoutError
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import threading as _real_threading
@@ -81,7 +78,7 @@ class _Task:
         self.target = target
         self.sem = _real_threading.Semaphore(0)
         self.state = NEW
-        self.block_kind: Optional[str] = None   # "lock"|"cond"|"event"|"join"|"future"|"sleep"
+        self.block_kind: Optional[str] = None   # "lock"|"cond"|"event"|"join"|"sleep"
         self.block_obj: Any = None
         self.deadline: Optional[float] = None   # fake-clock deadline for timed waits
         self.timed_out = False
@@ -545,66 +542,6 @@ class SchedThread:
                 return
 
 
-class SchedFuture:
-    """concurrent.futures.Future lookalike: InvalidStateError on double
-    resolution, TimeoutError from result(), waiters parked on the
-    scheduler.  `resolve_attempts` counts resolution calls (including
-    rejected doubles) for the model invariants."""
-
-    def __init__(self, sched: DeterministicScheduler, name: str = "future"):
-        self._sched = sched
-        self.mc_name = name
-        self._done = False
-        self._result: Any = None
-        self._exc: Optional[BaseException] = None
-        self.resolve_attempts = 0
-
-    def done(self) -> bool:
-        return self._done
-
-    def set_result(self, value: Any) -> None:
-        self.resolve_attempts += 1
-        if self._done:
-            raise InvalidStateError(f"{self.mc_name} already resolved")
-        self._done = True
-        self._result = value
-        sched = self._sched
-        if not sched._abort:
-            sched._wake("future", self)
-            sched.yield_point()
-
-    def set_exception(self, exc: BaseException) -> None:
-        self.resolve_attempts += 1
-        if self._done:
-            raise InvalidStateError(f"{self.mc_name} already resolved")
-        self._done = True
-        self._exc = exc
-        sched = self._sched
-        if not sched._abort:
-            sched._wake("future", self)
-            sched.yield_point()
-
-    def exception(self, timeout: Optional[float] = None) -> Optional[BaseException]:
-        self._wait_done(timeout)
-        return self._exc
-
-    def result(self, timeout: Optional[float] = None) -> Any:
-        self._wait_done(timeout)
-        if self._exc is not None:
-            raise self._exc
-        return self._result
-
-    def _wait_done(self, timeout: Optional[float]) -> None:
-        sched = self._sched
-        if sched._abort:
-            return
-        sched.yield_point()
-        while not self._done:
-            timed_out = sched.block("future", self, timeout)
-            if timed_out and not self._done:
-                raise FutureTimeoutError(f"{self.mc_name} unresolved past timeout")
-
-
 # ---------------------------------------------------------------------------
 # the provider
 # ---------------------------------------------------------------------------
@@ -644,9 +581,6 @@ class SchedulerProvider:
 
     def Event(self) -> SchedEvent:
         return SchedEvent(self.sched, name=self._name("event"))
-
-    def Future(self) -> SchedFuture:
-        return SchedFuture(self.sched, name=self._name("future"))
 
     def Thread(self, *args: Any, **kwargs: Any) -> SchedThread:
         global _AMBIENT

@@ -12,7 +12,7 @@ only *overrides*, swapped in as one immutable dict, so
     with the autopilot disabled the whole surface is bit-exact with the
     pre-registry behavior, and tests that monkeypatch env vars still work;
   * a controller write takes effect on the NEXT decision (next query,
-    next refill, next launch) without rebuilding broker/engine/batcher;
+    next refill, next launch) without rebuilding broker/engine;
   * ``view()`` returns one coherent snapshot — a query can never observe
     a mid-tick mix of old and new knob values (the model-checked
     contract: analysis/models.py ``KnobModel``);
@@ -27,7 +27,6 @@ ResourceBudget high-water marks, and moves AT MOST ONE knob per tick
 along a fixed degradation ladder:
 
     shed hedges (budget pct, multiplicative decrease)
-      -> widen the batch window (more coalescing per launch)
       -> shrink the macro-batch pipeline depth
       -> shrink the staging window
       -> cut the admission refill rate
@@ -94,12 +93,6 @@ class KnobSpec:
 
 
 SPECS: Tuple[KnobSpec, ...] = (
-    # broker micro-batcher coalescing window (cluster/batcher.py)
-    KnobSpec(
-        "batch_wait_ms", "PINOT_TPU_BATCH_WAIT_MS", 2.0,
-        lo=lambda i: 0.0, hi=lambda i: max(4.0 * i, i + 6.0),
-        step=1.0, degrade="up",
-    ),
     # macro-batch in-flight launch depth (parallel/engine.py)
     KnobSpec(
         "pipeline_depth", "PINOT_TPU_PIPELINE_DEPTH", 2,
@@ -143,7 +136,6 @@ SPECS: Tuple[KnobSpec, ...] = (
 # degrade order; recovery climbs back the same path in reverse
 LADDER: Tuple[str, ...] = (
     "hedge_budget_pct",
-    "batch_wait_ms",
     "pipeline_depth",
     "staging_depth",
     "admission_rate",

@@ -451,48 +451,6 @@ class HedgeController:
             }
 
 
-class _BatchMember:
-    """One pre-admitted query riding the micro-batcher: the submit-time
-    bracket (query id, trace root, deadline, governor grant + kill probe,
-    cache key) plus the plan/prune products filled in by _plan_member."""
-
-    __slots__ = (
-        "ctx", "sql", "fp", "sfp", "qid", "trace", "deadline", "t0",
-        "grant", "cancel", "ckey", "offline_ctx", "realtime_ctx",
-        "seg_names", "pruned",
-    )
-
-    def __init__(self, ctx, sql, fp, sfp, qid, trace, deadline, t0, grant, cancel, ckey):
-        self.ctx = ctx
-        self.sql = sql
-        self.fp = fp
-        self.sfp = sfp
-        self.qid = qid
-        self.trace = trace
-        self.deadline = deadline
-        self.t0 = t0
-        self.grant = grant
-        self.cancel = cancel
-        self.ckey = ckey
-        self.offline_ctx = ctx
-        self.realtime_ctx = ctx
-        self.seg_names: List[str] = []
-        self.pruned = 0
-
-
-def _has_subquery(node: Optional[FilterNode]) -> bool:
-    """IN (SELECT ...) anywhere in a filter tree — such queries keep the
-    synchronous path (their subqueries recurse through execute())."""
-    if node is None:
-        return False
-    from pinot_tpu.query.ir import Subquery
-
-    if node.op is FilterOp.PRED:
-        p = node.predicate
-        return bool(p is not None and p.values and isinstance(p.values[0], Subquery))
-    return any(_has_subquery(c) for c in (node.children or ()))
-
-
 class Broker:
     def __init__(self, coordinator, selector: str = "balanced"):
         # coordinator HA (r18): the broker never holds a raw Coordinator —
@@ -507,11 +465,6 @@ class Broker:
         self.selector = selector  # "balanced" | "replicagroup" | "adaptive"
         self._rr = 0  # round-robin cursor
         self._rr_lock = threading.Lock()  # cursor bump is an RMW across handler threads
-        # mesh-replica batch routing: whole same-fingerprint batches land on
-        # one replica row each (replica group ≅ mesh replica row), rotated
-        # per BATCH so concurrent batches spread across rows while every
-        # member of a batch shares its row's compiled kernel + staged copy
-        self._batch_rr = 0
         self.quota = QueryQuotaManager()
         self.server_stats = AdaptiveServerStats()
         self.health = ServerHealth()
@@ -558,16 +511,9 @@ class Broker:
         from pinot_tpu.query.planner import attach_plan_cache_budget
 
         attach_plan_cache_budget(self.governor.host_budget)
-        # cross-query micro-batcher (cluster/batcher.py): built lazily on the
-        # first submit() so brokers that never use the async tier never start
-        # its worker.  Tests inject a fake clock via batch_clock BEFORE the
-        # first submit and drive flushes deterministically with pump().
-        self.batch_clock = None
-        self._query_batcher = None
-        self._batcher_lock = threading.Lock()
         # SLO autopilot (cluster/autopilot.py): the feedback controller that
-        # tunes the KnobRegistry the batcher/hedge/admission/engine/residency
-        # paths read per decision.  Off by default — with PINOT_TPU_AUTOPILOT
+        # tunes the KnobRegistry the hedge/admission/engine/residency paths
+        # read per decision.  Off by default — with PINOT_TPU_AUTOPILOT
         # unset no controller thread exists, no knob override is ever written,
         # and every consumer reads its env default: pre-autopilot behavior
         # bit-exactly.  attach_autopilot() wires one explicitly (benches,
@@ -653,7 +599,6 @@ class Broker:
         seg_names: List[str],
         exclude: frozenset = frozenset(),
         partial_ok: bool = False,
-        prefer_group: Optional[int] = None,
         seen: Optional[Dict] = None,
     ):
         """segment list -> {server: [segments]} picking ONE live replica per
@@ -664,14 +609,10 @@ class Broker:
         OPEN) are skipped while a healthy replica exists; when a segment's
         every replica is quarantined, availability wins and they serve.
         With partial_ok, returns (assign, unroutable_segments) instead of
-        raising on a replica-less segment.  `prefer_group` (replicagroup
-        selector only) starts the group rotation at that replica group —
-        the batched scatter path uses it to pin a whole batch to one mesh
-        replica row; a dead/partial preferred group still falls through the
-        rotation, so it's a preference, never an availability constraint.
-        `seen` (a traced query's `route` span passes one) is given
-        `minReplicas`: the fewest live candidates any segment had (not by
-        the strict replica-group pick, which takes a whole group or none)."""
+        raising on a replica-less segment.  `seen` (a traced query's `route`
+        span passes one) is given `minReplicas`: the fewest live candidates
+        any segment had (not by the strict replica-group pick, which takes a
+        whole group or none)."""
         view = self.coordinator.external_view(table)
         healthy = {
             s for s in self.coordinator.live if s not in exclude and self.health.available(s)
@@ -687,10 +628,7 @@ class Broker:
                 groups.setdefault(self.coordinator.replica_group[s], set()).add(s)
             order = sorted(groups)
             for gi in range(len(order)):
-                if prefer_group is not None:
-                    g = order[(prefer_group + gi) % len(order)]
-                else:
-                    g = order[(rr + gi) % len(order)]
+                g = order[(rr + gi) % len(order)]
                 members = groups[g]
                 assign: Dict[str, List[str]] = {}
                 ok = True
@@ -853,9 +791,11 @@ class Broker:
         t0: float,
         cancel=None,
     ) -> ResultTable:
-        """One admitted query's serve path: execute() holds the admission
-        grant around this call; `cancel` is the watchdog's kill probe,
-        threaded through scatter into every server's between-kernel check."""
+        """One admitted query's serve path, from the cache probe to the
+        finished answer: plan check, prune, scatter with full failover, the
+        realtime part, reduce.  execute() holds the admission grant around
+        this call; `cancel` is the watchdog's kill probe, threaded through
+        scatter into every server's between-kernel check."""
         gov = self.governor
         ckey, hit = self._cache_probe(ctx, table, qid, t0)
         if hit is not None:
@@ -883,30 +823,6 @@ class Broker:
             seg_names, pruned = self._prune(offline_ctx, table)
         if psp is not None:
             psp.annotate(segments=len(seg_names), pruned=pruned)
-        return self._serve_tail(
-            ctx, offline_ctx, realtime_ctx, table, meta, seg_names, pruned,
-            qid, trace, deadline, t0, cancel, ckey,
-        )
-
-    def _serve_tail(
-        self,
-        ctx: QueryContext,
-        offline_ctx: QueryContext,
-        realtime_ctx: QueryContext,
-        table: str,
-        meta,
-        seg_names: List[str],
-        pruned: int,
-        qid: str,
-        trace: Trace,
-        deadline: Deadline,
-        t0: float,
-        cancel,
-        ckey,
-    ) -> ResultTable:
-        """Post-prune serve: scatter with full failover, realtime part,
-        reduce, finish.  Shared by the sync path, singleton batch members,
-        and the per-member fallback when a batched scatter hits a fault."""
         stats = ExecutionStats(num_segments_pruned=pruned)
         results = []
         if seg_names:
@@ -990,9 +906,7 @@ class Broker:
         trace: Trace,
     ) -> None:
         """Realtime tables: sealed + consuming segments served from the
-        coordinator-owned manager (the RealtimeTableDataManager view).
-        Shared by the sync serve path and each batched member (the realtime
-        part always executes per member — it is never coalesced)."""
+        coordinator-owned manager (the RealtimeTableDataManager view)."""
         rt = self.coordinator.realtime.get(table)
         if rt is None:
             return
@@ -1037,8 +951,7 @@ class Broker:
         stats: ExecutionStats,
     ) -> ResultTable:
         """Reduce + response stamping + result-cache populate + latency and
-        ShapeStats accounting — the tail every served query (sync or batch
-        member) runs through."""
+        ShapeStats accounting — the tail every served query runs through."""
         with trace.span("reduce", cpu=True) as rsp:
             out = reduce_mod.reduce_results(ctx, results, stats, trace)
             if rsp is not None and ctx.group_by:
@@ -1076,323 +989,6 @@ class Broker:
             engine="broker",
         )
         return out
-
-    # -- concurrent serving tier: async submit + cross-query batching ------
-    def submit(self, sql: str):
-        """Async entry point: returns a concurrent.futures.Future resolving
-        to the query's ResultTable (or raising its error).
-
-        Batchable queries (single table, no set-ops/joins/subqueries, not
-        EXPLAIN) pay their admission bracket — QPS quota, governor admit +
-        watchdog registration, result-cache probe — at submit time, then
-        wait up to PINOT_TPU_BATCH_WAIT_MS in the micro-batcher for
-        same-shape peers; a coalesced group executes as ONE vmapped launch
-        per segment.  Everything else (and every query when the wait window
-        is 0) takes the synchronous query() path and comes back as an
-        already-completed future, so semantics never change — batching is
-        purely an execution strategy."""
-        from concurrent.futures import Future
-
-        from pinot_tpu.sql.parser import parse_query
-        from pinot_tpu.spi.env import apply_env_defaults
-
-        fut = Future()
-        try:
-            ctx = parse_query(sql)
-            apply_env_defaults(ctx.options)
-        except Exception as e:
-            fut.set_exception(e)
-            return fut
-        if not self._batchable(ctx):
-            try:
-                fut.set_result(self.query(sql))
-            except Exception as e:
-                fut.set_exception(e)
-            return fut
-        fp = ctx.fingerprint()
-        # literal canonicalization needs column metadata: fingerprint against
-        # a representative segment so `v < 5` and `v < 6` share one slot
-        # (the same provider plan_segment keys its compile cache with)
-        sfp = ctx.shape_fingerprint(self._column_info(ctx.table))
-        try:
-            member = self._admit_member(ctx, sql, fp, sfp)
-        except Exception as e:
-            self.slow_queries.record(
-                sql, fp, None, error=f"{type(e).__name__}: {e}", shape_fingerprint=sfp
-            )
-            fut.set_exception(e)
-            return fut
-        if isinstance(member, ResultTable):  # result-cache hit at submit
-            self.slow_queries.record(sql, fp, member, shape_fingerprint=sfp)
-            fut.set_result(member)
-            return fut
-        from pinot_tpu.query.shape import shape_digest
-
-        # the batch key IS the shape fingerprint (digested) — literals
-        # differ freely (they ride the stacked params pytree), but options
-        # like trace are part of the shape, so traced and untraced queries
-        # never coalesce
-        return self._batcher().submit((ctx.table, shape_digest(sfp)), member)
-
-    def query_many(self, sqls: List[str]) -> List[ResultTable]:
-        """Submit a batch of queries concurrently, flush, and gather —
-        errors re-raise in submission order."""
-        futs = [self.submit(s) for s in sqls]
-        self.drain_batches()
-        return [f.result() for f in futs]
-
-    def drain_batches(self) -> int:
-        """Flush every pending micro-batch immediately (tests, shutdown,
-        synchronous gather)."""
-        with self._batcher_lock:
-            batcher = self._query_batcher
-        if batcher is None:
-            return 0
-        return batcher.flush()
-
-    def _batcher(self):
-        with self._batcher_lock:
-            if self._query_batcher is None:
-                from pinot_tpu.cluster.batcher import MicroBatcher
-
-                self._query_batcher = MicroBatcher(
-                    self._run_batch, clock=self.batch_clock
-                )
-            return self._query_batcher
-
-    def _column_info(self, table: str):
-        """Column-shape provider from any live replica's copy of any
-        segment — the audit input shape_fingerprint canonicalizes literals
-        with.  None (empty table / nothing routable) keeps literals baked,
-        which only means less coalescing, never wrong results."""
-        from pinot_tpu.query.shape import column_info_from
-
-        view = self.coordinator.external_view(table)
-        for seg, servers in view.items():
-            for s in servers:
-                srv = self.coordinator.servers.get(s)
-                seg_obj = srv.get_segment(table, seg) if srv is not None else None
-                if seg_obj is not None:
-                    return column_info_from(seg_obj)
-        return None
-
-    def _batchable(self, ctx: QueryContext) -> bool:
-        """Only plain single-table scans coalesce; compound shapes keep the
-        recursive synchronous path (their sub-plans pay their own quota and
-        admission there)."""
-        if ctx.options.get("__explain__") or ctx.options.get("__analyze__"):
-            return False
-        if ctx.set_ops or ctx.joins:
-            return False
-        if ctx.table not in self.coordinator.tables:
-            return False
-        return not _has_subquery(ctx.filter) and not _has_subquery(
-            getattr(ctx, "having", None)
-        )
-
-    def _admit_member(self, ctx: QueryContext, sql: str, fp: str, sfp: str):
-        """The pre-batch slice of execute(): quota, query id, trace root,
-        deadline, governor admission, cache probe.  Returns a cached
-        ResultTable on a hit, else a _BatchMember holding the live grant
-        (closed by _member_done on every completion path)."""
-        table = ctx.table
-        self.quota.check(
-            table, self.coordinator.tables[table].config.max_queries_per_second
-        )
-        t0 = time.perf_counter()
-        deadline = Deadline.from_ctx(ctx)
-        qid = f"{self._broker_id}_{next(self._qid_seq)}"
-        trace = Trace(bool(ctx.options.get("trace", False)), query_id=qid)
-        METRICS.counter("broker.queries").inc()
-        grant = None
-        cancel = None
-        gov = self.governor
-        if gov is not None:
-            cost = estimate_query_cost(
-                ctx, self.coordinator.tables[table].segment_meta.values()
-            )
-            grant = gov.admit(qid, ctx, cost, deadline)
-            cancel = gov.cancel_probe(qid)
-        try:
-            ckey, hit = self._cache_probe(ctx, table, qid, t0)
-        except Exception:
-            if grant is not None:
-                grant.close()
-            raise
-        if hit is not None:
-            if grant is not None:
-                grant.close()
-            return hit
-        return _BatchMember(
-            ctx=ctx, sql=sql, fp=fp, sfp=sfp, qid=qid, trace=trace,
-            deadline=deadline, t0=t0, grant=grant, cancel=cancel, ckey=ckey,
-        )
-
-    def _run_batch(self, entries) -> None:
-        """MicroBatcher runner: one coalesced group of same-shape members.
-        Owns completion — every entry's future resolves here."""
-        if len(entries) == 1:
-            m = entries[0].payload
-            self._member_done(entries[0], self._serve_member(m))
-            return
-        members = [e.payload for e in entries]
-        try:
-            outcomes = self._serve_batch(members)
-        except Exception as exc:  # orchestration safety net: never hang a future
-            outcomes = [exc] * len(members)
-        for entry, out in zip(entries, outcomes):
-            self._member_done(entry, out)
-
-    def _member_done(self, entry, outcome) -> None:
-        """Deliver one member's outcome: slow-log entry, future resolution,
-        admission grant release."""
-        m = entry.payload
-        try:
-            if isinstance(outcome, BaseException):
-                self.slow_queries.record(
-                    m.sql, m.fp, None,
-                    error=f"{type(outcome).__name__}: {outcome}",
-                    shape_fingerprint=m.sfp,
-                )
-                entry.future.set_exception(outcome)
-            else:
-                self.slow_queries.record(m.sql, m.fp, outcome, shape_fingerprint=m.sfp)
-                entry.future.set_result(outcome)
-        finally:
-            if m.grant is not None:
-                m.grant.close()
-
-    def _serve_member(self, m) -> object:
-        """Plan + prune + serve ONE pre-admitted member through the standard
-        failover path (singleton flushes and post-fault fallbacks).  Returns
-        the ResultTable or the exception — never raises."""
-        table = m.ctx.table
-        try:
-            meta = self.coordinator.tables[table]
-            self._plan_member(m, table, meta)
-            return self._serve_tail(
-                m.ctx, m.offline_ctx, m.realtime_ctx, table, meta,
-                m.seg_names, m.pruned, m.qid, m.trace, m.deadline, m.t0,
-                m.cancel, m.ckey,
-            )
-        except Exception as e:
-            # outcome, not a swallow: _member_done slow-logs it and fails
-            # the submitter's future
-            METRICS.counter("broker.memberServeErrors").inc()
-            return e
-
-    def _plan_member(self, m, table: str, meta) -> None:
-        """The plan-span + hybrid-split + prune slice of _serve, recorded on
-        the member's own trace."""
-        from pinot_tpu.analysis.plan_check import check_plan
-
-        gov = self.governor
-        with m.trace.span("plan") as bsp:
-            check_plan(m.ctx, meta.schema)
-            self._inject_global_ranges(m.ctx, table)
-            if bsp is not None:
-                from pinot_tpu.query.shape import shape_digest
-
-                bsp.annotate(
-                    shapeFp=shape_digest(m.ctx.shape_fingerprint()),
-                    resultCache="bypass" if m.ckey is None else "miss",
-                )
-                if gov is not None and gov.degrade.level > 0:
-                    bsp.annotate(pressure=gov.degrade.level)
-        m.offline_ctx, m.realtime_ctx = self._split_hybrid(m.ctx, table)
-        with m.trace.span("prune", table=table) as psp:
-            m.seg_names, m.pruned = self._prune(m.offline_ctx, table)
-        if psp is not None:
-            psp.annotate(segments=len(m.seg_names), pruned=m.pruned)
-
-    def _serve_batch(self, members: List) -> List:
-        """Serve one coalesced same-shape group: per-member plan/prune, then
-        sub-group by IDENTICAL pruned segment list (prune divergence never
-        mis-attributes work), one batched scatter per sub-group, and the
-        per-member realtime/reduce/finish tail.  Returns one outcome
-        (ResultTable or Exception) per member; a transport-level fault in a
-        batched scatter falls the affected sub-group back to the standard
-        per-member failover path instead of failing anyone."""
-        table = members[0].ctx.table
-        meta = self.coordinator.tables[table]
-        batch_id = f"b{self._broker_id}_{next(self._qid_seq)}"
-        outcomes: List = [None] * len(members)
-        groups: Dict[Tuple, List[int]] = {}
-        for i, m in enumerate(members):
-            try:
-                self._plan_member(m, table, meta)
-                m.trace.annotate(batchId=batch_id, batchSize=len(members))
-                groups.setdefault(tuple(m.seg_names), []).append(i)
-            except Exception as e:
-                # recorded as the member's outcome; _member_done slow-logs it
-                METRICS.counter("broker.memberServeErrors").inc()
-                outcomes[i] = e
-        METRICS.counter("broker.batches").inc()
-        METRICS.histogram("broker.batchSize").update(len(members))
-        for segs, idxs in groups.items():
-            group = [members[i] for i in idxs]
-            if len(idxs) == 1 or not segs:
-                # lone segment-list (or pure-realtime query): nothing to
-                # coalesce at the kernel layer — standard path
-                for i in idxs:
-                    outcomes[i] = self._serve_member(members[i])
-                continue
-            try:
-                res_lists, stats_list, errs = self._scatter_batch(
-                    group, table, list(segs), meta, batch_id
-                )
-            except Exception:
-                # batch-level fault (server crash, capacity, routing): the
-                # whole sub-group re-executes individually with the full
-                # failover machinery — batching is bypassed on faults
-                METRICS.counter("broker.batchFallbacks").inc()
-                for i in idxs:
-                    outcomes[i] = self._serve_member(members[i])
-                continue
-            for i, m, results, stats, err in zip(
-                idxs, group, res_lists, stats_list, errs
-            ):
-                outcomes[i] = self._finish_batch_member(
-                    m, table, results, stats, err
-                )
-        return outcomes
-
-    def _finish_batch_member(self, m, table, results, stats, err):
-        """Realtime part + reduce + finish for one batched member, honoring
-        the kill/timeout classification: a detached member degrades to a partial
-        result when it opted in, else its error is its outcome."""
-        allow_partial = str(m.ctx.options.get("allowPartialResults", "")).lower() in (
-            "1", "true", "yes",
-        )
-        try:
-            cancel = m.cancel
-            if err is not None:
-                if isinstance(err, QueryKilledError):
-                    METRICS.counter("broker.queriesKilled").inc()
-                if not allow_partial:
-                    return err
-                stats.partial_result = True
-                stats.exceptions.append(
-                    {
-                        "errorCode": "QUERY_KILLED"
-                        if isinstance(err, QueryKilledError)
-                        else "EXECUTION_TIMEOUT_ERROR",
-                        "message": str(err),
-                    }
-                )
-                METRICS.counter("broker.partialResults").inc()
-                cancel = None  # the kill already degraded this member
-            self._serve_realtime(
-                m.realtime_ctx, table, m.qid, cancel, m.deadline, stats,
-                results, m.trace,
-            )
-            return self._finish_result(
-                m.ctx, table, m.qid, m.t0, m.trace, m.ckey, results, stats
-            )
-        except Exception as e:
-            # outcome, not a swallow: the caller fails this member's future
-            METRICS.counter("broker.memberServeErrors").inc()
-            return e
 
     # -- hedged execution (tail tolerance) ---------------------------------
     @staticmethod
@@ -1463,24 +1059,11 @@ class Broker:
                 alive += 1
         return alive
 
-    def _account_loser(
-        self, name: str, ok: bool, out, ms: float, table: str, stats, batch: bool = False
-    ) -> None:
+    def _account_loser(self, name: str, ok: bool, out, ms: float, table: str, stats) -> None:
         """Settle the attempt that did NOT win a hedged call — accounting
         happens here EXACTLY once (the winner path never sees the loser).
         Runs on the loser's own thread (or the caller's, for a failure that
         arrived before the winner)."""
-        if ok and batch:
-            # a losing execute_batch returns normally with each member
-            # detached via its probe: all-members hedge_lost == cancelled
-            errs = out[2]
-            if errs and all(
-                isinstance(e, QueryKilledError) and getattr(e, "reason", None) == "hedge_lost"
-                for e in errs
-            ):
-                METRICS.counter("broker.hedgesCancelled").inc()
-                METRICS.timer("broker.hedgeCancelMs").update(ms)
-                return
         if ok:
             # the loser finished anyway (too late to matter): its latency is
             # real signal, its work is the hedge's waste
@@ -1519,7 +1102,6 @@ class Broker:
         segs: List[str] = (),
         exclude: frozenset = frozenset(),
         stats=None,
-        batch: bool = False,
     ):
         """Run ``run(server, lost_event)`` on `primary`, hedging a backup
         replica when the quantile-derived delay elapses without a reply.
@@ -1585,7 +1167,7 @@ class Broker:
                         result_q.put((name, ok, out, ms))
                         return
                 # a sibling already won: this attempt lost — settle off-path
-                self._account_loser(name, ok, out, ms, table, stats, batch=batch)
+                self._account_loser(name, ok, out, ms, table, stats)
             finally:
                 with self._hedge_lock:
                     self._hedge_threads.discard(threading.current_thread())
@@ -1628,14 +1210,14 @@ class Broker:
             # sibling IS the retry — hold the error until it reports
             name2, ok2, out2, ms2 = result_q.get()
             if ok2:
-                self._account_loser(name, False, out, ms, table, stats, batch=batch)
+                self._account_loser(name, False, out, ms, table, stats)
                 name, ok, out, ms = name2, True, out2, ms2
             else:
                 # both failed: side-account the backup, raise the primary's
                 # error so the outer classification keys on the routed server
                 prim_err, hedge_err = (out, out2) if name == primary else (out2, out)
                 hedge_ms = ms2 if name == primary else ms
-                self._account_loser(target, False, hedge_err, hedge_ms, table, stats, batch=batch)
+                self._account_loser(target, False, hedge_err, hedge_ms, table, stats)
                 raise prim_err
         if not ok:
             raise out  # no hedge in flight: identical to the inline path
@@ -1648,114 +1230,6 @@ class Broker:
         if hedge_fired and winner == target:
             METRICS.counter("broker.hedgeWins").inc()
         return winner, out, ms, info
-
-    def _scatter_batch(self, group: List, table: str, seg_names: List[str], meta, batch_id: str):
-        """Failover-free batched scatter: route ONCE for the whole
-        sub-group, run server.execute_batch per routed server (one vmapped
-        launch per segment), and accumulate per-member stats.  Per-member
-        kill/deadline errors come back in the errors list (siblings keep
-        their exact results); any transport-level fault raises so the
-        caller falls the sub-group back to the standard path — after
-        recording it on the breaker, so the retry routes around the bad
-        server."""
-        n = len(group)
-        # whole-batch replica-row pinning: every member of a same-fingerprint
-        # batch routes to ONE replica group (mesh replica row), and batches
-        # round-robin across rows — concurrent QPS scales with row count
-        # while each row serves its batch from one staged copy
-        with self._rr_lock:
-            prefer = self._batch_rr
-            self._batch_rr += 1
-        assign = self._route(table, seg_names, prefer_group=prefer)
-        trace_on = any(m.trace.enabled for m in group)
-        results: List[list] = [[] for _ in range(n)]
-        stats = [ExecutionStats(num_segments_pruned=m.pruned) for m in group]
-        member_errs: List[Optional[Exception]] = [None] * n
-        per_call = []
-        for m in group:
-            sto = m.ctx.options.get("serverTimeoutMs")
-            per_call.append(
-                m.deadline.bounded(float(sto) if sto is not None else None)
-            )
-        queried = 0
-        responded = 0
-        METRICS.gauge("broker.inFlightScatters").add(1)
-        try:
-            for server_name, segs in assign.items():
-                queried += 1
-
-                def run_batch(name, lost_evt, _segs=segs):
-                    srv = self.coordinator.servers[name]
-                    # per-member isolation survives hedging: each member's own
-                    # watchdog probe keeps priority inside the composed probe
-                    comp = (
-                        [m.cancel for m in group]
-                        if lost_evt is None
-                        else [self._compose_cancel(m.cancel, lost_evt) for m in group]
-                    )
-                    return srv.execute_batch(
-                        [m.offline_ctx for m in group],
-                        _segs,
-                        table_schema=meta.schema,
-                        deadlines=per_call,
-                        cancels=comp,
-                        batch_id=batch_id,
-                        trace_enabled=trace_on,
-                    )
-
-                try:
-                    winner, _payload, win_ms, hinfo = self._hedged_call(
-                        table, server_name, run_batch,
-                        opts=group[0].ctx.options, segs=segs, batch=True,
-                    )
-                    res, sstats, errs, btrace = _payload
-                except Exception as e:
-                    if not isinstance(e, ReservationError):
-                        # genuine fault: breaker + adaptive stats learn it so
-                        # the per-member fallback routes around this server
-                        self.server_stats.punish(server_name)
-                        self.health.record_failure(server_name)
-                        METRICS.counter("broker.scatterServerFailures").inc()
-                    else:
-                        METRICS.counter("broker.scatterCapacityRejections").inc()
-                    raise
-                self.health.record_success(winner)
-                transition = self.health.note_latency(winner, win_ms)
-                if hinfo["hedged"] or transition is not None:
-                    for st in stats:
-                        if hinfo["hedged"]:
-                            st.hedged += 1
-                            st.hedge_winner = winner
-                        if transition is not None:
-                            st.brownout_events.append(f"{transition}:{winner}")
-                if hinfo["hedged"]:
-                    queried += 1
-                responded += 1
-                for i in range(n):
-                    if errs[i] is not None:
-                        if member_errs[i] is None:
-                            member_errs[i] = errs[i]
-                        continue
-                    results[i].extend(res[i])
-                    stats[i].num_segments_queried += sstats[i].num_segments_queried
-                    stats[i].num_segments_processed += sstats[i].num_segments_processed
-                    stats[i].num_segments_pruned += sstats[i].num_segments_pruned
-                    stats[i].num_docs_scanned += sstats[i].num_docs_scanned
-                    stats[i].total_docs += sstats[i].total_docs
-                    stats[i].add_index_uses(sstats[i].filter_index_uses)
-                    stats[i].add_kernel_cost(sstats[i])
-                if btrace is not None:
-                    import copy
-
-                    for k, m in enumerate(group):
-                        if m.trace.enabled:
-                            m.trace.graft(copy.deepcopy(btrace))
-        finally:
-            METRICS.gauge("broker.inFlightScatters").add(-1)
-            for i in range(n):
-                stats[i].num_servers_queried = queried
-                stats[i].num_servers_responded = responded
-        return results, stats, member_errs
 
     # -- fault-tolerant scatter-gather ------------------------------------
     def _scatter(

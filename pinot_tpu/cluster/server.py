@@ -405,241 +405,6 @@ class ServerInstance:
         if compile_ms > 0:
             self.metrics.timer("server.compileMs").update(compile_ms)
 
-    def execute_batch(
-        self,
-        ctxs: List[QueryContext],
-        seg_names: List[str],
-        table_schema=None,
-        deadlines: Optional[List[Optional[Deadline]]] = None,
-        cancels: Optional[List] = None,
-        batch_id: Optional[str] = None,
-        trace_enabled: bool = False,
-        source: str = "broker",
-    ):
-        """Run N same-shape queries over the named LOCAL segments as ONE
-        vmapped launch per segment (executor.launch_segment_batch); returns
-        ``(results, stats, errors, batch_trace)`` with one slot per member.
-
-        Per-member isolation: a member whose deadline expires or whose kill
-        probe fires gets its error recorded in ``errors[i]`` and detaches —
-        its remaining lanes are computed but discarded, and its siblings'
-        results stay bit-exact.  Only BATCH-level faults raise out of this
-        call (crashed server, fault-plan failure, missing segment,
-        reservation exhaustion): the broker reacts by falling back to
-        per-member execution through the normal failover machinery.
-
-        Stats attribution: each segment's scanned docs and kernel
-        bytes divide across the members that actually scanned it
-        (pruned members are excluded from the division), so summing member
-        stats reproduces one unbatched run — never N duplicated copies.
-
-        A per-member prune divergence within a uniform-segment batch is
-        handled lane-wise: an all-pruned segment is skipped entirely, a
-        partially-pruned one still launches with every live member's lane
-        but credits pruned members with num_segments_pruned instead of
-        docs."""
-        from pinot_tpu.query.planner import _needed_columns
-        from pinot_tpu.utils.metrics import Trace
-
-        if self.crashed:
-            from pinot_tpu.cluster.faults import ServerFaultError
-
-            raise ServerFaultError(f"server {self.name} is down (crashed)")
-        n = len(ctxs)
-        deadlines = list(deadlines) if deadlines else [None] * n
-        cancels = list(cancels) if cancels else [None] * n
-        trace = Trace(trace_enabled, root=f"server:{self.name}", query_id=batch_id)
-        ticket = None
-        if self.budget is not None:
-            # members share one plan shape, so the working set is the
-            # SHARED column pytree — reserved once, not once per member
-            est = []
-            for name in seg_names:
-                seg = self.get_segment(ctxs[0].table, name)
-                if seg is not None:
-                    est.append(
-                        estimate_segment_bytes(
-                            ctxs[0], seg, _needed_columns(ctxs[0], seg)
-                        )
-                    )
-            if self.residency is not None:
-                # pipeline-window reservation (see execute): the cache
-                # pages segments through the budget, so only the window
-                # must be jointly resident
-                win = _staging_depth()
-                need = max(
-                    (sum(est[i : i + win]) for i in range(len(est))), default=0
-                )
-                ticket = self.budget.reserve_or_wait(
-                    need, what=f"batched scatter to server {self.name}"
-                )
-            else:
-                ticket = self.budget.reserve(
-                    sum(est), what=f"batched scatter to server {self.name}"
-                )
-        try:
-            plan = self.fault_plan
-            if plan is not None:
-                fault_n0 = len(plan.log)
-                plan.on_execute(self.name, source=source)  # may sleep, flap liveness, or raise
-                if trace.enabled and len(plan.log) > fault_n0:
-                    trace.annotate(faults=[k for (_, _, k, _) in plan.log[fault_n0:]])
-            stats = [ExecutionStats() for _ in range(n)]
-            results: List[list] = [[] for _ in range(n)]
-            errors: List[Optional[Exception]] = [None] * n
-            pending = []  # (launch state, member indices it carries)
-            with trace.span("dispatch", batchId=batch_id, batchSize=n) as dsp:
-                for name in seg_names:
-                    self._probe_members(deadlines, cancels, errors)
-                    live = [i for i in range(n) if errors[i] is None]
-                    if not live:
-                        break
-                    seg = self.get_segment(ctxs[0].table, name)
-                    if seg is not None and plan is not None and plan.segment_dropped(
-                        self.name, ctxs[0].table, name
-                    ):
-                        seg = None
-                    if seg is None:
-                        raise KeyError(
-                            f"server {self.name} does not serve {ctxs[0].table}/{name}"
-                        )
-                    for i in live:
-                        stats[i].num_segments_queried += 1
-                        stats[i].total_docs += seg.num_docs
-                    if table_schema is not None:
-                        seg.ensure_columns(table_schema, _needed_columns(ctxs[0], seg))
-                    scan = []
-                    for i in live:
-                        if executor.prune_segment(ctxs[i], seg):
-                            stats[i].num_segments_pruned += 1
-                        else:
-                            scan.append(i)
-                    if not scan:
-                        continue
-                    with trace.span(
-                        f"launch:{seg.name}", cpu=True, segment=seg.name, members=len(scan)
-                    ) as lsp:
-                        if len(scan) == 1:
-                            st = executor.launch_segment(
-                                ctxs[scan[0]], seg, device=self.device,
-                                residency=self.residency, trace=trace,
-                            )
-                            pending.append((st, scan))
-                        else:
-                            try:
-                                st = executor.launch_segment_batch(
-                                    [ctxs[i] for i in scan], seg, device=self.device,
-                                    residency=self.residency, trace=trace,
-                                )
-                                pending.append((st, scan))
-                            except executor.BatchShapeError:
-                                # vetted batches shouldn't land here; stay
-                                # correct with per-member launches if one does
-                                for i in scan:
-                                    pending.append(
-                                        (
-                                            executor.launch_segment(
-                                                ctxs[i], seg, device=self.device,
-                                                residency=self.residency, trace=trace,
-                                            ),
-                                            [i],
-                                        )
-                                    )
-                if dsp is not None:
-                    dsp.annotate(launches=len(pending))
-            if trace.enabled:
-                import jax
-
-                with trace.span("device_wait", launches=len(pending)):
-                    jax.block_until_ready(
-                        executor.pending_outputs([p[0] for p in pending])
-                    )
-            for st, members in pending:
-                self._probe_members(deadlines, cancels, errors, only=members)
-                alive = [i for i in members if errors[i] is None]
-                if not alive:
-                    continue  # every rider died — abandon uncollected
-                with trace.span("collect", cpu=True, members=len(alive)) as csp:
-                    if st[0] == "pending_batch":
-                        collected = executor.collect_segment_batch(st)
-                    else:
-                        collected = [executor.collect_segment(st)]
-                docs = 0
-                for (res, seg_st), i in zip(collected, members):
-                    if errors[i] is not None:
-                        continue  # killed member's lane computed but discarded
-                    stats[i].num_segments_processed += 1
-                    stats[i].num_docs_scanned += seg_st.num_docs_scanned
-                    stats[i].add_index_uses(seg_st.filter_index_uses)
-                    stats[i].add_kernel_cost(seg_st)
-                    results[i].append(res)
-                    docs += seg_st.num_docs_scanned
-                if csp is not None:
-                    csp.annotate(docs=docs)
-            served = sum(1 for e in errors if e is None)
-            self.metrics.counter("server.queries").inc(served)
-            self.metrics.counter("server.batches").inc()
-            self.metrics.histogram("server.batchSize").update(n)
-            METRICS.counter("server.batches").inc()
-            METRICS.histogram("server.batchSize").update(n)
-            docs_total = sum(s.num_docs_scanned for s in stats)
-            self.metrics.counter("server.docsScanned").inc(docs_total)
-            self.metrics.counter("server.launches").inc(len(pending))
-            trace.flush(self.metrics, _STAGE_TIMERS)
-            killed = sum(
-                1 for e in errors if isinstance(e, QueryKilledError)
-            )
-            if killed:
-                METRICS.counter("server.queriesKilled").inc(killed)
-            batch_trace = None
-            if trace.enabled:
-                from pinot_tpu import ops
-
-                trace.annotate(
-                    server=self.name,
-                    batchId=batch_id,
-                    batchSize=n,
-                    segments=len(seg_names),
-                    docsScanned=docs_total,
-                    backend=ops.scan_backend(),
-                )
-                batch_trace = trace.finish()
-            return results, stats, errors, batch_trace
-        finally:
-            if ticket is not None:
-                self.budget.release(ticket)
-
-    def _probe_members(
-        self,
-        deadlines: List[Optional[Deadline]],
-        cancels: List,
-        errors: List[Optional[Exception]],
-        only: Optional[List[int]] = None,
-    ) -> None:
-        """Per-member deadline + kill probes for a batched call.  A firing
-        probe records the member's error (detaching it from the batch)
-        instead of raising — siblings keep their lanes and their results."""
-        idx = only if only is not None else range(len(errors))
-        for i in idx:
-            if errors[i] is not None:
-                continue
-            cancel = cancels[i]
-            if cancel is not None:
-                reason = cancel()
-                if reason:
-                    errors[i] = QueryKilledError(
-                        f"server {self.name}: query killed ({reason}); "
-                        "batch member detached",
-                        reason=reason,
-                    )
-                    continue
-            deadline = deadlines[i]
-            if deadline is not None and deadline.expired():
-                errors[i] = QueryTimeoutError(
-                    f"server {self.name} ran out of query budget "
-                    f"(timeoutMs={deadline.timeout_ms:g}); batch member detached"
-                )
-
     def _check_budget(
         self, deadline: Optional[Deadline], cancelled: int, cancel=None
     ) -> None:

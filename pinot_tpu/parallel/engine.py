@@ -20,8 +20,7 @@ row to one partial table — then once over REPLICA_AXIS, the only reduction
 that crosses host/DCN boundaries on a multi-host pod, so cross-host bytes
 scale with partial-table size rather than raw rows.  The QPS deployment of
 the same mesh is ReplicatedEngine below: one 1-D sub-engine per replica
-row, each a full data copy, whole same-fingerprint batches routed to rows
-round-robin.
+row, each a full data copy, queries routed to rows round-robin.
 
 DataTable/Netty have no analog here by design: the wire format between
 "servers" (shards) is an XLA collective over ICI/DCN (SURVEY.md 2.6).
@@ -239,9 +238,6 @@ class DistributedEngine:
         self._plan_cache = LruCache(
             max_entries=_plan_cache_entries(), name="compile.dist", budget=process_host_budget()
         )
-        # vmapped-plan LRU for execute_many's cross-query batching: keyed on
-        # the base compiled fn + lane width so batching never recompiles
-        self._batch_fn_cache = LruCache(max_entries=32, name="compile.batch.dist")
         # shape fp + hit/miss of the most recent _plan call (trace/EXPLAIN
         # ANALYZE annotation; the engine plans one query at a time)
         self._last_shape_fp: str = ""
@@ -390,155 +386,6 @@ class DistributedEngine:
             engine="dist",
         )
         return out
-
-    def execute_many(self, ctxs: List[QueryContext]) -> List[ResultTable]:
-        """Cross-query batching at the distributed tier: queries sharing one
-        compiled plan execute as a SINGLE vmapped launch with their literal
-        params stacked on a leading query axis.
-
-        Eligibility is deliberately narrow — aggregation / dense group-by
-        plans with no row-sharded bitmap params and a single macro-batch
-        (index bitmap doc-slicing and the pipelined multi-launch schedule
-        don't compose with the query axis).  Ineligible queries, singleton
-        groups, and any group whose vmap attempt fails fall back to
-        sequential execute(), so results always match the unbatched path."""
-        from pinot_tpu.query.shape import column_info_from, shape_digest
-
-        results: List[Optional[ResultTable]] = [None] * len(ctxs)
-        groups: Dict[Any, List[int]] = {}
-        for i, ctx in enumerate(ctxs):
-            if ctx.joins or ctx.set_ops or ctx.table not in self.tables:
-                results[i] = self.execute(ctx)
-                continue
-            stacked = self.tables[ctx.table]
-            key = (ctx.table, shape_digest(ctx.shape_fingerprint(column_info_from(stacked))))
-            groups.setdefault(key, []).append(i)
-        for idxs in groups.values():
-            outs = self._execute_group([ctxs[i] for i in idxs]) if len(idxs) > 1 else None
-            if outs is None:
-                for i in idxs:
-                    results[i] = self.execute(ctxs[i])
-            else:
-                for i, o in zip(idxs, outs):
-                    results[i] = o
-        return results
-
-    def _execute_group(self, ctxs: List[QueryContext]) -> Optional[List[ResultTable]]:
-        """One vmapped launch for a same-shape group; None = not eligible or
-        the attempt failed (caller executes sequentially)."""
-        import time as _time
-
-        from pinot_tpu.query.shape import shape_digest
-
-        table = ctxs[0].table
-        stacked = self.tables[table]
-        n = len(ctxs)
-        t0 = _time.perf_counter()
-        try:
-            for ctx in ctxs:
-                self._inject_sketch_info(ctx, stacked)
-            plans = [self._plan(ctx, stacked) for ctx in ctxs]
-            base = plans[0]
-            if any(p.fn is not base.fn for p in plans[1:]):
-                return None
-            if base.kind not in ("aggregation", "groupby_dense"):
-                return None
-            if base.row_sharded_params or len(base.batch_offsets) != 1:
-                return None
-            width = sse_executor.batch_width()
-            if n > width:
-                return None
-            cols, dev_params = self.device_batches(base, stacked)[0]
-            pad_plans = plans + [plans[-1]] * (width - n)
-            repl = NamedSharding(self.mesh, P())
-            stacked_params = {}
-            axes = {}
-            for k in dev_params:
-                if k in ("__boff__", "__fresh__"):
-                    # launch-schedule scalars: identical across members
-                    stacked_params[k] = dev_params[k]
-                    axes[k] = None
-                else:
-                    stacked_params[k] = jax.device_put(
-                        jax.tree_util.tree_map(
-                            lambda *xs: np.stack([np.asarray(x) for x in xs]),
-                            *(p.params[k] for p in pad_plans),
-                        ),
-                        repl,
-                    )
-                    axes[k] = 0
-            key = (id(base.fn), width)
-            fnb = self._batch_fn_cache.get(key)
-            first_batched = fnb is None
-            if first_batched:
-                fnb = jax.jit(jax.vmap(base.fn, in_axes=(None, axes)))
-                self._batch_fn_cache.put(key, fnb)
-                sse_executor.BATCH_AUDIT.record_compile()
-            else:
-                sse_executor.BATCH_AUDIT.record_hit()
-            td0 = _time.perf_counter()
-            host = jax.device_get(fnb(cols, stacked_params))
-            compile_ms = (_time.perf_counter() - td0) * 1000.0 if first_batched else 0.0
-        except Exception:
-            METRICS.counter("dist.batchFallbacks").inc()
-            return None
-        share, rem = divmod(stacked.num_docs, n)
-        outs = []
-        for i, (ctx, plan) in enumerate(zip(ctxs, plans)):
-            member = jax.tree_util.tree_map(lambda a: a[i], host)
-            stats = ExecutionStats(
-                num_segments_queried=stacked.num_shards,
-                num_segments_processed=stacked.num_shards,
-                num_docs_scanned=share + (1 if i < rem else 0),
-                total_docs=stacked.num_docs,
-            )
-            stats.add_index_uses(plan.index_uses)
-            stats.kernel_bytes = base.scan_bytes / n
-            if i == 0 and compile_ms:
-                stats.compile_ms = compile_ms
-            if base.kind == "aggregation":
-                result = AggSegmentResult(partials=member)
-            else:
-                presence, partials = member
-                shim = SimpleNamespace(group_dims=base.group_dims, aggs=base.aggs)
-                keys, sliced = sse_executor._dense_to_present(
-                    shim, np.asarray(presence), partials, ctx.num_groups_limit,
-                    order_trim=planner_mod.order_by_agg_index(ctx),
-                )
-                stats.num_groups = len(keys[0]) if keys else 0
-                result = GroupBySegmentResult(
-                    keys=keys,
-                    partials=sliced,
-                    dense=DenseGroupData(
-                        presence=np.asarray(presence),
-                        partials=partials,
-                        key_space=tuple(
-                            ("dict", gd.name, gd.dictionary.fingerprint(), gd.null_code)
-                            if gd.kind == "dict"
-                            else ("rawint", gd.name, gd.base, gd.cardinality)
-                            for gd in base.group_dims
-                        ),
-                        group_dims=base.group_dims,
-                    ),
-                )
-            out = reduce_mod.reduce_results(ctx, [result], stats)
-            out.stats.time_ms = (_time.perf_counter() - t0) * 1000
-            METRICS.counter("dist.queries").inc()
-            METRICS.histogram("dist.queryLatency").update(out.stats.time_ms)
-            perf.SHAPE_STATS.record(
-                ctx.table,
-                shape_digest(self._last_shape_fp),
-                rows=out.stats.num_docs_scanned,
-                time_ms=out.stats.time_ms,
-                kernel_bytes=out.stats.kernel_bytes,
-                compile_ms=out.stats.compile_ms,
-                cache_hit=not first_batched,
-                engine="dist",
-            )
-            outs.append(out)
-        METRICS.counter("dist.batches").inc()
-        METRICS.histogram("dist.batchSize").update(n)
-        return outs
 
     @staticmethod
     def _inject_sketch_info(ctx: QueryContext, stacked) -> None:
@@ -1075,7 +922,7 @@ class DistributedEngine:
 
     def device_batches(self, plan: _DistPlan, stacked) -> List[Tuple[Dict, Dict]]:
         """Device-placed (cols, params) per macro-batch launch, the whole
-        list at once (the batched path and the AOT compile tests; _run itself
+        list at once (the AOT compile tests; _run itself
         stages lazily through the prefetch stream)."""
         shared, repl, shard = self._shared_params(plan)
         return [
@@ -1340,11 +1187,9 @@ class ReplicatedEngine:
     replica row, each holding a FULL copy of every registered table on its
     own disjoint device set (replica-group serving, SURVEY.md 2.5).
 
-    Routing follows the r14 micro-batcher contract: whole same-fingerprint
-    batches go to one replica row, rows rotate round-robin — concurrent
-    load spreads across rows so sustained QPS scales with R while each
-    row's plan/device caches stay hot (a per-query spray would cold-start
-    every row's cache on every shape).
+    Routing: a query goes whole to one replica row, rows rotate
+    round-robin — concurrent load spreads across rows so sustained QPS
+    scales with R.
 
     Placement is CoordinatorHandle-driven when a coordinator is attached:
     `mesh_placement(R)` maps journaled replica groups onto mesh rows, so
@@ -1429,26 +1274,3 @@ class ReplicatedEngine:
         row = self._next_row()
         with self._row_locks[row]:
             return self.engines[row].execute(ctx)
-
-    def execute_many(self, ctxs: List[QueryContext]) -> List[ResultTable]:
-        """Batch routing: members group by shape fingerprint and every
-        group lands WHOLE on one replica row (vmapped same-shape launches
-        never split across rows), rows rotating per group."""
-        from pinot_tpu.query.shape import column_info_from, shape_digest
-
-        results: List[Optional[ResultTable]] = [None] * len(ctxs)
-        groups: Dict[Any, List[int]] = {}
-        for i, ctx in enumerate(ctxs):
-            stacked = self.engines[0].tables.get(ctx.table)
-            if ctx.joins or ctx.set_ops or stacked is None:
-                results[i] = self.execute(ctx)
-                continue
-            key = (ctx.table, shape_digest(ctx.shape_fingerprint(column_info_from(stacked))))
-            groups.setdefault(key, []).append(i)
-        for idxs in groups.values():
-            row = self._next_row()
-            with self._row_locks[row]:
-                outs = self.engines[row].execute_many([ctxs[i] for i in idxs])
-            for i, out in zip(idxs, outs):
-                results[i] = out
-        return results

@@ -15,8 +15,6 @@ gather)."""
 from __future__ import annotations
 
 import contextlib
-import os
-import threading
 import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -399,8 +397,10 @@ class QueryLaunches:
 
     def outputs(self) -> list:
         """Device outputs of every launched group: what a tracing caller
-        fences on with ONE jax.block_until_ready (pending_outputs)."""
-        return pending_outputs([state for state, _, _ in self._states])
+        fences on with ONE jax.block_until_ready, to split device compute
+        time from host dispatch (never per launch: a fence in the loop would
+        serialize the pipeline, lint W002)."""
+        return [state[4] for state, _, _ in self._states]
 
     def collect(self) -> List[Tuple]:
         """(segment result, ExecutionStats) of every added segment, in the
@@ -465,14 +465,6 @@ def _placed_on(device):
     return jax.default_device(device) if device is not None else contextlib.nullcontext()
 
 
-def pending_outputs(states) -> list:
-    """Device output pytrees of the not-yet-collected launch states — the
-    tracing layer fences on ALL of these with ONE jax.block_until_ready to
-    split device compute time from host dispatch (never per-launch: a
-    per-launch fence in the loop would serialize the pipeline, lint W002)."""
-    return [st[4] for st in states if st[0] in ("pending", "pending_batch")]
-
-
 def collect_group(state, check=None, trace: Optional[Trace] = None):
     """Phase 2: ONE jax.device_get for the group's outputs (the fence, and
     the launch's one trip back), then the host-side decode a member on its
@@ -526,12 +518,10 @@ def collect_segment(state):
 
 
 def _decode_host(ctx, segment, plan, host, stats, trim=True):
-    """Host-side decode of one query's (already fetched) kernel outputs —
-    shared by the unbatched collect and the per-member unstack of a
-    cross-query batched launch.  `trim` False: a dense table that is already
-    several segments' combine keeps every group, as the reduce's aligned
-    merge of those segments' tables does (numGroupsLimit bounds what ONE
-    segment tracks)."""
+    """Host-side decode of one member's (already fetched) kernel outputs.
+    `trim` False: a dense table that is already several segments' combine
+    keeps every group, as the reduce's aligned merge of those segments'
+    tables does (numGroupsLimit bounds what ONE segment tracks)."""
     if plan.kind == "aggregation":
         partials = [fn.host_partial(p) for fn, p in zip(plan.aggs, host)]
         return AggSegmentResult(partials=partials), stats
@@ -563,172 +553,6 @@ def _decode_host(ctx, segment, plan, host, stats, trim=True):
     # selection
     tmask = np.asarray(host)
     return _gather_selection(ctx, plan, segment, tmask), stats
-
-
-# ---------------------------------------------------------------------------
-# cross-query vmap batching (the concurrent serving tier's kernel layer)
-# ---------------------------------------------------------------------------
-
-
-class BatchShapeError(RuntimeError):
-    """Batch members do not share one compiled plan — callers must fall
-    back to per-member execution (never a user-visible failure)."""
-
-
-class BatchAudit:
-    """Counts vmapped-plan compiles vs. cache hits, mirroring SSE_AUDIT for
-    the base plans: the ≤2-compiles-per-shape guarantee is 1 base compile
-    (SSE_AUDIT) + 1 batched compile (here)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.compiles = 0
-        self.hits = 0
-
-    def record_compile(self):
-        with self._lock:
-            self.compiles += 1
-
-    def record_hit(self):
-        with self._lock:
-            self.hits += 1
-
-    def reset(self):
-        with self._lock:
-            self.compiles = 0
-            self.hits = 0
-
-    def snapshot(self) -> Dict[str, int]:
-        with self._lock:
-            return {"compiles": self.compiles, "hits": self.hits}
-
-
-BATCH_AUDIT = BatchAudit()
-
-
-def batch_width() -> int:
-    """Fixed lane count of a batched launch (PINOT_TPU_BATCH_MAX).  Partial
-    batches pad to this width by repeating the last member's params, so
-    every batched launch of a given plan shares ONE compiled vmap kernel."""
-    return max(2, int(os.environ.get("PINOT_TPU_BATCH_MAX", "8")))
-
-
-def _batch_fn_cache():
-    global _BATCH_FN_CACHE
-    if _BATCH_FN_CACHE is None:
-        from pinot_tpu.utils.cache import LruCache
-
-        _BATCH_FN_CACHE = LruCache(
-            max_entries=int(os.environ.get("PINOT_TPU_BATCH_PLAN_ENTRIES", "64")),
-            name="compile.batch",
-        )
-    return _BATCH_FN_CACHE
-
-
-_BATCH_FN_CACHE = None
-
-
-def launch_segment_batch(
-    ctxs: List[QueryContext], segment: ImmutableSegment, device=None, residency=None,
-    trace: Optional[Trace] = None,
-):
-    """Dispatch N same-shape queries over one segment as a SINGLE vmapped
-    kernel launch: member literal-parameter pytrees stack along a leading
-    `query` axis (r9 made literals device args, so stacking needs no
-    retrace), segment columns are shared (in_axes None), and the vmapped
-    jitted fn lives in a bounded LRU keyed on the plan-cache key + lane
-    width so batching never causes recompile churn.
-
-    Per-member ExecutionStats divide the physical launch's cost — docs
-    scanned, kernel bytes — across the N live members (padding lanes
-    attributed to nobody), so summing member stats reproduces ONE unbatched
-    run of the same query, not N copies.  compile_ms lands on member 0.
-
-    Raises BatchShapeError when members don't resolve to one compiled plan
-    (callers fall back to per-member launches).  Star-tree levels are
-    not read here: the members' plans are the segment's own.  Same spans as launch_segment: launch_ship is
-    the columns plus one host-side np.stack per packed buffer of the members'
-    parameters (they ride the vmapped call as host numpy), launch_release an
-    empty block."""
-    n = len(ctxs)
-    if n < 1:
-        raise ValueError("launch_segment_batch needs at least one member")
-    trace = trace if trace is not None else Trace()
-    with trace.span("launch_plan", segment=segment.name) as psp:
-        plans = [planner.plan_segment(ctx, segment) for ctx in ctxs]
-        if psp is not None:
-            psp.annotate(cache="hit" if all(p.cache_hit for p in plans) else "miss")
-    base = plans[0]
-    for p in plans[1:]:
-        if p.fn is not base.fn or p.kind != base.kind:
-            raise BatchShapeError(
-                "batch members resolved to different compiled plans"
-            )
-    width = batch_width()
-    if n > width:
-        raise BatchShapeError(f"batch of {n} exceeds lane width {width}")
-
-    shared_keys = frozenset(base.params.keys() & {planner.VALID_KEY})
-    params_list = [p.params for p in plans]
-    if n < width:
-        params_list = params_list + [plans[-1].params] * (width - n)
-    with trace.span("launch_ship", segment=segment.name, params=len(base.param_layout)) as ssp:
-        cols = segment.to_device(
-            device=device, columns=base.needed_columns, packed_codes=True,
-            residency=residency, dict_rows=base.dict_sizes,
-        )
-        stacked = {
-            k: v0 if k in shared_keys else np.stack([pl[k] for pl in params_list])
-            for k, v0 in base.params.items()
-        }
-        if ssp is not None:
-            ssp.annotate(paramArrays=len(stacked))
-
-    key = (base.cache_key or id(base.fn), width, shared_keys)
-    cache = _batch_fn_cache()
-    batched = cache.get(key)
-    if batched is None:
-        batched = planner.vmapped_plan(base, shared_keys)
-        cache.put(key, batched)
-        BATCH_AUDIT.record_compile()
-    else:
-        BATCH_AUDIT.record_hit()
-    out, compile_ms = _enqueue(
-        trace, batched, (cols, stacked), device,
-        segment=segment.name, kind=base.kind, backend=base.cache_key[2], members=n,
-    )
-
-    docs = segment.num_docs
-    share, rem = divmod(docs, n)
-    stats_list = []
-    for i in range(n):
-        st = ExecutionStats(
-            num_segments_queried=1,
-            num_segments_processed=1,
-            num_docs_scanned=share + (1 if i < rem else 0),
-            total_docs=docs,
-        )
-        st.filter_index_uses = tuple(plans[i].index_uses)
-        st.kernel_bytes = base.scan_bytes / n
-        stats_list.append(st)
-    stats_list[0].compile_ms = compile_ms
-    return ("pending_batch", ctxs, segment, plans, out, stats_list)
-
-
-def collect_segment_batch(state):
-    """Phase 2 of a batched launch: ONE device_get fence for all members,
-    then per-member unstack (leading `query` axis) and host decode via the
-    same path the unbatched collect uses — batched results are bit-exact
-    vs. sequential execution."""
-    import jax
-
-    _, ctxs, segment, plans, out, stats_list = state
-    host = jax.device_get(out)
-    results = []
-    for i, (ctx, plan, st) in enumerate(zip(ctxs, plans, stats_list)):
-        member = jax.tree_util.tree_map(lambda a: a[i], host)
-        results.append(_decode_host(ctx, segment, plan, member, st))
-    return results
 
 
 def execute_segment(ctx: QueryContext, segment: ImmutableSegment, device=None):

@@ -171,9 +171,8 @@ class SegmentPlan:
     # a combining group program's: device -> the tables its first call of a
     # query folds into (identity_tables), made once a device
     identity: Dict[Any, Any] = field(default_factory=dict)
-    # plan-cache key (shape fp, segment signature, backend) — the stable
-    # identity the cross-query batcher keys its vmapped-fn LRU on, so
-    # batching never compiles more than once per (shape, batch width)
+    # plan-cache key (shape fp, segment signature, backend): a launch's span
+    # and a group program's name read the backend from it
     cache_key: Optional[Tuple] = None
     # whether plan_segment took the compiled fn from the plan cache (the
     # `cache` attr of the launch_plan span)
@@ -193,17 +192,6 @@ class SegmentPlan:
     # kernel was compiled for the table's shape, not the segment's (the
     # `shape` attr of the launch_plan span)
     table_shaped: bool = False
-
-
-def vmapped_plan(base: SegmentPlan, shared_keys: frozenset) -> SegmentPlan:
-    """`base` with its kernel vmapped over a leading `query` axis of every
-    parameter buffer but `shared_keys` (the columns are shared): what a
-    cross-query batched launch calls.  A program of its own, compiled apart
-    from base.fn, so with a first-launch record of its own."""
-    axes = {k: (None if k in shared_keys else 0) for k in base.params}
-    return replace(
-        base, fn=jax.jit(jax.vmap(base.fn, in_axes=(None, axes))), launched_on={}
-    )
 
 
 # A member's run in a group program's joined column starts on a multiple of

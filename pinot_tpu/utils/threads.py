@@ -1,7 +1,7 @@
 """Threading-primitive injection seam for the serving-tier protocols.
 
 The hand-rolled lock/condition-variable protocols (ResidencyManager,
-AdmissionController/ResourceBudget, MicroBatcher, LeaseManager /
+AdmissionController/ResourceBudget, LeaseManager /
 CoordinatorHandle, ServerHealth) construct their primitives through THIS
 module instead of `threading` directly:
 
@@ -11,7 +11,7 @@ module instead of `threading` directly:
     self._cv = threads.Condition()
 
 Under the default provider every call delegates 1:1 to the stdlib
-(`threading.Lock`, `concurrent.futures.Future`, `time.monotonic`) — zero
+(`threading.Lock`, `threading.Condition`, `time.monotonic`) — zero
 behavior change, no monkeypatching, nothing to configure.  The model
 checker (analysis/scheduler.py) installs a `DeterministicScheduler`
 provider for the duration of one explored schedule, so every primitive
@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import threading as _threading
 import time as _time
-from concurrent.futures import Future as _Future
 from contextlib import contextmanager
 from typing import Any, Iterator
 
@@ -48,7 +47,6 @@ class RealProvider:
     Condition = staticmethod(_threading.Condition)
     Event = staticmethod(_threading.Event)
     Thread = staticmethod(_threading.Thread)
-    Future = staticmethod(_Future)
     monotonic = staticmethod(_time.monotonic)
 
     @staticmethod
@@ -108,10 +106,6 @@ def Event():
 
 def Thread(*args, **kwargs):
     return _current.Thread(*args, **kwargs)
-
-
-def Future():
-    return _current.Future()
 
 
 def monotonic() -> float:

@@ -74,9 +74,9 @@ class TestKnobRegistry:
         monkeypatched env (and the autopilot-off path) behaves exactly
         like the pre-registry construction-time read."""
         reg = KnobRegistry()
-        assert reg.get("batch_wait_ms") == 2.0
-        monkeypatch.setenv("PINOT_TPU_BATCH_WAIT_MS", "5.5")
-        assert reg.get("batch_wait_ms") == 5.5
+        assert reg.get("hedge_budget_pct") == 10.0
+        monkeypatch.setenv("PINOT_TPU_HEDGE_BUDGET_PCT", "5.5")
+        assert reg.get("hedge_budget_pct") == 5.5
 
     def test_hard_ceiling_invariant(self):
         """Setters can NEVER exceed the static env-derived clamp bounds."""
@@ -93,21 +93,21 @@ class TestKnobRegistry:
 
     def test_set_many_one_atomic_tick(self):
         reg = KnobRegistry()
-        applied = reg.set_many({"batch_wait_ms": 4.0, "hedge_budget_pct": 5.0})
-        assert applied == {"batch_wait_ms": 4.0, "hedge_budget_pct": 5.0}
+        applied = reg.set_many({"hedge_delay_mult": 2.0, "hedge_budget_pct": 5.0})
+        assert applied == {"hedge_delay_mult": 2.0, "hedge_budget_pct": 5.0}
         view = reg.view()
-        assert view["batch_wait_ms"] == 4.0
+        assert view["hedge_delay_mult"] == 2.0
         assert view["hedge_budget_pct"] == 5.0
 
     def test_snapshot_marks_overrides_and_reset_clears(self):
         reg = KnobRegistry()
-        reg.set("batch_wait_ms", 4.0)
+        reg.set("hedge_budget_pct", 4.0)
         snap = reg.snapshot()["knobs"]
-        assert snap["batch_wait_ms"]["overridden"] is True
+        assert snap["hedge_budget_pct"]["overridden"] is True
         assert snap["pipeline_depth"]["overridden"] is False
         reg.reset()
-        assert reg.snapshot()["knobs"]["batch_wait_ms"]["overridden"] is False
-        assert reg.get("batch_wait_ms") == 2.0
+        assert reg.snapshot()["knobs"]["hedge_budget_pct"]["overridden"] is False
+        assert reg.get("hedge_budget_pct") == 10.0
 
     def test_splits_normalized_copy(self):
         reg = KnobRegistry()
@@ -164,7 +164,7 @@ class TestControlLaw:
         reg.set("hedge_budget_pct", 0.0)  # pinned at lo: saturated
         _, d = drive(ap, led, sim, 300.0, n=2)
         assert d["action"] == "degrade"
-        assert d["knob"] == "batch_wait_ms"  # next rung, not the pinned one
+        assert d["knob"] == "pipeline_depth"  # next rung, not the pinned one
 
     def test_ladder_walk_sets_cooldown(self):
         ap, reg, led, sim = make_pilot(slo_ms=100.0)
@@ -172,7 +172,6 @@ class TestControlLaw:
         reg.set_many(
             {
                 "hedge_budget_pct": 0.0,
-                "batch_wait_ms": 8.0,
                 "pipeline_depth": 1,
                 "staging_depth": 1,
             }
@@ -190,7 +189,6 @@ class TestControlLaw:
         reg.set_many(
             {
                 "hedge_budget_pct": 0.0,
-                "batch_wait_ms": 8.0,
                 "pipeline_depth": 1,
                 "staging_depth": 1,
                 "degrade_level": 3,
@@ -307,24 +305,6 @@ class TestControlLaw:
 
 
 class TestLiveKnobConsumers:
-    def test_batcher_wait_ms_live(self):
-        from pinot_tpu.cluster.batcher import MicroBatcher
-
-        b = MicroBatcher(runner=lambda entries: None, clock=lambda: 0.0)
-        assert b.wait_ms == 2.0
-        knobs().set("batch_wait_ms", 6.0)
-        assert b.wait_ms == 6.0  # no rebuild
-        b.wait_ms = 1.0  # direct assignment pins (pre-registry idiom)
-        knobs().set("batch_wait_ms", 7.0)
-        assert b.wait_ms == 1.0
-
-    def test_batcher_ctor_value_pins(self):
-        from pinot_tpu.cluster.batcher import MicroBatcher
-
-        b = MicroBatcher(runner=lambda entries: None, wait_ms=3.0, clock=lambda: 0.0)
-        knobs().set("batch_wait_ms", 6.0)
-        assert b.wait_ms == 3.0
-
     def test_hedge_controller_live(self):
         from pinot_tpu.cluster.broker import HedgeController
 
@@ -444,7 +424,7 @@ class TestObservability:
             code, payload = self._get(srv.port, "/debug/autopilot")
             assert code == 200
             assert payload["enabled"] is False
-            k = payload["knobs"]["batch_wait_ms"]
+            k = payload["knobs"]["hedge_budget_pct"]
             assert {"value", "initial", "lo", "hi", "overridden"} <= set(k)
         finally:
             srv.stop()
@@ -476,14 +456,14 @@ class TestObservability:
         broker = Broker(_small_cluster())
         broker.attach_autopilot()
         broker.autopilot.tick()
-        knobs().set("batch_wait_ms", 4.0)
+        knobs().set("hedge_budget_pct", 4.0)
         srv = QueryServer(broker).start()
         try:
             rc = cli_main(["autopilot", "--url", f"http://127.0.0.1:{srv.port}"])
             assert rc == 0
             out = capsys.readouterr().out
             assert "autopilot : ON" in out
-            assert "batch_wait_ms" in out and "*" in out  # override marker
+            assert "hedge_budget_pct" in out and "*" in out  # override marker
             rc = cli_main(
                 ["autopilot", "--url", f"http://127.0.0.1:{srv.port}", "--json"]
             )
@@ -491,15 +471,15 @@ class TestObservability:
             import json
 
             payload = json.loads(capsys.readouterr().out)
-            assert payload["knobs"]["batch_wait_ms"]["value"] == 4.0
+            assert payload["knobs"]["hedge_budget_pct"]["value"] == 4.0
         finally:
             srv.stop()
 
     def test_knob_gauges_published(self):
         from pinot_tpu.utils.metrics import METRICS
 
-        knobs().set("batch_wait_ms", 4.0)
-        assert METRICS.gauge("autopilot.knob.batch_wait_ms").value == 4.0
+        knobs().set("hedge_budget_pct", 4.0)
+        assert METRICS.gauge("autopilot.knob.hedge_budget_pct").value == 4.0
 
     def test_autopilot_env_toggle_attaches(self, monkeypatch):
         from pinot_tpu.cluster.broker import Broker

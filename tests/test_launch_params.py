@@ -52,11 +52,41 @@ DATA = _data()
 VALID = np.random.default_rng(5).random(N) < 0.7  # the upsert segment's validDocIds
 
 
-def _segment(upsert: bool, name="s0"):
-    seg = build_segment(_schema(), DATA, name)
+def _segment(upsert: bool, name="s0", rows=slice(None)):
+    seg = build_segment(_schema(), {k: v[rows] for k, v in DATA.items()}, name)
     if upsert:
-        seg.valid_docs = VALID.copy()
+        seg.valid_docs = VALID[rows].copy()
     return seg
+
+
+def _halves(upsert: bool):
+    """DATA's rows as two segments: one plan (every column's few values are in
+    both halves), so a query's two members ride ONE group call."""
+    return [_segment(upsert, "h0", slice(0, N // 2)), _segment(upsert, "h1", slice(N // 2, N))]
+
+
+def _launch_halves(ctx, segs, trace=None, device=None):
+    """`ctx` over `segs` as a server launches it (QueryLaunches), dispatched
+    and not collected."""
+    launches = executor.QueryLaunches(ctx, device=device, trace=trace)
+    for seg in segs:
+        launches.add(seg)
+    launches.flush()
+    return launches
+
+
+def _merged(launches):
+    """The members' answers as one: a combined group ships ONE table (None at
+    the other members' places), anything else a result a member."""
+    answers = [_answer(res) for res, _ in launches.collect() if res is not None]
+    if isinstance(answers[0], tuple):
+        return tuple(sum(x) for x in zip(*answers))
+    out = {}
+    for a in answers:
+        for k, (c, t) in a.items():
+            c0, t0 = out.get(k, (0, 0))
+            out[k] = (c0 + c, t0 + t)
+    return out
 
 
 def _grouped(mask, col):
@@ -149,9 +179,11 @@ CARRIED = {
 }
 
 
-def _check_carried(name, plan):
-    """The call's arguments: one host numpy buffer per dtype, the valid mask beside them."""
+def _check_carried(name, plan, rows=N):
+    """The call's arguments: one host numpy buffer per dtype, the valid mask
+    (the segment's `rows`) beside them."""
     carried, n_params = CARRIED[name]
+    carried = {k: (d, (rows,) if k == "__valid__" else sh) for k, (d, sh) in carried.items()}
     assert {k: (v.dtype.name, v.shape) for k, v in plan.params.items()} == carried
     assert all(type(v) is np.ndarray for v in plan.params.values())
     assert len(plan.param_layout) == n_params
@@ -189,97 +221,102 @@ def test_warm_launch_ships_no_parameter_array(name, device_puts):
 
 
 @pytest.mark.parametrize("name", list(CASES))
-def test_warm_batched_launch_ships_no_parameter_array(name, device_puts):
-    kind, seg, ctxs, wants = _case(name)
-    first = executor.collect_segment_batch(executor.launch_segment_batch(ctxs, seg))
-    assert [_answer(r) for r, _ in first] == wants
+def test_warm_group_launch_ships_no_parameter_array(name, device_puts):
+    """The width-2 group call of a server's launch: the members' parameter
+    buffers are stacked on the host ([2, n] numpy) and ride the ONE jitted
+    call; the columns are the resident ones, joined inside the program."""
+    kind, _, ctxs, wants = _case(name)
+    segs = _halves(CASES[name][1])
+    assert _merged(_launch_halves(ctxs[0], segs)) == wants[0]  # compiles, stages the columns
     staged = len(device_puts)
+    assert staged > 0
 
-    trace = Trace(True)
-    st = executor.launch_segment_batch(ctxs, seg, trace=trace)
+    for ctx, want in zip(ctxs, wants):  # warm: the same literals, then others of the shape
+        trace = Trace(True)
+        launches = _launch_halves(ctx, segs, trace)
+        assert len(device_puts) == staged  # no transfer but the call's own
+        assert launches.calls == 1 and launches.grouped_segments == 2
+        ((state, _, _),) = launches._states
+        assert [p.kind for p in state[3]] == [kind, kind] and all(p.cache_hit for p in state[3])
+        for plan in state[3]:
+            _check_carried(name, plan, rows=N // 2)
+        assert _merged(launches) == want
+        spans = _spans(trace.finish())
+        (enqueue,) = spans["launch_enqueue"]
+        assert enqueue["attrs"]["width"] == enqueue["attrs"]["segments"] == 2
+        assert [c["name"] for c in enqueue["children"]] == ["launch_release"]
+        assert [sp["attrs"]["paramArrays"] for sp in spans["launch_ship"]] == [len(state[3][0].params)] * 2
     assert len(device_puts) == staged
-    assert all(p.kind == kind for p in st[3])
-    assert [_answer(r) for r, _ in executor.collect_segment_batch(st)] == wants  # each lane its own literals
-    _check_trace(trace, st[3][0])
-    # and lane for lane the unbatched launch's answer
-    assert [_answer(executor.execute_segment(c, seg)[0]) for c in ctxs] == wants
 
 
 @pytest.mark.parametrize("name", list(CASES))
 def test_one_compiled_plan_sees_one_argument_form(name):
     """Committed device arrays and host numpy are different signatures to
     jit: a caller that still device-put its parameters would compile the
-    plan a second time.  The batch path, launch_segment and execute_segment
-    all run one plan here; it is compiled once, and other literals of the
-    same shape add nothing."""
+    plan a second time.  launch_segment, execute_segment and the group
+    launch (which traces the plan's kernel inside its own program) all run
+    one plan here; it is compiled once, and other literals of the same shape
+    add nothing."""
     kind, seg, ctxs, wants = _case(name)
     planner.plan_cache_clear()
     METRICS.reset()
 
-    batch = executor.launch_segment_batch(ctxs, seg)  # cold
-    fn = batch[3][0].fn
-    assert fn._cache_size() == 0  # called only under vmap so far, and lowered by nobody
-    assert [_answer(r) for r, _ in executor.collect_segment_batch(batch)] == wants
+    def counter(key):
+        return METRICS.snapshot()["counters"].get(key, 0)
 
-    def compiles():
-        return METRICS.snapshot()["counters"].get("compile.sse.compiles", 0)
-
-    n_compiles = compiles()
-    assert n_compiles == 1
-    st = executor.launch_segment(ctxs[0], seg)
-    assert st[3][0].fn is fn and _answer(executor.collect_segment(st)[0]) == wants[0]
+    st = executor.launch_segment(ctxs[0], seg)  # cold
+    fn = st[3][0].fn
+    assert _answer(executor.collect_segment(st)[0]) == wants[0]
+    assert fn._cache_size() == 1 and counter("compile.sse.compiles") == 1
     assert _answer(executor.execute_segment(ctxs[0], seg)[0]) == wants[0]
     assert fn._cache_size() == 1
+
+    # the second form: two members of that plan in ONE call (the same segment twice)
+    launches = _launch_halves(ctxs[0], [seg, seg])
+    ((state, _, _),) = launches._states
+    assert launches.calls == 1 and all(p.fn is fn for p in state[3])
+    double = _merged(launches)
+    assert double == (
+        {k: (2 * c, 2 * t) for k, (c, t) in wants[0].items()} if isinstance(wants[0], dict)
+        else tuple(2 * x for x in wants[0])
+    )
+    assert counter("compile.group.programs") == 1  # a program of its own, once
+    assert fn._cache_size() == 1 and counter("compile.sse.compiles") == 1  # and the plan's kernel compiled no second time
+
     assert _answer(executor.execute_segment(ctxs[1], seg)[0]) == wants[1]  # same shape, other literals
-    assert fn._cache_size() == 1 and compiles() == n_compiles
+    _launch_halves(ctxs[1], [seg, seg]).collect()
+    assert fn._cache_size() == 1 and counter("compile.sse.compiles") == 1
+    assert counter("compile.group.programs") == 1 and counter("compile.sse.rebuilds") == 0
 
 
 # ---------------------------------------------------------------------------
 # first launch: a fact of (program, device), kept on the plan-cache entry
 # ---------------------------------------------------------------------------
-def _launch_one(ctxs, seg, device, trace, on_first_launch=None):
-    st = executor.launch_segment(ctxs[0], seg, device=device, trace=trace, on_first_launch=on_first_launch)
-    _, stats = executor.collect_segment(st)
-    return st[3][0], stats.compile_ms, stats.kernel_bytes
-
-
-def _launch_batch(ctxs, seg, device, trace, on_first_launch=None):
-    st = executor.launch_segment_batch(ctxs, seg, device=device, trace=trace)
-    stats = [s for _, s in executor.collect_segment_batch(st)]
-    assert all(s.compile_ms == 0.0 for s in stats[1:])  # the compile lands on member 0
-    return st[3][0], stats[0].compile_ms, sum(s.kernel_bytes for s in stats)
-
-
-@pytest.mark.parametrize("launch", [_launch_one, _launch_batch], ids=["launch_segment", "launch_segment_batch"])
-def test_first_launch_is_per_plan_and_device(launch):
+def test_first_launch_is_per_plan_and_device():
     """Two of tier-1's eight devices: the program compiles once on each, the
     span says so there and only there, and the record is the plan-cache
-    entry's, so the plan a cache hit builds knows what the first one did."""
+    entry's, so the plan a cache hit builds knows what the first one did.
+    (A group program's first launch: tests/test_group_launch.py.)"""
     _, seg, ctxs, _ = _case("groupby_dense")
     planner.plan_cache_clear()
-    executor._batch_fn_cache().clear()
     d0, d1 = jax.devices()[1], jax.devices()[2]
     seen = []
     for device, want_first in [(d0, True), (d0, False), (d1, True), (d1, False), (d0, False)]:
         trace = Trace(True)
         hooked = []
-        plan, compile_ms, kernel_bytes = launch(ctxs, seg, device, trace, lambda: hooked.append(1))
+        st = executor.launch_segment(ctxs[0], seg, device=device, trace=trace, on_first_launch=lambda: hooked.append(1))
+        _, stats = executor.collect_segment(st)
+        plan, compile_ms, kernel_bytes = st[3][0], stats.compile_ms, stats.kernel_bytes
         (enqueue,) = _spans(trace.finish())["launch_enqueue"]
         assert enqueue["attrs"].get("firstLaunch", False) == want_first, (device, enqueue)
         assert (compile_ms > 0) == want_first and ("compileMs" in enqueue["attrs"]) == want_first
-        if launch is _launch_one:
-            assert hooked == ([1] if want_first else [])  # called before the compile, and only then
+        assert hooked == ([1] if want_first else [])  # called before the compile, and only then
         assert kernel_bytes == pytest.approx(plan.scan_bytes) and plan.scan_bytes > 0
         seen.append(plan)
     record = seen[0].launched_on
     assert all(p.launched_on is record for p in seen)  # one record, shared by reference
     assert seen[0] is not seen[1] and seen[1].cache_hit
-    if launch is _launch_one:
-        assert set(record) == {d0, d1} and all(ms > 0 for ms in record.values())
-    else:
-        assert record == {}  # the vmapped program keeps its own; the plan's own fn never ran
-        ((batched, _, _),) = executor._batch_fn_cache()._entries.values()
-        assert batched.fn is not seen[0].fn and set(batched.launched_on) == {d0, d1}
+    assert set(record) == {d0, d1} and all(ms > 0 for ms in record.values())
 
 
 def test_a_hit_that_races_the_first_launch_shares_its_record():
@@ -333,14 +370,6 @@ def test_server_on_device_3_answers_from_device_3(name, monkeypatch):
     if name.startswith("no_column"):
         (res,) = results
         assert int(res.partials[0]["count"]) == (int(VALID.sum()) if upsert else N)
-
-
-def test_batched_launch_runs_on_the_given_device():
-    dev = jax.devices()[3]
-    _, seg, ctxs, wants = _case("upsert")
-    st = executor.launch_segment_batch(ctxs, seg, device=dev)
-    assert all(leaf.devices() == {dev} for leaf in jax.tree_util.tree_leaves(st[4]))
-    assert [_answer(r) for r, _ in executor.collect_segment_batch(st)] == wants
 
 
 def test_pack_and_unpack_are_inverse_and_group_by_dtype():

@@ -45,7 +45,7 @@ def test_cli_lint_json_report(capsys):
     # the model-check sweep rides along in the one machine-readable gate
     mc = payload["modelCheck"]
     assert mc["ok"] is True
-    assert set(mc["protocols"]) == {"admission", "batcher", "knobs", "lease", "residency"}
+    assert set(mc["protocols"]) == {"admission", "knobs", "lease", "residency"}
     for entry in mc["protocols"].values():
         assert entry["failure"] is None
 

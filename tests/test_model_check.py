@@ -123,7 +123,7 @@ def test_cli_mc_capture_then_replay(tmp_path, capsys):
 
 
 def test_provider_restored_after_schedules():
-    model_check.run_schedule(PROTOCOLS["batcher"], seed=1)
+    model_check.run_schedule(PROTOCOLS["admission"], seed=1)
     assert threads.provider() is threads._DEFAULT
     # and real primitives work immediately after a checker run
     ev = threads.Event()

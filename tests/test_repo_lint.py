@@ -701,61 +701,6 @@ class TestW017UnfencedDispatchTiming:
         assert _rules(src) == []
 
 
-class TestW018BlockingInDispatch:
-    def test_flags_sleep_in_batcher_pump(self):
-        src = """
-        import time
-
-        class MicroBatcher:
-            def pump(self, now=None):
-                time.sleep(0.001)  # busy-wait for stragglers
-                return self._flush(now)
-        """
-        assert _rules(src, threaded=True) == ["W018"]
-
-    def test_flags_device_fence_in_dispatch_loop(self):
-        src = """
-        def broker_dispatch_loop(queue):
-            out = queue.popleft()
-            out.block_until_ready()
-        """
-        assert _rules(src, threaded=True) == ["W018"]
-
-    def test_flags_socket_wait_in_batcher_method(self):
-        src = """
-        class QueryBatcher:
-            def drain(self, sock):
-                return sock.recv(4096)
-        """
-        assert _rules(src, threaded=True) == ["W018"]
-
-    def test_quiet_on_condition_wait_and_out_of_scope_sleep(self):
-        src = """
-        import time
-
-        class MicroBatcher:
-            def pump(self, now=None):
-                with self._cv:
-                    self._cv.wait(timeout=0.01)  # sanctioned wakeup
-                return 0
-
-        def warmup():
-            time.sleep(0.5)  # not a dispatch path
-        """
-        assert _rules(src, threaded=True) == []
-
-    def test_rule_is_threaded_scope_only(self):
-        src = """
-        import time
-
-        class MicroBatcher:
-            def pump(self):
-                time.sleep(0.001)
-        """
-        assert _rules(src, threaded=False) == []
-        assert _rules(src, threaded=True) == ["W018"]
-
-
 class TestW019RetryLoopDiscipline:
     def test_flags_retry_loop_without_backoff(self):
         src = """
@@ -778,15 +723,17 @@ class TestW019RetryLoopDiscipline:
         """
         assert _rules(src, threaded=True) == ["W019"]
 
-    def test_flags_batch_reissue_without_cancels(self):
+    def test_only_a_server_call_makes_a_loop_a_retry_loop(self):
+        # `.execute(...)` by name: a per-segment executor call in a drain
+        # loop is no scatter, whatever keywords it lacks
         src = """
-        def rebatch(server, ctxs, segs, sleep):
+        def drain(executor, ctx, segs):
+            out = []
             while segs:
-                out = server.execute_batch(ctxs, segs)
-                segs = out.failed
-                sleep(0.002)
+                out.append(executor.execute_segment(ctx, segs.pop()))
+            return out
         """
-        assert _rules(src, threaded=True) == ["W019"]
+        assert _rules(src, threaded=True) == []
 
     def test_quiet_on_backoff_plus_cancel(self):
         src = """
@@ -1108,8 +1055,8 @@ class TestW026ControllerDiscipline:
 
     def test_flags_augassign_on_managed_knob(self):
         src = """
-        def widen(batcher):
-            batcher.wait_ms += 1.0
+        def narrow(engine):
+            engine.pipeline_depth -= 1
         """
         assert _rules(src) == ["W026"]
 
@@ -1128,13 +1075,13 @@ class TestW026ControllerDiscipline:
         # construction wires defaults; the property setter IS the sanctioned
         # pin-the-override path (stores an underscore override)
         src = """
-        class MicroBatcher:
-            def __init__(self, wait_ms):
-                self.wait_ms = wait_ms
+        class HedgeController:
+            def __init__(self, budget_pct):
+                self.budget_pct = budget_pct
 
-            @wait_ms.setter
-            def wait_ms(self, value):
-                self._wait_ms_override = float(value)
+            @budget_pct.setter
+            def budget_pct(self, value):
+                self._budget_pct_override = float(value)
         """
         assert _rules(src) == []
 

@@ -911,9 +911,11 @@ class Broker:
         if rt is None:
             return
         from pinot_tpu.query import executor as sse_executor
+        from pinot_tpu.query.planner import QueryPlanning
 
         with trace.span("realtime") as rsp:
             rt_docs = 0
+            planning = QueryPlanning(realtime_ctx)  # the pruner's verdicts, once a query
             for seg in rt.query_segments():
                 deadline.check(f"query on {table}")
                 if cancel is not None:
@@ -926,7 +928,7 @@ class Broker:
                         )
                 stats.num_segments_queried += 1
                 stats.total_docs += seg.num_docs
-                if sse_executor.prune_segment(realtime_ctx, seg):
+                if sse_executor.prune_segment(realtime_ctx, seg, planning):
                     stats.num_segments_pruned += 1
                     continue
                 res, sstats = sse_executor.execute_segment(realtime_ctx, seg)

@@ -21,6 +21,7 @@ segments, driving the broker's failover paths deterministically.
 from __future__ import annotations
 
 import os
+import threading
 from typing import Dict, List, Optional
 
 from pinot_tpu.cluster.admission import QueryKilledError, ResourceBudget
@@ -76,6 +77,11 @@ class ServerInstance:
         # kernels are compiled for (segment/table_shape.py), kept by
         # add_segment / drop_segment
         self.shapes: Dict[str, TableShape] = {}
+        # (table, query shape) whose programs were made ahead of need (_warm_widths)
+        self._width_warmed: set = set()
+        # table -> (the segment names a query named, their columns' min / max as arrays): _bounds_of
+        self._bounds: Dict[str, tuple] = {}
+        self._width_warm_lock = threading.Lock()
         # cluster.faults.FaultPlan hook (None in production)
         self.fault_plan = fault_plan
         # HBM reservation ledger (cluster.admission.ResourceBudget): every
@@ -138,6 +144,9 @@ class ServerInstance:
         self.segments.setdefault(table, {})[segment.name] = segment
         # one of this name held before (replaced in place) leaves the shape as this one joins
         self.shapes.setdefault(table, TableShape()).add(segment)
+        with self._width_warm_lock:
+            self._width_warmed.clear()  # a new segment may bring a kernel of its own, or fill a wider group
+        self._bounds.pop(table, None)
         # device-residency gauge: segment host arrays mirror what the
         # executor's pytree cache pins in HBM for this table
         METRICS.gauge(f"server.segmentBytes.{table}").add(_segment_bytes(segment))
@@ -145,6 +154,7 @@ class ServerInstance:
 
     def drop_segment(self, table: str, seg_name: str) -> None:
         seg = self.segments.get(table, {}).pop(seg_name, None)
+        self._bounds.pop(table, None)
         if seg is not None:
             self.shapes[table].remove(seg_name)  # add_segment made it
             for held in [seg, *seg.star_tables(made_only=True)]:  # its star-tree levels are groups of their own
@@ -279,24 +289,32 @@ class ServerInstance:
                 check=lambda: self._check_budget(deadline, cancelled=launches.uncollected, cancel=cancel),
             )
             with trace.span("dispatch") as dsp:
-                # host-side pre-filter FIRST: range/bloom metadata prunes
-                # cold segments before any staging, so a pruned segment
-                # never enters the host->device copy stream
-                scan = []
-                for name in seg_names:
-                    seg = self.get_segment(ctx.table, name)
-                    if seg is not None and plan is not None and plan.segment_dropped(self.name, ctx.table, name):
-                        seg = None
-                    if seg is None:
-                        raise KeyError(f"server {self.name} does not serve {ctx.table}/{name}")
-                    stats.num_segments_queried += 1
-                    stats.total_docs += seg.num_docs
-                    if table_schema is not None:
-                        seg.ensure_columns(table_schema, planning.needed_columns(seg))
-                    if executor.prune_segment(ctx, seg):
-                        stats.num_segments_pruned += 1
-                        continue
-                    scan.append(seg)
+                # host-side pre-filter FIRST: dictionary/range/bloom metadata
+                # prunes cold segments before any planning or staging, so a
+                # pruned segment never enters the host->device copy stream
+                # (span `prune`: every named segment looked up and asked;
+                # a pruned one still counts as queried)
+                scan, named = [], []
+                with trace.span("prune") as psp:
+                    for name in seg_names:
+                        seg = self.get_segment(ctx.table, name)
+                        if seg is not None and plan is not None and plan.segment_dropped(self.name, ctx.table, name):
+                            seg = None
+                        if seg is None:
+                            raise KeyError(f"server {self.name} does not serve {ctx.table}/{name}")
+                        stats.num_segments_queried += 1
+                        stats.total_docs += seg.num_docs
+                        if table_schema is not None:
+                            seg.ensure_columns(table_schema, planning.needed_columns(seg))
+                        named.append(seg)
+                    bounds = self._bounds_of(ctx.table, seg_names, named)
+                    for seg, pruned in zip(named, planning.prune_many(named, bounds)):
+                        if pruned:
+                            stats.num_segments_pruned += 1
+                        else:
+                            scan.append(seg)
+                if psp is not None:
+                    psp.annotate(segments=len(named), pruned=stats.num_segments_pruned)
                 for k, seg in enumerate(scan):
                     if self.residency is not None and k + 1 < len(scan):
                         # double-buffer: stage segment k+1's columns on the
@@ -328,6 +346,9 @@ class ServerInstance:
                     launches=launches.calls, starSegments=launches.star_segments,
                     combinedSegments=launches.combined_segments,
                     tableShapedSegments=launches.table_shaped_segments,
+                    docRangeSegments=launches.doc_range_segments,
+                    indexServedPredicates=launches.index_served,
+                    indexScannedPredicates=launches.index_scanned,
                     loopMs=round(dsp.duration_ms - sum(c.duration_ms for c in dsp.children), 3),
                 )
             if trace.enabled:
@@ -347,7 +368,10 @@ class ServerInstance:
                 stats.add_index_uses(seg_stats.filter_index_uses)
                 stats.add_kernel_cost(seg_stats)
                 results.append(res)
+            if stats.num_segments_pruned and launches.shape_fp is not None:
+                self._warm_widths(ctx, named, launches, trace)
             # server-local series the broker federates into the cluster view
+            self.metrics.counter("server.segmentsPruned").inc(stats.num_segments_pruned)
             self.metrics.counter("server.queries").inc()
             self.metrics.counter("server.docsScanned").inc(stats.num_docs_scanned)
             self.metrics.counter("server.launches").inc(launches.calls)
@@ -378,6 +402,38 @@ class ServerInstance:
             if ticket is not None:
                 self.budget.release(ticket)
 
+    def _bounds_of(self, table: str, seg_names: List[str], segments: List):
+        """The columns' min / max over the segments a query names, as arrays
+        (planner.SegmentBounds): kept a table for the list the last query
+        named (a table's queries name the same list until the routing
+        changes) and dropped when a segment joins or leaves."""
+        kept = self._bounds.get(table)
+        if kept is None or kept[0] != seg_names:
+            from pinot_tpu.query.planner import SegmentBounds
+
+            kept = self._bounds[table] = (list(seg_names), SegmentBounds(segments))
+        return kept[1]
+
+    def _warm_widths(self, ctx: QueryContext, asked: List, launches, trace) -> None:
+        """The first query of a shape that PRUNES here (its answer already
+        collected) makes every program a later query of the shape can need
+        ready for this server's device: the pruner leaves another member
+        count a query, so other widths of the group ladder, the other form,
+        and the kernels of the segments it dropped (executor.warm_widths,
+        span `width_warm`, attr `programs`).  Once a (table, query shape): a
+        table whose queries scan every segment never comes here."""
+        shape = (ctx.table, launches.shape_fp)
+        with self._width_warm_lock:
+            if shape in self._width_warmed:
+                return
+            self._width_warmed.add(shape)
+        with trace.span("width_warm", segments=len(asked)) as wsp:
+            made = executor.warm_widths(
+                ctx, asked, device=self.device, residency=self.residency, planning=launches.planning
+            )
+        if wsp is not None:
+            wsp.annotate(programs=made)
+
     def warm(self, ctx: QueryContext, seg_names: List[str], table_schema=None) -> None:
         """Compile, for this server's device, the programs `ctx` runs over the
         named local segments, by running it once as `execute` would (the same
@@ -398,7 +454,7 @@ class ServerInstance:
                 continue
             if table_schema is not None:
                 seg.ensure_columns(table_schema, launches.planning.needed_columns(seg))
-            if not executor.prune_segment(ctx, seg):
+            if not executor.prune_segment(ctx, seg, launches.planning):
                 launches.add(seg)
         launches.flush()
         compile_ms = sum(seg_stats.compile_ms for _, seg_stats in launches.collect())

@@ -30,17 +30,36 @@ import numpy as np
 from pinot_tpu.indexes.bitmap import num_words, WORD_BITS
 
 
+# Up to this many dictionary values a bitmap row is one compare and one
+# packbits over the codes; past it, one sort of the codes serves every row
+_COMPARE_MAX_CARDINALITY = 64
+
+
 def _bitmaps_from_codes(codes: np.ndarray, cardinality: int, num_docs: int) -> np.ndarray:
-    """Build (cardinality, words) doc bitmaps from the code array in one
-    vectorized pass (the off-heap creator analog)."""
+    """Build (cardinality, words) doc bitmaps from the code array (the
+    off-heap creator analog): bit `d & 31` of word `d >> 5` of row `c` is
+    set where doc `d` holds code `c`.  A low-cardinality column (what an
+    inverted index is for) takes a compare and a little-endian packbits a
+    value: 0.5 ms a value at 1.5M rows, numpy calls that release the
+    interpreter lock, where the scatter-OR this replaces (np.bitwise_or.at)
+    took 0.26 s for 25 values under it.  A larger dictionary sorts the docs
+    by code once and ORs each (row, word)'s bits with one reduceat."""
     words = num_words(num_docs)
-    out = np.zeros((cardinality, words), dtype=np.uint32)
-    docs = np.arange(num_docs, dtype=np.int64)
-    w = docs >> 5
-    bit = np.uint32(1) << (docs & 31).astype(np.uint32)
-    # scatter-OR per (code, word); np.bitwise_or.at handles duplicates.
-    np.bitwise_or.at(out, (codes.astype(np.int64), w), bit)
-    return out
+    codes = np.asarray(codes)[:num_docs]
+    if cardinality <= _COMPARE_MAX_CARDINALITY:
+        out = np.zeros((cardinality, words * 4), dtype=np.uint8)
+        for c in range(cardinality):
+            bits = np.packbits(codes == c, bitorder="little")
+            out[c, : bits.size] = bits
+        return out.view("<u4").astype(np.uint32, copy=False)
+    out = np.zeros(cardinality * words, dtype=np.uint32)
+    if num_docs:
+        order = np.argsort(codes, kind="stable")  # docs ascending within a code
+        flat = codes[order].astype(np.int64) * words + (order >> 5)  # non-decreasing
+        bit = np.uint32(1) << (order & 31).astype(np.uint32)
+        starts = np.flatnonzero(np.r_[True, flat[1:] != flat[:-1]])
+        out[flat[starts]] = np.bitwise_or.reduceat(bit, starts)
+    return out.reshape(cardinality, words)
 
 
 class InvertedIndex:

@@ -138,7 +138,7 @@ class QueryEngine:
                 if any(isinstance(s, Expr) and s.is_column and s.op == "*" for s in ctx.select_list):
                     needed = list(dict.fromkeys(list(needed) + state.schema.column_names))
                 seg.ensure_columns(state.schema, needed)
-                if executor.prune_segment(ctx, seg):
+                if executor.prune_segment(ctx, seg, launches.planning):
                     stats.num_segments_pruned += 1
                     continue
                 launches.add(seg)

@@ -24,7 +24,7 @@ import numpy as np
 from pinot_tpu.query import planner
 from pinot_tpu.utils.metrics import Trace
 from pinot_tpu.query.functions import combine_field
-from pinot_tpu.query.ir import Expr, FilterNode, FilterOp, PredicateType, QueryContext
+from pinot_tpu.query.ir import Expr, QueryContext
 from pinot_tpu.query.transform import eval_expr_host
 from pinot_tpu.query.result import (
     AggSegmentResult,
@@ -39,67 +39,29 @@ from pinot_tpu.segment.segment import ImmutableSegment
 # ---------------------------------------------------------------------------
 # Pruning (SegmentPrunerService analog — entirely host-side, metadata only)
 # ---------------------------------------------------------------------------
-def _top_level_predicates(node: Optional[FilterNode]):
-    if node is None:
-        return []
-    if node.op is FilterOp.PRED:
-        return [node.predicate]
-    if node.op is FilterOp.AND:
-        out = []
-        for c in node.children:
-            out.extend(_top_level_predicates(c))
-        return out
-    return []
-
-
-def prune_segment(ctx: QueryContext, segment: ImmutableSegment) -> bool:
-    """True if the segment provably matches no rows (value/bloom pruner)."""
-    for p in _top_level_predicates(ctx.filter):
-        if not p.lhs.is_column or p.lhs.op == "*" or p.lhs.op not in segment.columns:
-            continue
-        c = segment.column(p.lhs.op)
-        s = c.stats
-        if s.num_docs == 0:
-            return True
-        if p.ptype is PredicateType.EQ:
-            v = p.values[0]
-            if c.has_dictionary:
-                if c.dictionary.index_of(v) < 0:
-                    return True
-            elif s.min_value is not None and not c.data_type.is_string_like:
-                try:
-                    if v < s.min_value or v > s.max_value:
-                        return True
-                except TypeError:
-                    pass
-            bloom = segment.indexes.get("bloom", {}).get(p.lhs.op)
-            if bloom is not None and not bloom.might_contain(v):
-                return True
-        elif p.ptype is PredicateType.IN:
-            if c.has_dictionary and all(c.dictionary.index_of(v) < 0 for v in p.values):
-                return True
-        elif p.ptype is PredicateType.RANGE and s.min_value is not None:
-            try:
-                if p.lower is not None and (
-                    s.max_value < p.lower or (s.max_value == p.lower and not p.lower_inclusive)
-                ):
-                    return True
-                if p.upper is not None and (
-                    s.min_value > p.upper or (s.min_value == p.upper and not p.upper_inclusive)
-                ):
-                    return True
-            except TypeError:
-                pass
-    return False
+def prune_segment(ctx: QueryContext, segment: ImmutableSegment, planning: Optional[planner.QueryPlanning] = None) -> bool:
+    """True if the segment provably matches no rows (value/bloom pruner):
+    planner.QueryPlanning.prunes.  `planning` is the query's, where the
+    caller asks about more than one segment: a predicate's verdict is then
+    resolved once a query per distinct dictionary, and a surviving segment's
+    plan binds with the same resolution."""
+    return (planning if planning is not None else planner.QueryPlanning(ctx)).prunes(segment)
 
 
 # ---------------------------------------------------------------------------
 # Execution
 # ---------------------------------------------------------------------------
 # A group program is compiled for a power-of-two number of members, so a plan
-# has at most log2(MAX_GROUP_WIDTH) programs beside its own however a query's
-# pruning moves its member count: 40 segments launch as 5 x 8, 37 as 4 x 8 +
-# 4 + 1.  Eight, not more, by measurement on the chip (PERF.md, PR 29): a
+# has at most log2(MAX_GROUP_WIDTH) programs a form (stacked, combining)
+# beside its own however a query's pruning moves its member count: 40
+# segments launch as 5 x 8, 37 as 4 x 8 + 4 + 1.  A table whose queries all
+# scan every segment meets the same widths in every query and compiles them
+# at its first; where the pruner leaves another count a query (a table cut by
+# time), the first query of a shape that prunes has the server make the
+# narrower programs, the other form and the shape's other kernels (the
+# segments it pruned may hold another signature) before it returns
+# (warm_widths), so no later query compiles.  Eight, not more, by
+# measurement on the chip (PERF.md, PR 29): a
 # server's 40 segments then need ONE group program a plan where 32 + 8 need
 # two, and a program's cost is its set-up (compile: ~1 s at 8 and 2.6-4.2 s
 # at 32 for a dense group-by, 6-7 s either way for Q1; trace, lower and load
@@ -250,6 +212,8 @@ def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=No
     out, members[0].stats.compile_ms = _enqueue(
         trace, program, args, device, on_first_launch,
         segments=width, width=width, kind=base.kind, backend=base.cache_key[2],
+        # the host operands the call ships: the members' packed parameters
+        paramBytes=sum(int(v.nbytes) for v in args[1].values()),
     )
     tables, plans, stats = [m.table for m in members], [m.plan for m in members], [m.stats for m in members]
     if combined is not None and before is not None:
@@ -332,6 +296,10 @@ class QueryLaunches:
         self.star_level_rows = 0  # the true rows of those levels
         self.combined_segments = 0  # segments whose dense tables the chip folded into their group's one
         self.table_shaped_segments = 0  # segments whose kernel was compiled for the table's shape, not their own
+        self.doc_range_segments = 0  # segments whose plan answers a sorted column's predicate with a doc range
+        self.index_served = 0  # predicates answered from a range / inverted index's bitmaps
+        self.index_scanned = 0  # predicates on a column with such an index that scanned its codes (filter.bitmap_serves)
+        self.shape_fp: Optional[str] = None  # the first planned segment's shape fingerprint: the query shape's name
         self._added = 0
         # the query's half of its plans, derived once (the caller's, where it
         # already asked it for the columns the query reads)
@@ -347,8 +315,16 @@ class QueryLaunches:
             member = _plan_member(
                 self.ctx, segment, self.device, self.residency, self.trace, self.planning
             )
-        self.kernel_bytes += member.plan.scan_bytes
-        self.table_shaped_segments += member.plan.table_shaped
+        plan = member.plan
+        self.kernel_bytes += plan.scan_bytes
+        self.table_shaped_segments += plan.table_shaped
+        if plan.index_uses:
+            kinds = [kind for _, kind in plan.index_uses]
+            self.doc_range_segments += "sorted" in kinds
+            self.index_served += sum(kind in ("range", "inverted") for kind in kinds)
+        self.index_scanned += len(plan.index_scans)
+        if self.shape_fp is None:
+            self.shape_fp = plan.cache_key[0]
         if lsp is not None:
             # beside the span's cpuMs (the rest of its wall time is waiting:
             # interpreter lock, a lock); kernelBytes is EXPLAIN ANALYZE's Bytes
@@ -420,6 +396,71 @@ class QueryLaunches:
             if csp is not None:
                 csp.annotate(docs=sum(answers[slot][1].num_docs_scanned for slot in slots))
         return answers
+
+
+_WARM_THREADS = 4  # first launches made at once ahead of need: a compile releases the interpreter lock
+
+
+def warm_widths(ctx: QueryContext, segments: List[ImmutableSegment], device=None, residency=None,
+                planning: Optional[planner.QueryPlanning] = None) -> int:
+    """Make ready, for `device`, every program a query of `ctx`'s SHAPE can
+    launch over `segments` (ALL of the table's segments the server was asked
+    about, the pruned ones too) whatever its literals leave after pruning:
+    each kernel the segments' signatures resolve to (a pruned segment may
+    hold another: a column sorted in one segment and not in the next), alone
+    and in the widths of the ladder its segments can fill, in the stacked
+    form and, where the plan's tables combine, the combining one (the form
+    follows the members' key spaces: _combines_as).  A program already
+    launched on `device` is left alone; each of the others runs once over
+    real members, its answer dropped, so the jitted call's own cache holds
+    it (an AOT compile would be paid again at the first call).  The
+    parameters are `ctx`'s own: a pruned segment binds to an empty range.
+    Returns the programs made (counter compile.sse.widthWarmups).  Called
+    by the server once a query shape, at the first query of it that prunes
+    (ServerInstance.execute), before that query returns: a table whose
+    queries scan every segment never comes here."""
+    from collections import Counter
+    from concurrent.futures import ThreadPoolExecutor
+
+    from pinot_tpu.utils.metrics import METRICS
+
+    planning = planning if planning is not None else planner.QueryPlanning(ctx)
+    by_kernel: Dict[int, List[Tuple[ImmutableSegment, planner.SegmentPlan]]] = {}
+    for segment in segments:
+        table, asked = planning.source(segment)
+        plan = asked.plan(table)
+        by_kernel.setdefault(id(plan.fn), []).append((segment, plan))
+    tasks = []  # (segments to launch as one group, whether their tables combine)
+    for group in by_kernel.values():
+        base = group[0][1]
+        cap = min(group_cap(base.scan_bytes, residency), len(group))
+        # the segments of the commonest key space combine (where the plan's tables combine at all); a group
+        # of several key spaces, or of tables that do not combine, is stacked
+        spaces = Counter(_key_space_id(plan) for _, plan in group) if planner.combines(base) else Counter()
+        commonest = spaces.most_common(1)[0][0] if spaces else None
+        same = [seg for seg, plan in group if spaces and _key_space_id(plan) == commonest]
+        mixed = len(spaces) != 1
+        if device not in base.launched_on:
+            tasks.append(([group[0][0]], False))
+        width = 2
+        while width <= cap:
+            if mixed and device not in planner.grouped_plan(base, width, False).launched_on:
+                tasks.append(([seg for seg, _ in group[:width]], False))
+            if len(same) >= width and device not in planner.grouped_plan(base, width, True).launched_on:
+                tasks.append((same[:width], True))
+            width *= 2
+
+    def first_launch(task) -> None:
+        some, combine = task
+        trace = Trace()
+        members = [_plan_member(ctx, seg, device, residency, trace, planning) for seg in some]
+        _launch_group(ctx, members, device, trace, None, _combines_as(members) if combine else None)
+
+    if tasks:
+        with ThreadPoolExecutor(max_workers=min(_WARM_THREADS, len(tasks)), thread_name_prefix="warm-width") as pool:
+            list(pool.map(first_launch, tasks))
+        METRICS.counter("compile.sse.widthWarmups").inc(len(tasks))
+    return len(tasks)
 
 
 def _enqueue(trace, plan, args, device, on_first_launch=None, **attrs):

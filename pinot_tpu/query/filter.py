@@ -139,6 +139,51 @@ _DICT_RESOLVED = frozenset({
 # many bitmap-row ORs (past it a code scan reads less)
 _INV_MAX_ROWS = 256
 
+# What each side of the index decision moves, bytes a second: the chip reads
+# its own memory (a v5e's HBM, benchmarks/peaks.json), a launch's host
+# operands cross the host link
+_HBM_BYTES_PER_S = 819e9
+_HOST_LINK_BYTES_PER_S = 16e9
+
+
+def bitmap_serves(table_like, col) -> bool:
+    """Whether a predicate on dictionary column `col` of `table_like` is
+    answered from the column's inverted / range index (doc bitmaps ORed on
+    the host, rows / 8 bytes shipped with the launch) rather than by a scan
+    of its codes: the planner's decision, from what each costs, for a
+    segment whose columns are resident on the device.  The scan reads
+    rows x code_bits / 8 bytes of HBM; the bitmap, at its best (ONE row, no
+    OR), crosses the host link with rows / 8 bytes, a launch a segment a
+    query.  So the index wins only where code_bits passes the ratio of the
+    two speeds (~51): never for a dictionary column, whose codes ride in
+    4 to 32 bits.  A bitmap RESIDENT on the device and gathered there would
+    win by bytes where fewer rows are ORed than the lane has bits, by at most
+    (code_bits - 1) / 8 bytes a row: 1.6 us of a 1.5M-row segment's 8-bit
+    column, under a thousandth of the launch that carries it, for 19 MB of
+    HBM a segment; it is not built (PERF.md, PR 47).  The index is still
+    built, stored and reported; `index_scans` says which predicates had one
+    and scanned.  The decision hangs on the segment's shape alone (rows, lane
+    width), not on a literal, so a predicate on an indexed column is a
+    parameter slot of the shape fingerprint (query/shape.py) and binds on a
+    plan-cache hit.  The stacked engines' tables (parallel/, mse/: sharded
+    code matrices whose index words the engine slices per launch) are not
+    costed here and keep the index path."""
+    if not isinstance(table_like, ImmutableSegment):
+        return True
+    codes = getattr(col, "codes", None)
+    bits = getattr(col, "code_bits", None) or (codes.dtype.itemsize * 8 if codes is not None else 32)
+    rows = table_like.num_docs
+    return rows / 8 / _HOST_LINK_BYTES_PER_S < rows * bits / 8 / _HBM_BYTES_PER_S
+
+
+def sorted_doc_range(col, lo_code: int, hi_code: int) -> Tuple[int, int]:
+    """The docs [d0, d1) of a SORTED column that hold codes [lo_code,
+    hi_code): two reads of the column's dictId -> first-doc table
+    (ColumnData.first_docs), whatever the segment's rows."""
+    first = col.first_docs()
+    d0 = int(first[lo_code])
+    return d0, (int(first[hi_code]) if hi_code > lo_code else d0)
+
 
 class FilterCompiler:
     """Compiles one filter tree against one segment.
@@ -179,6 +224,9 @@ class FilterCompiler:
         self.used_columns = set()
         # (column, "sorted"|"range"|"inverted") per index-accelerated predicate
         self.index_uses: List[Tuple[str, str]] = []
+        # (column, "range"|"inverted") per predicate whose column has such an
+        # index and whose codes are scanned all the same (bitmap_serves)
+        self.index_scans: List[Tuple[str, str]] = []
         # Sharded compilation target (_ShardView): (axis_name, ndev,
         # local_rows) — bitmap params split on the leading device axis and
         # doc ranges compare against GLOBAL flat doc ids (parallel/engine.py)
@@ -201,9 +249,10 @@ class FilterCompiler:
         self._root_compiled = False
         # How each compiled predicate's parameters are made, in compile
         # order: (kind, ptype, column, multi-value, the param keys it
-        # wrote), kind "none" (no parameter), "range" (lo, hi codes) or
+        # wrote), kind "none" (no parameter), "range" (lo, hi codes),
         # "table" (a bool per dictionary entry), both dict_predicate_codes
-        # of (the segment's dictionary, the literals).  None once a
+        # of (the segment's dictionary, the literals), or "docrange" (a
+        # sorted column's [d0, d1): sorted_doc_range of those codes).  None once a
         # predicate was compiled whose parameters are not such a pure
         # function, or whose path an index may choose differently for
         # another segment or literal: a plan-cache hit then rebuilds
@@ -452,16 +501,19 @@ class FilterCompiler:
 
         # -- index-accelerated paths (no code scan) ----------------------
         if not is_mv:
-            accel = self._try_index_paths(name, col, lo_code, hi_code, table, has_nulls)
+            accel = self._try_index_paths(name, col, lo_code, hi_code, table, has_nulls, pt)
             if accel is not None:
                 return accel
 
         # What follows scans the codes with parameters that are
-        # dict_predicate_codes of this segment's dictionary.  With an
-        # inverted index on the column the choice between bitmap and scan
-        # hangs on how many codes the literals select in THIS dictionary, so
-        # another segment of the same signature may take the other path.
-        if pt in _DICT_RESOLVED and (is_mv or self._col_index("inverted", name) is None):
+        # dict_predicate_codes of this segment's dictionary.  Where the
+        # column's inverted index may serve (bitmap_serves: a stacked
+        # engine's table) the choice between bitmap and scan hangs on how
+        # many codes the literals select in THIS dictionary, so another
+        # segment of the same signature may take the other path.
+        if pt in _DICT_RESOLVED and (
+            is_mv or self._col_index("inverted", name) is None or not bitmap_serves(self.segment, col)
+        ):
             self._bindable = ("range" if table is None else "table", pt, name, is_mv)
 
         if table is not None:
@@ -578,11 +630,27 @@ class FilterCompiler:
 
         return eval_bitmap
 
-    def _try_index_paths(self, name, col, lo_code, hi_code, table, has_nulls):
+    def _consults_index(self, name, col) -> bool:
+        """Whether `name`'s range / inverted index answers its predicates
+        (bitmap_serves); where the column has one and scans, says so."""
+        if bitmap_serves(self.segment, col):
+            return True
+        for kind in ("range", "inverted"):
+            if self._col_index(kind, name) is not None:
+                self.index_scans.append((name, kind))
+                break
+        return False
+
+    def _try_index_paths(self, name, col, lo_code, hi_code, table, has_nulls, pt=None):
         """Sorted doc-range > range-index > inverted-index, else None (scan)."""
         if lo_code is not None:  # code-range predicate (EQ / RANGE)
             if col.stats.is_sorted and col.codes is not None:
                 codes_arr = np.asarray(col.codes)
+                if codes_arr.ndim == 1 and hasattr(col, "first_docs"):
+                    # the sorted index: two reads of the dictId -> first-doc
+                    # table, and on a plan-cache hit the recipe does the same
+                    self._bindable = ("docrange", pt, name, False)
+                    return self._emit_doc_range(name, *sorted_doc_range(col, lo_code, hi_code), has_nulls)
                 if codes_arr.ndim == 2:
                     # stacked [S, D]: flat row-major order IS the build input
                     # order (padding all at the tail) — slice it off so
@@ -598,7 +666,7 @@ class FilterCompiler:
             return self._try_bitmap_range(name, col, lo_code, hi_code, has_nulls)
         # table predicate (IN / NOT_IN / NEQ / regex / LIKE)
         inv = self._col_index("inverted", name)
-        if inv is None:
+        if inv is None or not self._consults_index(name, col):
             return None
         pos = np.nonzero(table)[0]
         neg_ids = np.nonzero(~table)[0]
@@ -612,6 +680,8 @@ class FilterCompiler:
 
     def _try_bitmap_range(self, name, col, lo_code, hi_code, has_nulls):
         """Range-index / inverted-index resolution for a code-range predicate."""
+        if not self._consults_index(name, col):
+            return None
         rng_idx = self._col_index("range", name)
         if rng_idx is not None:
             return self._emit_bitmap(

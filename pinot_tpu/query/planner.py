@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pinot_tpu import ops
-from pinot_tpu.query.filter import FilterCompiler, dict_predicate_codes
+from pinot_tpu.query.filter import _DICT_RESOLVED, FilterCompiler, dict_predicate_codes, sorted_doc_range
 from pinot_tpu.query.functions import (
     FIELD_COMBINE,
     AggFunction,
@@ -40,7 +40,7 @@ from pinot_tpu.query.functions import (
     for_spec,
     get_agg_function,
 )
-from pinot_tpu.query.ir import AggregationSpec, Expr, QueryContext
+from pinot_tpu.query.ir import AggregationSpec, Expr, FilterOp, PredicateType, QueryContext
 from pinot_tpu.query.shape import column_info_from, params_structure
 from pinot_tpu.query.startree import StarRewrite, pick_level, star_enabled, star_need
 from pinot_tpu.query.transform import as_row_array, eval_expr
@@ -152,6 +152,10 @@ class SegmentPlan:
     select_exprs: List[Expr] = field(default_factory=list)
     # (column, index kind) per index-accelerated filter predicate
     index_uses: List[Tuple[str, str]] = field(default_factory=list)
+    # (column, index kind) per filter predicate whose column has a range /
+    # inverted index and whose codes the plan scans all the same: the
+    # planner's decision from costs (filter.bitmap_serves)
+    index_scans: List[Tuple[str, str]] = field(default_factory=list)
     # bytes the scan must read: the segment's rows x the stored bytes per
     # row of needed_columns (utils/perf.scan_bytes_per_row), counted once
     # when the plan-cache entry is built; every launch reports it
@@ -1344,7 +1348,8 @@ def _param_recipe(binders: Optional[List[Tuple]], layout: Tuple) -> Optional[Par
     rows_at = where.pop(ROWS_KEY, None)
     out = []
     for kind, ptype, column, mv, keys in binders:
-        want = {"none": [], "range": [("int32", ()), ("int32", ())], "table": [("bool", None)]}[kind]
+        two_ints = [("int32", ()), ("int32", ())]
+        want = {"none": [], "range": two_ints, "docrange": two_ints, "table": [("bool", None)]}[kind]
         slots = [where.pop(k, None) for k in keys]
         if len(slots) != len(want) or any(
             s is None or s[0] != dtype or (shape is not None and s[2] != shape)
@@ -1387,6 +1392,11 @@ def _bind_params(
             (_, lo_at, _), (_, hi_at, _) = slots
             ints = packed["int32"]
             ints[lo_at], ints[hi_at] = codes[0], codes[1]
+        elif kind == "docrange":
+            # the signature says the column is sorted: its docs of those codes
+            (_, lo_at, _), (_, hi_at, _) = slots
+            ints = packed["int32"]
+            ints[lo_at], ints[hi_at] = sorted_doc_range(columns[column], codes[0], codes[1])
         else:
             ((_, table_at, shape),) = slots
             table = codes[2]
@@ -1469,6 +1479,113 @@ def _segment_memo(segment) -> _SegmentMemo:
     return memo
 
 
+def _prune_tree(node, at: Optional[List[int]] = None):
+    """`node` (a FilterNode) as ("pred", i) | ("and" | "or" | "not",
+    [children]), i the predicate's place in FilterNode.predicates()' order
+    (QueryPlanning.predicates: WHERE's come first); None without a filter."""
+    if node is None:
+        return None
+    at = [0] if at is None else at
+    if node.op is FilterOp.PRED:
+        at[0] += 1
+        return ("pred", at[0] - 1)
+    return (node.op.name.lower(), [_prune_tree(c, at) for c in node.children])
+
+
+def _unsat_by_stats(p, c, segment) -> bool:
+    """Whether no row of column `c` can satisfy predicate `p`, from its min
+    / max (EQ / IN / RANGE: a few compares, no look-up; a table cut by time
+    drops most of its segments here) and, for EQ, its bloom filter."""
+    s = c.stats
+    lo, hi = s.min_value, s.max_value
+    pt = p.ptype
+    try:
+        if lo is not None and pt in (PredicateType.EQ, PredicateType.IN):
+            if all(v < lo or v > hi for v in p.values):
+                return True
+        elif lo is not None and pt is PredicateType.RANGE:
+            if p.lower is not None and (hi < p.lower or (hi == p.lower and not p.lower_inclusive)):
+                return True
+            if p.upper is not None and (lo > p.upper or (lo == p.upper and not p.upper_inclusive)):
+                return True
+    except TypeError:  # a literal of another type than the column's: the exact paths decide
+        pass
+    if pt is PredicateType.EQ:
+        bloom = segment.indexes.get("bloom", {}).get(p.lhs.op)
+        return bloom is not None and not bloom.might_contain(p.values[0])
+    return False
+
+
+class SegmentBounds:
+    """What a query's pruner asks of a fixed list of segments, column by
+    column, as arrays over the list: the columns' min / max, and which
+    DISTINCT dictionary each segment holds.  They let the pruner answer for
+    the whole list in a few numpy operations a predicate and one dictionary
+    look-up a distinct dictionary (QueryPlanning.prune_many), not a Python
+    loop a segment a predicate.  A server keeps one a table for the segment
+    list its queries name (ServerInstance._bounds_of) and drops it when a
+    segment joins or leaves; a column is read at its first use."""
+
+    def __init__(self, segments: List) -> None:
+        self.segments = list(segments)
+        self.empty = np.asarray([seg.num_docs == 0 for seg in self.segments], bool)
+        self._columns: Dict[str, Optional[Tuple[np.ndarray, np.ndarray]]] = {}
+        self._dictionaries: Dict[str, Optional[Tuple]] = {}
+        self._blooms: Dict[str, bool] = {}
+
+    def of(self, column: str) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """(min, max) of `column` a segment, or None where it is not numeric
+        in every segment (or an empty segment has no bounds for it)."""
+        if column not in self._columns:
+            got = None
+            try:
+                stats = [seg.columns[column].stats for seg in self.segments]
+                lo, hi = np.asarray([s.min_value for s in stats]), np.asarray([s.max_value for s in stats])
+                if lo.dtype.kind in "iuf" and hi.dtype.kind in "iuf":
+                    wide = np.float64 if "f" in (lo.dtype.kind, hi.dtype.kind) else np.int64
+                    got = (lo.astype(wide), hi.astype(wide))
+            except (KeyError, TypeError, ValueError, OverflowError):
+                pass
+            self._columns[column] = got
+        return self._columns[column]
+
+    def dictionaries(self, column: str) -> Optional[Tuple]:
+        """(which distinct dictionary each segment holds for `column`, int a
+        segment; the distinct dictionaries' _dictionary_identity; one
+        Dictionary of each), or None where some segment has no dictionary
+        for it: a table drawn by one generator has ONE, a table cut by time
+        one a segment for its date attributes."""
+        if column not in self._dictionaries:
+            got = None
+            identities = [_dictionary_identity(seg, column) for seg in self.segments]
+            if identities and all(i is not None for i in identities):
+                place: Dict[Any, int] = {}
+                which, distinct = [], []
+                for seg, identity in zip(self.segments, identities):
+                    if identity not in place:
+                        place[identity] = len(distinct)
+                        distinct.append(seg.columns[column].dictionary)
+                    which.append(place[identity])
+                got = (np.asarray(which), list(place), distinct)
+            self._dictionaries[column] = got
+        return self._dictionaries[column]
+
+    def all_raw(self, column: str) -> bool:
+        """No segment holds a dictionary for `column` (a raw column, or one no segment has)."""
+        return all(getattr(seg.columns.get(column), "dictionary", None) is None for seg in self.segments)
+
+    def has_bloom(self, column: str) -> bool:
+        if column not in self._blooms:
+            self._blooms[column] = any(
+                (getattr(seg, "indexes", None) or {}).get("bloom", {}).get(column) is not None for seg in self.segments
+            )
+        return self._blooms[column]
+
+
+def _is_number(v) -> bool:
+    return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+
 class QueryPlanning:
     """The query's half of planning, made once a query a server
     (executor.QueryLaunches) and asked for a plan a segment.
@@ -1523,6 +1640,133 @@ class QueryPlanning:
         self._half_under: Optional[Tuple] = None  # the table shape and version _half_key was made under
         self._shape_fps: Dict[Tuple, str] = {}
         self._lookups: Dict[Tuple, Tuple] = {}
+        # WHERE as (op, children | the predicate's place in `predicates`), and
+        # each dictionary predicate's verdict per distinct dictionary (prunes)
+        self._prune_tree = _prune_tree(ctx.filter)
+        self._verdicts: Dict[Tuple, bool] = {}
+
+    def prunes(self, segment) -> bool:
+        """True where `segment` provably holds no row WHERE selects, from its
+        metadata alone (upstream's SegmentPrunerService: the value and bloom
+        pruners), before anything is planned, staged or bound for it.  The
+        filter tree is read whole: a conjunction is unsatisfiable where one
+        child is, a disjunction where every child is (so Q4.2's `d_year =
+        1997 OR d_year = 1998` prunes like an IN), a NOT never.  A predicate
+        on a dictionary column is unsatisfiable where no entry of the
+        segment's dictionary matches (filter.dict_predicate_codes: exact for
+        EQ / IN / RANGE / NEQ / NOT IN / LIKE), and that verdict is resolved
+        ONCE a query per distinct dictionary (_dictionary_identity): the
+        resolution is the very one the plan's recipe binds with
+        (`_lookups`), so a segment that survives pays for it once.  Before
+        any look-up a column's min / max say no for most segments of a table
+        cut by time (_unsat_by_stats: a few compares); a raw column has
+        those and its bloom filter alone."""
+        tree = self._prune_tree
+        if tree is None:
+            return False
+        if segment.num_docs == 0:
+            return True
+        return self._unsat(tree, segment)
+
+    def prune_many(self, segments: List, bounds: Optional[SegmentBounds] = None) -> List[bool]:
+        """`prunes` of each of `segments`.  With `bounds` (the SegmentBounds
+        of that very list) the filter tree is answered for the whole list at
+        once (`_maybe`): a column's min / max rule segments out in a few
+        numpy compares, and a dictionary predicate's verdict is looked up
+        once a DISTINCT dictionary of the segments still standing and spread
+        over them; only where the tree holds something the arrays cannot
+        settle (a bloom filter, bounds that are not numbers) are the
+        segments left asked one by one."""
+        tree = self._prune_tree
+        if tree is None:
+            return [False] * len(segments)
+        try:
+            got = self._maybe(tree, bounds) if bounds is not None else None
+        except OverflowError:  # a literal past int64: the per-segment path decides
+            got = None
+        if got is None:
+            return [self.prunes(seg) for seg in segments]
+        maybe, settled = got
+        maybe = maybe & ~bounds.empty
+        if settled:
+            return (~maybe).tolist()
+        return [not possible or self.prunes(seg) for possible, seg in zip(maybe.tolist(), segments)]
+
+    def _maybe(self, node, bounds: SegmentBounds) -> Tuple[np.ndarray, bool]:
+        """(bool a segment of `bounds`: False where no row of the segment can
+        satisfy `node`; whether that is all `prunes` could say of `node`, so
+        that a True needs no second look)."""
+        op, arg = node
+        n = len(bounds.segments)
+        if op == "not":
+            return np.ones(n, bool), True
+        if op != "pred":
+            parts = [self._maybe(c, bounds) for c in arg]
+            fold = np.logical_and if op == "and" else np.logical_or
+            return fold.reduce([m for m, _ in parts]), all(settled for _, settled in parts)
+        p = self.predicates[arg]
+        if not p.lhs.is_column:
+            return np.ones(n, bool), True
+        column, pt = p.lhs.op, p.ptype
+        maybe, by_bounds = np.ones(n, bool), False
+        got = bounds.of(column)
+        if got is not None:
+            lo, hi = got
+            if pt in (PredicateType.EQ, PredicateType.IN) and all(_is_number(v) for v in p.values):
+                maybe, by_bounds = np.logical_or.reduce([(lo <= v) & (v <= hi) for v in p.values]), True
+            elif pt is PredicateType.RANGE and all(v is None or _is_number(v) for v in (p.lower, p.upper)):
+                by_bounds = True
+                if p.lower is not None:
+                    maybe &= (hi >= p.lower) if p.lower_inclusive else (hi > p.lower)
+                if p.upper is not None:
+                    maybe &= (lo <= p.upper) if p.upper_inclusive else (lo < p.upper)
+        held = bounds.dictionaries(column) if pt in _DICT_RESOLVED else None
+        if held is None:
+            # a raw column (or one some segment lacks): its bounds are all prunes has, but for a bloom filter
+            if pt in _DICT_RESOLVED and not bounds.all_raw(column):
+                return maybe, False  # some segments hold a dictionary for it, some none: each is asked
+            raw = pt in (PredicateType.EQ, PredicateType.IN, PredicateType.RANGE)
+            return maybe, not raw or (by_bounds and not (pt is PredicateType.EQ and bounds.has_bloom(column)))
+        which, identities, dictionaries = held
+        unsat = np.zeros(len(identities), bool)
+        for k in np.unique(which[maybe]).tolist():  # the distinct dictionaries of the segments still standing
+            unsat[k] = self._no_entry_matches(arg, identities[k], dictionaries[k])
+        settled = not (pt is PredicateType.EQ and bounds.has_bloom(column))
+        return maybe & ~unsat[which], settled
+
+    def _unsat(self, node, segment) -> bool:
+        op, arg = node
+        if op == "and":
+            return any(self._unsat(c, segment) for c in arg)
+        if op == "or":
+            return all(self._unsat(c, segment) for c in arg)
+        if op == "not":
+            return False
+        p = self.predicates[arg]
+        if not p.lhs.is_column:
+            return False
+        c = segment.columns.get(p.lhs.op)
+        if c is None:
+            return False
+        if _unsat_by_stats(p, c, segment):
+            return True
+        if c.dictionary is not None and p.ptype in _DICT_RESOLVED:
+            return self._no_entry_matches(arg, _dictionary_identity(segment, p.lhs.op), c.dictionary)
+        return False
+
+    def _no_entry_matches(self, at: int, identity, dictionary) -> bool:
+        """Whether no entry of `dictionary` (told apart by `identity`:
+        _dictionary_identity) satisfies predicate `at`: resolved once a query
+        per distinct dictionary, by the resolution the recipe binds with."""
+        memo = (at, identity)
+        verdict = self._verdicts.get(memo)
+        if verdict is None:
+            codes = self._lookups.get(memo)
+            if codes is None:
+                codes = self._lookups[memo] = dict_predicate_codes(self.predicates[at], dictionary)
+            lo, hi, table = codes
+            verdict = self._verdicts[memo] = (hi <= lo) if table is None else not bool(table.any())
+        return verdict
 
     def source(self, segment) -> Tuple[Any, "QueryPlanning"]:
         """(the table the query reads for `segment`, the planning to ask for
@@ -2035,6 +2279,7 @@ def _build_plan(
         select_columns=select_columns,
         select_exprs=select_exprs,
         index_uses=list(fc.index_uses),
+        index_scans=list(fc.index_scans),
         # an entry's alone: a rebuilt hit has the entry's.  A theta
         # sub-filter's predicates come out of a function's arguments, not
         # the query's filter trees: no recipe walks them

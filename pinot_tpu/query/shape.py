@@ -27,7 +27,9 @@ an explicit per-predicate audit:
       list lengths within a bucket share one compile.
 
   SHAPE-AFFECTING (literal stays in the key):
-    * any predicate resolvable through an INVERTED index: the positive-row
+    * any predicate resolvable through an INVERTED index that the compiler
+      consults (filter.bitmap_serves: a stacked engine's table; a resident
+      segment's indexed column scans and is a slot): the positive-row
       / negated-row / scan choice (`_INV_MAX_ROWS` thresholds in
       query/filter.py) depends on the literal and bakes `negate`;
     * TEXT_MATCH / JSON_MATCH / VECTOR_SIMILARITY (top-k `k` is traced);
@@ -92,6 +94,8 @@ def column_info_from(table_like: Any) -> ColumnInfo:
     anything with `.column(name)` and an `.indexes` dict.  Unknown columns
     (or any introspection failure) return None -> the audit bakes."""
 
+    from pinot_tpu.query.filter import bitmap_serves
+
     def info(name: str) -> Optional[ColumnShape]:
         try:
             col = table_like.column(name)
@@ -101,12 +105,17 @@ def column_info_from(table_like: Any) -> ColumnInfo:
             return None
         idx = getattr(table_like, "indexes", None) or {}
         stats = getattr(col, "stats", None)
+        # an index the compiler will not consult (filter.bitmap_serves: a
+        # resident segment's codes scan cheaper than a bitmap ships) shapes
+        # nothing: the predicate is a plain scan's, a parameter slot
+        inverted, ranged = name in (idx.get("inverted") or {}), name in (idx.get("range") or {})
+        consulted = (inverted or ranged) and bitmap_serves(table_like, col)
         return ColumnShape(
             has_dictionary=bool(getattr(col, "has_dictionary", False)),
             is_sorted=bool(getattr(stats, "is_sorted", False))
             and getattr(col, "codes", None) is not None,
-            has_inverted=name in (idx.get("inverted") or {}),
-            has_range_index=name in (idx.get("range") or {}),
+            has_inverted=inverted and consulted,
+            has_range_index=ranged and consulted,
         )
 
     return info

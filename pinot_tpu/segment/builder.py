@@ -164,6 +164,8 @@ def build_segment(
                 code_bits=bits if bits < 32 else None,
                 packed=packing.pack_codes(codes, bits) if bits < 32 else None,
             )
+            if stats.is_sorted and num_docs:
+                columns[f.name].first_docs()  # the sorted index: dictId -> first doc, kept from the build
             card = dictionary.cardinality
             if f.name in idx_cfg.inverted_index_columns:
                 if card <= MAX_BITMAP_INDEX_CARDINALITY:

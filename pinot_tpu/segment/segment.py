@@ -58,6 +58,20 @@ class ColumnData:
     # MV, and wide (>16-bit) columns.
     code_bits: Optional[int] = None
     packed: Optional[np.ndarray] = None
+    # a SORTED dictionary column's dictId -> first doc, cardinality + 1
+    # entries, the last one num_docs (upstream's SortedIndexReader): the
+    # docs of codes [lo, hi) are [first_docs[lo], first_docs[hi]).  Made by
+    # the builder; a loaded segment makes it at first use (first_docs()).
+    sorted_first_docs: Optional[np.ndarray] = None
+
+    def first_docs(self) -> np.ndarray:
+        """The sorted column's dictId -> first-doc table (see the field)."""
+        if self.sorted_first_docs is None:
+            assert self.stats.is_sorted and self.codes is not None and self.codes.ndim == 1, self.name
+            self.sorted_first_docs = np.searchsorted(
+                self.codes, np.arange(self.cardinality + 1), side="left"
+            ).astype(np.int32)
+        return self.sorted_first_docs
 
     @property
     def has_dictionary(self) -> bool:

@@ -676,3 +676,47 @@ def test_every_metric_of_the_sketch_cell_has_a_reader_that_returns_a_value(monke
     assert values["sketch_scatter_ms"] == pytest.approx(400.0 / len(reqs))
     assert values["sketch_hash_ms"] == pytest.approx(200.0 / (len(reqs) - weights["p95_rev_year_nation"]))
     assert {m["name"] for m in cell["end_to_end"]} == {"throughput_qps", "latency_p50_ms", "latency_p95_ms", "setup_s"}
+
+
+def test_every_metric_of_the_time_ordered_cell_has_a_reader_that_returns_a_value():
+    """`ssb_sf10_bydate.dashboard_closed` (PR 47): every per-layer metric `load_cell` gives the cell, the list-less
+    ones too, returns a value over a traced window of its own traffic at toy size (4 segments of 10,000 rows, each
+    a quarter of the calendar, sorted by `lo_orderdate`, six columns inverted), and every end-to-end metric; and what
+    the new names say hangs together: the pruner's count is the calendar's, every hit binds, nothing compiles in the
+    window whatever count survives, and no launch carries a row-length operand."""
+    import sys
+
+    values, cell, reqs, weights = _toy_window("ssb_sf10_bydate.dashboard_closed", 40_000, 47)
+    new = {"segments_pruned_per_query", "prune_ms", "doc_range_segments_per_query", "launch_param_bytes_per_query",
+           "bydate_roofline"}
+    listed = {"compiles_in_window", "combined_segments_per_query", "table_decode_cpu_ms", "tables_decoded_per_query",
+              "table_shaped_segments_per_query", "tables_merged_by_value_per_query", "warm_up_compiles_per_template",
+              "warm_up_s"}
+    assert new | listed | {"launches_per_query", "plan_rebuilds_in_window"} <= set(values)
+    assert "scan_kernel_ms" not in values and "scan_roofline" not in values  # their count is every row of the table
+    assert DOOR_SPECS <= set(values)
+    assert not [name for name, v in values.items() if v is None], values
+    assert values["compiles_in_window"] == values["plan_rebuilds_in_window"] == 0.0
+    # the pruner's count, from the requests' parameters and the generator's calendar (lib/prunecount.py)
+    bench_dir = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench_dir)
+    try:
+        from lib import prunecount
+        from lib.datagen import ssb_flat_bydate
+
+        cal = ssb_flat_bydate.calendar()
+        config = dict(cell["config"], rows=40_000, segment_rows=10_000)
+        pruned = []
+        for r in reqs:
+            ref = cell["query_set"]["templates"][r.template]["reference"]
+            dated = [dict(ref, where=[t]) for t in ref["where"] if t[0] in cal]
+            shares = [prunecount.segment_shares(config, prunecount.matching_days(one, r.params, cal)) for one in dated]
+            pruned.append(sum(any(s[i] == 0.0 for s in shares) for i in range(4)))
+    finally:
+        sys.path.remove(bench_dir)
+    assert values["segments_pruned_per_query"] == pytest.approx(np.mean(pruned)) and np.mean(pruned) > 1.0
+    assert values["doc_range_segments_per_query"] > 0.0 and values["prune_ms"] > 0.0
+    assert 0.0 < values["launch_param_bytes_per_query"] < 4096.0
+    assert 0.0 < values["bydate_roofline"] < 100.0
+    # not throughput_qps: the driver holds a new cell's spread to the PARENT's median, a ninth of the change's here (PERF.md section 7)
+    assert {m["name"] for m in cell["end_to_end"]} == {"latency_p50_ms", "latency_p95_ms", "setup_s"}

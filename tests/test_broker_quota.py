@@ -328,4 +328,11 @@ class TestRefreshSegment:
         after = broker.query("SELECT city, COUNT(*), SUM(v) FROM t GROUP BY city ORDER BY city").rows
         assert before == after
         r = broker.query("SELECT COUNT(*) FROM t WHERE city = 'sf'")
-        assert ("city", "inverted") in r.stats.filter_index_uses
+        # the refreshed segment has the index; a resident segment's plan scans the codes and says so (PR 47)
+        refreshed = next(iter(coord.servers.values())).get_segment("t", "seg0")
+        assert "city" in refreshed.indexes["inverted"] and not r.stats.filter_index_uses
+        from pinot_tpu.query import planner
+        from pinot_tpu.sql.parser import parse_query
+
+        plan = planner.plan_segment(parse_query("SELECT COUNT(*) FROM t WHERE city = 'sf'"), refreshed)
+        assert plan.index_scans == [("city", "inverted")]

@@ -236,7 +236,8 @@ def test_mixed_scan_list_forms_the_right_groups_and_the_same_rows(segments):
     upsert.valid_docs = np.random.default_rng(3).random(N) < 0.6
     pruned = build_segment(SCHEMA, _block(2), "no_ber")  # POOL[2..7]: no 'ber'; nor has seg4 (POOL[4..8] + 'ams')
     scan = [segments[0], other, star, segments[1], upsert, pruned, segments[4]]
-    sql = "SELECT year, COUNT(*), SUM(rev) FROM t WHERE city = 'ber' OR city = 'ams' GROUP BY year"
+    # a NOT, which the pruner never reads through (an OR of EQs it prunes like an IN since PR 47)
+    sql = "SELECT year, COUNT(*), SUM(rev) FROM t WHERE NOT (city != 'ber' AND city != 'ams') GROUP BY year"
     sql_pruning = "SELECT year, COUNT(*), SUM(rev) FROM t WHERE city = 'ber' GROUP BY year"
 
     server = ServerInstance("s")
@@ -330,12 +331,17 @@ def test_pruning_moves_the_member_count_and_compiles_nothing_beyond_the_ladder(s
     # 'edi' = POOL[4] is in all five too: other literal, nothing new
     stats, moved = ask("edi")
     assert stats.num_segments_pruned == 0 and moved == (0, 0, 2, 4) and stats.compile_ms == 0.0
-    # 'gva' = POOL[6] is missing from seg0 (POOL[0..5]): four members, the x4 program again
+    # 'gva' = POOL[6] is missing from seg0 (POOL[0..5]): four members, the x4 program again; and the shape's
+    # first query that PRUNES has the server make the ladder's other program (x2) before it returns (PR 47:
+    # executor.warm_widths; the plan's own program ran as the lone member above)
+    warmups = METRICS.counter("compile.sse.widthWarmups").value
     stats, moved = ask("gva")
-    assert stats.num_segments_pruned == 1 and moved == (0, 0, 1, 4) and stats.compile_ms == 0.0
-    # 'ams' = POOL[0] is in seg0 and seg4 alone: x2, the ladder's next, and that is all a plan can add
+    assert stats.num_segments_pruned == 1 and moved == (1, 0, 1, 4) and stats.compile_ms == 0.0
+    assert METRICS.counter("compile.sse.widthWarmups").value == warmups + 1
+    # 'ams' = POOL[0] is in seg0 and seg4 alone: x2, the ladder's next, made ahead of need: nothing compiles
     stats, moved = ask("ams")
-    assert stats.num_segments_pruned == 3 and moved == (1, 0, 1, 2) and stats.compile_ms > 0
+    assert stats.num_segments_pruned == 3 and moved == (0, 0, 1, 2) and stats.compile_ms == 0.0
+    assert METRICS.counter("compile.sse.widthWarmups").value == warmups + 1
     (entry,) = [p for p, _, _ in planner._PLAN_CACHE._entries.values()]
     assert sorted(entry.widened) == [(2, False), (4, False)] and max(entry.widened)[0] <= executor.MAX_GROUP_WIDTH
 

@@ -77,27 +77,37 @@ QUERIES = [
 
 @pytest.mark.parametrize("sql_tpl,expected_use", QUERIES)
 def test_indexed_matches_scan(env, sql_tpl, expected_use):
+    """A segment resident on the device: the sorted column's doc range is
+    taken; a range / inverted index is built and kept, and the planner, from
+    costs (filter.bitmap_serves), scans the codes: the plan says which
+    predicates had an index and scanned (PR 47).  The answer is the plain
+    table's either way."""
     got_plain = env.query(sql_tpl.format(t="plain"))
     got_idx = env.query(sql_tpl.format(t="indexed"))
     assert got_idx.rows == got_plain.rows
-    assert expected_use in got_idx.stats.filter_index_uses
     assert not got_plain.stats.filter_index_uses
+    if expected_use[1] == "sorted":
+        assert expected_use in got_idx.stats.filter_index_uses
+    else:
+        seg = env.tables["indexed"].segments[0]
+        plan = planner.plan_segment(parse_query(sql_tpl.format(t="indexed")), seg)
+        assert expected_use in plan.index_scans and expected_use not in plan.index_uses
+        assert expected_use[0] in seg.indexes[expected_use[1]]
+        assert expected_use not in got_idx.stats.filter_index_uses
 
 
-def test_indexed_filter_column_not_shipped(env):
-    """An EQ predicate answered by the inverted index must not load the
-    filter column's codes onto the device at all."""
+def test_indexed_filter_column_scans_its_codes_and_ships_no_bitmap(env):
+    """An EQ predicate on an inverted-indexed column of a resident segment
+    scans the column's codes: no row-length bitmap rides the launch."""
     ctx = parse_query("SELECT SUM(v) FROM indexed WHERE city = 'sf'")
     seg = env.tables["indexed"].segments[0]
     plan = planner.plan_segment(ctx, seg)
-    assert ("city", "inverted") in plan.index_uses
-    assert "city" not in plan.needed_columns
-    assert "v" in plan.needed_columns
-    # bitmap words param shipped instead: ceil(N/32) uint32 words
+    assert plan.index_scans == [("city", "inverted")] and not plan.index_uses
+    assert "city" in plan.needed_columns and "v" in plan.needed_columns
     params = planner.unpack_params(plan.params, plan.param_layout)  # what the kernel reads
-    bits_params = [v for k, v in params.items() if k.endswith(".bits")]
-    assert len(bits_params) == 1 and bits_params[0].dtype == np.uint32
-    assert bits_params[0].shape[0] == -(-N // 32)
+    assert not [k for k in params if k.endswith(".bits")]
+    assert {k: v.shape for k, v in plan.params.items()} == {"int32": (2,)}  # the code range, packed
+    assert plan.recipe is not None  # and a plan-cache hit binds it
 
 
 def test_sorted_range_zero_reads(env):
@@ -131,7 +141,8 @@ def test_index_nulls_respected():
     e.add_segment("nt", build_segment(schema, data, "n0", table_config=cfg))
     r = e.query("SELECT COUNT(*) FROM nt WHERE c != 'a'")
     assert r.rows[0][0] == 2  # b rows only; NULLs excluded by 3VL
-    assert ("c", "inverted") in r.stats.filter_index_uses
+    plan = planner.plan_segment(parse_query("SELECT COUNT(*) FROM nt WHERE c != 'a'"), e.tables["nt"].segments[0])
+    assert plan.index_scans == [("c", "inverted")]  # the index is there; the codes are scanned (PR 47)
 
 
 # ---------------------------------------------------------------------------

@@ -508,8 +508,10 @@ class TestStages:
         # the two segments share one compiled kernel: ONE jitted call, a
         # sibling of their launch:<segment> spans under dispatch, and ONE fetch
         (dispatch,) = _spans(res.stats.trace)["dispatch"]
-        assert [k["name"] for k in dispatch["children"]] == ["launch:seg0", "launch:seg1", "launch_enqueue"]
-        enqueue = dispatch["children"][2]
+        # (and of the pruner's pass over the query's segments, PR 47)
+        assert [k["name"] for k in dispatch["children"]] == ["prune", "launch:seg0", "launch:seg1", "launch_enqueue"]
+        assert dispatch["children"][0]["attrs"] == {"segments": 2, "pruned": 0}
+        enqueue = dispatch["children"][3]
         assert enqueue["attrs"]["kind"] == "groupby_dense" and enqueue["attrs"]["backend"]
         assert enqueue["attrs"]["segments"] == enqueue["attrs"]["width"] == dispatch["attrs"]["launches"] * 2 == 2
         assert [k["name"] for k in enqueue["children"]] == ["launch_release"]  # its arguments dropped

@@ -119,8 +119,15 @@ class TestSnapshotIndexes:
         mgr.consume_all()
         r = eng.query("SELECT COUNT(*) FROM logs WHERE level = 'error'")
         assert int(r.rows[0][0]) == 20
-        # the CONSUMING snapshot's inverted index answered the filter
-        assert ("level", "inverted") in r.stats.filter_index_uses
+        # the CONSUMING snapshot has its inverted index; a resident segment's codes scan cheaper than a
+        # bitmap ships, so the planner scans them and says so (PR 47: filter.bitmap_serves)
+        snapshot = mgr.query_segments()[-1]
+        assert "level" in snapshot.indexes["inverted"]
+        from pinot_tpu.query import planner
+        from pinot_tpu.sql.parser import parse_query
+
+        plan = planner.plan_segment(parse_query("SELECT COUNT(*) FROM logs WHERE level = 'error'"), snapshot)
+        assert ("level", "inverted") in plan.index_scans and not r.stats.filter_index_uses
         r2 = eng.query("SELECT COUNT(*) FROM logs WHERE TEXT_MATCH(msg, 'failed')")
         assert int(r2.rows[0][0]) == 20
         assert ("msg", "text") in r2.stats.filter_index_uses

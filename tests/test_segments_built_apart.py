@@ -308,6 +308,11 @@ def test_a_table_whose_segments_agree_is_planned_as_before():
         assert all(p.fn is plans[0].fn and not p.table_shaped and p.dict_sizes == {"d": 700} for p in plans)
         assert all(p.num_groups == 700 and p.param_layout == plans[0].param_layout for p in plans)
         assert planner.grouped_plan(plans[1], 2).fn is planner.grouped_plan(plans[0], 2).fn
+        # an empty table_config over unordered rows: no index consulted, none scanned past, no doc range,
+        # and the recipe's kinds are the code scan's (PR 47 added `docrange` and `index_scans` beside them)
+        assert all(not p.index_uses and not p.index_scans for p in plans)
+        assert [b[0] for b in planner._PLAN_CACHE.get(plans[0].cache_key).recipe.binders] == ["table"]
+        assert not any(server.get_segment("agree", f"a{i}").column("d").stats.is_sorted for i in range(3))
     finally:
         planner.plan_cache_clear()
 

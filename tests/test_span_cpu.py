@@ -97,8 +97,8 @@ def test_the_stages_no_one_asked_stay_off_the_cpu_clock(tree):
 def test_dispatch_loop_ms_is_its_time_less_its_childrens(tree):
     (dispatch,) = _named(tree, "dispatch")
     kids = dispatch["children"]
-    assert sorted({k["name"].split(":", 1)[0] for k in kids}) == ["launch", "launch_enqueue"]
-    assert len(kids) == SEGMENTS + 1  # four segments planned and shipped, one jitted call
+    assert sorted({k["name"].split(":", 1)[0] for k in kids}) == ["launch", "launch_enqueue", "prune"]
+    assert len(kids) == SEGMENTS + 2  # the pruner's pass (PR 47), four segments planned and shipped, one jitted call
     loop = dispatch["ms"] - sum(k["ms"] for k in kids)
     assert dispatch["attrs"]["loopMs"] == pytest.approx(loop, abs=0.001 * (len(kids) + 2))  # each rounded to the us
     assert 0.0 <= dispatch["attrs"]["loopMs"] < dispatch["ms"]
@@ -106,20 +106,40 @@ def test_dispatch_loop_ms_is_its_time_less_its_childrens(tree):
 
 def test_a_slow_loop_is_loop_time_and_no_childs(broker, monkeypatch):
     """What the server's loop does between two launches has no span: it is
-    `loopMs`, and no child's `ms`."""
-    from pinot_tpu.query import executor
+    `loopMs`, and no child's `ms`.  (The pruner's pass has had a span of its
+    own since PR 47, so the loop is slowed where it checks the residency.)"""
+    from pinot_tpu.cluster import server as server_mod
 
-    real = executor.prune_segment
+    real = server_mod.executor.QueryLaunches.add
 
-    def slow_prune(ctx, seg):
-        time.sleep(0.02)
-        return real(ctx, seg)
+    def slow_add(self, seg):
+        real(self, seg)
+        time.sleep(0.02)  # after the segment's own spans closed: the loop's time
 
-    monkeypatch.setattr(executor, "prune_segment", slow_prune)
+    monkeypatch.setattr(server_mod.executor.QueryLaunches, "add", slow_add)
     slow = broker.query("SET trace = true; " + GROUP_SQL).stats.trace
     (dispatch,) = _named(slow, "dispatch")
     assert dispatch["attrs"]["loopMs"] >= SEGMENTS * 20.0
     assert sum(k["ms"] for k in dispatch["children"]) < dispatch["ms"] - SEGMENTS * 20.0 + 1.0
+
+
+def test_a_slow_pruner_is_the_prune_spans_time(broker, monkeypatch):
+    """The pruner's pass over a query's segments is the `prune` span under
+    `dispatch` (PR 47: `segments`, `pruned`), not the loop's."""
+    from pinot_tpu.query import planner
+
+    real = planner.QueryPlanning.prune_many
+
+    def slow_prune(self, segs, bounds=None):
+        time.sleep(0.02 * len(segs))
+        return real(self, segs, bounds)
+
+    monkeypatch.setattr(planner.QueryPlanning, "prune_many", slow_prune)
+    slow = broker.query("SET trace = true; " + GROUP_SQL).stats.trace
+    (dispatch,) = _named(slow, "dispatch")
+    (prune,) = _named(dispatch, "prune")
+    assert prune["ms"] >= SEGMENTS * 20.0 and prune["attrs"] == {"segments": SEGMENTS, "pruned": 0}
+    assert dispatch["attrs"]["loopMs"] < SEGMENTS * 20.0
 
 
 @pytest.mark.parametrize("sql", [GROUP_SQL, SCALAR_SQL], ids=["group_by", "scalar"])

@@ -288,6 +288,7 @@ def test_levels_of_unequal_rows_ride_one_program_and_one_launch(bench, ssb):
     assert {k: v for k, v in dispatch.items() if k != "loopMs"} == {
         "launches": 1, "starSegments": SEGMENTS, "combinedSegments": SEGMENTS,  # the levels' tables fold into one on the chip
         "tableShapedSegments": 0,  # a level is bucketed by its own rule, not by the table's shape
+        "docRangeSegments": 0, "indexServedPredicates": 0, "indexScannedPredicates": 0,  # nothing sorted, nothing indexed
     }
     assert stats.trace["attrs"]["docsScanned"] == stats.num_docs_scanned == sum(rows)
     assert all("cpuMs" in n["attrs"] and n["attrs"]["kernelBytes"] > 0 for n in spans["launch"])

@@ -208,7 +208,10 @@ class ServerInstance:
         results, and None at the others'), tableShapedSegments: the segments
         whose plan's kernel was compiled for the dictionary sizes the table's
         segments here share and not their own (`self.shapes`;
-        planner.compiled_dict_sizes), loopMs: its time outside its child
+        planner.compiled_dict_sizes), contractedLookups / gatheredLookups:
+        the table-by-code lookups in the launched segments' programs, by the
+        form each was compiled with (ops/code_lookup.py), loopMs: its time
+        outside its child
         spans; per segment a
         launch:<segment> span over the executor's
         launch_plan / launch_ship, and per GROUP of segments that share a
@@ -346,6 +349,8 @@ class ServerInstance:
                     launches=launches.calls, starSegments=launches.star_segments,
                     combinedSegments=launches.combined_segments,
                     tableShapedSegments=launches.table_shaped_segments,
+                    contractedLookups=launches.contracted_lookups,
+                    gatheredLookups=launches.gathered_lookups,
                     docRangeSegments=launches.doc_range_segments,
                     indexServedPredicates=launches.index_served,
                     indexScannedPredicates=launches.index_scanned,
@@ -379,6 +384,7 @@ class ServerInstance:
             self.metrics.counter("server.sparseGroups").inc(launches.sparse_groups)
             self.metrics.counter("server.combinedSegments").inc(launches.combined_segments)
             self.metrics.counter("server.tableShapedSegments").inc(launches.table_shaped_segments)
+            self.metrics.counter("server.contractedLookups").inc(launches.contracted_lookups)
             if launches.star_segments:
                 self.metrics.counter("server.starTreeSegments").inc(launches.star_segments)
                 self.metrics.counter("server.starTreeLevelRows").inc(launches.star_level_rows)

@@ -55,6 +55,19 @@ _CHUNK = 1 << 16
 _W = 64
 # Above this group count the one-hot matrices stop paying for themselves.
 _MATMUL_MAX_GROUPS = 8192
+# A table read at a row's dictionary code, `table[codes]`, is read by a
+# one-hot contraction (ops/code_lookup.py) where it has this many entries, by
+# a gather outside.  Measured on the chip, one 1.5M-row segment, kernel alone
+# (PERF.md section 5, PR 48): up to 64 entries the TPU compiler turns the
+# gather into compares and selects, priced by the table and not by the row
+# (under the 0.4 ms a call costs; the contraction 0.4 ms for a bool table, 1.2
+# for an int32 one); from 65 on the gather is 10.8-12.1 ms a segment whatever
+# the size, the contraction 0.4 / 1.2 ms up to 16,384 entries and linear in
+# the table past that: 8.2 ms at 131,072 int32 entries, 12.3 at 196,608.  The
+# upper end is that crossover (~172,000) rounded down to a power of two; a
+# bool table's lies past 327,680 (5.2 ms there) and is not told apart.
+_CONTRACT_MIN_TABLE = 65
+_CONTRACT_MAX_TABLE = 1 << 17
 
 _POS_INF32 = np.float32(np.inf)
 _NEG_INF32 = np.float32(-np.inf)

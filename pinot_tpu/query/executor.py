@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from pinot_tpu.ops.code_lookup import CONTRACTED, GATHERED
 from pinot_tpu.query import planner
 from pinot_tpu.utils.metrics import Trace
 from pinot_tpu.query.functions import combine_field
@@ -296,6 +297,8 @@ class QueryLaunches:
         self.star_level_rows = 0  # the true rows of those levels
         self.combined_segments = 0  # segments whose dense tables the chip folded into their group's one
         self.table_shaped_segments = 0  # segments whose kernel was compiled for the table's shape, not their own
+        self.contracted_lookups = 0  # table-by-code lookups of the launched segments' programs read by a one-hot contraction
+        self.gathered_lookups = 0  # and by a gather a row (ops/code_lookup.py)
         self.doc_range_segments = 0  # segments whose plan answers a sorted column's predicate with a doc range
         self.index_served = 0  # predicates answered from a range / inverted index's bitmaps
         self.index_scanned = 0  # predicates on a column with such an index that scanned its codes (filter.bitmap_serves)
@@ -370,6 +373,9 @@ class QueryLaunches:
             self.grouped_segments += len(group)
         if combined is not None:
             self.combined_segments += len(group)
+        lookups = members[0].plan.lookups  # the kernel's, traced by now: every member runs it
+        self.contracted_lookups += len(group) * lookups.get(CONTRACTED, 0)
+        self.gathered_lookups += len(group) * lookups.get(GATHERED, 0)
 
     def outputs(self) -> list:
         """Device outputs of every launched group: what a tracing caller

@@ -29,6 +29,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from pinot_tpu.ops.code_lookup import code_lookup
 from pinot_tpu.query.ir import FilterNode, FilterOp, Predicate, PredicateType
 from pinot_tpu.query.transform import eval_expr, _or_masks
 from pinot_tpu.segment.segment import ImmutableSegment
@@ -446,7 +447,7 @@ class FilterCompiler:
 
         def eval_table(cols, params, _key=key, _name=name, _has=has_nulls):
             codes = cols[_name]["codes"].astype(jnp.int32)
-            t = params[_key][codes]
+            t = code_lookup(params[_key], codes)
             nulls = cols[_name].get("nulls") if _has else None
             if nulls is not None:
                 t = t & ~nulls
@@ -528,7 +529,7 @@ class FilterCompiler:
 
             def eval_table(cols, params, _key=key, _name=name, _has=has_nulls):
                 codes = cols[_name]["codes"].astype(jnp.int32)
-                t = params[_key][codes]
+                t = code_lookup(params[_key], codes)
                 if t.ndim == 2:
                     t = jnp.any(t, axis=1)
                 nulls = cols[_name].get("nulls") if _has else None

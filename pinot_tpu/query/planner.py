@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pinot_tpu import ops
+from pinot_tpu.ops.code_lookup import lookup_tally
 from pinot_tpu.query.filter import _DICT_RESOLVED, FilterCompiler, dict_predicate_codes, sorted_doc_range
 from pinot_tpu.query.functions import (
     FIELD_COMBINE,
@@ -196,6 +197,11 @@ class SegmentPlan:
     # kernel was compiled for the table's shape, not the segment's (the
     # `shape` attr of the launch_plan span)
     table_shaped: bool = False
+    # form -> the table-by-code lookups of this plan's program compiled in
+    # that form (ops/code_lookup.py: "contracted" / "gathered"), written when
+    # the kernel's body is traced, which a program's first call does before
+    # it returns; the plan-cache entry's, shared by reference like launched_on
+    lookups: Dict[str, int] = field(default_factory=dict)
 
 
 # A member's run in a group program's joined column starts on a multiple of
@@ -1910,6 +1916,7 @@ class QueryPlanning:
                     plan.scan_bytes = cached.scan_bytes
                     plan.launched_on = cached.launched_on
                     plan.widened = cached.widened
+                    plan.lookups = cached.lookups
                 else:
                     plan = None
             if plan is not None:
@@ -2240,8 +2247,15 @@ def _build_plan(
     param_layout = params_structure(fc.params)
     dict_kernel = kernel
 
+    lookups: Dict[str, int] = {}
+
     def kernel(cols, packed):
-        return dict_kernel(cols, unpack_params(packed, param_layout))
+        # trace time: the table-by-code lookups of this program, by the form
+        # each was compiled with (ops/code_lookup.py)
+        with lookup_tally() as seen:
+            out = dict_kernel(cols, unpack_params(packed, param_layout))
+        lookups.update(seen)
+        return out
 
     # the jitted program is named by what it is (module `jit_<kind>_<backend>`
     # in a device trace), not `kernel`
@@ -2289,4 +2303,5 @@ def _build_plan(
             else None
         ),
         dict_sizes={name: dict_sizes[name] for name in needed if name in dict_sizes},
+        lookups=lookups,
     )

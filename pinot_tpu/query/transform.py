@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 import numpy as np
 
+from pinot_tpu.ops.code_lookup import code_lookup
 from pinot_tpu.query import scalar
 from pinot_tpu.query.ir import Expr, ExprKind
 from pinot_tpu.segment.segment import ImmutableSegment
@@ -94,7 +95,7 @@ def column_values(name: str, segment: ImmutableSegment, cols: Dict) -> EvalResul
             # segment, so only segment-sized decodes take the barrier.
             if codes.shape[0] * 4 <= _DECODE_BARRIER_MAX_BYTES:
                 codes = jax.lax.optimization_barrier(codes)
-        vals = entry["dict"][codes]
+        vals = code_lookup(entry["dict"], codes)
     nulls = entry.get("nulls")
     return vals, nulls
 
@@ -178,7 +179,7 @@ def eval_expr(expr: Expr, segment: ImmutableSegment, cols: Dict) -> EvalResult:
             )
         derived = scalar.derived_for(expr, c.dictionary)
         entry = cols[col]
-        vals = jnp.asarray(derived)[entry["codes"].astype(jnp.int32)]
+        vals = code_lookup(jnp.asarray(derived), entry["codes"].astype(jnp.int32))
         return vals, entry.get("nulls")
     raise ValueError(f"unsupported transform function {op!r} in {expr}")
 

@@ -135,6 +135,36 @@ def test_pallas_scan_compiles_for_v5e(one_chip, no_compile_cache, name):
         assert f"%kernel_dense_onehot_{name}" in text
 
 
+# table[codes] as a one-hot contraction (ops/code_lookup.py, PR 48) over one
+# served segment whose rows are no whole number of tiles: cell 7's IN table
+# and INT_COL's dictionary (compiled at 8,192 entries), a FLOAT dictionary,
+# and both ends of the contracted range (the longest table's one-hot is met
+# a block of 256 table rows at a time)
+LOOKUP_CASES = {
+    "bool_8192": (jnp.bool_, 8192, "l1_h64"),
+    "int32_8192": (jnp.int32, 8192, "l4_h64"),
+    "float32_8192": (jnp.float32, 8192, "l4_h64"),
+    "int32_min": (jnp.int32, segmented._CONTRACT_MIN_TABLE, "l4_h16"),
+    "int32_max": (jnp.int32, segmented._CONTRACT_MAX_TABLE, f"l4_h{segmented._CONTRACT_MAX_TABLE // 128}"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LOOKUP_CASES))
+def test_code_lookup_compiles_for_v5e(one_chip, no_compile_cache, monkeypatch, name):
+    from pinot_tpu.ops import code_lookup
+
+    dtype, entries, kernel = LOOKUP_CASES[name]
+    monkeypatch.setattr(ops, "scan_backend", lambda: "pallas")
+    rows = 1_500_000
+    compiled = jax.jit(code_lookup.code_lookup).lower(
+        jax.ShapeDtypeStruct((entries,), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert f"%kernel_code_lookup_{kernel}" in text  # under the name a device trace lists
+    assert "kind=kCustom" not in text  # and no gather a row beside it
+
+
 # SSB Q3.2-Q3.4's table (437,500 slots) over one served segment: past the
 # one-hot kernel, so under chunked32 the fused tables are _wide_group_tables'
 WIDE_CASES = {

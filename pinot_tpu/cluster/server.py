@@ -208,9 +208,10 @@ class ServerInstance:
         results, and None at the others'), tableShapedSegments: the segments
         whose plan's kernel was compiled for the dictionary sizes the table's
         segments here share and not their own (`self.shapes`;
-        planner.compiled_dict_sizes), contractedLookups / gatheredLookups:
-        the table-by-code lookups in the launched segments' programs, by the
-        form each was compiled with (ops/code_lookup.py), loopMs: its time
+        planner.compiled_dict_sizes), contractedLookups / gatheredLookups /
+        residentLookups: the table-by-code lookups in the launched segments'
+        programs, by the form each was compiled with (ops/code_lookup.py; a
+        resident one reads the column staging decoded), loopMs: its time
         outside its child
         spans; per segment a
         launch:<segment> span over the executor's
@@ -326,10 +327,12 @@ class ServerInstance:
                         # Columns the device already holds have nothing to
                         # stage: no task, no wake-up of the staging thread
                         # (a table that fits its cache: every segment of
-                        # every query after the first)
+                        # every query after the first).  The flavors asked
+                        # about are the plan's: its decoded columns too
                         nxt, asked = planning.source(scan[k + 1])
                         ahead = asked.needed_columns(nxt)
-                        if not nxt.resident(self.device, ahead, packed_codes=True):
+                        by_value = asked.value_columns(nxt)
+                        if not nxt.resident(self.device, ahead, packed_codes=True, value_columns=by_value):
                             self.residency.submit(
                                 nxt.to_device,
                                 device=self.device,
@@ -337,6 +340,7 @@ class ServerInstance:
                                 packed_codes=True,
                                 residency=self.residency,
                                 prefetch=True,
+                                value_columns=by_value,
                             )
                     # pipelined: a full group dispatches async while the
                     # host plans the next, then drain (executor.QueryLaunches)
@@ -351,6 +355,7 @@ class ServerInstance:
                     tableShapedSegments=launches.table_shaped_segments,
                     contractedLookups=launches.contracted_lookups,
                     gatheredLookups=launches.gathered_lookups,
+                    residentLookups=launches.resident_lookups,
                     docRangeSegments=launches.doc_range_segments,
                     indexServedPredicates=launches.index_served,
                     indexScannedPredicates=launches.index_scanned,
@@ -385,6 +390,7 @@ class ServerInstance:
             self.metrics.counter("server.combinedSegments").inc(launches.combined_segments)
             self.metrics.counter("server.tableShapedSegments").inc(launches.table_shaped_segments)
             self.metrics.counter("server.contractedLookups").inc(launches.contracted_lookups)
+            self.metrics.counter("server.residentLookups").inc(launches.resident_lookups)
             if launches.star_segments:
                 self.metrics.counter("server.starTreeSegments").inc(launches.star_segments)
                 self.metrics.counter("server.starTreeLevelRows").inc(launches.star_level_rows)

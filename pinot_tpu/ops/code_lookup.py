@@ -23,9 +23,22 @@ The contraction's work grows with T (2 x T flop a row a limb), the gather's
 does not, and a gather of up to 64 entries is no gather on the chip (the
 compiler turns it into selects): tables outside segmented._CONTRACT_MIN_TABLE
 .. _CONTRACT_MAX_TABLE, 64-bit tables and a multi-value column's [rows, k]
-codes keep `table[codes]`.  Which form a
-lookup was traced with is tallied (lookup_tally) for the plan that traced
-it: the `dispatch` span's contractedLookups / gatheredLookups.
+codes keep `table[codes]`.
+
+Past _CONTRACT_MAX_TABLE neither form is cheap, and a 32-bit table that long
+needs no index at all: its codes take more than 16 bits, so they ride as
+int32 (segment/packing.py LANE_WIDTHS stop at 16), 4 B a row, and the decoded
+values are exactly as wide.  That is the third form, RESIDENT: staging hands
+the column out decoded, made once a segment and device
+(ImmutableSegment.to_device `value_columns`), and the kernel streams it.
+lookup_form names it; it is staging's to give and the plan's to ask for
+(planner.QueryPlanning.value_columns), so a table of that range that reaches
+code_lookup all the same (the stacked engine's, a dictionary function's
+derived table) is gathered.
+
+Which form a lookup was traced with is tallied (lookup_tally) for the plan
+that traced it: the `dispatch` span's contractedLookups / gatheredLookups /
+residentLookups.
 
 Forms: the Pallas kernel on the chip (scan_backend() "pallas"; "interpret"
 runs it through the interpreter), the plain jnp form elsewhere.
@@ -56,22 +69,30 @@ _CHUNK = {1: 4096, 4: 2048}
 # one-hot is built and met a block at a time
 _K_BLOCK = 256
 
-CONTRACTED, GATHERED = "contracted", "gathered"
+CONTRACTED, GATHERED, RESIDENT = "contracted", "gathered", "resident"
 
 _tally = threading.local()
 
 
 @contextlib.contextmanager
 def lookup_tally() -> Iterator[Dict[str, int]]:
-    """Counts, by form, the code_lookup calls traced on this thread inside
-    the block: what a plan's kernel wraps its body in (trace time only)."""
-    seen = {CONTRACTED: 0, GATHERED: 0}
+    """Counts, by form, the lookups traced on this thread inside the block
+    (code_lookup's calls, and the columns read decoded: tally): what a plan's
+    kernel wraps its body in (trace time only)."""
+    seen = {CONTRACTED: 0, GATHERED: 0, RESIDENT: 0}
     outer = getattr(_tally, "seen", None)
     _tally.seen = seen
     try:
         yield seen
     finally:
         _tally.seen = outer
+
+
+def tally(form: str) -> None:
+    """One lookup of `form` for the tally open on this thread, if any."""
+    seen = getattr(_tally, "seen", None)
+    if seen is not None:
+        seen[form] += 1
 
 
 def _limbs_of(dtype) -> Optional[int]:
@@ -85,21 +106,25 @@ def _limbs_of(dtype) -> Optional[int]:
 
 
 def lookup_form(table_len: int, dtype, codes_ndim: int = 1) -> str:
-    """The form code_lookup gives a table of these static properties."""
-    if codes_ndim != 1 or _limbs_of(dtype) is None or not _CONTRACT_MIN_TABLE <= table_len <= _CONTRACT_MAX_TABLE:
+    """The best form of `table[codes]` for a table of these static
+    properties (its COMPILED length): RESIDENT is the decoded column's,
+    which staging gives where a plan asks; code_lookup has the other two."""
+    limbs = _limbs_of(dtype)
+    if codes_ndim != 1 or limbs is None or table_len < _CONTRACT_MIN_TABLE:
         return GATHERED
-    return CONTRACTED
+    if table_len <= _CONTRACT_MAX_TABLE:
+        return CONTRACTED
+    return RESIDENT if limbs == 4 else GATHERED
 
 
 def code_lookup(table, codes):
     """`table[codes]`, bit for bit, for in-bounds int32 `codes` (a
     dictionary's): contracted where lookup_form says so, gathered else."""
     form = lookup_form(int(table.shape[0]), table.dtype, codes.ndim)
-    seen = getattr(_tally, "seen", None)
-    if seen is not None:
-        seen[form] += 1
-    if form == GATHERED:
+    if form != CONTRACTED:
+        tally(GATHERED)  # a table of RESIDENT's range that was not staged decoded is indexed
         return table[codes]
+    tally(CONTRACTED)
     from pinot_tpu import ops  # the package's name for it: what the planner reads and tests steer
 
     with jax.named_scope("code_lookup"):

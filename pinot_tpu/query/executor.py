@@ -21,7 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from pinot_tpu.ops.code_lookup import CONTRACTED, GATHERED
+from pinot_tpu.ops.code_lookup import CONTRACTED, GATHERED, RESIDENT
 from pinot_tpu.query import planner
 from pinot_tpu.utils.metrics import Trace
 from pinot_tpu.query.functions import combine_field
@@ -149,7 +149,7 @@ def _plan_member(ctx, segment, device, residency, trace, planning=None) -> _Memb
     with trace.span("launch_ship", segment=segment.name, params=len(plan.param_layout)) as ssp:
         cols = table.to_device(
             device=device, columns=plan.needed_columns, packed_codes=True,
-            residency=residency, dict_rows=plan.dict_sizes,
+            residency=residency, dict_rows=plan.dict_sizes, value_columns=plan.value_columns,
         )
         if ssp is not None:
             ssp.annotate(paramArrays=len(plan.params))
@@ -299,6 +299,7 @@ class QueryLaunches:
         self.table_shaped_segments = 0  # segments whose kernel was compiled for the table's shape, not their own
         self.contracted_lookups = 0  # table-by-code lookups of the launched segments' programs read by a one-hot contraction
         self.gathered_lookups = 0  # and by a gather a row (ops/code_lookup.py)
+        self.resident_lookups = 0  # and not at all: the dictionary column came decoded from staging (SegmentPlan.value_columns)
         self.doc_range_segments = 0  # segments whose plan answers a sorted column's predicate with a doc range
         self.index_served = 0  # predicates answered from a range / inverted index's bitmaps
         self.index_scanned = 0  # predicates on a column with such an index that scanned its codes (filter.bitmap_serves)
@@ -376,6 +377,7 @@ class QueryLaunches:
         lookups = members[0].plan.lookups  # the kernel's, traced by now: every member runs it
         self.contracted_lookups += len(group) * lookups.get(CONTRACTED, 0)
         self.gathered_lookups += len(group) * lookups.get(GATHERED, 0)
+        self.resident_lookups += len(group) * lookups.get(RESIDENT, 0)
 
     def outputs(self) -> list:
         """Device outputs of every launched group: what a tracing caller

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pinot_tpu import ops
-from pinot_tpu.ops.code_lookup import lookup_tally
+from pinot_tpu.ops.code_lookup import RESIDENT, lookup_form, lookup_tally
 from pinot_tpu.query.filter import _DICT_RESOLVED, FilterCompiler, dict_predicate_codes, sorted_doc_range
 from pinot_tpu.query.functions import (
     FIELD_COMBINE,
@@ -44,7 +44,7 @@ from pinot_tpu.query.functions import (
 from pinot_tpu.query.ir import AggregationSpec, Expr, FilterOp, PredicateType, QueryContext
 from pinot_tpu.query.shape import column_info_from, params_structure
 from pinot_tpu.query.startree import StarRewrite, pick_level, star_enabled, star_need
-from pinot_tpu.query.transform import as_row_array, eval_expr
+from pinot_tpu.query.transform import as_row_array, eval_expr, value_leaves
 from pinot_tpu.segment.segment import ImmutableSegment
 from pinot_tpu.spi.schema import DataType
 from pinot_tpu.utils.metrics import METRICS
@@ -198,10 +198,18 @@ class SegmentPlan:
     # `shape` attr of the launch_plan span)
     table_shaped: bool = False
     # form -> the table-by-code lookups of this plan's program compiled in
-    # that form (ops/code_lookup.py: "contracted" / "gathered"), written when
-    # the kernel's body is traced, which a program's first call does before
-    # it returns; the plan-cache entry's, shared by reference like launched_on
+    # that form (ops/code_lookup.py: "contracted" / "gathered" / "resident"),
+    # written when the kernel's body is FIRST traced, which a program's first
+    # call does before it returns (a later trace over another staging's pytree
+    # leaves it: the served launch's count stands); the plan-cache entry's,
+    # shared by reference like launched_on
     lookups: Dict[str, int] = field(default_factory=dict)
+    # the dictionary columns the kernel reads BY VALUE where a gather would be
+    # row-priced (code_lookup's RESIDENT form; _value_columns): what the
+    # launch asks staging to hand out decoded beside their codes
+    # (ImmutableSegment.to_device `value_columns`).  The same for every
+    # segment of the plan-cache key: the compiled sizes and the dtypes are in it
+    value_columns: frozenset = frozenset()
 
 
 # A member's run in a group program's joined column starts on a multiple of
@@ -431,6 +439,82 @@ def plan_cache_size() -> int:
 
 def _sig_value(v):
     return v.item() if isinstance(v, np.generic) else v
+
+
+_NO_VALUE_COLUMNS: frozenset = frozenset()
+
+
+def _value_reads(ctx: QueryContext) -> Tuple[Tuple, List[AggregationSpec]]:
+    """What `ctx` alone says of the columns its kernel reads BY VALUE
+    (transform.column_values), as _build_plan's _agg_inputs reaches them,
+    hashable: ((the columns an aggregation's expressions reach through
+    eval_expr's COLUMN case, ...), ((function, column, the options its
+    binding reads), ...) of the aggregations over a bare column whose
+    function agg_input_codes feeds: codes or values, which is its binding's
+    to say a segment); and those aggregations."""
+    leaves: List[str] = []
+    coded: List[AggregationSpec] = []
+    for spec in ctx.aggregations:
+        fn = for_spec(spec)
+        if spec.expr is None or getattr(fn, "mv_input", False):
+            continue
+        exprs = list(spec.extra_exprs) if fn.needs_extra_exprs else []
+        if fn.needs_codes:
+            if spec.expr.is_column:
+                coded.append(spec)
+        elif not (fn.name == "count" and spec.expr.is_column):
+            exprs.append(spec.expr)
+        for e in exprs:
+            leaves.extend(value_leaves(e))
+    if not leaves and not coded:
+        return _NO_VALUE_READS
+
+    def option(key):
+        v = ctx.options.get(key)
+        return tuple(v) if isinstance(v, list) else v
+
+    return (
+        tuple(sorted(set(leaves))),
+        tuple([
+            (spec.function, spec.expr.op, option(f"__range__{spec.expr.op}"), option(f"__dictfp__{spec.expr.op}"))
+            for spec in coded
+        ]),
+    ), coded
+
+
+_NO_VALUE_READS: Tuple[Tuple, List[AggregationSpec]] = (((), ()), [])
+
+
+def _value_columns(ctx: QueryContext, segment, dict_sizes: Dict[str, int], reads: Tuple) -> frozenset:
+    """The columns of `segment` the query's kernel reads by value in
+    code_lookup's RESIDENT form: the rule's (ops/code_lookup.lookup_form) of
+    the dictionary's COMPILED length (`dict_sizes`), its device dtype and the
+    codes' rank; `reads` is _value_reads(ctx)."""
+    (leaves, _), specs = reads
+    out = [name for name in leaves if _resident_form(segment.columns.get(name), dict_sizes)]
+    for spec in specs:
+        name = spec.expr.op
+        if name in out or not _resident_form(segment.columns.get(name), dict_sizes):
+            continue
+        fn = for_spec(spec)
+        if fn.needs_binding:
+            fn = fn.bind_column(column_binding(spec, segment, ctx))
+        if getattr(fn, "input_kind", "codes") != "codes":
+            out.append(name)
+    return frozenset(out) if out else _NO_VALUE_COLUMNS
+
+
+def _resident_form(c, dict_sizes: Dict[str, int]) -> bool:
+    """Whether column `c`'s dictionary, at its compiled length, is one
+    lookup_form would read decoded (1-D codes: a single-value column)."""
+    if c is None or not c.has_dictionary or c.is_multi_value or c.data_type.is_string_like:
+        return False
+    size = dict_sizes.get(c.name, 0)
+    # by its length alone first (the widest dtype the form takes): nearly every
+    # dictionary stops here, before its values are touched for their dtype
+    if lookup_form(size, np.int32) != RESIDENT:
+        return False
+    return lookup_form(size, c.dictionary.device_values().dtype) == RESIDENT
 
 
 def compiled_dict_sizes(segment, needed: List[str], exact_cols: frozenset, shape) -> Dict[str, int]:
@@ -1458,11 +1542,12 @@ class _SegmentMemo:
         self.state = state
         # (predicate columns, needed columns, bound columns, value-hashed
         # columns, group columns,
-        # the table shape asked and its version) ->
+        # the table shape asked and its version, the by-value reads:
+        # _value_reads' key) ->
         # (the predicate columns' ColumnShapes, _segment_signature, the
         # predicate columns' _dictionary_identity, compiled_dict_sizes,
         # whether one of those passes the segment's own dictionary, the
-        # group columns' of those sizes)
+        # group columns' of those sizes, value_columns)
         self.halves: Dict[Tuple, Tuple] = {}
         # (GROUP BY fingerprint, null handling, the group columns' compiled
         # sizes) -> [GroupDim]: the decode's view of this segment's dictionaries
@@ -1641,6 +1726,13 @@ class QueryPlanning:
         self.predicate_cols = tuple(p.lhs.op if p.lhs.is_column else None for p in self.predicates)
         self._group_by = ("|".join(g.fingerprint() for g in ctx.group_by), ctx.null_handling)
         self._referenced = _referenced_columns(ctx)
+        self._value_reads = _value_reads(ctx)
+        # the columns those reads name, and whether one of them can be compiled
+        # in the RESIDENT form on this table: value_columns' short way out
+        (leaves, coded), _ = self._value_reads
+        self._value_names = tuple(set(leaves) | {key[1] for key in coded})
+        self._may_be_resident = False
+        self._resident_under: Optional[Tuple] = None  # the table shape and version it was decided under
         self._needed: Optional[List[str]] = None  # where no segment changes it
         self._half_key: Optional[Tuple] = None
         self._half_under: Optional[Tuple] = None  # the table shape and version _half_key was made under
@@ -1817,16 +1909,45 @@ class QueryPlanning:
         once."""
         return self._key(segment, self.needed_columns(segment), _segment_memo(segment))[0]
 
-    def _key(self, segment, needed: List[str], memo: _SegmentMemo) -> Tuple[Tuple, Tuple, Dict[str, int], bool, Tuple]:
+    def value_columns(self, segment) -> frozenset:
+        """The plan's `value_columns` for `segment` before there is a plan:
+        what a staging ahead of need (the server's look-ahead) passes
+        to_device / resident beside needed_columns.  Nothing is asked of the
+        segment where no dictionary the query reads by value is compiled past
+        the contraction's range on this table (decided once a query and
+        version of the table's shape); else its memo holds the answer."""
+        names = self._value_names
+        if not names:
+            return _NO_VALUE_COLUMNS
+        shape = self.shape
+        if shape is None or getattr(segment, "level_rows", None) is not None:  # its own sizes: compiled_dict_sizes
+            longest = max((c.cardinality for c in map(segment.columns.get, names) if c is not None), default=0)
+            may = lookup_form(longest, np.int32) == RESIDENT
+        else:
+            under = (id(shape), shape.version)
+            if under != self._resident_under:
+                self._may_be_resident = lookup_form(shape.longest(names), np.int32) == RESIDENT
+                self._resident_under = under
+            may = self._may_be_resident
+        if not may:
+            return _NO_VALUE_COLUMNS
+        return self._key(segment, self.needed_columns(segment), _segment_memo(segment))[5]
+
+    def _key(
+        self, segment, needed: List[str], memo: _SegmentMemo
+    ) -> Tuple[Tuple, Tuple, Dict[str, int], bool, Tuple, frozenset]:
         """(the key, the predicate columns' _dictionary_identity, the
         dictionary sizes the key's kernel is compiled for, whether one of
         them passes this segment's own dictionary, the group columns' of
-        those sizes)."""
+        those sizes, the columns staging hands out decoded: _value_columns)."""
         shape = self.shape
         under = None if shape is None else (id(shape), shape.version)
         half_key = self._half_key if needed is self._needed and under == self._half_under else None
         if half_key is None:
-            half_key = (self.predicate_cols, tuple(needed), self.bound_cols, self.hashed_cols, self.group_cols, under)
+            half_key = (
+                self.predicate_cols, tuple(needed), self.bound_cols, self.hashed_cols, self.group_cols, under,
+                self._value_reads[0],
+            )
             if needed is self._needed:
                 self._half_key, self._half_under = half_key, under
         half = memo.halves.get(half_key)
@@ -1841,16 +1962,17 @@ class QueryPlanning:
                 sizes,
                 any(size > segment.column(name).cardinality for name, size in sizes.items()),
                 tuple([sizes.get(c) for c in self.group_cols]),
+                _value_columns(self.ctx, segment, sizes, self._value_reads),
             )
             if len(memo.halves) >= memo.MAX_ENTRIES:
                 memo.halves.clear()
             memo.halves[half_key] = half
-        shapes, signature, same_dict, sizes, table_shaped, group_sizes = half
+        shapes, signature, same_dict, sizes, table_shaped, group_sizes, by_value = half
         fp = self._shape_fps.get(shapes)
         if fp is None:
             fp = self._shape_fps[shapes] = self.ctx.shape_fingerprint(column_info_from(segment))
         # pallas/xla plans trace different kernels
-        return (fp, signature, ops.scan_backend()), same_dict, sizes, table_shaped, group_sizes
+        return (fp, signature, ops.scan_backend()), same_dict, sizes, table_shaped, group_sizes, by_value
 
     def _bound(
         self, cached: SegmentPlan, segment, same_dict: Tuple, memo: _SegmentMemo, sizes: Dict[str, int],
@@ -1896,7 +2018,7 @@ class QueryPlanning:
             self._checked = True
         needed = self._needed if self._needed is not None else self.needed_columns(segment)
         memo = _segment_memo(segment)
-        key, same_dict, sizes, table_shaped, group_sizes = self._key(segment, needed, memo)
+        key, same_dict, sizes, table_shaped, group_sizes, by_value = self._key(segment, needed, memo)
         cached = _PLAN_CACHE.get(key)
         if cached is not None:
             plan = (
@@ -1911,7 +2033,7 @@ class QueryPlanning:
                 # silently retrace, so it counts (and compiles) as a miss
                 # instead.
                 bind = "rebuild"
-                plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn, dict_sizes=sizes)
+                plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn, dict_sizes=sizes, value_columns=by_value)
                 if plan.param_layout == cached.param_layout:
                     plan.scan_bytes = cached.scan_bytes
                     plan.launched_on = cached.launched_on
@@ -1930,7 +2052,7 @@ class QueryPlanning:
         SSE_AUDIT.record_compile(key[0])
         # a process that has an entry has both counters, moved or not
         METRICS.counter("compile.sse.binds"), METRICS.counter("compile.sse.rebuilds")
-        plan = _build_plan(ctx, segment, needed, compiled_fn=None, dict_sizes=sizes)
+        plan = _build_plan(ctx, segment, needed, compiled_fn=None, dict_sizes=sizes, value_columns=by_value)
         plan.scan_bytes = segment.num_docs * scan_bytes_per_row(
             segment.column(n) for n in plan.needed_columns
         )
@@ -1953,10 +2075,13 @@ def _build_plan(
     needed: List[str],
     compiled_fn: Optional[Callable],
     dict_sizes: Optional[Dict[str, int]] = None,
+    value_columns: frozenset = _NO_VALUE_COLUMNS,
 ) -> SegmentPlan:
     """`dict_sizes` (compiled_dict_sizes): what the kernel bakes of each
     dictionary column's size, the key of the plan cache says the same; None:
-    the segment's own cardinalities."""
+    the segment's own cardinalities.  `value_columns` (_value_columns): the
+    plan's, for the launch to pass to staging; the kernel reads
+    whatever entry it is handed (transform.column_values)."""
     null_handling = ctx.null_handling
     if dict_sizes is None:
         dict_sizes = compiled_dict_sizes(segment, needed, frozenset(), None)
@@ -2254,7 +2379,8 @@ def _build_plan(
         # each was compiled with (ops/code_lookup.py)
         with lookup_tally() as seen:
             out = dict_kernel(cols, unpack_params(packed, param_layout))
-        lookups.update(seen)
+        if not lookups:  # the first trace's, the served launch's: a later trace over another staging's pytree leaves it
+            lookups.update(seen)
         return out
 
     # the jitted program is named by what it is (module `jit_<kind>_<backend>`
@@ -2304,4 +2430,5 @@ def _build_plan(
         ),
         dict_sizes={name: dict_sizes[name] for name in needed if name in dict_sizes},
         lookups=lookups,
+        value_columns=value_columns,
     )

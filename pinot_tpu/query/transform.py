@@ -13,14 +13,14 @@ known null-free).
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 import numpy as np
 
-from pinot_tpu.ops.code_lookup import code_lookup
+from pinot_tpu.ops.code_lookup import RESIDENT, code_lookup, tally
 from pinot_tpu.query import scalar
 from pinot_tpu.query.ir import Expr, ExprKind
 from pinot_tpu.segment.segment import ImmutableSegment
@@ -70,7 +70,9 @@ _DECODE_BARRIER_MAX_BYTES = 1 << 26
 
 def column_values(name: str, segment: ImmutableSegment, cols: Dict) -> EvalResult:
     """Numeric values of a column from the device pytree (dictionary gather
-    for dict-encoded numerics — the ProjectionOperator/DataFetcher analog)."""
+    for dict-encoded numerics — the ProjectionOperator/DataFetcher analog;
+    no gather where staging handed the dictionary column out decoded: the
+    plan's value_columns, code_lookup's RESIDENT form)."""
     c = segment.column(name)
     entry = cols[name]
     if c.data_type.is_string_like:
@@ -80,6 +82,8 @@ def column_values(name: str, segment: ImmutableSegment, cols: Dict) -> EvalResul
         )
     if "values" in entry:
         vals = entry["values"]
+        if c.has_dictionary:
+            tally(RESIDENT)
     else:
         codes = entry["codes"].astype(jnp.int32)
         bits = getattr(c, "code_bits", None)
@@ -100,8 +104,37 @@ def column_values(name: str, segment: ImmutableSegment, cols: Dict) -> EvalResul
     return vals, nulls
 
 
+def value_leaves(expr: Expr) -> List[str]:
+    """The column references of `expr` that eval_expr reads BY VALUE (its
+    COLUMN case: column_values), with repeats, from the expression alone.
+    The cases are eval_expr's, in its order; a call it does not descend into
+    through itself (a CASE, a condition, a dictionary-domain function, an
+    array length: readers of codes, nulls or lengths) gives none, so a
+    column under one is not taken for a by-value read."""
+    if expr.kind is ExprKind.COLUMN:
+        return [expr.op]
+    if expr.kind is ExprKind.LITERAL:
+        return []
+    op, args = expr.op, expr.args
+    if (op in _BINARY and len(args) == 2) or op in ("divide", "div") or (op in _UNARY and len(args) == 1):
+        descend = args
+    elif op == "cast" and len(args) == 2 and args[1].is_literal:
+        descend = args[:1]
+    elif op in ("arraylength", "cardinality", "case") or op.startswith("__"):
+        descend = ()
+    elif (op in ("least", "greatest") and args) or op in scalar.DEVICE_MULTI_FNS:
+        descend = args
+    elif op in scalar.DEVICE_FNS:
+        descend = [a for a in args if not a.is_literal]
+    else:
+        descend = ()
+    return [name for a in descend for name in value_leaves(a)]
+
+
 def eval_expr(expr: Expr, segment: ImmutableSegment, cols: Dict) -> EvalResult:
-    """Trace an expression into jnp ops over the segment's device columns."""
+    """Trace an expression into jnp ops over the segment's device columns
+    (value_leaves above says which columns that reads by value: keep the
+    two in step)."""
     if expr.kind is ExprKind.COLUMN:
         return column_values(expr.op, segment, cols)
     if expr.kind is ExprKind.LITERAL:

@@ -10,7 +10,9 @@ TPU re-design (SURVEY.md section 7 "Segment = pytree of device arrays"):
   * Host side: zero-copy mmaps over columns.bin (store.py).
   * Device side: `to_device()` pins a plain-dict pytree of jnp arrays in HBM —
     {col: {"codes": u8/u16/u32[n]} | {"values": dtype[n]}, plus "dict" for
-    numeric dictionaries and "nulls" for null masks}.  Static facts
+    numeric dictionaries and "nulls" for null masks}; a dictionary column a
+    plan reads by value through a row-priced gather is handed out DECODED as
+    well (`value_columns`: its "values", made once).  Static facts
     (num_docs, cardinalities, stats) stay host-side for pruning and for
     building closed-form predicate constants, so jitted kernels see only
     dense arrays and static shapes.
@@ -221,13 +223,16 @@ class ImmutableSegment:
 
     # -- device residency ----------------------------------------------
     def device_group(self, device=None):
-        """Residency cache-group key: ALL flavors (raw and #packed) of this
-        segment on one device live and die as a unit."""
+        """Residency cache-group key: ALL flavors (raw, #packed and #values)
+        of this segment on one device live and die as a unit."""
         return ("seg", id(self), device)
 
     @staticmethod
-    def _entry_bytes(c: ColumnData, use_packed: bool) -> int:
-        """Host-side estimate of the device bytes one cache entry pins."""
+    def _entry_bytes(c: ColumnData, use_packed: bool, decoded: bool = False) -> int:
+        """Host-side estimate of the device bytes one cache entry pins;
+        `decoded`: the #values entry of a dictionary column (_stage_entry)."""
+        if decoded:
+            return c.codes.shape[0] * c.dictionary.device_values().dtype.itemsize
         n = 0
         if use_packed:
             n += c.packed.nbytes
@@ -242,33 +247,46 @@ class ImmutableSegment:
                 n += arr.nbytes
         return n
 
-    def _plan_missing(self, device, cols, packed_codes):
-        """(missing [(cname, key, use_packed)], bytes) the cache lacks."""
+    def _plan_missing(self, device, cols, packed_codes, value_columns=None):
+        """(missing [(cname, key, use_packed, decoded)], bytes) the cache
+        lacks.  `value_columns` (to_device): a column of it has a #values
+        entry beside its code entry."""
         need = []
         nbytes = 0
         with self._device_lock:
             cache = self._device_cache.get(device, {})
             for cname in cols:
                 c = self.columns[cname]
+                if value_columns and cname in value_columns:
+                    key = f"{cname}#values"
+                    if key not in cache:
+                        need.append((cname, key, False, True))
+                        nbytes += self._entry_bytes(c, False, decoded=True)
                 use_packed = bool(packed_codes and c.packed is not None)
                 key = f"{cname}#packed" if use_packed else cname
                 if key in cache:
                     continue
-                need.append((cname, key, use_packed))
+                need.append((cname, key, use_packed, False))
                 nbytes += self._entry_bytes(c, use_packed)
         return need, nbytes
 
-    def resident(self, device, columns: List[str], packed_codes: bool = False) -> bool:
+    def resident(self, device, columns: List[str], packed_codes: bool = False, value_columns=None) -> bool:
         """Whether every entry to_device would hand out for `columns` is in
         the device cache now: there is nothing to stage ahead of need."""
-        return not self._plan_missing(device, columns, packed_codes)[0]
+        return not self._plan_missing(device, columns, packed_codes, value_columns)[0]
 
-    def _stage_entry(self, c: ColumnData, use_packed: bool, device) -> Dict[str, Any]:
+    def _stage_entry(self, c: ColumnData, use_packed: bool, device, decoded: bool = False) -> Dict[str, Any]:
         """One column's host->device copy (NO locks held — this runs on the
-        staging stream or a staging owner, never under _device_lock)."""
+        staging stream or a staging owner, never under _device_lock).
+        `decoded`: the #values entry of a single-value dictionary column, its
+        values at its rows' codes (a take on the host, once a stage): what a
+        kernel reads where it would have gathered."""
         import jax
 
         entry: Dict[str, Any] = {}
+        if decoded:
+            entry["values"] = jax.device_put(c.dictionary.device_values()[np.asarray(c.codes)], device)
+            return entry
         if use_packed:
             entry["codes_packed"] = jax.device_put(np.asarray(c.packed), device)
         elif c.codes is not None:
@@ -285,25 +303,32 @@ class ImmutableSegment:
             entry["lengths"] = jax.device_put(np.asarray(c.mv_lengths), device)
         return entry
 
-    def _assemble(self, device, cols, packed_codes) -> Optional[Dict[str, Any]]:
+    def _assemble(self, device, cols, packed_codes, value_columns=None) -> Optional[Dict[str, Any]]:
         """Read the pytree out of the cache in ONE critical section; None if
         any needed entry vanished (a racing eviction) — the caller re-stages
-        the whole group, so it can never observe a half-evicted segment."""
+        the whole group, so it can never observe a half-evicted segment.  A
+        column of `value_columns` comes out as its code entry joined with
+        its #values entry."""
         with self._device_lock:
             cache = self._device_cache.get(device, {})
             out: Dict[str, Any] = {}
             for cname in cols:
                 c = self.columns[cname]
+                decoded = None
+                if value_columns and cname in value_columns:
+                    decoded = cache.get(f"{cname}#values")
+                    if decoded is None:
+                        return None
                 use_packed = bool(packed_codes and c.packed is not None)
                 key = f"{cname}#packed" if use_packed else cname
                 if key not in cache:
                     return None
-                out[cname] = cache[key]
+                out[cname] = cache[key] if decoded is None else {**cache[key], **decoded}
             return out
 
     def evict_device(self, device=None) -> None:
         """Atomic flavor invalidation: the entire per-device cache region —
-        raw, #packed, dict, null entries together — drops in one critical
+        raw, #packed, #values, dict, null entries together — drops in one critical
         section (residency eviction callback; satellite fix r17)."""
         with self._device_lock:
             self._device_cache.pop(device, None)
@@ -317,8 +342,15 @@ class ImmutableSegment:
         prefetch: bool = False,
         query_id: Optional[str] = None,
         dict_rows: Optional[Dict[str, int]] = None,
+        value_columns=None,
     ) -> Dict[str, Any]:
         """Pin column arrays into device memory; returns the segment pytree.
+
+        `value_columns` (a set of columns, a plan's `value_columns`): each
+        named single-value dictionary column is handed out DECODED as well,
+        "values" as a raw column's beside its codes and dictionary, from a
+        cache entry of its own (`<col>#values`, made once a segment and
+        device, charged and evicted like any flavor).
 
         `dict_rows` ({column: rows}, a plan's `dict_sizes`): the device
         dictionary ("dict") of each named column is handed out with that
@@ -345,7 +377,9 @@ class ImmutableSegment:
         of its star-trees (each a table and a residency group of its own,
         under key "*startree" -> its name); an empty list is no column."""
         if dict_rows:
-            out = self.to_device(device, columns, packed_codes, residency, prefetch, query_id)
+            out = self.to_device(
+                device, columns, packed_codes, residency, prefetch, query_id, value_columns=value_columns
+            )
             return self._with_dict_rows(device, out, dict_rows, packed_codes)
         cols = list(self.columns) if columns is None else columns
         if columns is None and self.indexes.get("startree"):
@@ -359,26 +393,26 @@ class ImmutableSegment:
             # legacy pin-everything path: no budget, no eviction — but the
             # copy still happens with no lock held, and the publish races
             # resolve first-wins through setdefault
-            out: Dict[str, Any] = {}
-            for cname in cols:
-                c = self.columns[cname]
-                use_packed = bool(packed_codes and c.packed is not None)
-                key = f"{cname}#packed" if use_packed else cname
+            while True:
+                missing, _ = self._plan_missing(device, cols, packed_codes, value_columns)
+                staged = {
+                    key: self._stage_entry(self.columns[cname], up, device, decoded)
+                    for cname, key, up, decoded in missing
+                }
                 with self._device_lock:
-                    entry = self._device_cache.setdefault(device, {}).get(key)
-                if entry is None:
-                    entry = self._stage_entry(c, use_packed, device)
-                    with self._device_lock:
-                        entry = self._device_cache.setdefault(device, {}).setdefault(key, entry)
-                out[cname] = entry
-            return out
+                    cache = self._device_cache.setdefault(device, {})
+                    for key, entry in staged.items():
+                        cache.setdefault(key, entry)
+                out = self._assemble(device, cols, packed_codes, value_columns)
+                if out is not None:  # None: released between publish and read
+                    return out
 
         from pinot_tpu.segment import residency as res_mod
         from pinot_tpu.utils.crashpoints import crash_point
 
         group = self.device_group(device)
         while True:
-            missing, _ = self._plan_missing(device, cols, packed_codes)
+            missing, _ = self._plan_missing(device, cols, packed_codes, value_columns)
             st, entry = residency.begin_stage(
                 group, self.table_name, lambda: self.evict_device(device), prefetch=prefetch
             )
@@ -387,7 +421,7 @@ class ImmutableSegment:
                 continue
             if st == res_mod.HIT:
                 if not missing:
-                    out = self._assemble(device, cols, packed_codes)
+                    out = self._assemble(device, cols, packed_codes, value_columns)
                     if out is not None:
                         return out
                     continue  # evicted between plan and read: re-stage
@@ -401,12 +435,12 @@ class ImmutableSegment:
                     continue
             # OWN: charge, copy (no locks held), publish, commit
             try:
-                missing, nbytes = self._plan_missing(device, cols, packed_codes)
+                missing, nbytes = self._plan_missing(device, cols, packed_codes, value_columns)
                 residency.charge(group, nbytes, query_id=query_id)
                 crash_point("segment.stage.after_charge")
                 staged = {
-                    key: self._stage_entry(self.columns[cname], up, device)
-                    for cname, key, up in missing
+                    key: self._stage_entry(self.columns[cname], up, device, decoded)
+                    for cname, key, up, decoded in missing
                 }
                 crash_point("segment.stage.after_copy")
                 with self._device_lock:
@@ -415,7 +449,7 @@ class ImmutableSegment:
                 residency.abort_stage(group)
                 raise
             residency.finish_stage(group)
-            out = self._assemble(device, cols, packed_codes)
+            out = self._assemble(device, cols, packed_codes, value_columns)
             if out is not None:
                 return out
 
@@ -441,13 +475,14 @@ class ImmutableSegment:
             if rows > len(dvals):
                 last = dvals[-1] if len(dvals) else 0
                 dvals = np.concatenate([dvals, np.full(rows - len(dvals), last, dvals.dtype)])
-            entry = dict(entry, dict=jax.device_put(dvals[:rows], device))
+            padded = jax.device_put(dvals[:rows], device)
             key = f"{cname}#packed" if packed_codes and c.packed is not None else cname
             with self._device_lock:
                 cache = self._device_cache.get(device)
                 if cache is not None and key in cache:
-                    cache[key] = entry
-            out[cname] = entry
+                    # the cache's own entry: `entry` may be it joined with the column's #values
+                    cache[key] = dict(cache[key], dict=padded)
+            out[cname] = dict(entry, dict=padded)
         return out
 
     def release_device(self) -> None:

@@ -92,6 +92,13 @@ class TableShape:
         with self._lock:
             self._rebound(self._uncount(name))
 
+    def longest(self, names) -> int:
+        """The largest bound of any lane of the columns `names`: whether a
+        kernel over some segment of the table can be compiled for a
+        dictionary past a given length, without asking a segment."""
+        with self._lock:
+            return max((size for (name, _), size in self._bounds.items() if name in names), default=0)
+
     def bound(self, name: str, c) -> int:
         """The dictionary size a kernel over column `c` (called `name`) of
         one of this table's segments is compiled for; `c`'s own where the

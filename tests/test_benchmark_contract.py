@@ -663,10 +663,18 @@ def test_every_metric_of_the_sketch_cell_has_a_reader_that_returns_a_value(monke
     values, cell, reqs, weights = _toy_window("ssb_sf10_sketch.sketch_closed", 40_000, 43, events=events)
     new = {"sketch_scatter_ms", "sketch_hash_ms", "sketch_roofline", "sketch_table_bytes_per_query", "sketch_final_cpu_ms"}
     assert new | {"launches_per_query", "compiles_in_window", "table_decode_cpu_ms", "combined_segments_per_query",
-                  "tables_decoded_per_query", "warm_up_compiles_per_template", "warm_up_s"} <= set(values)
+                  "tables_decoded_per_query", "warm_up_compiles_per_template", "warm_up_s",
+                  "contracted_lookups_per_query", "resident_lookups_per_query"} <= set(values)
     assert DOOR_SPECS <= set(values)
     assert not [name for name, v in values.items() if v is None], values
     assert values["compiles_in_window"] == values["plan_rebuilds_in_window"] == 0.0
+    # PR 49's metric is on the traced line (its file is a data file beside PR 48's: the same reducer, the next attr
+    # of the same span); a toy table's lo_custkey dictionary is in the contraction's range, so the two HLL templates'
+    # reads of it are contracted here, one a segment, and none is resident: at the timed size it is the other way round
+    assert SPECS["resident_lookups_per_query"]["attr"] == "residentLookups"
+    assert SPECS["resident_lookups_per_query"]["reducer"] == SPECS["contracted_lookups_per_query"]["reducer"]
+    assert values["resident_lookups_per_query"] == 0.0
+    assert values["contracted_lookups_per_query"] == pytest.approx(4 * (len(reqs) - weights["p95_rev_year_nation"]) / len(reqs))
     # a kernel and its combining group program (4 segments: one call of width 4) a template
     assert values["warm_up_compiles_per_template"] == 2.0 and values["launches_per_query"] == 1.0
     assert values["combined_segments_per_query"] == 4.0 and values["tables_decoded_per_query"] == 1.0

@@ -528,3 +528,62 @@ def test_star_tree_level_group_program_compiles_for_v5e(one_chip, no_compile_cac
         planner.plan_cache_clear()
     assert text.count("tpu_custom_call") == 1 and text.count(" while(") == 1
     assert "groupby_dense_pallas_x8_combined" in text and f"[8,{slots}]" not in text
+
+
+@pytest.mark.parametrize("decoded", [True, False], ids=["decoded", "indexed"])
+def test_hll_template_streams_the_decoded_column_for_v5e(one_chip, no_compile_cache, monkeypatch, decoded):
+    """The sketch cell's HLL template over one 1.5M-row segment whose
+    `lo_custkey` dictionary is SF10's (~298,000 of 300,000 keys: past
+    segmented._CONTRACT_MAX_TABLE), for a described v5e.  Staged as the plan
+    asks (PR 49: `value_columns`, the column decoded) the program has no
+    row-length gather and no dictionary operand: the hash streams an
+    s32[1500000] parameter into the registers' scatter.  Staged as the parent
+    staged it (codes and dictionary), the same plan's kernel gathers: the
+    control, and what a caller without the flavour still gets."""
+    from pinot_tpu.ops.code_lookup import GATHERED, RESIDENT
+    from pinot_tpu.query import planner
+    from pinot_tpu.segment.builder import build_segment
+    from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
+    from pinot_tpu.sql.parser import parse_query
+
+    monkeypatch.setattr(ops, "scan_backend", lambda: "pallas")
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    planner.plan_cache_clear()
+
+    rows = 1_500_000
+    rng = np.random.default_rng(49)
+    schema = Schema("lineorder_flat", [
+        FieldSpec("d_year", DataType.INT), FieldSpec("c_nation", DataType.INT), FieldSpec("s_region", DataType.INT),
+        FieldSpec("lo_custkey", DataType.INT), FieldSpec("lo_revenue", DataType.INT, role=FieldRole.METRIC),
+    ])
+    seg = build_segment(schema, {
+        "d_year": rng.integers(1992, 1999, rows).astype(np.int32),
+        "c_nation": rng.integers(0, 25, rows).astype(np.int32),
+        "s_region": rng.integers(0, 5, rows).astype(np.int32),
+        "lo_custkey": rng.integers(1, 300_001, rows).astype(np.int32),
+        "lo_revenue": rng.integers(90_000, 10_000_000, rows).astype(np.int32),
+    }, "seg0")
+    keys = seg.column("lo_custkey").cardinality
+    assert segmented._CONTRACT_MAX_TABLE < keys < 300_000
+    ctx = parse_query("SELECT d_year, c_nation, DISTINCTCOUNTHLL(lo_custkey, 12) FROM lineorder_flat "
+                      "WHERE s_region = 2 GROUP BY d_year, c_nation ORDER BY d_year, c_nation LIMIT 10000")
+    try:
+        plan = planner.plan_segment(ctx, seg)
+        assert plan.kind == "groupby_dense" and plan.value_columns == {"lo_custkey"}
+        cols = seg.to_device(  # on the CPU: shapes only
+            columns=plan.needed_columns, packed_codes=True, value_columns=plan.value_columns if decoded else None)
+        assert sorted(cols["lo_custkey"]) == ["codes", "dict"] + ["values"] * decoded  # the program takes what it reads
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        text = plan.fn.lower(jax.tree_util.tree_map(described, cols), plan.params).compile().as_text()
+        assert plan.lookups[RESIDENT if decoded else GATHERED] == 1
+    finally:
+        planner.plan_cache_clear()
+    gathers = [line for line in text.splitlines() if "value_transform/gather" in line or " gather(" in line]
+    assert bool(gathers) == (not decoded), gathers[:2]
+    assert (f"s32[{keys}]" in text) == (not decoded)  # the dictionary operand
+    # the registers' scatter-max is there either way, fed by the hash of a row-length int32
+    assert "sketch_scatter" in text and f"s32[{rows}]" in text and "s32[716800]" in text

@@ -73,8 +73,11 @@ def test_equals_the_gather_bit_for_bit(form, dtype, length):
     assert got.dtype == table.dtype
     assert np.array_equal(_bits(got), _bits(table[codes]))
     contracted = _CONTRACT_MIN_TABLE <= length <= _CONTRACT_MAX_TABLE
-    assert cl.lookup_form(length, table.dtype) == (cl.CONTRACTED if contracted else cl.GATHERED)
-    assert seen == {cl.CONTRACTED: int(contracted), cl.GATHERED: int(not contracted)}
+    # past the range a 32-bit table's best form is the decoded column, which is staging's to give
+    # (PR 49): one that reaches the helper all the same is gathered, and counted so
+    beyond = cl.GATHERED if dtype == "bool" or length < _CONTRACT_MIN_TABLE else cl.RESIDENT
+    assert cl.lookup_form(length, table.dtype) == (cl.CONTRACTED if contracted else beyond)
+    assert seen == {cl.CONTRACTED: int(contracted), cl.GATHERED: int(not contracted), cl.RESIDENT: 0}
 
 
 @pytest.mark.parametrize("bits", [4, 8, 16])
@@ -103,6 +106,9 @@ def test_codes_from_packed_lanes(form, dtype, bits):
     (np.zeros(100, np.float64), 1, "a 64-bit table"),
     (np.zeros(100, bool), 2, "a multi-value column's [rows, k] codes"),
     (np.zeros(11, np.int32), 1, "a table the chip's compiler turns into selects (SSB's lo_discount)"),
+    (np.zeros(_CONTRACT_MAX_TABLE + 1, bool), 1, "a bool table past the range: no column of its width to stage"),
+    (np.zeros(_CONTRACT_MAX_TABLE + 1, np.int64), 1, "a 64-bit table past the range: 8 B a row decoded"),
+    (np.zeros(_CONTRACT_MAX_TABLE + 1, np.int32), 2, "a multi-value column's codes over a long 32-bit table"),
 ])
 def test_what_keeps_the_gather(table, codes_ndim, why):
     assert cl.lookup_form(len(table), table.dtype, codes_ndim) == cl.GATHERED, why
@@ -118,6 +124,24 @@ def test_a_tally_counts_its_own_block_alone():
         with cl.lookup_tally() as inner:
             cl.code_lookup(jnp.zeros(100, jnp.int64), codes)
         cl.code_lookup(table, codes)
-    assert inner == {cl.CONTRACTED: 0, cl.GATHERED: 1}
-    assert outer == {cl.CONTRACTED: 2, cl.GATHERED: 0}
+        cl.tally(cl.RESIDENT)  # what transform.column_values says of a column it took decoded
+    assert inner == {cl.CONTRACTED: 0, cl.GATHERED: 1, cl.RESIDENT: 0}
+    assert outer == {cl.CONTRACTED: 2, cl.GATHERED: 0, cl.RESIDENT: 1}
     cl.code_lookup(table, codes)  # no tally open: counted nowhere, no error
+    cl.tally(cl.RESIDENT)
+
+
+@pytest.mark.parametrize("length,dtype,ndim,form", [
+    (_CONTRACT_MAX_TABLE, np.int32, 1, cl.CONTRACTED),
+    (_CONTRACT_MAX_TABLE + 1, np.int32, 1, cl.RESIDENT),
+    (_CONTRACT_MAX_TABLE + 1, np.float32, 1, cl.RESIDENT),
+    (327_680, np.int32, 1, cl.RESIDENT),  # lo_custkey's compiled length at SF10
+    (_CONTRACT_MAX_TABLE + 1, np.bool_, 1, cl.GATHERED),
+    (_CONTRACT_MAX_TABLE + 1, np.int64, 1, cl.GATHERED),
+    (_CONTRACT_MAX_TABLE + 1, np.float64, 1, cl.GATHERED),
+    (_CONTRACT_MAX_TABLE + 1, np.int32, 2, cl.GATHERED),
+    (_CONTRACT_MIN_TABLE - 1, np.int32, 1, cl.GATHERED),
+    (_CONTRACT_MIN_TABLE, np.float32, 1, cl.CONTRACTED),
+])
+def test_the_rule_is_of_the_length_the_dtype_and_the_rank(length, dtype, ndim, form):
+    assert cl.lookup_form(length, dtype, ndim) == form

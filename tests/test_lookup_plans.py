@@ -4,10 +4,12 @@ tests/test_segments_built_apart.py.
 Three segments built apart (the dictionaries of `d` differ in size and in
 values) behind a broker: a `count_in`-shaped and a `filtered_query`-shaped
 query equal numpy over the rows; their lowered programs hold no gather, a
-query over a dictionary longer than the threshold still does; the plan-cache
+predicate's table longer than the threshold still does; the plan-cache
 key and the kernel are one across the segments; and the `dispatch` span says
-how many lookups were contracted and how many gathered, a multi-value
-column's table predicate among the gathered.
+how many lookups were contracted, how many gathered and how many read a
+column staging handed out decoded (PR 49: a 32-bit dictionary past the
+threshold read by value), a multi-value column's table predicate among the
+gathered.
 """
 import numpy as np
 import pytest
@@ -15,7 +17,7 @@ import pytest
 import jax
 
 from pinot_tpu.cluster import Broker, Coordinator, ServerInstance
-from pinot_tpu.ops.code_lookup import CONTRACTED, GATHERED
+from pinot_tpu.ops.code_lookup import CONTRACTED, GATHERED, RESIDENT
 from pinot_tpu.ops.segmented import _CONTRACT_MAX_TABLE
 from pinot_tpu.query import planner
 from pinot_tpu.segment.builder import build_segment
@@ -35,6 +37,7 @@ COUNT_IN = "SELECT COUNT(*) FROM t WHERE d IN (3, 5, 8, 13, 21, 400, 405, 1000)"
 FILTERED = ("SELECT SUM(d) FILTER(WHERE d > 40 AND d < 5000), MAX(d) FILTER(WHERE d > 40 AND d < 5000) "
             "FROM t WHERE v > 5 AND v < 900")
 OVER_BIG = "SELECT SUM(big) FROM t WHERE big IN (7, 8, 9, 100000) AND v > 5"
+SUM_BIG = "SELECT SUM(big), MAX(big + v) FROM t WHERE v > 5"
 MV_IN = "SELECT COUNT(*) FROM t WHERE tags IN (1, 3)"
 
 
@@ -83,7 +86,9 @@ def _plans(server, sql):
 
 def _lowered(server, sql):
     seg, plan = _plans(server, sql)[0]
-    cols = seg.to_device(columns=plan.needed_columns, packed_codes=True, dict_rows=plan.dict_sizes)
+    cols = seg.to_device(
+        columns=plan.needed_columns, packed_codes=True, dict_rows=plan.dict_sizes, value_columns=plan.value_columns
+    )
     return plan.fn.lower(cols, {k: jax.device_put(v) for k, v in plan.params.items()}).as_text()
 
 
@@ -114,11 +119,50 @@ def test_a_long_dictionary_still_answers_right(table):
     assert want > 0 and _rows(broker.query(OVER_BIG)) == [[want]]
 
 
-@pytest.mark.parametrize("sql,gathers", [(COUNT_IN, False), (FILTERED, False), (OVER_BIG, True), (MV_IN, True)],
-                         ids=["count_in", "filtered", "over_big", "multi_value"])
+@pytest.mark.parametrize("sql,gathers", [
+    (COUNT_IN, 0), (FILTERED, 0),
+    (OVER_BIG, 1),  # the IN's bool table past the threshold; SUM(big) reads the decoded column
+    (SUM_BIG, 0), (MV_IN, 1),
+], ids=["count_in", "filtered", "over_big", "sum_big", "multi_value"])
 def test_which_lowered_programs_hold_a_gather(table, sql, gathers):
     _, server, _ = table
-    assert ("stablehlo.gather" in _lowered(server, sql)) == gathers
+    assert _lowered(server, sql).count('"stablehlo.gather"(') == gathers
+
+
+@pytest.mark.parametrize("sql,want", [
+    (OVER_BIG, {"big"}),  # the predicate reads its codes from the same entry
+    (SUM_BIG, {"big"}),
+    (FILTERED, set()),  # d's dictionary is in the contraction's range
+    ("SELECT COUNT(big) FROM t WHERE v > 5", set()),  # COUNT reads the null mask
+    ("SELECT SUM(v) FROM t WHERE big > 5", set()),  # read by code alone
+], ids=["over_big", "sum_big", "filtered", "count", "predicate_only"])
+def test_the_plan_says_which_columns_it_reads_decoded(table, sql, want):
+    _, server, _ = table
+    planning = planner.QueryPlanning(parse_query(sql), server.shapes["t"])
+    for seg, plan in _plans(server, sql):
+        assert plan.value_columns == want == planning.value_columns(seg)
+        cols = seg.to_device(columns=plan.needed_columns, packed_codes=True, value_columns=plan.value_columns)
+        for name in want:
+            assert {"codes", "dict", "values"} <= set(cols[name])
+
+
+def test_the_look_ahead_asks_no_segment_where_no_dictionary_it_reads_is_long(table, monkeypatch):
+    """QueryPlanning.value_columns decides from the table's shape, once a
+    query: where no dictionary the query reads by value is compiled past the
+    contraction's range, no segment's memo is touched (the hit path of every
+    cell but the sketch's)."""
+    _, server, _ = table
+    shape = server.shapes["t"]
+    assert 0 < shape.longest(("d",)) <= _CONTRACT_MAX_TABLE < shape.longest(("big",)) == shape.longest(("d", "big"))
+    assert shape.longest(("v", "no_such_column")) == 0  # a raw column has no lane
+    segs = list(server.segments["t"].values())
+    for sql, asks in ((FILTERED, False), (SUM_BIG, True), ("SELECT COUNT(*) FROM t WHERE big > 5", False)):
+        planning = planner.QueryPlanning(parse_query(sql), shape)
+        asked = []
+        real = planning._key
+        monkeypatch.setattr(planning, "_key", lambda *a, real=real, asked=asked: asked.append(1) or real(*a))
+        assert all(bool(planning.value_columns(seg)) == asks for seg in segs)
+        assert bool(asked) == asks, sql
 
 
 @pytest.mark.parametrize("sql", [COUNT_IN, FILTERED], ids=["count_in", "filtered"])
@@ -130,20 +174,30 @@ def test_one_program_a_shape_across_the_segments(table, sql):
     assert any(plan.table_shaped for plan in plans)  # compiled for the table's bound, not for a dictionary of their own
 
 
-@pytest.mark.parametrize("sql,contracted,gathered", [
-    (COUNT_IN, 1, 0),  # the IN's bool table
-    (FILTERED, 2, 0),  # SUM's and MAX's read of d's dictionary (one column: XLA merges them)
-    (OVER_BIG, 0, 2),  # a table and a dictionary past the threshold
-    (MV_IN, 0, 1),  # [rows, k] codes keep the gather
-    ("SELECT SUM(v) FROM t WHERE v > 5", 0, 0),  # raw columns: no lookup
-], ids=["count_in", "filtered", "over_big", "multi_value", "raw"])
-def test_the_dispatch_span_counts_the_lookups_by_form(table, sql, contracted, gathered):
+@pytest.mark.parametrize("sql,contracted,gathered,resident", [
+    (COUNT_IN, 1, 0, 0),  # the IN's bool table
+    (FILTERED, 2, 0, 0),  # SUM's and MAX's read of d's dictionary (one column: XLA merges them)
+    (OVER_BIG, 0, 1, 1),  # a bool table past the threshold, and a 32-bit dictionary past it read decoded
+    (SUM_BIG, 0, 0, 2),  # two reads of the decoded column
+    (MV_IN, 0, 1, 0),  # [rows, k] codes keep the gather
+    ("SELECT SUM(v) FROM t WHERE v > 5", 0, 0, 0),  # raw columns: no lookup
+], ids=["count_in", "filtered", "over_big", "sum_big", "multi_value", "raw"])
+def test_the_dispatch_span_counts_the_lookups_by_form(table, sql, contracted, gathered, resident):
     broker, server, _ = table
-    counter = server.metrics.counter("server.contractedLookups")
+    counters = [server.metrics.counter(f"server.{form}Lookups") for form in ("contracted", "resident")]
     for again in range(2):  # the first answer traces the program, the second finds it made
-        before = counter.value
+        before = [c.value for c in counters]
         (dispatch,) = _spans(broker.query("SET trace = true; " + sql).stats.trace, "dispatch")
         attrs = dispatch["attrs"]
-        assert (attrs["contractedLookups"], attrs["gatheredLookups"]) == (SEGMENTS * contracted, SEGMENTS * gathered), again
-        assert counter.value - before == SEGMENTS * contracted
-    assert _plans(server, sql)[0][1].lookups == {CONTRACTED: contracted, GATHERED: gathered}
+        assert (attrs["contractedLookups"], attrs["gatheredLookups"], attrs["residentLookups"]) == (
+            SEGMENTS * contracted, SEGMENTS * gathered, SEGMENTS * resident), again
+        assert [c.value - b for c, b in zip(counters, before)] == [SEGMENTS * contracted, SEGMENTS * resident]
+    assert _plans(server, sql)[0][1].lookups == {CONTRACTED: contracted, GATHERED: gathered, RESIDENT: resident}
+
+
+def test_sums_over_the_decoded_column_equal_numpy(table):
+    broker, _, blocks = table
+    picked = [b["v"] > 5 for b in blocks]
+    want = [sum(int(b["big"][p].astype(np.int64).sum()) for b, p in zip(blocks, picked)),
+            max(int((b["big"][p].astype(np.int64) + b["v"][p]).max()) for b, p in zip(blocks, picked))]
+    assert _rows(broker.query(SUM_BIG)) == [want]

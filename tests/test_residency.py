@@ -301,6 +301,42 @@ class TestAtomicFlavorEviction:
         assert METRICS.counter(f"{res.name}.misses").value - miss0 == 1
 
 
+    @pytest.mark.parametrize("codes_first", [False, True], ids=["both_at_once", "codes_resident_before"])
+    def test_the_decoded_flavour_is_charged_evicted_and_restaged(self, codes_first):
+        """`<col>#values` (PR 49: a dictionary column handed out decoded) is
+        a flavour like #packed: _entry_bytes charges what it pins, the
+        group's eviction drops it with the rest, a re-stage makes it again;
+        it rides in one entry with the column's codes, nulls and dictionary,
+        staged with them or added to a group that held them already."""
+        seg = _segment(n=8192)
+        c = seg.column("g")
+        c.nulls = np.random.default_rng(5).random(8192) < 0.1
+        res = _mgr(10 << 20)
+        want = c.dictionary.device_values()[c.codes]
+        decoded = 8192 * 4
+        assert seg._entry_bytes(c, False, decoded=True) == decoded
+        rest = seg._entry_bytes(seg.column("v"), False) + seg._entry_bytes(c, True)
+        for again in range(2):
+            if codes_first:
+                plain = seg.to_device(columns=["g", "v"], packed_codes=True, residency=res)
+                assert sorted(plain["g"]) == ["codes_packed", "dict", "nulls"] and res.resident_bytes == rest
+                assert seg.resident(None, ["g", "v"], True)
+            assert not seg.resident(None, ["g", "v"], True, {"g"})
+            cols = seg.to_device(columns=["g", "v"], packed_codes=True, residency=res, value_columns=frozenset({"g"}))
+            assert seg.resident(None, ["g", "v"], True, {"g"})
+            assert sorted(cols["g"]) == ["codes_packed", "dict", "nulls", "values"]
+            assert np.array_equal(np.asarray(cols["g"]["values"]), want)
+            assert np.array_equal(np.asarray(cols["g"]["nulls"]), c.nulls)
+            assert sorted(seg._device_cache[None]) == ["g#packed", "g#values", "v"]
+            assert res.resident_bytes == res.budget.in_use == decoded + rest
+            # the code flavour alone is another request: it finds its entry, and leaves #values where it is
+            plain = seg.to_device(columns=["g"], packed_codes=True, residency=res)
+            assert sorted(plain["g"]) == ["codes_packed", "dict", "nulls"]
+            assert res.resident_bytes == decoded + rest
+            res.evict(seg.device_group(None))
+            assert None not in seg._device_cache and res.resident_bytes == res.budget.in_use == 0
+
+
 # ---------------------------------------------------------------------------
 # staged-fetch admission (reserve_or_wait)
 # ---------------------------------------------------------------------------

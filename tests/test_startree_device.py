@@ -289,7 +289,7 @@ def test_levels_of_unequal_rows_ride_one_program_and_one_launch(bench, ssb):
         "launches": 1, "starSegments": SEGMENTS, "combinedSegments": SEGMENTS,  # the levels' tables fold into one on the chip
         "tableShapedSegments": 0,  # a level is bucketed by its own rule, not by the table's shape
         "docRangeSegments": 0, "indexServedPredicates": 0, "indexScannedPredicates": 0,  # nothing sorted, nothing indexed
-        "contractedLookups": 0, "gatheredLookups": 0,  # no table read at a row's code (ops/code_lookup.py)
+        "contractedLookups": 0, "gatheredLookups": 0, "residentLookups": 0,  # no table read at a row's code (ops/code_lookup.py)
     }
     assert stats.trace["attrs"]["docsScanned"] == stats.num_docs_scanned == sum(rows)
     assert all("cpuMs" in n["attrs"] and n["attrs"]["kernelBytes"] > 0 for n in spans["launch"])

@@ -143,7 +143,11 @@ class ServerInstance:
     def add_segment(self, table: str, segment: ImmutableSegment) -> None:
         self.segments.setdefault(table, {})[segment.name] = segment
         # one of this name held before (replaced in place) leaves the shape as this one joins
-        self.shapes.setdefault(table, TableShape()).add(segment)
+        shape = self.shapes.setdefault(table, TableShape())
+        shape.add(segment)
+        # what its resident columns are padded to on this device where no plan says (to_device)
+        segment.table_shapes[self.device] = shape
+        self.metrics.gauge(f"server.rowBuckets.{table}").set(shape.row_buckets())
         with self._width_warm_lock:
             self._width_warmed.clear()  # a new segment may bring a kernel of its own, or fill a wider group
         self._bounds.pop(table, None)
@@ -157,6 +161,8 @@ class ServerInstance:
         self._bounds.pop(table, None)
         if seg is not None:
             self.shapes[table].remove(seg_name)  # add_segment made it
+            seg.table_shapes.pop(self.device, None)
+            self.metrics.gauge(f"server.rowBuckets.{table}").set(self.shapes[table].row_buckets())
             for held in [seg, *seg.star_tables(made_only=True)]:  # its star-tree levels are groups of their own
                 if self.residency is not None:
                     # uncharge the cache budget AND drop the device entry;
@@ -211,7 +217,11 @@ class ServerInstance:
         planner.compiled_dict_sizes), contractedLookups / gatheredLookups /
         residentLookups: the table-by-code lookups in the launched segments'
         programs, by the form each was compiled with (ops/code_lookup.py; a
-        resident one reads the column staging decoded), loopMs: its time
+        resident one reads the column staging decoded), rowBuckets: the
+        distinct row counts the launched segments' kernels were compiled for
+        (planner.compiled_rows: the table's, where its segments hold unequal
+        rows), rowsPadded: those counts less the segments' true rows, summed:
+        the rows scanned and masked, loopMs: its time
         outside its child
         spans; per segment a
         launch:<segment> span over the executor's
@@ -341,6 +351,7 @@ class ServerInstance:
                                 residency=self.residency,
                                 prefetch=True,
                                 value_columns=by_value,
+                                rows=asked.rows(nxt),
                             )
                     # pipelined: a full group dispatches async while the
                     # host plans the next, then drain (executor.QueryLaunches)
@@ -356,6 +367,7 @@ class ServerInstance:
                     contractedLookups=launches.contracted_lookups,
                     gatheredLookups=launches.gathered_lookups,
                     residentLookups=launches.resident_lookups,
+                    rowBuckets=len(launches.row_buckets), rowsPadded=launches.rows_padded,
                     docRangeSegments=launches.doc_range_segments,
                     indexServedPredicates=launches.index_served,
                     indexScannedPredicates=launches.index_scanned,

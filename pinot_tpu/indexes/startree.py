@@ -24,7 +24,7 @@ level as a segment of its own (LevelSegment): its dimension columns carry the
 PARENT segment's dictionaries over the level's codes (so star results and
 raw-scan results from other segments merge in one key space at reduce time),
 its fields are metric columns named `<column>:<kind>` (`*:count`,
-`lo_revenue:sum`), and its rows are padded to a bucket (level_bucket) so that
+`lo_revenue:sum`), and its rows are padded to a bucket (table_shape.row_bucket) so that
 the same level of every segment of a table has ONE shape and shares ONE
 compiled program; the true row count is the plan's bound parameter
 (planner.ROWS_KEY).  The parent's `to_device()` stages its levels with it,
@@ -45,22 +45,14 @@ import numpy as np
 from pinot_tpu.query.functions import get_agg_function
 from pinot_tpu.segment.segment import ColumnData, ImmutableSegment
 from pinot_tpu.segment.stats import ColumnStats
+from pinot_tpu.segment.table_shape import row_bucket
 from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
 
-# A level's rows on the device are padded to a bucket: a power of two from
-# _MIN_BUCKET up to the dense scan kernel's row tile, whole tiles past it
-# (35,000 combinations -> 65,536 rows, 4,375 -> 8,192, one grand total ->
-# 1,024).  The padding is identity rows, masked out by the bound row count.
-_MIN_BUCKET = 1 << 10
-_ROW_TILE = 1 << 15
+# A level's rows on the device are padded to a bucket (table_shape.row_bucket,
+# the rule a table's segments of unequal rows are padded by).  The padding
+# is identity rows, masked out by the bound row count.
 # a dense key space up to this many slots is counted (bincount), not sorted
 _DENSE_KEY_SPACE = 1 << 24
-
-
-def level_bucket(num_rows: int) -> int:
-    if num_rows > _ROW_TILE:
-        return -(-num_rows // _ROW_TILE) * _ROW_TILE
-    return max(_MIN_BUCKET, 1 << max(0, num_rows - 1).bit_length())
 
 
 def field_column(col: str, kind: str) -> str:
@@ -340,7 +332,7 @@ def _envelope(arr: np.ndarray) -> Tuple[int, int]:
 
 
 class LevelSegment(ImmutableSegment):
-    """One star-tree level as a table of its own, padded to level_bucket
+    """One star-tree level as a table of its own, padded to row_bucket
     rows: dimension columns under the parent's dictionaries (or its raw
     ints), one metric column a field (LONG for integer counts and sums,
     DOUBLE for the rest).  The padding rows hold code 0 and the fields'
@@ -350,7 +342,7 @@ class LevelSegment(ImmutableSegment):
 
     def __init__(self, parent: ImmutableSegment, tree: str, level: StarLevel):
         n = level.num_rows
-        bucket = level_bucket(n)
+        bucket = row_bucket(n)
         specs: List[FieldSpec] = []
         columns: Dict[str, ColumnData] = {}
         for name, arr in level.dims.items():
@@ -391,7 +383,7 @@ class LevelSegment(ImmutableSegment):
             columns=columns,
             num_docs=bucket,
         )
-        self.level_rows = n
+        self.level_rows = self.true_rows = n
         self.tree = tree
         self.level = level.k
         self.prefix = "/".join(level.dims) or "*"  # what EXPLAIN's index uses name the level by

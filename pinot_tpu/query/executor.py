@@ -149,7 +149,7 @@ def _plan_member(ctx, segment, device, residency, trace, planning=None) -> _Memb
     with trace.span("launch_ship", segment=segment.name, params=len(plan.param_layout)) as ssp:
         cols = table.to_device(
             device=device, columns=plan.needed_columns, packed_codes=True,
-            residency=residency, dict_rows=plan.dict_sizes, value_columns=plan.value_columns,
+            residency=residency, dict_rows=plan.dict_sizes, value_columns=plan.value_columns, rows=plan.rows,
         )
         if ssp is not None:
             ssp.annotate(paramArrays=len(plan.params))
@@ -300,6 +300,8 @@ class QueryLaunches:
         self.contracted_lookups = 0  # table-by-code lookups of the launched segments' programs read by a one-hot contraction
         self.gathered_lookups = 0  # and by a gather a row (ops/code_lookup.py)
         self.resident_lookups = 0  # and not at all: the dictionary column came decoded from staging (SegmentPlan.value_columns)
+        self.row_buckets: set = set()  # the row counts the launched segments' kernels were compiled for
+        self.rows_padded = 0  # those counts less the segments' true rows, summed: rows scanned and masked
         self.doc_range_segments = 0  # segments whose plan answers a sorted column's predicate with a doc range
         self.index_served = 0  # predicates answered from a range / inverted index's bitmaps
         self.index_scanned = 0  # predicates on a column with such an index that scanned its codes (filter.bitmap_serves)
@@ -322,6 +324,8 @@ class QueryLaunches:
         plan = member.plan
         self.kernel_bytes += plan.scan_bytes
         self.table_shaped_segments += plan.table_shaped
+        self.row_buckets.add(plan.rows)
+        self.rows_padded += plan.rows - planner.true_rows(member.table)
         if plan.index_uses:
             kinds = [kind for _, kind in plan.index_uses]
             self.doc_range_segments += "sorted" in kinds

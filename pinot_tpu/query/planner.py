@@ -157,10 +157,11 @@ class SegmentPlan:
     # inverted index and whose codes the plan scans all the same: the
     # planner's decision from costs (filter.bitmap_serves)
     index_scans: List[Tuple[str, str]] = field(default_factory=list)
-    # bytes the scan must read: the segment's rows x the stored bytes per
-    # row of needed_columns (utils/perf.scan_bytes_per_row), counted once
-    # when the plan-cache entry is built; every launch reports it
+    # bytes the scan must read: the segment's TRUE rows x the stored bytes per
+    # row of needed_columns (utils/perf.scan_bytes_per_row: `scan_row_bytes`,
+    # counted once when the plan-cache entry is built); every launch reports it
     scan_bytes: float = 0.0
+    scan_row_bytes: float = 0.0
     # device -> wall ms of this program's first call there (trace + compile:
     # a jitted program compiles anew for every device it first runs on, and
     # the persistent cache keys an entry by its device too).  The dict is the
@@ -210,6 +211,10 @@ class SegmentPlan:
     # (ImmutableSegment.to_device `value_columns`).  The same for every
     # segment of the plan-cache key: the compiled sizes and the dtypes are in it
     value_columns: frozenset = frozenset()
+    # the rows the kernel was compiled for (compiled_rows), the same for every
+    # segment of the plan-cache key: what the launch asks staging to pad the
+    # segment's resident columns to (ImmutableSegment.to_device `rows`)
+    rows: int = 0
 
 
 # A member's run in a group program's joined column starts on a multiple of
@@ -354,10 +359,13 @@ def grouped_plan(base: SegmentPlan, width: int, combine: bool = False) -> Segmen
 # is the segment's state (bool[num_docs], shared by every member of a batched
 # launch), not a literal of the query.
 VALID_KEY = "__valid__"
-# A star-tree level's table is padded to a bucket of rows (indexes/startree.py
-# LevelSegment) so that the same level of every segment has one shape: the
-# true row count is an int32 parameter of every plan over a level, packed
-# with the query's own, and rows from it on are masked out of every filter.
+# A table whose rows on the device are padded past its true count
+# (`true_rows`: a star-tree level's table, padded to a bucket so that the same
+# level of every segment has one shape, indexes/startree.py LevelSegment; a
+# segment of a table whose segments hold unequal rows, padded to the table's
+# rows, segment/table_shape.py and ImmutableSegment.padded_to): the true row
+# count is an int32 parameter of every plan over it, packed with the query's
+# own, and rows from it on are masked out of every filter.
 ROWS_KEY = "__rows__"
 
 
@@ -533,15 +541,30 @@ def compiled_dict_sizes(segment, needed: List[str], exact_cols: frozenset, shape
     return sizes
 
 
+def compiled_rows(segment, shape) -> int:
+    """The rows a kernel over `segment` is compiled for, and its resident
+    columns hold: what its table's segments share (segment/table_shape.py,
+    `shape`: the server's, None where the caller has none), its own count
+    where they agree or without a shape; a star-tree level's table states its
+    bucket itself."""
+    if shape is None or getattr(segment, "true_rows", None) is not None:
+        return segment.num_docs
+    return shape.rows(segment)
+
+
 def _segment_signature(
     segment: ImmutableSegment, needed: List[str], sketch_cols: frozenset = frozenset(),
     group_cols: frozenset = frozenset(), dict_sizes: Optional[Dict[str, int]] = None,
+    rows: Optional[int] = None,
 ) -> Tuple:
     """`dict_sizes` (compiled_dict_sizes) is what the kernel bakes of each
-    dictionary column's size; None: every column's own cardinality."""
-    sig = [segment.num_docs, segment.valid_docs is not None]
-    if getattr(segment, "level_rows", None) is not None:
-        sig.append(ROWS_KEY)  # a star-tree level: its kernel masks by the bound row count
+    dictionary column's size; None: every column's own cardinality.  `rows`
+    (compiled_rows) is the row count it bakes; None: the segment's own."""
+    if rows is None:
+        rows = segment.num_docs
+    sig = [rows, segment.valid_docs is not None]
+    if getattr(segment, "true_rows", None) is not None or rows != segment.num_docs:
+        sig.append(ROWS_KEY)  # padded rows: the kernel masks by the bound row count
     for name in sorted(needed):
         c = segment.column(name)
         # MV columns: the padded width is a static kernel shape, and the
@@ -1418,8 +1441,8 @@ class ParamRecipe:
     binders: Tuple[Tuple, ...]
     # (dtype, length) of each packed buffer, in pack_params' order
     buffers: Tuple[Tuple[str, int], ...]
-    valid_docs: bool  # VALID_KEY rides beside the buffers
-    level_rows: Optional[int] = None  # where ROWS_KEY sits in the int32 buffer (a star-tree level's plan)
+    valid_docs: Optional[int]  # VALID_KEY rides beside the buffers: the rows it holds (the compiled count)
+    rows_at: Optional[int] = None  # where ROWS_KEY sits in the int32 buffer (a plan over padded rows)
 
 
 def _param_recipe(binders: Optional[List[Tuple]], layout: Tuple) -> Optional[ParamRecipe]:
@@ -1450,7 +1473,8 @@ def _param_recipe(binders: Optional[List[Tuple]], layout: Tuple) -> Optional[Par
     if where:
         return None
     return ParamRecipe(
-        tuple(out), tuple(lengths.items()), any(key == VALID_KEY for key, _, _ in layout),
+        tuple(out), tuple(lengths.items()),
+        next((shape[0] for key, _, shape in layout if key == VALID_KEY), None),
         None if rows_at is None else rows_at[1],
     )
 
@@ -1498,15 +1522,36 @@ def _bind_params(
                 return None
             packed["bool"][table_at : table_at + table.shape[0]] = table
             packed["bool"][table_at + table.shape[0] : table_at + shape[0]] = False
-    if recipe.valid_docs:
+    if recipe.valid_docs is not None:
         if segment.valid_docs is None:
             return None
-        packed[VALID_KEY] = np.asarray(segment.valid_docs, dtype=bool)
-    if recipe.level_rows is not None:
-        if segment.level_rows is None:
-            return None
-        packed["int32"][recipe.level_rows] = segment.level_rows
+        packed[VALID_KEY] = _valid_mask(segment, recipe.valid_docs)
+    if recipe.rows_at is not None:
+        packed["int32"][recipe.rows_at] = true_rows(segment)
     return packed
+
+
+def true_rows(table) -> int:
+    """The rows of `table` that count: all of a segment's, and what a table
+    of padded rows says (a star-tree level's table, a segment's padded view)."""
+    n = table.true_rows
+    return table.num_docs if n is None else n
+
+
+def _row_mask(rows: int, counted):
+    """bool[rows]: the rows before the bound true count `counted`."""
+    return jnp.arange(rows, dtype=jnp.int32) < counted
+
+
+def _valid_mask(segment, rows: int) -> np.ndarray:
+    """`segment`'s validDocIds as the bool[rows] a kernel compiled for `rows`
+    rows takes: a padded row is no valid doc."""
+    valid = np.asarray(segment.valid_docs, dtype=bool)
+    if len(valid) == rows:
+        return valid
+    out = np.zeros(rows, dtype=bool)
+    out[: len(valid)] = valid
+    return out
 
 
 # A dictionary's content hash is read once and kept (Dictionary.fingerprint),
@@ -1547,7 +1592,7 @@ class _SegmentMemo:
         # (the predicate columns' ColumnShapes, _segment_signature, the
         # predicate columns' _dictionary_identity, compiled_dict_sizes,
         # whether one of those passes the segment's own dictionary, the
-        # group columns' of those sizes, value_columns)
+        # group columns' of those sizes, value_columns, compiled_rows)
         self.halves: Dict[Tuple, Tuple] = {}
         # (GROUP BY fingerprint, null handling, the group columns' compiled
         # sizes) -> [GroupDim]: the decode's view of this segment's dictionaries
@@ -1933,13 +1978,19 @@ class QueryPlanning:
             return _NO_VALUE_COLUMNS
         return self._key(segment, self.needed_columns(segment), _segment_memo(segment))[5]
 
+    def rows(self, segment) -> int:
+        """The plan's `rows` for `segment` before there is a plan: what a
+        staging ahead of need passes to_device / resident."""
+        return compiled_rows(segment, self.shape)
+
     def _key(
         self, segment, needed: List[str], memo: _SegmentMemo
-    ) -> Tuple[Tuple, Tuple, Dict[str, int], bool, Tuple, frozenset]:
+    ) -> Tuple[Tuple, Tuple, Dict[str, int], bool, Tuple, frozenset, int]:
         """(the key, the predicate columns' _dictionary_identity, the
         dictionary sizes the key's kernel is compiled for, whether one of
         them passes this segment's own dictionary, the group columns' of
-        those sizes, the columns staging hands out decoded: _value_columns)."""
+        those sizes, the columns staging hands out decoded: _value_columns,
+        the rows the key's kernel is compiled for: compiled_rows)."""
         shape = self.shape
         under = None if shape is None else (id(shape), shape.version)
         half_key = self._half_key if needed is self._needed and under == self._half_under else None
@@ -1955,24 +2006,26 @@ class QueryPlanning:
             info = column_info_from(segment)
             baked = baked_columns(segment, self.bound_cols, self.hashed_cols)
             sizes = compiled_dict_sizes(segment, needed, baked, shape)
+            rows = compiled_rows(segment, shape)
             half = (
                 tuple([info(c) for c in self.predicate_cols if c is not None]),
-                _segment_signature(segment, needed, baked, self.group_cols, sizes),
+                _segment_signature(segment, needed, baked, self.group_cols, sizes, rows),
                 tuple([_dictionary_identity(segment, c) for c in self.predicate_cols]),
                 sizes,
                 any(size > segment.column(name).cardinality for name, size in sizes.items()),
                 tuple([sizes.get(c) for c in self.group_cols]),
                 _value_columns(self.ctx, segment, sizes, self._value_reads),
+                rows,
             )
             if len(memo.halves) >= memo.MAX_ENTRIES:
                 memo.halves.clear()
             memo.halves[half_key] = half
-        shapes, signature, same_dict, sizes, table_shaped, group_sizes, by_value = half
+        shapes, signature, same_dict, sizes, table_shaped, group_sizes, by_value, rows = half
         fp = self._shape_fps.get(shapes)
         if fp is None:
             fp = self._shape_fps[shapes] = self.ctx.shape_fingerprint(column_info_from(segment))
         # pallas/xla plans trace different kernels
-        return (fp, signature, ops.scan_backend()), same_dict, sizes, table_shaped, group_sizes, by_value
+        return (fp, signature, ops.scan_backend()), same_dict, sizes, table_shaped, group_sizes, by_value, rows
 
     def _bound(
         self, cached: SegmentPlan, segment, same_dict: Tuple, memo: _SegmentMemo, sizes: Dict[str, int],
@@ -1991,6 +2044,7 @@ class QueryPlanning:
         plan = object.__new__(SegmentPlan)
         plan.__dict__.update(cached.__dict__)
         plan.params = params
+        plan.scan_bytes = segment.num_docs * cached.scan_row_bytes
         ctx = self.ctx
         if ctx.group_by:
             # the strides are the compiled sizes', the values this segment's dictionaries'
@@ -2018,7 +2072,7 @@ class QueryPlanning:
             self._checked = True
         needed = self._needed if self._needed is not None else self.needed_columns(segment)
         memo = _segment_memo(segment)
-        key, same_dict, sizes, table_shaped, group_sizes, by_value = self._key(segment, needed, memo)
+        key, same_dict, sizes, table_shaped, group_sizes, by_value, rows = self._key(segment, needed, memo)
         cached = _PLAN_CACHE.get(key)
         if cached is not None:
             plan = (
@@ -2033,9 +2087,13 @@ class QueryPlanning:
                 # silently retrace, so it counts (and compiles) as a miss
                 # instead.
                 bind = "rebuild"
-                plan = _build_plan(ctx, segment, needed, compiled_fn=cached.fn, dict_sizes=sizes, value_columns=by_value)
+                plan = _build_plan(
+                    ctx, segment.padded_to(rows), needed, compiled_fn=cached.fn, dict_sizes=sizes,
+                    value_columns=by_value,
+                )
                 if plan.param_layout == cached.param_layout:
-                    plan.scan_bytes = cached.scan_bytes
+                    plan.scan_row_bytes = cached.scan_row_bytes
+                    plan.scan_bytes = segment.num_docs * plan.scan_row_bytes
                     plan.launched_on = cached.launched_on
                     plan.widened = cached.widened
                     plan.lookups = cached.lookups
@@ -2052,10 +2110,11 @@ class QueryPlanning:
         SSE_AUDIT.record_compile(key[0])
         # a process that has an entry has both counters, moved or not
         METRICS.counter("compile.sse.binds"), METRICS.counter("compile.sse.rebuilds")
-        plan = _build_plan(ctx, segment, needed, compiled_fn=None, dict_sizes=sizes, value_columns=by_value)
-        plan.scan_bytes = segment.num_docs * scan_bytes_per_row(
-            segment.column(n) for n in plan.needed_columns
+        plan = _build_plan(
+            ctx, segment.padded_to(rows), needed, compiled_fn=None, dict_sizes=sizes, value_columns=by_value
         )
+        plan.scan_row_bytes = scan_bytes_per_row(segment.column(n) for n in plan.needed_columns)
+        plan.scan_bytes = segment.num_docs * plan.scan_row_bytes
         plan.table_shaped = table_shaped
         plan.cache_key = key
         _PLAN_CACHE.put(key, plan)
@@ -2094,7 +2153,7 @@ def _build_plan(
     # queries apply without recompiling; presence is part of the plan-cache
     # signature (_segment_signature) since the kernel must consume it.
     if segment.valid_docs is not None:
-        fc.params[VALID_KEY] = np.asarray(segment.valid_docs, dtype=bool)
+        fc.params[VALID_KEY] = _valid_mask(segment, segment.num_docs)
         base_filter_fn = filter_fn
 
         def filter_fn(cols, params):
@@ -2102,19 +2161,24 @@ def _build_plan(
             v = params[VALID_KEY]
             return t & v, (nl & v if nl is not None else None)
 
-    # A star-tree level: its table's rows past the true count are padding
-    # (identity rows), masked out of every filter like replaced rows above.
-    level_rows = getattr(segment, "level_rows", None)
-    if level_rows is not None:
-        fc.params[ROWS_KEY] = np.int32(level_rows)
+    # Padded rows (a star-tree level's table, a segment's view at its table's
+    # rows: ImmutableSegment.true_rows): the rows past the true count are
+    # masked out of every filter like replaced rows above.  ONE rule for both.
+    counted_rows = getattr(segment, "true_rows", None)
+    if counted_rows is not None:
+        fc.params[ROWS_KEY] = np.int32(counted_rows)
         whole_table_filter_fn = filter_fn
+        star_level = getattr(segment, "level_rows", None) is not None
 
         def filter_fn(cols, params):
-            # trace time: this plan's program reads a star-tree level (beside
-            # the backend's own scan.traced.* counter)
-            METRICS.counter("scan.traced.startree").inc()
+            # trace time: this plan's program masks by a bound row count, and
+            # reads a star-tree level where it does (beside the backend's own
+            # scan.traced.* counter)
+            METRICS.counter("scan.traced.rowmasked").inc()
+            if star_level:
+                METRICS.counter("scan.traced.startree").inc()
             t, nl = whole_table_filter_fn(cols, params)
-            v = jnp.arange(segment.num_docs, dtype=jnp.int32) < params[ROWS_KEY]
+            v = _row_mask(segment.num_docs, params[ROWS_KEY])
             return t & v, (nl & v if nl is not None else None)
 
     # Device-trace names (HLO op_name metadata only; nothing computes
@@ -2431,4 +2495,5 @@ def _build_plan(
         dict_sizes={name: dict_sizes[name] for name in needed if name in dict_sizes},
         lookups=lookups,
         value_columns=value_columns,
+        rows=segment.num_docs,
     )

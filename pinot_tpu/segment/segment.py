@@ -119,12 +119,41 @@ class ColumnData:
         return self.values
 
 
+# the device cache's key that says what rows its entries are padded to
+# (absent: the segment's own count); no column's entry is keyed so
+_ROWS_TAG = "#rows"
+
+
+def _pad_rows(arr: np.ndarray, rows: int) -> np.ndarray:
+    """`arr` (row-length along its first axis) with `rows` rows: the last
+    row repeated, so that whatever a kernel derives from a padded row it
+    derives from a real one's values (a raw group key stays in its range);
+    zeros for a matrix (a multi-value column's padding codes ride with a
+    length of 0; a vector of zeros has no similarity) and where there is no
+    row to repeat.  Every padded row is masked out of every filter
+    (planner.ROWS_KEY); a null or a length pads to none."""
+    more = rows - arr.shape[0]
+    if more <= 0:
+        return arr
+    if arr.ndim == 1 and arr.shape[0] and arr.dtype != bool and arr.dtype.kind in "iuf":
+        fill = np.full(more, arr[-1], arr.dtype)
+    else:
+        fill = np.zeros((more,) + arr.shape[1:], arr.dtype)
+    return np.concatenate([arr, fill])
+
+
 class ImmutableSegment:
     """Loaded immutable segment with optional device residency."""
 
     # the true row count of a star-tree level's table (indexes/startree.py
     # LevelSegment), whose rows are padded to a bucket; None: every row counts
     level_rows: Optional[int] = None
+    # the rows that count, of a table whose `num_docs` is what its kernel is
+    # compiled for and its resident columns are padded to: a star-tree level's
+    # table (= level_rows) and a segment's view at its table's rows (padded_to);
+    # None: every row of `num_docs` counts.  Every plan over such a table binds
+    # it as a parameter and masks by it (planner.ROWS_KEY)
+    true_rows: Optional[int] = None
 
     def __init__(
         self,
@@ -153,6 +182,11 @@ class ImmutableSegment:
         self.valid_docs: Optional[np.ndarray] = None
         self.sort_order: Optional[np.ndarray] = None
         self._device_cache: Dict[str, Any] = {}
+        # device -> the TableShape of the table this segment is served from
+        # there (ServerInstance.add_segment): the rows its resident columns
+        # are padded to where no caller says (to_device `rows`)
+        self.table_shapes: Dict[Any, Any] = {}
+        self._padded_views: Dict[int, "ImmutableSegment"] = {}
         # the segment's half of its plan-cache keys (query/planner.py
         # _SegmentMemo): column shapes, signatures and group dimensions do
         # not change between queries; thrown away when valid_docs or an
@@ -210,6 +244,33 @@ class ImmutableSegment:
     def column_names(self) -> List[str]:
         return list(self.columns)
 
+    def padded_to(self, rows: int) -> "ImmutableSegment":
+        """This segment as the table a kernel compiled for `rows` rows reads:
+        itself where `rows` is its own count, else a view of it (the same
+        columns, indexes and caches) whose `num_docs` is `rows` and whose
+        `true_rows` is this segment's count, as a star-tree level's table
+        states its bucket and its true rows.  What is traced against the view
+        takes every shape from `rows`; the host arrays keep their true length
+        and staging pads them (to_device `rows`)."""
+        if rows == self.num_docs:
+            return self
+        view = self._padded_views.get(rows)
+        if view is None:
+            import copy
+
+            view = copy.copy(self)
+            view.num_docs, view.true_rows = rows, self.num_docs
+            if len(self._padded_views) >= 4:  # the table's bound moved: the old views go
+                self._padded_views.clear()
+            self._padded_views[rows] = view
+        return view
+
+    def staged_rows(self, device=None) -> int:
+        """The rows this segment's resident columns hold on `device` where no
+        plan says: its table's there (segment/table_shape.py), else its own."""
+        shape = self.table_shapes.get(device)
+        return self.num_docs if shape is None else shape.rows(self)
+
     def star_tables(self, made_only: bool = False) -> List["ImmutableSegment"]:
         """Every level of every star-tree of this segment, as the tables the
         plans read (indexes/startree.py LevelSegment); with `made_only`,
@@ -228,89 +289,138 @@ class ImmutableSegment:
         return ("seg", id(self), device)
 
     @staticmethod
-    def _entry_bytes(c: ColumnData, use_packed: bool, decoded: bool = False) -> int:
+    def _entry_bytes(c: ColumnData, use_packed: bool, decoded: bool = False, rows: Optional[int] = None) -> int:
         """Host-side estimate of the device bytes one cache entry pins;
-        `decoded`: the #values entry of a dictionary column (_stage_entry)."""
+        `decoded`: the #values entry of a dictionary column (_stage_entry);
+        `rows`: what the row-length arrays are padded to (None: as they are)."""
+
+        def padded(arr) -> int:
+            return arr.nbytes if rows is None or not arr.shape[0] else arr.nbytes // arr.shape[0] * rows
+
         if decoded:
-            return c.codes.shape[0] * c.dictionary.device_values().dtype.itemsize
+            return (c.codes.shape[0] if rows is None else rows) * c.dictionary.device_values().dtype.itemsize
         n = 0
         if use_packed:
-            n += c.packed.nbytes
+            n += c.packed.nbytes if rows is None else 4 * packing.packed_words(rows, c.code_bits)
         elif c.codes is not None:
-            n += c.codes.nbytes
+            n += padded(c.codes)
         if c.codes is not None and c.dictionary is not None:
             dvals = c.dictionary.device_values()
             if dvals is not None:
                 n += dvals.nbytes
         for arr in (c.values, c.nulls, c.mv_lengths):
             if arr is not None:
-                n += arr.nbytes
+                n += padded(arr)
         return n
 
-    def _plan_missing(self, device, cols, packed_codes, value_columns=None):
+    def _plan_missing(self, device, cols, packed_codes, value_columns=None, rows: Optional[int] = None):
         """(missing [(cname, key, use_packed, decoded)], bytes) the cache
         lacks.  `value_columns` (to_device): a column of it has a #values
-        entry beside its code entry."""
+        entry beside its code entry.  `rows` (to_device): what the entries'
+        rows are padded to (None: the segment's own count, no pad); None
+        comes back where the cache holds entries padded to ANOTHER count
+        (the table's bound moved: to_device drops them, _drop_stale)."""
         need = []
         nbytes = 0
+        pad = None if rows is None or rows == self.num_docs else rows
         with self._device_lock:
             cache = self._device_cache.get(device, {})
+            if rows is not None and cache.get(_ROWS_TAG, self.num_docs) != rows and cache:
+                return None
             for cname in cols:
                 c = self.columns[cname]
                 if value_columns and cname in value_columns:
                     key = f"{cname}#values"
                     if key not in cache:
                         need.append((cname, key, False, True))
-                        nbytes += self._entry_bytes(c, False, decoded=True)
+                        nbytes += self._entry_bytes(c, False, decoded=True, rows=pad)
                 use_packed = bool(packed_codes and c.packed is not None)
                 key = f"{cname}#packed" if use_packed else cname
                 if key in cache:
                     continue
                 need.append((cname, key, use_packed, False))
-                nbytes += self._entry_bytes(c, use_packed)
+                nbytes += self._entry_bytes(c, use_packed, rows=pad)
         return need, nbytes
 
     def resident(self, device, columns: List[str], packed_codes: bool = False, value_columns=None) -> bool:
         """Whether every entry to_device would hand out for `columns` is in
-        the device cache now: there is nothing to stage ahead of need."""
-        return not self._plan_missing(device, columns, packed_codes, value_columns)[0]
+        the device cache now, at the rows its table states: there is nothing
+        to stage ahead of need."""
+        missing = self._plan_missing(device, columns, packed_codes, value_columns, self.staged_rows(device))
+        return missing is not None and not missing[0]
 
-    def _stage_entry(self, c: ColumnData, use_packed: bool, device, decoded: bool = False) -> Dict[str, Any]:
+    def _drop_stale(self, device, residency=None) -> None:
+        """Drop what the device cache holds at another row count than a
+        launch asks for (the table's bound moved: a segment of more rows
+        joined it): every flavor together, uncharged, to be staged again."""
+        if residency is not None:
+            residency.evict(self.device_group(device))
+        self.evict_device(device)
+
+    def _publish(self, device, staged: Dict[str, Any], rows: int, first_wins: bool) -> None:
+        """`staged` entries into the device cache, which says what rows they
+        are padded to where that is not the segment's own count."""
+        with self._device_lock:
+            cache = self._device_cache.setdefault(device, {})
+            if rows != self.num_docs:
+                cache[_ROWS_TAG] = rows
+            if first_wins:
+                for key, entry in staged.items():
+                    cache.setdefault(key, entry)
+            else:
+                cache.update(staged)
+
+    def _stage_entry(
+        self, c: ColumnData, use_packed: bool, device, decoded: bool = False, rows: Optional[int] = None
+    ) -> Dict[str, Any]:
         """One column's host->device copy (NO locks held — this runs on the
         staging stream or a staging owner, never under _device_lock).
         `decoded`: the #values entry of a single-value dictionary column, its
         values at its rows' codes (a take on the host, once a stage): what a
-        kernel reads where it would have gathered."""
+        kernel reads where it would have gathered.  `rows`: every row-length
+        array is padded to that many rows (_pad_rows; packed words to whole
+        blocks of zeros), the kernel's compiled count; None: as they are."""
         import jax
+
+        def put(arr):
+            arr = np.asarray(arr)
+            return jax.device_put(arr if rows is None else _pad_rows(arr, rows), device)
 
         entry: Dict[str, Any] = {}
         if decoded:
-            entry["values"] = jax.device_put(c.dictionary.device_values()[np.asarray(c.codes)], device)
+            entry["values"] = put(c.dictionary.device_values()[np.asarray(c.codes)])
             return entry
         if use_packed:
-            entry["codes_packed"] = jax.device_put(np.asarray(c.packed), device)
+            words = np.asarray(c.packed)
+            if rows is not None:
+                more = packing.packed_words(rows, c.code_bits) - words.shape[-1]
+                words = np.concatenate([words, np.zeros(words.shape[:-1] + (more,), words.dtype)], axis=-1)
+            entry["codes_packed"] = jax.device_put(words, device)
         elif c.codes is not None:
-            entry["codes"] = jax.device_put(np.asarray(c.codes), device)
+            entry["codes"] = put(c.codes)
         if c.codes is not None:
             dvals = c.dictionary.device_values() if c.dictionary else None
             if dvals is not None:
                 entry["dict"] = jax.device_put(dvals, device)
         if c.values is not None:
-            entry["values"] = jax.device_put(np.asarray(c.values), device)
+            entry["values"] = put(c.values)
         if c.nulls is not None:
-            entry["nulls"] = jax.device_put(np.asarray(c.nulls), device)
+            entry["nulls"] = put(c.nulls)
         if c.mv_lengths is not None:
-            entry["lengths"] = jax.device_put(np.asarray(c.mv_lengths), device)
+            entry["lengths"] = put(c.mv_lengths)
         return entry
 
-    def _assemble(self, device, cols, packed_codes, value_columns=None) -> Optional[Dict[str, Any]]:
+    def _assemble(self, device, cols, packed_codes, value_columns=None, rows: Optional[int] = None) -> Optional[Dict[str, Any]]:
         """Read the pytree out of the cache in ONE critical section; None if
-        any needed entry vanished (a racing eviction) — the caller re-stages
+        any needed entry vanished (a racing eviction) or the cache holds
+        another row count than `rows` — the caller re-stages
         the whole group, so it can never observe a half-evicted segment.  A
         column of `value_columns` comes out as its code entry joined with
         its #values entry."""
         with self._device_lock:
             cache = self._device_cache.get(device, {})
+            if rows is not None and cache.get(_ROWS_TAG, self.num_docs) != rows:
+                return None
             out: Dict[str, Any] = {}
             for cname in cols:
                 c = self.columns[cname]
@@ -343,8 +453,18 @@ class ImmutableSegment:
         query_id: Optional[str] = None,
         dict_rows: Optional[Dict[str, int]] = None,
         value_columns=None,
+        rows: Optional[int] = None,
     ) -> Dict[str, Any]:
         """Pin column arrays into device memory; returns the segment pytree.
+
+        `rows` (a plan's `rows`: what its kernel was compiled for): every
+        row-length array is handed out padded to that many rows (a packed
+        column's words to whole blocks); None: what the segment's table on
+        `device` states (staged_rows: the table's bound where its segments
+        hold unequal rows, segment/table_shape.py), else its own count, no
+        pad.  The padding is made on the host once a stage; the device cache
+        holds ONE row count, and entries at another are dropped and staged
+        again (the table's bound moved).
 
         `value_columns` (a set of columns, a plan's `value_columns`): each
         named single-value dictionary column is handed out DECODED as well,
@@ -378,32 +498,35 @@ class ImmutableSegment:
         under key "*startree" -> its name); an empty list is no column."""
         if dict_rows:
             out = self.to_device(
-                device, columns, packed_codes, residency, prefetch, query_id, value_columns=value_columns
+                device, columns, packed_codes, residency, prefetch, query_id, value_columns=value_columns, rows=rows
             )
             return self._with_dict_rows(device, out, dict_rows, packed_codes)
         cols = list(self.columns) if columns is None else columns
         if columns is None and self.indexes.get("startree"):
-            out = self.to_device(device, cols, packed_codes, residency, prefetch, query_id)
+            out = self.to_device(device, cols, packed_codes, residency, prefetch, query_id, rows=rows)
             out["*startree"] = {
                 t.name: t.to_device(device, None, packed_codes, residency, prefetch, query_id)
                 for t in self.star_tables()
             }
             return out
+        if rows is None:
+            rows = self.staged_rows(device)
+        pad = None if rows == self.num_docs else rows
         if residency is None:
             # legacy pin-everything path: no budget, no eviction — but the
             # copy still happens with no lock held, and the publish races
             # resolve first-wins through setdefault
             while True:
-                missing, _ = self._plan_missing(device, cols, packed_codes, value_columns)
+                missing = self._plan_missing(device, cols, packed_codes, value_columns, rows)
+                if missing is None:
+                    self._drop_stale(device)
+                    continue
                 staged = {
-                    key: self._stage_entry(self.columns[cname], up, device, decoded)
-                    for cname, key, up, decoded in missing
+                    key: self._stage_entry(self.columns[cname], up, device, decoded, pad)
+                    for cname, key, up, decoded in missing[0]
                 }
-                with self._device_lock:
-                    cache = self._device_cache.setdefault(device, {})
-                    for key, entry in staged.items():
-                        cache.setdefault(key, entry)
-                out = self._assemble(device, cols, packed_codes, value_columns)
+                self._publish(device, staged, rows, first_wins=True)
+                out = self._assemble(device, cols, packed_codes, value_columns, rows)
                 if out is not None:  # None: released between publish and read
                     return out
 
@@ -412,7 +535,11 @@ class ImmutableSegment:
 
         group = self.device_group(device)
         while True:
-            missing, _ = self._plan_missing(device, cols, packed_codes, value_columns)
+            missing = self._plan_missing(device, cols, packed_codes, value_columns, rows)
+            if missing is None:
+                self._drop_stale(device, residency)
+                continue
+            missing = missing[0]
             st, entry = residency.begin_stage(
                 group, self.table_name, lambda: self.evict_device(device), prefetch=prefetch
             )
@@ -421,7 +548,7 @@ class ImmutableSegment:
                 continue
             if st == res_mod.HIT:
                 if not missing:
-                    out = self._assemble(device, cols, packed_codes, value_columns)
+                    out = self._assemble(device, cols, packed_codes, value_columns, rows)
                     if out is not None:
                         return out
                     continue  # evicted between plan and read: re-stage
@@ -435,21 +562,24 @@ class ImmutableSegment:
                     continue
             # OWN: charge, copy (no locks held), publish, commit
             try:
-                missing, nbytes = self._plan_missing(device, cols, packed_codes, value_columns)
+                again = self._plan_missing(device, cols, packed_codes, value_columns, rows)
+                if again is None:  # entries at another row count were published meanwhile: start over
+                    residency.abort_stage(group)
+                    continue
+                missing, nbytes = again
                 residency.charge(group, nbytes, query_id=query_id)
                 crash_point("segment.stage.after_charge")
                 staged = {
-                    key: self._stage_entry(self.columns[cname], up, device, decoded)
+                    key: self._stage_entry(self.columns[cname], up, device, decoded, pad)
                     for cname, key, up, decoded in missing
                 }
                 crash_point("segment.stage.after_copy")
-                with self._device_lock:
-                    self._device_cache.setdefault(device, {}).update(staged)
+                self._publish(device, staged, rows, first_wins=False)
             except BaseException:
                 residency.abort_stage(group)
                 raise
             residency.finish_stage(group)
-            out = self._assemble(device, cols, packed_codes, value_columns)
+            out = self._assemble(device, cols, packed_codes, value_columns, rows)
             if out is not None:
                 return out
 

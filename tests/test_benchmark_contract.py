@@ -728,3 +728,82 @@ def test_every_metric_of_the_time_ordered_cell_has_a_reader_that_returns_a_value
     assert 0.0 < values["bydate_roofline"] < 100.0
     # not throughput_qps: the driver holds a new cell's spread to the PARENT's median, a ninth of the change's here (PERF.md section 7)
     assert {m["name"] for m in cell["end_to_end"]} == {"latency_p50_ms", "latency_p95_ms", "setup_s"}
+
+
+def test_every_metric_of_the_month_cut_cell_has_a_reader_that_returns_a_value():
+    """`ssb_sf10_bymonth.dashboard_closed` (PR 50): every per-layer metric `load_cell` gives the cell, the list-less
+    ones too, returns a value over a traced window of its own traffic at toy size (4 segments of 21 whole months each,
+    ~10,000 rows and no two alike, sorted by `lo_orderdate`), and every end-to-end metric; and what the new names say
+    hangs together: ONE compiled row count serves the four segments, every launched segment is padded to it and
+    masked (`scan.traced.rowmasked`), the pruner's count is the calendar's, nothing compiles or plans again in the
+    window, and the roofline's least bytes are the TRUE rows' (lib/monthcount.py)."""
+    import sys
+
+    masked = METRICS.counter("scan.traced.rowmasked").value
+    values, cell, reqs, weights = _toy_window("ssb_sf10_bymonth.dashboard_closed", 40_000, 50)
+    new = {"row_buckets_per_query", "padded_rows_per_query", "bymonth_roofline"}
+    listed = {"compiles_in_window", "warm_up_compiles_per_template", "warm_up_s", "segments_pruned_per_query", "prune_ms",
+              "doc_range_segments_per_query", "launch_param_bytes_per_query", "combined_segments_per_query",
+              "table_shaped_segments_per_query", "tables_decoded_per_query", "tables_merged_by_value_per_query",
+              "table_decode_cpu_ms"}
+    assert new | listed | {"launches_per_query", "plan_rebuilds_in_window"} <= set(values)
+    assert "bydate_roofline" not in values and "scan_roofline" not in values  # their counts are another cut's
+    assert DOOR_SPECS <= set(values)
+    assert not [name for name, v in values.items() if v is None], values
+    assert values["compiles_in_window"] == values["plan_rebuilds_in_window"] == 0.0
+    assert METRICS.counter("scan.traced.rowmasked").value > masked  # the warm-up traced programs that mask by a bound row count
+    bench_dir = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench_dir)
+    try:
+        from lib import monthcount, prunecount
+        from lib.datagen import ssb_flat_bymonth
+
+        cal = ssb_flat_bymonth.calendar()
+        config = dict(cell["config"], rows=40_000, segment_rows=10_000)
+        counts = ssb_flat_bymonth.segment_row_counts(config)
+        assert sum(counts) == 40_000 and len(set(counts)) == 4
+        pruned, padded = [], []
+        for r in reqs:
+            ref = cell["query_set"]["templates"][r.template]["reference"]
+            dated = [dict(ref, where=[t]) for t in ref["where"] if t[0] in cal]
+            shares = [monthcount.segment_shares(config, prunecount.matching_days(one, r.params, cal)) for one in dated]
+            gone = [any(s[i] == 0.0 for s in shares) for i in range(4)]
+            pruned.append(sum(gone))
+            padded.append(sum(16_384 - n for n, out in zip(counts, gone) if not out))
+    finally:
+        sys.path.remove(bench_dir)
+    assert values["row_buckets_per_query"] == 1.0  # four row counts, one program: the table's bound, 16,384 rows
+    assert values["padded_rows_per_query"] == pytest.approx(np.mean(padded))
+    assert values["segments_pruned_per_query"] == pytest.approx(np.mean(pruned)) and np.mean(pruned) > 1.0
+    assert values["launches_per_query"] == 1.0  # whatever survives the pruner rides one call
+    assert values["doc_range_segments_per_query"] > 0.0 and values["prune_ms"] > 0.0
+    assert 0.0 < values["bymonth_roofline"] < 100.0
+    # not throughput_qps: as for the time-ordered cell (PERF.md section 7)
+    assert {m["name"] for m in cell["end_to_end"]} == {"latency_p50_ms", "latency_p95_ms", "setup_s"}
+
+
+def test_the_month_cut_configuration_is_the_time_ordered_one_in_another_cut():
+    def load(name):
+        with open(os.path.join(ROOT, "benchmarks", "configs", f"{name}.json"), encoding="utf-8") as f:
+            return json.load(f)
+
+    month, bydate = load("ssb_flat_sf10_bymonth"), load("ssb_flat_sf10_bydate")
+    changed = {"name", "source", "stands_for", "datagen", "segment_rows", "cut_seed", "columns", "guarantees", "assumed",
+               "memory"}
+    assert {k for k in set(month) | set(bydate) if month.get(k) != bydate.get(k)} == changed
+    assert (month["datagen"], month["rows"], month["segment_rows"], -(-month["rows"] // month["segment_rows"])) == (
+        "ssb_flat_bymonth", 60_000_000, 714_286, 84)
+    # the columns are the sibling's but for what lo_orderdate's says of the cut
+    assert [dict(c, distribution=None) for c in month["columns"]] == [dict(c, distribution=None) for c in bydate["columns"]]
+    assert [c["name"] for c in month["columns"] if c not in bydate["columns"]] == ["lo_orderdate"]
+    assert set(month["guarantees"]) == set(bydate["guarantees"]) | {"rows_free"}
+    assert "numSegmentsQueried = 84" in month["guarantees"]["complete"]
+    assert set(month["assumed"]) == set(bydate["assumed"]) | {"cut_seed"}
+    assert month["assumed"]["order_dates"] == bydate["assumed"]["order_dates"]
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        bench = json.load(f)
+    (entry,) = [c for c in bench["configs"] if c["name"] == "ssb_flat_sf10_bymonth"]
+    assert entry["source"] == month["source"] and entry["reduced"] == month["reduced"] and len(entry["source"]) <= 200
+    (cell,) = [w for w in bench["workloads"] if w["config"] == "ssb_flat_sf10_bymonth"]
+    assert (cell["name"], cell["traffic"], cell["chips"]) == ("ssb_sf10_bymonth.dashboard_closed", "bydate_closed", 1)
+    assert len(bench["workloads"]) == 10 and sum(w["chips"] == 4 for w in bench["workloads"]) == 1

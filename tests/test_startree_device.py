@@ -24,7 +24,8 @@ import pytest
 from pinot_tpu import ops
 from pinot_tpu.cluster import Broker, Coordinator, ServerInstance
 from pinot_tpu.cluster.admission import ResourceBudget
-from pinot_tpu.indexes.startree import StarTreeIndex, level_bucket
+from pinot_tpu.indexes.startree import StarTreeIndex
+from pinot_tpu.segment.table_shape import row_bucket as level_bucket
 from pinot_tpu.ops import segmented
 from pinot_tpu.query import planner
 from pinot_tpu.segment.builder import build_segment
@@ -290,6 +291,7 @@ def test_levels_of_unequal_rows_ride_one_program_and_one_launch(bench, ssb):
         "tableShapedSegments": 0,  # a level is bucketed by its own rule, not by the table's shape
         "docRangeSegments": 0, "indexServedPredicates": 0, "indexScannedPredicates": 0,  # nothing sorted, nothing indexed
         "contractedLookups": 0, "gatheredLookups": 0, "residentLookups": 0,  # no table read at a row's code (ops/code_lookup.py)
+        "rowBuckets": 1, "rowsPadded": sum(16384 - r for r in rows),  # the one rule of padded rows (PR 50): the levels' too
     }
     assert stats.trace["attrs"]["docsScanned"] == stats.num_docs_scanned == sum(rows)
     assert all("cpuMs" in n["attrs"] and n["attrs"]["kernelBytes"] > 0 for n in spans["launch"])

@@ -217,7 +217,9 @@ class ServerInstance:
         planner.compiled_dict_sizes), contractedLookups / gatheredLookups /
         residentLookups: the table-by-code lookups in the launched segments'
         programs, by the form each was compiled with (ops/code_lookup.py; a
-        resident one reads the column staging decoded), rowBuckets: the
+        resident one reads the column staging decoded), compactedScatters: the
+        compactions in those programs, one a filtered mask whose row-priced
+        scatters take the passing rows alone (ops/segmented.py), rowBuckets: the
         distinct row counts the launched segments' kernels were compiled for
         (planner.compiled_rows: the table's, where its segments hold unequal
         rows), rowsPadded: those counts less the segments' true rows, summed:
@@ -367,6 +369,7 @@ class ServerInstance:
                     contractedLookups=launches.contracted_lookups,
                     gatheredLookups=launches.gathered_lookups,
                     residentLookups=launches.resident_lookups,
+                    compactedScatters=launches.compacted_scatters,
                     rowBuckets=len(launches.row_buckets), rowsPadded=launches.rows_padded,
                     docRangeSegments=launches.doc_range_segments,
                     indexServedPredicates=launches.index_served,

@@ -5,6 +5,7 @@ here they are TPU kernels — segmented reductions, bitmap algebra, sketch
 updates — shared by the SSE planner, the distributed engine and the MSE.
 """
 from pinot_tpu.ops.segmented import (  # noqa: F401
+    MaskFacts,
     accum_policy,
     fused_group_tables,
     int_sum_entry,
@@ -22,6 +23,7 @@ from pinot_tpu.ops.segmented import (  # noqa: F401
     masked_min,
     masked_sum,
     masked_sum_sq,
+    mask_facts,
     sketch_count_table,
     sketch_max_table,
     unpack_bitmap_words,

@@ -40,8 +40,10 @@ sized, so the final widening costs nothing.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Tuple
+import threading
+from typing import Iterator, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +129,130 @@ def _scatter_extreme(target, idx_i32, updates, *, is_min: bool):
         indices_are_sorted=False, unique_indices=False,
         mode=lax.GatherScatterMode.FILL_OR_DROP,
     )
+
+
+# ---------------------------------------------------------------------------
+# Compaction: a row-priced scatter paid by the rows that PASS the filter
+# ---------------------------------------------------------------------------
+# The chip prices a 32-bit scatter by the ROW (7-9 ns, 10-14 ms a 1.5M-row
+# segment, whatever the table's size) and gives a row the filter rejected a
+# 0 to add.  A sort is not row-priced, so where a mask carries a predicate
+# the passing rows are sorted first (ONE one-operand int32 sort a mask,
+# rejected rows a sentinel that sorts last) and a loop of ceil(count / C)
+# trips scatters C sorted entries a trip: the passing prefix alone.  What is
+# sorted is what the scatter needs of a row in one word: the PAYLOAD where
+# it packs (a sketch's cell << bits | value, a histogram's cell: the trip
+# unpacks, no gather), else the ROW NUMBER (a wide table's codes and values
+# ride one mask: the trip gathers them at C rows, a gather being row-priced
+# too).  Integer max and integer add are order-free: the tables are the plain
+# scatter's bit for bit at every passing share.
+#
+# Which form a call gets is read from its input alone.  A plan with no
+# predicate says so where its kernel is traced (mask_facts: its masks are
+# padding masks) and compiles no compaction: the plain scatter, its text
+# unchanged.  A plan with one counts its mask on the device and takes the
+# compaction under lax.cond where at most _COMPACT_MAX_SHARE_* of the rows
+# pass, so a filter that passes nearly everything pays one count and the
+# plain scatter.  accum_policy() "wide" (the CPU: native 64-bit scatters, no
+# row price) keeps the plain form.
+#
+# Measured on the chip, kernel alone, one 1.5M-row segment, median of 7
+# calls (PERF.md section 6, PR 51).  A row dropped by an out-of-range index
+# is NOT free: 11.04 ms against the plain scatter's 11.07 at every share.
+# The one-operand int32 sort 1.92 ms (3.09 stable).  PAYLOAD sorted (716,800
+# registers, scatter-max; 358,400 bins, scatter-add, the same to 0.1 ms):
+# 1.7 ms where no row passes, 2.8-2.9 at a share of 0.08, 4.5-4.6 at 0.2,
+# 8.4-8.7 at 0.5, 10.4-10.5 at 0.65, 12.4-12.8 at 0.8 against the plain
+# 11.0: linear, 1.9 + 13.2 x share, crossing at 0.69; the share below keeps
+# a margin.  ROW NUMBERS sorted: a wide table's count and two limb tables
+# (two gathers and three scatters a trip) 2.4-2.5 ms at 1/625, 6.6 at 0.08,
+# 13.0 at 0.2, 28.9 at 0.5 against the plain 31.5 (crossing at 0.54); ONE
+# table behind two gathers (a sketch whose value does not pack) 4.5 at
+# 0.08, 8.7 at 0.2, 14.3 at 0.35 against 11.0, crossing at 0.26: the share
+# below is that narrowest case's, every other mask's tables cross later.
+# The chunk: a trip costs nothing that could be read (183 trips of 2^13 and
+# 46 of 2^15 at a share of 1: 55.5 and 55.4 ms), and a lone trip is paid
+# whole, so a small chunk wins where few rows pass (row numbers at 1/625:
+# 2.3 / 2.4 / 2.8 / 3.9 ms at 2^12 / 2^13 / 2^14 / 2^15; 8.5 at 2^16) and
+# loses nothing where many do (13.3 / 13.0 / 13.2 / 14.3 at 0.2).
+_COMPACT_CHUNK = 1 << 13
+_COMPACT_MAX_SHARE_PAYLOAD = 0.6
+_COMPACT_MAX_SHARE_ROWS = 0.25
+_I32_MAX = np.int32(np.iinfo(np.int32).max)
+
+
+class MaskFacts:
+    """What a plan's kernel says, at trace time, of the masks it hands to
+    the scatters of this module (mask_facts), and what they answer."""
+
+    def __init__(self, filtered: bool):
+        self.filtered = filtered  # a predicate narrowed the masks: more than the padding mask
+        self.compactions = 0  # compactions traced inside the block: one a distinct mask
+
+
+_facts = threading.local()
+
+
+@contextlib.contextmanager
+def mask_facts(filtered: bool) -> Iterator[MaskFacts]:
+    """What a plan's kernel wraps its body in (trace time only): `filtered`
+    is the planner's static fact that the row masks carry a predicate.
+    Outside any block a mask is taken as unfiltered: the plain scatter."""
+    outer = getattr(_facts, "open", None)
+    facts = _facts.open = MaskFacts(filtered)
+    try:
+        yield facts
+    finally:
+        _facts.open = outer
+
+
+def _compacts() -> bool:
+    """Whether the scatter being traced compiles the compaction."""
+    facts = getattr(_facts, "open", None)
+    return facts is not None and facts.filtered and accum_policy() != "wide"
+
+
+def _compact_scatter(mask, keys, sentinel, init, scatter, plain, max_share: float):
+    """The tables of a scatter of the mask-true rows: the compaction
+    described above over `init()` (zeros), or `plain()` (every row
+    scattered, a rejected one adding nothing) where more than `max_share`
+    of the rows pass.
+
+    keys: int32[n], sorted with `sentinel` (past every passing key) in the
+    rejected rows' place.  scatter(tables, part, valid, pos) -> tables puts
+    one chunk of the sorted keys: `part` int32[C], `valid` the entries that
+    are passing rows' and not yet put, `pos` their places in the sorted
+    order (a chunk's index where tables are kept a chunk of rows)."""
+    from pinot_tpu.utils.metrics import METRICS
+
+    facts = getattr(_facts, "open", None)
+    if facts is not None:
+        if not facts.compactions:
+            METRICS.counter("scan.traced.compact_scatter").inc()  # trace time: this plan's program carries the compaction
+        facts.compactions += 1
+    n = mask.shape[0]
+    if n == 0:
+        return plain()
+    chunk = min(_COMPACT_CHUNK, n)
+    count = jnp.sum(mask, dtype=jnp.int32)
+
+    def compacted():
+        with jax.named_scope("compact_sort"):
+            first = lax.sort(jnp.where(mask, keys, sentinel), is_stable=False)
+        lane = lax.iota(jnp.int32, chunk)
+
+        def trip(i, tables):
+            # the last chunk is cut from n - C: its head, put a trip before, drops out
+            begin = i * np.int32(chunk)
+            start = jnp.minimum(begin, np.int32(n - chunk))
+            pos = start + lane
+            part = lax.dynamic_slice_in_dim(first, start, chunk)
+            return scatter(tables, part, (pos >= begin) & (pos < count), pos)
+
+        trips = (count + np.int32(chunk - 1)) // np.int32(chunk)
+        return lax.fori_loop(np.int32(0), trips, trip, init())
+
+    return lax.cond(count <= np.int32(max_share * n), compacted, plain)
 
 
 # ---------------------------------------------------------------------------
@@ -492,8 +618,13 @@ def _fused_group_tables_xla(entries, codes, num_groups: int, mask_words, codes_p
     if mask_words is not None:
         # declined the Pallas path (wide table, float kinds, CPU policy):
         # fall back to one explicit unpack shared by every entry
+        # (once a distinct mask: the entries that shared one still do)
         row_mask = unpack_bitmap_words(mask_words, codes.shape[0])
-        entries = [(k, v, m & row_mask, lp) for k, v, m, lp in entries]
+        anded = {}
+        for _, _, m, _ in entries:
+            if id(m) not in anded:
+                anded[id(m)] = m & row_mask
+        entries = [(k, v, anded[id(m)], lp) for k, v, m, lp in entries]
     if accum_policy() == "wide":
         return _fused_wide_tables(entries, codes, num_groups)
     if num_groups > _MATMUL_MAX_GROUPS:
@@ -697,18 +828,97 @@ def limb_prefix_table(kind, values, mask, limb_plan, lo, hi=None):
     )
 
 
+_LIMB_KINDS = ("count", "int_sum", "int64_sum")
+
+
+def _wide_limb_shifts(kind, limb_plan):
+    """The bit shifts of an integer entry's int32 tables, in _wide_int_limbs'
+    order (a count: its one table): the plan's alone, static."""
+    if kind == "count":
+        return [0]
+    if kind == "int_sum":
+        n_limbs, signed = limb_plan if limb_plan is not None else (4, True)
+        return list(range(0, 8 * n_limbs, _WIDE_LIMB_BITS)) + ([8 * n_limbs] if signed else [])
+    return list(range(0, 8 * (limb_plan if limb_plan is not None else 8), _WIDE_LIMB_BITS))
+
+
+def _compacted_limb_tables(entries, codes, num_groups: int):
+    """{entry index: limb_scatter_table's table, as int64} of the integer
+    entries, by ONE compaction a distinct mask (_compact_scatter, the row
+    numbers sorted): the entries that share a mask (a Q3.x's count and its
+    limb tables all carry the WHERE mask) share its sort and its trips, a
+    trip gathering the codes and each entry's values at C rows.  The int32
+    bound is limb_scatter_table's: a limb table holds at most 2^19 rows'
+    worth, the compacted POSITION taking the row number's place in the chunk
+    index, so the same k tables a limb meet in int64 at table size."""
+    n = codes.shape[0]
+    by_mask = {}
+    for i, (kind, _, mask, _) in enumerate(entries):
+        if kind in _LIMB_KINDS:
+            by_mask.setdefault(id(mask), []).append(i)
+    out = {}
+    for members in by_mask.values():
+        mask = entries[members[0]][2]
+        # every int32 table of the members, in order: (entry, bit shift, rows a chunk)
+        specs = [
+            (i, shift, n if entries[i][0] == "count" else _WIDE_CHUNK)
+            for i in members
+            for shift in _wide_limb_shifts(entries[i][0], entries[i][3])
+        ]
+
+        def columns(passing, take):
+            cols = []
+            for i in members:
+                kind, values, _, limb_plan = entries[i]
+                if kind == "count":
+                    cols.append(passing.astype(jnp.int32))
+                else:
+                    cols.extend(limb for limb, _ in _wide_int_limbs(kind, take(values), passing, limb_plan))
+            return cols
+
+        def plain():
+            return tuple(
+                _chunked_scatter(col, codes, num_groups, per).reshape(-1)
+                for col, (_, _, per) in zip(columns(mask, lambda v: v), specs)
+            )
+
+        def init():
+            return tuple(jnp.zeros((max(1, -(-n // per)) * num_groups,), jnp.int32) for _, _, per in specs)
+
+        def gathered(tables, rows, valid, pos):
+            rows = jnp.minimum(rows, np.int32(n - 1))
+            at = codes[rows]
+            chunked = (pos // np.int32(_WIDE_CHUNK)) * np.int32(num_groups) + at
+            return tuple(
+                _scatter_add(table, jnp.where(valid, at if per >= n else chunked, np.int32(table.shape[0])), col)
+                for table, col, (_, _, per) in zip(tables, columns(valid, lambda v: v[rows]), specs)
+            )
+
+        tables = _compact_scatter(mask, lax.iota(jnp.int32, n), np.int32(n), init, gathered, plain, _COMPACT_MAX_SHARE_ROWS)
+        for (i, shift, _), table in zip(specs, tables):
+            limb = table.reshape(-1, num_groups).astype(jnp.int64).sum(axis=0) << np.int64(shift)
+            out[i] = out[i] + limb if i in out else limb
+    return out
+
+
 def _wide_group_tables(entries, codes, num_groups: int):
     """fused_group_tables for a table past _MATMUL_MAX_GROUPS under
     chunked32: one int32 (or, for floats, f32) scatter a limb column, the
-    form described above.  Returns f64[num_groups] tables in entry order."""
+    form described above; the integer entries of a filtered mask by its
+    compaction.  Float entries keep the plain scatter: moving rows between
+    chunks would change a float sum's last bits.  Returns f64[num_groups]
+    tables in entry order."""
     from pinot_tpu.utils.metrics import METRICS
 
     METRICS.counter("scan.traced.wide_scatter").inc()  # trace time: which form this plan's table got
     codes = _i32(codes)
     out = []
     with jax.named_scope("wide_scatter"):
-        for kind, values, mask, limb_plan in entries:
-            if kind in ("count", "int_sum", "int64_sum"):
+        compacted = _compacted_limb_tables(entries, codes, num_groups) if _compacts() else {}
+        for i, (kind, values, mask, limb_plan) in enumerate(entries):
+            if i in compacted:
+                out.append(compacted[i].astype(jnp.float64))
+            elif kind in _LIMB_KINDS:
                 out.append(limb_scatter_table(kind, values, mask, limb_plan, codes, num_groups).astype(jnp.float64))
             else:
                 v = values.astype(jnp.float32)
@@ -805,15 +1015,44 @@ def _sketch_scatter_traced() -> None:
     METRICS.counter("scan.traced.sketch_scatter").inc()  # trace time: this plan's sketch table took the scatter form
 
 
-def sketch_max_table(values, mask, cells, num_cells: int):
+def sketch_max_table(values, mask, cells, num_cells: int, value_bits=None):
     """int32[num_cells]: the largest of `values` (int32, >= 0: HyperLogLog's
     rho) over the mask-true rows of each cell, 0 where a cell has none.  ONE
     int32 scatter-max under either policy: a register is a small integer and
-    never rides a float."""
+    never rides a float.  `value_bits`: the caller's static bound, values <
+    2^value_bits; where cell << value_bits | value fits an int32 a filtered
+    mask's compaction sorts that payload, else the row numbers."""
     _sketch_scatter_traced()
     with jax.named_scope("sketch_scatter"):
-        v = jnp.where(mask, values.astype(jnp.int32), np.int32(0))
-        return _scatter_extreme(jnp.zeros((num_cells,), jnp.int32), _i32(cells), v, is_min=False)
+
+        def zeros():
+            return jnp.zeros((num_cells,), jnp.int32)
+
+        def plain():
+            v = jnp.where(mask, values.astype(jnp.int32), np.int32(0))
+            return _scatter_extreme(zeros(), _i32(cells), v, is_min=False)
+
+        if not _compacts():
+            return plain()
+        cells = _i32(cells)
+        values = values.astype(jnp.int32)
+        if value_bits is not None and num_cells << value_bits <= _I32_MAX:
+            low = np.int32((1 << value_bits) - 1)
+
+            def unpacked(table, part, valid, pos):
+                at = jnp.where(valid, part >> np.int32(value_bits), np.int32(num_cells))
+                return _scatter_extreme(table, at, part & low, is_min=False)
+
+            payload = (cells << np.int32(value_bits)) | values
+            return _compact_scatter(mask, payload, _I32_MAX, zeros, unpacked, plain, _COMPACT_MAX_SHARE_PAYLOAD)
+        n = mask.shape[0]
+
+        def gathered(table, rows, valid, pos):
+            rows = jnp.minimum(rows, np.int32(n - 1))
+            at = jnp.where(valid, cells[rows], np.int32(num_cells))
+            return _scatter_extreme(table, at, values[rows], is_min=False)
+
+        return _compact_scatter(mask, lax.iota(jnp.int32, n), np.int32(n), zeros, gathered, plain, _COMPACT_MAX_SHARE_ROWS)
 
 
 def sketch_count_table(mask, cells, num_cells: int):
@@ -822,14 +1061,27 @@ def sketch_count_table(mask, cells, num_cells: int):
     (_MATMUL_MAX_GROUPS) is group_count's; past it ONE scatter-add, int32
     under chunked32 (a cell of one call holds at most its rows, far under
     2^31) and widened at table size, so that tables can meet by addition
-    across any number of segments."""
+    across any number of segments.  A filtered mask's compaction sorts the
+    cells themselves."""
     if num_cells <= _MATMUL_MAX_GROUPS:
         return group_count(mask, cells, num_cells)
     _sketch_scatter_traced()
     with jax.named_scope("sketch_scatter"):
         dt = jnp.int64 if accum_policy() == "wide" else jnp.int32
-        table = _scatter_add(jnp.zeros((num_cells,), dt), _i32(cells), mask.astype(dt))
-        return table.astype(jnp.int64)
+
+        def zeros():
+            return jnp.zeros((num_cells,), dt)
+
+        def plain():
+            return _scatter_add(zeros(), _i32(cells), mask.astype(dt))
+
+        if not _compacts():
+            return plain().astype(jnp.int64)
+
+        def counted(table, part, valid, pos):
+            return _scatter_add(table, jnp.where(valid, part, np.int32(num_cells)), jnp.ones(part.shape, dt))
+
+        return _compact_scatter(mask, _i32(cells), _I32_MAX, zeros, counted, plain, _COMPACT_MAX_SHARE_PAYLOAD).astype(jnp.int64)
 
 
 # ---------------------------------------------------------------------------

@@ -300,6 +300,7 @@ class QueryLaunches:
         self.contracted_lookups = 0  # table-by-code lookups of the launched segments' programs read by a one-hot contraction
         self.gathered_lookups = 0  # and by a gather a row (ops/code_lookup.py)
         self.resident_lookups = 0  # and not at all: the dictionary column came decoded from staging (SegmentPlan.value_columns)
+        self.compacted_scatters = 0  # compactions in the launched segments' programs: a filtered mask's passing rows sorted first (ops.mask_facts)
         self.row_buckets: set = set()  # the row counts the launched segments' kernels were compiled for
         self.rows_padded = 0  # those counts less the segments' true rows, summed: rows scanned and masked
         self.doc_range_segments = 0  # segments whose plan answers a sorted column's predicate with a doc range
@@ -382,6 +383,7 @@ class QueryLaunches:
         self.contracted_lookups += len(group) * lookups.get(CONTRACTED, 0)
         self.gathered_lookups += len(group) * lookups.get(GATHERED, 0)
         self.resident_lookups += len(group) * lookups.get(RESIDENT, 0)
+        self.compacted_scatters += len(group) * members[0].plan.mask_facts.compactions
 
     def outputs(self) -> list:
         """Device outputs of every launched group: what a tracing caller

@@ -205,6 +205,12 @@ class SegmentPlan:
     # leaves it: the served launch's count stands); the plan-cache entry's,
     # shared by reference like launched_on
     lookups: Dict[str, int] = field(default_factory=dict)
+    # what the kernel said of its row masks where it was first traced and what
+    # the row-priced scatters answered (ops.mask_facts: `filtered`, the plan's
+    # static fact that a predicate narrows them; `compactions`, the
+    # compactions its program carries, one a distinct mask); the plan-cache
+    # entry's, written and shared like `lookups`
+    mask_facts: ops.MaskFacts = field(default_factory=lambda: ops.MaskFacts(False))
     # the dictionary columns the kernel reads BY VALUE where a gather would be
     # row-priced (code_lookup's RESIDENT form; _value_columns): what the
     # launch asks staging to hand out decoded beside their codes
@@ -2097,6 +2103,7 @@ class QueryPlanning:
                     plan.launched_on = cached.launched_on
                     plan.widened = cached.widened
                     plan.lookups = cached.lookups
+                    plan.mask_facts = cached.mask_facts
                 else:
                     plan = None
             if plan is not None:
@@ -2437,14 +2444,24 @@ def _build_plan(
     dict_kernel = kernel
 
     lookups: Dict[str, int] = {}
+    # A mask that holds more than the padding: a WHERE, an aggregation's
+    # FILTER, replaced rows.  Without one a row-priced scatter keeps every row
+    # and compiles no compaction (ops/segmented.py); the sparse plan's slot
+    # tables are read off its own sort and are not asked.
+    mask_facts = ops.MaskFacts(
+        kind in ("aggregation", "groupby_dense")
+        and (ctx.filter is not None or segment.valid_docs is not None or any(spec.filter is not None for spec in agg_specs))
+    )
 
     def kernel(cols, packed):
         # trace time: the table-by-code lookups of this program, by the form
-        # each was compiled with (ops/code_lookup.py)
-        with lookup_tally() as seen:
+        # each was compiled with (ops/code_lookup.py), and the compactions of
+        # its row-priced scatters
+        with lookup_tally() as seen, ops.mask_facts(mask_facts.filtered) as traced:
             out = dict_kernel(cols, unpack_params(packed, param_layout))
         if not lookups:  # the first trace's, the served launch's: a later trace over another staging's pytree leaves it
             lookups.update(seen)
+            mask_facts.compactions = traced.compactions
         return out
 
     # the jitted program is named by what it is (module `jit_<kind>_<backend>`
@@ -2494,6 +2511,7 @@ def _build_plan(
         ),
         dict_sizes={name: dict_sizes[name] for name in needed if name in dict_sizes},
         lookups=lookups,
+        mask_facts=mask_facts,
         value_columns=value_columns,
         rows=segment.num_docs,
     )

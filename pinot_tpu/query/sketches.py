@@ -393,15 +393,21 @@ class DistinctCountHLLFunction(AggFunction):
             rho = jnp.asarray(self.rho_table)[values_or_codes]
             return bucket, rho
 
+    @property
+    def _rho_bits(self) -> int:
+        """rho < 2^bits, from the hash's width alone: at most its bits past
+        the bucket's, and one (_bucket_rho; _hll_host_tables hashes 64)."""
+        return ((32 if self.device_hash else 64) - self.log2m + 1).bit_length()
+
     def partial(self, codes, mask):
         bucket, rho = self._bucket_rho(codes)
-        return {"hll": ops.sketch_max_table(rho, mask, bucket, self.m)}
+        return {"hll": ops.sketch_max_table(rho, mask, bucket, self.m, value_bits=self._rho_bits)}
 
     def partial_grouped(self, codes, mask, keys, num_groups):
         _check_cell_budget(self.name, num_groups, self.m)
         bucket, rho = self._bucket_rho(codes)
         flat = keys * np.int32(self.m) + bucket
-        regs = ops.sketch_max_table(rho, mask, flat, num_groups * self.m)
+        regs = ops.sketch_max_table(rho, mask, flat, num_groups * self.m, value_bits=self._rho_bits)
         return {"hll": regs.reshape(num_groups, self.m)}
 
     def merge(self, a, b):

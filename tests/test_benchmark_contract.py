@@ -318,7 +318,7 @@ def drill_served():
 
 def test_the_drill_layer_has_its_metrics():
     assert {"wide_table_ms", "sparse_sort_ms", "sparse_roofline", "sparse_table_bytes_per_query",
-            "sparse_decode_ms"} <= set(DRILL_SPECS)
+            "sparse_decode_ms", "compacted_scatters_per_query", "compact_sort_ms"} <= set(DRILL_SPECS)
 
 
 @pytest.mark.parametrize("name", sorted(DRILL_SPECS))
@@ -636,6 +636,8 @@ def test_every_metric_of_the_built_apart_cell_has_a_reader_that_returns_a_value(
     assert 3 * shaped <= values["table_shaped_segments_per_query"] <= 4 * shaped  # the segment that holds a bound may be its own shape
     assert values["tables_decoded_per_query"] == values["tables_merged_by_value_per_query"] == 4.0  # group_low_high's
     assert values["combined_segments_per_query"] == 0.0 and 0.0 < values["ssqe_roofline"] < 100.0
+    # PR 51: the one table past the one-hot kernel's slots is group_low_high's, which has no predicate: no compaction
+    assert values["compacted_scatters_per_query"] == 0.0
     # each op's time over ITS template's traced queries, nothing of the kLoop vector or the other table
     assert values["in_table_gather_ms"] == pytest.approx(300.0 / weights["count_in"])
     assert values["dict_decode_gather_ms"] == pytest.approx(200.0 / weights["filtered_query"])
@@ -657,11 +659,15 @@ def test_every_metric_of_the_sketch_cell_has_a_reader_that_returns_a_value(monke
         "%fusion.5 = s32[358400]{0:T(1024)S(1)} fusion(%fusion.7, %param_0.64, %param_1.63), kind=kCustom, calls=%f": (4, 0.10),
         "%fusion.3 = s32[10000]{0:T(1024)S(1)} fusion(%bitcast.5, %bitcast.4), kind=kLoop, calls=%fused_computation.4": (8, 5.0),
         "%fusion.9 = s32[70001]{0:T(1024)S(1)} fusion(%fusion.1, %broadcast.55, %constant.7), kind=kCustom, calls=%f": (8, 5.0),
+        # PR 51: the compaction's one-operand sort; Q4.3's kind, three operands by two keys, is there to be left out
+        "%sort.1 = s32[10000]{0:T(1024)S(1)} sort(%fusion.11), dimensions={0}, to_apply=%region_5.9, metadata={op_name=": (8, 0.60),
+        "%sort.7 = (s32[10000]{0:T(1024)S(1)}, s32[10000]{0:T(1024)S(1)}, s32[10000]{0:T(1024)S(1)}) sort(%fusion.3, %iota.1": (8, 5.0),
     }
     monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
     monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
     values, cell, reqs, weights = _toy_window("ssb_sf10_sketch.sketch_closed", 40_000, 43, events=events)
-    new = {"sketch_scatter_ms", "sketch_hash_ms", "sketch_roofline", "sketch_table_bytes_per_query", "sketch_final_cpu_ms"}
+    new = {"sketch_scatter_ms", "sketch_hash_ms", "sketch_roofline", "sketch_table_bytes_per_query", "sketch_final_cpu_ms",
+           "compacted_scatters_per_query", "compact_sort_ms"}
     assert new | {"launches_per_query", "compiles_in_window", "table_decode_cpu_ms", "combined_segments_per_query",
                   "tables_decoded_per_query", "warm_up_compiles_per_template", "warm_up_s",
                   "contracted_lookups_per_query", "resident_lookups_per_query"} <= set(values)
@@ -683,7 +689,50 @@ def test_every_metric_of_the_sketch_cell_has_a_reader_that_returns_a_value(monke
     # the scatters over every traced query (all three templates scatter), the gather over the HLL templates' alone
     assert values["sketch_scatter_ms"] == pytest.approx(400.0 / len(reqs))
     assert values["sketch_hash_ms"] == pytest.approx(200.0 / (len(reqs) - weights["p95_rev_year_nation"]))
+    # PR 51: every template has a WHERE and one mask, so each segment's program carries one compaction, and the
+    # one-operand sorts are read over every traced query (`scan.traced.compact_scatter` moved in all three warm-ups)
+    assert values["compacted_scatters_per_query"] == 4.0 and values["compact_sort_ms"] == pytest.approx(600.0 / len(reqs))
     assert {m["name"] for m in cell["end_to_end"]} == {"throughput_qps", "latency_p50_ms", "latency_p95_ms", "setup_s"}
+
+
+@pytest.mark.parametrize("case", ["sort_in_trace", "no_sort_in_trace", "attr_on_span", "no_attr_on_span", "counter_never_moved"])
+def test_the_compactions_two_metrics_read_a_value_or_nothing(case):
+    """PR 51's two data files over reducers the benchmark had: `compact_sort_ms` reads the one-operand int32
+    sorts of a trace over the queries whose warm-up moved `scan.traced.compact_scatter`, and nothing where no such
+    event ran (every mask passed more than the crossover) or no plan carries the compaction (the parent);
+    `compacted_scatters_per_query` the `dispatch` span's attr, and nothing on a program without it."""
+    import sys
+    from types import SimpleNamespace
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        from lib import harness
+    finally:
+        sys.path.remove(os.path.join(ROOT, "benchmarks"))
+    events = {
+        "%sort.4 = s32[1500000]{0:T(1024)S(1)} sort(%broadcast_select_fusion), dimensions={0}, to_apply=%region_6.9": (16, 0.032),
+        "%sort.9 = (s32[1500000]{0:T(1024)S(1)}, s32[1500000]{0:T(1024)S(1)}, s32[1500000]{0:T(1024)S(1)}) sort(%f": (8, 0.017),
+        "%fusion.63 = s32[437500]{0:T(1024)S(1)} fusion(%get-tuple-element.346, %bitcast.37, %fusion.62), kind=kCustom": (16, 0.004),
+    }
+    if case == "no_sort_in_trace":
+        events = {k: v for k, v in events.items() if not k.startswith("%sort.4 ")}
+    moved = {"q3_2": {"scan.traced.compact_scatter": 1.0, "scan.traced.wide_scatter": 1.0},
+             "q3_3": {"scan.traced.compact_scatter": 3.0}, "q4_3": {"scan.traced.sparse_sort": 1.0}}
+    if case == "counter_never_moved":
+        moved = {t: {k: v for k, v in m.items() if "compact" not in k} for t, m in moved.items()}
+    attrs = {"launches": 1, "residentLookups": 0}
+    if case != "no_attr_on_span":
+        attrs["compactedScatters"] = 4
+    tree = {"name": "query", "children": [{"name": "server:s0", "children": [{"name": "dispatch", "attrs": attrs}]}]}
+    ctx = {
+        "requests": [SimpleNamespace(spans=tree), SimpleNamespace(spans=tree), SimpleNamespace(spans=None)],
+        "warm_moved": moved,
+        "device_trace": {"events": events, "template_weights": {"q3_2": 2.0, "q3_3": 2.0, "q4_3": 2.0}},
+    }
+    sort_ms = harness.metric_value("layer_metrics", "compact_sort_ms", ctx)
+    per_query = harness.metric_value("layer_metrics", "compacted_scatters_per_query", ctx)
+    assert sort_ms == (pytest.approx(32.0 / 4.0) if case in ("sort_in_trace", "attr_on_span", "no_attr_on_span") else None)
+    assert per_query == (None if case == "no_attr_on_span" else 4.0)
 
 
 def test_every_metric_of_the_time_ordered_cell_has_a_reader_that_returns_a_value():

@@ -587,3 +587,74 @@ def test_hll_template_streams_the_decoded_column_for_v5e(one_chip, no_compile_ca
     assert (f"s32[{keys}]" in text) == (not decoded)  # the dictionary operand
     # the registers' scatter-max is there either way, fed by the hash of a row-length int32
     assert "sketch_scatter" in text and f"s32[{rows}]" in text and "s32[716800]" in text
+
+
+def _layer_metric(name):
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks", "layer_metrics", name + ".json")) as f:
+        return json.load(f)
+
+
+COMPACTED_CASES = {
+    # (sql, the flat tables its scatters write: a multiple of the template's group space)
+    "hll_cust_year_nation": (
+        "SELECT d_year, c_nation, DISTINCTCOUNTHLL(lo_custkey, 12) FROM lineorder_flat WHERE s_region = 2 "
+        "GROUP BY d_year, c_nation ORDER BY d_year, c_nation LIMIT 10000", 175, {716_800}),
+    "q3_2": (
+        "SELECT c_city, s_city, d_year, SUM(lo_revenue) FROM lineorder_flat WHERE c_nation = 24 AND s_nation = 24 "
+        "AND d_year >= 1992 AND d_year <= 1997 GROUP BY c_city, s_city, d_year ORDER BY d_year ASC, SUM(lo_revenue) DESC "
+        "LIMIT 100000", 437_500, {437_500, 1_312_500}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(COMPACTED_CASES))
+def test_a_filtered_plans_scatters_take_a_sorted_prefix_for_v5e(one_chip, no_compile_cache, monkeypatch, name):
+    """Cell 8's HLL template and cell 5's Q3.2 over one 1.5M-row segment, for a
+    described v5e (PR 51): the program sorts ONE int32 operand a mask (the
+    registers' payload; the row numbers that the count and the two limb
+    tables share), under an event name `compact_sort_ms` matches, and the
+    scatters still write the flat tables `table_scatter_ms_per_query` tells
+    by their size (`sketch_scatter_ms`, `wide_table_ms`)."""
+    from pinot_tpu.query import planner
+    from pinot_tpu.segment.builder import build_segment
+    from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
+    from pinot_tpu.sql.parser import parse_query
+
+    monkeypatch.setattr(ops, "scan_backend", lambda: "pallas")
+    monkeypatch.setattr(ops, "accum_policy", lambda: "chunked32")
+    monkeypatch.setattr(segmented, "accum_policy", lambda: "chunked32")
+    planner.plan_cache_clear()
+
+    rows = 1_500_000
+    rng = np.random.default_rng(51)
+    domains = {"d_year": (1992, 1999), "c_nation": (0, 25), "s_nation": (0, 25), "s_region": (0, 5), "c_city": (0, 250),
+               "s_city": (0, 250), "lo_custkey": (1, 100_001)}
+    schema = Schema("lineorder_flat", [FieldSpec(d, DataType.INT) for d in domains]
+                    + [FieldSpec("lo_revenue", DataType.INT, role=FieldRole.METRIC)])
+    block = {d: rng.integers(lo, hi, rows).astype(np.int32) for d, (lo, hi) in domains.items()}
+    block["lo_revenue"] = rng.integers(90_000, 10_000_000, rows).astype(np.int32)
+    seg = build_segment(schema, block, "seg0")
+    sql, group_space, tables = COMPACTED_CASES[name]
+    try:
+        plan = planner.plan_segment(parse_query(sql), seg)
+        assert plan.kind == "groupby_dense" and plan.num_groups == group_space
+        cols = seg.to_device(columns=plan.needed_columns, packed_codes=True, value_columns=plan.value_columns)  # on the CPU: shapes only
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        text = plan.fn.lower(jax.tree_util.tree_map(described, cols), plan.params).compile().as_text()
+        assert plan.mask_facts.filtered and plan.mask_facts.compactions == 1
+    finally:
+        planner.plan_cache_clear()
+    # a device trace names an event by its instruction: `%name = shape op(operands...`
+    instructions = [line.strip() for line in text.splitlines() if re.match(r"\s*(ROOT )?%[\w.\-]+ = ", line)]
+    sort = re.compile(_layer_metric("compact_sort_ms")["pattern"])
+    sorts = [line for line in instructions if sort.search(line.removeprefix("ROOT "))]
+    assert len(sorts) == 1 and f"s32[{rows}]" in sorts[0], sorts
+    assert not [line for line in instructions if " sort(" in line and line not in sorts]
+    scatter = re.compile(r"^%[\w.\-]+ = [suf]32\[(\d+)\]\S* fusion\(.*kind=kCustom")  # benchmarks/lib/reducers/table_scatter_ms_per_query.py
+    written = {int(m.group(1)) for m in map(scatter.match, instructions) if m and int(m.group(1)) % group_space == 0}
+    assert written == tables, written
+    assert text.count(" while(") == 1 and " conditional(" in text  # the trips over the passing prefix, under the cond

@@ -152,13 +152,15 @@ def test_the_trace_time_counter_names_the_form_a_template_got(name, bench, table
     """`scan.traced.wide_scatter` for the 437,500-slot tables, `scan.traced.sparse_sort`
     for Q4.3's 1,750,000 slots, and beside it `scan.traced.sparse_limb_scatter` (PR 42:
     its integer sum rides int32 limbs after the sort) and `scan.traced.sparse_prefix_sums` (PR 44: its
-    count and sum are read from prefix sums at the slots' row ranges): what the benchmark's warm-up line prints."""
+    count and sum are read from prefix sums at the slots' row ranges): what the benchmark's warm-up line prints.
+    A wide table's plan has a WHERE, so its scatters take the passing rows alone (PR 51: `scan.traced.compact_scatter`,
+    once a plan: its count and its limb tables share the mask's one compaction)."""
     planner.plan_cache_clear()
     equal, numbers, _ = _compare(bench, table, name, _params(bench, name)[0])
     assert equal, numbers
     moved = {k.rsplit(".", 1)[1] for k, v in METRICS.snapshot()["counters"].items()
              if k.startswith("scan.traced.") and v and not k.endswith((".lane_unpack", ".xla"))}
-    assert moved == {FORM[name]} | ({"sparse_limb_scatter", "sparse_prefix_sums"} if FORM[name] == "sparse_sort" else set())
+    assert moved == {FORM[name]} | ({"sparse_limb_scatter", "sparse_prefix_sums"} if FORM[name] == "sparse_sort" else {"compact_scatter"})
 
 
 def _f32_tables(entries, codes, num_groups):

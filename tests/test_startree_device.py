@@ -291,6 +291,7 @@ def test_levels_of_unequal_rows_ride_one_program_and_one_launch(bench, ssb):
         "tableShapedSegments": 0,  # a level is bucketed by its own rule, not by the table's shape
         "docRangeSegments": 0, "indexServedPredicates": 0, "indexScannedPredicates": 0,  # nothing sorted, nothing indexed
         "contractedLookups": 0, "gatheredLookups": 0, "residentLookups": 0,  # no table read at a row's code (ops/code_lookup.py)
+        "compactedScatters": 0,  # a level's table is the one-hot kernel's: no row-priced scatter to compact (PR 51)
         "rowBuckets": 1, "rowsPadded": sum(16384 - r for r in rows),  # the one rule of padded rows (PR 50): the levels' too
     }
     assert stats.trace["attrs"]["docsScanned"] == stats.num_docs_scanned == sum(rows)

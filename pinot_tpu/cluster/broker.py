@@ -988,7 +988,6 @@ class Broker:
             kernel_bytes=out.stats.kernel_bytes,
             compile_ms=out.stats.compile_ms,
             cache_hit=out.stats.compile_ms == 0.0,
-            engine="broker",
         )
         return out
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional
@@ -22,6 +23,7 @@ import numpy as np
 
 from pinot_tpu.query.cursors import ResponseStore
 from pinot_tpu.query.result import ResultTable
+from pinot_tpu.utils.interpreter import ACCEPT_LOOP_THREAD, WATCH
 from pinot_tpu.utils.metrics import METRICS, annotate_root, mark, now_ns, stage
 
 
@@ -144,6 +146,12 @@ class QueryServer:
                             return
                         limit = int(qs.get("limit", ["0"])[0]) or None
                         self._send(200, {"queries": slow.snapshot(limit)})
+                    elif url.path == "/debug/interpreter":
+                        # the interpreter lock's waiters, holders and the collector's pauses
+                        # (utils/interpreter.py); ?watch=N turns the watch on for N seconds
+                        if "watch" in qs:
+                            WATCH.renew(float(qs["watch"][0]))
+                        self._send(200, WATCH.snapshot())
                     elif url.path == "/debug/admission":
                         gov = getattr(outer.engine, "governor", None)
                         if gov is None:
@@ -204,9 +212,17 @@ class QueryServer:
                 before its root span on it (acceptT0Ns on t0Ns' clock,
                 acceptWaitMs, headMs, httpReadMs); serialise and write cannot
                 ride the payload they produce, and reach, like the rest, the
-                slow-query log's entry of a request that was slow here."""
+                slow-query log's entry of a request that was slow here.
+                While the interpreter watch runs (a traced query started it,
+                or `/debug/interpreter?watch=N`), and only then, the thread's
+                CPU clock is read at the stamps around the engine call and the
+                serialising and at the end: `doorCpuMs` (the thread's whole
+                life: its clock starts at 0), `engineCpuMs`, `serializeCpuMs`,
+                timers beside their walls and the handler's share of the
+                interpreter's CPU (`runtime.cpuMs.handler`)."""
                 head, accept_ns = self.head, self.accept_ns
                 wait_ms = (head.t0_ns - accept_ns) / 1e6
+                watched = WATCH.running
                 try:
                     with stage("http_read") as read:
                         n = int(self.headers.get("Content-Length", 0))
@@ -216,8 +232,10 @@ class QueryServer:
                         return
                     sql = req.get("sql", "")
                     run = getattr(outer.engine, "sql", None) or outer.engine.query
+                    cpu0 = time.thread_time() if watched else 0.0
                     with stage("http_engine") as eng:
                         result = run(sql)
+                    cpu1 = time.thread_time() if watched else 0.0
                     if result.stats.trace is not None:
                         annotate_root(
                             result.stats.trace, acceptT0Ns=accept_ns, acceptWaitMs=round(wait_ms, 3),
@@ -232,6 +250,7 @@ class QueryServer:
                                 : int(req.get("pageSize", 1000))
                             ]
                         body = json.dumps(payload).encode("utf-8")
+                    cpu2 = time.thread_time() if watched else 0.0
                     with stage("http_write") as write:
                         self._send_body(200, body)
                     door = {
@@ -240,9 +259,15 @@ class QueryServer:
                     }
                     for name, ms in door.items():  # rest.acceptWaitMs ... rest.doorMs
                         METRICS.timer("rest." + name).update(ms)
+                    if watched:
+                        door_cpu = time.thread_time() * 1000.0
+                        METRICS.timer("rest.doorCpuMs").update(door_cpu)
+                        METRICS.timer("rest.engineCpuMs").update((cpu1 - cpu0) * 1000.0)
+                        METRICS.timer("rest.serializeCpuMs").update((cpu2 - cpu1) * 1000.0)
+                        METRICS.counter("runtime.cpuMs.handler").inc(door_cpu)
                     slow = getattr(outer.engine, "slow_queries", None)
                     if slow is not None:
-                        slow.door(result.stats, door)
+                        slow.door(result.stats, door, accept_ns)
                 except Exception as e:  # noqa: BLE001 - boundary
                     from pinot_tpu.analysis.plan_check import PlanCheckError
                     from pinot_tpu.cluster.admission import (
@@ -340,7 +365,7 @@ class QueryServer:
         self._thread: Optional[threading.Thread] = None
 
     def start(self) -> "QueryServer":
-        self._thread = threading.Thread(target=self._httpd.serve_forever, daemon=True)
+        self._thread = threading.Thread(target=self._httpd.serve_forever, daemon=True, name=ACCEPT_LOOP_THREAD)
         self._thread.start()
         return self
 

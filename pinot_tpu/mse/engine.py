@@ -289,7 +289,6 @@ class MultiStageEngine:
             kernel_bytes=out.stats.kernel_bytes,
             compile_ms=out.stats.compile_ms,
             cache_hit=getattr(self, "_last_plan_cache_hit", None),
-            engine="mse",
         )
         return out
 

@@ -383,7 +383,6 @@ class DistributedEngine:
             kernel_bytes=out.stats.kernel_bytes,
             compile_ms=out.stats.compile_ms,
             cache_hit=self._last_plan_cache_hit,
-            engine="dist",
         )
         return out
 

@@ -183,7 +183,6 @@ class QueryEngine:
             kernel_bytes=out.stats.kernel_bytes,
             compile_ms=out.stats.compile_ms,
             cache_hit=out.stats.compile_ms == 0.0,
-            engine="sse",
         )
         return out
 

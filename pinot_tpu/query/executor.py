@@ -210,11 +210,21 @@ def _launch_group(ctx, members: List[_Member], device, trace, on_first_launch=No
                 before[4] if before is not None
                 else planner.identity_tables(program, base.fn, (members[0].cols, base.params), device),
             )
+    counted = {}
+    if trace.enabled:
+        import jax
+
+        # the array leaves the jitted call is handed, which its dispatch walks every call: a resident entry a
+        # column a member, the stacked parameter buffers, the carried tables of a combining call
+        counted["operands"] = (
+            sum(len(entry) for m in members for entry in m.cols.values()) + len(args[1])
+            + (len(jax.tree_util.tree_leaves(args[2])) if combined is not None else 0)
+        )
     out, members[0].stats.compile_ms = _enqueue(
         trace, program, args, device, on_first_launch,
         segments=width, width=width, kind=base.kind, backend=base.cache_key[2],
         # the host operands the call ships: the members' packed parameters
-        paramBytes=sum(int(v.nbytes) for v in args[1].values()),
+        paramBytes=sum(int(v.nbytes) for v in args[1].values()), **counted,
     )
     tables, plans, stats = [m.table for m in members], [m.plan for m in members], [m.stats for m in members]
     if combined is not None and before is not None:

@@ -81,6 +81,15 @@ class Timer:
             if ms > self.max_ms:
                 self.max_ms = ms
 
+    def merge(self, count: int, total_ms: float, max_ms: float) -> None:
+        """`count` updates at once, timed where no lock may be taken (the
+        collector's callback, utils/interpreter.py)."""
+        with self._lock:
+            self.count += count
+            self.total_ms += total_ms
+            if max_ms > self.max_ms:
+                self.max_ms = max_ms
+
     @property
     def mean_ms(self) -> float:
         with self._lock:
@@ -209,7 +218,9 @@ class MetricsRegistry:
 
     def _copies(self):
         """Stable name->metric copies: concurrent registration must never
-        blow up the snapshot iteration (dict-changed-size)."""
+        blow up the snapshot iteration (dict-changed-size).  What the
+        collector's callback timed since the last read is published first."""
+        _interpreter.flush_gc()
         with self._lock:
             return (
                 dict(self._counters),
@@ -258,6 +269,7 @@ class MetricsRegistry:
         return "\n".join(lines) + "\n"
 
     def reset(self) -> None:
+        _interpreter.flush_gc()  # pauses from before the reset do not show after it
         with self._lock:
             self._counters.clear()
             self._gauges.clear()
@@ -567,6 +579,8 @@ class Trace:
         self.query_id = query_id
         self.totals_ns: Dict[str, int] = collections.defaultdict(int)  # span name -> summed ns, this query
         self.root = Span(root, cpu=True) if enabled else None
+        if enabled:
+            _interpreter.WATCH.renew()  # a traced query is watched: the interpreter lock's waiters and holders
         if self.root is not None and query_id is not None:
             self.root.attrs["queryId"] = query_id
         self._stack = [self.root] if enabled else []
@@ -599,3 +613,7 @@ class Trace:
             self.root.close()
             return self.root.to_dict()
         return None
+
+
+# the one resource the spans do not see; imported last, because it feeds METRICS, Stage and mark above
+from pinot_tpu.utils import interpreter as _interpreter  # noqa: E402

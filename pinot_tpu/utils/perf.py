@@ -119,7 +119,6 @@ class ShapeStats:
         kernel_bytes: float = 0.0,
         compile_ms: float = 0.0,
         cache_hit: Optional[bool] = None,
-        engine: str = "sse",
     ) -> None:
         if not table:
             table = "_unknown"

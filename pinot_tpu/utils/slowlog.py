@@ -26,6 +26,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from pinot_tpu.utils.interpreter import WATCH
 from pinot_tpu.utils.metrics import METRICS
 
 
@@ -127,13 +128,18 @@ class SlowQueryLog:
         if stats is not None and stats.trace is not None:
             entry["trace"] = stats.trace
 
-    def door(self, stats, door: Dict[str, float]) -> None:
+    def door(self, stats, door: Dict[str, float], accept_ns: Optional[int] = None) -> None:
         """The front door's verdict on a request it has just answered:
         `door` holds its times there in ms (`doorMs`: accept() to the last
         byte written).  Slow by `doorMs`, the request's entry keeps them and
         `stagesMs`: per source (`broker`, each server) what every stage
-        summed to over the query, `launch:<segment>` folded into `launch`.
-        A fast request's entry is left as record() made it."""
+        summed to over the query, `launch:<segment>` folded into `launch`;
+        and, where the interpreter watch ran and `accept_ns` says when the
+        request began on the tracer's clock, `heldBy`: the holds of the
+        interpreter lock that overlap its life (utils/interpreter.py: holder,
+        thread, frame, lateMs), so the record that says WHERE the request
+        waited says for WHOM.  A fast request's entry is left as record()
+        made it."""
         entry = stats.slow_entry
         if entry is None or door["doorMs"] < self.slow_ms:
             return
@@ -148,6 +154,8 @@ class SlowQueryLog:
                 self._slow(entry, stats)  # record() had let it pass
             entry["door"] = {k: round(v, 3) for k, v in door.items()}
             entry["stagesMs"] = {s: {k: round(ns / 1e6, 3) for k, ns in d.items()} for s, d in stages.items()}
+            if accept_ns is not None:
+                WATCH.held_by(entry, accept_ns, accept_ns + int(door["doorMs"] * 1e6))
 
     def snapshot(self, limit: Optional[int] = None) -> List[Dict[str, Any]]:
         with self._lock:

@@ -33,9 +33,11 @@ def _reset_metrics():
     """The METRICS registry and the stats window are process-global; without
     a reset, counter/histogram assertions and federated per-server series
     see spill-over from whichever tests ran before."""
+    from pinot_tpu.utils.interpreter import WATCH
     from pinot_tpu.utils.metrics import METRICS
     from pinot_tpu.utils.perf import SHAPE_STATS
 
+    WATCH.stop()  # a traced query of the test before started the interpreter watch: it must not tick into this one
     METRICS.reset()
     SHAPE_STATS.reset()
     yield

@@ -28,6 +28,14 @@ wall on the group-level stages, the server's loop) are cases of the first
 kind: each names an attr or a timer, and the traced answers came through the
 door, so the attr is looked for and not the span alone.
 
+The metrics of PR 52 (the interpreter watch, utils/interpreter.py: the lock's
+waiters and holders, the collector's pauses, CPU beside wall at the front door,
+the jitted call's operands) are cases of the first kind again: the traced
+answers started the watch, so its timers ticked, its counters are held (the
+ones that count what may never happen at 0) and the door read its thread's
+CPU clock.  `INVENTORY`'s case goes the other way: every span, attr, counter
+and timer those served queries LEFT has a reader named in PERF.md section 3.
+
 The files are read as data: nothing of `benchmarks/lib` is imported, and no
 number is checked, only presence.  The last two tests alone run the
 benchmark's own readers: PR 36 was refused because one of them found nothing
@@ -53,12 +61,13 @@ from pinot_tpu.query import planner
 from pinot_tpu.segment.builder import build_segment
 from pinot_tpu.spi.config import TableConfig
 from pinot_tpu.spi.schema import DataType, FieldRole, FieldSpec, Schema
+from pinot_tpu.utils.interpreter import WATCH
 from pinot_tpu.utils.metrics import METRICS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # the keys by which a metric's file names something of the program
 SPAN_KEYS = ("spans", "span", "numerator", "denominator")
-REGISTRY_KEYS = ("counter", "counters", "prefix", "timer")
+REGISTRY_KEYS = ("counter", "counters", "over", "prefix", "timer")
 
 
 DRILL_LAYER = "wide and sparse group-by"
@@ -67,6 +76,15 @@ STAR_LAYER = "star-tree"
 # (a reducer that finds no such counter reports nothing), at 0 after QUERIES,
 # whose every plan-cache hit binds its parameters by the entry's recipe
 SOUND_AT_ZERO = {"plan_rebuilds_in_window"}
+# counters of what may or may not happen while QUERIES run (a hold of the interpreter lock past 50 ms, a full
+# collection): the registry must HOLD them, moved or not
+HELD_WHATEVER_MOVED = {"interpreter_holds_in_window", "gc_full_collections_in_window"}
+# PR 52's, all among SPECS
+INTERPRETER_SPECS = {
+    "interpreter_wait_ms", "interpreter_wait_over_20ms_share", "interpreter_cpu_share", "interpreter_embedder_share",
+    "interpreter_holds_in_window", "gc_pause_ms", "gc_full_collections_in_window", "frontdoor_door_cpu_ms",
+    "frontdoor_engine_cpu_ms", "frontdoor_serialize_cpu_ms", "launch_operands_per_query",
+}
 # read what only a group-by produces (BENCHMARK.json lists their cells): looked for in QUERIES' group-by alone
 GROUP_BY_ONLY = {"table_decode_cpu_ms", "tables_decoded_per_query", "tables_merged_by_value_per_query",
                  "sketch_table_bytes_per_query"}
@@ -166,6 +184,7 @@ def served():
             settled.wait(0.01)
     finally:
         front.stop()
+        WATCH.stop()  # the traced answers started it; a reset registry must not tick on into the next module
     counters, timers = {}, {}
     for snap in [METRICS.snapshot()] + [s.metrics.snapshot() for s in servers]:
         for k, v in snap["counters"].items():
@@ -205,10 +224,14 @@ def test_program_still_says_what_the_metric_reads(name, served):
     if "counter" in spec:
         if name in SOUND_AT_ZERO:
             assert counters.get(spec["counter"]) == 0, f"{name}: counter {spec['counter']!r} missing, or moved"
+        elif name in HELD_WHATEVER_MOVED:
+            assert spec["counter"] in counters, f"{name}: counter {spec['counter']!r}"
         else:
             assert counters.get(spec["counter"], 0) > 0, f"{name}: counter {spec['counter']!r}"
-    for counter in spec.get("counters", ()):  # a sum of several: each is held, whether it moved or not
-        assert counter in counters, f"{name}: counter {counter!r}"
+    for counter in list(spec.get("counters", ())) + list(spec.get("over", ())):  # a sum of several, a ratio's
+        assert counter in counters, f"{name}: counter {counter!r}"  # denominator: each is held, moved or not
+    if "over" in spec:
+        assert sum(counters[c] for c in spec["over"]) > 0, f"{name}: the denominator {spec['over']} stood still"
     if "prefix" in spec:
         family = {k: v for k, v in counters.items() if k.startswith(spec["prefix"])}
         # replication 2 over two servers: the balanced selector routes to both
@@ -259,6 +282,76 @@ def test_launches_per_query_counts_the_jitted_calls(served):
             # two of the four segments a server, in one call and one fetch
             assert len(_named(root, "launch")) == enqueues[0]["attrs"]["segments"] == 2
             assert [n["attrs"]["segments"] for n in _named(root, "collect")] == [2]
+
+
+def test_the_interpreter_watch_has_its_metrics():
+    assert INTERPRETER_SPECS <= set(SPECS)
+    layers = {SPECS[n]["layer"] for n in INTERPRETER_SPECS}
+    assert layers == {"interpreter lock", "front door", "per-server execute"}
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name in INTERPRETER_SPECS:  # every cell is given them: no `workloads` list
+        assert "workloads" not in listed[name] and listed[name]["moves"] == "latency_p50_ms", name
+
+
+def test_the_served_queries_were_watched(served):
+    """The traced answers of QUERIES started the watch: it ticked, it read
+    the threads' clocks by class, and the door read its own thread's."""
+    _, counters, timers = served
+    assert timers["runtime.interpreterWaitMs"] == counters["runtime.interpreterWait.ticks"] > 0
+    assert counters["runtime.watchedMs"] > 0 and counters["runtime.cpuMs.watch"] > 0
+    assert counters["runtime.cpuMs.handler"] > 0 and timers["rest.doorCpuMs"] >= len(QUERIES) - 1
+    assert timers["rest.doorCpuMs"] == timers["rest.engineCpuMs"] == timers["rest.serializeCpuMs"]
+    assert counters["runtime.gc.gen1"] + counters["runtime.gc.gen2"] == timers["runtime.gcPauseMs"] > 0
+
+
+def test_launch_operands_counts_what_the_jitted_call_is_handed(served):
+    """`operands` on `launch_enqueue`: a resident entry a column a member,
+    the stacked parameter buffers (`paramArrays` on `launch_ship`), the
+    carried (presence, partials) of a combining call."""
+    trees, _, _ = served
+    for sql, tree in zip(QUERIES, trees):
+        for root in [n for n in _named(tree, "server") if "server" in n.get("attrs", {})]:
+            (enqueue,) = _named(root, "launch_enqueue")
+            ships = _named(root, "launch_ship")
+            params = {n["attrs"]["paramArrays"] for n in ships}
+            assert len(params) == 1 and len(ships) == enqueue["attrs"]["segments"] == 2
+            operands = enqueue["attrs"]["operands"]
+            # at least an entry a column a member and the parameter buffers; the exact count is held against the
+            # call's own arguments in tests/test_span_cpu.py
+            assert isinstance(operands, int) and operands >= 2 + params.pop(), sql
+
+
+# what a served query leaves and PERF.md section 3 does not name with its reader is removed or documented:
+# names that stand there under a family's stem (`residency.<server>.hits`, `.launchShipMs`)
+INVENTORY_STEMS = {
+    "broker.routedSegments.": "broker.routedSegments.<server>", "residency.server": "residency.<server>",
+    "server.launch": "server.launchPlanMs", "server.collectMs": ".collectMs", "server.compileMs": ".compileMs",
+    "runtime.cpuMs.": "runtime.cpuMs.<class>", "runtime.interpreterWait.": "runtime.interpreterWait.ticks",
+    "runtime.gc.": "runtime.gc.gen1",
+}
+
+
+def test_every_name_a_served_query_leaves_has_a_reader_in_perf_md(served):
+    trees, counters, timers = served
+    with open(os.path.join(ROOT, "PERF.md"), encoding="utf-8") as f:
+        layers = f.read().split("## 3. Layers", 1)[1].split("## 4. Cells", 1)[0]
+    names = set(counters) | set(timers)
+
+    def walk(node):
+        names.add(node["name"].split(":", 1)[0])
+        names.update(node.get("attrs", {}))
+        for child in node.get("children", ()):
+            walk(child)
+
+    for tree in trees:
+        walk(tree)
+    unread = []
+    for name in sorted(names):
+        stem = next((doc for stem, doc in INVENTORY_STEMS.items() if name.startswith(stem)), name)
+        if f"`{stem}`" not in layers and f"`{stem}" not in layers and f"{stem}`" not in layers:
+            unread.append(name)
+    assert not unread, f"left by a served query, with no reader named in PERF.md section 3: {unread}"
 
 
 # ---------------------------------------------------------------------------
@@ -582,13 +675,18 @@ def test_every_metric_of_the_star_tree_cell_has_a_reader_that_returns_a_value():
     assert values["compiles_in_window"] == values["plan_rebuilds_in_window"] == 0.0
 
 
-def test_the_door_and_cpu_metrics_read_a_value_on_a_scan_cells_traffic():
+@pytest.fixture(scope="module")
+def q1_window():
+    return _toy_window("ssb_sf10.q1_closed", 40_000, 39)
+
+
+def test_the_door_and_cpu_metrics_read_a_value_on_a_scan_cells_traffic(q1_window):
     """PR 39's metrics carry no `workloads` list (but `table_decode_cpu_ms`), so
     every cell is given them: on Q1's traffic, which decodes no table, each
     returns a value and `table_decode_cpu_ms` is not asked for; and what they
     return hangs together: the door's time holds its parts, a stage's CPU is at
     most its wall, the server's loop is part of `dispatch`."""
-    values, cell, reqs, _ = _toy_window("ssb_sf10.q1_closed", 40_000, 39)
+    values, cell, reqs, _ = q1_window
     assert DOOR_SPECS - {"table_decode_cpu_ms"} <= set(values) and "table_decode_cpu_ms" not in values
     assert not [name for name, v in values.items() if v is None], values
     assert all(values[name] >= 0.0 for name in DOOR_SPECS & set(values)), values
@@ -602,6 +700,27 @@ def test_the_door_and_cpu_metrics_read_a_value_on_a_scan_cells_traffic():
     # one update of rest.doorMs an answered request: the two means of frontdoor_before_accept_ms are over the same requests
     sent = np.mean([r.done - r.sent for r in reqs]) * 1000.0
     assert values["frontdoor_before_accept_ms"] == pytest.approx(sent - values["frontdoor_door_ms"])
+
+
+@pytest.mark.parametrize("name", sorted(INTERPRETER_SPECS))
+def test_an_interpreter_metric_reads_a_value_on_a_scan_cells_traffic(name, q1_window):
+    """PR 52's eleven carry no `workloads` list: every cell is given them,
+    and each returns a value over a traced window (the traced warm-up
+    started the watch; the window's own traced queries kept it)."""
+    values = q1_window[0]
+    assert values[name] is not None and values[name] >= 0.0, (name, values[name])
+    if name.endswith("_share"):
+        assert values[name] <= (8.0 if name == "interpreter_cpu_share" else 1.0)  # cores here; a share of a whole
+    if name == "launch_operands_per_query":
+        assert values[name] >= values["launches_per_query"] * 2
+
+
+def test_what_the_interpreter_metrics_read_hangs_together(q1_window):
+    values = q1_window[0]
+    assert values["frontdoor_engine_cpu_ms"] + values["frontdoor_serialize_cpu_ms"] <= values["frontdoor_door_cpu_ms"] + 0.01
+    assert values["frontdoor_door_cpu_ms"] <= values["frontdoor_door_ms"] + 1.0
+    assert values["frontdoor_engine_cpu_ms"] <= values["frontdoor_engine_ms"] + 1.0
+    assert values["interpreter_cpu_share"] > 0.0 and values["interpreter_embedder_share"] > 0.0  # the clients are this process's
 
 
 def test_every_metric_of_the_built_apart_cell_has_a_reader_that_returns_a_value(monkeypatch):

@@ -157,3 +157,77 @@ def test_an_untraced_query_never_reads_the_cpu_clock(broker, monkeypatch, sql):
     traced = broker.query("SET trace = true; " + sql)
     spans = [n for name in CPU_STAGES + ("launch", "query", "server") for n in _named(traced.stats.trace, name)]
     assert len(reads) == 2 * len(spans)  # once at entry, once at exit, of every span that asked and no other
+
+
+
+@pytest.mark.parametrize("sql", [GROUP_SQL, SCALAR_SQL], ids=["group_by", "scalar"])
+def test_launch_enqueue_says_how_many_arrays_the_jitted_call_is_handed(broker, monkeypatch, sql):
+    """`operands` (PR 52): the array leaves of the call's arguments, counted
+    from the members' column entries and the parameter buffers without
+    flattening them; a combining call also carries its tables.  An untraced
+    query counts nothing."""
+    import jax
+
+    from pinot_tpu.query import executor
+
+    handed = []
+    real = executor._enqueue
+
+    def counting(trace, plan, args, device, on_first_launch=None, **attrs):
+        handed.append((len(jax.tree_util.tree_leaves(args)), attrs))
+        return real(trace, plan, args, device, on_first_launch, **attrs)
+
+    monkeypatch.setattr(executor, "_enqueue", counting)
+    assert broker.query(sql).rows
+    assert handed and all("operands" not in attrs for _, attrs in handed)
+    del handed[:]
+    traced = broker.query("SET trace = true; " + sql).stats.trace
+    spans = _named(traced, "launch_enqueue")
+    assert [sp["attrs"]["operands"] for sp in spans] == [leaves for leaves, _ in handed]
+    assert all(leaves >= SEGMENTS + 1 for leaves, _ in handed)  # four members' columns and their parameters
+
+
+def test_the_front_door_reads_no_cpu_clock_until_the_watch_runs(broker, monkeypatch):
+    """The handler reads `time.thread_time` only while the interpreter watch
+    runs (utils/interpreter.py): an untraced, unwatched request through the
+    front door reads it 0 times, handler included, and starts no thread."""
+    import json
+    import threading
+    import urllib.request
+
+    from pinot_tpu.cluster.rest import QueryServer
+    from pinot_tpu.utils.interpreter import WATCH, WATCH_THREAD
+
+    WATCH.stop()
+    reads = []
+    real = time.thread_time
+
+    def counting():
+        reads.append(threading.current_thread().name)
+        return real()
+
+    monkeypatch.setattr(metrics.time, "thread_time", counting)
+    front = QueryServer(broker).start()
+
+    def post(sql):
+        req = urllib.request.Request(f"http://127.0.0.1:{front.port}/query/sql", data=json.dumps({"sql": sql}).encode(),
+                                     headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=60) as r:
+            return json.loads(r.read().decode("utf-8"))
+
+    try:
+        for sql in (GROUP_SQL, SCALAR_SQL):
+            assert post(sql)["resultTable"]["rows"]
+        assert not reads and not [t for t in threading.enumerate() if t.name == WATCH_THREAD]
+        assert post("SET trace = true; " + GROUP_SQL)["trace"]  # starts the watch
+        assert WATCH.running and reads
+        del reads[:]
+        assert post(GROUP_SQL)["resultTable"]["rows"]  # untraced, watched: the door's four reads and no span's
+        for _ in range(500):
+            if len(reads) >= 4:
+                break
+            time.sleep(0.01)
+        assert len(reads) == 4 and all("process_request_thread" in name for name in reads)
+    finally:
+        front.stop()
+        WATCH.stop()
